@@ -1,12 +1,8 @@
 """Flash vs dense attention, forward + backward, on the real chip.
 
 The long-context story: the Pallas kernels (block-512, O(L) memory) against
-the XLA dense path (O(L²) memory) across sequence lengths. Measured v5e
-results (B=4, H=12, D=64, bf16, causal):
-
-    L=1024: flash fwd ~5.9ms  grad ~4.3ms  | dense fwd ~6.0ms  grad ~7.7ms
-    L=2048: flash fwd ~6.7ms  grad ~7.6ms  | dense fwd ~11.6ms grad ~15.4ms
-    L=4096: flash fwd ~15.7ms grad ~20.7ms | dense fwd ~24.4ms grad ~51.9ms
+the XLA dense path (O(L²) memory) across sequence lengths (B=4, H=12, D=64,
+bf16, causal). On this installation: not measured.
 
 Also benches the paged-attention decode kernel (block-table-native, scalar
 prefetch) against the gather reference that materializes the whole
@@ -90,6 +86,9 @@ def main():
     ap.add_argument("--skip-flash", action="store_true",
                     help="bench only the paged-attention rows")
     args = ap.parse_args()
+    from ray_tpu.util.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     bench_paged(args.quick)
     if args.skip_flash or args.quick:
         return

@@ -828,6 +828,9 @@ def main() -> int:
                         help="write BENCH_serve_rNN.json at repo root")
     args = parser.parse_args()
 
+    from ray_tpu.util.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.quick:
         results = bench_modes([4], reps=2, slots=4, chunk=args.chunk)
         results += bench_prefix_modes([4], reps=2, slots=4, chunk=args.chunk)

@@ -5,9 +5,10 @@ C ABI + ctypes — pybind11 isn't in the image). Buffers come back as ZERO-COPY
 memoryviews over the shm mapping; ``NativeObjectStore.put/get`` move bytes
 once (producer memcpy into the arena) and never again in-process.
 
-Builds on demand with ``make -C ray_tpu/_native`` (g++ is in the image);
-importers should catch ``NativeStoreUnavailable`` and fall back to the
-pure-Python store.
+The library is not in git: it is built with ``make -C ray_tpu/_native`` on
+first use, and again whenever ``object_store.cc`` is newer than it (g++ is in
+the image); importers should catch ``NativeStoreUnavailable`` and fall back
+to the pure-Python store.
 """
 
 from __future__ import annotations
@@ -23,6 +24,7 @@ logger = get_logger("native_store")
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "_native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libray_tpu_store.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "object_store.cc")
 
 ID_SIZE = 20
 
@@ -34,18 +36,40 @@ class NativeStoreUnavailable(RuntimeError):
 _lib: Optional[ctypes.CDLL] = None
 
 
+def _needs_build() -> bool:
+    if not os.path.exists(_LIB_PATH):
+        return True
+    try:
+        return os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH)
+    except OSError:
+        return False  # no source beside it: the library is what there is
+
+
+def _build() -> None:
+    """``make`` under an exclusive file lock: every daemon and worker of a
+    fresh checkout gets here at once, and the compiler writes the library in
+    place — a second process must neither start a second build over it nor
+    load it half-written."""
+    import fcntl
+
+    try:
+        with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if _needs_build():  # else: built while we waited for the lock
+                subprocess.run(
+                    ["make", "-C", _NATIVE_DIR],
+                    check=True, capture_output=True, timeout=120,
+                )
+    except (OSError, subprocess.SubprocessError) as e:
+        raise NativeStoreUnavailable(f"cannot build native store: {e}") from e
+
+
 def _load() -> ctypes.CDLL:
     global _lib
     if _lib is not None:
         return _lib
-    if not os.path.exists(_LIB_PATH):
-        try:
-            subprocess.run(
-                ["make", "-C", _NATIVE_DIR],
-                check=True, capture_output=True, timeout=120,
-            )
-        except Exception as e:
-            raise NativeStoreUnavailable(f"cannot build native store: {e}") from e
+    if _needs_build():
+        _build()
     lib = ctypes.CDLL(_LIB_PATH)
     lib.rt_store_create.restype = ctypes.c_void_p
     lib.rt_store_create.argtypes = [ctypes.c_char_p, ctypes.c_uint64, ctypes.c_uint32]

@@ -456,12 +456,9 @@ class Runtime:
         """
         if "TPU" in resources:
             return
-        try:
-            from ray_tpu.accelerators import tpu_resources
+        from ray_tpu.accelerators import tpu_resources
 
-            resources.update(tpu_resources())
-        except Exception:  # noqa: BLE001 — detection is best-effort
-            log_swallowed(logger, "TPU resource autodetect")
+        resources.update(tpu_resources())
 
     def add_node(
         self, resources: Dict[str, float], labels: Dict[str, str] | None = None

@@ -842,6 +842,9 @@ def main() -> int:
     _leak_install()  # leak_check_enabled: stamp allocation sites early
     _die_with_parent()
     _install_stack_dumper()
+    from ray_tpu.util.compile_cache import enable_compile_cache
+
+    enable_compile_cache()  # before user code can import jax and compile
     if os.environ.get("RAY_TPU_PROFILE_WORKER"):
         # Debug aid: accumulate a cProfile of every actor-task handler
         # invocation (they run on RPC pool threads, so a main-thread

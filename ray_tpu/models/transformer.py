@@ -195,45 +195,98 @@ def _dense_attention(q, k, v, *, scale: float, cstr=None):
     return out.astype(q.dtype)
 
 
+def _mesh_axes_size(mesh: Mesh, axes) -> int:
+    """Number of shards a logical axis' mesh axes (str | tuple | None) cut."""
+    names = axes if isinstance(axes, tuple) else (axes,)
+    size = 1
+    for name in names:
+        if name is not None and name in mesh.shape:
+            size *= mesh.shape[name]
+    return size
+
+
+def _make_flash_attention(scale: float, mesh: Optional[Mesh],
+                          rules: ShardingRules):
+    """Causal flash attention over [B, L, H, Dh], per shard under a mesh.
+
+    A Mosaic kernel is one device's program: GSPMD cannot partition it, so
+    on a mesh the call is wrapped in ``shard_map`` — batch on the data axes,
+    heads on the tensor axis, every shard a full-length causal problem of its
+    own. (A sharded SEQUENCE is not: see ``_make_attention``.) The kernel is
+    compiled when the devices it runs on are TPUs and interpreted anywhere
+    else — read off the mesh when there is one, so a program lowered for a
+    device other than the default backend's gets the right kernel."""
+    from ray_tpu.ops.flash_attention import flash_attention
+
+    platform = (mesh.devices.flat[0].platform if mesh is not None
+                else jax.default_backend())
+    interpret = platform != "tpu"
+
+    def flash(q, k, v):
+        # Largest block ≤512 that divides the sequence, so lengths like 1280
+        # run the kernel; a length no block divides raises inside it.
+        l = q.shape[1]
+        blk = next((b for b in (512, 256, 128) if l % b == 0), 128)
+        return flash_attention(q, k, v, True, scale, blk, blk, interpret)
+
+    if mesh is None:
+        return flash
+    q_spec = rules.mesh_axes(("batch", None, "heads", None))
+    kv_spec = rules.mesh_axes(("batch", None, "kv_heads", None))
+    return jax.shard_map(flash, mesh=mesh,
+                         in_specs=(q_spec, kv_spec, kv_spec),
+                         out_specs=q_spec, check_vma=False)
+
+
 def _make_attention(config: TransformerConfig, mesh: Optional[Mesh],
                     rules: Optional[ShardingRules] = None):
     scale = 1.0 / config.head_dim ** 0.5
     impl = config.attn_impl
-    # Largest power-of-two block ≤512 that divides the sequence, so the
-    # kernel never silently falls back to dense for lengths like 1280.
-    block = next((b for b in (512, 256, 128)
-                  if config.max_seq_len % b == 0), None)
-    if impl == "auto":
-        # Flash wins on TPU from ~1k tokens (block-512 kernels beat the
-        # dense path ~2x fwd+bwd at 2k-4k, measured on v5e); below that or
-        # for ragged lengths the dense path is simpler and as fast.
-        impl = ("flash" if config.max_seq_len >= 1024 and block is not None
-                else "dense")
-    if impl == "flash":
-        import jax as _jax
+    if impl not in ("auto", "dense", "flash", "ring", "ulysses"):
+        raise ValueError(f"unknown attn_impl {config.attn_impl!r}")
+    axes = rules or ShardingRules()
+    seq_shards = (_mesh_axes_size(mesh, axes.seq_act)
+                  if mesh is not None else 1)
+    if seq_shards > 1:
+        # Per-shard flash over a sharded sequence would mask each block as
+        # if it started at position 0 — wrong causality, silently.
+        if impl == "flash":
+            raise ValueError(
+                f"attn_impl='flash' cannot run with the sequence sharded "
+                f"{seq_shards} ways; use 'ring' or 'ulysses' (or 'auto')")
+        if impl == "auto":
+            impl = "ring"
 
-        from ray_tpu.ops.flash_attention import flash_attention
+    if mesh is not None and rules is not None:
+        dense = functools.partial(
+            _dense_attention, scale=scale,
+            cstr=lambda x, logical: constrain(x, mesh, rules, logical))
+    else:
+        dense = functools.partial(_dense_attention, scale=scale)
 
-        interpret = _jax.default_backend() != "tpu"
-        blk = block or 128
-        return lambda q, k, v: flash_attention(
-            q, k, v, True, scale, blk, blk, interpret
-        )
+    if impl in ("auto", "flash"):
+        flash = _make_flash_attention(scale, mesh, axes)
+        if impl == "flash":
+            return flash
+
+        def auto(q, k, v):
+            # Flash from 1k tokens up; below that, or for a length the
+            # kernel's blocks do not divide, the dense path. Decided on the
+            # traced length, which is static. (The 1k threshold predates
+            # this installation and has not been re-measured: ROADMAP S1.)
+            l = q.shape[1]
+            return (flash if l >= 1024 and l % 128 == 0 else dense)(q, k, v)
+
+        return auto
     if impl == "dense" or mesh is None:
-        if mesh is not None and rules is not None:
-            return functools.partial(
-                _dense_attention, scale=scale,
-                cstr=lambda x, logical: constrain(x, mesh, rules, logical))
-        return functools.partial(_dense_attention, scale=scale)
+        return dense
     if impl == "ring":
         from ray_tpu.parallel.ring_attention import make_ring_attention
 
         return make_ring_attention(mesh, causal=True, scale=scale)
-    if impl == "ulysses":
-        from ray_tpu.parallel.ring_attention import make_ulysses_attention
+    from ray_tpu.parallel.ring_attention import make_ulysses_attention
 
-        return make_ulysses_attention(mesh, causal=True, scale=scale)
-    raise ValueError(f"unknown attn_impl {config.attn_impl!r}")
+    return make_ulysses_attention(mesh, causal=True, scale=scale)
 
 
 def make_block_fn(
@@ -463,11 +516,7 @@ def pp_lm_loss(
     tokens = batch["tokens"]
     B, L = tokens.shape
     assert B % num_microbatches == 0, (B, num_microbatches)
-    dp = 1
-    for ax in (rules.batch if isinstance(rules.batch, tuple)
-               else (rules.batch,)):
-        if ax is not None and ax in mesh.shape:
-            dp *= mesh.shape[ax]
+    dp = _mesh_axes_size(mesh, rules.batch)
     assert B % dp == 0 and (B // dp) % num_microbatches == 0, (
         f"per-device batch {B}/{dp} must split evenly into "
         f"{num_microbatches} microbatches")

@@ -18,9 +18,14 @@ probability matrix:
   ``dk += dsᵀ @ q``.
 
 ``delta = rowsum(dO * O)`` is a cheap elementwise reduce left to XLA fusion.
-Sequence lengths not divisible by the block size fall back to the XLA dense
-path (odd L is never the perf-critical case). Numerics are validated against
+Sequence lengths must divide the block size: a ragged length raises (there is
+no padded kernel); ``models.transformer``'s ``attn_impl="auto"`` picks the
+dense path for those. Numerics are validated against
 ``parallel.ring_attention.reference_attention`` in interpret mode on CPU.
+
+The kernels see per-device arrays only — Mosaic calls cannot be partitioned
+by GSPMD. Under a mesh the caller wraps ``flash_attention`` in
+``jax.shard_map`` (``models.transformer._make_attention``).
 """
 
 from __future__ import annotations
@@ -107,9 +112,6 @@ def _flash_forward(
     o [BH, L, D], lse [BH, L, 1] (row log-sum-exp of scaled scores)."""
     bh, lq, d = q.shape
     lk = k.shape[1]
-    assert lq % block_q == 0 and lk % block_k == 0, (
-        f"seq lens ({lq},{lk}) must divide blocks ({block_q},{block_k})"
-    )
     q_blocks = lq // block_q
     kv_blocks = lk // block_k
 
@@ -360,12 +362,12 @@ def _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     b, l, h, d = q.shape
     s = scale if scale is not None else 1.0 / d**0.5
     bq = min(block_q, l)
-    bk = min(block_k, l)
+    bk = min(block_k, k.shape[1])
     if l % bq != 0 or k.shape[1] % bk != 0:
-        # Odd sequence lengths: take the dense path rather than tracing a
-        # kernel with ragged blocks (padding+masking inside the kernel is a
-        # later optimization; odd L is never the perf-critical case).
-        return _dense_reference(q, k, v, scale=s, causal=causal), (q, k, v, None, None)
+        raise ValueError(
+            f"flash_attention: sequence lengths ({l}, {k.shape[1]}) must be "
+            f"multiples of the blocks ({bq}, {bk}); use the dense path for "
+            f"ragged lengths")
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
     of, lse = _flash_forward(
         qf, kf, vf,
@@ -378,13 +380,6 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, of, lse = res
     b, l, h, d = q.shape
     s = scale if scale is not None else 1.0 / d**0.5
-    if of is None:
-        # Dense-path residuals (ragged seq len): recompute-through-XLA.
-        _, vjp = jax.vjp(
-            lambda q, k, v: _dense_reference(q, k, v, scale=s, causal=causal),
-            q, k, v,
-        )
-        return vjp(g)
     bq = min(block_q, l)
     bk = min(block_k, k.shape[1])
     dqf, dkf, dvf = _flash_backward(

@@ -14,10 +14,10 @@ per-slot lengths ride in as scalar-prefetch operands
 (``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index map dereferences
 ``tables[s, j]`` on the host side of the DMA pipeline and each grid program
 streams pool blocks straight from HBM into VMEM — only the
-``ceil(len/block_tokens)`` LIVE blocks of its slot do real work. Dead table
-entries point at the reserved trash block 0, and because consecutive grid
-steps that map to the same pool block skip the re-fetch, the dead tail of a
-table costs one block of traffic, not ``NB - live``. Softmax is the online
+``ceil(len/block_tokens)`` LIVE blocks of its slot do real work. Grid steps
+past the last live block re-map onto it, and because consecutive grid steps
+that map to the same pool block skip the re-fetch, the dead tail of a table
+costs no traffic, not ``NB - live`` blocks of it. Softmax is the online
 (m, l, acc) accumulator pattern shared with ``flash_attention._flash_kernel``,
 held in VMEM scratch across the kv sweep.
 
@@ -27,6 +27,16 @@ position ``lengths[s] + t`` and attends kv positions ``<= lengths[s] + t``.
 The T new tokens' K/V must already be scattered into the pool at those
 positions (the caller writes K/V first, then attends — same order as the
 gather path).
+
+Grid: ``(S, q tiles, nb_seq)``, kv innermost. Queries are tiled
+``_Q_TILE`` at a time so the per-step VMEM footprint (q/out blocks plus the
+``[H*tile, ·]`` f32 accumulators, whose 1-wide m/l columns pad to a full
+128-lane tile) is bounded by the tile, not by T: a 1024-token prefill as
+ONE block asked the v5e compiler for 18 MB of scoped VMEM against its 16 MB
+limit. Decode and verify (T <= ``_Q_TILE``) are a single tile — the same
+program as before the tile axis existed. Each tile sweeps only the blocks
+at or below its own last query; the index map clamps later steps onto that
+block, so the revisit-skip makes their DMA free.
 
 Runs compiled on TPU and in interpret mode on CPU (the tier-1 path);
 ``paged_attention_reference`` is the gather-path oracle the kernel is
@@ -44,11 +54,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
+# Queries per grid step. 128 rows x 12 heads of f32 accumulators is ~2.4 MB
+# of scratch on v5e; untuned (ROADMAP S2) — chosen to fit, not to be fast.
+_Q_TILE = 128
+
+
+def _last_block(first_pos, q_tile: int, block_tokens: int):
+    """Highest block index holding a position attended by the ``q_tile``
+    queries that start at absolute position ``first_pos`` (the last of them
+    sits at ``first_pos + q_tile - 1``). One formula for the kernel body,
+    which skips later blocks, and the index map, which does not fetch them."""
+    return jax.lax.div(first_pos + q_tile - 1, block_tokens)
 
 
 def _paged_kernel(
     tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
-    q_ref,                     # [1, H, T, D] block
+    q_ref,                     # [1, H, T, D] block — T = one q tile
     k_ref, v_ref,              # [1, bt, H, D] block — pool block tables[s, j]
     o_ref,                     # [1, H, T, D] block
     m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, D]
@@ -56,17 +77,18 @@ def _paged_kernel(
     scale: float,
     block_tokens: int,
     num_heads: int,
-    q_tokens: int,
+    q_tile: int,
     nb_seq: int,
 ):
     s = pl.program_id(0)
-    j = pl.program_id(1)
-    bt, H, T = block_tokens, num_heads, q_tokens
-    ctx = lengths_ref[s]
-    # Highest block index holding any attendable position: query T-1 sits at
-    # ctx + T - 1. Blocks past it are dead — their table entries are trash
-    # (block 0), the revisit-skip makes their DMA free, and the body skips.
-    last_blk = jax.lax.div(ctx + T - 1, bt)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    bt, H, T = block_tokens, num_heads, q_tile
+    # Absolute position of this tile's first query.
+    ctx = lengths_ref[s] + i * T
+    # Grid steps past this block re-map onto it in the index map, so they
+    # cost no DMA, and the body skips them.
+    last_blk = _last_block(ctx, T, bt)
 
     @pl.when(j == 0)
     def _init():
@@ -133,34 +155,44 @@ def paged_attention(
     tables = tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
     qt = q.transpose(0, 2, 1, 3)                      # [S, H, T, D]
+    tq = min(T, _Q_TILE)
+    q_tiles = pl.cdiv(T, tq)
+    if T % tq:
+        # Ragged last tile: pad queries are causally AHEAD of every real one
+        # and their rows are sliced off below.
+        qt = jnp.pad(qt, ((0, 0), (0, 0), (0, q_tiles * tq - T), (0, 0)))
+
+    def kv_index(s, i, j, tbl, ln):
+        last_blk = _last_block(ln[s] + i * tq, tq, bt)
+        return (tbl[s, jnp.minimum(j, last_blk)], 0, 0, 0)
+
+    def q_index(s, i, j, tbl, ln):
+        return (s, 0, i, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(S, nb_seq),
+        grid=(S, q_tiles, nb_seq),
         in_specs=[
-            pl.BlockSpec((1, H, T, D), lambda s, j, tbl, ln: (s, 0, 0, 0)),
-            pl.BlockSpec((1, bt, H, D),
-                         lambda s, j, tbl, ln: (tbl[s, j], 0, 0, 0)),
-            pl.BlockSpec((1, bt, H, D),
-                         lambda s, j, tbl, ln: (tbl[s, j], 0, 0, 0)),
+            pl.BlockSpec((1, H, tq, D), q_index),
+            pl.BlockSpec((1, bt, H, D), kv_index),
+            pl.BlockSpec((1, bt, H, D), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, H, T, D),
-                               lambda s, j, tbl, ln: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, H, tq, D), q_index),
         scratch_shapes=[
-            pltpu.VMEM((H * T, 1), jnp.float32),
-            pltpu.VMEM((H * T, 1), jnp.float32),
-            pltpu.VMEM((H * T, D), jnp.float32),
+            pltpu.VMEM((H * tq, 1), jnp.float32),
+            pltpu.VMEM((H * tq, 1), jnp.float32),
+            pltpu.VMEM((H * tq, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, scale=s_val, block_tokens=bt, num_heads=H,
-            q_tokens=T, nb_seq=nb_seq),
+            q_tile=tq, nb_seq=nb_seq),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, T, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype),
         interpret=interpret,
     )(tables, lengths, qt, k_pool, v_pool)
-    return out.transpose(0, 2, 1, 3)                  # [S, T, H, D]
+    return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, lengths, *,

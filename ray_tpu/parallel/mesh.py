@@ -88,18 +88,18 @@ class MeshSpec:
 
 
 def best_devices(n: Optional[int] = None) -> List[jax.Device]:
-    """All devices of the best available platform (TPU > CPU)."""
-    devs = [d for d in jax.devices() if d.platform != "cpu"]
-    if not devs:
-        devs = jax.devices("cpu")
+    """The accelerator's devices, or the CPU's where JAX found no
+    accelerator; with ``n``, the first ``n`` of them.
+
+    Asking for more than there are is an error: a program written for four
+    chips must not come up on virtual CPU devices because the host has one.
+    CPU meshes are asked for by name (``cpu_mesh``, ``JAX_PLATFORMS=cpu``)."""
+    devs = ([d for d in jax.devices() if d.platform != "cpu"]
+            or jax.devices("cpu"))
     if n is not None:
         if len(devs) < n:
-            cpu = jax.devices("cpu")
-            if len(cpu) >= n:
-                devs = cpu  # virtual CPU mesh (tests / dryrun)
-            else:
-                raise ValueError(f"need {n} devices, have {len(devs)} "
-                                 f"(cpu: {len(cpu)})")
+            raise ValueError(
+                f"need {n} {devs[0].platform} devices, have {len(devs)}")
         devs = devs[:n]
     return devs
 

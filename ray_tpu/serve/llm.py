@@ -659,6 +659,22 @@ class LLMEngine:
         return {"slots_total": float(self.slots), "slots_busy": float(busy),
                 "queue_depth": float(depth)}
 
+    def describe(self) -> Dict:
+        """What this engine resolved to at run time, for an operator or a
+        smoke test to print: names and placements, not numbers (``stats``
+        stays all-float — it feeds the router). ``warmed_buckets`` is empty
+        until ``warmup`` has compiled every bucket."""
+        return {
+            "engine": type(self).__name__,
+            "slots": self.slots,
+            "chunk": self.chunk,
+            "max_len": self.max_len,
+            "warmed_buckets": list(self.buckets) if self._steady else [],
+            "params_devices": sorted(
+                {str(d) for leaf in jax.tree.leaves(self.params)
+                 for d in leaf.devices()}),
+        }
+
     def decode_tokens_per_sec(self) -> float:
         with self._agg_lock:
             if self.decode_seconds == 0:
@@ -1644,6 +1660,15 @@ class PagedLLMEngine(LLMEngine):
             out["spec_accept_ratio"] = float(acc) / prop if prop else 0.0
         return out
 
+    def describe(self) -> Dict:
+        out = super().describe()
+        out["attention_kernel"] = self._pg.attention_kernel  # as resolved
+        out["block_tokens"] = self.block_tokens
+        out["pool_blocks"] = self.kv.num_blocks
+        out["kv_pool_devices"] = sorted(
+            str(d) for d in self._k_pool.devices())
+        return out
+
     def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
         super()._observe(delivered, ttfts)
         hits, self._hit_pending = self._hit_pending, 0
@@ -2075,6 +2100,9 @@ class DisaggregatedLLMEngine:
         out["prefill_kv_blocks_cached"] = pf["kv_blocks_cached"]
         return out
 
+    def describe(self) -> Dict:
+        return self.decode.describe()
+
     def decode_tokens_per_sec(self) -> float:
         return self.decode.decode_tokens_per_sec()
 
@@ -2218,6 +2246,9 @@ def llm_deployment(
 
         def get_engine_stats(self):
             return self.engine.stats()
+
+        def describe(self):
+            return self.engine.describe()
 
         # -- drain migration (controller-driven, cluster KV tier) -------------
         def kv_migrate_out(self, lane_name: str) -> int:

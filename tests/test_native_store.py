@@ -216,3 +216,23 @@ def test_asan_stress_clean():
                          capture_output=True, text=True, timeout=120)
     assert out.returncode == 0, out.stdout + out.stderr[-2000:]
     assert "leftover_objects=0" in out.stdout
+
+
+def test_stale_library_is_rebuilt(tmp_path, monkeypatch):
+    """The library is a build product, not a tracked file: it is rebuilt when
+    missing and when ``object_store.cc`` is newer — never served stale."""
+    from ray_tpu.core import native_store as ns
+
+    lib, src = tmp_path / "lib.so", tmp_path / "object_store.cc"
+    monkeypatch.setattr(ns, "_LIB_PATH", str(lib))
+    monkeypatch.setattr(ns, "_SRC_PATH", str(src))
+    src.write_text("// source")
+    assert ns._needs_build()                      # missing
+    lib.write_bytes(b"")
+    os.utime(lib, (1_000, 1_000))
+    os.utime(src, (2_000, 2_000))
+    assert ns._needs_build()                      # older than its source
+    os.utime(lib, (3_000, 3_000))
+    assert not ns._needs_build()
+    src.unlink()
+    assert not ns._needs_build()                  # shipped without source

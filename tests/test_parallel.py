@@ -35,6 +35,24 @@ def test_mesh_mismatch_raises(cpu_mesh_devices):
         make_mesh(MeshSpec(data=3, tensor=5), cpu_mesh_devices)
 
 
+def test_best_devices_raises_rather_than_substituting(monkeypatch):
+    """One chip and eight CPU devices: asking for four is an error, not a
+    CPU mesh (the silent swap is how a 4-chip program 'ran' on no chips)."""
+    from ray_tpu.parallel import mesh as mesh_mod
+
+    class Chip:
+        platform = "tpu"
+
+    chip, cpus = Chip(), jax.devices("cpu")
+    monkeypatch.setattr(
+        mesh_mod.jax, "devices",
+        lambda backend=None: cpus if backend == "cpu" else [chip])
+    assert mesh_mod.best_devices() == [chip]
+    assert mesh_mod.best_devices(1) == [chip]
+    with pytest.raises(ValueError, match="need 4 tpu devices, have 1"):
+        mesh_mod.best_devices(4)
+
+
 def test_logical_sharding_rules():
     mesh = cpu_mesh(MeshSpec(data=2, tensor=4))
     rules = ShardingRules()

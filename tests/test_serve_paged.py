@@ -216,6 +216,21 @@ class TestCOWForkIsolation:
         assert (s["kv_blocks_active"] + s["kv_blocks_cached"]
                 + s["kv_blocks_free"]) == s["kv_blocks_total"]
 
+    def test_describe_names_what_was_resolved(self, paged):
+        """The non-numeric twin of ``stats``: the kernel as resolved (never
+        "auto"), the buckets warmup compiled, and where the state lives."""
+        d = paged.describe()
+        assert d["engine"] == "PagedLLMEngine"
+        assert d["attention_kernel"] in ("pallas", "interpret", "gather")
+        assert d["warmed_buckets"] == [16, 32]
+        assert d["pool_blocks"] == 129 and d["block_tokens"] == BT
+        assert d["params_devices"] and d["kv_pool_devices"]
+        cold = PagedLLMEngine(paged.params, paged.config,
+                              prompt_buckets=(16,), slots=1, max_queue=0,
+                              name="paged-cold", block_tokens=BT,
+                              pool_blocks=9)
+        assert cold.describe()["warmed_buckets"] == []
+
 
 class TestPagedMetrics:
     def test_kv_metrics_exported(self, paged):

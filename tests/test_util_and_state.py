@@ -4,6 +4,7 @@ modeled on the reference's ``python/ray/tests/test_actor_pool.py``,
 """
 
 import json
+import os
 import subprocess
 import sys
 
@@ -154,6 +155,117 @@ class TestAccelerators:
         assert _generation_from_type("v5litepod-16") == "V5E"
         assert _generation_from_type("v4-8") == "V4"
         assert _generation_from_type("v5p-128") == "V5P"
+
+
+    @staticmethod
+    def _stub_devices(monkeypatch, kind, n=1):
+        import jax
+
+        class Chip:
+            platform = "tpu"
+            device_kind = kind
+
+        monkeypatch.setattr(jax, "devices", lambda *a: [Chip()] * n)
+
+    @pytest.mark.parametrize("kind,marker", [
+        ("TPU v5 lite", "TPU-V5E"),   # what a v5e reports
+        ("TPU v6 lite", "TPU-V6E"),
+        ("TPU v4", "TPU-V4"),
+        ("TPU v5", "TPU-V5P"),
+        ("TPU v5p", "TPU-V5P"),
+    ])
+    def test_generation_from_device_kind(self, monkeypatch, kind, marker):
+        """The marker a live JAX client yields is the one the env path
+        yields for the same hardware (v5litepod-* -> TPU-V5E)."""
+        from ray_tpu.accelerators import tpu as acc
+
+        self._stub_devices(monkeypatch, kind, n=4)
+        res = acc.tpu_resources()
+        assert res["TPU"] == 4.0 and res[marker] == 4.0
+
+    def test_unknown_device_kind_gets_no_marker(self, monkeypatch):
+        import logging
+
+        from ray_tpu.accelerators import tpu as acc
+
+        seen = []
+        handler = logging.Handler()
+        handler.emit = lambda record: seen.append(record.getMessage())
+        log = logging.getLogger("ray_tpu.accelerators")
+        log.addHandler(handler)
+        try:
+            self._stub_devices(monkeypatch, "TPU v99 mega")
+            res = acc.tpu_resources()
+        finally:
+            log.removeHandler(handler)
+        assert res == {"TPU": 1.0}
+        assert any("unknown TPU device_kind 'TPU v99 mega'" in m
+                   for m in seen)
+
+    def test_failed_platform_probe_propagates(self, monkeypatch):
+        """A JAX that cannot bring up its platform must stop ``init`` —
+        not leave a node that quietly has no TPU resource."""
+        import jax
+
+        from ray_tpu.accelerators import tpu as acc
+
+        def boom(*a):
+            raise RuntimeError("Unable to initialize backend 'tpu'")
+
+        monkeypatch.setattr(jax, "devices", boom)
+        with pytest.raises(RuntimeError, match="backend 'tpu'"):
+            acc.detect_tpu()
+        with pytest.raises(RuntimeError, match="backend 'tpu'"):
+            ray_tpu.init(num_cpus=1)
+        ray_tpu.shutdown()
+
+
+class TestCompileCache:
+    """``enable_compile_cache``: the environment's directory wins untouched;
+    otherwise one fixed checkout path, the same from every process."""
+
+    ENV = "JAX_COMPILATION_CACHE_DIR"
+
+    def test_honours_the_variable(self, monkeypatch):
+        import jax
+
+        from ray_tpu.util.compile_cache import enable_compile_cache
+
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.setenv(self.ENV, "/placed/from/outside")
+        assert enable_compile_cache() == "/placed/from/outside"
+        assert os.environ[self.ENV] == "/placed/from/outside"
+        assert jax.config.jax_compilation_cache_dir == before  # set nothing
+
+    def test_fixed_checkout_path_in_every_process(self, monkeypatch):
+        import jax
+
+        from ray_tpu.util.compile_cache import enable_compile_cache
+
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        before = jax.config.jax_compilation_cache_dir
+        monkeypatch.delenv(self.ENV, raising=False)
+        try:
+            first = enable_compile_cache()
+            assert first == os.path.join(repo, ".jax_cache")
+            assert jax.config.jax_compilation_cache_dir == first
+            # Exported, so spawned workers inherit it ...
+            assert os.environ[self.ENV] == first
+            monkeypatch.delenv(self.ENV)
+            assert enable_compile_cache() == first
+            # ... and a process that inherits nothing, started elsewhere,
+            # still lands on the same directory (no cwd, pid or time in it).
+            env = {k: v for k, v in os.environ.items() if k != self.ENV}
+            env["PYTHONPATH"] = repo
+            child = subprocess.run(
+                [sys.executable, "-c",
+                 "from ray_tpu.util.compile_cache import enable_compile_cache"
+                 "; print(enable_compile_cache())"],
+                env=env, cwd="/", capture_output=True, text=True, timeout=60)
+            assert child.returncode == 0, child.stderr
+            assert child.stdout.strip() == first
+        finally:
+            jax.config.update("jax_compilation_cache_dir", before)
 
 
 class TestStateApi:
