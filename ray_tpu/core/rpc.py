@@ -1086,14 +1086,13 @@ class RpcClient:
                 return None
         except Exception:  # noqa: BLE001 — config unavailable mid-teardown
             return None
-        return (tracing.current_context(), time.monotonic())
+        return (tracing.current_context(), tracing.now_ns())
 
     def _trace_call_end(self, method: str, trace_start) -> None:
         from ray_tpu.util import tracing
 
         ctx, t0 = trace_start
-        tracing.emit(f"rpc.{method}", ctx,
-                     duration=time.monotonic() - t0,
+        tracing.emit(f"rpc.{method}", ctx, start=t0, end=tracing.now_ns(),
                      attrs={"addr": self.address})
 
     def release_dests(self, futs, wait_timeout: float = 30.0) -> None:

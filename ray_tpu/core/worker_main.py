@@ -907,12 +907,14 @@ def main() -> int:
     def _flush_tails():
         from ray_tpu.util import tracing
 
+        # Spans first: flush() hands the pending ones to the event buffer
+        # (this process's span sink), which the second flush then ships.
         try:
-            service._events.flush()
+            tracing.flush(core)
         except Exception:  # noqa: BLE001 — flush-on-death is best-effort
             pass
         try:
-            tracing.flush(core)
+            service._events.flush()
         except Exception:  # noqa: BLE001
             pass
         flightrec.close()

@@ -108,7 +108,7 @@ def actor_dag_loop(instance, method_name: str, in_channels: List[Any],
             else:
                 args = [values[payload] if kind == "c" else payload
                         for kind, payload in arg_template]
-                t0 = time.monotonic()
+                t0 = time.perf_counter_ns()
                 try:
                     result = method(*args)
                 except Exception as exc:  # noqa: BLE001 — deliver to caller
@@ -118,7 +118,7 @@ def actor_dag_loop(instance, method_name: str, in_channels: List[Any],
 
                     tracing.emit(
                         f"dag.stage:{method_name}", trace.ctx,
-                        duration=time.monotonic() - t0,
+                        start=t0, end=time.perf_counter_ns(),
                         parent_span_id=trace.tick_span,
                         attrs={"method": method_name})
             if trace is not None:
@@ -422,13 +422,18 @@ class CompiledDAG:
             from ray_tpu.core.metrics_export import (dag_tick_hist,
                                                      metrics_enabled)
 
+            elapsed = time.monotonic() - start
             if metrics_enabled():
-                dag_tick_hist().observe(time.monotonic() - start)
+                dag_tick_hist().observe(elapsed)
             if trace is not None:
                 from ray_tpu.util import tracing
 
+                # The tick ends here, where its output was fetched; its
+                # start (stamped on the histogram's clock at execute()) is
+                # carried back onto the span clock.
+                end = tracing.now_ns()
                 tracing.emit("dag.tick", trace[0], span_id=trace[1],
-                             duration=time.monotonic() - start,
+                             start=end - int(elapsed * 1e9), end=end,
                              attrs={"index": index})
         return result
 

@@ -433,8 +433,9 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
         if c.pos == "rope":
             q = rope(q, positions)
             k = rope(k, positions)
-        k_pool = k_pool.at[layer, blk, off].set(k)
-        v_pool = v_pool.at[layer, blk, off].set(v)
+        with jax.named_scope("kv_pool_write"):
+            k_pool = k_pool.at[layer, blk, off].set(k)
+            v_pool = v_pool.at[layer, blk, off].set(v)
         o = _paged_attend(q, k_pool[layer], v_pool[layer], tables, lengths,
                           scale=scale, kernel=kernel)
         o = jnp.einsum("bthk,hkd->btd", o, bp["wo"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bo"]
@@ -562,15 +563,20 @@ class PagedGenerator:
 
             def step(carry, _):
                 k_p, v_p, lens, last, keys = carry
-                real = last[:, : c.vocab_size]
-                split = jax.vmap(jax.random.split)(keys)   # [S, 2, 2]
-                keys2, subs = split[:, 0], split[:, 1]
-                samp = jax.vmap(jax.random.categorical)(subs, real / temp_safe)
-                nxt = jnp.where(greedy, jnp.argmax(real, axis=-1),
-                                samp).astype(jnp.int32)
-                logits, k_p, v_p = _forward_decode_paged(
-                    params, nxt[:, None], k_p, v_p, tables, lens, c, bt,
-                    kernel=kernel)
+                # Scope names are what a profiler's op metadata carries:
+                # stable across refactors of the code inside them.
+                with jax.named_scope("sample"):
+                    real = last[:, : c.vocab_size]
+                    split = jax.vmap(jax.random.split)(keys)   # [S, 2, 2]
+                    keys2, subs = split[:, 0], split[:, 1]
+                    samp = jax.vmap(jax.random.categorical)(
+                        subs, real / temp_safe)
+                    nxt = jnp.where(greedy, jnp.argmax(real, axis=-1),
+                                    samp).astype(jnp.int32)
+                with jax.named_scope("decode_step"):
+                    logits, k_p, v_p = _forward_decode_paged(
+                        params, nxt[:, None], k_p, v_p, tables, lens, c, bt,
+                        kernel=kernel)
                 lens = lens + adv
                 last = jnp.where(act_col, logits[:, -1], last)
                 keys = jnp.where(act_col, keys2, keys)
@@ -908,6 +914,8 @@ class KVBlockManager:
         self.hit_tokens = 0
         self.miss_tokens = 0
         self.cow_copies = 0
+        # CACHED blocks dropped to serve an alloc (``kv.alloc``'s span attr)
+        self.evicted_blocks = 0
 
     # -- allocation -----------------------------------------------------------
     def alloc(self, n: int) -> List[int]:
@@ -926,6 +934,7 @@ class KVBlockManager:
                 else:
                     b = next(iter(self._cached))   # LRU head
                     self._drop_cached_locked(b)
+                    self.evicted_blocks += 1
                 self._ref[b] = 1
                 out.append(b)
             return out

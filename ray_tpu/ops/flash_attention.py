@@ -122,6 +122,7 @@ def _flash_forward(
     )
     return pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=(bh, q_blocks, kv_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -284,6 +285,7 @@ def _flash_backward(
             _dq_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_blocks=kv_blocks,
         ),
+        name="flash_bwd_dq",
         grid=(bh, q_blocks, kv_blocks),
         in_specs=[q_spec, kv_spec_for_dq, kv_spec_for_dq, q_spec,
                   row_spec, row_spec],
@@ -302,6 +304,7 @@ def _flash_backward(
             _dkv_kernel, scale=scale, causal=causal,
             block_q=block_q, block_k=block_k, q_blocks=q_blocks,
         ),
+        name="flash_bwd_dkv",
         grid=(bh, kv_blocks, q_blocks),
         in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t,
                   row_spec_t, row_spec_t],

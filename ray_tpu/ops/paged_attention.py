@@ -191,6 +191,8 @@ def paged_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype),
         interpret=interpret,
+        # The name a profiler prints for the kernel, whatever calls it.
+        name="paged_decode_attn" if T == 1 else "paged_prefill_attn",
     )(tables, lengths, qt, k_pool, v_pool)
     return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
 

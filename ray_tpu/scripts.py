@@ -206,14 +206,10 @@ def format_trace_tree(events) -> str:
 
     # TTFT decomposition: admission wait + prefill + first decode chunk.
     parts = []
-    for name in ("llm.admission_wait", "llm.prefill"):
+    for name in ("llm.admission_wait", "llm.prefill", "llm.first_chunk"):
         found = [e for e in events if e.get("name") == name]
         if found:
             parts.append((name, min(found, key=start)["duration"]))
-    decodes = [e for e in events if e.get("name") == "llm.decode_chunk"]
-    if decodes:
-        parts.append(("llm.decode_chunk[0]",
-                      min(decodes, key=start)["duration"]))
     if parts:
         lines.append("")
         lines.append("TTFT breakdown:")

@@ -95,7 +95,7 @@ def _emit_request_span(trace, replica_key: str) -> None:
         return
     from ray_tpu.util import tracing
 
-    parent_ctx, req_ctx, submit_t = trace
+    parent_ctx, req_ctx, submit_ns = trace
     if req_ctx is None or not req_ctx[2]:
         return
     # The span's own id was pre-allocated as req_ctx's span (children
@@ -103,8 +103,7 @@ def _emit_request_span(trace, replica_key: str) -> None:
     tracing.emit(
         "serve.request",
         (req_ctx[0], parent_ctx[1] if parent_ctx else None, req_ctx[2]),
-        span_id=req_ctx[1],
-        duration=max(0.0, time.time() - submit_t),
+        span_id=req_ctx[1], start=submit_ns, end=tracing.now_ns(),
         attrs={"replica": replica_key})
 
 
@@ -126,9 +125,20 @@ class DeploymentResponseGenerator:
         return None
 
     def __iter__(self):
+        first = self.trace_id is not None
         try:
             for ref in self._gen:
-                yield ray_tpu.get(ref)
+                item = ray_tpu.get(ref)
+                if first:
+                    # An instant: the first stream item leaves the handle
+                    # for its caller — where the client's TTFT clock stops.
+                    first = False
+                    from ray_tpu.util import tracing
+
+                    now = tracing.now_ns()
+                    tracing.emit("serve.first_item", self._trace[1],
+                                 start=now, end=now)
+                yield item
         finally:
             if not self._done:
                 self._done = True
@@ -444,7 +454,8 @@ class DeploymentHandle:
             return None, None
         return parent, tracing.child_context(root, tracing.new_span_id())
 
-    def _emit_pick_span(self, req_ctx, key: str, elapsed_s: float) -> None:
+    def _emit_pick_span(self, req_ctx, key: str, start_ns: int,
+                        end_ns: int) -> None:
         """Router-pick span: the chosen replica plus the occupancy snapshot
         the choice was made on (ongoing count, reported KV-slot load)."""
         from ray_tpu.util import tracing
@@ -458,8 +469,8 @@ class DeploymentHandle:
             for stat in ("slots_busy", "slots_total", "queue_depth"):
                 if stat in load:
                     attrs[stat] = load[stat]
-        tracing.emit("serve.router_pick", req_ctx, duration=elapsed_s,
-                     attrs=attrs)
+        tracing.emit("serve.router_pick", req_ctx, start=start_ns,
+                     end=end_ns, attrs=attrs)
 
     @staticmethod
     def _affinity_hash(args) -> Optional[bytes]:
@@ -507,8 +518,7 @@ class DeploymentHandle:
         model_id = getattr(self, "_model_id", "")
         parent_ctx, req_ctx = self._trace_root()
         sampled = req_ctx is not None and req_ctx[2]
-        submit_t = time.time()
-        t0 = time.monotonic()
+        submit_ns = tracing.now_ns()
         prefix_hash = self._affinity_hash(args)
         # Tenant quota gate sits in FRONT of the router: an over-quota
         # tenant sheds here without consuming any replica queue slot.
@@ -527,8 +537,11 @@ class DeploymentHandle:
                 f"admit -> {key[:12]}"
                 + (f" trace={req_ctx[0]}" if req_ctx is not None else ""))
             if sampled:
-                self._emit_pick_span(req_ctx, key, time.monotonic() - t0)
-                kwargs["_trace_submit_ts"] = time.time()
+                picked_ns = tracing.now_ns()
+                self._emit_pick_span(req_ctx, key, submit_ns, picked_ns)
+                # Wall time, derived from the span clock: the replica may
+                # live in another process and puts it on ITS span clock.
+                kwargs["_trace_submit_ts"] = tracing.wall_of(picked_ns)
             if model_id:
                 kwargs["_multiplexed_model_id"] = model_id
             if self._stream:
@@ -537,7 +550,7 @@ class DeploymentHandle:
                 ).remote(self._method, *args, **kwargs)
                 return DeploymentResponseGenerator(
                     gen, self._router, key,
-                    trace=(parent_ctx, req_ctx, submit_t),
+                    trace=(parent_ctx, req_ctx, submit_ns),
                     release=release)
             ref = replica.handle_request.remote(self._method, *args, **kwargs)
 
@@ -548,7 +561,7 @@ class DeploymentHandle:
 
             return DeploymentResponse(ref, self._router, key,
                                       resubmit=resubmit,
-                                      trace=(parent_ctx, req_ctx, submit_t),
+                                      trace=(parent_ctx, req_ctx, submit_ns),
                                       release=release)
         except BaseException:
             # Pick/submit failed (saturated shed, timeout): the admission

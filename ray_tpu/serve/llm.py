@@ -32,6 +32,7 @@ overwrites pad garbage before the causal mask could ever expose it.
 from __future__ import annotations
 
 import collections
+import contextlib
 import threading
 import time
 import weakref
@@ -104,6 +105,7 @@ class _Request:
         "error", "finish_reason", "decode_tokens", "decode_seconds",
         "submitted_at", "ttft_s", "trace_ctx", "queued_s", "prefill_s",
         "out_ids", "blocks", "hit_tokens", "preloaded",
+        "submitted_ns", "prefill_end_ns", "prefill_span",
     )
 
     def __init__(self, prompt, padded, real_len, bucket, max_new,
@@ -125,12 +127,19 @@ class _Request:
         self.finish_reason: Optional[str] = None
         self.decode_tokens = 0
         self.decode_seconds = 0.0
-        self.submitted_at = time.perf_counter()
+        # One reading, on the span clock: ``ttft_s``, the TTFT histogram
+        # phases and the request's spans all count from this instant.
+        self.submitted_ns = tracing.now_ns()
+        self.submitted_at = self.submitted_ns / 1e9
         self.ttft_s: Optional[float] = None
         # TTFT decomposition (metrics phase labels): submit→admission and
         # the prefill dispatch, stamped by the scheduler.
         self.queued_s = 0.0
         self.prefill_s = 0.0
+        self.prefill_end_ns = 0
+        # Id of the request's ``llm.prefill`` span, allocated at admission
+        # so that ``kv.alloc`` can parent to it before it is recorded.
+        self.prefill_span: Optional[str] = None
         # Every delivered token id, in order — the paged engine registers
         # the finished prompt+output chain in the prefix cache at retire.
         self.out_ids: List[int] = []
@@ -154,11 +163,157 @@ class _Request:
         return self.decode_tokens / self.decode_seconds
 
 
+# What JAX reports, per program it builds, through ``jax.monitoring``: seconds
+# spent tracing to a jaxpr, lowering to a module, and in the backend compiler
+# or reading the persistent cache instead.
+_COMPILE_DURATIONS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace_s",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+    "/jax/core/compile/backend_compile_duration": "backend_s",
+}
+_COMPILE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": "cache_hits",
+    "/jax/compilation_cache/cache_misses": "cache_misses",
+}
+_compile_tls = threading.local()    # .sink: the open program's tallies
+_compile_listeners_lock = threading.Lock()
+_compile_listeners_on = False
+
+
+def _install_compile_listeners() -> None:
+    """Once per process, at the first traced warm-up. JAX compiles on the
+    calling thread, so a thread-local sink attributes each event to the
+    ``llm.warmup.program`` span open on that thread."""
+    global _compile_listeners_on
+    with _compile_listeners_lock:
+        if _compile_listeners_on:
+            return
+        _compile_listeners_on = True
+    import jax.monitoring as mon
+
+    def on_duration(name: str, duration: float, **kw) -> None:
+        sink = getattr(_compile_tls, "sink", None)
+        key = _COMPILE_DURATIONS.get(name)
+        if sink is not None and key is not None:
+            # An event is reported as it ends. Kept as an interval: a nested
+            # jit's tracing lies inside its caller's and must count once.
+            end = time.perf_counter()
+            sink[key].append((end - duration, end))
+
+    def on_event(name: str, **kw) -> None:
+        sink = getattr(_compile_tls, "sink", None)
+        key = _COMPILE_EVENTS.get(name)
+        if sink is not None and key is not None:
+            sink[key] += 1
+
+    mon.register_event_duration_secs_listener(on_duration)
+    mon.register_event_listener(on_event)
+
+
+def _union_s(intervals: List[tuple]) -> float:
+    """Seconds covered by at least one of the ``(start, end)`` intervals."""
+    total, reach = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        if b > reach:
+            total += b - max(a, reach)
+            reach = b
+    return total
+
+
+class _WarmupTrace:
+    """``llm.warmup`` around an engine's warm-up, with one
+    ``llm.warmup.program`` child per program it builds: why a replica takes
+    the time it takes to come up."""
+
+    def __init__(self, engine: "LLMEngine"):
+        self.on = tracing.trace_enabled()
+        self.ctx = (engine.trace_id, tracing.new_span_id(), True)
+        self.engine = engine.name
+        self.programs = 0
+        self.start = tracing.now_ns()
+        if self.on:
+            _install_compile_listeners()
+
+    @contextlib.contextmanager
+    def program(self, program: str, bucket: Optional[int] = None):
+        if not self.on:
+            yield
+            return
+        sink = {"trace_s": [], "lower_s": [], "backend_s": [],
+                "cache_hits": 0, "cache_misses": 0}
+        _compile_tls.sink = sink
+        start = tracing.now_ns()
+        try:
+            yield
+        finally:
+            _compile_tls.sink = None
+            self.programs += 1
+            attrs = {k: _union_s(v) if isinstance(v, list) else v
+                     for k, v in sink.items()}
+            attrs["program"] = program
+            if bucket is not None:
+                attrs["bucket"] = bucket
+            tracing.emit("llm.warmup.program", self.ctx, start=start,
+                         end=tracing.now_ns(), attrs=attrs)
+
+    def close(self) -> None:
+        if self.on:
+            tracing.emit("llm.warmup", (self.ctx[0], None, True),
+                         span_id=self.ctx[1], start=self.start,
+                         end=tracing.now_ns(),
+                         attrs={"engine": self.engine,
+                                "programs": self.programs})
+
+
+class _StepTrace:
+    """One engine step's clock. A ``perf_counter_ns`` stamp at every phase
+    boundary, always: the ``stats()`` counters are sums of them. With
+    tracing on the same intervals are open ``jax.profiler.TraceAnnotation``s
+    while they run (an operator's profiler session shows them above the
+    device lines) and become the ``llm.step`` span tree when the step ends.
+
+    Phases tile the step: each ends where the next starts."""
+
+    __slots__ = ("on", "marks", "end_ns", "attrs", "_open")
+
+    def __init__(self, on: bool):
+        self.on = on
+        self.marks: List[tuple] = []    # (phase, start_ns), in order
+        self.end_ns = 0
+        self.attrs: Dict = {}
+        self._open: List = []           # the step's annotation, the phase's
+        if on:
+            self._push("llm.step")
+
+    def _push(self, name: str) -> None:
+        ann = tracing.annotation(name)
+        ann.__enter__()
+        self._open.append(ann)
+
+    def enter(self, phase: str) -> None:
+        if len(self._open) > 1:
+            self._open.pop().__exit__(None, None, None)
+        self.marks.append((phase, tracing.now_ns()))
+        if self.on:
+            self._push("llm.step." + phase)
+
+    def close(self) -> None:
+        self.end_ns = tracing.now_ns()
+        while self._open:
+            self._open.pop().__exit__(None, None, None)
+
+    def phases(self) -> List[tuple]:
+        """(phase, start_ns, end_ns) of every phase entered."""
+        ends = [m[1] for m in self.marks[1:]] + [self.end_ns]
+        return [(name, start, end)
+                for (name, start), end in zip(self.marks, ends)]
+
+
 class LLMEngine:
     """Continuous-batching engine: S cache slots, caller-driven stepping.
 
-    The single-sequence surface (``stream``/``generate``/``warmup``/
-    ``device_metrics``) is unchanged; concurrency comes from calling
+    The single-sequence surface (``stream``/``generate``/``warmup``) is
+    unchanged; concurrency comes from calling
     ``stream`` from many threads — their sequences SHARE the batched decode
     dispatches instead of queueing behind each other.
     """
@@ -210,6 +365,22 @@ class LLMEngine:
         # device->host reads (enforced when jitcheck is installed).
         self._steady = False
 
+        # The engine's own trace: ``llm.warmup`` and every ``llm.step`` are
+        # roots of it (steps belong to no request).
+        self.trace_id = tracing.new_span_id()
+        # Cumulative counts at the step's boundaries (under _agg_lock;
+        # written by the step thread, read by stats()). The ``_s`` ones are
+        # kept in ns here and given in seconds by stats().
+        # Each has a reader under benchmark/metrics: admit_budget_stop_share
+        # (budget stops over steps), pool_blocked_share, step_host_share.
+        self._counts = {"steps_total": 0, "admit_stopped_budget_total": 0,
+                        "admit_blocked_pool_s": 0, "step_host_s": 0,
+                        "step_device_wait_s": 0}
+        # Start of a step whose admission stopped on NoFreeBlocks with a
+        # slot free and a request waiting; charged to admit_blocked_pool_s
+        # when the next step starts. Step-thread-owned.
+        self._blocked_since_ns: Optional[int] = None
+
     # -- device-half hooks (the paged engine overrides these) -----------------
     # The scheduler above them — admission budget, slot bookkeeping, token
     # distribution, the streaming contract — is engine-agnostic; everything
@@ -256,9 +427,9 @@ class LLMEngine:
         on per-step acceptance. Called under _state_lock."""
         return [int(t) for t in host_toks[slot][:self.chunk]], self.chunk
 
-    def _chunk_span_attrs(self, slot: int) -> Optional[Dict]:
-        """Extra attrs merged into a sampled request's ``llm.decode_chunk``
-        span (the spec engine reports proposed/accepted counts)."""
+    def _step_spec_attrs(self) -> Optional[Dict]:
+        """Extra attrs for the step's ``llm.step`` span (the speculative
+        engine reports the step's proposed/accepted counts)."""
         return None
 
     def _release_slot_device(self, slot: int) -> None:
@@ -280,19 +451,23 @@ class LLMEngine:
         """Compile prefill for every bucket + the decode chunk, then reset —
         TTFT never pays XLA compilation. One program per bucket and one per
         chunk size: greedy vs sampled is an operand, not a recompile."""
+        wt = _WarmupTrace(self)
         with self._step_lock:
             for b in self.buckets:
-                pf = self._sg.prefill_fn(b)
-                self._cache, self._last, self._keys = pf(
+                with wt.program("prefill", b):
+                    pf = self._sg.prefill_fn(b)
+                    self._cache, self._last, self._keys = pf(
+                        self.params, self._cache, self._last, self._keys,
+                        np.zeros((1, b), np.int32), b, 0, 0)
+            with wt.program("decode"):
+                df = self._sg.decode_fn(self.chunk)
+                toks, self._cache, self._last, self._keys = df(
                     self.params, self._cache, self._last, self._keys,
-                    np.zeros((1, b), np.int32), b, 0, 0)
-            df = self._sg.decode_fn(self.chunk)
-            toks, self._cache, self._last, self._keys = df(
-                self.params, self._cache, self._last, self._keys,
-                np.zeros(self.slots, bool), self._greedy, self._temps)
-            np.asarray(toks)
+                    np.zeros(self.slots, bool), self._greedy, self._temps)
+                np.asarray(toks)
             self._cache, self._last, self._keys = self._sg.init_state()
             self._steady = True
+        wt.close()
 
     def _bucket_for(self, n: int) -> int:
         # One full decode chunk must fit after the prompt: decode always
@@ -429,14 +604,28 @@ class LLMEngine:
                 self._waiting.remove(req)
             except ValueError:
                 pass
-            if req.slot is not None:
-                self._free_slot_locked(req.slot)
+            slot = req.slot
+            if slot is not None:
+                self._free_slot_locked(slot)
             else:
                 self._discard_request_locked(req)
             req.done = True
             if req.finish_reason is None:
                 req.finish_reason = "cancelled"
+            self._emit_request_span(req, slot)
             req.cond.notify_all()
+
+    def _emit_request_span(self, req: _Request, slot: Optional[int]) -> None:
+        """``llm.request``: submit to finish, whatever the finish was. A ring
+        append: safe under _state_lock."""
+        if req.trace_ctx is None:
+            return
+        tracing.emit("llm.request", req.trace_ctx,
+                     start=req.submitted_ns, end=tracing.now_ns(),
+                     attrs={"prompt_len": req.real_len,
+                            "hit_tokens": req.hit_tokens,
+                            "tokens": req.emitted, "slot": slot,
+                            "finish_reason": req.finish_reason})
 
     def _free_slot_locked(self, slot: int) -> None:
         self._release_slot_device(slot)
@@ -450,9 +639,11 @@ class LLMEngine:
     def _finish_locked(self, req: _Request, reason: str) -> None:
         req.finish_reason = reason
         req.done = True
-        if req.slot is not None:
+        slot = req.slot
+        if slot is not None:
             self._on_retire_locked(req)
-            self._free_slot_locked(req.slot)
+            self._free_slot_locked(slot)
+        self._emit_request_span(req, slot)
         req.cond.notify_all()
 
     def _fail_inflight(self, err: BaseException) -> None:
@@ -471,6 +662,7 @@ class LLMEngine:
                 r.done = True
                 if r.finish_reason is None:
                     r.finish_reason = "error"
+                self._emit_request_span(r, None)
                 r.cond.notify_all()
         self._reset_device_state()
 
@@ -480,16 +672,30 @@ class LLMEngine:
         # step runs under the steady-state contract: any new XLA compile or
         # implicit device->host read is a violation (recorded when jitcheck
         # is installed; steady_state() is a no-op otherwise).
+        st = _StepTrace(tracing.trace_enabled())
+        st.enter("retire")
+        if self._blocked_since_ns is not None:
+            # The step before stopped admitting on an exhausted pool: the
+            # whole stretch from its start to this one's is time a free slot
+            # stood empty for want of blocks.
+            with self._agg_lock:
+                self._counts["admit_blocked_pool_s"] += (
+                    st.marks[0][1] - self._blocked_since_ns)
+            self._blocked_since_ns = None
         try:
-            if self._steady:
-                with jitcheck.steady_state():
-                    self._step_inner()
-            else:
-                self._step_inner()
-        except BaseException as err:
-            self._fail_inflight(err)
-            raise
-        self._post_step()
+            try:
+                if self._steady:
+                    with jitcheck.steady_state():
+                        self._step_inner(st)
+                else:
+                    self._step_inner(st)
+            except BaseException as err:
+                self._fail_inflight(err)
+                raise
+            self._post_step()
+        finally:
+            st.close()      # whatever happened, no annotation stays open
+        self._record_step(st)
 
     def _post_step(self) -> None:
         """Post-iteration hook, still under _step_lock (the paged engine
@@ -497,7 +703,38 @@ class LLMEngine:
         the one that retires the last request, so spill pins never strand
         on an idle engine)."""
 
-    def _step_inner(self) -> None:
+    def _record_step(self, st: _StepTrace) -> None:
+        """Fold one finished step into the counters and, traced, record it
+        as ``llm.step`` with one child per phase. The gap between one
+        ``llm.step``'s end and the next one's start (the driver yielding its
+        own tokens, the hand-off to another driver) is read off consecutive
+        spans."""
+        phases = st.phases()
+        start = phases[0][1]
+        wait = sum(e - b for name, b, e in phases if name == "device_wait")
+        a = st.attrs
+        with self._agg_lock:
+            c = self._counts
+            c["steps_total"] += 1 if a.get("batch") else 0
+            c["admit_stopped_budget_total"] += a["admit_stopped"] == "budget"
+            c["step_device_wait_s"] += wait
+            c["step_host_s"] += st.end_ns - start - wait
+        if not st.on:
+            return
+        ctx = (self.trace_id, None, True)
+        a["engine"] = self.name
+        a["driver"] = threading.current_thread().name
+        # Ring-only: eight spans a step whatever ``trace_sample_rate`` says
+        # would crowd the requests an operator did sample out of the GCS's
+        # task-event ring. ``tracing.recorded()`` and a profiler session
+        # (the annotations) are where a step is read.
+        sid = tracing.emit("llm.step", ctx, start=start, end=st.end_ns,
+                           attrs=a, export=False)
+        for name, b, e in phases:
+            tracing.emit("llm.step." + name, ctx, start=b, end=e,
+                         parent_span_id=sid, export=False)
+
+    def _step_inner(self, st: _StepTrace) -> None:
         # 1. Retire: a slot whose next chunk would cross max_len ends as
         #    length_cap BEFORE dispatch (no partial chunks — shapes stay
         #    static), and cancelled slots free immediately.
@@ -514,17 +751,24 @@ class LLMEngine:
         # 2. Admit queued prompts into free slots under the prefill budget.
         #    The FIRST admission always goes through — the budget bounds how
         #    much prefill work piles into one step, never progress.
+        st.enter("admit")
         admitted_tokens = 0
+        admitted = 0
         while True:
             with self._state_lock:
                 free = next((s for s in range(self.slots)
                              if self._slot_req[s] is None), None)
-                if free is None or not self._waiting:
+                if not self._waiting:
+                    stopped = "queue_empty"
+                    break
+                if free is None:
+                    stopped = "no_slot"
                     break
                 nxt = self._waiting[0]
                 cost = self._admission_cost(nxt)
                 if admitted_tokens and (
                         admitted_tokens + cost > self.prefill_budget):
+                    stopped = "budget"
                     break
                 self._waiting.popleft()
                 if nxt.cancelled:
@@ -535,7 +779,9 @@ class LLMEngine:
                 self._active[free] = True
                 self._greedy[free] = nxt.temperature <= 0
                 self._temps[free] = nxt.temperature if nxt.temperature > 0 else 0.0
-            t_admit = time.perf_counter()
+            t_admit = tracing.now_ns()
+            if nxt.trace_ctx is not None:
+                nxt.prefill_span = tracing.new_span_id()
             try:
                 self._dispatch_prefill(nxt, free)
             except NoFreeBlocks:
@@ -548,42 +794,59 @@ class LLMEngine:
                     self._free_slot_locked(free)
                     if not nxt.cancelled:
                         self._waiting.appendleft(nxt)
+                stopped = "no_blocks"
+                self._blocked_since_ns = st.marks[0][1]
                 break
-            nxt.queued_s = t_admit - nxt.submitted_at
-            nxt.prefill_s = time.perf_counter() - t_admit
+            nxt.prefill_end_ns = tracing.now_ns()
+            nxt.queued_s = (t_admit - nxt.submitted_ns) / 1e9
+            nxt.prefill_s = (nxt.prefill_end_ns - t_admit) / 1e9
             if nxt.trace_ctx is not None:
                 tracing.emit(
                     "llm.admission_wait", nxt.trace_ctx,
-                    duration=nxt.queued_s,
+                    start=nxt.submitted_ns, end=t_admit,
                     attrs={"slot": free, "engine": self.name})
                 tracing.emit(
-                    "llm.prefill", nxt.trace_ctx,
-                    duration=nxt.prefill_s,
+                    "llm.prefill", nxt.trace_ctx, span_id=nxt.prefill_span,
+                    start=t_admit, end=nxt.prefill_end_ns,
                     attrs={"slot": free, "bucket": nxt.bucket,
                            "prompt_len": nxt.real_len,
                            "hit_tokens": nxt.hit_tokens})
             admitted_tokens += cost
+            admitted += 1
+        st.attrs.update(admitted=admitted, admit_stopped=stopped)
 
+        st.enter("operands")
         with self._state_lock:
             if not any(r is not None for r in self._slot_req):
+                # Nothing in flight (and so nothing pins a block): the pool
+                # cannot be what holds an admission back.
+                self._blocked_since_ns = None
+                st.enter("observe")
                 return
             active = self._active.copy()
             greedy = self._greedy.copy()
             temps = self._temps.copy()
             extra = self._decode_operands_locked()
 
-        # 3. One batched decode chunk advancing every active slot.
-        t0 = time.perf_counter()
+        # 3. One batched decode chunk advancing every active slot: the
+        #    jitted call returns (dispatch), then the step's single device
+        #    sync (device_wait). The speculative program syncs inside its
+        #    dispatch to read acceptance counts, so there the wait is short.
+        st.enter("dispatch")
+        t0_ns = st.marks[-1][1]
         toks = self._run_decode(active, greedy, temps, extra)
-        host_toks = jax.device_get(toks)  # the step's single device sync
-        dt = time.perf_counter() - t0
-        now = time.perf_counter()
+        st.enter("device_wait")
+        host_toks = jax.device_get(toks)
+        st.enter("deliver")
+        now_ns = st.marks[-1][1]
+        dt = (now_ns - t0_ns) / 1e9
+        now = now_ns / 1e9
 
         # 4. Distribute each slot's tokens to its request.
         delivered_total = 0
         ttfts: List[tuple] = []  # (total, queued, prefill) per first token
         batch_size = int(active.sum())
-        chunk_spans: List[tuple] = []  # sampled requests' (ctx, slot, ntok)
+        firsts: List[tuple] = []  # sampled requests' (req, slot, ntok)
         with self._state_lock:
             for slot in range(self.slots):
                 req = self._slot_req[slot]
@@ -598,8 +861,8 @@ class LLMEngine:
                 if upto > 0 and req.ttft_s is None:
                     req.ttft_s = now - req.submitted_at
                     ttfts.append((req.ttft_s, req.queued_s, req.prefill_s))
-                if req.trace_ctx is not None and upto > 0:
-                    chunk_spans.append((req.trace_ctx, slot, upto))
+                    if req.trace_ctx is not None:
+                        firsts.append((req, slot, upto))
                 new_toks = emitted[:upto]
                 req.tokens.extend(new_toks)
                 req.out_ids.extend(new_toks)
@@ -611,22 +874,29 @@ class LLMEngine:
                     self._finish_locked(req, "stop")
                 else:
                     req.cond.notify_all()
+            # What the engine still holds once this step's tokens are out:
+            # with nothing in flight, the wait for the next step is the
+            # traffic's, not the host's.
+            inflight = (sum(r is not None for r in self._slot_req)
+                        + len(self._waiting))
         with self._agg_lock:
             self.decode_tokens += delivered_total
             self.decode_seconds += dt
-        # Emitted OUTSIDE _state_lock: span export may take its own locks.
-        for ctx, slot, ntok in chunk_spans:
-            attrs = {"slot": slot, "tokens": ntok, "batch": batch_size}
-            extra_attrs = self._chunk_span_attrs(slot)
-            if extra_attrs:
-                attrs.update(extra_attrs)
-                # Propose + verify run fused in the one spec dispatch, so
-                # the spec span's duration IS the step's device time; the
-                # attrs carry the per-slot proposed/accepted split.
-                tracing.emit("llm.spec", ctx, duration=dt, end_time=None,
-                             attrs={"slot": slot, **extra_attrs})
-            tracing.emit("llm.decode_chunk", ctx, duration=dt, end_time=None,
-                         attrs=attrs)
+        for req, slot, ntok in firsts:
+            # Prefill's end to the first tokens reaching the host: the
+            # instant ``ttft_s`` is stamped, so admission_wait + prefill +
+            # first_chunk IS the engine's TTFT. What follows (the hand-off
+            # to the consumer thread) is the step's deliver phase.
+            tracing.emit("llm.first_chunk", req.trace_ctx,
+                         start=req.prefill_end_ns, end=now_ns,
+                         attrs={"slot": slot, "tokens": ntok,
+                                "batch": batch_size})
+        st.attrs.update(batch=batch_size, tokens=delivered_total,
+                        inflight_after=inflight)
+        spec = self._step_spec_attrs()
+        if spec:
+            st.attrs.update(spec)
+        st.enter("observe")
         self._observe(delivered_total, ttfts)
 
     def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
@@ -656,8 +926,18 @@ class LLMEngine:
         with self._state_lock:
             busy = sum(1 for r in self._slot_req if r is not None)
             depth = len(self._waiting)
-        return {"slots_total": float(self.slots), "slots_busy": float(busy),
-                "queue_depth": float(depth)}
+        out = {"slots_total": float(self.slots), "slots_busy": float(busy),
+               "queue_depth": float(depth)}
+        # Cumulative counts at the step's boundaries: they count with
+        # tracing off too. ``steps_total`` counts steps that dispatched a
+        # decode; ``admit_blocked_pool_s`` is the time from the start of a
+        # step whose admission stopped on NoFreeBlocks (a slot free, a
+        # request waiting) to the start of the next; ``step_host_s`` +
+        # ``step_device_wait_s`` is all the time spent inside steps.
+        with self._agg_lock:
+            out.update({k: v / 1e9 if k.endswith("_s") else float(v)
+                        for k, v in self._counts.items()})
+        return out
 
     def describe(self) -> Dict:
         """What this engine resolved to at run time, for an operator or a
@@ -670,6 +950,7 @@ class LLMEngine:
             "chunk": self.chunk,
             "max_len": self.max_len,
             "warmed_buckets": list(self.buckets) if self._steady else [],
+            "trace_id": self.trace_id,
             "params_devices": sorted(
                 {str(d) for leaf in jax.tree.leaves(self.params)
                  for d in leaf.devices()}),
@@ -680,63 +961,6 @@ class LLMEngine:
             if self.decode_seconds == 0:
                 return 0.0
             return self.decode_tokens / self.decode_seconds
-
-    def device_metrics(self, *, prompt_len: int = 16, reps: int = 10) -> Dict:
-        """Device-side TTFT and decode rate, excluding host↔device RTT.
-
-        Runs on a throwaway slot state (serialized with serving via the step
-        lock): TTFT is prefill + first decode chunk; the decode rate chains
-        chunks with one final sync so async dispatch overlaps and the number
-        reflects pure device time. One slot active — the per-sequence rate
-        of the batched program.
-        """
-        import jax
-
-        bucket = self._bucket_for(prompt_len)
-        with self._step_lock:
-            pf = self._sg.prefill_fn(bucket)
-            df = self._sg.decode_fn(self.chunk)
-            padded = np.zeros((1, bucket), np.int32)
-            active = np.zeros(self.slots, bool)
-            active[0] = True
-            greedy = np.ones(self.slots, bool)
-            temps = np.zeros(self.slots, np.float32)
-
-            cache, last, keys = self._sg.init_state()
-            # Warm both programs before timing.
-            cache, last, keys = pf(self.params, cache, last, keys, padded,
-                                   prompt_len, 0, 0)
-            toks, cache, last, keys = df(self.params, cache, last, keys,
-                                         active, greedy, temps)
-            np.asarray(toks)
-
-            outs = []
-            t0 = time.perf_counter()
-            for i in range(reps):
-                cache, last, keys = pf(self.params, cache, last, keys,
-                                       padded, prompt_len, 0, i)
-                toks, cache, last, keys = df(self.params, cache, last, keys,
-                                             active, greedy, temps)
-                outs.append(toks)
-            jax.block_until_ready(outs)
-            ttft_ms = (time.perf_counter() - t0) / reps * 1e3
-
-            n_chunks = (self.max_len - prompt_len) // self.chunk - 1
-            if n_chunks < 1:
-                return {"device_ttft_ms": round(ttft_ms, 2),
-                        "device_decode_tokens_per_sec": 0.0}
-            cache, last, keys = pf(self.params, cache, last, keys, padded,
-                                   prompt_len, 0, 0)
-            t0 = time.perf_counter()
-            for _ in range(n_chunks):
-                toks, cache, last, keys = df(self.params, cache, last, keys,
-                                             active, greedy, temps)
-            jax.block_until_ready(toks)
-            dt = time.perf_counter() - t0
-        return {
-            "device_ttft_ms": round(ttft_ms, 2),
-            "device_decode_tokens_per_sec": round(n_chunks * self.chunk / dt, 1),
-        }
 
 
 class PagedLLMEngine(LLMEngine):
@@ -894,64 +1118,76 @@ class PagedLLMEngine(LLMEngine):
         self._init_spec_state()
 
     def warmup(self) -> None:
+        wt = _WarmupTrace(self)
         with self._step_lock:
             zero_row = np.zeros(self.blocks_per_seq, np.int32)  # all trash
             for b in self.buckets:
-                pf = self._pg.prefill_fn(b)
-                (self._k_pool, self._v_pool, self._last, self._keys) = pf(
+                with wt.program("paged_prefill", b):
+                    pf = self._pg.prefill_fn(b)
+                    (self._k_pool, self._v_pool,
+                     self._last, self._keys) = pf(
+                        self.params, self._k_pool, self._v_pool, self._last,
+                        self._keys, zero_row, np.zeros((1, b), np.int32),
+                        0, b, 0, 0)
+            with wt.program("paged_decode"):
+                df = self._pg.decode_fn(self.chunk)
+                (toks, self._k_pool, self._v_pool,
+                 self._last, self._keys) = df(
                     self.params, self._k_pool, self._v_pool, self._last,
-                    self._keys, zero_row, np.zeros((1, b), np.int32),
-                    0, b, 0, 0)
-            df = self._pg.decode_fn(self.chunk)
-            toks, self._k_pool, self._v_pool, self._last, self._keys = df(
-                self.params, self._k_pool, self._v_pool, self._last,
-                self._keys, np.zeros((self.slots, self.blocks_per_seq),
-                                     np.int32),
-                np.zeros(self.slots, np.int32), np.zeros(self.slots, bool),
-                self._greedy, self._temps)
-            np.asarray(toks)
-            cf = self._pg.copy_fn()
-            self._k_pool, self._v_pool = cf(self._k_pool, self._v_pool, 0, 0)
+                    self._keys, np.zeros((self.slots, self.blocks_per_seq),
+                                         np.int32),
+                    np.zeros(self.slots, np.int32),
+                    np.zeros(self.slots, bool), self._greedy, self._temps)
+                np.asarray(toks)
+            with wt.program("copy_block"):
+                cf = self._pg.copy_fn()
+                self._k_pool, self._v_pool = cf(self._k_pool, self._v_pool,
+                                                0, 0)
             # The handoff attach program (set_last) runs mid-step when a
             # prefilled request lands — compile it here, not on its TTFT.
-            sl = self._pg.set_last_fn()
-            self._last, self._keys = sl(
-                self._last, self._keys,
-                np.zeros(self._last.shape[1], np.float32), 0, 0)
+            with wt.program("set_last"):
+                sl = self._pg.set_last_fn()
+                self._last, self._keys = sl(
+                    self._last, self._keys,
+                    np.zeros(self._last.shape[1], np.float32), 0, 0)
             if self._tier is not None:
                 # Tier upload/download programs: compile HERE so a cold
                 # replica's first store fetch never pays XLA on its TTFT
                 # (block 0 is the padding block — inserting zeros is inert).
-                zb = np.zeros((self._k_pool.shape[0], 1)
-                              + tuple(self._k_pool.shape[2:]),
-                              self._k_pool.dtype)
-                self._tier_insert_blocks(zb, zb, [0])
-                self._tier_extract_blocks([0])
+                with wt.program("kv_tier_blocks"):
+                    zb = np.zeros((self._k_pool.shape[0], 1)
+                                  + tuple(self._k_pool.shape[2:]),
+                                  self._k_pool.dtype)
+                    self._tier_insert_blocks(zb, zb, [0])
+                    self._tier_extract_blocks([0])
             if self._spec:
                 for b in self.buckets:
-                    dpf = self._pg.draft_prefill_fn(b)
-                    self._kd_pool, self._vd_pool = dpf(
-                        self._draft_params, self._kd_pool, self._vd_pool,
-                        zero_row, np.zeros((1, b), np.int32), 0, b)
+                    with wt.program("draft_prefill", b):
+                        dpf = self._pg.draft_prefill_fn(b)
+                        self._kd_pool, self._vd_pool = dpf(
+                            self._draft_params, self._kd_pool, self._vd_pool,
+                            zero_row, np.zeros((1, b), np.int32), 0, b)
                 self._kd_pool, self._vd_pool = cf(self._kd_pool,
                                                   self._vd_pool, 0, 0)
-                sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
-                out = sf(self.params, self._draft_params, self._k_pool,
-                         self._v_pool, self._kd_pool, self._vd_pool,
-                         self._last, self._keys,
-                         np.zeros((self.slots, self.blocks_per_seq),
-                                  np.int32),
-                         np.zeros(self.slots, np.int32),
-                         np.zeros(self.slots, bool), self._greedy,
-                         self._temps, np.zeros(self.slots, bool),
-                         np.zeros(self.slots, np.int32),
-                         np.zeros(self.slots, np.int32),
-                         np.zeros(self.slots, bool))
-                np.asarray(out[0])
+                with wt.program("spec_decode"):
+                    sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
+                    out = sf(self.params, self._draft_params, self._k_pool,
+                             self._v_pool, self._kd_pool, self._vd_pool,
+                             self._last, self._keys,
+                             np.zeros((self.slots, self.blocks_per_seq),
+                                      np.int32),
+                             np.zeros(self.slots, np.int32),
+                             np.zeros(self.slots, bool), self._greedy,
+                             self._temps, np.zeros(self.slots, bool),
+                             np.zeros(self.slots, np.int32),
+                             np.zeros(self.slots, np.int32),
+                             np.zeros(self.slots, bool))
+                    np.asarray(out[0])
                 (self._k_pool, self._v_pool, self._kd_pool, self._vd_pool,
                  self._last, self._keys) = out[3:9]
             self._reset_device_state()
             self._steady = True
+        wt.close()
 
     def _suffix_bucket(self, n: int) -> int:
         # The suffix prefill's compile bucket — unlike _bucket_for it needs
@@ -973,6 +1209,8 @@ class PagedLLMEngine(LLMEngine):
         if req.preloaded is not None:
             self._attach_preloaded(req, slot)
             return
+        t_alloc = tracing.now_ns()
+        evicted0 = self.kv.evicted_blocks
         tokens = [int(t) for t in req.prompt]
         full, tail, hit_len = self.kv.lookup(tokens)
         digests: List[bytes] = []
@@ -1032,6 +1270,17 @@ class PagedLLMEngine(LLMEngine):
             self.kv.release([tail])  # pin the private copy, not the original
             ids.append(dst)
         ids.extend(fresh)
+        if req.trace_ctx is not None:
+            # The KV manager's share of the admission: hashing, lookup,
+            # allocation (with any eviction) and the copy-on-write dispatch.
+            tracing.emit(
+                "kv.alloc", req.trace_ctx, parent_span_id=req.prefill_span,
+                start=t_alloc, end=tracing.now_ns(),
+                attrs={"hit_tokens": hit_len,
+                       "miss_tokens": req.real_len - hit_len,
+                       "blocks_taken": len(ids) - len(full),
+                       "blocks_evicted": self.kv.evicted_blocks - evicted0,
+                       "cow": tail is not None})
         row = np.zeros(self.blocks_per_seq, np.int32)
         row[:len(ids)] = ids
         req.hit_tokens = hit_len
@@ -1281,12 +1530,13 @@ class PagedLLMEngine(LLMEngine):
             out.extend(int(x) for x in toks[t, :counts[t]])
         return out, int(counts.sum())
 
-    def _chunk_span_attrs(self, slot: int) -> Optional[Dict]:
-        if (not self._spec or self._last_counts is None
-                or not self._spec_last_on[slot]):
+    def _step_spec_attrs(self) -> Optional[Dict]:
+        if not self._spec or self._last_counts is None:
             return None
-        return {"spec_proposed": self.chunk * self.spec_k,
-                "spec_accepted": int(self._spec_last_accept[slot])}
+        on = self._spec_last_on
+        return {"spec_proposed": int(on.sum()) * self.chunk * self.spec_k,
+                "spec_accepted": int(self._spec_last_accept[on].sum()),
+                "spec_s": self._spec_last_dt}
 
     def _release_slot_device(self, slot: int) -> None:
         ids = self._slot_blocks[slot]
@@ -1418,6 +1668,7 @@ class PagedLLMEngine(LLMEngine):
         req.trace_ctx = trace_ctx
         if submitted_at is not None:
             req.submitted_at = submitted_at
+            req.submitted_ns = int(submitted_at * 1e9)
         if max_new_tokens <= 0:
             req.done = True
             req.finish_reason = "stop"
@@ -1742,64 +1993,6 @@ class PagedLLMEngine(LLMEngine):
         if self._tier is not None:
             self._tier.close()
 
-    def device_metrics(self, *, prompt_len: int = 16, reps: int = 10) -> Dict:
-        import jax
-
-        bucket = self._suffix_bucket(prompt_len)
-        bps = self.blocks_per_seq
-        with self._step_lock:
-            pf = self._pg.prefill_fn(bucket)
-            df = self._pg.decode_fn(self.chunk)
-            padded = np.zeros((1, bucket), np.int32)
-            row = np.arange(1, bps + 1, dtype=np.int32)
-            tables = np.zeros((self.slots, bps), np.int32)
-            tables[0] = row
-            lengths = np.zeros(self.slots, np.int32)
-            lengths[0] = prompt_len
-            active = np.zeros(self.slots, bool)
-            active[0] = True
-            greedy = np.ones(self.slots, bool)
-            temps = np.zeros(self.slots, np.float32)
-
-            kp, vp, last, keys = self._pg.init_state()  # throwaway pool
-            kp, vp, last, keys = pf(self.params, kp, vp, last, keys, row,
-                                    padded, 0, prompt_len, 0, 0)
-            toks, kp, vp, last, keys = df(self.params, kp, vp, last, keys,
-                                          tables, lengths, active, greedy,
-                                          temps)
-            np.asarray(toks)
-
-            outs = []
-            t0 = time.perf_counter()
-            for i in range(reps):
-                kp, vp, last, keys = pf(self.params, kp, vp, last, keys,
-                                        row, padded, 0, prompt_len, 0, i)
-                toks, kp, vp, last, keys = df(self.params, kp, vp, last,
-                                              keys, tables, lengths, active,
-                                              greedy, temps)
-                outs.append(toks)
-            jax.block_until_ready(outs)
-            ttft_ms = (time.perf_counter() - t0) / reps * 1e3
-
-            n_chunks = (self.max_len - prompt_len) // self.chunk - 1
-            if n_chunks < 1:
-                return {"device_ttft_ms": round(ttft_ms, 2),
-                        "device_decode_tokens_per_sec": 0.0}
-            kp, vp, last, keys = pf(self.params, kp, vp, last, keys, row,
-                                    padded, 0, prompt_len, 0, 0)
-            t0 = time.perf_counter()
-            for _ in range(n_chunks):
-                toks, kp, vp, last, keys = df(self.params, kp, vp, last,
-                                              keys, tables, lengths, active,
-                                              greedy, temps)
-            jax.block_until_ready(toks)
-            dt = time.perf_counter() - t0
-        return {
-            "device_ttft_ms": round(ttft_ms, 2),
-            "device_decode_tokens_per_sec": round(n_chunks * self.chunk / dt,
-                                                  1),
-        }
-
 
 class _DisaggTicket:
     """One request's place in the disaggregated pipeline: queued → prefill
@@ -2105,9 +2298,6 @@ class DisaggregatedLLMEngine:
 
     def decode_tokens_per_sec(self) -> float:
         return self.decode.decode_tokens_per_sec()
-
-    def device_metrics(self, **kw) -> Dict:
-        return self.decode.device_metrics(**kw)
 
     def close(self) -> None:
         """Stop the pipeline workers, poison-pill the lane, destroy it.

@@ -109,8 +109,12 @@ class ReplicaActor:
 
         ctx = tracing.current_context()
         if ctx is not None:
+            # The submit stamp is another process's wall clock: under skew
+            # it can land after now, and the span then has no length.
+            end = tracing.now_ns()
             tracing.emit("serve.replica_queue", ctx,
-                         duration=max(0.0, time.time() - submit_ts),
+                         start=min(tracing.ns_of_wall(submit_ts), end),
+                         end=end,
                          attrs={"deployment": self.deployment_name})
 
     def handle_request(self, method_name: str, *args, **kwargs):

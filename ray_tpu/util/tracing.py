@@ -15,14 +15,22 @@ check (the ``metrics_export_enabled`` pattern). With tracing on, head-based
 sampling (``trace_sample_rate``) is decided ONCE where the trace root is
 stamped and the decision is carried in the context — children of an
 unsampled root emit nothing instead of starting fresh roots, so a trace is
-either fully collected or not at all. Span export is batched: workers route
-spans into their existing task-event buffer (one ``record_task_events``
-notify per flush, not one RPC per span); drivers buffer in-module and ship
-size/time-triggered batches.
+either fully collected or not at all.
+
+A span is an interval on ONE clock, ``time.perf_counter_ns()`` of its
+process (:func:`now_ns`), stamped where the work starts and where it ends.
+Every span first lands in a bounded in-memory ring (:func:`recorded` reads
+it back in-process: the process that holds the chip is the only one that can
+lay its spans on a device trace). Export to the GCS is a side channel: a
+short-lived exporter thread drains the ring's pending queue in batches
+(workers hand the batch to their task-event buffer: one
+``record_task_events`` notify per flush), so an emitter, the engine's step
+thread above all, never runs an RPC and never waits on a lock an RPC holds.
 """
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import os
@@ -30,7 +38,7 @@ import random
 import threading
 import time
 import uuid
-from typing import Callable, Iterator, Optional, Tuple
+from typing import Callable, Iterator, List, Optional, Tuple
 
 # contextvars, not threading.local: async actor methods run as tasks on a
 # shared event loop, where thread-locals leak between interleaved
@@ -139,9 +147,8 @@ _get_runtime: Optional[Callable] = None
 
 def _node_id() -> str:
     """The runtime's node id when one is attached (timeline ``pid`` lanes
-    then group spans by node like task events); the pid otherwise. Read per
-    emit, NOT cached per runtime — ``current_node_id`` is execution-context
-    dependent (a worker thread reports the virtual node it runs on)."""
+    then group spans by node like task events); the pid otherwise. Resolved
+    once per exported batch, on the exporting thread — never per span."""
     global _get_runtime
     try:
         if _get_runtime is None:
@@ -160,6 +167,51 @@ def _node_id() -> str:
     return f"pid-{os.getpid()}"
 
 
+# ====================== the span clock ======================
+
+# One clock for every span of a process: ``time.perf_counter_ns()``, the clock
+# the engine's ``submitted_at``/``ttft_s`` and a profiler session's host times
+# are on. One wall-clock anchor, taken here at import, turns it into the
+# ``time`` field of the exported task event, so a span's wall time is DERIVED
+# (never a second ``time.time()`` reading at emit) and two processes of one
+# host agree to within the anchors' reading jitter.
+_ANCHOR_NS = time.perf_counter_ns()
+_ANCHOR_WALL = time.time()
+
+now_ns = time.perf_counter_ns
+
+
+def wall_of(ns: int) -> float:
+    """Wall-clock seconds of a ``perf_counter_ns`` reading of this process."""
+    return _ANCHOR_WALL + (ns - _ANCHOR_NS) / 1e9
+
+
+def ns_of_wall(wall: float) -> int:
+    """This process's ``perf_counter_ns`` at wall time ``wall`` — how a
+    timestamp stamped by another process (``wall_of`` there) lands on the
+    local span clock."""
+    return _ANCHOR_NS + int(round((wall - _ANCHOR_WALL) * 1e9))
+
+
+# ====================== the in-memory ring ======================
+
+Span = collections.namedtuple(
+    "Span", "name start_ns end_ns span_id parent_id trace_id attrs")
+
+# Every span of the process lands here first — a tuple append, no lock, no
+# dict, no runtime lookup — so the hot paths (the engine's step thread) pay
+# the same whether or not anything is exported. Bounded, oldest dropped.
+RING_MAX = 1 << 16
+_RING: "collections.deque[Span]" = collections.deque(maxlen=RING_MAX)
+
+
+def recorded(since_ns: int = 0) -> List[Span]:
+    """The ring's spans that START at or after ``since_ns``, oldest first:
+    the in-process reader (a benchmark that holds the chip, a test). The
+    ring outlives ``serve.shutdown()`` / ``ray_tpu.shutdown()``."""
+    return [s for s in list(_RING) if s.start_ns >= since_ns]
+
+
 # ====================== batched span export ======================
 
 # Per-process sink override: worker processes point this at their
@@ -173,43 +225,114 @@ def set_sink(sink: Optional[Callable[[dict], None]]) -> None:
     _SINK = sink
 
 
-class _SpanBuffer:
-    """Driver-side batched export: spans accumulate locally and ship as one
-    ``record_task_events`` batch when the buffer fills or goes stale —
-    checked at emit time (no flusher thread to leak) plus an explicit
-    :func:`flush` from runtime shutdown."""
-
-    FLUSH_MAX = 64
-    FLUSH_INTERVAL_S = 0.5
-    MAX_BUFFER = 4096
-
-    def __init__(self):
-        self._lock = threading.Lock()
-        self._buf: list = []
-        self._last_flush = time.monotonic()
-
-    def record(self, event: dict) -> None:
-        with self._lock:
-            if len(self._buf) < self.MAX_BUFFER:
-                self._buf.append(event)
-            due = (len(self._buf) >= self.FLUSH_MAX
-                   or time.monotonic() - self._last_flush
-                   >= self.FLUSH_INTERVAL_S)
-            if not due:
-                return
-            batch, self._buf = self._buf, []
-            self._last_flush = time.monotonic()
-        _ship(batch, None)
-
-    def flush(self, runtime=None) -> None:
-        with self._lock:
-            batch, self._buf = self._buf, []
-            self._last_flush = time.monotonic()
-        if batch:
-            _ship(batch, runtime)
+# Spans awaiting export to the GCS task-event stream. Emitters only append;
+# the exporter thread (or an explicit flush()) pops, builds the event dicts
+# and ships them — so no emitter ever runs an RPC or waits on _EXPORT_LOCK.
+PENDING_MAX = 4096
+_PENDING: "collections.deque[Span]" = collections.deque(maxlen=PENDING_MAX)
+EXPORT_INTERVAL_S = 0.5
+_EXPORT_LOCK = threading.Lock()     # held across drain + ship (an RPC)
+_START_LOCK = threading.Lock()      # guards the exporter's start/exit
+_exporter_alive = False
+# Spans pushed out of a full _PENDING since the last export (the ring still
+# has them): the exporter logs the count with the batch it ships.
+_dropped = 0
 
 
-_BUFFER = _SpanBuffer()
+def _after_fork_in_child() -> None:
+    """A forked child has the parent's flag and queue but not its exporter
+    thread: start clean, so the child's first span starts its own exporter
+    and the parent's pending spans are shipped once, by the parent."""
+    global _exporter_alive, _dropped, _EXPORT_LOCK, _START_LOCK
+    _exporter_alive = False
+    _dropped = 0
+    _EXPORT_LOCK = threading.Lock()
+    _START_LOCK = threading.Lock()
+    _PENDING.clear()
+
+
+os.register_at_fork(after_in_child=_after_fork_in_child)
+
+
+def _event_of(s: Span, node_id: str) -> dict:
+    event = {
+        "task_id": s.span_id,
+        "name": s.name,
+        "state": "FINISHED",
+        "kind": "span",
+        "time": wall_of(s.end_ns),
+        "duration": (s.end_ns - s.start_ns) / 1e9,
+        "trace_id": s.trace_id,
+        "parent_span_id": s.parent_id,
+        "node_id": node_id,
+    }
+    if s.attrs:
+        event["attrs"] = s.attrs
+    return event
+
+
+def _drain(runtime=None) -> int:
+    """Export everything pending, as one batch; returns how many spans."""
+    with _EXPORT_LOCK:
+        batch = []
+        while True:
+            try:
+                batch.append(_PENDING.popleft())
+            except IndexError:
+                break
+        if not batch:
+            return 0
+        global _dropped
+        if _dropped:
+            from ray_tpu.utils.logging import get_logger
+
+            get_logger("tracing").warning(
+                "%d spans left out of the GCS export: more than %d were "
+                "pending (tracing.recorded() still has them)",
+                _dropped, PENDING_MAX)
+            _dropped = 0
+        node_id = _node_id()    # once a batch, not once a span
+        events = [_event_of(s, node_id) for s in batch]
+        if _SINK is not None:
+            try:
+                for event in events:
+                    _SINK(event)
+            except Exception:  # noqa: BLE001 — tracing must never break work
+                from ray_tpu.utils.logging import get_logger, log_swallowed
+
+                log_swallowed(get_logger("tracing"), "span sink")
+        else:
+            _ship(events, runtime)
+        return len(batch)
+
+
+def _export_loop() -> None:
+    global _exporter_alive
+    while True:
+        time.sleep(EXPORT_INTERVAL_S)
+        if _drain():
+            continue
+        with _START_LOCK:
+            # Idle: exit instead of parking a thread in every process that
+            # ever traced; the next span starts a fresh exporter. The flag
+            # drops BEFORE the last look at the queue: an emitter appends
+            # and then reads the flag, so its span is either seen here or
+            # finds the flag down and starts the next exporter.
+            _exporter_alive = False
+            if _PENDING:
+                _exporter_alive = True
+                continue
+            return
+
+
+def _wake_exporter() -> None:
+    global _exporter_alive
+    with _START_LOCK:
+        if _exporter_alive:
+            return
+        _exporter_alive = True
+        threading.Thread(target=_export_loop, name="trace-export",
+                         daemon=True).start()
 
 
 def _ship(batch: list, runtime) -> None:
@@ -232,63 +355,70 @@ def _ship(batch: list, runtime) -> None:
         log_swallowed(get_logger("tracing"), "span export")
 
 
-def _record(event: dict, runtime=None) -> None:
+def _record(s: Span, runtime=None, export: bool = True) -> None:
+    global _dropped
+    _RING.append(s)
+    if not export:
+        return
     if runtime is not None:
         # Explicit-runtime emission (tests, pre-init drivers) delivers NOW —
         # the caller named the destination and may not live to flush later.
-        _ship([event], runtime)
+        _ship([_event_of(s, _node_id())], runtime)
         return
-    if _SINK is not None:
-        try:
-            _SINK(event)
-        except Exception:  # noqa: BLE001 — tracing must never break work
-            from ray_tpu.utils.logging import get_logger, log_swallowed
-
-            log_swallowed(get_logger("tracing"), "span sink")
-        return
-    _BUFFER.record(event)
+    if len(_PENDING) >= PENDING_MAX:
+        _dropped += 1       # the deque drops its oldest on this append
+    _PENDING.append(s)
+    if not _exporter_alive:
+        _wake_exporter()
 
 
 def flush(runtime=None) -> None:
-    """Ship any buffered spans now (runtime shutdown / test sync point)."""
-    _BUFFER.flush(runtime)
+    """Ship any pending spans now, on this thread (runtime shutdown / test
+    sync point); waits out an export the exporter thread has in flight."""
+    _drain(runtime)
 
 
 # ====================== span emission ======================
 
-def emit(name: str, ctx: Optional[tuple], *,
-         duration: float, end_time: Optional[float] = None,
+def emit(name: str, ctx: Optional[tuple], *, start: int, end: int,
          parent_span_id: Optional[str] = None,
          span_id: Optional[str] = None,
-         attrs: Optional[dict] = None, runtime=None) -> Optional[str]:
-    """Emit one finished span under an EXPLICIT context — for code that
+         attrs: Optional[dict] = None,
+         export: bool = True) -> Optional[str]:
+    """Record one finished span under an EXPLICIT context — for code that
     tracks many concurrent requests on one thread (the LLM engine's slot
     loop, DAG stage loops), where the ambient contextvar belongs to a
     different request than the span being recorded.
 
-    ``ctx`` is a (trace_id, span_id, sampled) triple; the span parents to
-    ``ctx``'s span unless ``parent_span_id`` overrides. Returns the new
-    span id, or None when the trace is unsampled / ctx is absent."""
+    ``start``/``end`` are :func:`now_ns` readings taken where the work
+    started and ended — never "now, at emit". ``ctx`` is a (trace_id,
+    span_id, sampled) triple; the span parents to ``ctx``'s span unless
+    ``parent_span_id`` overrides. ``export=False`` keeps the span in the ring
+    alone (the engine's step tree: eight spans a step, read in-process and
+    never worth a place in the GCS's task-event ring). Returns the new span
+    id, or None when the trace is unsampled / ctx is absent."""
     if ctx is None or (len(ctx) > 2 and not ctx[2]):
         return None
     sid = span_id or _new_id()
-    now = end_time if end_time is not None else time.time()
-    event = {
-        "task_id": sid,
-        "name": name,
-        "state": "FINISHED",
-        "kind": "span",
-        "time": now,
-        "duration": max(0.0, float(duration)),
-        "trace_id": ctx[0],
-        "parent_span_id": (parent_span_id if parent_span_id is not None
-                           else ctx[1]),
-        "node_id": _node_id(),
-    }
-    if attrs:
-        event["attrs"] = attrs
-    _record(event, runtime)
+    _record(Span(name, start, max(start, end), sid,
+                 parent_span_id if parent_span_id is not None else ctx[1],
+                 ctx[0], attrs), export=export)
     return sid
+
+
+_annotation_cls = None
+
+
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name``: the same interval,
+    written into whatever profiler session is open, above the device lines.
+    With no session open it costs one flag check inside the profiler."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls(name)
 
 
 @contextlib.contextmanager
@@ -308,30 +438,17 @@ def span(name: str, *, runtime=None,
         sampled = trace_enabled() and _decide_sampled()
     span_id = _new_id()
     set_context((trace_id, span_id, sampled))
-    # Duration comes from the monotonic clock (immune to NTP steps /
-    # wall-clock adjustments mid-span); the event timestamp stays wall time
-    # so spans line up with the rest of the task-event stream.
-    started_mono = time.monotonic()
+    start = now_ns()
     try:
         yield (trace_id, span_id)
     finally:
+        end = now_ns()
         set_context(parent)
         if sampled:
-            event = {
-                "task_id": span_id,
-                "name": name,
-                "state": "FINISHED",
-                "kind": "span",
-                "time": time.time(),
-                "duration": time.monotonic() - started_mono,
-                "trace_id": trace_id,
-                "parent_span_id": parent[1] if parent else None,
-                "node_id": _node_id(),
-            }
-            if attrs:
-                event["attrs"] = attrs
             try:
-                _record(event, runtime)
+                _record(Span(name, start, end, span_id,
+                             parent[1] if parent else None, trace_id, attrs),
+                        runtime)
             except Exception:  # noqa: BLE001 — tracing must never break work
                 from ray_tpu.utils.logging import get_logger, log_swallowed
 

@@ -1,13 +1,22 @@
 """End-to-end request tracing tests (ISSUE 10).
 
 A sampled serve LLM request must yield ONE connected trace — handle root,
-router pick, replica queue wait, engine admission/prefill/decode spans —
+router pick, replica queue wait, engine admission/prefill/first-chunk spans —
 retrievable by trace id through ``gcs.trace``, ``ray_tpu.timeline`` and the
 CLI tree, with the TTFT span decomposition matching the engine's measured
 TTFT. Head-based sampling is decided once at the root and inherited;
 export is batched (spans ≫ RPCs); compiled-DAG ticks trace only under an
 already-sampled caller.
+
+ISSUE 24: every span is an interval on one clock (``perf_counter_ns``),
+lands first in a bounded in-memory ring (``tracing.recorded``), and leaves
+for the GCS from an exporter thread, never from the engine's step thread;
+the engine step is a span tree of its own (``llm.step`` + phases) and the
+counts at its boundaries live in ``stats()``.
 """
+
+import collections
+import threading
 
 import jax
 import pytest
@@ -18,7 +27,7 @@ from ray_tpu.core.config import Config, set_config
 from ray_tpu.core.runtime import get_runtime
 from ray_tpu.dag import InputNode
 from ray_tpu.models import transformer
-from ray_tpu.serve.llm import llm_deployment
+from ray_tpu.serve.llm import PagedLLMEngine, llm_deployment
 from ray_tpu.util import tracing
 
 
@@ -65,8 +74,11 @@ class TestServeTraceE2E:
             names = {e["name"] for e in events}
             for expected in ("client", "serve.request", "serve.router_pick",
                             "serve.replica_queue", "llm.admission_wait",
-                            "llm.prefill", "llm.decode_chunk"):
+                            "llm.prefill", "kv.alloc", "llm.first_chunk",
+                            "serve.first_item", "llm.request"):
                 assert expected in names, f"missing span {expected}: {names}"
+            # One span a step, not one a request a step.
+            assert "llm.decode_chunk" not in names
 
             # Connected: every event's parent resolves inside the trace
             # (only the client root has no parent).
@@ -89,12 +101,39 @@ class TestServeTraceE2E:
                 _span_events(events, n), key=lambda e: e["time"])
             parts = (first("llm.admission_wait")["duration"]
                      + first("llm.prefill")["duration"]
-                     + first("llm.decode_chunk")["duration"])
+                     + first("llm.first_chunk")["duration"])
             assert ttft > 0
             assert abs(parts - ttft) <= 0.10 * ttft + 0.015, \
                 f"TTFT decomposition {parts:.4f}s vs measured {ttft:.4f}s"
+
+            # The same spans, read back in-process on the span clock: real
+            # intervals, in order, none shifted by when it was emitted.
+            ring = {s.name: s for s in tracing.recorded()
+                    if s.trace_id == trace_id}
+            wait, prefill, chunk = (ring["llm.admission_wait"],
+                                    ring["llm.prefill"],
+                                    ring["llm.first_chunk"])
+            assert ring["serve.request"].start_ns <= wait.start_ns
+            assert wait.end_ns <= prefill.start_ns
+            assert prefill.end_ns <= chunk.start_ns <= chunk.end_ns
+            assert (chunk.end_ns - wait.start_ns) / 1e9 == \
+                pytest.approx(ttft, abs=1e-6)
+            alloc = ring["kv.alloc"]
+            assert alloc.parent_id == prefill.span_id
+            assert prefill.start_ns <= alloc.start_ns <= alloc.end_ns \
+                <= prefill.end_ns
+            assert chunk.end_ns <= ring["serve.first_item"].start_ns \
+                <= ring["serve.request"].end_ns
+            req = ring["llm.request"]
+            assert req.start_ns == wait.start_ns
+            assert req.attrs["tokens"] == 8
+            assert req.attrs["finish_reason"] == "stop"
         finally:
             serve.shutdown()
+        # The ring outlives the deployment: the process that held the chip
+        # reads its spans back after the server is gone.
+        assert {"llm.request", "llm.step", "llm.warmup"} <= {
+            s.name for s in tracing.recorded()}
 
     def test_trace_reaches_timeline_and_cli_tree(self, ray_start_regular):
         """The same trace is retrievable through the timeline view (with
@@ -234,9 +273,277 @@ class TestBatchedExport:
         ctx = tracing.new_root_context()
         assert ctx is not None and ctx[2]
         for _ in range(n):
-            tracing.emit("bulk", ctx, duration=0.001)
+            t = tracing.now_ns()
+            tracing.emit("bulk", ctx, start=t, end=t + 1_000_000)
         tracing.flush()
         assert calls["events"] >= n
-        # 300 spans ride ~ n/FLUSH_MAX batched record_task_events calls —
-        # far fewer RPCs than spans (time-triggered flushes add a handful).
+        # 300 spans ride a few batched record_task_events calls — far fewer
+        # RPCs than spans (the exporter thread may take a batch of its own).
         assert calls["batches"] <= n // 32
+
+
+class TestRing:
+    def test_bounded_and_drops_oldest(self, monkeypatch):
+        monkeypatch.setattr(tracing, "_RING", collections.deque(maxlen=8))
+        ctx = ("ring-trace", None, True)
+        for i in range(20):
+            tracing.emit(f"s{i}", ctx, start=i, end=i + 1)
+        kept = tracing.recorded()
+        assert [s.name for s in kept] == [f"s{i}" for i in range(12, 20)]
+        assert [s.name for s in tracing.recorded(since_ns=18)] == \
+            ["s18", "s19"]
+        tracing.flush()     # no runtime here: the export is dropped, quietly
+        assert len(tracing.recorded()) == 8
+
+    def test_wall_time_is_derived_from_the_one_anchor(self):
+        t = tracing.now_ns()
+        assert tracing.ns_of_wall(tracing.wall_of(t)) == \
+            pytest.approx(t, abs=1000)
+        event = tracing._event_of(
+            tracing.Span("x", t, t + 2_000_000, "a", None, "b", None), "n")
+        assert event["duration"] == pytest.approx(0.002)
+        assert event["time"] == pytest.approx(tracing.wall_of(t) + 0.002)
+
+    def test_exporter_thread_exits_when_idle(self, ray_start_regular,
+                                             monkeypatch):
+        monkeypatch.setattr(tracing, "EXPORT_INTERVAL_S", 0.02)
+        with tracing.span("wake") as (trace_id, _sid):
+            pass
+        deadline = tracing.now_ns() + 5_000_000_000
+        while tracing.now_ns() < deadline and (
+                tracing._exporter_alive
+                or not get_runtime().gcs.trace(trace_id)):
+            threading.Event().wait(0.01)
+        # Shipped with no flush() call, and the thread is gone again.
+        assert _span_events(get_runtime().gcs.trace(trace_id), "wake")
+        assert not tracing._exporter_alive
+
+
+    def test_full_pending_queue_counts_what_it_drops(self, monkeypatch):
+        monkeypatch.setattr(tracing, "_PENDING", collections.deque(maxlen=4))
+        monkeypatch.setattr(tracing, "PENDING_MAX", 4)
+        monkeypatch.setattr(tracing, "_exporter_alive", True)  # no thread
+        monkeypatch.setattr(tracing, "_dropped", 0)
+        shipped = []
+        monkeypatch.setattr(tracing, "_ship",
+                            lambda batch, runtime: shipped.extend(batch))
+        ctx = ("drop-trace", None, True)
+        for i in range(10):
+            tracing.emit(f"d{i}", ctx, start=i, end=i + 1)
+        tracing.emit("ring-only", ctx, start=10, end=11, export=False)
+        assert tracing._dropped == 6
+        tracing.flush()
+        assert [e["name"] for e in shipped] == ["d6", "d7", "d8", "d9"]
+        assert tracing._dropped == 0    # said once, with the batch
+
+    def test_forked_child_starts_without_the_parents_exporter(
+            self, monkeypatch):
+        monkeypatch.setattr(tracing, "_PENDING", collections.deque(maxlen=4))
+        monkeypatch.setattr(tracing, "_exporter_alive", True)
+        monkeypatch.setattr(tracing, "_EXPORT_LOCK", tracing._EXPORT_LOCK)
+        monkeypatch.setattr(tracing, "_START_LOCK", tracing._START_LOCK)
+        tracing._PENDING.append(tracing.Span("p", 0, 1, "a", None, "t", None))
+        held = tracing._START_LOCK
+        with held:                       # forked while another thread held it
+            tracing._after_fork_in_child()
+        assert not tracing._exporter_alive and not tracing._PENDING
+        assert tracing._START_LOCK is not held
+
+    def test_replica_queue_span_never_starts_in_the_future(self):
+        """The submit stamp is another process's wall clock."""
+        import types
+
+        from ray_tpu.serve.replica import ReplicaActor
+
+        prev = tracing.current_context()
+        tracing.set_context(("skew-trace", "root", True))
+        try:
+            t0 = tracing.now_ns()
+            ReplicaActor._trace_queue_wait(
+                types.SimpleNamespace(deployment_name="d"),
+                {"_trace_submit_ts": tracing.wall_of(t0) + 5.0})
+        finally:
+            tracing.set_context(prev)
+        [s] = [s for s in tracing.recorded(t0) if s.trace_id == "skew-trace"]
+        assert s.name == "serve.replica_queue"
+        assert s.start_ns == s.end_ns <= tracing.now_ns()
+
+
+@pytest.fixture(scope="module")
+def tiny_model():
+    cfg = transformer.tiny(max_seq_len=64)
+    return cfg, transformer.init_params(cfg, jax.random.key(0))
+
+
+def _engine(tiny_model, pool_blocks, name):
+    cfg, params = tiny_model
+    eng = PagedLLMEngine(params, cfg, prompt_buckets=(16,), chunk=4, slots=2,
+                         max_queue=0, name=name, block_tokens=8,
+                         pool_blocks=pool_blocks)
+    eng.warmup()
+    return eng
+
+
+def _two_requests(eng):
+    """Two requests queued before the first step; each pins 3 blocks."""
+    reqs = [eng.submit([7, 3, 11 + i], max_new_tokens=16) for i in range(2)]
+    return [list(eng.drive(r)) for r in reqs]
+
+
+class TestEngineStepTrace:
+    def test_one_step_span_per_step_with_phases_that_tile_it(
+            self, tiny_model):
+        eng = _engine(tiny_model, 65, "steps")
+        t0 = tracing.now_ns()
+        before = eng.stats()
+        outs = _two_requests(eng)
+        assert [len(o) for o in outs] == [16, 16]
+        spans = [s for s in tracing.recorded(t0)
+                 if s.trace_id == eng.trace_id]
+        steps = [s for s in spans if s.name == "llm.step"]
+        stats = eng.stats()
+        decoded = [s for s in steps if s.attrs["batch"]]
+        assert len(decoded) == stats["steps_total"] - before["steps_total"]
+        assert sum(s.attrs["admitted"] for s in steps) == 2
+        assert sum(s.attrs["tokens"] for s in steps) == 32
+        assert not any(s.name == "llm.decode_chunk"
+                       for s in tracing.recorded(t0))
+        for step in decoded:
+            kids = [s for s in spans if s.parent_id == step.span_id]
+            assert [k.name for k in kids] == [
+                f"llm.step.{p}" for p in (
+                    "retire", "admit", "operands", "dispatch",
+                    "device_wait", "deliver", "observe")]
+            assert kids[0].start_ns == step.start_ns
+            assert kids[-1].end_ns == step.end_ns
+            total = sum(k.end_ns - k.start_ns for k in kids)
+            assert total == pytest.approx(step.end_ns - step.start_ns,
+                                          rel=0.02)
+            assert step.attrs["admit_stopped"] in (
+                "queue_empty", "no_slot", "budget", "no_blocks")
+            assert step.attrs["driver"] == threading.current_thread().name
+        # Steps do not overlap; what lies between two is the driver's own.
+        for a, b in zip(steps, steps[1:]):
+            assert a.end_ns <= b.start_ns
+        in_steps = sum(s.end_ns - s.start_ns for s in steps) / 1e9
+        assert (stats["step_host_s"] + stats["step_device_wait_s"]
+                - before["step_host_s"] - before["step_device_wait_s"]
+                ) == pytest.approx(in_steps, rel=1e-6)
+
+    def test_warmup_span_has_one_child_per_program(self, tiny_model):
+        t0 = tracing.now_ns()
+        eng = _engine(tiny_model, 65, "warm")
+        spans = [s for s in tracing.recorded(t0)
+                 if s.trace_id == eng.trace_id]
+        [warm] = [s for s in spans if s.name == "llm.warmup"]
+        programs = [s for s in spans if s.name == "llm.warmup.program"]
+        assert all(p.parent_id == warm.span_id for p in programs)
+        assert [p.attrs["program"] for p in programs] == [
+            "paged_prefill", "paged_decode", "copy_block", "set_last"]
+        assert programs[0].attrs["bucket"] == 16
+        assert warm.attrs["programs"] == 4
+        # JAX reported the time it spent tracing and lowering each one.
+        assert all(p.attrs["trace_s"] > 0 and p.attrs["lower_s"] > 0
+                   for p in programs[:2])
+        # Nested jits report their tracing inside their caller's: a program's
+        # seconds are counted once, so they fit inside its span.
+        for p in programs:
+            took = (p.end_ns - p.start_ns) / 1e9
+            assert max(p.attrs["trace_s"], p.attrs["lower_s"],
+                       p.attrs["backend_s"]) <= took
+        assert warm.start_ns <= programs[0].start_ns
+        assert programs[-1].end_ns <= warm.end_ns
+
+    @pytest.mark.parametrize("pool_blocks,blocked", [(5, True), (65, False)])
+    def test_pool_blocked_time_is_counted_where_admission_stops(
+            self, tiny_model, pool_blocks, blocked):
+        """A pool that holds one request's blocks but not two: the second
+        waits with a slot free, and the engine counts that time."""
+        eng = _engine(tiny_model, pool_blocks, f"pool{pool_blocks}")
+        t0 = tracing.now_ns()
+        outs = _two_requests(eng)
+        assert [len(o) for o in outs] == [16, 16]
+        st = eng.stats()
+        stops = [s.attrs["admit_stopped"] for s in tracing.recorded(t0)
+                 if s.name == "llm.step" and s.trace_id == eng.trace_id]
+        if blocked:
+            assert st["admit_blocked_pool_s"] > 0
+            assert "no_blocks" in stops
+            assert st["admit_blocked_pool_s"] <= (
+                st["step_host_s"] + st["step_device_wait_s"]) * 1.5
+        else:
+            assert st["admit_blocked_pool_s"] == 0
+            assert "no_blocks" not in stops
+
+    def test_budget_stops_are_counted_per_step(self, tiny_model):
+        """A prefill budget of one prompt a step: with two queued, the first
+        step admits one and stops on the budget, the second finds the queue
+        empty. ``admit_budget_stop_share`` reads the two counters."""
+        eng = _engine(tiny_model, 65, "budget")
+        eng.prefill_budget = 16
+        t0 = tracing.now_ns()
+        before = eng.stats()
+        outs = _two_requests(eng)
+        assert [len(o) for o in outs] == [16, 16]
+        st = eng.stats()
+        stops = [s.attrs["admit_stopped"] for s in tracing.recorded(t0)
+                 if s.name == "llm.step" and s.trace_id == eng.trace_id]
+        assert stops[:2] == ["budget", "queue_empty"]
+        assert st["admit_stopped_budget_total"] \
+            - before["admit_stopped_budget_total"] == stops.count("budget") == 1
+        assert st["steps_total"] - before["steps_total"] >= 4
+
+    def test_gate_off_leaves_ring_empty_and_counters_counting(
+            self, tiny_model, fresh_config):
+        eng = _engine(tiny_model, 65, "gated")
+        set_config(Config({"trace_enabled": False}))
+        t0 = tracing.now_ns()
+        before = eng.stats()
+        outs = _two_requests(eng)
+        assert [len(o) for o in outs] == [16, 16]
+        assert tracing.recorded(t0) == []
+        st = eng.stats()
+        assert st["steps_total"] - before["steps_total"] >= 4
+        assert st["admit_stopped_budget_total"] == \
+            before["admit_stopped_budget_total"]
+        assert st["step_device_wait_s"] > before["step_device_wait_s"]
+        assert st["step_host_s"] > before["step_host_s"]
+
+    def test_step_thread_never_exports(self, ray_start_regular, tiny_model,
+                                       monkeypatch):
+        """The GCS export runs on the exporter thread or in an explicit
+        flush(), never on the thread that steps the engine."""
+        shippers = []
+        real = tracing._ship
+
+        def recording_ship(batch, runtime):
+            shippers.append(threading.current_thread().name)
+            return real(batch, runtime)
+
+        monkeypatch.setattr(tracing, "_ship", recording_ship)
+        monkeypatch.setattr(tracing, "EXPORT_INTERVAL_S", 0.01)
+        eng = _engine(tiny_model, 65, "noexport")
+        out = []
+
+        def drive():
+            with tracing.span("caller"):
+                out.extend(eng.generate([7, 3, 11], max_new_tokens=32))
+
+        th = threading.Thread(target=drive, name="engine-driver")
+        th.start()
+        th.join(timeout=120)
+        assert not th.is_alive() and len(out) == 32
+        tracing.flush()
+        assert shippers and "engine-driver" not in shippers
+        # What the step thread emitted for the request did reach the GCS ...
+        shipped = get_runtime().gcs.task_events()
+        assert _span_events(shipped, "llm.request")
+        # ... and the step tree stayed in the ring: eight spans a step are
+        # read in-process, whatever the sample rate, and never exported.
+        assert not _span_events(shipped, "llm.step")
+        assert not _span_events(get_runtime().gcs.trace(eng.trace_id),
+                                "llm.step.dispatch")
+        steps = [s for s in tracing.recorded()
+                 if s.name == "llm.step" and s.trace_id == eng.trace_id]
+        assert steps and all(s.attrs["driver"] == "engine-driver"
+                             for s in steps if s.attrs["batch"])

@@ -16,14 +16,26 @@ libtpu at once collide on its lock file.
 
 import json
 import os
+import re
 import subprocess
 import sys
+
+import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+# An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
+# custom-call(...), custom_call_target="tpu_custom_call"``. The profiler names
+# a device operation by this same text, and the benchmark's kernel metrics
+# match ``<name>:custom-call:<shape>`` in it.
+_KERNEL = re.compile(r"%([\w\-]+?)(?:\.\d+)* = \(?(\w+\[[\d,]*\])[^\n]*"
+                     r"custom_call_target=\"tpu_custom_call\"")
+
+
 def compile_all() -> dict:
-    """Child side: {"skip": reason} or {"programs": {name: "ok" | error}}."""
+    """Child side: {"skip": reason} or {"programs": {name: "ok" | error},
+    "kernels": {name: [[instruction name, first output shape], ...]}}."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -51,11 +63,12 @@ def compile_all() -> dict:
     cfg = transformer.gpt2_small(max_seq_len=1024, n_layers=2, remat=True)
     ctx, H, D, bt, slots = cfg.max_seq_len, cfg.n_heads, cfg.head_dim, 16, 8
     one = SingleDeviceSharding(devices[0])
-    programs = {}
+    programs, kernels = {}, {}
 
     def attempt(name, lower):
         try:
-            lower().compile()
+            text = lower().compile().as_text()
+            kernels[name] = sorted(set(_KERNEL.findall(text)))
             programs[name] = "ok"
         except Exception as e:  # noqa: BLE001 — the verdict IS the result
             programs[name] = f"{type(e).__name__}: {str(e)[:600]}"
@@ -98,25 +111,53 @@ def compile_all() -> dict:
         attempt(name, lambda b=bundle, p=p_shape, o=o_shape: b.step.lower(
             placed(p, b.param_shardings), placed(o, b.opt_shardings),
             {"tokens": arr((16, ctx), jnp.int32, b.batch_sharding)}))
-    return {"programs": programs}
+    return {"programs": programs, "kernels": kernels}
 
 
-def test_kernels_and_sharded_train_step_compile_for_v5e():
-    import pytest
-
+@pytest.fixture(scope="module")
+def verdict():
+    """One child process compiles everything; both tests read its verdict."""
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     child = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            env=env, capture_output=True, text=True,
                            timeout=170)
     assert child.returncode == 0, child.stderr[-3000:]
-    verdict = json.loads(child.stdout.strip().splitlines()[-1])
-    if "skip" in verdict:
-        pytest.skip(verdict["skip"])
+    out = json.loads(child.stdout.strip().splitlines()[-1])
+    if "skip" in out:
+        pytest.skip(out["skip"])
+    return out
+
+
+def test_kernels_and_sharded_train_step_compile_for_v5e(verdict):
     programs = verdict["programs"]
     assert {"flash_fwd_bwd", "paged_decode", "paged_prefill_1024",
             "train_step_data4", "train_step_data2_tensor2"} <= set(programs)
     refused = {n: v for n, v in programs.items() if v != "ok"}
     assert not refused, json.dumps(refused, indent=1)
+
+
+def test_kernels_carry_stable_names_and_unchanged_shapes(verdict):
+    """Each Pallas call is named for what it is, whatever jit, scan, vjp or
+    remat scope calls it, and its custom-call still has the output shape the
+    benchmark's metric files match (``:custom-call:bf16[S,H,1,D]`` for the
+    decode kernel, four dimensions for prefill, three for flash)."""
+    kernels = verdict["kernels"]
+    [[name, shape]] = kernels["paged_decode"]
+    assert name == "paged_decode_attn"
+    assert re.fullmatch(r"bf16\[\d+,\d+,1,\d+\]", shape), shape
+    for program in ("paged_verify", "paged_prefill_16", "paged_prefill_1024"):
+        [[name, shape]] = kernels[program]
+        assert name == "paged_prefill_attn"
+        assert re.fullmatch(r"bf16\[\d+,\d+,\d+,\d+\]", shape), shape
+    for program in ("flash_fwd_bwd", "train_step_data4",
+                    "train_step_data2_tensor2"):
+        found = kernels[program]
+        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+            hits = [shape for name, shape in found if kernel in name]
+            assert hits, (program, kernel, found)
+            assert all(re.fullmatch(r"bf16\[\d+,\d+,\d+\]", s)
+                       for s in hits), (program, hits)
+        assert all("flash_" in name for name, _shape in found), found
 
 
 if __name__ == "__main__":
