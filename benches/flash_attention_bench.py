@@ -54,9 +54,9 @@ def bench_paged(quick: bool) -> None:
         rng = np.random.default_rng(0)
         pool = S * nb_seq + 1  # + trash block 0
         q = jnp.asarray(rng.standard_normal((S, 1, H, D)), jnp.float32)
-        k_pool = jnp.asarray(rng.standard_normal((pool, bt, H, D)),
+        k_pool = jnp.asarray(rng.standard_normal((1, pool, bt, H * D)),
                              jnp.float32)
-        v_pool = jnp.asarray(rng.standard_normal((pool, bt, H, D)),
+        v_pool = jnp.asarray(rng.standard_normal((1, pool, bt, H * D)),
                              jnp.float32)
         tables = jnp.asarray(
             np.arange(1, S * nb_seq + 1, dtype=np.int32).reshape(S, nb_seq))
@@ -68,9 +68,9 @@ def bench_paged(quick: bool) -> None:
         rec = {
             "metric": f"paged_attention_s{S}_ctx{nb_seq * bt}",
             "kernel_ms": round(_bench(kern, q, k_pool, v_pool, tables,
-                                      lengths, iters=iters), 2),
+                                      lengths, 0, iters=iters), 2),
             "gather_ms": round(_bench(ref, q, k_pool, v_pool, tables,
-                                      lengths, iters=iters), 2),
+                                      lengths, 0, iters=iters), 2),
             "kernel_mode": "pallas" if on_tpu else "interpret",
             "platform": jax.devices()[0].platform,
         }

@@ -91,8 +91,9 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     nb_seq = ctx // bt
     pool_blocks = 2 * SLOTS * nb_seq + 1          # the engine's own auto size
     rng = np.random.default_rng(SEED)
+    # One layer's pool in the engine's layout, heads folded into the lanes.
     k_pool, v_pool = (jnp.asarray(
-        rng.standard_normal((pool_blocks, bt, H, D), np.float32), dt)
+        rng.standard_normal((1, pool_blocks, bt, H * D), np.float32), dt)
         for _ in range(2))
     # Every slot owns a shuffled chain over its whole table (block 0 = trash).
     tables = jnp.asarray(rng.permutation(np.arange(1, pool_blocks))
@@ -103,7 +104,8 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     def paged_case(name, t_tokens, lengths):
         n = len(lengths)
         qq = jnp.asarray(rng.standard_normal((n, t_tokens, H, D), np.float32), dt)
-        ops = (qq, k_pool, v_pool, tables[:n], jnp.asarray(lengths, jnp.int32))
+        ops = (qq, k_pool, v_pool, tables[:n],
+               jnp.asarray(lengths, jnp.int32), 0)
         with jax.default_matmul_precision("highest"):  # read at trace time
             want = oracle(*ops)
         errs[name] = _rel_err(kernel(*ops), want)

@@ -316,24 +316,33 @@ def init_block_pool(config: TransformerConfig, num_blocks: int,
     per-sequence block TABLES instead of private max_len slabs. Block 0 is
     the reserved TRASH block: freed table rows and pad positions point at
     it, so out-of-range scatter writes land somewhere harmless instead of
-    corrupting a live sequence."""
+    corrupting a live sequence.
+
+    Shape ``[n_layers, num_blocks, block_tokens, n_heads * head_dim]``: the
+    heads are folded into the lane dimension, so a token's K (or V) row is
+    dense in the layout the array has in HBM, and the one layout serves the
+    scatter that writes it, the kernel that reads it in place
+    (``ops/paged_attention.py``) and every program it passes through. With a
+    trailing ``[.., n_heads, head_dim]`` the 64-wide minor dimension made
+    every serve program re-tile the whole pool on entry and on exit."""
     c = config
-    shape = (c.n_layers, num_blocks, block_tokens, c.n_heads, c.head_dim)
+    shape = (c.n_layers, num_blocks, block_tokens, c.n_heads * c.head_dim)
     return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
 
 
-def _paged_attend(q, k_pool, v_pool, tables, lengths, *, scale, kernel):
-    """Attention over the paged pool for one layer, switched by ``kernel``:
-    the Pallas kernel streams only live blocks (compiled on TPU, interpret
-    on CPU); ``gather`` is the legacy table-gather + dense-mask path."""
+def _paged_attend(q, k_pool, v_pool, tables, lengths, layer, *, scale,
+                  kernel):
+    """Attention over ``layer`` of the whole paged pool, switched by
+    ``kernel``: the Pallas kernel streams only live blocks, in place
+    (compiled on TPU, interpret on CPU); ``gather`` is the legacy
+    table-gather + dense-mask path."""
     if kernel in ("pallas", "interpret"):
-        return paged_attention(q, k_pool, v_pool, tables, lengths,
+        return paged_attention(q, k_pool, v_pool, tables, lengths, layer,
                                scale=scale, interpret=kernel == "interpret")
-    S, T = q.shape[:2]
-    nb, bt, H, D = k_pool.shape
-    nb_seq = tables.shape[1]
-    kc = k_pool[tables].reshape(S, nb_seq * bt, H, D)
-    vc = v_pool[tables].reshape(S, nb_seq * bt, H, D)
+    S, T, H, D = q.shape
+    max_len = tables.shape[1] * k_pool.shape[2]
+    kc = k_pool[layer, tables].reshape(S, max_len, H, D)
+    vc = v_pool[layer, tables].reshape(S, max_len, H, D)
     return _attend_cached(q, kc, vc, lengths + T, scale=scale)
 
 
@@ -375,10 +384,11 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
         if c.pos == "rope":
             q = rope(q, positions[None])
             k = rope(k, positions[None])
-        k_pool = k_pool.at[layer, blk, off].set(k[0])
-        v_pool = v_pool.at[layer, blk, off].set(v[0])
-        o = _paged_attend(q, k_pool[layer], v_pool[layer], table[None],
-                          lengths1, scale=scale, kernel=kernel)
+        with jax.named_scope("kv_pool_write"):
+            k_pool = k_pool.at[layer, blk, off].set(k.reshape(P, -1))
+            v_pool = v_pool.at[layer, blk, off].set(v.reshape(P, -1))
+        o = _paged_attend(q, k_pool, v_pool, table[None], lengths1, layer,
+                          scale=scale, kernel=kernel)
         o = jnp.einsum("bthk,hkd->btd", o, bp["wo"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bo"]
         h = h + o
         x = layer_norm(h, bp["ln2_g"], bp["ln2_b"])
@@ -434,9 +444,9 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
             q = rope(q, positions)
             k = rope(k, positions)
         with jax.named_scope("kv_pool_write"):
-            k_pool = k_pool.at[layer, blk, off].set(k)
-            v_pool = v_pool.at[layer, blk, off].set(v)
-        o = _paged_attend(q, k_pool[layer], v_pool[layer], tables, lengths,
+            k_pool = k_pool.at[layer, blk, off].set(k.reshape(S, T, -1))
+            v_pool = v_pool.at[layer, blk, off].set(v.reshape(S, T, -1))
+        o = _paged_attend(q, k_pool, v_pool, tables, lengths, layer,
                           scale=scale, kernel=kernel)
         o = jnp.einsum("bthk,hkd->btd", o, bp["wo"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bo"]
         h = h + o
@@ -817,7 +827,7 @@ class PagedGenerator:
         return self._copy_fn
 
     def extract_fn(self, nb: int):
-        """extract(k_pool, v_pool, block_ids [nb]) -> (k [L,nb,bt,H,Dh], v):
+        """extract(k_pool, v_pool, block_ids [nb]) -> (k [L,nb,bt,H*Dh], v):
         gather a finished prefill's blocks for the disaggregation handoff
         (the pool itself is NOT donated — the prefill engine keeps serving
         its prefix cache from it)."""
@@ -832,7 +842,7 @@ class PagedGenerator:
         return fn
 
     def insert_fn(self, nb: int):
-        """insert(k_pool, v_pool, k [L,nb,bt,H,Dh], v, block_ids [nb]) ->
+        """insert(k_pool, v_pool, k [L,nb,bt,H*Dh], v, block_ids [nb]) ->
         (k_pool, v_pool): scatter handed-off blocks into the decode pool —
         donated, so the upload lands in place of the old pool buffers."""
         fn = self._insert_fns.get(nb)

@@ -28,6 +28,18 @@ The T new tokens' K/V must already be scattered into the pool at those
 positions (the caller writes K/V first, then attends — same order as the
 gather path).
 
+The pool is the WHOLE model's, ``[L, num_blocks, bt, H*D]``: heads folded
+into the lane dimension, so a block is one dense ``[bt, H*D]`` tile in the
+layout the array already has in HBM, and ``layer`` rides as a third
+scalar-prefetch operand into the index map. A Mosaic call cannot read
+through an XLA slice: handed ``pool[layer]`` it made XLA copy that layer's
+slab out of the pool every call (and a ``[.., H, D]`` pool with D = 64 minor
+was re-tiled whole on the way into and out of every program). Addressed in
+place, no program copies pool-sized data. Head ``h`` is the static lane
+slice ``[:, h*D:(h+1)*D]`` of the block; the body takes the lanes in chunks
+of G heads (``_heads_per_chunk``), every chunk a 128-lane-aligned slice, and
+only the finalize step cuts single heads out of the accumulator.
+
 Grid: ``(S, q tiles, nb_seq)``, kv innermost. Queries are tiled
 ``_Q_TILE`` at a time so the per-step VMEM footprint (q/out blocks plus the
 ``[H*tile, ·]`` f32 accumulators, whose 1-wide m/l columns pad to a full
@@ -67,12 +79,26 @@ def _last_block(first_pos, q_tile: int, block_tokens: int):
     return jax.lax.div(first_pos + q_tile - 1, block_tokens)
 
 
+def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
+    """How many heads the kernel takes in one dot. The block's lanes are cut
+    into chunks of G heads; a chunk's G*T query rows, each zero outside its
+    own head's lanes, meet the chunk's G*D lanes of K in ONE dot, so no head
+    is sliced out of a 128-lane register on every block. The dot computes G
+    times the products it needs: free while the rows fit one MXU pass
+    (decode, verify: all heads at once), so beyond that G is only what fills
+    128 lanes (prefill: two heads of 64)."""
+    if num_heads * q_tile <= 128:
+        return num_heads
+    return min(num_heads, max(1, 128 // head_dim))
+
+
 def _paged_kernel(
     tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
-    q_ref,                     # [1, H, T, D] block — T = one q tile
-    k_ref, v_ref,              # [1, bt, H, D] block — pool block tables[s, j]
+    layer_ref,                 # scalar prefetch: [1] int32 (index map only)
+    q_ref,                     # [1, H*T, G*D] block — T = one q tile
+    k_ref, v_ref,              # [1, bt, H*D] block — pool block tables[s, j]
     o_ref,                     # [1, H, T, D] block
-    m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, D]
+    m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, G*D]
     *,
     scale: float,
     block_tokens: int,
@@ -84,6 +110,8 @@ def _paged_kernel(
     i = pl.program_id(1)
     j = pl.program_id(2)
     bt, H, T = block_tokens, num_heads, q_tile
+    D = o_ref.shape[-1]
+    G = q_ref.shape[-1] // D                   # heads per lane chunk
     # Absolute position of this tile's first query.
     ctx = lengths_ref[s] + i * T
     # Grid steps past this block re-map onto it in the index map, so they
@@ -98,47 +126,57 @@ def _paged_kernel(
 
     @pl.when(j <= last_blk)
     def _body():
-        qb = q_ref[0].astype(jnp.float32)            # [H, T, D]
-        kb = k_ref[0].astype(jnp.float32)            # [bt, H, D]
-        vb = v_ref[0].astype(jnp.float32)            # [bt, H, D]
         # Causal + validity in one mask: kv position vs absolute q position.
-        kv_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (T, bt), 1)
-        q_pos = ctx + jax.lax.broadcasted_iota(jnp.int32, (T, bt), 0)
-        mask = kv_pos <= q_pos
-        for h in range(H):                           # static unroll
+        # Row r of a chunk is (head r // T, query r % T).
+        rows = G * T
+        kv_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 0)
+        q_pos = ctx + (0 if T == 1 else jax.lax.rem(row, T))
+        mask_all = kv_pos <= q_pos
+        for c0 in range(0, H, G):                    # static unroll
+            g = min(G, H - c0)                       # heads of this chunk
+            d0, d1 = c0 * D, (c0 + g) * D            # their lanes
+            r0, r1 = c0 * T, (c0 + g) * T            # their rows
+            mask = mask_all[: g * T]
+            qb = q_ref[0, r0:r1, : g * D].astype(jnp.float32)   # [g*T, g*D]
+            kb = k_ref[0, :, d0:d1].astype(jnp.float32)         # [bt, g*D]
+            vb = v_ref[0, :, d0:d1].astype(jnp.float32)
             scores = jax.lax.dot_general(
-                qb[h], kb[:, h, :], (((1,), (1,)), ((), ())),
+                qb, kb, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            ) * scale                                 # [T, bt]
+            ) * scale                                 # [g*T, bt]
             scores = jnp.where(mask, scores, _NEG_INF)
-            r0, r1 = h * T, (h + 1) * T
-            m_prev = m_scr[r0:r1]                     # [T, 1]
+            m_prev = m_scr[r0:r1]                     # [g*T, 1]
             m_cur = jnp.max(scores, axis=-1, keepdims=True)
             m_new = jnp.maximum(m_prev, m_cur)
             alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(scores - m_new)               # [T, bt]
+            p = jnp.exp(scores - m_new)               # [g*T, bt]
             l_scr[r0:r1] = alpha * l_scr[r0:r1] + jnp.sum(
                 p, axis=-1, keepdims=True)
             pv = jax.lax.dot_general(
-                p, vb[:, h, :], (((1,), (0,)), ((), ())),
+                p, vb, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32,
-            )                                         # [T, D]
-            acc_scr[r0:r1] = acc_scr[r0:r1] * alpha + pv
+            )                                         # [g*T, g*D]
+            acc_scr[r0:r1, : g * D] = acc_scr[r0:r1, : g * D] * alpha + pv
             m_scr[r0:r1] = m_new
 
     @pl.when(j == nb_seq - 1)
     def _finalize():
-        denom = jnp.maximum(l_scr[:], 1e-30)          # [H*T, 1]
-        out = (acc_scr[:] / denom).reshape(H, T, acc_scr.shape[-1])
-        o_ref[0] = out.astype(o_ref.dtype)
+        for h in range(H):                            # static unroll
+            r0, r1 = h * T, (h + 1) * T
+            e0 = (h % G) * D                          # head h's lanes in its chunk
+            denom = jnp.maximum(l_scr[r0:r1], 1e-30)  # [T, 1]
+            o_ref[0, h] = (acc_scr[r0:r1, e0:e0 + D] / denom).astype(
+                o_ref.dtype)
 
 
 def paged_attention(
     q: jax.Array,                # [S, T, H, D]
-    k_pool: jax.Array,           # [num_blocks, bt, H, D] (one layer's pool)
+    k_pool: jax.Array,           # [L, num_blocks, bt, H*D] (the whole pool)
     v_pool: jax.Array,
     tables: jax.Array,           # [S, NB] int32 — pool block ids, 0 = trash
     lengths: jax.Array,          # [S] int32 — valid context BEFORE the T tokens
+    layer,                       # int or int32 scalar — which layer's blocks
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
@@ -146,14 +184,21 @@ def paged_attention(
     """Fused paged-attention over the block pool; returns [S, T, H, D].
 
     Query t of slot s is at absolute position ``lengths[s] + t`` and attends
-    positions ``<= lengths[s] + t`` gathered through ``tables[s]``. No
-    ``[S, max_len, H, D]`` intermediate exists at any point."""
+    positions ``<= lengths[s] + t`` of layer ``layer`` gathered through
+    ``tables[s]``. No ``[S, max_len, H, D]`` intermediate exists at any
+    point, and the pool is read in place: a caller holding one layer's pool
+    passes ``pool[None]`` and layer 0."""
     S, T, H, D = q.shape
-    bt = k_pool.shape[1]
+    if k_pool.ndim != 4 or k_pool.shape[3] != H * D:
+        raise ValueError(
+            f"pool {k_pool.shape} is not [L, num_blocks, bt, {H}*{D}]: the "
+            f"kernel reads the whole folded pool (one layer's: pool[None])")
+    bt = k_pool.shape[2]
     nb_seq = tables.shape[1]
     s_val = scale if scale is not None else 1.0 / D**0.5
     tables = tables.astype(jnp.int32)
     lengths = lengths.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     qt = q.transpose(0, 2, 1, 3)                      # [S, H, T, D]
     tq = min(T, _Q_TILE)
     q_tiles = pl.cdiv(T, tq)
@@ -162,26 +207,38 @@ def paged_attention(
         # and their rows are sliced off below.
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, q_tiles * tq - T), (0, 0)))
 
-    def kv_index(s, i, j, tbl, ln):
-        last_blk = _last_block(ln[s] + i * tq, tq, bt)
-        return (tbl[s, jnp.minimum(j, last_blk)], 0, 0, 0)
+    G = _heads_per_chunk(H, tq, D)
+    # q of head h sits in lanes (h % G) * D of a G*D-wide row, zeros beside
+    # it: one dot of a chunk's rows against the chunk's lanes then gives
+    # every head its own scores. Rows of a tile are (head, query).
+    own = jnp.arange(G)[None, :] == (jnp.arange(H) % G)[:, None]   # [H, G]
+    qw = jnp.where(own[None, :, None, :, None], qt[:, :, :, None, :], 0)
+    qw = qw.reshape(S, H, q_tiles, tq, G * D).transpose(0, 2, 1, 3, 4)
+    qw = qw.reshape(S, q_tiles, H * tq, G * D)
 
-    def q_index(s, i, j, tbl, ln):
+    def kv_index(s, i, j, tbl, ln, lyr):
+        last_blk = _last_block(ln[s] + i * tq, tq, bt)
+        return (lyr[0], tbl[s, jnp.minimum(j, last_blk)], 0, 0)
+
+    def q_index(s, i, j, tbl, ln, lyr):
+        return (s, i, 0, 0)
+
+    def o_index(s, i, j, tbl, ln, lyr):
         return (s, 0, i, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, q_tiles, nb_seq),
         in_specs=[
-            pl.BlockSpec((1, H, tq, D), q_index),
-            pl.BlockSpec((1, bt, H, D), kv_index),
-            pl.BlockSpec((1, bt, H, D), kv_index),
+            pl.BlockSpec((None, 1, H * tq, G * D), q_index),
+            pl.BlockSpec((None, 1, bt, H * D), kv_index),
+            pl.BlockSpec((None, 1, bt, H * D), kv_index),
         ],
-        out_specs=pl.BlockSpec((1, H, tq, D), q_index),
+        out_specs=pl.BlockSpec((1, H, tq, D), o_index),
         scratch_shapes=[
             pltpu.VMEM((H * tq, 1), jnp.float32),
             pltpu.VMEM((H * tq, 1), jnp.float32),
-            pltpu.VMEM((H * tq, D), jnp.float32),
+            pltpu.VMEM((H * tq, G * D), jnp.float32),
         ],
     )
     out = pl.pallas_call(
@@ -193,21 +250,22 @@ def paged_attention(
         interpret=interpret,
         # The name a profiler prints for the kernel, whatever calls it.
         name="paged_decode_attn" if T == 1 else "paged_prefill_attn",
-    )(tables, lengths, qt, k_pool, v_pool)
+    )(tables, lengths, layer, qw, k_pool, v_pool)
     return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
 
 
-def paged_attention_reference(q, k_pool, v_pool, tables, lengths, *,
+def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
                               scale: Optional[float] = None) -> jax.Array:
-    """Gather-path oracle: materializes [S, NB*bt, H, D] through the table
-    and runs masked dense attention — numerically what the pre-kernel decode
-    did, kept as the equivalence target and the CPU fallback reference."""
+    """Gather-path oracle over the same operands: materializes
+    [S, NB*bt, H, D] of ``layer`` through the table and runs masked dense
+    attention — numerically what the pre-kernel decode did, kept as the
+    equivalence target and the CPU fallback reference."""
     S, T, H, D = q.shape
-    bt = k_pool.shape[1]
+    bt = k_pool.shape[2]
     nb_seq = tables.shape[1]
     s_val = scale if scale is not None else 1.0 / D**0.5
-    kc = k_pool[tables].reshape(S, nb_seq * bt, H, D)
-    vc = v_pool[tables].reshape(S, nb_seq * bt, H, D)
+    kc = k_pool[layer, tables].reshape(S, nb_seq * bt, H, D)
+    vc = v_pool[layer, tables].reshape(S, nb_seq * bt, H, D)
     scores = jnp.einsum("bthd,bshd->bhts", q, kc,
                         preferred_element_type=jnp.float32) * s_val
     kv_pos = jnp.arange(nb_seq * bt)[None, None, None, :]

@@ -1590,7 +1590,7 @@ class PagedLLMEngine(LLMEngine):
     def prefill_to_blocks(self, prompt_ids: Sequence[int], *, seed: int = 0):
         """Prefill-side half of disaggregated serving: run (suffix-)prefill
         for ``prompt_ids`` into pool blocks and return host copies for the
-        handoff lane — ``(k [L,nb,bt,H,Dh], v, last_row [V], hit_tokens)``.
+        handoff lane — ``(k [L,nb,bt,H*Dh], v, last_row [V], hit_tokens)``.
 
         The chain (full blocks AND partial tail — nothing will extend these
         blocks here) is registered in the LOCAL prefix cache before the pins

@@ -545,6 +545,11 @@ def test_daemon_sigkill_holding_block_reclaims_capacity(block_cluster):
     it removes a node from the module-scoped cluster.)"""
     gcs = RpcClient(block_cluster.gcs_address)
     try:
+        # The burst test before this one hands its blocks back through the
+        # daemons' idle sweep: wait for that, or the grant below is partial.
+        assert _wait_for(
+            lambda: gcs.call("available_resources").get("CPU", 0) == 4.0,
+            timeout=60)
         block_id, node_id, addr, granted = gcs.call(
             "request_lease_batch", {"CPU": 1}, None, 2, 30.0, timeout=35.0)
         assert granted == 2

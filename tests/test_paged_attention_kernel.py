@@ -26,13 +26,18 @@ NB = 6   # blocks per sequence (table width)
 H, D = 4, 16
 
 
-def _setup(lengths, t_tokens, *, seed=0, pool_blocks=24):
-    """Random pool + one live block chain per slot; returns operands."""
+def _setup(lengths, t_tokens, *, seed=0, pool_blocks=24, layers=1, layer=0,
+           heads=H, dim=D):
+    """Random pool + one live block chain per slot; returns the operands of
+    ``paged_attention`` / ``paged_attention_reference``: the pool is the
+    whole model's, ``[layers, pool_blocks, BT, heads * dim]`` with distinct
+    content in every layer, and ``layer`` picks the one attended."""
     rng = np.random.default_rng(seed)
     S = len(lengths)
-    q = rng.standard_normal((S, t_tokens, H, D)).astype(np.float32)
-    k_pool = rng.standard_normal((pool_blocks, BT, H, D)).astype(np.float32)
-    v_pool = rng.standard_normal((pool_blocks, BT, H, D)).astype(np.float32)
+    q = rng.standard_normal((S, t_tokens, heads, dim)).astype(np.float32)
+    pool = (layers, pool_blocks, BT, heads * dim)
+    k_pool = rng.standard_normal(pool).astype(np.float32)
+    v_pool = rng.standard_normal(pool).astype(np.float32)
     tables = np.zeros((S, NB), np.int32)
     nxt = 1  # block 0 stays trash
     for s, ln in enumerate(lengths):
@@ -41,7 +46,8 @@ def _setup(lengths, t_tokens, *, seed=0, pool_blocks=24):
             tables[s, j] = nxt
             nxt += 1
     return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-            jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)))
+            jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)),
+            layer)
 
 
 def _assert_close(a, b, tol=2e-5):
@@ -72,6 +78,39 @@ class TestKernelOracleEquivalence:
         ref = paged_attention_reference(*ops)
         _assert_close(out, ref)
 
+    @pytest.mark.parametrize("layers,layer", [(3, 1), (3, 2), (5, 4)])
+    @pytest.mark.parametrize("t_tokens", [1, 4])
+    def test_layer_of_a_whole_pool(self, layers, layer, t_tokens):
+        """The kernel addresses the whole model's pool by layer: every layer
+        holds distinct content, so a wrong layer index cannot pass."""
+        ops = _setup([3, BT, 2 * BT + 1], t_tokens, seed=13, layers=layers,
+                     layer=layer)
+        out = paged_attention(*ops, interpret=True)
+        _assert_close(out, paged_attention_reference(*ops))
+        wrong = paged_attention_reference(*ops[:-1], (layer + 1) % layers)
+        assert not np.allclose(np.asarray(out), np.asarray(wrong), atol=1e-2)
+        # A traced layer index (a scan over layers) reads the same blocks.
+        traced = jax.jit(lambda lyr: paged_attention(
+            *ops[:-1], lyr, interpret=True))(jnp.int32(layer))
+        _assert_close(traced, out)
+
+    @pytest.mark.parametrize("heads,dim", [(5, 16), (3, 24), (25, 8)])
+    def test_folded_width_not_a_multiple_of_128(self, heads, dim):
+        """``H*D`` off the 128-lane grid (gpt2-xl's 25 x 64 = 1600): the
+        block's last dimension is the array's own, heads stay static lane
+        slices of it."""
+        assert (heads * dim) % 128
+        ops = _setup([0, BT - 1, 2 * BT + 1], 1, seed=17, layers=2, layer=1,
+                     heads=heads, dim=dim)
+        out = paged_attention(*ops, interpret=True)
+        _assert_close(out, paged_attention_reference(*ops))
+
+    def test_pool_of_another_width_is_refused(self):
+        q, k_pool, v_pool, tables, lens, layer = _setup([5], 1)
+        with pytest.raises(ValueError, match="pool"):
+            paged_attention(q, k_pool[..., :-D], v_pool[..., :-D], tables,
+                            lens, layer, interpret=True)
+
     def test_scale_override(self):
         ops = _setup([11], 1, seed=5)
         out = paged_attention(*ops, scale=0.25, interpret=True)
@@ -82,21 +121,21 @@ class TestKernelOracleEquivalence:
         """Poisoning the reserved trash block (and the dead tail of every
         table) must not move any live output by a single ULP."""
         lengths = [5, BT + 2]
-        q, k_pool, v_pool, tables, lens = _setup(lengths, 1, seed=7)
-        out = paged_attention(q, k_pool, v_pool, tables, lens,
+        q, k_pool, v_pool, tables, lens, layer = _setup(lengths, 1, seed=7)
+        out = paged_attention(q, k_pool, v_pool, tables, lens, layer,
                               interpret=True)
-        k_bad = k_pool.at[0].set(1e9)
-        v_bad = v_pool.at[0].set(-1e9)
-        out_bad = paged_attention(q, k_bad, v_bad, tables, lens,
+        k_bad = k_pool.at[:, 0].set(1e9)
+        v_bad = v_pool.at[:, 0].set(-1e9)
+        out_bad = paged_attention(q, k_bad, v_bad, tables, lens, layer,
                                   interpret=True)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out_bad))
 
     def test_inactive_slot_is_finite(self):
         """An all-trash table at length 0 (a parked slot) must produce
         finite output — the online softmax may not divide by zero."""
-        q, k_pool, v_pool, tables, lens = _setup([0, 9], 1, seed=9)
+        q, k_pool, v_pool, tables, lens, layer = _setup([0, 9], 1, seed=9)
         tables = tables.at[0].set(0)
-        out = paged_attention(q, k_pool, v_pool, tables, lens,
+        out = paged_attention(q, k_pool, v_pool, tables, lens, layer,
                               interpret=True)
         assert np.isfinite(np.asarray(out)).all()
 
@@ -111,7 +150,7 @@ class TestTiledPrefill:
     def _ops(t_tokens, start, *, bt=16, nb=64, heads=2, dim=16, seed=11):
         rng = np.random.default_rng(seed)
         q = rng.standard_normal((1, t_tokens, heads, dim)).astype(np.float32)
-        pool = (nb + 1, bt, heads, dim)
+        pool = (1, nb + 1, bt, heads * dim)      # one layer's pool: [None]
         k_pool = rng.standard_normal(pool).astype(np.float32)
         v_pool = rng.standard_normal(pool).astype(np.float32)
         live = min(nb, -(-(start + t_tokens) // bt))
@@ -119,7 +158,7 @@ class TestTiledPrefill:
         # A shuffled chain: tile i must dereference ITS blocks, not 1..n.
         table[0, :live] = rng.permutation(np.arange(1, nb + 1))[:live]
         return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-                jnp.asarray(table), jnp.asarray([start], jnp.int32))
+                jnp.asarray(table), jnp.asarray([start], jnp.int32), 0)
 
     @pytest.mark.parametrize("t_tokens,start", [
         (1024, 0),      # the full-context bucket, 8 tiles

@@ -234,6 +234,10 @@ class TestLengthCapRegression:
         nb_seq = 3
         pool = 8
         k_pool, v_pool = generate.init_block_pool(cfg, pool, BT)
+        # Heads folded into the lanes; blocks stay dimension 1, so the
+        # [:, 0] / [:, 1:] reads below index trash and live blocks as before.
+        assert k_pool.shape == v_pool.shape == (
+            cfg.n_layers, pool, BT, cfg.n_heads * cfg.head_dim)
         k_pool = k_pool + 1.5  # sentinel content
         v_pool = v_pool + 2.5
         tables = jnp.asarray(
@@ -252,3 +256,4 @@ class TestLengthCapRegression:
                                       np.asarray(v_pool[:, 1:]))
         assert not np.array_equal(np.asarray(k2[:, 0]),
                                   np.asarray(k_pool[:, 0]))
+        assert k2.shape == k_pool.shape and v2.shape == v_pool.shape

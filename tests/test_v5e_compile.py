@@ -24,6 +24,12 @@ import pytest
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
+# The serve programs compiled whole: gpt2-medium's width (16 heads of 64 fold
+# to 1024 lanes) and gpt2-xl's (25 x 64 = 1600, not a multiple of 128), depth
+# cut; the benchmark's pool and the larger one PR 23 found a cliff at.
+SERVE_WIDTHS = {"medium": "gpt2_medium", "xl": "gpt2_xl"}
+SERVE_LAYERS, SERVE_SLOTS, SERVE_POOLS = 2, 36, (1281, 1537)
+
 
 # An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
 # custom-call(...), custom_call_target="tpu_custom_call"``. The profiler names
@@ -32,10 +38,53 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _KERNEL = re.compile(r"%([\w\-]+?)(?:\.\d+)* = \(?(\w+\[[\d,]*\])[^\n]*"
                      r"custom_call_target=\"tpu_custom_call\"")
 
+# One instruction of an optimized module: name, output shape(s), op kind.
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w\-.]+) = (.*?) ([a-z][\w\-]*)\(")
+# Op kinds that move no data: they name, pass on or alias a buffer.
+_NO_DATA = {"parameter", "get-tuple-element", "tuple", "bitcast", "while",
+            "conditional", "call", "optimization-barrier"}
+
+
+def pool_shaped_data_movers(hlo: str, n_layers: int, num_blocks: int,
+                            block_tokens: int) -> list:
+    """[[op kind, instruction, shape], ...]: every instruction of the
+    optimized module whose output has the KV pool's shape, or one layer's
+    slab of it, and that is not the in-place write: a ``scatter``, or the
+    ``fusion`` that wraps one and aliases the pool through. A ``copy``, a
+    ``slice`` or any other fusion of that shape is pool-sized traffic that a
+    serve program pays on every call."""
+    shapes = (f"[{n_layers},{num_blocks},{block_tokens},",
+              f"[{num_blocks},{block_tokens},")
+    bodies, current = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w\-.]+) \(.*\{\s*$", line)
+        if head:
+            current = bodies.setdefault(head.group(1), [])
+        elif current is not None:
+            current.append(line)
+    found = []
+    for body in bodies.values():
+        for line in body:
+            m = _INSTR.match(line)
+            if not m or not any(s in m.group(2) for s in shapes):
+                continue
+            name, shape, op = m.groups()
+            if op in _NO_DATA or op == "scatter":
+                continue
+            called = re.search(r"calls=%([\w\-.]+)", line)
+            if op == "fusion" and called and any(
+                    " scatter(" in inner
+                    for inner in bodies.get(called.group(1), [])):
+                continue
+            found.append([op, name, shape[:80]])
+    return found
+
 
 def compile_all() -> dict:
     """Child side: {"skip": reason} or {"programs": {name: "ok" | error},
-    "kernels": {name: [[instruction name, first output shape], ...]}}."""
+    "kernels": {name: [[instruction name, first output shape], ...]},
+    "pool_movers": {serve program: pool_shaped_data_movers() of it},
+    "temp_bytes": {serve program: temporaries the compiler reports}}."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -51,6 +100,7 @@ def compile_all() -> dict:
                         f"{str(e)[:300]}"}
 
     from ray_tpu.models import transformer
+    from ray_tpu.models.generate import PagedGenerator
     from ray_tpu.models.training import make_train_step
     from ray_tpu.ops.flash_attention import flash_attention
     from ray_tpu.ops.paged_attention import paged_attention
@@ -63,12 +113,16 @@ def compile_all() -> dict:
     cfg = transformer.gpt2_small(max_seq_len=1024, n_layers=2, remat=True)
     ctx, H, D, bt, slots = cfg.max_seq_len, cfg.n_heads, cfg.head_dim, 16, 8
     one = SingleDeviceSharding(devices[0])
-    programs, kernels = {}, {}
+    programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
 
-    def attempt(name, lower):
+    def attempt(name, lower, pool=None):
         try:
-            text = lower().compile().as_text()
+            compiled = lower().compile()
+            text = compiled.as_text()
             kernels[name] = sorted(set(_KERNEL.findall(text)))
+            if pool is not None:
+                pool_movers[name] = pool_shaped_data_movers(text, *pool)
+                temp_bytes[name] = compiled.memory_analysis().temp_size_in_bytes
             programs[name] = "ok"
         except Exception as e:  # noqa: BLE001 — the verdict IS the result
             programs[name] = f"{type(e).__name__}: {str(e)[:600]}"
@@ -83,13 +137,48 @@ def compile_all() -> dict:
         argnums=(0, 1, 2))).lower(qkv, qkv, qkv))
 
     nb_seq = ctx // bt
-    pool = arr((2 * slots * nb_seq + 1, bt, H, D))
+    pool = arr((1, 2 * slots * nb_seq + 1, bt, H * D))   # one layer's: [None]
     cases = [("paged_decode", slots, 1), ("paged_verify", slots, 5)]
     cases += [(f"paged_prefill_{b}", 1, b) for b in _default_buckets(ctx)]
     for name, s, t in cases:
         attempt(name, lambda s=s, t=t: jax.jit(paged_attention).lower(
             arr((s, t, H, D)), pool, pool, arr((s, nb_seq), jnp.int32),
-            arr((s,), jnp.int32)))
+            arr((s,), jnp.int32), arr((), jnp.int32)))
+
+    # The serve programs whole, at the benchmark's serve geometry (36 slots,
+    # blocks of 16, the decode chunk of 8, the 256 bucket) and two published
+    # widths, depth cut: what XLA does with the pool AROUND the kernel.
+    for width, full in SERVE_WIDTHS.items():
+        scfg = getattr(transformer, full)(max_seq_len=ctx).replace(
+            n_layers=SERVE_LAYERS)
+        params = jax.tree.map(
+            lambda x: arr(x.shape, x.dtype),
+            jax.eval_shape(lambda key, c=scfg: transformer.init_params(c, key),
+                           jax.random.key(0)))
+        for num_blocks in SERVE_POOLS:
+            gen = PagedGenerator(params, scfg, slots=SERVE_SLOTS,
+                                 num_blocks=num_blocks, block_tokens=bt,
+                                 attention_kernel="pallas")
+            kv = arr((scfg.n_layers, num_blocks, bt,
+                      scfg.n_heads * scfg.head_dim))
+            state = (params, kv, kv, arr((SERVE_SLOTS, gen.logits_dim),
+                                         jnp.float32),
+                     arr((SERVE_SLOTS, 2), jnp.uint32))
+            per_slot = lambda dtype: arr((SERVE_SLOTS,), dtype)  # noqa: E731
+            i32 = arr((), jnp.int32)
+            geometry = (scfg.n_layers, num_blocks, bt)
+            attempt(f"serve_decode_{width}_{num_blocks}",
+                    lambda: gen.decode_fn(8).lower(
+                        *state, arr((SERVE_SLOTS, gen.blocks_per_seq),
+                                    jnp.int32),
+                        per_slot(jnp.int32), per_slot(jnp.bool_),
+                        per_slot(jnp.bool_), per_slot(jnp.float32)),
+                    pool=geometry)
+            attempt(f"serve_prefill_{width}_{num_blocks}",
+                    lambda: gen.prefill_fn(256).lower(
+                        *state, arr((gen.blocks_per_seq,), jnp.int32),
+                        arr((1, 256), jnp.int32), i32, i32, i32, i32),
+                    pool=geometry)
 
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
@@ -111,7 +200,8 @@ def compile_all() -> dict:
         attempt(name, lambda b=bundle, p=p_shape, o=o_shape: b.step.lower(
             placed(p, b.param_shardings), placed(o, b.opt_shardings),
             {"tokens": arr((16, ctx), jnp.int32, b.batch_sharding)}))
-    return {"programs": programs, "kernels": kernels}
+    return {"programs": programs, "kernels": kernels,
+            "pool_movers": pool_movers, "temp_bytes": temp_bytes}
 
 
 @pytest.fixture(scope="module")
@@ -120,7 +210,7 @@ def verdict():
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     child = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            env=env, capture_output=True, text=True,
-                           timeout=170)
+                           timeout=400)
     assert child.returncode == 0, child.stderr[-3000:]
     out = json.loads(child.stdout.strip().splitlines()[-1])
     if "skip" in out:
@@ -158,6 +248,53 @@ def test_kernels_carry_stable_names_and_unchanged_shapes(verdict):
             assert all(re.fullmatch(r"bf16\[\d+,\d+,\d+\]", s)
                        for s in hits), (program, hits)
         assert all("flash_" in name for name, _shape in found), found
+
+
+@pytest.mark.parametrize("num_blocks", SERVE_POOLS)
+@pytest.mark.parametrize("width", sorted(SERVE_WIDTHS))
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_serve_programs_move_no_pool_sized_data(verdict, program, width,
+                                                num_blocks):
+    """``paged_decode`` and ``paged_prefill`` hold the KV pool in one layout
+    from residency through write to read: besides the in-place scatters, the
+    optimized module has no ``copy``, ``slice`` or fusion whose output is the
+    pool or one layer of it (PR 25: they were 40-59% of the serve cells'
+    device time), and its temporaries do not grow with the pool."""
+    name = f"serve_{program}_{width}_{num_blocks}"
+    assert verdict["programs"][name] == "ok", verdict["programs"][name]
+    assert verdict["pool_movers"][name] == []
+    temps = [verdict["temp_bytes"][f"serve_{program}_{width}_{n}"]
+             for n in SERVE_POOLS]
+    assert max(temps) - min(temps) < 1 << 20, temps
+    [[kernel, shape]] = verdict["kernels"][name]
+    assert kernel == ("paged_decode_attn" if program == "decode"
+                      else "paged_prefill_attn")
+    assert re.fullmatch(r"bf16\[\d+,\d+,\d+,64\]", shape), shape
+
+
+def test_pool_mover_scan_sees_what_the_old_layout_did():
+    """The scan itself, on the instructions PR 24's trace named."""
+    hlo = """
+%fused_computation.3 (p0: bf16[4,1281,16,1024]) -> bf16[4,1281,16,1024] {
+  %p0 = bf16[4,1281,16,1024]{3,2,1,0} parameter(0)
+  ROOT %scatter.1 = bf16[4,1281,16,1024]{3,2,1,0} scatter(%p0, %i, %u)
+}
+%fused_computation.9 (p0: bf16[4,1281,16,1024]) -> bf16[1281,16,1024] {
+  %p0 = bf16[4,1281,16,1024]{3,2,1,0} parameter(0)
+  ROOT %slice.2 = bf16[1281,16,1024]{2,1,0} slice(%p0), slice={[1:2]}
+}
+ENTRY %main (k: bf16[4,1281,16,1024]) -> bf16[4,1281,16,1024] {
+  %k = bf16[4,1281,16,1024]{3,2,1,0} parameter(0)
+  %copy.1 = bf16[4,1281,16,1024]{3,2,1,0:T(8,128)(2,1)} copy(%k)
+  %fusion.528 = bf16[4,1281,16,1024]{3,2,1,0} fusion(%copy.1), kind=kCustom, calls=%fused_computation.3
+  %slice_bitcast_fusion.4 = bf16[1281,16,1024]{2,1,0} fusion(%fusion.528), kind=kLoop, calls=%fused_computation.9
+  ROOT %tuple.1 = (bf16[4,1281,16,1024]{3,2,1,0}) tuple(%fusion.528)
+}
+"""
+    found = pool_shaped_data_movers(hlo, 4, 1281, 16)
+    assert sorted((op, name) for op, name, _shape in found) == [
+        ("copy", "copy.1"), ("fusion", "slice_bitcast_fusion.4"),
+        ("slice", "slice.2")]
 
 
 if __name__ == "__main__":
