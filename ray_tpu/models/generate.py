@@ -16,7 +16,7 @@ host; batch/beam layouts stay static so both programs compile exactly once.
 from __future__ import annotations
 
 import functools
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -460,6 +460,78 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
     return logits, k_pool, v_pool
 
 
+class AuxCount(NamedTuple):
+    """One entry of a family's per-call counts: the ``stats()`` key the
+    decode programs' value adds to, the key a prefill's adds to (None: a
+    prefill's is not kept), and the ``llm.step`` span attribute that carries
+    the decode chunk's value (None: none)."""
+    decode: str
+    prefill: Optional[str] = None
+    step_attr: Optional[str] = None
+
+
+class PagedFamily(NamedTuple):
+    """What a model family gives :class:`PagedGenerator`: what its pool is
+    and how one forward pass writes and attends it. The generator keeps the
+    programs (their names, buckets, donation, the sampling tail), the engine
+    and the block manager know only block ids.
+
+    - ``init_pool(config, num_blocks, block_tokens)`` -> a tuple of arrays,
+      each ``[layers or sublayers, num_blocks, block_tokens, ...]``: blocks
+      are dimension 1 of every one, block 0 the trash block, so block copy,
+      extract and insert are the generator's, whatever a row holds;
+    - ``prefill(params, tokens [1, P], pool, table, start_pos, suffix_len,
+      config, block_tokens, kernel)`` -> ``(logits, pool, aux)``;
+    - ``decode(params, tokens [S, T], pool, tables, lengths, config,
+      block_tokens, kernel, active)`` -> ``(logits, pool, aux)``;
+      ``aux`` is None or a small integer array of per-call counts that the
+      programs hand back beside the tokens (summed over a decode chunk);
+    - ``aux_counts``: one :class:`AuxCount` for each entry of ``aux``, in its
+      order: the engine folds them into ``stats()`` under these names and
+      knows nothing else about them;
+    - ``logits_dim(params, config)``: rows of the ``last`` carry;
+    - ``unsupported``: engine features the family cannot run yet, refused
+      when an engine is built: ``draft_model``, ``disaggregation``,
+      ``kv_tier``.
+
+    A config object names its family through a ``paged_family()`` method;
+    one without it is GPT-2 (``transformer.TransformerConfig``)."""
+    init_pool: Callable
+    prefill: Callable
+    decode: Callable
+    logits_dim: Callable
+    unsupported: Tuple[str, ...] = ()
+    aux_counts: Tuple[AuxCount, ...] = ()
+
+
+def _gpt2_prefill(params, tokens, pool, table, start_pos, suffix_len, config,
+                  block_tokens, kernel="gather"):
+    logits, k_pool, v_pool = _forward_prefill_paged(
+        params, tokens, *pool, table, start_pos, suffix_len, config,
+        block_tokens, kernel=kernel)
+    return logits, (k_pool, v_pool), None
+
+
+def _gpt2_decode(params, tokens, pool, tables, lengths, config, block_tokens,
+                 kernel="gather", active=None):
+    logits, k_pool, v_pool = _forward_decode_paged(
+        params, tokens, *pool, tables, lengths, config, block_tokens,
+        kernel=kernel)
+    return logits, (k_pool, v_pool), None
+
+
+GPT2_FAMILY = PagedFamily(
+    init_pool=init_block_pool, prefill=_gpt2_prefill, decode=_gpt2_decode,
+    logits_dim=lambda params, config: (
+        params["tok_embed"].shape[0] if config.tie_embeddings
+        else params["lm_head"].shape[-1]))
+
+
+def paged_family(config) -> PagedFamily:
+    named = getattr(config, "paged_family", None)
+    return named() if named is not None else GPT2_FAMILY
+
+
 class PagedGenerator:
     """Paged device half of the serving engine: same compile discipline as
     :class:`SlottedGenerator` (one program per prompt bucket, one per chunk
@@ -467,19 +539,23 @@ class PagedGenerator:
     per-sequence block tables — the layout that makes hash-based prefix
     reuse, copy-on-write forks and prefill/decode KV handoff possible.
 
-    Device state is ``(k_pool, v_pool, last, keys)`` threaded with buffer
-    donation; block tables and per-slot lengths are plain numpy operands
-    owned by the host-side :class:`KVBlockManager` + engine.
+    Device state is ``(pool, last, keys)`` threaded with buffer donation:
+    ``pool`` is the family's tuple of pool arrays (:class:`PagedFamily`; K
+    and V for GPT-2, one latent array for LongCat), a pytree that every
+    program takes and returns whole. Block tables and per-slot lengths are
+    plain numpy operands owned by the host-side :class:`KVBlockManager` +
+    engine.
     """
 
-    def __init__(self, params, config: TransformerConfig, *, slots: int,
+    def __init__(self, params, config, *, slots: int,
                  num_blocks: int, block_tokens: int,
                  max_len: Optional[int] = None,
                  attention_kernel: str = "auto",
                  draft_params=None,
-                 draft_config: Optional[TransformerConfig] = None):
+                 draft_config=None):
         self.params = params
         self.config = config
+        self.family = paged_family(config)
         self.slots = slots
         self.max_len = max_len or config.max_seq_len
         self.block_tokens = int(block_tokens)
@@ -493,15 +569,19 @@ class PagedGenerator:
         if (draft_params is None) != (draft_config is None):
             raise ValueError("draft_params and draft_config go together")
         if draft_config is not None and (
+                "draft_model" in self.family.unsupported
+                or "draft_model" in paged_family(draft_config).unsupported):
+            raise ValueError(
+                f"{type(config).__name__}: speculative decoding with a "
+                f"draft model is not supported for this family yet")
+        if draft_config is not None and (
                 draft_config.vocab_size != config.vocab_size):
             raise ValueError(
                 f"draft vocab {draft_config.vocab_size} != target vocab "
                 f"{config.vocab_size} — speculative verify needs one vocab")
         self.draft_params = draft_params
         self.draft_config = draft_config
-        self.logits_dim = (params["tok_embed"].shape[0]
-                          if config.tie_embeddings
-                          else params["lm_head"].shape[-1])
+        self.logits_dim = self.family.logits_dim(params, config)
         self._prefill_fns = {}   # suffix bucket -> jitted paged prefill
         self._decode_fns = {}    # chunk -> jitted paged decode
         self._extract_fns = {}   # nb -> jitted block gather (KV handoff out)
@@ -511,68 +591,72 @@ class PagedGenerator:
         self._spec_decode_fns = {}    # (chunk, k) -> jitted spec decode
 
     def init_state(self):
-        k_pool, v_pool = init_block_pool(self.config, self.num_blocks,
-                                         self.block_tokens)
+        pool = tuple(self.family.init_pool(self.config, self.num_blocks,
+                                           self.block_tokens))
         last = jnp.zeros((self.slots, self.logits_dim), jnp.float32)
         keys = jnp.zeros((self.slots, 2), jnp.uint32)
-        return k_pool, v_pool, last, keys
+        return pool, last, keys
 
     def init_draft_state(self):
         """Draft-model pool mirroring the target pool's block geometry: the
         SAME block tables index both, so advance/rollback bookkeeping is
         shared and speculation adds zero KVBlockManager state."""
-        return init_block_pool(self.draft_config, self.num_blocks,
-                               self.block_tokens)
+        return tuple(paged_family(self.draft_config).init_pool(
+            self.draft_config, self.num_blocks, self.block_tokens))
 
     def prefill_fn(self, bucket: int):
-        """paged_prefill(params, k_pool, v_pool, last, keys, table [NB],
-        padded [1,P], start_pos, suffix_len, slot, seed) -> (k_pool, v_pool,
-        last, keys): prefill the SUFFIX bucket at start_pos (the prefix-hit
-        length) and park last-token logits + PRNG key in the slot rows."""
+        """paged_prefill(params, pool, last, keys, table [NB], padded [1,P],
+        start_pos, suffix_len, slot, seed) -> (pool, last, keys, aux):
+        prefill the SUFFIX bucket at start_pos (the prefix-hit length) and
+        park last-token logits + PRNG key in the slot rows. ``aux`` is the
+        family's per-call counts (None for GPT-2)."""
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
         c = self.config
         bt = self.block_tokens
         kernel = self.attention_kernel
+        forward = self.family.prefill
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def paged_prefill(params, k_pool, v_pool, last, keys, table, padded,
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
+        def paged_prefill(params, pool, last, keys, table, padded,
                           start_pos, suffix_len, slot, seed):
-            logits, k_pool, v_pool = _forward_prefill_paged(
-                params, padded, k_pool, v_pool, table, start_pos,
-                suffix_len, c, bt, kernel=kernel)
+            logits, pool, aux = forward(
+                params, padded, pool, table, start_pos, suffix_len, c, bt,
+                kernel=kernel)
             row = jax.lax.dynamic_index_in_dim(
                 logits, suffix_len - 1, axis=1, keepdims=False)     # [1, V]
             last = lax.dynamic_update_slice(last, row, (slot, 0))
             keys = lax.dynamic_update_slice(
                 keys, jax.random.PRNGKey(seed)[None], (slot, 0))
-            return k_pool, v_pool, last, keys
+            return pool, last, keys, aux
 
         self._prefill_fns[bucket] = paged_prefill
         return paged_prefill
 
     def decode_fn(self, chunk: int):
-        """paged_decode(params, k_pool, v_pool, last, keys, tables [S,NB],
-        lengths [S], active, greedy, temps) -> (toks [S, chunk], k_pool,
-        v_pool, last, keys): ``chunk`` scan steps advancing every active
-        slot through its block table in one dispatch."""
+        """paged_decode(params, pool, last, keys, tables [S,NB], lengths [S],
+        active, greedy, temps) -> (toks [S, chunk], pool, last, keys, aux):
+        ``chunk`` scan steps advancing every active slot through its block
+        table in one dispatch; ``aux`` the family's counts summed over the
+        chunk (None for GPT-2)."""
         fn = self._decode_fns.get(chunk)
         if fn is not None:
             return fn
         c = self.config
         bt = self.block_tokens
         kernel = self.attention_kernel
+        forward = self.family.decode
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
-        def paged_decode(params, k_pool, v_pool, last, keys, tables, lengths,
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
+        def paged_decode(params, pool, last, keys, tables, lengths,
                          active, greedy, temps):
             adv = active.astype(jnp.int32)
             act_col = active[:, None]
             temp_safe = jnp.maximum(temps, 1e-6)[:, None]
 
             def step(carry, _):
-                k_p, v_p, lens, last, keys = carry
+                pool, lens, last, keys = carry
                 # Scope names are what a profiler's op metadata carries:
                 # stable across refactors of the code inside them.
                 with jax.named_scope("sample"):
@@ -584,25 +668,27 @@ class PagedGenerator:
                     nxt = jnp.where(greedy, jnp.argmax(real, axis=-1),
                                     samp).astype(jnp.int32)
                 with jax.named_scope("decode_step"):
-                    logits, k_p, v_p = _forward_decode_paged(
-                        params, nxt[:, None], k_p, v_p, tables, lens, c, bt,
-                        kernel=kernel)
+                    logits, pool, aux = forward(
+                        params, nxt[:, None], pool, tables, lens, c, bt,
+                        kernel=kernel, active=active)
                 lens = lens + adv
                 last = jnp.where(act_col, logits[:, -1], last)
                 keys = jnp.where(act_col, keys2, keys)
-                return (k_p, v_p, lens, last, keys), nxt
+                return (pool, lens, last, keys), (nxt, aux)
 
-            (k_pool, v_pool, _lens, last, keys), toks = lax.scan(
-                step, (k_pool, v_pool, jnp.asarray(lengths), last, keys),
+            (pool, _lens, last, keys), (toks, aux) = lax.scan(
+                step, (pool, jnp.asarray(lengths), last, keys),
                 None, length=chunk)
-            return toks.T, k_pool, v_pool, last, keys
+            if aux is not None:
+                aux = jnp.sum(aux, axis=0)
+            return toks.T, pool, last, keys, aux
 
         self._decode_fns[chunk] = paged_decode
         return paged_decode
 
     def draft_prefill_fn(self, bucket: int):
-        """draft_prefill(draft_params, kd_pool, vd_pool, table [NB],
-        padded [1,P], start_pos, suffix_len) -> (kd_pool, vd_pool): run the
+        """draft_prefill(draft_params, draft_pool, table [NB], padded [1,P],
+        start_pos, suffix_len) -> draft_pool: run the
         DRAFT model over the same suffix bucket through the same block
         table so its pool holds draft-KV for every position the target
         holds — the draft chain in :meth:`spec_decode_fn` then starts from
@@ -614,14 +700,15 @@ class PagedGenerator:
         dc = self.draft_config
         bt = self.block_tokens
         kernel = self.attention_kernel
+        forward = paged_family(dc).prefill
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2))
-        def draft_prefill(draft_params, kd_pool, vd_pool, table, padded,
+        @functools.partial(jax.jit, donate_argnums=(1,))
+        def draft_prefill(draft_params, draft_pool, table, padded,
                           start_pos, suffix_len):
-            _, kd_pool, vd_pool = _forward_prefill_paged(
-                draft_params, padded, kd_pool, vd_pool, table, start_pos,
+            _, draft_pool, _aux = forward(
+                draft_params, padded, draft_pool, table, start_pos,
                 suffix_len, dc, bt, kernel=kernel)
-            return kd_pool, vd_pool
+            return draft_pool
 
         self._draft_prefill_fns[bucket] = draft_prefill
         return draft_prefill
@@ -630,11 +717,11 @@ class PagedGenerator:
         """Speculative decode: ``chunk`` scan steps, each proposing ``k``
         draft tokens and verifying them in ONE batched target forward.
 
-        spec_decode(params, draft_params, k_pool, v_pool, kd_pool, vd_pool,
-        last, keys, tables, lengths, active, greedy, temps, spec_on, tail,
-        pending, use_pending) -> (toks [S, chunk, k+1], counts [S, chunk],
-        accepted [S, chunk], k_pool, v_pool, kd_pool, vd_pool, last, keys,
-        tail, pending, use_pending).
+        spec_decode(params, draft_params, pool, draft_pool, last, keys,
+        tables, lengths, active, greedy, temps, spec_on, tail, pending,
+        use_pending) -> (toks [S, chunk, k+1], counts [S, chunk],
+        accepted [S, chunk], pool, draft_pool, last, keys, tail, pending,
+        use_pending).
 
         Per step and slot: token n0 comes from ``last`` (or the carried
         rejection replacement when ``use_pending``); the draft runs k+1
@@ -674,17 +761,19 @@ class PagedGenerator:
         bt = self.block_tokens
         kernel = self.attention_kernel
         V = c.vocab_size
+        target_forward = self.family.decode
+        draft_forward = paged_family(dc).decode
 
-        @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5, 6, 7))
-        def spec_decode(params, draft_params, k_pool, v_pool, kd_pool,
-                        vd_pool, last, keys, tables, lengths, active, greedy,
-                        temps, spec_on, tail, pending, use_pending):
+        @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
+        def spec_decode(params, draft_params, pool, draft_pool, last, keys,
+                        tables, lengths, active, greedy, temps, spec_on,
+                        tail, pending, use_pending):
             adv_gate = active.astype(jnp.int32)
             act_col = active[:, None]
             temp_safe = jnp.maximum(temps, 1e-6)[:, None]
 
             def step(carry, _):
-                (k_p, v_p, kd_p, vd_p, lens, last, keys, tail, pending,
+                (pool, draft_pool, lens, last, keys, tail, pending,
                  use_pending) = carry
                 nsub = 2 * k + 3
                 split = jax.vmap(
@@ -709,8 +798,8 @@ class PagedGenerator:
                 cur_pos = jnp.maximum(lens - 1, 0)
                 proposals, dlogits = [], []
                 for i in range(k + 1):
-                    dl, kd_p, vd_p = _forward_decode_paged(
-                        draft_params, cur_tok[:, None], kd_p, vd_p, tables,
+                    dl, draft_pool, _aux = draft_forward(
+                        draft_params, cur_tok[:, None], draft_pool, tables,
                         cur_pos, dc, bt, kernel=kernel)
                     if i == 0:
                         # Forward 0 only (re)writes tail's draft KV at
@@ -728,8 +817,8 @@ class PagedGenerator:
 
                 # Single batched target verify over [n0, d_1..d_k].
                 verify = jnp.stack([n0] + proposals, axis=1)   # [S, k+1]
-                logits, k_p, v_p = _forward_decode_paged(
-                    params, verify, k_p, v_p, tables, lens, c, bt,
+                logits, pool, _aux = target_forward(
+                    params, verify, pool, tables, lens, c, bt,
                     kernel=kernel)
                 treal = logits[:, :, :V]                       # [S, k+1, V]
 
@@ -794,65 +883,65 @@ class PagedGenerator:
                     logits, row_idx[:, None, None], axis=1)[:, 0]
                 last = jnp.where(refresh[:, None], row, last)
                 keys = jnp.where(act_col, keys2, keys)
-                return ((k_p, v_p, kd_p, vd_p, lens_new, last, keys, tail,
+                return ((pool, draft_pool, lens_new, last, keys, tail,
                          pending, use_pending),
                         (verify, adv, a * adv_gate))
 
-            carry0 = (k_pool, v_pool, kd_pool, vd_pool,
+            carry0 = (pool, draft_pool,
                       jnp.asarray(lengths), last, keys, jnp.asarray(tail),
                       jnp.asarray(pending), jnp.asarray(use_pending))
-            (k_pool, v_pool, kd_pool, vd_pool, _lens, last, keys, tail,
+            (pool, draft_pool, _lens, last, keys, tail,
              pending, use_pending), (toks, counts, accepted) = lax.scan(
                 step, carry0, None, length=chunk)
             return (toks.transpose(1, 0, 2), counts.T, accepted.T,
-                    k_pool, v_pool, kd_pool, vd_pool, last, keys, tail,
+                    pool, draft_pool, last, keys, tail,
                     pending, use_pending)
 
         self._spec_decode_fns[key_ck] = spec_decode
         return spec_decode
 
     def copy_fn(self):
-        """copy_block(k_pool, v_pool, src, dst) -> (k_pool, v_pool): the
-        copy-on-write primitive — duplicate one shared block (a prefix-hit
-        partial tail) into a private block before divergent writes."""
+        """copy_block(pool, src, dst) -> pool: the copy-on-write primitive
+        — duplicate one shared block (a prefix-hit partial tail) into a
+        private block before divergent writes, in every array of the pool."""
         if self._copy_fn is None:
 
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def copy_block(k_pool, v_pool, src, dst):
-                k_pool = k_pool.at[:, dst].set(k_pool[:, src])
-                v_pool = v_pool.at[:, dst].set(v_pool[:, src])
-                return k_pool, v_pool
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def copy_block(pool, src, dst):
+                return jax.tree.map(
+                    lambda a: a.at[:, dst].set(a[:, src]), pool)
 
             self._copy_fn = copy_block
         return self._copy_fn
 
     def extract_fn(self, nb: int):
-        """extract(k_pool, v_pool, block_ids [nb]) -> (k [L,nb,bt,H*Dh], v):
-        gather a finished prefill's blocks for the disaggregation handoff
-        (the pool itself is NOT donated — the prefill engine keeps serving
-        its prefix cache from it)."""
+        """extract(pool, block_ids [nb]) -> the blocks of every pool array
+        (GPT-2: ``(k [L,nb,bt,H*Dh], v)``): gather a finished prefill's
+        blocks for the disaggregation handoff (the pool itself is NOT
+        donated — the prefill engine keeps serving its prefix cache from
+        it)."""
         fn = self._extract_fns.get(nb)
         if fn is None:
 
             @jax.jit
-            def extract(k_pool, v_pool, block_ids):
-                return k_pool[:, block_ids], v_pool[:, block_ids]
+            def extract(pool, block_ids):
+                return jax.tree.map(lambda a: a[:, block_ids], pool)
 
             fn = self._extract_fns[nb] = extract
         return fn
 
     def insert_fn(self, nb: int):
-        """insert(k_pool, v_pool, k [L,nb,bt,H*Dh], v, block_ids [nb]) ->
-        (k_pool, v_pool): scatter handed-off blocks into the decode pool —
-        donated, so the upload lands in place of the old pool buffers."""
+        """insert(pool, blocks, block_ids [nb]) -> pool: scatter handed-off
+        blocks (a tuple shaped as ``extract`` gives them) into the decode
+        pool — donated, so the upload lands in place of the old pool
+        buffers."""
         fn = self._insert_fns.get(nb)
         if fn is None:
 
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def insert(k_pool, v_pool, k, v, block_ids):
-                k_pool = k_pool.at[:, block_ids].set(k)
-                v_pool = v_pool.at[:, block_ids].set(v)
-                return k_pool, v_pool
+            @functools.partial(jax.jit, donate_argnums=(0,))
+            def insert(pool, blocks, block_ids):
+                return jax.tree.map(
+                    lambda a, b: a.at[:, block_ids].set(b), pool, blocks)
 
             fn = self._insert_fns[nb] = insert
         return fn

@@ -103,3 +103,102 @@ def moe_ffn(params: Dict, x: jax.Array, cfg: MoEConfig) -> Tuple[jax.Array, Dict
         "dropped_fraction": 1.0 - jnp.mean(keep.astype(jnp.float32)),
     }
     return y.reshape(B, S, D), metrics
+
+
+# ---------------------------------------------------------------------------
+# Top-k dropless routing over the experts held here (serve path)
+# ---------------------------------------------------------------------------
+# The Switch layer above holds every expert and drops overflow. The layer
+# below is what an expert-parallel SERVING deployment asks of one chip: it is
+# told which experts it holds, routes over ALL of them (and over the
+# zero-compute experts, which need no weights), and computes the part of the
+# result that its own experts give. What the absent experts would have added
+# is left out; no code stands in for the other chips or for their exchange.
+
+# Per-call pick counts, in the order of the array ``held_experts_ffn`` returns
+# (a model family names its serve counters after them): picks, zero-compute
+# picks, picks on held experts, the busiest held expert's pairs, held experts
+# with at least one pair.
+PICK_COUNT_NAMES = ("picks", "picks_zero", "picks_held", "held_pairs_max",
+                    "experts_hit")
+PICK_COUNTS = len(PICK_COUNT_NAMES)
+
+
+def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
+               topk: int, scale: float) -> Tuple[jax.Array, jax.Array]:
+    """h [N, D] -> (idx [N, topk] int32, weights [N, topk] float32).
+
+    ``s = softmax(W_r h)`` in float32 over every router output (routed and
+    zero-compute experts alike); the ``topk`` largest of ``s + bias`` are
+    picked (the bias selects, it never weighs), and a pick's weight is
+    ``scale * s`` there, not renormalised."""
+    logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32),
+                        w_router.astype(jnp.float32),
+                        precision=jax.lax.Precision.HIGHEST)
+    s = jax.nn.softmax(logits, axis=-1)
+    _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), topk)
+    return idx.astype(jnp.int32), scale * jnp.take_along_axis(s, idx, axis=-1)
+
+
+def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
+                     w_gate_up: jax.Array, w_down: jax.Array, *,
+                     held: Tuple[int, int], n_routed: int,
+                     valid: Optional[jax.Array] = None,
+                     ) -> Tuple[jax.Array, jax.Array]:
+    """This chip's part of a top-k expert layer, dropless.
+
+    ``h`` [N, D]; ``idx`` / ``weights`` [N, k] from :func:`route_topk`;
+    ``w_gate_up`` [E_held, D, 2F] (gate | up) and ``w_down`` [E_held, F, D]
+    are the gated FFNs of experts ``held = (first, count)`` of ``n_routed``.
+    A pick ``i >= n_routed`` is a zero-compute (identity) expert and adds
+    ``w_i * h`` with no matrix product; a pick on a held expert adds
+    ``w_i * Expert_i(h)``; a pick on an absent expert adds nothing here.
+    ``valid`` [N] masks tokens whose output is dead (pad positions, idle
+    slots): they route nowhere, so they make no expert be read.
+
+    Only routed pairs are multiplied: the (token, expert) pairs that land on
+    held experts are sorted by expert and go through ``jax.lax.ragged_dot``
+    (a grouped matrix product; on TPU a Mosaic kernel that visits only the
+    row tiles that hold pairs). The row buffer is ``N * k`` long, the most
+    that can land here, so no pick is ever dropped however uneven the load.
+
+    Returns (out [N, D] in ``h.dtype``, counts int32 [PICK_COUNTS], one a
+    name of ``PICK_COUNT_NAMES``)."""
+    N, D = h.shape
+    k = idx.shape[1]
+    first, count = held
+    F = w_down.shape[1]
+    live = jnp.ones((N,), bool) if valid is None else valid
+    live_k = live[:, None]
+
+    local = idx - first
+    on_held = (local >= 0) & (local < count) & live_k            # [N, k]
+    key = jnp.where(on_held, local, count).reshape(-1)           # [N*k]
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
+    tok = (jnp.arange(N * k, dtype=jnp.int32) // k)[order]
+    x = h[tok]                                                   # [N*k, D]
+    with jax.named_scope("moe_experts"):
+        gu = jax.lax.ragged_dot(x, w_gate_up, sizes,
+                                preferred_element_type=jnp.float32)
+        a = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
+        y = jax.lax.ragged_dot(a, w_down, sizes,
+                               preferred_element_type=jnp.float32)
+    # Rows past the last pair belong to no group: their product is not
+    # defined, so they are cut out before the weights touch them.
+    in_group = jnp.arange(N * k) < jnp.sum(sizes)
+    y = jnp.where(in_group[:, None], y, 0.0)
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    w_held = jnp.where(on_held, weights, 0.0)                    # [N, k]
+    out = jnp.einsum("nk,nkd->nd", w_held, y[back].reshape(N, k, D))
+
+    is_zero = (idx >= n_routed) & live_k
+    w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)  # [N]
+    out = out + w_zero[:, None] * h.astype(jnp.float32)
+
+    counts = {"picks": jnp.sum(live) * k, "picks_zero": jnp.sum(is_zero),
+              "picks_held": jnp.sum(on_held), "held_pairs_max": jnp.max(sizes),
+              "experts_hit": jnp.sum(sizes > 0)}
+    return out.astype(h.dtype), jnp.stack(
+        [counts[n] for n in PICK_COUNT_NAMES]).astype(jnp.int32)

@@ -79,6 +79,27 @@ def _last_block(first_pos, q_tile: int, block_tokens: int):
     return jax.lax.div(first_pos + q_tile - 1, block_tokens)
 
 
+def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int = 1,
+                         offset: int = 0, nb_seq: int = 0):
+    """The index map both kernels address the pool with: grid step ``j`` of
+    slot ``s``, query tile ``i`` maps to pool block ``tables[s, b]`` of layer
+    ``layer[0]``, ``b`` clamped onto the tile's last live block so that later
+    steps revisit it and cost no DMA. A kernel that takes ``step_blocks``
+    blocks a step passes the pool once per block, each with its ``offset``
+    (and ``nb_seq``, the table's width, which ``j * step_blocks + offset``
+    may pass)."""
+    def kv_index(s, i, j, tbl, ln, lyr):
+        last_blk = _last_block(ln[s] + i * q_tile, q_tile, block_tokens)
+        if step_blocks == 1:
+            blk = jnp.minimum(j, last_blk)
+        else:
+            blk = jnp.minimum(jnp.minimum(j * step_blocks + offset, last_blk),
+                              nb_seq - 1)
+        return (lyr[0], tbl[s, blk], 0, 0)
+
+    return kv_index
+
+
 def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
     """How many heads the kernel takes in one dot. The block's lanes are cut
     into chunks of G heads; a chunk's G*T query rows, each zero outside its
@@ -216,9 +237,7 @@ def paged_attention(
     qw = qw.reshape(S, H, q_tiles, tq, G * D).transpose(0, 2, 1, 3, 4)
     qw = qw.reshape(S, q_tiles, H * tq, G * D)
 
-    def kv_index(s, i, j, tbl, ln, lyr):
-        last_blk = _last_block(ln[s] + i * tq, tq, bt)
-        return (lyr[0], tbl[s, jnp.minimum(j, last_blk)], 0, 0)
+    kv_index = _clamped_block_index(tq, bt)
 
     def q_index(s, i, j, tbl, ln, lyr):
         return (s, i, 0, 0)
@@ -274,4 +293,161 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
     scores = jnp.where(kv_pos <= q_pos, scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhts,bshd->bthd", probs, vc.astype(jnp.float32))
+    return out.astype(q.dtype)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention (MLA) over a paged pool of latent rows
+# ---------------------------------------------------------------------------
+# One row a token a sublayer: ``[c_kv (value_lanes) | k_rope | zero pad]``,
+# shared by every query head. Keys are the whole row, values its first
+# ``value_lanes`` lanes, and the up-projections are absorbed into q and into
+# the output by the caller, so the kernel's dot is a real ``[T*H, W] x
+# [W, tokens]`` product: the rows are the heads, where the kernel above needs
+# a block-diagonal q to give each head its own lanes. Same grid (slots, query
+# tiles, table steps, kv innermost), same scalar prefetch (tables, lengths,
+# layer) and the same clamped index map; a grid step takes
+# ``_LATENT_STEP_BLOCKS`` pool blocks (the pool handed in once per block),
+# because one 16-token block of one shared row is 20 KB, far too little work
+# for a step's fixed cost.
+
+_LATENT_STEP_BLOCKS = 8      # x 16-token blocks = one 128-lane row of scores
+_LATENT_Q_TILE = 16          # x 64 heads = 1024 rows of f32 accumulators
+
+
+def _latent_kernel(
+    tables_ref, lengths_ref, layer_ref,   # scalar prefetch, as _paged_kernel
+    q_ref,                                # [1, T*H, W] block; row = (query, head)
+    *rest,                                # G pool blocks [1, bt, W]; out; scratch
+    scale: float,
+    block_tokens: int,
+    num_heads: int,
+    q_tile: int,
+    nb_steps: int,
+    step_blocks: int,
+    value_lanes: int,
+):
+    kv_refs = rest[:step_blocks]
+    o_ref = rest[step_blocks]              # [1, T*H, value_lanes]
+    m_scr, l_scr, acc_scr = rest[step_blocks + 1:]
+    s = pl.program_id(0)
+    i = pl.program_id(1)
+    j = pl.program_id(2)
+    bt, H, T, G = block_tokens, num_heads, q_tile, step_blocks
+    ctx = lengths_ref[s] + i * T
+    last_blk = _last_block(ctx, T, bt)
+
+    @pl.when(j == 0)
+    def _init():
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+        acc_scr[:] = jnp.zeros_like(acc_scr)
+
+    @pl.when(j * G <= last_blk)
+    def _body():
+        rows = T * H
+        kv = jnp.concatenate([r[0] for r in kv_refs], axis=0)   # [G*bt, W]
+        scores = jax.lax.dot_general(
+            q_ref[0], kv, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale         # [rows, G*bt]
+        kv_pos = j * (G * bt) + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, G * bt), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, G * bt), 0)
+        q_pos = ctx + (0 if T == 1 else jax.lax.div(row, H))
+        scores = jnp.where(kv_pos <= q_pos, scores, _NEG_INF)
+        m_prev = m_scr[:]
+        m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)
+        l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p.astype(kv.dtype), kv[:, :value_lanes], (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [rows, V]
+        acc_scr[:] = acc_scr[:] * alpha + pv
+        m_scr[:] = m_new
+
+    @pl.when(j == nb_steps - 1)
+    def _finalize():
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(
+            o_ref.dtype)
+
+
+def latent_paged_attention(
+    q: jax.Array,                # [S, T, H, W] — absorbed queries, W = row width
+    pool: jax.Array,             # [A, num_blocks, bt, W] latent rows, A sublayers
+    tables: jax.Array,           # [S, NB] int32
+    lengths: jax.Array,          # [S] int32 — valid context BEFORE the T tokens
+    layer,                       # which attention sublayer's blocks
+    *,
+    value_lanes: int,
+    scale: float,
+    interpret: bool = False,
+) -> jax.Array:
+    """Softmax over the latent rows, returns ``sum_j p_j row_j[:value_lanes]``
+    as [S, T, H, value_lanes]: the caller up-projects it per head. Query t of
+    slot s sits at ``lengths[s] + t`` and attends positions ``<=`` it; the T
+    new rows must already be in the pool."""
+    S, T, H, W = q.shape
+    if pool.ndim != 4 or pool.shape[3] != W:
+        raise ValueError(f"pool {pool.shape} is not [A, num_blocks, bt, {W}]")
+    bt = pool.shape[2]
+    nb_seq = tables.shape[1]
+    G = _LATENT_STEP_BLOCKS
+    nb_steps = pl.cdiv(nb_seq, G)
+    tq = min(T, _LATENT_Q_TILE)
+    q_tiles = pl.cdiv(T, tq)
+    if T % tq:
+        q = jnp.pad(q, ((0, 0), (0, q_tiles * tq - T), (0, 0), (0, 0)))
+    qr = q.reshape(S, q_tiles, tq * H, W)
+    tables = tables.astype(jnp.int32)
+    lengths = lengths.astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+
+    def q_index(s, i, j, tbl, ln, lyr):
+        return (s, i, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(S, q_tiles, nb_steps),
+        in_specs=[pl.BlockSpec((None, 1, tq * H, W), q_index)] + [
+            pl.BlockSpec((None, 1, bt, W),
+                         _clamped_block_index(tq, bt, G, g, nb_seq))
+            for g in range(G)],
+        out_specs=pl.BlockSpec((None, 1, tq * H, value_lanes), q_index),
+        scratch_shapes=[
+            pltpu.VMEM((tq * H, 1), jnp.float32),
+            pltpu.VMEM((tq * H, 1), jnp.float32),
+            pltpu.VMEM((tq * H, value_lanes), jnp.float32),
+        ],
+    )
+    out = pl.pallas_call(
+        functools.partial(
+            _latent_kernel, scale=scale, block_tokens=bt, num_heads=H,
+            q_tile=tq, nb_steps=nb_steps, step_blocks=G,
+            value_lanes=value_lanes),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, q_tiles, tq * H, value_lanes),
+                                       q.dtype),
+        interpret=interpret,
+        name="mla_decode_attn" if T == 1 else "mla_prefill_attn",
+    )(tables, lengths, layer, qr, *([pool] * G))
+    return out.reshape(S, q_tiles * tq, H, value_lanes)[:, :T]
+
+
+def latent_paged_attention_reference(q, pool, tables, lengths, layer, *,
+                                     value_lanes: int, scale: float) -> jax.Array:
+    """Gather-path oracle of :func:`latent_paged_attention` (and the CPU
+    path of the serve programs): the table's rows gathered into
+    [S, NB*bt, W], masked dense attention over them."""
+    S, T, H, W = q.shape
+    bt = pool.shape[2]
+    n = tables.shape[1] * bt
+    rows = pool[layer, tables].reshape(S, n, W)
+    scores = jnp.einsum("sthw,snw->shtn", q, rows,
+                        preferred_element_type=jnp.float32) * scale
+    kv_pos = jnp.arange(n)[None, None, None, :]
+    q_pos = lengths.reshape(-1, 1, 1, 1) + jnp.arange(T)[None, None, :, None]
+    probs = jax.nn.softmax(jnp.where(kv_pos <= q_pos, scores, _NEG_INF), axis=-1)
+    out = jnp.einsum("shtn,snv->sthv", probs,
+                     rows[..., :value_lanes].astype(jnp.float32))
     return out.astype(q.dtype)

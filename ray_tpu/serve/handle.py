@@ -273,24 +273,40 @@ class Router:
         total = load.get("slots_total", 0)
         return total > 0 and load.get("slots_busy", 0) >= total
 
-    def _all_shedding(self, replicas) -> bool:
-        """Admission control: shed (fast Saturated) only when EVERY replica
-        reports an admission queue at/over ``serve_admission_queue_limit`` —
-        a replica with headroom, or one that doesn't report a queue at all
-        (non-engine deployments), keeps the blocking-queue behavior."""
+    def _queue_overage(self, replica) -> Optional[float]:
+        """How far ``replica``'s admission queue is over its shed limit
+        (negative: headroom); None when it reports no queue (a non-engine
+        deployment) or sheds nothing. The limit is the replica's own
+        (``queue_limit``: the engine's ``max_queue``), else the
+        ``serve_admission_queue_limit`` knob; the queue is what waits
+        beyond the free slots, as the engine counts it: a burst onto idle
+        slots is admitted over a few steps and waits for no slot."""
         from ray_tpu.core.config import config
 
-        try:
-            limit = config().serve_admission_queue_limit
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            return False
-        if not limit or not replicas:
+        load = self._replica_load.get(self._key(replica))
+        if not load or load.get("queue_depth") is None:
+            return None
+        limit = load.get("queue_limit")
+        if limit is None:
+            try:
+                limit = config().serve_admission_queue_limit
+            except Exception:  # noqa: BLE001 — config unavailable mid-teardown
+                return None
+        if not limit:
+            return None
+        free = max(0.0, load.get("slots_total", 0) - load.get("slots_busy", 0))
+        return load["queue_depth"] - free - limit
+
+    def _all_shedding(self, replicas) -> bool:
+        """Admission control: shed (fast Saturated) only when EVERY replica
+        reports an admission queue at/over its limit (``_queue_overage``) —
+        a replica with headroom, or one that doesn't report a queue at all
+        (non-engine deployments), keeps the blocking-queue behavior."""
+        if not replicas:
             return False
         for r in replicas:
-            load = self._replica_load.get(self._key(r))
-            if not load or load.get("queue_depth") is None:
-                return False
-            if load["queue_depth"] < limit:
+            over = self._queue_overage(r)
+            if over is None or over < 0:
                 return False
         return True
 
@@ -380,26 +396,19 @@ class Router:
 
     def _retry_after_hint(self, replicas) -> Optional[float]:
         """Backoff hint for a saturated shed: how long the LEAST-loaded
-        replica's admission queue likely needs to drain back under the
+        replica's admission queue likely needs to drain back under its
         limit, at serve_retry_after_item_s per queued item. Advisory."""
         from ray_tpu.core.config import config
 
+        overs = [o for o in map(self._queue_overage, replicas)
+                 if o is not None]
+        if not overs:
+            return None
         try:
-            cfg = config()
-            limit = cfg.serve_admission_queue_limit
-            item_s = cfg.serve_retry_after_item_s
+            item_s = config().serve_retry_after_item_s
         except Exception:  # noqa: BLE001 — config unavailable mid-teardown
             return None
-        if not limit:
-            return None
-        depths = []
-        for r in replicas:
-            load = self._replica_load.get(self._key(r))
-            if load and load.get("queue_depth") is not None:
-                depths.append(load["queue_depth"])
-        if not depths:
-            return None
-        return max(1, min(depths) - limit + 1) * item_s
+        return max(1, min(overs) + 1) * item_s
 
     # -- metrics push (feeds autoscaling) ------------------------------------
     def total_ongoing(self) -> int:

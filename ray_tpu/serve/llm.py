@@ -21,7 +21,8 @@ There is no engine thread: the step loop is driven by whichever request
 thread wins a non-blocking try-lock (``drive``), so an idle engine owns no
 resources (leak-check clean) and a busy one is stepped exactly as fast as
 its consumers read. Admission control sheds with :class:`~ray_tpu.serve.
-errors.Saturated` once ``max_queue`` requests are already waiting.
+errors.Saturated` once ``max_queue`` requests are already waiting for a
+slot (beyond those the free slots will take over the next steps).
 
 Prompt bucketing is unchanged from the single-sequence engine: prompts pad
 to a power-of-two bucket (one prefill compile per bucket, warmed at replica
@@ -43,7 +44,8 @@ import numpy as np
 
 from ray_tpu.devtools import jitcheck
 from ray_tpu.models.generate import (KVBlockManager, NoFreeBlocks,
-                                     PagedGenerator, SlottedGenerator)
+                                     PagedGenerator, SlottedGenerator,
+                                     paged_family)
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.serve.errors import Saturated
 from ray_tpu.util import tracing
@@ -386,6 +388,11 @@ class LLMEngine:
     # distribution, the streaming contract — is engine-agnostic; everything
     # cache-layout-specific funnels through this narrow seam.
     def _init_device(self) -> None:
+        if not isinstance(self.config, TransformerConfig):
+            raise ValueError(
+                f"{type(self.config).__name__}: the slotted engine runs the "
+                f"GPT-2 family only; serve this family through the paged "
+                f"engine (serve_kv_paged_enabled=1)")
         self._sg = SlottedGenerator(self.params, self.config,
                                     slots=self.slots, max_len=self.max_len)
         self._cache, self._last, self._keys = self._sg.init_state()
@@ -431,6 +438,14 @@ class LLMEngine:
         """Extra attrs for the step's ``llm.step`` span (the speculative
         engine reports the step's proposed/accepted counts)."""
         return None
+
+    def _take_step_aux(self):
+        """Device arrays to fetch WITH the step's tokens, in its one
+        ``device_get`` (the paged engine: the family's per-call counts)."""
+        return None
+
+    def _fold_step_aux(self, host_aux, st: "_StepTrace") -> None:
+        """Fold what ``_take_step_aux`` handed over, now on the host."""
 
     def _release_slot_device(self, slot: int) -> None:
         """Per-slot device-side cleanup when a slot frees (paged: unpin the
@@ -523,7 +538,8 @@ class LLMEngine:
     def submit(self, prompt_ids: Sequence[int], *, max_new_tokens: int = 32,
                temperature: float = 0.0, seed: int = 0) -> _Request:
         """Validate + enqueue; raises :class:`Saturated` when ``max_queue``
-        requests are already waiting for a slot (0 disables shedding)."""
+        requests are already waiting for a slot, beyond those the free slots
+        will take (0 disables shedding)."""
         prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
         real_len = int(prompt.shape[0])
         if real_len == 0:
@@ -540,9 +556,13 @@ class LLMEngine:
             req.finish_reason = "stop"
             return req
         with self._state_lock:
-            if self.max_queue and len(self._waiting) >= self.max_queue:
-                raise _shed(self.name, len(self._waiting), self.max_queue,
-                            "already waiting")
+            # A waiting request that a free slot is there for waits for the
+            # step's prefill budget, not for a slot: a burst onto idle slots
+            # is admitted over a few steps and must not be shed.
+            backlog = len(self._waiting) - self._slot_req.count(None)
+            if self.max_queue and backlog >= self.max_queue:
+                raise _shed(self.name, backlog, self.max_queue,
+                            "already waiting for a slot")
             self._waiting.append(req)
         return req
 
@@ -836,7 +856,7 @@ class LLMEngine:
         t0_ns = st.marks[-1][1]
         toks = self._run_decode(active, greedy, temps, extra)
         st.enter("device_wait")
-        host_toks = jax.device_get(toks)
+        host_toks, host_aux = jax.device_get((toks, self._take_step_aux()))
         st.enter("deliver")
         now_ns = st.marks[-1][1]
         dt = (now_ns - t0_ns) / 1e9
@@ -896,6 +916,7 @@ class LLMEngine:
         spec = self._step_spec_attrs()
         if spec:
             st.attrs.update(spec)
+        self._fold_step_aux(host_aux, st)
         st.enter("observe")
         self._observe(delivered_total, ttfts)
 
@@ -926,8 +947,11 @@ class LLMEngine:
         with self._state_lock:
             busy = sum(1 for r in self._slot_req if r is not None)
             depth = len(self._waiting)
+        # ``queue_limit`` is this engine's own ``max_queue``: the router
+        # sheds at it, not at the global knob it defaults to.
         out = {"slots_total": float(self.slots), "slots_busy": float(busy),
-               "queue_depth": float(depth)}
+               "queue_depth": float(depth),
+               "queue_limit": float(self.max_queue)}
         # Cumulative counts at the step's boundaries: they count with
         # tracing off too. ``steps_total`` counts steps that dispatched a
         # decode; ``admit_blocked_pool_s`` is the time from the start of a
@@ -984,16 +1008,23 @@ class PagedLLMEngine(LLMEngine):
       the request rather than failing it.
     """
 
-    def __init__(self, params, config: TransformerConfig, *,
+    def __init__(self, params, config, *,
                  block_tokens: Optional[int] = None,
                  pool_blocks: Optional[int] = None,
                  attention_kernel: Optional[str] = None,
                  draft_params=None,
-                 draft_config: Optional[TransformerConfig] = None,
+                 draft_config=None,
                  spec_tokens: Optional[int] = None, **kw):
         from ray_tpu.core.config import config as _get_config
+        from ray_tpu.serve.kv_tier import kv_tier_enabled
 
         knobs = _get_config()
+        # What the family cannot run yet is refused HERE, not in a step
+        # (a draft model: by the generator, in ``_init_device``).
+        if kv_tier_enabled() and "kv_tier" in paged_family(config).unsupported:
+            raise ValueError(
+                f"{type(config).__name__}: the cluster KV tier "
+                f"(kv_tier_enabled) is not supported for this family yet")
         self.block_tokens = int(block_tokens if block_tokens is not None
                                 else knobs.serve_kv_block_tokens)
         self._pool_blocks_cfg = int(pool_blocks if pool_blocks is not None
@@ -1029,8 +1060,14 @@ class PagedLLMEngine(LLMEngine):
                                   draft_params=self._draft_params,
                                   draft_config=self._draft_config)
         self.kv = KVBlockManager(num_blocks, self.block_tokens)
-        (self._k_pool, self._v_pool,
-         self._last, self._keys) = self._pg.init_state()
+        self._pool, self._last, self._keys = self._pg.init_state()
+        # The family's per-call counts (None for GPT-2): the decode call's
+        # and this step's prefills', fetched with the step's tokens.
+        self._decode_aux = None
+        self._prefill_aux: List = []
+        self._aux_totals = {
+            key: 0 for n in self._pg.family.aux_counts
+            for key in (n.decode, n.prefill) if key}
         self._slot_table = np.zeros((self.slots, self.blocks_per_seq),
                                     np.int32)
         self._slot_blocks: List[List[int]] = [[] for _ in range(self.slots)]
@@ -1086,7 +1123,7 @@ class PagedLLMEngine(LLMEngine):
         # _state_lock, which the step thread also holds there).
         if not self._spec:
             return
-        self._kd_pool, self._vd_pool = self._pg.init_draft_state()
+        self._draft_pool = self._pg.init_draft_state()
         self._spec_tail = np.zeros(self.slots, np.int32)
         self._spec_pending = np.zeros(self.slots, np.int32)
         self._spec_use_pending = np.zeros(self.slots, bool)
@@ -1102,8 +1139,8 @@ class PagedLLMEngine(LLMEngine):
         self._spec_accepted_total = 0
 
     def _reset_device_state(self) -> None:
-        (self._k_pool, self._v_pool,
-         self._last, self._keys) = self._pg.init_state()
+        self._pool, self._last, self._keys = self._pg.init_state()
+        self._decode_aux, self._prefill_aux = None, []
         # Pool contents are gone — the prefix cache resets with it. Queued
         # spill entries and tracked chains point into the dead pool, so
         # they go too (their pins die with the replaced manager); chains
@@ -1124,16 +1161,14 @@ class PagedLLMEngine(LLMEngine):
             for b in self.buckets:
                 with wt.program("paged_prefill", b):
                     pf = self._pg.prefill_fn(b)
-                    (self._k_pool, self._v_pool,
-                     self._last, self._keys) = pf(
-                        self.params, self._k_pool, self._v_pool, self._last,
+                    self._pool, self._last, self._keys, _aux = pf(
+                        self.params, self._pool, self._last,
                         self._keys, zero_row, np.zeros((1, b), np.int32),
                         0, b, 0, 0)
             with wt.program("paged_decode"):
                 df = self._pg.decode_fn(self.chunk)
-                (toks, self._k_pool, self._v_pool,
-                 self._last, self._keys) = df(
-                    self.params, self._k_pool, self._v_pool, self._last,
+                toks, self._pool, self._last, self._keys, _aux = df(
+                    self.params, self._pool, self._last,
                     self._keys, np.zeros((self.slots, self.blocks_per_seq),
                                          np.int32),
                     np.zeros(self.slots, np.int32),
@@ -1141,8 +1176,7 @@ class PagedLLMEngine(LLMEngine):
                 np.asarray(toks)
             with wt.program("copy_block"):
                 cf = self._pg.copy_fn()
-                self._k_pool, self._v_pool = cf(self._k_pool, self._v_pool,
-                                                0, 0)
+                self._pool = cf(self._pool, 0, 0)
             # The handoff attach program (set_last) runs mid-step when a
             # prefilled request lands — compile it here, not on its TTFT.
             with wt.program("set_last"):
@@ -1155,25 +1189,23 @@ class PagedLLMEngine(LLMEngine):
                 # replica's first store fetch never pays XLA on its TTFT
                 # (block 0 is the padding block — inserting zeros is inert).
                 with wt.program("kv_tier_blocks"):
-                    zb = np.zeros((self._k_pool.shape[0], 1)
-                                  + tuple(self._k_pool.shape[2:]),
-                                  self._k_pool.dtype)
+                    k_pool = self._pool[0]
+                    zb = np.zeros((k_pool.shape[0], 1)
+                                  + tuple(k_pool.shape[2:]), k_pool.dtype)
                     self._tier_insert_blocks(zb, zb, [0])
                     self._tier_extract_blocks([0])
             if self._spec:
                 for b in self.buckets:
                     with wt.program("draft_prefill", b):
                         dpf = self._pg.draft_prefill_fn(b)
-                        self._kd_pool, self._vd_pool = dpf(
-                            self._draft_params, self._kd_pool, self._vd_pool,
+                        self._draft_pool = dpf(
+                            self._draft_params, self._draft_pool,
                             zero_row, np.zeros((1, b), np.int32), 0, b)
-                self._kd_pool, self._vd_pool = cf(self._kd_pool,
-                                                  self._vd_pool, 0, 0)
+                self._draft_pool = cf(self._draft_pool, 0, 0)
                 with wt.program("spec_decode"):
                     sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
-                    out = sf(self.params, self._draft_params, self._k_pool,
-                             self._v_pool, self._kd_pool, self._vd_pool,
-                             self._last, self._keys,
+                    out = sf(self.params, self._draft_params, self._pool,
+                             self._draft_pool, self._last, self._keys,
                              np.zeros((self.slots, self.blocks_per_seq),
                                       np.int32),
                              np.zeros(self.slots, np.int32),
@@ -1183,8 +1215,8 @@ class PagedLLMEngine(LLMEngine):
                              np.zeros(self.slots, np.int32),
                              np.zeros(self.slots, bool))
                     np.asarray(out[0])
-                (self._k_pool, self._v_pool, self._kd_pool, self._vd_pool,
-                 self._last, self._keys) = out[3:9]
+                (self._pool, self._draft_pool,
+                 self._last, self._keys) = out[3:7]
             self._reset_device_state()
             self._steady = True
         wt.close()
@@ -1259,13 +1291,11 @@ class PagedLLMEngine(LLMEngine):
         if tail is not None:
             dst = fresh.pop(0)
             cf = self._pg.copy_fn()
-            self._k_pool, self._v_pool = cf(self._k_pool, self._v_pool,
-                                            int(tail), int(dst))
+            self._pool = cf(self._pool, int(tail), int(dst))
             if self._spec:
                 # The draft pool mirrors the block tables, so a COW fork
                 # must duplicate the draft-side content of the tail too.
-                self._kd_pool, self._vd_pool = cf(
-                    self._kd_pool, self._vd_pool, int(tail), int(dst))
+                self._draft_pool = cf(self._draft_pool, int(tail), int(dst))
             self.kv.note_cow()
             self.kv.release([tail])  # pin the private copy, not the original
             ids.append(dst)
@@ -1290,15 +1320,17 @@ class PagedLLMEngine(LLMEngine):
         padded = np.zeros((1, req.bucket), np.int32)
         padded[0, :suffix_len] = req.prompt[hit_len:]
         pf = self._pg.prefill_fn(req.bucket)
-        (self._k_pool, self._v_pool, self._last, self._keys) = pf(
-            self.params, self._k_pool, self._v_pool, self._last, self._keys,
+        self._pool, self._last, self._keys, aux = pf(
+            self.params, self._pool, self._last, self._keys,
             row, padded, hit_len, suffix_len, slot, req.seed)
+        if aux is not None:
+            self._prefill_aux.append(aux)
         if self._spec:
             # Warm the draft pool over the same suffix/table so the draft
             # chain starts from draft-KV covering every committed position.
             dpf = self._pg.draft_prefill_fn(req.bucket)
-            self._kd_pool, self._vd_pool = dpf(
-                self._draft_params, self._kd_pool, self._vd_pool, row,
+            self._draft_pool = dpf(
+                self._draft_params, self._draft_pool, row,
                 padded, hit_len, suffix_len)
         # Commit ATOMICALLY with the cancel path: this runs outside
         # _state_lock, so a concurrent _cancel may have freed the slot
@@ -1455,9 +1487,9 @@ class PagedLLMEngine(LLMEngine):
         if not self._spec:
             tables, lengths = extra
             df = self._pg.decode_fn(self.chunk)
-            (toks, self._k_pool, self._v_pool,
-             self._last, self._keys) = df(
-                self.params, self._k_pool, self._v_pool, self._last,
+            (toks, self._pool, self._last, self._keys,
+             self._decode_aux) = df(
+                self.params, self._pool, self._last,
                 self._keys, tables, lengths, active, greedy, temps)
             return toks
         tables, lengths, spec_on, tail, pending, use_pending = extra
@@ -1469,9 +1501,9 @@ class PagedLLMEngine(LLMEngine):
             # more spec step, which consumes its pending token and clears
             # the carry.)
             df = self._pg.decode_fn(self.chunk)
-            (toks, self._k_pool, self._v_pool,
-             self._last, self._keys) = df(
-                self.params, self._k_pool, self._v_pool, self._last,
+            (toks, self._pool, self._last, self._keys,
+             self._decode_aux) = df(
+                self.params, self._pool, self._last,
                 self._keys, tables, lengths, active, greedy, temps)
             self._last_counts = None
             self._spec_last_accept[:] = 0
@@ -1479,11 +1511,11 @@ class PagedLLMEngine(LLMEngine):
             return toks
         sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
         t0 = time.perf_counter()
-        (toks, counts, accepted, self._k_pool, self._v_pool, self._kd_pool,
-         self._vd_pool, self._last, self._keys, tail_j, pending_j,
+        (toks, counts, accepted, self._pool, self._draft_pool,
+         self._last, self._keys, tail_j, pending_j,
          up_j) = sf(
-            self.params, self._draft_params, self._k_pool, self._v_pool,
-            self._kd_pool, self._vd_pool, self._last, self._keys, tables,
+            self.params, self._draft_params, self._pool,
+            self._draft_pool, self._last, self._keys, tables,
             lengths, active, greedy, temps, spec_on, tail, pending,
             use_pending)
         # One batched fetch syncs the step: counts/accepted plus the spec
@@ -1537,6 +1569,31 @@ class PagedLLMEngine(LLMEngine):
         return {"spec_proposed": int(on.sum()) * self.chunk * self.spec_k,
                 "spec_accepted": int(self._spec_last_accept[on].sum()),
                 "spec_s": self._spec_last_dt}
+
+    def _take_step_aux(self):
+        aux, self._decode_aux = self._decode_aux, None
+        pre, self._prefill_aux = self._prefill_aux, []
+        return None if aux is None and not pre else (aux, pre)
+
+    def _fold_step_aux(self, host_aux, st: _StepTrace) -> None:
+        # The family's counts, by the names it gives them (``PagedFamily.
+        # aux_counts``): the decode chunk's go to its ``stats()`` keys and
+        # onto the ``llm.step`` span, a prefill's to keys of their own.
+        if host_aux is None:
+            return
+        aux, pre = host_aux
+        names = self._pg.family.aux_counts
+        with self._agg_lock:
+            totals = self._aux_totals
+            if aux is not None:
+                for n, v in zip(names, aux, strict=True):
+                    totals[n.decode] += int(v)
+                    if n.step_attr:
+                        st.attrs[n.step_attr] = int(v)
+            for p in pre:
+                for n, v in zip(names, p, strict=True):
+                    if n.prefill:
+                        totals[n.prefill] += int(v)
 
     def _release_slot_device(self, slot: int) -> None:
         ids = self._slot_blocks[slot]
@@ -1616,8 +1673,7 @@ class PagedLLMEngine(LLMEngine):
             if tail is not None:
                 dst = fresh.pop(0)
                 cf = self._pg.copy_fn()
-                self._k_pool, self._v_pool = cf(self._k_pool, self._v_pool,
-                                                int(tail), int(dst))
+                self._pool = cf(self._pool, int(tail), int(dst))
                 self.kv.note_cow()
                 self.kv.release([tail])
                 ids.append(dst)
@@ -1629,11 +1685,11 @@ class PagedLLMEngine(LLMEngine):
             padded = np.zeros((1, bucket), np.int32)
             padded[0, :suffix_len] = prompt[hit_len:]
             pf = self._pg.prefill_fn(bucket)
-            (self._k_pool, self._v_pool, self._last, self._keys) = pf(
-                self.params, self._k_pool, self._v_pool, self._last,
+            self._pool, self._last, self._keys, _aux = pf(
+                self.params, self._pool, self._last,
                 self._keys, row, padded, hit_len, suffix_len, 0, seed)
             ef = self._pg.extract_fn(len(ids))
-            k, v = ef(self._k_pool, self._v_pool, np.asarray(ids, np.int32))
+            k, v = ef(self._pool, np.asarray(ids, np.int32))
             k = np.asarray(k)
             v = np.asarray(v)
             last_row = np.asarray(self._last[0])
@@ -1688,10 +1744,10 @@ class PagedLLMEngine(LLMEngine):
                 time.sleep(0.002)  # in-flight retires free blocks
         with self._step_lock:
             inf = self._pg.insert_fn(nb_in)
-            self._k_pool, self._v_pool = inf(
-                self._k_pool, self._v_pool, np.asarray(k), np.asarray(v),
+            self._pool = inf(
+                self._pool, (np.asarray(k), np.asarray(v)),
                 np.asarray(ids[:nb_in], np.int32))
-            jax.block_until_ready(self._k_pool)
+            jax.block_until_ready(self._pool)
         # Publish the prompt's full blocks for LOCAL hits too — a colocated
         # follow-up (or affinity-routed repeat) skips the handoff entirely.
         tokens = [int(t) for t in prompt]
@@ -1723,10 +1779,10 @@ class PagedLLMEngine(LLMEngine):
         length, right on the cold-fetch TTFT path."""
         inf = self._pg.insert_fn(1)
         for i, b in enumerate(ids):
-            self._k_pool, self._v_pool = inf(
-                self._k_pool, self._v_pool,
-                np.ascontiguousarray(k_in[:, i:i + 1]),
-                np.ascontiguousarray(v_in[:, i:i + 1]),
+            self._pool = inf(
+                self._pool,
+                (np.ascontiguousarray(k_in[:, i:i + 1]),
+                 np.ascontiguousarray(v_in[:, i:i + 1])),
                 np.asarray([b], np.int32))
 
     def _tier_extract_blocks(self, ids):
@@ -1736,7 +1792,7 @@ class PagedLLMEngine(LLMEngine):
         ef = self._pg.extract_fn(1)
         ks, vs = [], []
         for b in ids:
-            k, v = ef(self._k_pool, self._v_pool, np.asarray([b], np.int32))
+            k, v = ef(self._pool, np.asarray([b], np.int32))
             ks.append(np.asarray(k))
             vs.append(np.asarray(v))
         return np.concatenate(ks, axis=1), np.concatenate(vs, axis=1)
@@ -1784,7 +1840,7 @@ class PagedLLMEngine(LLMEngine):
                 time.sleep(0.002)  # in-flight retires free blocks
         with self._step_lock:
             self._tier_insert_blocks(k, v, ids)
-            jax.block_until_ready(self._k_pool)
+            jax.block_until_ready(self._pool)
         self.kv.register_chain(tokens, ids, n_real)
         self.kv.release(ids)  # ACTIVE -> CACHED: pure prefix-cache state
         from ray_tpu.util import blockhash
@@ -1909,6 +1965,8 @@ class PagedLLMEngine(LLMEngine):
             out["spec_proposed_total"] = float(prop)
             out["spec_accepted_total"] = float(acc)
             out["spec_accept_ratio"] = float(acc) / prop if prop else 0.0
+        with self._agg_lock:
+            out.update({k: float(v) for k, v in self._aux_totals.items()})
         return out
 
     def describe(self) -> Dict:
@@ -1916,8 +1974,10 @@ class PagedLLMEngine(LLMEngine):
         out["attention_kernel"] = self._pg.attention_kernel  # as resolved
         out["block_tokens"] = self.block_tokens
         out["pool_blocks"] = self.kv.num_blocks
+        out["model_family"] = type(self.config).__name__
+        out["kv_pool_shapes"] = [list(a.shape) for a in self._pool]
         out["kv_pool_devices"] = sorted(
-            str(d) for d in self._k_pool.devices())
+            {str(d) for a in self._pool for d in a.devices()})
         return out
 
     def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
@@ -2037,7 +2097,7 @@ class DisaggregatedLLMEngine:
     decode live in separate replicas.
     """
 
-    def __init__(self, params, config: TransformerConfig, *,
+    def __init__(self, params, config, *,
                  max_len: Optional[int] = None,
                  prompt_buckets: Optional[Sequence[int]] = None,
                  chunk: int = 8, slots: Optional[int] = None,
@@ -2048,6 +2108,12 @@ class DisaggregatedLLMEngine:
                  lane_slots: int = 4):
         from ray_tpu.core.config import config as _get_config
         from ray_tpu.serve.dag_pipeline import KVHandoffLane
+
+        if "disaggregation" in paged_family(config).unsupported:
+            raise ValueError(
+                f"{type(config).__name__}: prefill/decode disaggregation "
+                f"(serve_disaggregation_enabled) is not supported for this "
+                f"family yet")
 
         knobs = _get_config()
         self.name = name
@@ -2288,6 +2354,7 @@ class DisaggregatedLLMEngine:
         with self._cv:
             out["queue_depth"] += float(len(self._pq)
                                         + len(self._lane_fifo))
+        out["queue_limit"] = float(self.max_queue)  # the inner engines' is 0
         pf = self.prefill.kv.stats()
         out["prefill_kv_hit_tokens"] = pf["kv_hit_tokens"]
         out["prefill_kv_blocks_cached"] = pf["kv_blocks_cached"]
@@ -2333,7 +2400,7 @@ class DisaggregatedLLMEngine:
 
 
 def llm_deployment(
-    config: TransformerConfig,
+    config,
     params_fn: Callable[[], Dict],
     *,
     name: str = "LLM",
@@ -2341,12 +2408,19 @@ def llm_deployment(
     slots: Optional[int] = None,
     chunk: int = 8,
     max_queue: Optional[int] = None,
-    draft_config: Optional[TransformerConfig] = None,
+    draft_config=None,
     draft_params_fn: Optional[Callable[[], Dict]] = None,
     **deployment_kwargs,
 ):
     """Build a Serve deployment class around a continuous-batching
     :class:`LLMEngine`.
+
+    ``config`` is a model family's config object: a
+    ``models.transformer.TransformerConfig`` (GPT-2) or a
+    ``models.longcat.LongCatConfig``; the paged engine finds the family's
+    pool and forward pass through it (``models.generate.PagedFamily``), and
+    what a family cannot run yet (a draft model, disaggregation, the KV
+    tier) raises when the replica builds its engine.
 
     ``params_fn`` runs inside the replica (checkpoint load / init) so weights
     never ship through the controller. Request payload::
@@ -2369,7 +2443,7 @@ def llm_deployment(
 
     from ray_tpu import serve
     from ray_tpu.core.config import config as _get_config  # `config` is the
-    # model's TransformerConfig here
+    # model's config object here
 
     knobs = _get_config()
     n_slots = int(slots if slots is not None else knobs.serve_llm_slots)
@@ -2379,6 +2453,12 @@ def llm_deployment(
     # slot set plus a shed-depth of waiters plus control-plane calls.
     deployment_kwargs.setdefault(
         "max_concurrency", n_slots + max(q_limit, 4) + 4)
+    # The handle lets through what the engine can hold: a full slot set plus
+    # its admission queue (the engine sheds beyond that). The deployment
+    # default of 100 would keep a 128-slot engine a fifth empty with
+    # callers blocked at the router.
+    deployment_kwargs.setdefault(
+        "max_ongoing_requests", max(100, n_slots + q_limit))
 
     @serve.deployment(name=name, **deployment_kwargs)
     class LLMServer:

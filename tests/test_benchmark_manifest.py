@@ -1,0 +1,30 @@
+"""Tier-1's guard of what the benchmark needs from the program.
+
+The benchmark's own tests live in ``benchmark/tests/`` (``python -m pytest
+benchmark/tests -q``), which the tier-1 command does not run. This thin file
+runs, from there, the checks of the shipped manifest, the newest
+configuration's counts against hand-worked numbers and the ``--rehearse``
+run of its cell, so that a PR that breaks what a cell reads from the program
+(a program's name, a counter, the family seam) fails tier-1."""
+
+import pytest
+
+pytest.register_assert_rewrite("benchmark.tests.test_manifest",
+                               "benchmark.tests.test_longcat_cell")
+
+from benchmark.tests.test_longcat_cell import (  # noqa: E402,F401
+    config,
+    test_counter_readers_by_hand,
+    test_counts_by_hand,
+    test_readers_find_nothing_on_a_program_without_the_counters,
+    test_rehearsal_of_the_cell,
+    test_the_file_states_the_cut_and_every_published_width,
+    test_the_rehearsal_overlay_is_the_tiny_models_sizes,
+)
+from benchmark.tests.test_manifest import (  # noqa: E402,F401
+    test_check_names_a_configuration_that_is_not_whole,
+    test_every_cell_resolves_and_every_moves_is_reported,
+    test_names_units_and_entry_keys,
+    test_no_driver_or_reader_names_an_architecture,
+    test_shipped_manifest_is_sound,
+)

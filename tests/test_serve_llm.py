@@ -208,6 +208,24 @@ class TestAdmissionControl:
             oracle(PROMPTS[2], 4)
 
 
+    def test_a_burst_onto_idle_slots_is_not_shed(self, tiny_model):
+        """The queue that ``max_queue`` bounds is what waits beyond the free
+        slots: 4 idle slots and ``max_queue`` 2 hold a burst of 6 (four wait
+        for the prefill budget, two for a slot), and shed the seventh."""
+        cfg, params = tiny_model
+        eng = LLMEngine(params, cfg, prompt_buckets=(16,), chunk=4, slots=4,
+                        max_queue=2, name="burst")
+        held = [eng.submit(PROMPTS[i % len(PROMPTS)], max_new_tokens=4)
+                for i in range(6)]
+        assert eng.stats()["queue_depth"] == 6.0
+        assert eng.stats()["queue_limit"] == 2.0
+        with pytest.raises(Saturated, match="waiting for a slot"):
+            eng.submit(PROMPTS[0], max_new_tokens=4)
+        for req in held:
+            eng._cancel(req)
+        assert _drained(eng)
+
+
 class _StubReplica:
     def __init__(self, key):
         class _Id:
@@ -272,6 +290,33 @@ class TestOccupancyRouting:
         assert not _mk_router(reps, {"a": over})._all_shedding(reps)
         assert not _mk_router(
             reps, {"a": over, "b": {"ongoing": 1.0}})._all_shedding(reps)
+
+    def test_shedding_at_the_replicas_own_limit_beyond_its_free_slots(self):
+        """A replica that reports ``queue_limit`` (its engine's ``max_queue``)
+        is shed at that, not at the knob; what its free slots will take is
+        not queue. 160 closed-loop clients on 128 slots with ``max_queue``
+        64: 32 wait in steady state, up to 157 while the slots first fill."""
+        from ray_tpu.core.config import config
+
+        assert config().serve_admission_queue_limit == 32
+        reps = [_StubReplica("a")]
+        base = {"slots_total": 128.0, "queue_limit": 64.0}
+
+        def shedding(**load):
+            return _mk_router(reps, {"a": dict(base, **load)})._all_shedding(reps)
+
+        assert not shedding(slots_busy=128.0, queue_depth=35.0)   # steady
+        assert not shedding(slots_busy=3.0, queue_depth=157.0)    # filling
+        assert shedding(slots_busy=128.0, queue_depth=64.0)
+        assert shedding(slots_busy=120.0, queue_depth=72.0)
+        assert not shedding(slots_busy=120.0, queue_depth=71.0)
+        # queue_limit 0: the engine sheds nothing, so neither does the router.
+        assert not shedding(slots_busy=128.0, queue_depth=500.0,
+                            queue_limit=0.0)
+        r = _mk_router(reps, {"a": dict(base, slots_busy=128.0,
+                                        queue_depth=66.0)})
+        assert r._retry_after_hint(reps) == pytest.approx(
+            3 * config().serve_retry_after_item_s)
 
     def test_pick_sheds_when_all_over_limit(self):
         from ray_tpu.core.config import config

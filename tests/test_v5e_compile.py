@@ -30,6 +30,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SERVE_WIDTHS = {"medium": "gpt2_medium", "xl": "gpt2_xl"}
 SERVE_LAYERS, SERVE_SLOTS, SERVE_POOLS = 2, 36, (1281, 1537)
 
+# LongCat-Flash at the sizes of the cell longcat-flash-omni.moe-decode:
+# published widths, 4 layers, 16 experts held, 128 slots, pool 7297 x 16.
+LONGCAT_SLOTS, LONGCAT_POOL = 128, 7297
+
 
 # An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
 # custom-call(...), custom_call_target="tpu_custom_call"``. The profiler names
@@ -84,7 +88,8 @@ def compile_all() -> dict:
     """Child side: {"skip": reason} or {"programs": {name: "ok" | error},
     "kernels": {name: [[instruction name, first output shape], ...]},
     "pool_movers": {serve program: pool_shaped_data_movers() of it},
-    "temp_bytes": {serve program: temporaries the compiler reports}}."""
+    "temp_bytes": {serve program: temporaries the compiler reports},
+    "need_bytes": {LongCat serve program: arguments + temporaries}}."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -114,6 +119,7 @@ def compile_all() -> dict:
     ctx, H, D, bt, slots = cfg.max_seq_len, cfg.n_heads, cfg.head_dim, 16, 8
     one = SingleDeviceSharding(devices[0])
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
+    need_bytes = {}
 
     def attempt(name, lower, pool=None):
         try:
@@ -122,7 +128,10 @@ def compile_all() -> dict:
             kernels[name] = sorted(set(_KERNEL.findall(text)))
             if pool is not None:
                 pool_movers[name] = pool_shaped_data_movers(text, *pool)
-                temp_bytes[name] = compiled.memory_analysis().temp_size_in_bytes
+                mem = compiled.memory_analysis()
+                temp_bytes[name] = mem.temp_size_in_bytes
+                need_bytes[name] = (mem.argument_size_in_bytes
+                                    + mem.temp_size_in_bytes)
             programs[name] = "ok"
         except Exception as e:  # noqa: BLE001 — the verdict IS the result
             programs[name] = f"{type(e).__name__}: {str(e)[:600]}"
@@ -167,6 +176,7 @@ def compile_all() -> dict:
             per_slot = lambda dtype: arr((SERVE_SLOTS,), dtype)  # noqa: E731
             i32 = arr((), jnp.int32)
             geometry = (scfg.n_layers, num_blocks, bt)
+            state = (params, (kv, kv)) + state[3:]   # the pool: one pytree
             attempt(f"serve_decode_{width}_{num_blocks}",
                     lambda: gen.decode_fn(8).lower(
                         *state, arr((SERVE_SLOTS, gen.blocks_per_seq),
@@ -179,6 +189,37 @@ def compile_all() -> dict:
                         *state, arr((gen.blocks_per_seq,), jnp.int32),
                         arr((1, 256), jnp.int32), i32, i32, i32, i32),
                     pool=geometry)
+
+    # LongCat-Flash's serve programs whole, at the cell's own sizes: the
+    # latent kernels under their names, no pool-shaped copy, and the bytes
+    # the chip must hold (5.2B bf16 parameters and a 1.2 GB latent pool).
+    from ray_tpu.models import longcat
+
+    lcfg = longcat.longcat_flash_share()
+    lparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: longcat.init_params(lcfg, key),
+                       jax.random.key(0)))
+    lgen = PagedGenerator(lparams, lcfg, slots=LONGCAT_SLOTS,
+                          num_blocks=LONGCAT_POOL, block_tokens=bt,
+                          attention_kernel="pallas")
+    lstate = (lparams,
+              (arr((lcfg.attn_sublayers, LONGCAT_POOL, bt, lcfg.pool_width)),),
+              arr((LONGCAT_SLOTS, lgen.logits_dim), jnp.float32),
+              arr((LONGCAT_SLOTS, 2), jnp.uint32))
+    l_slot = lambda dtype: arr((LONGCAT_SLOTS,), dtype)  # noqa: E731
+    i32 = arr((), jnp.int32)
+    l_geometry = (lcfg.attn_sublayers, LONGCAT_POOL, bt)
+    attempt("longcat_decode",
+            lambda: lgen.decode_fn(8).lower(
+                *lstate, arr((LONGCAT_SLOTS, lgen.blocks_per_seq), jnp.int32),
+                l_slot(jnp.int32), l_slot(jnp.bool_), l_slot(jnp.bool_),
+                l_slot(jnp.float32)), pool=l_geometry)
+    attempt("longcat_prefill_1024",
+            lambda: lgen.prefill_fn(1024).lower(
+                *lstate, arr((lgen.blocks_per_seq,), jnp.int32),
+                arr((1, 1024), jnp.int32), i32, i32, i32, i32),
+            pool=l_geometry)
 
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
@@ -201,7 +242,8 @@ def compile_all() -> dict:
             placed(p, b.param_shardings), placed(o, b.opt_shardings),
             {"tokens": arr((16, ctx), jnp.int32, b.batch_sharding)}))
     return {"programs": programs, "kernels": kernels,
-            "pool_movers": pool_movers, "temp_bytes": temp_bytes}
+            "pool_movers": pool_movers, "temp_bytes": temp_bytes,
+            "need_bytes": need_bytes}
 
 
 @pytest.fixture(scope="module")
@@ -270,6 +312,29 @@ def test_serve_programs_move_no_pool_sized_data(verdict, program, width,
     assert kernel == ("paged_decode_attn" if program == "decode"
                       else "paged_prefill_attn")
     assert re.fullmatch(r"bf16\[\d+,\d+,\d+,64\]", shape), shape
+
+
+@pytest.mark.parametrize("program,kernel,shape", [
+    ("longcat_decode", "mla_decode_attn", "bf16[128,1,64,512]"),
+    ("longcat_prefill_1024", "mla_prefill_attn", "bf16[1,64,1024,512]")])
+def test_longcat_serve_programs_fit_the_chip(verdict, program, kernel, shape):
+    """LongCat-Flash's ``paged_decode`` and its largest ``paged_prefill`` at
+    the sizes of ``longcat-flash-omni.moe-decode``: they compile for a v5e,
+    arguments plus temporaries stay under the chip's 16 GB, the latent
+    kernels carry their names and output shapes (the benchmark's
+    ``mla_attn_*`` metrics match ``^mla_decode_attn:``), the expert layer is
+    the TPU's grouped product (``ragged-dot``) and no instruction copies or
+    slices pool-shaped data."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    assert verdict["need_bytes"][program] < 16e9, verdict["need_bytes"]
+    # weights 10.35 GB + pool 1.20 GB: the cell fills the chip as reckoned
+    assert verdict["need_bytes"][program] > 11.5e9, verdict["need_bytes"]
+    found = verdict["kernels"][program]
+    assert [s for n, s in found if n == kernel] == [shape], found
+    assert any(n.startswith("ragged-dot") for n, _s in found), found
+    assert {n for n, _s in found} <= {kernel, "ragged-dot-none",
+                                      "ragged-dot-metadata"}, found
+    assert verdict["pool_movers"][program] == []
 
 
 def test_pool_mover_scan_sees_what_the_old_layout_did():
