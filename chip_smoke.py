@@ -110,8 +110,9 @@ def phase_kernels(cfg, interpret: bool) -> dict:
             want = oracle(*ops)
         errs[name] = _rel_err(kernel(*ops), want)
 
+    # around a block's edge and around a group of blocks' (128 positions)
     paged_case("paged_decode_t1", 1,
-               [0, 1, bt - 1, bt, bt + 1, ctx // 2, ctx - 2, ctx - 1])
+               [0, bt - 1, bt, 127, 128, 129, ctx // 2, ctx - 1])
     paged_case("paged_verify_t5", 5,
                [0, 3, bt - 1, bt, 5 * bt + 4, ctx // 2, ctx - 6, ctx - 5])
     for b in _default_buckets(ctx):

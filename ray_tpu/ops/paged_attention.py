@@ -9,46 +9,57 @@ S × max_len × H × D every token and reads every pool block a slot's table
 points at, live or not. Decode is memory-bandwidth-bound, so that gather is
 exactly the HBM traffic the roofline says we cannot afford.
 
-This kernel reads the block table NATIVELY instead: the table and the
-per-slot lengths ride in as scalar-prefetch operands
-(``pltpu.PrefetchScalarGridSpec``), so the BlockSpec index map dereferences
-``tables[s, j]`` on the host side of the DMA pipeline and each grid program
-streams pool blocks straight from HBM into VMEM — only the
-``ceil(len/block_tokens)`` LIVE blocks of its slot do real work. Grid steps
-past the last live block re-map onto it, and because consecutive grid steps
-that map to the same pool block skip the re-fetch, the dead tail of a table
-costs no traffic, not ``NB - live`` blocks of it. Softmax is the online
-(m, l, acc) accumulator pattern shared with ``flash_attention._flash_kernel``,
-held in VMEM scratch across the kv sweep.
+This kernel WALKS the block table itself: the table, the per-slot lengths
+and ``layer`` ride in as scalar-prefetch operands
+(``pltpu.PrefetchScalarGridSpec``), the pools stay in HBM
+(``memory_space=pl.ANY``), and the grid is ``(S, q tiles)`` with no table
+axis. Inside a grid step a loop runs over GROUPS of ``G`` consecutive table
+entries (``_blocks_per_group``: 128 kv positions, one lane row of scores),
+its bound computed from ``lengths[s]`` and the tile's last real query: a slot
+of 400 tokens does 4 iterations at 16-token blocks, a parked slot one, and
+there is no step that does nothing. A group's live blocks are fetched by one
+``pltpu.make_async_copy`` each, ``pool.at[layer, tables[s, b]]`` into rows
+``j * bt`` of one half of a double-buffered ``[2, G*bt, H*D]`` scratch, all
+in flight at once; the next group's copies are started before the current
+group is computed on, and a step's last iteration starts the FIRST group of
+the next grid step, so no slot opens on an exposed DMA. Entries past the
+slot's last live block are NOT fetched (dead table entries are never
+dereferenced); their rows of the V half are zeroed before the dot, because
+a masked score gives p = 0 and 0 x NaN is NaN, whatever the scratch held.
+Softmax is the online (m, l, acc) accumulator pattern shared with
+``flash_attention._flash_kernel``, held in VMEM scratch across the loop, over
+``G*bt`` kv positions at once: one dot a lane chunk where a block-a-step
+kernel had ``G``.
 
 Layout: ``q`` [S, T, H, D] — T > 1 is the multi-token speculative-decoding
 verify (and the paged prefill, S == 1): query t of slot s sits at absolute
 position ``lengths[s] + t`` and attends kv positions ``<= lengths[s] + t``.
 The T new tokens' K/V must already be scattered into the pool at those
 positions (the caller writes K/V first, then attends — same order as the
-gather path).
+gather path). Queries are tiled ``_Q_TILE`` at a time so that VMEM is
+bounded by the tile, not by T; decode and verify are a single tile, and each
+tile walks only the blocks at or below its own last real query.
 
 The pool is the WHOLE model's, ``[L, num_blocks, bt, H*D]``: heads folded
 into the lane dimension, so a block is one dense ``[bt, H*D]`` tile in the
-layout the array already has in HBM, and ``layer`` rides as a third
-scalar-prefetch operand into the index map. A Mosaic call cannot read
-through an XLA slice: handed ``pool[layer]`` it made XLA copy that layer's
-slab out of the pool every call (and a ``[.., H, D]`` pool with D = 64 minor
-was re-tiled whole on the way into and out of every program). Addressed in
-place, no program copies pool-sized data. Head ``h`` is the static lane
-slice ``[:, h*D:(h+1)*D]`` of the block; the body takes the lanes in chunks
-of G heads (``_heads_per_chunk``), every chunk a 128-lane-aligned slice, and
+layout the array already has in HBM, read where it lies: a Mosaic call
+cannot read through an XLA slice (handed ``pool[layer]`` it made XLA copy
+that layer's slab out of the pool every call), so no program copies
+pool-sized data. Head ``h`` is the static lane slice ``[:, h*D:(h+1)*D]``
+of the block; the body takes the lanes in chunks of C heads
+(``_heads_per_chunk``), every chunk a 128-lane-aligned slice, q and K
+meeting in bfloat16 (exact products, float32 sums), p and V in float32, and
 only the finalize step cuts single heads out of the accumulator.
 
-Grid: ``(S, q tiles, nb_seq)``, kv innermost. Queries are tiled
-``_Q_TILE`` at a time so the per-step VMEM footprint (q/out blocks plus the
-``[H*tile, ·]`` f32 accumulators, whose 1-wide m/l columns pad to a full
-128-lane tile) is bounded by the tile, not by T: a 1024-token prefill as
-ONE block asked the v5e compiler for 18 MB of scoped VMEM against its 16 MB
-limit. Decode and verify (T <= ``_Q_TILE``) are a single tile — the same
-program as before the tile axis existed. Each tile sweeps only the blocks
-at or below its own last query; the index map clamps later steps onto that
-block, so the revisit-skip makes their DMA free.
+A folded width that is no multiple of 128 lanes (gpt2-xl: 25 x 64 = 1600)
+cannot take the loop: Mosaic (jaxlib 0.9.0) pads such a ref's last
+dimension to whole lane tiles and then refuses every slice of it that is
+not a multiple of 128 wide, the full width included, so no DMA can name one
+block. There the same groups are a third grid axis and a group's blocks come
+in through ``G`` BlockSpecs (``_paged_kernel_unaligned``; the form
+``latent_paged_attention`` has): same body, same bound, dead steps cost a
+grid step each. A pool padded to whole lanes would take the loop at every
+width; it is ``models/generate.py``'s to make (ROADMAP L1).
 
 Runs compiled on TPU and in interpret mode on CPU (the tier-1 path);
 ``paged_attention_reference`` is the gather-path oracle the kernel is
@@ -66,9 +77,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
-# Queries per grid step. 128 rows x 12 heads of f32 accumulators is ~2.4 MB
-# of scratch on v5e; untuned (ROADMAP S2) — chosen to fit, not to be fast.
+# Queries per grid step: the prefill's accumulators are [H*tile, .] float32
+# with 1-wide m and l columns padded to 128 lanes, three buffers of
+# H*tile*512 bytes each. Compile-only for a v5e the 1024 bucket takes 5.9 MB
+# of scoped VMEM at 16 heads and 8.9 MB at 25, of 16 MB: twice the tile
+# would not fit gpt2-xl. Never swept on the chip: prefill is 1-2% of the
+# serve cells' device time.
 _Q_TILE = 128
+# kv positions one iteration of the walk takes: one 128-lane row of scores.
+_GROUP_KV = 128
+# What the K and V buffers (two halves each) may take of VMEM.
+_GROUP_VMEM_BYTES = 4 << 20
 
 
 def _last_block(first_pos, q_tile: int, block_tokens: int):
@@ -79,22 +98,33 @@ def _last_block(first_pos, q_tile: int, block_tokens: int):
     return jax.lax.div(first_pos + q_tile - 1, block_tokens)
 
 
-def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int = 1,
-                         offset: int = 0, nb_seq: int = 0):
-    """The index map both kernels address the pool with: grid step ``j`` of
-    slot ``s``, query tile ``i`` maps to pool block ``tables[s, b]`` of layer
-    ``layer[0]``, ``b`` clamped onto the tile's last live block so that later
-    steps revisit it and cost no DMA. A kernel that takes ``step_blocks``
-    blocks a step passes the pool once per block, each with its ``offset``
-    (and ``nb_seq``, the table's width, which ``j * step_blocks + offset``
-    may pass)."""
+def _tile_last_block(lengths_ref, s, i, q_tile: int, total: int,
+                     block_tokens: int, nb_seq: int):
+    """The last table entry that query tile ``i`` of slot ``s`` attends, by
+    its last REAL query: of ``total`` queries the last tile may hold fewer
+    than ``q_tile``, and its pad queries, like a slot at capacity, would pass
+    the slot's live blocks or the table's end."""
+    real = jnp.minimum(q_tile, total - i * q_tile)
+    return jnp.minimum(
+        _last_block(lengths_ref[s] + i * q_tile, real, block_tokens),
+        nb_seq - 1)
+
+
+def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int,
+                         offset: int, nb_seq: int,
+                         total: Optional[int] = None):
+    """The index map of a kernel whose table walk is a grid axis: grid step
+    ``j`` of slot ``s``, query tile ``i`` takes ``step_blocks`` table entries,
+    the pool handed in once per entry, each with its ``offset``; this one
+    maps to pool block ``tables[s, b]`` of layer ``layer[0]``, ``b`` clamped
+    onto the tile's last live block (by its real queries where ``total``,
+    their number over all tiles, is given), so that a dead entry is never
+    dereferenced and a step that maps where the last one did costs no DMA."""
     def kv_index(s, i, j, tbl, ln, lyr):
-        last_blk = _last_block(ln[s] + i * q_tile, q_tile, block_tokens)
-        if step_blocks == 1:
-            blk = jnp.minimum(j, last_blk)
-        else:
-            blk = jnp.minimum(jnp.minimum(j * step_blocks + offset, last_blk),
-                              nb_seq - 1)
+        last_blk = _tile_last_block(
+            ln, s, i, q_tile, q_tile * (i + 1) if total is None else total,
+            block_tokens, nb_seq)
+        blk = jnp.minimum(j * step_blocks + offset, last_blk)
         return (lyr[0], tbl[s, blk], 0, 0)
 
     return kv_index
@@ -102,93 +132,239 @@ def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int = 1,
 
 def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
     """How many heads the kernel takes in one dot. The block's lanes are cut
-    into chunks of G heads; a chunk's G*T query rows, each zero outside its
-    own head's lanes, meet the chunk's G*D lanes of K in ONE dot, so no head
-    is sliced out of a 128-lane register on every block. The dot computes G
+    into chunks of C heads; a chunk's C*T query rows, each zero outside its
+    own head's lanes, meet the chunk's C*D lanes of K in ONE dot, so no head
+    is sliced out of a 128-lane register on every block. The dot computes C
     times the products it needs: free while the rows fit one MXU pass
-    (decode, verify: all heads at once), so beyond that G is only what fills
+    (decode, verify: all heads at once), so beyond that C is only what fills
     128 lanes (prefill: two heads of 64)."""
     if num_heads * q_tile <= 128:
         return num_heads
     return min(num_heads, max(1, 128 // head_dim))
 
 
+def _blocks_per_group(block_tokens: int, width: int, itemsize: int) -> int:
+    """How many consecutive table entries one iteration of the kernel's walk
+    takes: ``_GROUP_KV`` kv positions, one lane row of scores, halved while
+    the four halves of the K and V buffers (two each, ``[G*bt, width]``)
+    would pass ``_GROUP_VMEM_BYTES``."""
+    g = max(1, _GROUP_KV // block_tokens)
+    while g > 1 and 4 * g * block_tokens * width * itemsize > _GROUP_VMEM_BYTES:
+        g //= 2
+    return g
+
+
+def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
+                  scale: float, num_heads: int, q_tile: int, head_dim: int,
+                  group_tokens: int):
+    """One online-softmax update over group ``g`` of a slot's kv positions,
+    ``[g * group_tokens, (g+1) * group_tokens)``. ``k_rows(d0, d1)`` and
+    ``v_rows(d0, d1)`` give the group's ``[group_tokens, d1 - d0]`` lanes."""
+    H, T, D = num_heads, q_tile, head_dim
+    C = q_ref.shape[-1] // D                   # heads per lane chunk
+    # Causal + validity in one mask: kv position vs absolute q position.
+    # Row r of a chunk is (head r // T, query r % T).
+    rows = C * T
+    kv_pos = g * group_tokens + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, group_tokens), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, group_tokens), 0)
+    q_pos = ctx + (0 if T == 1 else jax.lax.rem(row, T))
+    mask_all = kv_pos <= q_pos
+    for c0 in range(0, H, C):                    # static unroll
+        c = min(C, H - c0)                       # heads of this chunk
+        d0, d1 = c0 * D, (c0 + c) * D            # their lanes
+        r0, r1 = c0 * T, (c0 + c) * T            # their rows
+        mask = mask_all[: c * T]
+        # q and K meet in the dtype they share (bfloat16 on the chip: the
+        # products are exact in the float32 they are summed in).
+        qb = q_ref[0, r0:r1, : c * D]                       # [c*T, c*D]
+        kb = k_rows(d0, d1)                                 # [kv, c*D]
+        if qb.dtype != kb.dtype:
+            qb, kb = qb.astype(jnp.float32), kb.astype(jnp.float32)
+        scores = jax.lax.dot_general(
+            qb, kb, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                 # [c*T, kv]
+        scores = jnp.where(mask, scores, _NEG_INF)
+        m_prev = m_scr[r0:r1]                     # [c*T, 1]
+        m_cur = jnp.max(scores, axis=-1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(scores - m_new)               # [c*T, kv]
+        l_scr[r0:r1] = alpha * l_scr[r0:r1] + jnp.sum(
+            p, axis=-1, keepdims=True)
+        pv = jax.lax.dot_general(
+            p, v_rows(d0, d1).astype(jnp.float32), (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )                                         # [c*T, c*D]
+        acc_scr[r0:r1, : c * D] = acc_scr[r0:r1, : c * D] * alpha + pv
+        m_scr[r0:r1] = m_new
+
+
+def _init_accumulators(m_scr, l_scr, acc_scr):
+    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+    l_scr[:] = jnp.zeros_like(l_scr)
+    acc_scr[:] = jnp.zeros_like(acc_scr)
+
+
+def _finalize(o_ref, l_scr, acc_scr):
+    _, H, T, D = o_ref.shape
+    C = acc_scr.shape[-1] // D
+    out = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+    for h in range(H):                                # static unroll
+        e0 = (h % C) * D                              # head h's lanes in its chunk
+        o_ref[0, h] = out[h * T:(h + 1) * T, e0:e0 + D]
+
+
 def _paged_kernel(
     tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
-    layer_ref,                 # scalar prefetch: [1] int32 (index map only)
-    q_ref,                     # [1, H*T, G*D] block — T = one q tile
-    k_ref, v_ref,              # [1, bt, H*D] block — pool block tables[s, j]
+    layer_ref,                 # scalar prefetch: [1] int32
+    q_ref,                     # [1, H*T, C*D] block — T = one q tile
+    k_hbm, v_hbm,              # the whole pools [L, num_blocks, bt, H*D], in HBM
     o_ref,                     # [1, H, T, D] block
-    m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, G*D]
+    m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, C*D]
+    k_buf, v_buf,              # VMEM scratch: [2, G*bt, H*D] each
+    sems,                      # DMA semaphores [2 (K, V), 2 (buffer half)]
+    half_ref,                  # SMEM [1]: the half this step's first group is in
     *,
     scale: float,
     block_tokens: int,
-    num_heads: int,
     q_tile: int,
+    total: int,                # queries over all tiles (the last may be ragged)
     nb_seq: int,
+    group_blocks: int,
 ):
+    """The walk: grid step ``(s, i)`` loops over the groups of ``G`` table
+    entries its query tile attends, the bound read from ``lengths[s]``. A
+    group's live blocks arrive by one DMA each into one half of ``k_buf`` /
+    ``v_buf`` while the other half is computed on."""
     s = pl.program_id(0)
     i = pl.program_id(1)
-    j = pl.program_id(2)
-    bt, H, T = block_tokens, num_heads, q_tile
-    D = o_ref.shape[-1]
-    G = q_ref.shape[-1] // D                   # heads per lane chunk
-    # Absolute position of this tile's first query.
+    n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
+    bt, T, G = block_tokens, q_tile, group_blocks
+    layer = layer_ref[0]
+
+    def last_block(s_, i_):
+        return _tile_last_block(lengths_ref, s_, i_, T, total, bt, nb_seq)
+
+    def live_entries(last, g):
+        """How many of group ``g``'s ``G`` entries are live, 0..G, where the
+        tile's last live entry is ``last``."""
+        return jnp.clip(last - g * G + 1, 0, G)
+
+    def live_copies(s_, g, n_live, half, act):
+        """``act`` on the K and the V copy of the ``n_live`` live entries of
+        group ``g`` of slot ``s_``, into rows ``j * bt`` of buffer ``half``.
+        Start and wait both go through here, so a wait meets exactly the
+        copies that were started. (A loop, not unrolled: a serve program
+        lowers this kernel, and an unrolled ``pl.when`` an entry tripled the
+        time that takes.)"""
+        def entry(j, _):
+            blk = tables_ref[s_, g * G + j]
+            rows = pl.ds(pl.multiple_of(j * bt, bt), bt)
+            act(pltpu.make_async_copy(
+                k_hbm.at[layer, blk], k_buf.at[half, rows], sems.at[0, half]))
+            act(pltpu.make_async_copy(
+                v_hbm.at[layer, blk], v_buf.at[half, rows], sems.at[1, half]))
+
+        jax.lax.fori_loop(0, n_live, entry, None)
+
+    last_blk = last_block(s, i)
+    n_groups = jax.lax.div(last_blk + G, G)           # ceil((last_blk+1)/G)
+
+    @pl.when(jnp.logical_and(s == 0, i == 0))
+    def _first_step():
+        half_ref[0] = 0
+        live_copies(s, 0, live_entries(last_blk, 0), 0, lambda c: c.start())
+
+    first_half = half_ref[0]
+    _init_accumulators(m_scr, l_scr, acc_scr)
+
+    # The step after this one: the next q tile of the slot, else the next
+    # slot's first (clamped where there is none; ``has_next`` guards it).
+    more_tiles = i + 1 < n_tiles
+    next_s = jnp.minimum(jnp.where(more_tiles, s, s + 1), n_slots - 1)
+    next_last = last_block(next_s, jnp.where(more_tiles, i + 1, 0))
+    has_next = jnp.logical_or(more_tiles, s + 1 < n_slots)
+    row = jax.lax.broadcasted_iota(jnp.int32, (G * bt, 1), 0)
+
+    def group(g, _):
+        half = jax.lax.rem(first_half + g, 2)
+        # Start what comes next into the other half before computing on this
+        # one: this step's next group, or the FIRST group of the next step,
+        # so that no step opens with an exposed DMA.
+        in_step = g + 1 < n_groups
+
+        @pl.when(jnp.logical_or(in_step, has_next))
+        def _prefetch():
+            next_g = jnp.where(in_step, g + 1, 0)
+            live_copies(
+                jnp.where(in_step, s, next_s), next_g,
+                live_entries(jnp.where(in_step, last_blk, next_last), next_g),
+                1 - half, lambda c: c.start())
+
+        n_live = live_entries(last_blk, g)
+        live_copies(s, g, n_live, half, lambda c: c.wait())
+        # Entries past ``last_blk`` (the last group's tail) were not fetched:
+        # their rows hold an earlier group's data, or nothing yet. K's are
+        # masked whatever they hold; V's meet p = 0, and 0 x NaN is NaN, so
+        # they are read as zero.
+        fetched = row < n_live * bt
+        _attend_group(
+            q_ref, lambda d0, d1: k_buf[half, :, d0:d1],
+            lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
+            g, lengths_ref[s] + i * T, m_scr, l_scr, acc_scr, scale=scale,
+            num_heads=o_ref.shape[1], q_tile=T, head_dim=o_ref.shape[-1],
+            group_tokens=G * bt)
+
+    jax.lax.fori_loop(0, n_groups, group, None)
+    # The half the prefetched first group of the next step went into.
+    half_ref[0] = jax.lax.rem(first_half + n_groups, 2)
+    _finalize(o_ref, l_scr, acc_scr)
+
+
+def _paged_kernel_unaligned(
+    tables_ref, lengths_ref, layer_ref,   # scalar prefetch, as _paged_kernel
+    q_ref,                                # [1, H*T, C*D] block
+    *rest,                                # G K blocks, G V blocks [1, bt, H*D];
+                                          # out; m, l, acc scratch
+    scale: float,
+    block_tokens: int,
+    q_tile: int,
+    total: int,                # queries over all tiles (the last may be ragged)
+    nb_seq: int,
+    group_blocks: int,
+):
+    """The same groups for a folded width off the 128-lane grid, where
+    Mosaic refuses to slice a block out of the pool for a DMA (the ref's
+    last dimension is padded to lanes, and a slice must be a multiple of
+    128 of them): the groups are a grid axis, a group's ``G`` blocks come in
+    through ``G`` BlockSpecs (``_clamped_block_index``), and a step past the
+    slot's last group skips its body and re-fetches nothing."""
+    G, bt, T = group_blocks, block_tokens, q_tile
+    k_refs, v_refs = rest[:G], rest[G:2 * G]
+    o_ref = rest[2 * G]
+    m_scr, l_scr, acc_scr = rest[2 * G + 1:]
+    s, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     ctx = lengths_ref[s] + i * T
-    # Grid steps past this block re-map onto it in the index map, so they
-    # cost no DMA, and the body skips them.
-    last_blk = _last_block(ctx, T, bt)
+    last_blk = _tile_last_block(lengths_ref, s, i, T, total, bt, nb_seq)
 
     @pl.when(j == 0)
     def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+        _init_accumulators(m_scr, l_scr, acc_scr)
 
-    @pl.when(j <= last_blk)
+    @pl.when(j * G <= last_blk)
     def _body():
-        # Causal + validity in one mask: kv position vs absolute q position.
-        # Row r of a chunk is (head r // T, query r % T).
-        rows = G * T
-        kv_pos = j * bt + jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, bt), 0)
-        q_pos = ctx + (0 if T == 1 else jax.lax.rem(row, T))
-        mask_all = kv_pos <= q_pos
-        for c0 in range(0, H, G):                    # static unroll
-            g = min(G, H - c0)                       # heads of this chunk
-            d0, d1 = c0 * D, (c0 + g) * D            # their lanes
-            r0, r1 = c0 * T, (c0 + g) * T            # their rows
-            mask = mask_all[: g * T]
-            qb = q_ref[0, r0:r1, : g * D].astype(jnp.float32)   # [g*T, g*D]
-            kb = k_ref[0, :, d0:d1].astype(jnp.float32)         # [bt, g*D]
-            vb = v_ref[0, :, d0:d1].astype(jnp.float32)
-            scores = jax.lax.dot_general(
-                qb, kb, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                 # [g*T, bt]
-            scores = jnp.where(mask, scores, _NEG_INF)
-            m_prev = m_scr[r0:r1]                     # [g*T, 1]
-            m_cur = jnp.max(scores, axis=-1, keepdims=True)
-            m_new = jnp.maximum(m_prev, m_cur)
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(scores - m_new)               # [g*T, bt]
-            l_scr[r0:r1] = alpha * l_scr[r0:r1] + jnp.sum(
-                p, axis=-1, keepdims=True)
-            pv = jax.lax.dot_general(
-                p, vb, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            )                                         # [g*T, g*D]
-            acc_scr[r0:r1, : g * D] = acc_scr[r0:r1, : g * D] * alpha + pv
-            m_scr[r0:r1] = m_new
+        gather = lambda refs: lambda d0, d1: jnp.concatenate(  # noqa: E731
+            [r[0, :, d0:d1] for r in refs], axis=0)
+        _attend_group(
+            q_ref, gather(k_refs), gather(v_refs), j, ctx, m_scr, l_scr,
+            acc_scr, scale=scale, num_heads=o_ref.shape[1], q_tile=T,
+            head_dim=o_ref.shape[-1], group_tokens=G * bt)
 
-    @pl.when(j == nb_seq - 1)
-    def _finalize():
-        for h in range(H):                            # static unroll
-            r0, r1 = h * T, (h + 1) * T
-            e0 = (h % G) * D                          # head h's lanes in its chunk
-            denom = jnp.maximum(l_scr[r0:r1], 1e-30)  # [T, 1]
-            o_ref[0, h] = (acc_scr[r0:r1, e0:e0 + D] / denom).astype(
-                o_ref.dtype)
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _done():
+        _finalize(o_ref, l_scr, acc_scr)
 
 
 def paged_attention(
@@ -214,12 +390,23 @@ def paged_attention(
         raise ValueError(
             f"pool {k_pool.shape} is not [L, num_blocks, bt, {H}*{D}]: the "
             f"kernel reads the whole folded pool (one layer's: pool[None])")
+    return _paged_attention(
+        q, k_pool, v_pool, tables.astype(jnp.int32),
+        lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        scale=float(scale) if scale is not None else 1.0 / D**0.5,
+        interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
+                     interpret):
+    """:func:`paged_attention` on checked operands, ``layer`` an int32[1]
+    VALUE: a jit of its own, so that a program that calls it once a layer
+    (24 unrolled layers, eight serve programs) traces and lowers the kernel
+    once and calls it 24 times; XLA inlines the calls."""
+    S, T, H, D = q.shape
     bt = k_pool.shape[2]
     nb_seq = tables.shape[1]
-    s_val = scale if scale is not None else 1.0 / D**0.5
-    tables = tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     qt = q.transpose(0, 2, 1, 3)                      # [S, H, T, D]
     tq = min(T, _Q_TILE)
     q_tiles = pl.cdiv(T, tq)
@@ -228,48 +415,58 @@ def paged_attention(
         # and their rows are sliced off below.
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, q_tiles * tq - T), (0, 0)))
 
-    G = _heads_per_chunk(H, tq, D)
-    # q of head h sits in lanes (h % G) * D of a G*D-wide row, zeros beside
+    C = _heads_per_chunk(H, tq, D)
+    # q of head h sits in lanes (h % C) * D of a C*D-wide row, zeros beside
     # it: one dot of a chunk's rows against the chunk's lanes then gives
     # every head its own scores. Rows of a tile are (head, query).
-    own = jnp.arange(G)[None, :] == (jnp.arange(H) % G)[:, None]   # [H, G]
+    own = jnp.arange(C)[None, :] == (jnp.arange(H) % C)[:, None]   # [H, C]
     qw = jnp.where(own[None, :, None, :, None], qt[:, :, :, None, :], 0)
-    qw = qw.reshape(S, H, q_tiles, tq, G * D).transpose(0, 2, 1, 3, 4)
-    qw = qw.reshape(S, q_tiles, H * tq, G * D)
+    qw = qw.reshape(S, H, q_tiles, tq, C * D).transpose(0, 2, 1, 3, 4)
+    qw = qw.reshape(S, q_tiles, H * tq, C * D)
 
-    kv_index = _clamped_block_index(tq, bt)
-
-    def q_index(s, i, j, tbl, ln, lyr):
-        return (s, i, 0, 0)
-
-    def o_index(s, i, j, tbl, ln, lyr):
-        return (s, 0, i, 0)
+    G = _blocks_per_group(bt, H * D, k_pool.dtype.itemsize)
+    accumulators = [
+        pltpu.VMEM((H * tq, 1), jnp.float32),
+        pltpu.VMEM((H * tq, 1), jnp.float32),
+        pltpu.VMEM((H * tq, C * D), jnp.float32),
+    ]
+    if (H * D) % 128 == 0:
+        kernel, grid = _paged_kernel, (S, q_tiles)
+        kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        scratch = accumulators + [
+            pltpu.VMEM((2, G * bt, H * D), k_pool.dtype),
+            pltpu.VMEM((2, G * bt, H * D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.SMEM((1,), jnp.int32),
+        ]
+        pools = (k_pool, v_pool)
+    else:
+        kernel, grid = _paged_kernel_unaligned, (S, q_tiles, pl.cdiv(nb_seq, G))
+        kv_specs = [
+            pl.BlockSpec((None, 1, bt, H * D),
+                         _clamped_block_index(tq, bt, G, g, nb_seq, T))
+            for g in range(G)] * 2
+        scratch = accumulators
+        pools = (k_pool,) * G + (v_pool,) * G
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, q_tiles, nb_seq),
-        in_specs=[
-            pl.BlockSpec((None, 1, H * tq, G * D), q_index),
-            pl.BlockSpec((None, 1, bt, H * D), kv_index),
-            pl.BlockSpec((None, 1, bt, H * D), kv_index),
-        ],
-        out_specs=pl.BlockSpec((1, H, tq, D), o_index),
-        scratch_shapes=[
-            pltpu.VMEM((H * tq, 1), jnp.float32),
-            pltpu.VMEM((H * tq, 1), jnp.float32),
-            pltpu.VMEM((H * tq, G * D), jnp.float32),
-        ],
+        grid=grid,
+        in_specs=[pl.BlockSpec((None, 1, H * tq, C * D),
+                               lambda s, i, *_: (s, i, 0, 0))] + kv_specs,
+        out_specs=pl.BlockSpec((1, H, tq, D), lambda s, i, *_: (s, 0, i, 0)),
+        scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, scale=s_val, block_tokens=bt, num_heads=H,
-            q_tile=tq, nb_seq=nb_seq),
+            kernel, scale=scale, block_tokens=bt, q_tile=tq, total=T,
+            nb_seq=nb_seq, group_blocks=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype),
         interpret=interpret,
         # The name a profiler prints for the kernel, whatever calls it.
         name="paged_decode_attn" if T == 1 else "paged_prefill_attn",
-    )(tables, lengths, layer, qw, k_pool, v_pool)
+    )(tables, lengths, layer, qw, *pools)
     return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
 
 
@@ -304,9 +501,10 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
 # ``value_lanes`` lanes, and the up-projections are absorbed into q and into
 # the output by the caller, so the kernel's dot is a real ``[T*H, W] x
 # [W, tokens]`` product: the rows are the heads, where the kernel above needs
-# a block-diagonal q to give each head its own lanes. Same grid (slots, query
-# tiles, table steps, kv innermost), same scalar prefetch (tables, lengths,
-# layer) and the same clamped index map; a grid step takes
+# a block-diagonal q to give each head its own lanes. The grid is (slots,
+# query tiles, table steps), kv innermost, as ``_paged_kernel_unaligned``'s:
+# same scalar prefetch (tables, lengths, layer), same clamped index map (the
+# loop walk of ``_paged_kernel`` is the next step for it); a grid step takes
 # ``_LATENT_STEP_BLOCKS`` pool blocks (the pool handed in once per block),
 # because one 16-token block of one shared row is 20 KB, far too little work
 # for a step's fixed cost.

@@ -17,34 +17,45 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import generate, transformer
-from ray_tpu.ops.paged_attention import (paged_attention,
+from ray_tpu.ops.paged_attention import (_blocks_per_group, paged_attention,
                                          paged_attention_reference)
 from ray_tpu.serve.llm import PagedLLMEngine
 
 BT = 8   # block_tokens
 NB = 6   # blocks per sequence (table width)
-H, D = 4, 16
+# 8 x 16 folds to 128 lanes: the kernel's walk (a loop over groups of table
+# entries, each block one DMA). A width off the 128-lane grid takes the same
+# groups through BlockSpecs; the odd widths below run that form.
+H, D = 8, 16
+GROUP = _blocks_per_group(BT, H * D, 4)    # table entries a loop iteration
 
 
 def _setup(lengths, t_tokens, *, seed=0, pool_blocks=24, layers=1, layer=0,
-           heads=H, dim=D):
+           heads=H, dim=D, nb=NB, dead=0):
     """Random pool + one live block chain per slot; returns the operands of
     ``paged_attention`` / ``paged_attention_reference``: the pool is the
     whole model's, ``[layers, pool_blocks, BT, heads * dim]`` with distinct
-    content in every layer, and ``layer`` picks the one attended."""
+    content in every layer, and ``layer`` picks the one attended. A length
+    of None is a parked slot: length 0, an all-trash table. ``dead`` is the
+    block id the entries past a live slot's chain hold (0: the trash block)."""
     rng = np.random.default_rng(seed)
     S = len(lengths)
     q = rng.standard_normal((S, t_tokens, heads, dim)).astype(np.float32)
     pool = (layers, pool_blocks, BT, heads * dim)
     k_pool = rng.standard_normal(pool).astype(np.float32)
     v_pool = rng.standard_normal(pool).astype(np.float32)
-    tables = np.zeros((S, NB), np.int32)
+    tables = np.zeros((S, nb), np.int32)
     nxt = 1  # block 0 stays trash
     for s, ln in enumerate(lengths):
-        live = -(-max(ln + t_tokens, 1) // BT)
-        for j in range(min(live, NB)):
-            tables[s, j] = nxt
-            nxt += 1
+        if ln is None:
+            continue
+        live = min(-(-max(ln + t_tokens, 1) // BT), nb)
+        tables[s, live:] = dead
+        # A shuffled chain: the walk must dereference the table, not count.
+        tables[s, :live] = rng.permutation(np.arange(nxt, nxt + live))
+        nxt += live
+    assert nxt <= pool_blocks - (dead > 0), (nxt, pool_blocks)
+    lengths = [ln or 0 for ln in lengths]
     return (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
             jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)),
             layer)
@@ -78,6 +89,46 @@ class TestKernelOracleEquivalence:
         ref = paged_attention_reference(*ops)
         _assert_close(out, ref)
 
+    @pytest.mark.parametrize("heads,dim", [(H, D), (5, 16)])
+    @pytest.mark.parametrize("nb,t_tokens", [
+        (5, 1), (5, 4), (5, 7),                # narrower than one group
+        (40, 1), (40, 4), (40, 7), (40, 130),  # two groups and a half
+        (65, 1), (65, 7), (65, 130),           # four groups and one entry
+    ])
+    def test_walk_edges(self, nb, t_tokens, heads, dim):
+        """The walk's edges in one batch: contexts of 0, 1, a group of table
+        entries -1 / +0 / +1 and the whole table, parked slots (all-trash
+        tables) between the live ones, so that a slot's first group is
+        fetched from the slot before it, and a table whose width is no
+        multiple of the group. Dead table entries hold the id of a block
+        full of NaN while the oracle reads a clean pool: a row past the
+        loop's bound may reach neither dot (0 x NaN is NaN)."""
+        cap = nb * BT - t_tokens
+        group = GROUP * BT
+        lengths = [None, 0, 1, None, None, min(group - 1, cap),
+                   min(group, cap), None, min(group + 1, cap), cap, None]
+        blocks = sum(-(-(ln + t_tokens) // BT)
+                     for ln in lengths if ln is not None) + 2
+        q, k_pool, v_pool, tables, lens, layer = _setup(
+            lengths, t_tokens, seed=nb + t_tokens, pool_blocks=blocks,
+            layers=2, layer=1, heads=heads, dim=dim, nb=nb, dead=blocks - 1)
+        ref = paged_attention_reference(q, k_pool, v_pool, tables, lens,
+                                        layer)
+        out = paged_attention(q, k_pool.at[:, -1].set(jnp.nan),
+                              v_pool.at[:, -1].set(jnp.nan), tables, lens,
+                              layer, interpret=True)
+        _assert_close(out, ref)
+
+    @pytest.mark.parametrize("block_tokens,width,itemsize,blocks", [
+        (16, 1024, 2, 8), (16, 1600, 2, 8), (8, 128, 4, 16), (128, 1024, 2, 1),
+        (256, 1024, 2, 1), (16, 8192, 2, 4), (16, 8192, 4, 2)])
+    def test_group_is_128_positions_within_vmem(self, block_tokens, width,
+                                                itemsize, blocks):
+        """One loop iteration takes 128 kv positions' worth of table entries
+        (never under one), fewer where four halves of K and V at this width
+        would pass the buffers' share of VMEM."""
+        assert _blocks_per_group(block_tokens, width, itemsize) == blocks
+
     @pytest.mark.parametrize("layers,layer", [(3, 1), (3, 2), (5, 4)])
     @pytest.mark.parametrize("t_tokens", [1, 4])
     def test_layer_of_a_whole_pool(self, layers, layer, t_tokens):
@@ -94,16 +145,24 @@ class TestKernelOracleEquivalence:
             *ops[:-1], lyr, interpret=True))(jnp.int32(layer))
         _assert_close(traced, out)
 
-    @pytest.mark.parametrize("heads,dim", [(5, 16), (3, 24), (25, 8)])
-    def test_folded_width_not_a_multiple_of_128(self, heads, dim):
-        """``H*D`` off the 128-lane grid (gpt2-xl's 25 x 64 = 1600): the
-        block's last dimension is the array's own, heads stay static lane
-        slices of it."""
+    @pytest.mark.parametrize("heads,dim", [(5, 16), (3, 24), (25, 8),
+                                           (25, 64)])
+    @pytest.mark.parametrize("t_tokens", [1, 4])
+    def test_folded_width_not_a_multiple_of_128(self, heads, dim, t_tokens):
+        """``H*D`` off the 128-lane grid (gpt2-xl's 25 x 64 = 1600): Mosaic
+        cannot slice such a pool for a DMA, so the groups are a grid axis
+        and the blocks come through BlockSpecs whose last dimension is the
+        array's own; heads stay static lane slices of it. A table of 20
+        entries: one whole group and a quarter."""
         assert (heads * dim) % 128
-        ops = _setup([0, BT - 1, 2 * BT + 1], 1, seed=17, layers=2, layer=1,
-                     heads=heads, dim=dim)
+        ops = _setup([0, BT - 1, 2 * BT + 1, None, GROUP * BT + 3], t_tokens,
+                     seed=17, layers=2, layer=1, heads=heads, dim=dim, nb=20,
+                     pool_blocks=32)
         out = paged_attention(*ops, interpret=True)
         _assert_close(out, paged_attention_reference(*ops))
+        traced = jax.jit(lambda lyr: paged_attention(
+            *ops[:-1], lyr, interpret=True))(jnp.int32(1))
+        _assert_close(traced, out)
 
     def test_pool_of_another_width_is_refused(self):
         q, k_pool, v_pool, tables, lens, layer = _setup([5], 1)

@@ -42,6 +42,11 @@ LONGCAT_SLOTS, LONGCAT_POOL = 128, 7297
 _KERNEL = re.compile(r"%([\w\-]+?)(?:\.\d+)* = \(?(\w+\[[\d,]*\])[^\n]*"
                      r"custom_call_target=\"tpu_custom_call\"")
 
+# What a compiled Pallas call says it takes of scoped VMEM, in bytes.
+_SCOPED = re.compile(r'"used_scoped_memory_configs":\[\{"memory_space":"1",'
+                     r'"offset":"\d+","size":"(\d+)"')
+V5E_SCOPED_VMEM = 16 << 20
+
 # One instruction of an optimized module: name, output shape(s), op kind.
 _INSTR = re.compile(r"^\s*(?:ROOT )?%([\w\-.]+) = (.*?) ([a-z][\w\-]*)\(")
 # Op kinds that move no data: they name, pass on or alias a buffer.
@@ -84,9 +89,25 @@ def pool_shaped_data_movers(hlo: str, n_layers: int, num_blocks: int,
     return found
 
 
+def pallas_grids(jaxpr) -> list:
+    """The grid of every ``pallas_call`` in a jaxpr, nested ones included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.append(list(eqn.params["grid_mapping"].grid))
+        for sub in eqn.params.values():
+            for inner in sub if isinstance(sub, (list, tuple)) else [sub]:
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    found.extend(pallas_grids(inner))
+    return found
+
+
 def compile_all() -> dict:
     """Child side: {"skip": reason} or {"programs": {name: "ok" | error},
     "kernels": {name: [[instruction name, first output shape], ...]},
+    "grids": {name: pallas_grids() of the traced program},
+    "scoped_vmem": {name: bytes of scoped VMEM each Pallas call takes},
     "pool_movers": {serve program: pool_shaped_data_movers() of it},
     "temp_bytes": {serve program: temporaries the compiler reports},
     "need_bytes": {LongCat serve program: arguments + temporaries}}."""
@@ -119,13 +140,18 @@ def compile_all() -> dict:
     ctx, H, D, bt, slots = cfg.max_seq_len, cfg.n_heads, cfg.head_dim, 16, 8
     one = SingleDeviceSharding(devices[0])
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
-    need_bytes = {}
+    need_bytes, grids, scoped_vmem = {}, {}, {}
 
-    def attempt(name, lower, pool=None):
+    def attempt(name, trace, pool=None):
         try:
-            compiled = lower().compile()
+            traced = trace()
+            grids[name] = pallas_grids(traced.jaxpr.jaxpr)
+            compiled = traced.lower().compile()
             text = compiled.as_text()
             kernels[name] = sorted(set(_KERNEL.findall(text)))
+            scoped_vmem[name] = [
+                int(n) for line in text.splitlines()
+                if "tpu_custom_call" in line for n in _SCOPED.findall(line)]
             if pool is not None:
                 pool_movers[name] = pool_shaped_data_movers(text, *pool)
                 mem = compiled.memory_analysis()
@@ -143,16 +169,32 @@ def compile_all() -> dict:
     attempt("flash_fwd_bwd", lambda: jax.jit(jax.value_and_grad(
         lambda q, k, v: flash_attention(
             q, k, v, True, None, 512, 512, False).astype(jnp.float32).sum(),
-        argnums=(0, 1, 2))).lower(qkv, qkv, qkv))
+        argnums=(0, 1, 2))).trace(qkv, qkv, qkv))
 
     nb_seq = ctx // bt
     pool = arr((1, 2 * slots * nb_seq + 1, bt, H * D))   # one layer's: [None]
     cases = [("paged_decode", slots, 1), ("paged_verify", slots, 5)]
     cases += [(f"paged_prefill_{b}", 1, b) for b in _default_buckets(ctx)]
     for name, s, t in cases:
-        attempt(name, lambda s=s, t=t: jax.jit(paged_attention).lower(
+        attempt(name, lambda s=s, t=t: jax.jit(paged_attention).trace(
             arr((s, t, H, D)), pool, pool, arr((s, nb_seq), jnp.int32),
             arr((s,), jnp.int32), arr((), jnp.int32)))
+
+    # The kernel alone at the serve cells' geometry (36 slots, a table of 64,
+    # the 1024 bucket in tiles of 128), both published widths, both pools.
+    for width, full in SERVE_WIDTHS.items():
+        wcfg = getattr(transformer, full)(max_seq_len=ctx)
+        for num_blocks in SERVE_POOLS:
+            wpool = arr((SERVE_LAYERS, num_blocks, bt,
+                         wcfg.n_heads * wcfg.head_dim))
+            for kind, s, t in (("decode", SERVE_SLOTS, 1), ("prefill", 1, ctx)):
+                attempt(f"kernel_{kind}_{width}_{num_blocks}",
+                        lambda s=s, t=t, c=wcfg, wpool=wpool: jax.jit(
+                            paged_attention).trace(
+                            arr((s, t, c.n_heads, c.head_dim)), wpool, wpool,
+                            arr((s, nb_seq), jnp.int32), arr((s,), jnp.int32),
+                            arr((), jnp.int32)),
+                        pool=(SERVE_LAYERS, num_blocks, bt))
 
     # The serve programs whole, at the benchmark's serve geometry (36 slots,
     # blocks of 16, the decode chunk of 8, the 256 bucket) and two published
@@ -178,14 +220,14 @@ def compile_all() -> dict:
             geometry = (scfg.n_layers, num_blocks, bt)
             state = (params, (kv, kv)) + state[3:]   # the pool: one pytree
             attempt(f"serve_decode_{width}_{num_blocks}",
-                    lambda: gen.decode_fn(8).lower(
+                    lambda: gen.decode_fn(8).trace(
                         *state, arr((SERVE_SLOTS, gen.blocks_per_seq),
                                     jnp.int32),
                         per_slot(jnp.int32), per_slot(jnp.bool_),
                         per_slot(jnp.bool_), per_slot(jnp.float32)),
                     pool=geometry)
             attempt(f"serve_prefill_{width}_{num_blocks}",
-                    lambda: gen.prefill_fn(256).lower(
+                    lambda: gen.prefill_fn(256).trace(
                         *state, arr((gen.blocks_per_seq,), jnp.int32),
                         arr((1, 256), jnp.int32), i32, i32, i32, i32),
                     pool=geometry)
@@ -211,12 +253,12 @@ def compile_all() -> dict:
     i32 = arr((), jnp.int32)
     l_geometry = (lcfg.attn_sublayers, LONGCAT_POOL, bt)
     attempt("longcat_decode",
-            lambda: lgen.decode_fn(8).lower(
+            lambda: lgen.decode_fn(8).trace(
                 *lstate, arr((LONGCAT_SLOTS, lgen.blocks_per_seq), jnp.int32),
                 l_slot(jnp.int32), l_slot(jnp.bool_), l_slot(jnp.bool_),
                 l_slot(jnp.float32)), pool=l_geometry)
     attempt("longcat_prefill_1024",
-            lambda: lgen.prefill_fn(1024).lower(
+            lambda: lgen.prefill_fn(1024).trace(
                 *lstate, arr((lgen.blocks_per_seq,), jnp.int32),
                 arr((1, 1024), jnp.int32), i32, i32, i32, i32),
             pool=l_geometry)
@@ -238,12 +280,12 @@ def compile_all() -> dict:
         o_shape = jax.eval_shape(optimizer.init, p_shape)
         placed = lambda tree, sh: jax.tree.map(  # noqa: E731
             lambda x, s: arr(x.shape, x.dtype, s), tree, sh)
-        attempt(name, lambda b=bundle, p=p_shape, o=o_shape: b.step.lower(
+        attempt(name, lambda b=bundle, p=p_shape, o=o_shape: b.step.trace(
             placed(p, b.param_shardings), placed(o, b.opt_shardings),
             {"tokens": arr((16, ctx), jnp.int32, b.batch_sharding)}))
-    return {"programs": programs, "kernels": kernels,
-            "pool_movers": pool_movers, "temp_bytes": temp_bytes,
-            "need_bytes": need_bytes}
+    return {"programs": programs, "kernels": kernels, "grids": grids,
+            "scoped_vmem": scoped_vmem, "pool_movers": pool_movers,
+            "temp_bytes": temp_bytes, "need_bytes": need_bytes}
 
 
 @pytest.fixture(scope="module")
@@ -312,6 +354,37 @@ def test_serve_programs_move_no_pool_sized_data(verdict, program, width,
     assert kernel == ("paged_decode_attn" if program == "decode"
                       else "paged_prefill_attn")
     assert re.fullmatch(r"bf16\[\d+,\d+,\d+,64\]", shape), shape
+
+
+@pytest.mark.parametrize("num_blocks", SERVE_POOLS)
+@pytest.mark.parametrize("width", sorted(SERVE_WIDTHS))
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_paged_kernel_walks_the_table_itself(verdict, kind, width, num_blocks):
+    """The table is no grid axis: a call is ``slots x query tiles`` grid
+    steps (36, and 8 for the 1024 bucket) and the walk over a slot's live
+    blocks is a loop inside each, whose K and V buffers fit the chip's
+    scoped VMEM; name and output shape are what the benchmark's metrics
+    match, and the pool goes in where it lies. gpt2-xl's 1,600 lanes are no
+    multiple of 128, and Mosaic refuses to slice such a pool for a DMA:
+    there the groups of eight entries stay a third grid axis (64 / 8)."""
+    name = f"kernel_{kind}_{width}_{num_blocks}"
+    assert verdict["programs"][name] == "ok", verdict["programs"][name]
+    steps = [SERVE_SLOTS, 1] if kind == "decode" else [1, 8]
+    if width == "xl":
+        steps.append(8)
+    assert verdict["grids"][name] == [steps]
+    [vmem] = verdict["scoped_vmem"][name]
+    assert 0 < vmem < V5E_SCOPED_VMEM, vmem
+    [[kernel, shape]] = verdict["kernels"][name]
+    assert kernel == f"paged_{kind}_attn"
+    tokens = "1" if kind == "decode" else r"\d+"
+    assert re.fullmatch(rf"bf16\[\d+,\d+,{tokens},64\]", shape), shape
+    assert verdict["pool_movers"][name] == []
+    # The serve programs whole call it once a layer (their prefill is the
+    # 256 bucket: two tiles).
+    steps[1] = 1 if kind == "decode" else 2
+    serve = f"serve_{kind}_{width}_{num_blocks}"
+    assert verdict["grids"][serve] == [steps] * SERVE_LAYERS
 
 
 @pytest.mark.parametrize("program,kernel,shape", [
