@@ -262,7 +262,7 @@ class Config:
     # of queueing unboundedly (the router also sheds when every replica
     # reports a queue this deep). 0 disables shedding.
     serve_admission_queue_limit = _Flag(32)
-    # Tokens per KV block in the PAGED cache (serve/llm.py PagedLLMEngine +
+    # Tokens per KV block in the paged cache (serve/llm.py LLMEngine +
     # models/generate.py PagedGenerator): sequences hold block TABLES into a
     # shared pool instead of a private max_len slab, and prefix reuse /
     # copy-on-write forks share at this granularity. Smaller blocks = finer
@@ -273,17 +273,6 @@ class Config:
     # hold every slot at max_len, so retired prefixes stay hash-cached for
     # reuse instead of being evicted the moment a new request arrives.
     serve_kv_pool_blocks = _Flag(0)
-    # Engine selection for llm_deployment: 1 serves replicas on the paged
-    # prefix-caching engine (PagedLLMEngine), 0 falls back to the PR 8
-    # slotted engine (LLMEngine). The streaming contract is identical; the
-    # paged engine adds hash-based prefix reuse and COW forks.
-    serve_kv_paged_enabled = _Flag(True)
-    # Prefill/decode disaggregation: 1 splits each llm_deployment replica
-    # into a prefill-specialized engine and a decode-specialized engine that
-    # exchange finished KV blocks over a multi-slot shm Channel lane
-    # (deferred-ack handoff, serve/dag_pipeline.py KVHandoffLane). 0 (the
-    # default) keeps the colocated engine — byte-identical to PR 8 behavior.
-    serve_disaggregation_enabled = _Flag(False)
     # Router prefix affinity: 1 makes DeploymentHandle hash the prompt's
     # leading KV blocks and prefer the replica that served that prefix last
     # (its pool likely still caches those blocks), layered on the
@@ -310,7 +299,7 @@ class Config:
     # deployment): bounds the GCS aggregator query rate from the serve
     # controller regardless of its reconcile cadence.
     serve_slo_rollup_interval_s = _Flag(1.0)
-    # Paged-attention implementation for the paged engine's decode/prefill
+    # Paged-attention implementation for the serve engine's decode/prefill
     # forwards: "auto" picks the fused Pallas kernel on TPU (streams only a
     # slot's live KV blocks through the block table — no [S, max_len, H, D]
     # gather) and the XLA gather path on CPU; "pallas" / "interpret" /
@@ -319,7 +308,7 @@ class Config:
     serve_paged_attention_kernel = _Flag("auto")
     # Speculative decoding: how many draft-model tokens each slot proposes
     # per scan step, all verified in ONE batched target forward. 0 disables
-    # speculation; > 0 requires a draft model (PagedLLMEngine draft_params/
+    # speculation; > 0 requires a draft model (LLMEngine draft_params/
     # draft_config, or llm_deployment draft_params_fn). Acceptance is
     # rejection-sampled so emitted tokens follow the TARGET distribution
     # exactly (greedy output is token-identical to non-speculative greedy).

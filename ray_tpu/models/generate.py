@@ -128,187 +128,6 @@ def _forward_cached(params, tokens, cache, config: TransformerConfig, start_pos)
     return logits, new_cache
 
 
-def init_slot_cache(config: TransformerConfig, slots: int,
-                    max_len: Optional[int] = None) -> Dict:
-    """KV cache for ``slots`` INDEPENDENT sequences: the batch dim is a slot
-    index and ``lengths[s]`` replaces the single scalar ``length`` — each
-    slot decodes at its own position (the continuous-batching layout)."""
-    c = config
-    max_len = max_len or c.max_seq_len
-    shape = (c.n_layers, slots, max_len, c.n_heads, c.head_dim)
-    return {
-        "k": jnp.zeros(shape, c.dtype),
-        "v": jnp.zeros(shape, c.dtype),
-        "lengths": jnp.zeros((slots,), jnp.int32),
-    }
-
-
-def _forward_decode_slotted(params, tokens, k_cache, v_cache, lengths,
-                            config: TransformerConfig):
-    """One decode step for S independent slots: ``tokens`` [S, 1] at per-slot
-    positions ``lengths`` [S]. Writes each slot's new K/V at its own position
-    (scatter over the batch dim — ``dynamic_update_slice`` only takes scalar
-    starts) and attends with per-slot validity. Returns
-    (logits [S, 1, V], new_k, new_v); rows are fully independent, so an
-    inactive slot's garbage output never contaminates its neighbours.
-    """
-    c = config
-    cast = lambda p: p.astype(c.dtype)
-    S, T = tokens.shape  # T == 1
-    M = k_cache.shape[2]
-    h = jnp.take(cast(params["tok_embed"]), tokens, axis=0)
-    # Clamp the write position: a slot parked at the context cap (retired,
-    # awaiting refill) must not scatter out of bounds. Its row's output is
-    # dead either way — the clamp only keeps the scatter well-defined.
-    pos = jnp.minimum(lengths, M - 1)
-    positions = pos[:, None]                              # [S, 1]
-    if c.pos == "learned":
-        h = h + cast(params["pos_embed"])[positions]
-    scale = 1.0 / c.head_dim**0.5
-    rows = jnp.arange(S)
-    valid_len = pos + 1                                   # new token attendable
-
-    new_k, new_v = [], []
-    for layer in range(c.n_layers):
-        bp = jax.tree.map(lambda p: cast(p[layer]), params["blocks"])
-        x = layer_norm(h, bp["ln1_g"], bp["ln1_b"])
-        q = jnp.einsum("btd,dhk->bthk", x, bp["wq"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bq"]
-        k = jnp.einsum("btd,dhk->bthk", x, bp["wk"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bk"]
-        v = jnp.einsum("btd,dhk->bthk", x, bp["wv"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bv"]
-        if c.pos == "rope":
-            q = rope(q, positions)
-            k = rope(k, positions)
-        kc = k_cache[layer].at[rows, pos].set(k[:, 0])
-        vc = v_cache[layer].at[rows, pos].set(v[:, 0])
-        new_k.append(kc)
-        new_v.append(vc)
-        o = _attend_cached(q, kc, vc, valid_len, scale=scale)
-        o = jnp.einsum("bthk,hkd->btd", o, bp["wo"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bo"]
-        h = h + o
-        x = layer_norm(h, bp["ln2_g"], bp["ln2_b"])
-        u = gelu(linear(x, bp["w_up"], bp["b_up"]))
-        h = h + linear(u, bp["w_down"], bp["b_down"])
-
-    h = layer_norm(h, cast(params["lnf_g"]), cast(params["lnf_b"]))
-    w_out = params["tok_embed"].T if c.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", h, cast(w_out), preferred_element_type=jnp.float32)
-    return logits, jnp.stack(new_k), jnp.stack(new_v)
-
-
-class SlottedGenerator:
-    """Compiled slot-level prefill + batched decode for continuous batching.
-
-    The serving engine's device half (``serve/llm.py LLMEngine``): S cache
-    slots hold S independent sequences, and
-
-    - :meth:`prefill_fn` — jitted per prompt bucket — writes ONE prompt's
-      K/V into its slot (``insert_prefill``) and parks its next-token logits
-      in the ``last`` [S, V] carry;
-    - :meth:`decode_fn` — jitted once per chunk size — advances ALL slots by
-      ``chunk`` tokens in ONE dispatch via ``lax.scan``: inactive slots are
-      masked (their ``lengths`` freeze, their ``last``/key rows keep their
-      values), greedy and sampled slots ride the same program through
-      per-slot ``greedy``/``temps`` operands, so everything compiles exactly
-      once per (bucket | chunk) regardless of the traffic mix.
-
-    Device state is the ``(cache, last, keys)`` triple threaded through both
-    functions with buffer donation — the engine must hold only the returned
-    arrays.
-    """
-
-    def __init__(self, params, config: TransformerConfig, *, slots: int,
-                 max_len: Optional[int] = None):
-        self.params = params
-        self.config = config
-        self.slots = slots
-        self.max_len = max_len or config.max_seq_len
-        self.logits_dim = (params["tok_embed"].shape[0]
-                          if config.tie_embeddings
-                          else params["lm_head"].shape[-1])
-        self._prefill_fns = {}  # bucket -> jitted insert_prefill
-        self._decode_fns = {}   # chunk -> jitted decode_chunk
-
-    def init_state(self):
-        cache = init_slot_cache(self.config, self.slots, self.max_len)
-        last = jnp.zeros((self.slots, self.logits_dim), jnp.float32)
-        keys = jnp.zeros((self.slots, 2), jnp.uint32)
-        return cache, last, keys
-
-    def prefill_fn(self, bucket: int):
-        """insert_prefill(params, cache, last, keys, padded [1,P], real_len,
-        slot, seed) -> (cache, last, keys): one prompt's K/V into one slot."""
-        fn = self._prefill_fns.get(bucket)
-        if fn is not None:
-            return fn
-        c = self.config
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
-        def insert_prefill(params, cache, last, keys, padded, real_len, slot,
-                           seed):
-            P = padded.shape[1]
-            tmp = {
-                "k": jnp.zeros((c.n_layers, 1, P, c.n_heads, c.head_dim),
-                               c.dtype),
-                "v": jnp.zeros((c.n_layers, 1, P, c.n_heads, c.head_dim),
-                               c.dtype),
-                "length": jnp.zeros((), jnp.int32),
-            }
-            logits, tmp = _forward_cached(params, padded, tmp, c, 0)
-            k_c = lax.dynamic_update_slice(cache["k"], tmp["k"],
-                                           (0, slot, 0, 0, 0))
-            v_c = lax.dynamic_update_slice(cache["v"], tmp["v"],
-                                           (0, slot, 0, 0, 0))
-            lengths = cache["lengths"].at[slot].set(real_len)
-            row = jax.lax.dynamic_index_in_dim(
-                logits, real_len - 1, axis=1, keepdims=False)      # [1, V]
-            last = lax.dynamic_update_slice(last, row, (slot, 0))
-            keys = lax.dynamic_update_slice(
-                keys, jax.random.PRNGKey(seed)[None], (slot, 0))
-            return {"k": k_c, "v": v_c, "lengths": lengths}, last, keys
-
-        self._prefill_fns[bucket] = insert_prefill
-        return insert_prefill
-
-    def decode_fn(self, chunk: int):
-        """decode_chunk(params, cache, last, keys, active, greedy, temps) ->
-        (toks [S, chunk], cache, last, keys): ``chunk`` scan steps advancing
-        every active slot, one dispatch."""
-        fn = self._decode_fns.get(chunk)
-        if fn is not None:
-            return fn
-        c = self.config
-
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
-        def decode_chunk(params, cache, last, keys, active, greedy, temps):
-            adv = active.astype(jnp.int32)
-            act_col = active[:, None]
-            temp_safe = jnp.maximum(temps, 1e-6)[:, None]
-
-            def step(carry, _):
-                k_c, v_c, lengths, last, keys = carry
-                real = last[:, : c.vocab_size]
-                split = jax.vmap(jax.random.split)(keys)   # [S, 2, 2]
-                keys2, subs = split[:, 0], split[:, 1]
-                samp = jax.vmap(jax.random.categorical)(subs, real / temp_safe)
-                nxt = jnp.where(greedy, jnp.argmax(real, axis=-1),
-                                samp).astype(jnp.int32)
-                logits, k_c, v_c = _forward_decode_slotted(
-                    params, nxt[:, None], k_c, v_c, lengths, c)
-                lengths = lengths + adv
-                last = jnp.where(act_col, logits[:, -1], last)
-                keys = jnp.where(act_col, keys2, keys)
-                return (k_c, v_c, lengths, last, keys), nxt
-
-            (k_c, v_c, lengths, last, keys), toks = lax.scan(
-                step, (cache["k"], cache["v"], cache["lengths"], last, keys),
-                None, length=chunk)
-            return (toks.T, {"k": k_c, "v": v_c, "lengths": lengths}, last,
-                    keys)
-
-        self._decode_fns[chunk] = decode_chunk
-        return decode_chunk
-
-
 def init_block_pool(config: TransformerConfig, num_blocks: int,
                     block_tokens: int) -> Tuple[jax.Array, jax.Array]:
     """Shared paged KV pool: ``num_blocks`` fixed-size blocks of
@@ -491,8 +310,7 @@ class PagedFamily(NamedTuple):
       knows nothing else about them;
     - ``logits_dim(params, config)``: rows of the ``last`` carry;
     - ``unsupported``: engine features the family cannot run yet, refused
-      when an engine is built: ``draft_model``, ``disaggregation``,
-      ``kv_tier``.
+      when an engine is built: ``draft_model``, ``kv_tier``.
 
     A config object names its family through a ``paged_family()`` method;
     one without it is GPT-2 (``transformer.TransformerConfig``)."""
@@ -533,11 +351,12 @@ def paged_family(config) -> PagedFamily:
 
 
 class PagedGenerator:
-    """Paged device half of the serving engine: same compile discipline as
-    :class:`SlottedGenerator` (one program per prompt bucket, one per chunk
-    size), but K/V lives in a SHARED block pool addressed through
-    per-sequence block tables — the layout that makes hash-based prefix
-    reuse, copy-on-write forks and prefill/decode KV handoff possible.
+    """Device half of the serving engine (``serve/llm.py LLMEngine``): one
+    program per prompt bucket, one per chunk size, greedy and sampled slots
+    riding the same program through per-slot operands. K/V lives in a
+    SHARED block pool addressed through per-sequence block tables — the
+    layout that makes hash-based prefix reuse, copy-on-write forks and
+    moving a chain's blocks between pools possible.
 
     Device state is ``(pool, last, keys)`` threaded with buffer donation:
     ``pool`` is the family's tuple of pool arrays (:class:`PagedFamily`; K
@@ -584,8 +403,8 @@ class PagedGenerator:
         self.logits_dim = self.family.logits_dim(params, config)
         self._prefill_fns = {}   # suffix bucket -> jitted paged prefill
         self._decode_fns = {}    # chunk -> jitted paged decode
-        self._extract_fns = {}   # nb -> jitted block gather (KV handoff out)
-        self._insert_fns = {}    # nb -> jitted block scatter (KV handoff in)
+        self._extract_fns = {}   # nb -> jitted block gather (KV tier out)
+        self._insert_fns = {}    # nb -> jitted block scatter (KV tier in)
         self._copy_fn = None
         self._draft_prefill_fns = {}  # suffix bucket -> jitted draft prefill
         self._spec_decode_fns = {}    # (chunk, k) -> jitted spec decode
@@ -916,10 +735,9 @@ class PagedGenerator:
 
     def extract_fn(self, nb: int):
         """extract(pool, block_ids [nb]) -> the blocks of every pool array
-        (GPT-2: ``(k [L,nb,bt,H*Dh], v)``): gather a finished prefill's
-        blocks for the disaggregation handoff (the pool itself is NOT
-        donated — the prefill engine keeps serving its prefix cache from
-        it)."""
+        (GPT-2: ``(k [L,nb,bt,H*Dh], v)``): gather a chain's blocks for the
+        KV tier's spill or drain migration (the pool itself is NOT donated
+        — the engine keeps serving from it)."""
         fn = self._extract_fns.get(nb)
         if fn is None:
 
@@ -931,8 +749,8 @@ class PagedGenerator:
         return fn
 
     def insert_fn(self, nb: int):
-        """insert(pool, blocks, block_ids [nb]) -> pool: scatter handed-off
-        blocks (a tuple shaped as ``extract`` gives them) into the decode
+        """insert(pool, blocks, block_ids [nb]) -> pool: scatter fetched or
+        migrated blocks (a tuple shaped as ``extract`` gives them) into the
         pool — donated, so the upload lands in place of the old pool
         buffers."""
         fn = self._insert_fns.get(nb)
@@ -945,22 +763,6 @@ class PagedGenerator:
 
             fn = self._insert_fns[nb] = insert
         return fn
-
-    def set_last_fn(self):
-        """set_last(last, keys, row [V], slot, seed) -> (last, keys): park a
-        handed-off request's next-token logits + PRNG key in its decode
-        slot (the decode-side half of the prefill handoff)."""
-        if not hasattr(self, "_set_last_fn") or self._set_last_fn is None:
-
-            @functools.partial(jax.jit, donate_argnums=(0, 1))
-            def set_last(last, keys, row, slot, seed):
-                last = lax.dynamic_update_slice(last, row[None], (slot, 0))
-                keys = lax.dynamic_update_slice(
-                    keys, jax.random.PRNGKey(seed)[None], (slot, 0))
-                return last, keys
-
-            self._set_last_fn = set_last
-        return self._set_last_fn
 
 
 class NoFreeBlocks(RuntimeError):

@@ -371,6 +371,6 @@ PAGED_FAMILY = PagedFamily(
     # Each needs work this family has not had: a draft model of its own
     # family and pool, a handoff lane and a tier payload that carry one
     # latent array instead of a (k, v) pair.
-    unsupported=("draft_model", "disaggregation", "kv_tier"),
+    unsupported=("draft_model", "kv_tier"),
     aux_counts=AUX_COUNTS,
 )

@@ -3,7 +3,7 @@
 The workload that ties the serving and training stacks together
 (PAPERS.md RLAX): **generation actors** sample completions for a prompt
 dataset from the paged continuous-batching engine
-(``serve/llm.py PagedLLMEngine`` over ``models/generate.py`` — repeated
+(``serve/llm.py LLMEngine`` over ``models/generate.py`` — repeated
 prompts hit the prefix cache, so rollout prefill cost amortizes across
 rounds), a **pluggable reward function** scores them into the replay
 buffer (``rllib/replay.py``), and a **policy-gradient learner** updates a
@@ -83,12 +83,12 @@ class GenerationActor:
     padded columnar rollout (tokens / mask / behavior log-probs)."""
 
     def __init__(self, model_config, *, slots: int = 2, seed: int = 0):
-        from ray_tpu.serve.llm import PagedLLMEngine
+        from ray_tpu.serve.llm import LLMEngine
 
         self.model_config = model_config
         self._seed = seed
         params = transformer.init_params(model_config, jax.random.key(seed))
-        self._engine = PagedLLMEngine(
+        self._engine = LLMEngine(
             params, model_config, slots=slots,
             max_len=model_config.max_seq_len, chunk=4, name="llm-rl-gen")
         self._max_len = int(model_config.max_seq_len)

@@ -167,27 +167,29 @@ def build_compiled_pipeline(controller, stage_names: List[str], *,
 
 
 class KVHandoffLane:
-    """Prefill→decode KV-block transport over one multi-slot shm
-    :class:`~ray_tpu.dag.channel.Channel` — the disaggregated-serving lane.
+    """Engine→engine KV-block transport over one multi-slot shm
+    :class:`~ray_tpu.dag.channel.Channel` — the lane the cluster KV tier's
+    drain-by-migration ships a retiring replica's warm chains over
+    (``serve/llm.py LLMEngine.kv_migrate_out`` / ``kv_migrate_in``).
 
-    A finished prefill's pool blocks travel as one framed payload::
+    A chain's pool blocks travel as one framed payload::
 
         [meta_len, k_len, v_len : <QQQ>] [pickled meta] [raw K] [raw V]
 
-    where meta carries the request (prompt, sampling params, last-token
-    logits row) and the K/V dtype+shape needed to reinterpret the raw bytes.
+    where meta carries the chain (its tokens and how many are real) and the
+    K/V dtype+shape needed to reinterpret the raw bytes.
     ``send`` lands the arrays DIRECTLY in the ring slot via the channel's
     ``_wait_writable``/``_publish`` split (no intermediate buffer), and
     ``recv`` returns zero-copy ``np.frombuffer`` views into the slot plus an
     ack token: the DEFERRED-ACK protocol (``_consume_view``/``_ack``) built
-    for DMA in PR 7 — the decode engine uploads the views into its own pool
-    (a donated ``insert_fn`` dispatch), blocks until the transfer lands,
-    and only then releases the slot back to the prefill writer. Up to
-    ``slots`` handoffs ride in flight, so prefill keeps producing while
-    decode drains.
+    for DMA in PR 7 — the receiving engine uploads the views into its own
+    pool (a donated ``insert_fn`` dispatch), blocks until the transfer
+    lands, and only then releases the slot back to the writer. Up to
+    ``slots`` handoffs ride in flight, so the sender keeps extracting while
+    the receiver drains.
 
-    Single-writer (prefill side) / single-reader (decode side), in- or
-    cross-process: a remote decode replica attaches by ``name`` with
+    Single-writer (the victim) / single-reader (the survivor), in- or
+    cross-process: the other endpoint attaches by ``name`` with
     ``create=False``, same as every other channel endpoint.
     """
 
@@ -223,7 +225,7 @@ class KVHandoffLane:
                     return None
                 time.sleep(0.01)
 
-    # -- writer half (prefill engine) -----------------------------------------
+    # -- writer half (the sending engine) -------------------------------------
     def send(self, meta: dict, k: np.ndarray, v: np.ndarray,
              timeout: Optional[float] = 30.0) -> None:
         from ray_tpu.core import serialization
@@ -253,7 +255,7 @@ class KVHandoffLane:
             v.reshape(-1).view(np.uint8)
         self.chan._publish(total)
 
-    # -- reader half (decode engine) ------------------------------------------
+    # -- reader half (the receiving engine) -----------------------------------
     def recv(self, timeout: Optional[float] = 30.0
              ) -> Tuple[dict, np.ndarray, np.ndarray, Tuple[int, int]]:
         """Return ``(meta, k, v, ack_token)``. ``k``/``v`` are views into

@@ -1,11 +1,15 @@
-"""LLM serving — continuous-batching KV-cache engine + Serve deployment.
+"""LLM serving — one continuous-batching engine over a paged KV cache, and
+its Serve deployment.
 
 The reference serves LLMs by embedding engines (vLLM) inside replicas;
-TPU-native the engine is jitted XLA programs (``models/generate.py``) over a
-SLOTTED KV cache: S independent sequences share one cache with per-slot
-positions, and every decode dispatch advances ALL active slots at once — the
-matmuls run at batch S instead of batch 1, which is the difference between
-feeding the MXU and starving it.
+TPU-native the engine is jitted XLA programs (``models/generate.py
+PagedGenerator``: ``paged_prefill`` per prompt bucket, ``paged_decode`` per
+chunk size) over a shared pool of ``serve_kv_block_tokens``-sized KV blocks.
+S independent sequences address the pool through per-slot block tables, and
+every decode dispatch advances ALL active slots at once — the matmuls run at
+batch S instead of batch 1, which is the difference between feeding the MXU
+and starving it. A host-side ``KVBlockManager`` keeps the blocks' refcounts
+and the hash table that lets a prompt reuse the blocks of an earlier one.
 
 Scheduling is iteration-level (the vLLM/Orca policy): each engine step
 
@@ -13,7 +17,9 @@ Scheduling is iteration-level (the vLLM/Orca policy): each engine step
    chunk before ``max_len`` — ``length_cap``) and immediately
 2. admits queued prompts into the free slots, bounded by a prefill token
    budget per step (``serve_llm_prefill_tokens``) so a burst of long
-   prompts can't starve in-flight decode, then
+   prompts can't starve in-flight decode; an admission prefills only the
+   suffix the prefix cache does not already hold, and a prompt the pool has
+   no blocks for goes back to the head of the queue; then
 3. runs ONE batched decode chunk and distributes each slot's tokens to its
    request's queue.
 
@@ -24,10 +30,10 @@ its consumers read. Admission control sheds with :class:`~ray_tpu.serve.
 errors.Saturated` once ``max_queue`` requests are already waiting for a
 slot (beyond those the free slots will take over the next steps).
 
-Prompt bucketing is unchanged from the single-sequence engine: prompts pad
-to a power-of-two bucket (one prefill compile per bucket, warmed at replica
-start), first-token logits are read at the REAL last position, and decode
-overwrites pad garbage before the causal mask could ever expose it.
+Prompts pad to a power-of-two bucket (one prefill compile per bucket, warmed
+at replica start), first-token logits are read at the REAL last position,
+and decode overwrites pad garbage before the causal mask could ever expose
+it.
 """
 
 from __future__ import annotations
@@ -44,9 +50,7 @@ import numpy as np
 
 from ray_tpu.devtools import jitcheck
 from ray_tpu.models.generate import (KVBlockManager, NoFreeBlocks,
-                                     PagedGenerator, SlottedGenerator,
-                                     paged_family)
-from ray_tpu.models.transformer import TransformerConfig
+                                     PagedGenerator, paged_family)
 from ray_tpu.serve.errors import Saturated
 from ray_tpu.util import tracing
 from ray_tpu.utils.logging import get_logger
@@ -106,7 +110,7 @@ class _Request:
         "seed", "tokens", "cond", "slot", "emitted", "done", "cancelled",
         "error", "finish_reason", "decode_tokens", "decode_seconds",
         "submitted_at", "ttft_s", "trace_ctx", "queued_s", "prefill_s",
-        "out_ids", "blocks", "hit_tokens", "preloaded",
+        "out_ids", "hit_tokens",
         "submitted_ns", "prefill_end_ns", "prefill_span",
     )
 
@@ -142,16 +146,11 @@ class _Request:
         # Id of the request's ``llm.prefill`` span, allocated at admission
         # so that ``kv.alloc`` can parent to it before it is recorded.
         self.prefill_span: Optional[str] = None
-        # Every delivered token id, in order — the paged engine registers
-        # the finished prompt+output chain in the prefix cache at retire.
+        # Every delivered token id, in order — the engine registers the
+        # finished prompt+output chain in the prefix cache at retire.
         self.out_ids: List[int] = []
-        # Paged-engine state: pool blocks pinned for a QUEUED request that
-        # already owns them (disaggregation handoff), prefix-cache hit size,
-        # and — for handed-off requests — the prefill's last-token logits
-        # row (None means prefill runs locally at admission).
-        self.blocks: List[int] = []
+        # Prompt tokens the prefix cache already held at admission.
         self.hit_tokens = 0
-        self.preloaded: Optional[np.ndarray] = None
         # Captured at submit time on the request's own thread; engine spans
         # must use THIS explicit context (the step loop runs on whichever
         # thread won the driver election — its ambient context belongs to a
@@ -312,24 +311,53 @@ class _StepTrace:
 
 
 class LLMEngine:
-    """Continuous-batching engine: S cache slots, caller-driven stepping.
+    """Continuous-batching engine over a PAGED KV cache with prefix reuse:
+    S cache slots, caller-driven stepping.
 
-    The single-sequence surface (``stream``/``generate``/``warmup``) is
-    unchanged; concurrency comes from calling
-    ``stream`` from many threads — their sequences SHARE the batched decode
-    dispatches instead of queueing behind each other.
+    ``stream``/``generate`` serve one request each; concurrency comes from
+    calling ``stream`` from many threads — their sequences SHARE the batched
+    decode dispatches instead of queueing behind each other.
+
+    The device half is a shared pool of ``serve_kv_block_tokens``-sized KV
+    blocks (:class:`~ray_tpu.models.generate.PagedGenerator`) addressed
+    through per-slot block tables, with a host-side :class:`~ray_tpu.models.
+    generate.KVBlockManager` doing refcounts and hash-based prefix reuse:
+
+    - admission looks the prompt up in the block-hash table and prefills
+      ONLY the uncached suffix (``start_pos = hit_len``) — a shared system
+      prompt or multi-turn history costs its prefill FLOPs once;
+    - a hit on a retired sequence's partial tail block is copy-on-write:
+      the block is duplicated into a private block before the divergent
+      suffix writes into it, full-block hits share by refcount alone;
+    - at retire the finished prompt+output chain is registered so the NEXT
+      turn of the conversation hits it;
+    - pool exhaustion (after LRU-evicting unpinned cached blocks) requeues
+      the request rather than failing it.
     """
 
-    def __init__(self, params, config: TransformerConfig, *,
+    def __init__(self, params, config, *,
                  max_len: Optional[int] = None,
                  prompt_buckets: Optional[Sequence[int]] = None,
                  chunk: int = 8,
                  slots: Optional[int] = None,
                  max_queue: Optional[int] = None,
-                 name: str = "LLM"):
+                 name: str = "LLM",
+                 block_tokens: Optional[int] = None,
+                 pool_blocks: Optional[int] = None,
+                 attention_kernel: Optional[str] = None,
+                 draft_params=None,
+                 draft_config=None,
+                 spec_tokens: Optional[int] = None):
         from ray_tpu.core.config import config as _get_config
+        from ray_tpu.serve.kv_tier import kv_tier_enabled
 
         knobs = _get_config()
+        # What the family cannot run yet is refused HERE, not in a step
+        # (a draft model: by the generator, below).
+        if kv_tier_enabled() and "kv_tier" in paged_family(config).unsupported:
+            raise ValueError(
+                f"{type(config).__name__}: the cluster KV tier "
+                f"(kv_tier_enabled) is not supported for this family yet")
         self.params = params
         self.config = config
         self.max_len = max_len or config.max_seq_len
@@ -340,7 +368,51 @@ class LLMEngine:
                              else knobs.serve_admission_queue_limit)
         self.prefill_budget = int(knobs.serve_llm_prefill_tokens)
         self.name = name
-        self._init_device()
+        self.block_tokens = int(block_tokens if block_tokens is not None
+                                else knobs.serve_kv_block_tokens)
+        self.attention_kernel = str(
+            attention_kernel if attention_kernel is not None
+            else knobs.serve_paged_attention_kernel)
+        self.spec_k = int(spec_tokens if spec_tokens is not None
+                          else knobs.serve_spec_tokens)
+        if self.spec_k > 0 and draft_params is None:
+            raise ValueError(
+                "serve_spec_tokens > 0 needs a draft model "
+                "(draft_params/draft_config)")
+        self._draft_params = draft_params
+        self._spec = self.spec_k > 0
+        self._spec_floor = float(knobs.serve_spec_accept_floor)
+        self._spec_alpha = float(knobs.serve_spec_accept_alpha)
+
+        self.blocks_per_seq = -(-self.max_len // self.block_tokens)
+        num_blocks = int(pool_blocks if pool_blocks is not None
+                         else knobs.serve_kv_pool_blocks)
+        if not num_blocks:
+            # Auto pool size: 2x a full slot set plus the trash block — half
+            # the pool can idle as reusable prefix cache under full load.
+            num_blocks = 2 * self.slots * self.blocks_per_seq + 1
+        self._pg = PagedGenerator(params, config, slots=self.slots,
+                                  num_blocks=num_blocks,
+                                  block_tokens=self.block_tokens,
+                                  max_len=self.max_len,
+                                  attention_kernel=self.attention_kernel,
+                                  draft_params=draft_params,
+                                  draft_config=draft_config)
+        self.kv = KVBlockManager(num_blocks, self.block_tokens)
+        self._pool, self._last, self._keys = self._pg.init_state()
+        # The family's per-call counts (None for GPT-2): the decode call's
+        # and this step's prefills', fetched with the step's tokens.
+        self._decode_aux = None
+        self._prefill_aux: List = []
+        self._aux_totals = {
+            key: 0 for n in self._pg.family.aux_counts
+            for key in (n.decode, n.prefill) if key}
+        self._slot_table = np.zeros((self.slots, self.blocks_per_seq),
+                                    np.int32)
+        self._slot_blocks: List[List[int]] = [[] for _ in range(self.slots)]
+        self._hit_pending = 0  # hit tokens awaiting metric flush (step thread)
+        self._init_tier_state()
+        self._init_spec_state()
 
         # Lock order: _step_lock (try-acquired, never under others) →
         # _state_lock (request/slot bookkeeping; also every req.cond) →
@@ -383,104 +455,148 @@ class LLMEngine:
         # when the next step starts. Step-thread-owned.
         self._blocked_since_ns: Optional[int] = None
 
-    # -- device-half hooks (the paged engine overrides these) -----------------
-    # The scheduler above them — admission budget, slot bookkeeping, token
-    # distribution, the streaming contract — is engine-agnostic; everything
-    # cache-layout-specific funnels through this narrow seam.
-    def _init_device(self) -> None:
-        if not isinstance(self.config, TransformerConfig):
-            raise ValueError(
-                f"{type(self.config).__name__}: the slotted engine runs the "
-                f"GPT-2 family only; serve this family through the paged "
-                f"engine (serve_kv_paged_enabled=1)")
-        self._sg = SlottedGenerator(self.params, self.config,
-                                    slots=self.slots, max_len=self.max_len)
-        self._cache, self._last, self._keys = self._sg.init_state()
+    def _init_tier_state(self) -> None:
+        # Cluster KV tier (serve/kv_tier.py). All tier state is touched
+        # under the locks noted inline; with the flag off every field stays
+        # empty and every tier branch is dead — exact engine-private
+        # behavior.
+        from ray_tpu.serve.kv_tier import KVTier, kv_tier_enabled
+
+        self._tier = KVTier(self.name) if kv_tier_enabled() else None
+        from ray_tpu.core.config import config as _get_config
+
+        try:
+            knobs = _get_config()
+            self._tier_min_spill = max(
+                1, int(knobs.kv_tier_min_spill_blocks))
+        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
+            self._tier_min_spill = 1
+        # Retired chains pinned for spill: (chain, full_ids, n_full,
+        # digests — the chain's full-block hash list).
+        # Appended under _state_lock by the step thread's retire phase,
+        # drained by _post_step — both inside the _step_lock scope.
+        self._tier_spill_q: List[tuple] = []
+        # head digest -> (chain tuple, n_real): the drain-migration export
+        # set (active sessions' chains). Insertion-ordered LRU, bounded.
+        self._tier_chains: "Dict[bytes, tuple]" = {}
+        # Digests of chains that arrived via drain migration (ordered-set
+        # dict, bounded) — attributes their local hits to source=migrated.
+        self._tier_migrated: "Dict[bytes, None]" = {}
+        self._tier_hits_pending = {"local": 0, "store": 0, "migrated": 0}
+        self._tier_hits_total = {"local": 0, "store": 0, "migrated": 0}
+        self._tier_spill_bytes_pending = 0
+        self._tier_fetch_bytes_pending = 0
+
+    _TIER_CHAIN_CAP = 512       # migration export set
+    _TIER_MIGRATED_CAP = 4096   # migrated-digest attribution set
+
+    def _tier_note_chain_locked(self, head: bytes, chain, n_real: int) -> None:
+        # Under _state_lock. LRU re-insert, like the KV manager's cache.
+        self._tier_chains.pop(head, None)
+        self._tier_chains[head] = (tuple(int(t) for t in chain), int(n_real))
+        while len(self._tier_chains) > self._TIER_CHAIN_CAP:
+            self._tier_chains.pop(next(iter(self._tier_chains)))
+
+    def _init_spec_state(self) -> None:
+        # Speculative-decoding host state — all [S], step-thread-owned
+        # except the per-slot resets at admission/release (under
+        # _state_lock, which the step thread also holds there).
+        if not self._spec:
+            return
+        self._draft_pool = self._pg.init_draft_state()
+        self._spec_tail = np.zeros(self.slots, np.int32)
+        self._spec_pending = np.zeros(self.slots, np.int32)
+        self._spec_use_pending = np.zeros(self.slots, bool)
+        self._spec_ewma = np.ones(self.slots, np.float32)
+        self._spec_on = np.zeros(self.slots, bool)
+        self._last_counts = None        # last spec step's [S, chunk] advances
+        self._spec_last_accept = np.zeros(self.slots, np.int64)
+        self._spec_last_on = np.zeros(self.slots, bool)
+        self._spec_last_dt = 0.0
+        self._spec_proposed_pending = 0  # await metric flush (step thread)
+        self._spec_accepted_pending = 0
+        self._spec_proposed_total = 0
+        self._spec_accepted_total = 0
 
     def _reset_device_state(self) -> None:
-        self._cache, self._last, self._keys = self._sg.init_state()
+        """A fresh empty engine on the device: after warm-up, and after a
+        failed dispatch took the in-flight requests' cache state with it."""
+        self._pool, self._last, self._keys = self._pg.init_state()
+        self._decode_aux, self._prefill_aux = None, []
+        # Pool contents are gone — the prefix cache resets with it. Queued
+        # spill entries and tracked chains point into the dead pool, so
+        # they go too (their pins die with the replaced manager); chains
+        # ALREADY published to the tier survive — those payloads are host
+        # copies in the object plane, not pool references.
+        self.kv = KVBlockManager(self.kv.num_blocks, self.block_tokens)
+        self._slot_table[:] = 0
+        self._slot_blocks = [[] for _ in range(self.slots)]
+        self._tier_spill_q = []
+        self._tier_chains = {}
+        self._tier_migrated = {}
+        self._init_spec_state()
 
-    def _admission_cost(self, req: _Request) -> int:
-        """Prefill tokens this admission charges against the step budget
-        (called under _state_lock)."""
-        return req.bucket
-
-    def _dispatch_prefill(self, req: _Request, slot: int) -> None:
-        """Run the prompt's prefill into ``slot``. May raise
-        :class:`NoFreeBlocks` (paged pool exhausted) — the scheduler requeues
-        the request at the head and stops admitting this step."""
-        pf = self._sg.prefill_fn(req.bucket)
-        self._cache, self._last, self._keys = pf(
-            self.params, self._cache, self._last, self._keys,
-            req.padded, req.real_len, slot, req.seed)
-
-    def _decode_operands_locked(self):
-        """Extra decode operands snapshotted under _state_lock (the paged
-        engine's block tables/lengths — mutated by cancel paths, so they
-        must be captured atomically with the active mask)."""
-        return None
-
-    def _run_decode(self, active, greedy, temps, extra):
-        df = self._sg.decode_fn(self.chunk)
-        toks, self._cache, self._last, self._keys = df(
-            self.params, self._cache, self._last, self._keys,
-            active, greedy, temps)
-        return toks
-
-    def _slot_result(self, host_toks, slot: int):
-        """The step's emitted tokens for ``slot`` plus its device-length
-        advance. The base engine always emits exactly ``chunk`` tokens; the
-        speculative paged engine emits a variable 1..chunk*(k+1) depending
-        on per-step acceptance. Called under _state_lock."""
-        return [int(t) for t in host_toks[slot][:self.chunk]], self.chunk
-
-    def _step_spec_attrs(self) -> Optional[Dict]:
-        """Extra attrs for the step's ``llm.step`` span (the speculative
-        engine reports the step's proposed/accepted counts)."""
-        return None
-
-    def _take_step_aux(self):
-        """Device arrays to fetch WITH the step's tokens, in its one
-        ``device_get`` (the paged engine: the family's per-call counts)."""
-        return None
-
-    def _fold_step_aux(self, host_aux, st: "_StepTrace") -> None:
-        """Fold what ``_take_step_aux`` handed over, now on the host."""
-
-    def _release_slot_device(self, slot: int) -> None:
-        """Per-slot device-side cleanup when a slot frees (paged: unpin the
-        slot's blocks). Called under _state_lock; must be idempotent."""
-
-    def _on_retire_locked(self, req: _Request) -> None:
-        """A request finished cleanly ("stop"/"length_cap") and still owns
-        its slot (paged: publish its prefix into the reuse cache). Called
-        under _state_lock just before the slot frees."""
-
-    def _discard_request_locked(self, req: _Request) -> None:
-        """A request is leaving the engine WITHOUT owning a slot (cancelled
-        while queued, or poisoned by a device failure) — drop any resources
-        it holds directly (paged: pre-attached handoff blocks)."""
-
-    # -- public single-request surface (back-compat) -------------------------
+    # -- public single-request surface ---------------------------------------
     def warmup(self) -> None:
         """Compile prefill for every bucket + the decode chunk, then reset —
         TTFT never pays XLA compilation. One program per bucket and one per
         chunk size: greedy vs sampled is an operand, not a recompile."""
         wt = _WarmupTrace(self)
         with self._step_lock:
+            zero_row = np.zeros(self.blocks_per_seq, np.int32)  # all trash
             for b in self.buckets:
-                with wt.program("prefill", b):
-                    pf = self._sg.prefill_fn(b)
-                    self._cache, self._last, self._keys = pf(
-                        self.params, self._cache, self._last, self._keys,
-                        np.zeros((1, b), np.int32), b, 0, 0)
-            with wt.program("decode"):
-                df = self._sg.decode_fn(self.chunk)
-                toks, self._cache, self._last, self._keys = df(
-                    self.params, self._cache, self._last, self._keys,
+                with wt.program("paged_prefill", b):
+                    pf = self._pg.prefill_fn(b)
+                    self._pool, self._last, self._keys, _aux = pf(
+                        self.params, self._pool, self._last,
+                        self._keys, zero_row, np.zeros((1, b), np.int32),
+                        0, b, 0, 0)
+            with wt.program("paged_decode"):
+                df = self._pg.decode_fn(self.chunk)
+                toks, self._pool, self._last, self._keys, _aux = df(
+                    self.params, self._pool, self._last,
+                    self._keys, np.zeros((self.slots, self.blocks_per_seq),
+                                         np.int32),
+                    np.zeros(self.slots, np.int32),
                     np.zeros(self.slots, bool), self._greedy, self._temps)
                 np.asarray(toks)
-            self._cache, self._last, self._keys = self._sg.init_state()
+            with wt.program("copy_block"):
+                cf = self._pg.copy_fn()
+                self._pool = cf(self._pool, 0, 0)
+            if self._tier is not None:
+                # Tier upload/download programs: compile HERE so a cold
+                # replica's first store fetch never pays XLA on its TTFT
+                # (block 0 is the padding block — inserting zeros is inert).
+                with wt.program("kv_tier_blocks"):
+                    k_pool = self._pool[0]
+                    zb = np.zeros((k_pool.shape[0], 1)
+                                  + tuple(k_pool.shape[2:]), k_pool.dtype)
+                    self._tier_insert_blocks(zb, zb, [0])
+                    self._tier_extract_blocks([0])
+            if self._spec:
+                for b in self.buckets:
+                    with wt.program("draft_prefill", b):
+                        dpf = self._pg.draft_prefill_fn(b)
+                        self._draft_pool = dpf(
+                            self._draft_params, self._draft_pool,
+                            zero_row, np.zeros((1, b), np.int32), 0, b)
+                self._draft_pool = cf(self._draft_pool, 0, 0)
+                with wt.program("spec_decode"):
+                    sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
+                    out = sf(self.params, self._draft_params, self._pool,
+                             self._draft_pool, self._last, self._keys,
+                             np.zeros((self.slots, self.blocks_per_seq),
+                                      np.int32),
+                             np.zeros(self.slots, np.int32),
+                             np.zeros(self.slots, bool), self._greedy,
+                             self._temps, np.zeros(self.slots, bool),
+                             np.zeros(self.slots, np.int32),
+                             np.zeros(self.slots, np.int32),
+                             np.zeros(self.slots, bool))
+                    np.asarray(out[0])
+                (self._pool, self._draft_pool,
+                 self._last, self._keys) = out[3:7]
+            self._reset_device_state()
             self._steady = True
         wt.close()
 
@@ -496,6 +612,15 @@ class LLMEngine:
             if n <= b:
                 return b
         raise ValueError(f"prompt of {n} tokens exceeds max_len {self.max_len}")
+
+    def _suffix_bucket(self, n: int) -> int:
+        # The suffix prefill's compile bucket — unlike _bucket_for it needs
+        # no decode-chunk headroom check (submit already validated the full
+        # prompt against max_len).
+        for b in self.buckets:
+            if n <= b:
+                return b
+        return self.buckets[-1]
 
     def stream(self, prompt_ids: Sequence[int], *, max_new_tokens: int = 32,
                temperature: float = 0.0, seed: int = 0,
@@ -627,8 +752,6 @@ class LLMEngine:
             slot = req.slot
             if slot is not None:
                 self._free_slot_locked(slot)
-            else:
-                self._discard_request_locked(req)
             req.done = True
             if req.finish_reason is None:
                 req.finish_reason = "cancelled"
@@ -648,13 +771,58 @@ class LLMEngine:
                             "finish_reason": req.finish_reason})
 
     def _free_slot_locked(self, slot: int) -> None:
-        self._release_slot_device(slot)
+        """Unpin the slot's blocks and clear its bookkeeping. Under
+        _state_lock; idempotent."""
+        ids = self._slot_blocks[slot]
+        if ids:
+            self._slot_blocks[slot] = []
+            self._slot_table[slot, :] = 0
+            self.kv.release(ids)
+        if self._spec:
+            self._spec_on[slot] = False
+            self._spec_use_pending[slot] = False
         r = self._slot_req[slot]
         if r is not None:
             r.slot = None
         self._slot_req[slot] = None
         self._slot_len[slot] = 0
         self._active[slot] = False
+
+    def _on_retire_locked(self, req: _Request) -> None:
+        """A request finished cleanly ("stop"/"length_cap") and still owns
+        its slot: publish its chain into the prefix cache. Under _state_lock,
+        just before the slot frees."""
+        ids = self._slot_blocks[req.slot]
+        if not ids:
+            return
+        # Register the finished prompt+output chain (including a partial
+        # tail entry) — the conversation's next turn extends exactly this
+        # token sequence. Tokens past `emitted` (final-chunk spill) were
+        # written to the pool but are NOT part of the chain, and
+        # register_chain only publishes blocks fully covered by n_real.
+        chain = [int(t) for t in req.prompt] + req.out_ids[:req.emitted]
+        n_real = min(len(chain), len(ids) * self.block_tokens)
+        self.kv.register_chain(chain, ids, n_real)
+        if self._tier is None:
+            return
+        # Refcounted publish from the retire path: pin the chain's FULL
+        # blocks (their content is final) and queue them for the spill
+        # drain in _post_step — LRU eviction can't beat the extract to
+        # them, and the pins drop the moment the payload is off-device.
+        from ray_tpu.util import blockhash
+
+        bt = self.block_tokens
+        n_full = n_real // bt
+        if n_full < self._tier_min_spill:
+            return
+        digests = blockhash.block_hashes(chain, bt, max_blocks=n_full)
+        head = digests[-1]
+        self._tier_note_chain_locked(head, chain[:n_real], n_real)
+        if not self._tier.is_published(head):
+            full_ids = list(ids[:n_full])
+            self.kv.pin(full_ids)
+            self._tier_spill_q.append(
+                (list(chain), full_ids, n_full, digests))
 
     def _finish_locked(self, req: _Request, reason: str) -> None:
         req.finish_reason = reason
@@ -672,8 +840,6 @@ class LLMEngine:
         with self._state_lock:
             victims = list(self._waiting) + [r for r in self._slot_req
                                              if r is not None]
-            for r in self._waiting:
-                self._discard_request_locked(r)
             self._waiting.clear()
             for slot in range(self.slots):
                 self._free_slot_locked(slot)
@@ -718,10 +884,31 @@ class LLMEngine:
         self._record_step(st)
 
     def _post_step(self) -> None:
-        """Post-iteration hook, still under _step_lock (the paged engine
-        drains its KV-tier spill queue here — EVERY step runs it, including
-        the one that retires the last request, so spill pins never strand
-        on an idle engine)."""
+        """Drain the KV tier's spill queue (chains pinned at retire), still
+        under _step_lock: extract the full blocks off-device and publish
+        them to the cluster tier, then unpin. EVERY step runs it, including
+        the one that retires the last request, so spill pins never strand on
+        an idle engine. Best-effort — a tier failure must never poison
+        serving (the chain stays locally cached either way)."""
+        if self._tier is None or not self._tier_spill_q:
+            return
+        q, self._tier_spill_q = self._tier_spill_q, []
+        for chain, ids, n_full, digests in q:
+            try:
+                if not self._tier.is_published(digests[-1]):
+                    k, v = self._tier_extract_blocks(ids)
+                    payload = {"k": k, "v": v,
+                               "tokens": list(chain[:n_full
+                                                    * self.block_tokens])}
+                    self._tier.publish_chain(digests, payload,
+                                             n_full * self.block_tokens,
+                                             n_full)
+                    self._tier_spill_bytes_pending += (
+                        payload["k"].nbytes + payload["v"].nbytes)
+            except Exception:  # noqa: BLE001 — spill is best-effort
+                logger.exception("kv tier spill failed on %s", self.name)
+            finally:
+                self.kv.release(ids)
 
     def _record_step(self, st: _StepTrace) -> None:
         """Fold one finished step into the counters and, traced, record it
@@ -785,7 +972,10 @@ class LLMEngine:
                     stopped = "no_slot"
                     break
                 nxt = self._waiting[0]
-                cost = self._admission_cost(nxt)
+                # What this admission charges against the budget: the bucket
+                # of the suffix the prefix cache does not already hold.
+                hit = self.kv.peek_hit_len([int(t) for t in nxt.prompt])
+                cost = self._suffix_bucket(max(1, nxt.real_len - hit))
                 if admitted_tokens and (
                         admitted_tokens + cost > self.prefill_budget):
                     stopped = "budget"
@@ -846,7 +1036,12 @@ class LLMEngine:
             active = self._active.copy()
             greedy = self._greedy.copy()
             temps = self._temps.copy()
-            extra = self._decode_operands_locked()
+            # Cancel paths mutate the block tables and lengths, so they are
+            # captured atomically with the active mask.
+            tables = self._slot_table.copy()
+            lengths = np.asarray(self._slot_len, np.int32)
+            spec_ops = (self._spec_operands_locked(lengths)
+                        if self._spec else None)
 
         # 3. One batched decode chunk advancing every active slot: the
         #    jitted call returns (dispatch), then the step's single device
@@ -854,7 +1049,8 @@ class LLMEngine:
         #    dispatch to read acceptance counts, so there the wait is short.
         st.enter("dispatch")
         t0_ns = st.marks[-1][1]
-        toks = self._run_decode(active, greedy, temps, extra)
+        toks = self._run_decode(active, greedy, temps, tables, lengths,
+                                spec_ops)
         st.enter("device_wait")
         host_toks, host_aux = jax.device_get((toks, self._take_step_aux()))
         st.enter("deliver")
@@ -867,12 +1063,19 @@ class LLMEngine:
         ttfts: List[tuple] = []  # (total, queued, prefill) per first token
         batch_size = int(active.sum())
         firsts: List[tuple] = []  # sampled requests' (req, slot, ntok)
+        spec_step = self._spec and self._last_counts is not None
         with self._state_lock:
             for slot in range(self.slots):
                 req = self._slot_req[slot]
                 if req is None or not active[slot]:
                     continue
-                emitted, adv = self._slot_result(host_toks, slot)
+                # A plain step emits exactly ``chunk`` tokens a slot; a
+                # speculative one 1..chunk*(k+1), by the step's acceptance.
+                if spec_step:
+                    emitted, adv = self._spec_slot_result(host_toks, slot)
+                else:
+                    emitted = [int(t) for t in host_toks[slot][:self.chunk]]
+                    adv = self.chunk
                 self._slot_len[slot] += adv
                 if req.cancelled:
                     self._free_slot_locked(slot)
@@ -913,334 +1116,22 @@ class LLMEngine:
                                 "batch": batch_size})
         st.attrs.update(batch=batch_size, tokens=delivered_total,
                         inflight_after=inflight)
-        spec = self._step_spec_attrs()
-        if spec:
-            st.attrs.update(spec)
+        if spec_step:
+            on = self._spec_last_on
+            st.attrs.update(
+                spec_proposed=int(on.sum()) * self.chunk * self.spec_k,
+                spec_accepted=int(self._spec_last_accept[on].sum()),
+                spec_s=self._spec_last_dt)
         self._fold_step_aux(host_aux, st)
         st.enter("observe")
         self._observe(delivered_total, ttfts)
 
-    def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
-        from ray_tpu.core.metrics_export import (metrics_enabled,
-                                                 serve_tokens_total,
-                                                 serve_ttft_hist)
-
-        if not metrics_enabled():
-            return
-        tags = {"deployment": self.name}
-        if delivered:
-            serve_tokens_total().inc(delivered, tags)
-        hist = serve_ttft_hist()
-        for total, queued, prefill in ttfts:
-            # Phase split: queued (submit→admission), prefill (the prefill
-            # dispatch), decode (the remainder — first chunk + distribution).
-            hist.observe(total, {**tags, "phase": "total"})
-            hist.observe(queued, {**tags, "phase": "queued"})
-            hist.observe(prefill, {**tags, "phase": "prefill"})
-            hist.observe(max(0.0, total - queued - prefill),
-                         {**tags, "phase": "decode"})
-
-    # -- introspection --------------------------------------------------------
-    def stats(self) -> Dict[str, float]:
-        """Slot occupancy + admission queue depth — exported through
-        ``ReplicaActor.get_metrics`` for KV-occupancy-aware routing."""
-        with self._state_lock:
-            busy = sum(1 for r in self._slot_req if r is not None)
-            depth = len(self._waiting)
-        # ``queue_limit`` is this engine's own ``max_queue``: the router
-        # sheds at it, not at the global knob it defaults to.
-        out = {"slots_total": float(self.slots), "slots_busy": float(busy),
-               "queue_depth": float(depth),
-               "queue_limit": float(self.max_queue)}
-        # Cumulative counts at the step's boundaries: they count with
-        # tracing off too. ``steps_total`` counts steps that dispatched a
-        # decode; ``admit_blocked_pool_s`` is the time from the start of a
-        # step whose admission stopped on NoFreeBlocks (a slot free, a
-        # request waiting) to the start of the next; ``step_host_s`` +
-        # ``step_device_wait_s`` is all the time spent inside steps.
-        with self._agg_lock:
-            out.update({k: v / 1e9 if k.endswith("_s") else float(v)
-                        for k, v in self._counts.items()})
-        return out
-
-    def describe(self) -> Dict:
-        """What this engine resolved to at run time, for an operator or a
-        smoke test to print: names and placements, not numbers (``stats``
-        stays all-float — it feeds the router). ``warmed_buckets`` is empty
-        until ``warmup`` has compiled every bucket."""
-        return {
-            "engine": type(self).__name__,
-            "slots": self.slots,
-            "chunk": self.chunk,
-            "max_len": self.max_len,
-            "warmed_buckets": list(self.buckets) if self._steady else [],
-            "trace_id": self.trace_id,
-            "params_devices": sorted(
-                {str(d) for leaf in jax.tree.leaves(self.params)
-                 for d in leaf.devices()}),
-        }
-
-    def decode_tokens_per_sec(self) -> float:
-        with self._agg_lock:
-            if self.decode_seconds == 0:
-                return 0.0
-            return self.decode_tokens / self.decode_seconds
-
-
-class PagedLLMEngine(LLMEngine):
-    """Continuous-batching engine over a PAGED KV cache with prefix reuse.
-
-    Same scheduler and streaming contract as :class:`LLMEngine`; the device
-    half is a shared pool of ``serve_kv_block_tokens``-sized KV blocks
-    (:class:`~ray_tpu.models.generate.PagedGenerator`) addressed through
-    per-slot block tables, with a host-side :class:`~ray_tpu.models.generate.
-    KVBlockManager` doing refcounts and hash-based prefix reuse:
-
-    - admission looks the prompt up in the block-hash table and prefills
-      ONLY the uncached suffix (``start_pos = hit_len``) — a shared system
-      prompt or multi-turn history costs its prefill FLOPs once;
-    - a hit on a retired sequence's partial tail block is copy-on-write:
-      the block is duplicated into a private block before the divergent
-      suffix writes into it, full-block hits share by refcount alone;
-    - at retire the finished prompt+output chain is registered so the NEXT
-      turn of the conversation hits it;
-    - pool exhaustion (after LRU-evicting unpinned cached blocks) requeues
-      the request rather than failing it.
-    """
-
-    def __init__(self, params, config, *,
-                 block_tokens: Optional[int] = None,
-                 pool_blocks: Optional[int] = None,
-                 attention_kernel: Optional[str] = None,
-                 draft_params=None,
-                 draft_config=None,
-                 spec_tokens: Optional[int] = None, **kw):
-        from ray_tpu.core.config import config as _get_config
-        from ray_tpu.serve.kv_tier import kv_tier_enabled
-
-        knobs = _get_config()
-        # What the family cannot run yet is refused HERE, not in a step
-        # (a draft model: by the generator, in ``_init_device``).
-        if kv_tier_enabled() and "kv_tier" in paged_family(config).unsupported:
-            raise ValueError(
-                f"{type(config).__name__}: the cluster KV tier "
-                f"(kv_tier_enabled) is not supported for this family yet")
-        self.block_tokens = int(block_tokens if block_tokens is not None
-                                else knobs.serve_kv_block_tokens)
-        self._pool_blocks_cfg = int(pool_blocks if pool_blocks is not None
-                                    else knobs.serve_kv_pool_blocks)
-        self.attention_kernel = str(
-            attention_kernel if attention_kernel is not None
-            else knobs.serve_paged_attention_kernel)
-        self.spec_k = int(spec_tokens if spec_tokens is not None
-                          else knobs.serve_spec_tokens)
-        if self.spec_k > 0 and draft_params is None:
-            raise ValueError(
-                "serve_spec_tokens > 0 needs a draft model "
-                "(draft_params/draft_config)")
-        self._draft_params = draft_params
-        self._draft_config = draft_config
-        self._spec = self.spec_k > 0
-        self._spec_floor = float(knobs.serve_spec_accept_floor)
-        self._spec_alpha = float(knobs.serve_spec_accept_alpha)
-        super().__init__(params, config, **kw)
-
-    # -- device-half hooks ----------------------------------------------------
-    def _init_device(self) -> None:
-        self.blocks_per_seq = -(-self.max_len // self.block_tokens)
-        # Auto pool size: 2x a full slot set plus the trash block — half the
-        # pool can idle as reusable prefix cache under full load.
-        num_blocks = self._pool_blocks_cfg or (
-            2 * self.slots * self.blocks_per_seq + 1)
-        self._pg = PagedGenerator(self.params, self.config, slots=self.slots,
-                                  num_blocks=num_blocks,
-                                  block_tokens=self.block_tokens,
-                                  max_len=self.max_len,
-                                  attention_kernel=self.attention_kernel,
-                                  draft_params=self._draft_params,
-                                  draft_config=self._draft_config)
-        self.kv = KVBlockManager(num_blocks, self.block_tokens)
-        self._pool, self._last, self._keys = self._pg.init_state()
-        # The family's per-call counts (None for GPT-2): the decode call's
-        # and this step's prefills', fetched with the step's tokens.
-        self._decode_aux = None
-        self._prefill_aux: List = []
-        self._aux_totals = {
-            key: 0 for n in self._pg.family.aux_counts
-            for key in (n.decode, n.prefill) if key}
-        self._slot_table = np.zeros((self.slots, self.blocks_per_seq),
-                                    np.int32)
-        self._slot_blocks: List[List[int]] = [[] for _ in range(self.slots)]
-        self._hit_pending = 0  # hit tokens awaiting metric flush (step thread)
-        self._init_tier_state()
-        self._init_spec_state()
-
-    def _init_tier_state(self) -> None:
-        # Cluster KV tier (serve/kv_tier.py). All tier state is touched
-        # under the locks noted inline; with the flag off every field stays
-        # empty and every tier branch is dead — exact engine-private
-        # behavior.
-        from ray_tpu.serve.kv_tier import KVTier, kv_tier_enabled
-
-        self._tier = KVTier(self.name) if kv_tier_enabled() else None
-        from ray_tpu.core.config import config as _get_config
-
-        try:
-            knobs = _get_config()
-            self._tier_min_spill = max(
-                1, int(knobs.kv_tier_min_spill_blocks))
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            self._tier_min_spill = 1
-        # Retired chains pinned for spill: (chain, full_ids, n_full,
-        # digests — the chain's full-block hash list).
-        # Appended under _state_lock by the step thread's retire phase,
-        # drained by _post_step — both inside the _step_lock scope.
-        self._tier_spill_q: List[tuple] = []
-        # head digest -> (chain tuple, n_real): the drain-migration export
-        # set (active sessions' chains). Insertion-ordered LRU, bounded.
-        self._tier_chains: "Dict[bytes, tuple]" = {}
-        # Digests of chains that arrived via drain migration (ordered-set
-        # dict, bounded) — attributes their local hits to source=migrated.
-        self._tier_migrated: "Dict[bytes, None]" = {}
-        self._tier_hits_pending = {"local": 0, "store": 0, "migrated": 0}
-        self._tier_hits_total = {"local": 0, "store": 0, "migrated": 0}
-        self._tier_spill_bytes_pending = 0
-        self._tier_fetch_bytes_pending = 0
-
-    _TIER_CHAIN_CAP = 512       # migration export set
-    _TIER_MIGRATED_CAP = 4096   # migrated-digest attribution set
-
-    def _tier_note_chain_locked(self, head: bytes, chain, n_real: int) -> None:
-        # Under _state_lock. LRU re-insert, like the KV manager's cache.
-        self._tier_chains.pop(head, None)
-        self._tier_chains[head] = (tuple(int(t) for t in chain), int(n_real))
-        while len(self._tier_chains) > self._TIER_CHAIN_CAP:
-            self._tier_chains.pop(next(iter(self._tier_chains)))
-
-    def _init_spec_state(self) -> None:
-        # Speculative-decoding host state — all [S], step-thread-owned
-        # except the per-slot resets at admission/release (under
-        # _state_lock, which the step thread also holds there).
-        if not self._spec:
-            return
-        self._draft_pool = self._pg.init_draft_state()
-        self._spec_tail = np.zeros(self.slots, np.int32)
-        self._spec_pending = np.zeros(self.slots, np.int32)
-        self._spec_use_pending = np.zeros(self.slots, bool)
-        self._spec_ewma = np.ones(self.slots, np.float32)
-        self._spec_on = np.zeros(self.slots, bool)
-        self._last_counts = None        # last spec step's [S, chunk] advances
-        self._spec_last_accept = np.zeros(self.slots, np.int64)
-        self._spec_last_on = np.zeros(self.slots, bool)
-        self._spec_last_dt = 0.0
-        self._spec_proposed_pending = 0  # await metric flush (step thread)
-        self._spec_accepted_pending = 0
-        self._spec_proposed_total = 0
-        self._spec_accepted_total = 0
-
-    def _reset_device_state(self) -> None:
-        self._pool, self._last, self._keys = self._pg.init_state()
-        self._decode_aux, self._prefill_aux = None, []
-        # Pool contents are gone — the prefix cache resets with it. Queued
-        # spill entries and tracked chains point into the dead pool, so
-        # they go too (their pins die with the replaced manager); chains
-        # ALREADY published to the tier survive — those payloads are host
-        # copies in the object plane, not pool references.
-        self.kv = KVBlockManager(self.kv.num_blocks, self.block_tokens)
-        self._slot_table[:] = 0
-        self._slot_blocks = [[] for _ in range(self.slots)]
-        self._tier_spill_q = []
-        self._tier_chains = {}
-        self._tier_migrated = {}
-        self._init_spec_state()
-
-    def warmup(self) -> None:
-        wt = _WarmupTrace(self)
-        with self._step_lock:
-            zero_row = np.zeros(self.blocks_per_seq, np.int32)  # all trash
-            for b in self.buckets:
-                with wt.program("paged_prefill", b):
-                    pf = self._pg.prefill_fn(b)
-                    self._pool, self._last, self._keys, _aux = pf(
-                        self.params, self._pool, self._last,
-                        self._keys, zero_row, np.zeros((1, b), np.int32),
-                        0, b, 0, 0)
-            with wt.program("paged_decode"):
-                df = self._pg.decode_fn(self.chunk)
-                toks, self._pool, self._last, self._keys, _aux = df(
-                    self.params, self._pool, self._last,
-                    self._keys, np.zeros((self.slots, self.blocks_per_seq),
-                                         np.int32),
-                    np.zeros(self.slots, np.int32),
-                    np.zeros(self.slots, bool), self._greedy, self._temps)
-                np.asarray(toks)
-            with wt.program("copy_block"):
-                cf = self._pg.copy_fn()
-                self._pool = cf(self._pool, 0, 0)
-            # The handoff attach program (set_last) runs mid-step when a
-            # prefilled request lands — compile it here, not on its TTFT.
-            with wt.program("set_last"):
-                sl = self._pg.set_last_fn()
-                self._last, self._keys = sl(
-                    self._last, self._keys,
-                    np.zeros(self._last.shape[1], np.float32), 0, 0)
-            if self._tier is not None:
-                # Tier upload/download programs: compile HERE so a cold
-                # replica's first store fetch never pays XLA on its TTFT
-                # (block 0 is the padding block — inserting zeros is inert).
-                with wt.program("kv_tier_blocks"):
-                    k_pool = self._pool[0]
-                    zb = np.zeros((k_pool.shape[0], 1)
-                                  + tuple(k_pool.shape[2:]), k_pool.dtype)
-                    self._tier_insert_blocks(zb, zb, [0])
-                    self._tier_extract_blocks([0])
-            if self._spec:
-                for b in self.buckets:
-                    with wt.program("draft_prefill", b):
-                        dpf = self._pg.draft_prefill_fn(b)
-                        self._draft_pool = dpf(
-                            self._draft_params, self._draft_pool,
-                            zero_row, np.zeros((1, b), np.int32), 0, b)
-                self._draft_pool = cf(self._draft_pool, 0, 0)
-                with wt.program("spec_decode"):
-                    sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
-                    out = sf(self.params, self._draft_params, self._pool,
-                             self._draft_pool, self._last, self._keys,
-                             np.zeros((self.slots, self.blocks_per_seq),
-                                      np.int32),
-                             np.zeros(self.slots, np.int32),
-                             np.zeros(self.slots, bool), self._greedy,
-                             self._temps, np.zeros(self.slots, bool),
-                             np.zeros(self.slots, np.int32),
-                             np.zeros(self.slots, np.int32),
-                             np.zeros(self.slots, bool))
-                    np.asarray(out[0])
-                (self._pool, self._draft_pool,
-                 self._last, self._keys) = out[3:7]
-            self._reset_device_state()
-            self._steady = True
-        wt.close()
-
-    def _suffix_bucket(self, n: int) -> int:
-        # The suffix prefill's compile bucket — unlike _bucket_for it needs
-        # no decode-chunk headroom check (submit already validated the full
-        # prompt against max_len).
-        for b in self.buckets:
-            if n <= b:
-                return b
-        return self.buckets[-1]
-
-    def _admission_cost(self, req: _Request) -> int:
-        if req.preloaded is not None:
-            return 0  # prefill already paid on the prefill-side engine
-        hit = self.kv.peek_hit_len([int(t) for t in req.prompt])
-        return self._suffix_bucket(max(1, req.real_len - hit))
-
     def _dispatch_prefill(self, req: _Request, slot: int) -> None:
+        """Take the prompt's blocks and run its (suffix) prefill into
+        ``slot``. May raise :class:`NoFreeBlocks` (pool exhausted) — the
+        scheduler requeues the request at the head and stops admitting this
+        step."""
         bt = self.block_tokens
-        if req.preloaded is not None:
-            self._attach_preloaded(req, slot)
-            return
         t_alloc = tracing.now_ns()
         evicted0 = self.kv.evicted_blocks
         tokens = [int(t) for t in req.prompt]
@@ -1335,7 +1226,7 @@ class PagedLLMEngine(LLMEngine):
         # Commit ATOMICALLY with the cancel path: this runs outside
         # _state_lock, so a concurrent _cancel may have freed the slot
         # mid-dispatch. Attaching first and registering later would let
-        # _release_slot_device free blocks the prefix table still points
+        # _free_slot_locked free blocks the prefix table still points
         # at; attaching after a lost cancel would leak the pins forever.
         # Publishing the prompt's FULL blocks here (their content is final —
         # decode writes only at positions >= real_len) lets a concurrent
@@ -1369,9 +1260,9 @@ class PagedLLMEngine(LLMEngine):
                     self._tier_note_chain_locked(
                         digests[nf - 1], tokens[:nf * bt], nf * bt)
             if self._spec and fetched is not None:
-                # Store-fetched blocks carry no draft-side KV (like a
-                # disaggregation handoff) — speculation stays off for this
-                # request rather than proposing from garbage draft state.
+                # Store-fetched blocks carry no draft-side KV — speculation
+                # stays off for this request rather than proposing from
+                # garbage draft state.
                 self._spec_on[slot] = False
                 self._spec_ewma[slot] = 0.0
                 self._spec_use_pending[slot] = False
@@ -1409,68 +1300,9 @@ class PagedLLMEngine(LLMEngine):
             return None
         return payload, n_local_full, j + 1
 
-    def _post_step(self) -> None:
-        # Drain the spill queue (chains pinned at retire) under _step_lock:
-        # extract the full blocks off-device and publish them to the
-        # cluster tier, then unpin. Best-effort — a tier failure must never
-        # poison serving (the chain stays locally cached either way).
-        if self._tier is None or not self._tier_spill_q:
-            return
-        q, self._tier_spill_q = self._tier_spill_q, []
-        for chain, ids, n_full, digests in q:
-            try:
-                if not self._tier.is_published(digests[-1]):
-                    k, v = self._tier_extract_blocks(ids)
-                    payload = {"k": k, "v": v,
-                               "tokens": list(chain[:n_full
-                                                    * self.block_tokens])}
-                    self._tier.publish_chain(digests, payload,
-                                             n_full * self.block_tokens,
-                                             n_full)
-                    self._tier_spill_bytes_pending += (
-                        payload["k"].nbytes + payload["v"].nbytes)
-            except Exception:  # noqa: BLE001 — spill is best-effort
-                logger.exception("kv tier spill failed on %s", self.name)
-            finally:
-                self.kv.release(ids)
-
-    def _attach_preloaded(self, req: _Request, slot: int) -> None:
-        """Disaggregation handoff: the prompt's K/V blocks were already
-        uploaded into the pool by ``admit_prefilled`` — attach the table row
-        and seed the slot's logits/PRNG rows from the handed-off state."""
-        ids = list(req.blocks)
-        row = np.zeros(self.blocks_per_seq, np.int32)
-        row[:len(ids)] = ids
-        sl = self._pg.set_last_fn()
-        self._last, self._keys = sl(self._last, self._keys,
-                                    np.asarray(req.preloaded, np.float32),
-                                    slot, req.seed)
-        # Same atomic commit as _dispatch_prefill: a cancel that freed the
-        # slot mid-attach found _slot_blocks[slot] empty (and, with req.slot
-        # set, never took the _discard_request_locked path), so the handoff
-        # pins are ours to drop here.
-        with self._state_lock:
-            req.blocks = []
-            if self._slot_req[slot] is not req or req.cancelled:
-                self.kv.release(ids)
-                return
-            self._slot_table[slot, :] = row
-            self._slot_blocks[slot] = ids
-            self._hit_pending += req.hit_tokens
-            if self._spec:
-                # Handed-off blocks carry no draft-side KV — the draft
-                # never saw this prompt. Speculation stays off for the
-                # request; the slot decodes one token per scan step.
-                self._spec_on[slot] = False
-                self._spec_ewma[slot] = 0.0
-                self._spec_use_pending[slot] = False
-
-    def _decode_operands_locked(self):
-        base = (self._slot_table.copy(),
-                np.asarray(self._slot_len, np.int32))
-        if not self._spec:
-            return base
-        tables, lengths = base
+    def _spec_operands_locked(self, lengths):
+        """The speculative program's extra operands, snapshotted under
+        _state_lock with the step's tables and lengths."""
         # Headroom gate: a spec step can write chunk*(k+1) positions ahead,
         # so slots without that much table room degrade to one token per
         # step INSIDE the same program — the base retire rule
@@ -1479,36 +1311,36 @@ class PagedLLMEngine(LLMEngine):
         cap = self.blocks_per_seq * self.block_tokens
         headroom = lengths + self.chunk * (self.spec_k + 1) <= cap
         spec_on = self._spec_on & headroom & self._active
-        return base + (spec_on, self._spec_tail.copy(),
-                       self._spec_pending.copy(),
-                       self._spec_use_pending.copy())
+        return (spec_on, self._spec_tail.copy(), self._spec_pending.copy(),
+                self._spec_use_pending.copy())
 
-    def _run_decode(self, active, greedy, temps, extra):
-        if not self._spec:
-            tables, lengths = extra
-            df = self._pg.decode_fn(self.chunk)
-            (toks, self._pool, self._last, self._keys,
-             self._decode_aux) = df(
-                self.params, self._pool, self._last,
-                self._keys, tables, lengths, active, greedy, temps)
-            return toks
-        tables, lengths, spec_on, tail, pending, use_pending = extra
-        if not spec_on.any() and not (use_pending & active).any():
-            # Every slot degraded (low acceptance / no headroom / handoff)
-            # and none still carries a rejection replacement: the plain
-            # one-token program is strictly cheaper than a spec step that
-            # would force-reject everything. (A just-demoted slot runs one
-            # more spec step, which consumes its pending token and clears
-            # the carry.)
-            df = self._pg.decode_fn(self.chunk)
-            (toks, self._pool, self._last, self._keys,
-             self._decode_aux) = df(
-                self.params, self._pool, self._last,
-                self._keys, tables, lengths, active, greedy, temps)
+    def _run_decode(self, active, greedy, temps, tables, lengths, spec_ops):
+        """Dispatch the step's decode program; its tokens come back still on
+        the device."""
+        if spec_ops is not None:
+            spec_on, _tail, _pending, use_pending = spec_ops
+            if spec_on.any() or (use_pending & active).any():
+                return self._run_spec_decode(active, greedy, temps, tables,
+                                             lengths, spec_ops)
+            # Every slot degraded (low acceptance / no headroom / fetched
+            # from the store) and none still carries a rejection
+            # replacement: the plain one-token program is strictly cheaper
+            # than a spec step that would force-reject everything. (A
+            # just-demoted slot runs one more spec step, which consumes its
+            # pending token and clears the carry.)
             self._last_counts = None
             self._spec_last_accept[:] = 0
             self._spec_last_on[:] = False
-            return toks
+        df = self._pg.decode_fn(self.chunk)
+        (toks, self._pool, self._last, self._keys,
+         self._decode_aux) = df(
+            self.params, self._pool, self._last,
+            self._keys, tables, lengths, active, greedy, temps)
+        return toks
+
+    def _run_spec_decode(self, active, greedy, temps, tables, lengths,
+                         spec_ops):
+        spec_on, tail, pending, use_pending = spec_ops
         sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
         t0 = time.perf_counter()
         (toks, counts, accepted, self._pool, self._draft_pool,
@@ -1552,9 +1384,9 @@ class PagedLLMEngine(LLMEngine):
         self._spec_accepted_total += int(acc.sum())
         return toks
 
-    def _slot_result(self, host_toks, slot: int):
-        if not self._spec or self._last_counts is None:
-            return super()._slot_result(host_toks, slot)
+    def _spec_slot_result(self, host_toks, slot: int):
+        """A speculative step's emitted tokens for ``slot`` and its
+        device-length advance. Under _state_lock."""
         counts = self._last_counts[slot]          # [chunk] advances
         toks = host_toks[slot]                    # [chunk, k+1]
         out: List[int] = []
@@ -1562,23 +1394,23 @@ class PagedLLMEngine(LLMEngine):
             out.extend(int(x) for x in toks[t, :counts[t]])
         return out, int(counts.sum())
 
-    def _step_spec_attrs(self) -> Optional[Dict]:
-        if not self._spec or self._last_counts is None:
-            return None
-        on = self._spec_last_on
-        return {"spec_proposed": int(on.sum()) * self.chunk * self.spec_k,
-                "spec_accepted": int(self._spec_last_accept[on].sum()),
-                "spec_s": self._spec_last_dt}
-
     def _take_step_aux(self):
+        """Device arrays to fetch WITH the step's tokens, in its one
+        ``device_get``: the family's per-call counts, the decode call's and
+        this step's prefills' (None for GPT-2). A method of its own so that
+        no name in ``_step_inner`` keeps these arrays alive past the fetch:
+        held as locals through deliver and observe they cost the
+        ``longcat-flash-omni`` cell 12 ms of device idle a step, 3.4% of its
+        ``serve_out_tok_s`` (PERF.md §6, PR 29)."""
         aux, self._decode_aux = self._decode_aux, None
         pre, self._prefill_aux = self._prefill_aux, []
         return None if aux is None and not pre else (aux, pre)
 
     def _fold_step_aux(self, host_aux, st: _StepTrace) -> None:
-        # The family's counts, by the names it gives them (``PagedFamily.
-        # aux_counts``): the decode chunk's go to its ``stats()`` keys and
-        # onto the ``llm.step`` span, a prefill's to keys of their own.
+        """Fold the family's counts, now on the host, by the names it gives
+        them (``PagedFamily.aux_counts``): the decode chunk's go to its
+        ``stats()`` keys and onto the ``llm.step`` span, a prefill's to keys
+        of their own."""
         if host_aux is None:
             return
         aux, pre = host_aux
@@ -1595,171 +1427,81 @@ class PagedLLMEngine(LLMEngine):
                     if n.prefill:
                         totals[n.prefill] += int(v)
 
-    def _release_slot_device(self, slot: int) -> None:
-        ids = self._slot_blocks[slot]
-        if ids:
-            self._slot_blocks[slot] = []
-            self._slot_table[slot, :] = 0
-            self.kv.release(ids)
+    def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
+        hits, self._hit_pending = self._hit_pending, 0
+        if self._tier is not None:
+            with self._state_lock:
+                tier_hits = dict(self._tier_hits_pending)
+                for src in self._tier_hits_pending:
+                    self._tier_hits_pending[src] = 0
+            spill_b, self._tier_spill_bytes_pending = \
+                self._tier_spill_bytes_pending, 0
+            fetch_b, self._tier_fetch_bytes_pending = \
+                self._tier_fetch_bytes_pending, 0
+        from ray_tpu.core.metrics_export import (metrics_enabled,
+                                                 serve_kv_block_occupancy,
+                                                 serve_kv_hit_tokens_total,
+                                                 serve_kv_spilled_blocks,
+                                                 serve_kv_tier_fetch_bytes_total,
+                                                 serve_kv_tier_hits_total,
+                                                 serve_kv_tier_spill_bytes_total,
+                                                 serve_spec_accept_ratio,
+                                                 serve_spec_accepted_total,
+                                                 serve_spec_proposed_total,
+                                                 serve_tokens_total,
+                                                 serve_ttft_hist)
+
+        if not metrics_enabled():
+            if self._spec:
+                self._spec_proposed_pending = 0
+                self._spec_accepted_pending = 0
+            return
+        tags = {"deployment": self.name}
+        if delivered:
+            serve_tokens_total().inc(delivered, tags)
+        hist = serve_ttft_hist()
+        for total, queued, prefill in ttfts:
+            # Phase split: queued (submit→admission), prefill (the prefill
+            # dispatch), decode (the remainder — first chunk + distribution).
+            hist.observe(total, {**tags, "phase": "total"})
+            hist.observe(queued, {**tags, "phase": "queued"})
+            hist.observe(prefill, {**tags, "phase": "prefill"})
+            hist.observe(max(0.0, total - queued - prefill),
+                         {**tags, "phase": "decode"})
+        if hits:
+            serve_kv_hit_tokens_total().inc(hits, tags)
+        st = self.kv.stats()
+        gauge = serve_kv_block_occupancy()
+        for state in ("active", "cached", "free"):
+            gauge.set(st[f"kv_blocks_{state}"], {**tags, "state": state})
+        if self._tier is not None:
+            ctr = serve_kv_tier_hits_total()
+            for src, n in tier_hits.items():
+                if n:
+                    ctr.inc(n, {**tags, "source": src})
+            if spill_b:
+                serve_kv_tier_spill_bytes_total().inc(spill_b, tags)
+            if fetch_b:
+                serve_kv_tier_fetch_bytes_total().inc(fetch_b, tags)
+            serve_kv_spilled_blocks().set(self._tier.spilled_blocks(), tags)
         if self._spec:
-            self._spec_on[slot] = False
-            self._spec_use_pending[slot] = False
-
-    def _on_retire_locked(self, req: _Request) -> None:
-        ids = self._slot_blocks[req.slot] if req.slot is not None else []
-        if not ids:
-            return
-        # Register the finished prompt+output chain (including a partial
-        # tail entry) — the conversation's next turn extends exactly this
-        # token sequence. Tokens past `emitted` (final-chunk spill) were
-        # written to the pool but are NOT part of the chain, and
-        # register_chain only publishes blocks fully covered by n_real.
-        chain = [int(t) for t in req.prompt] + req.out_ids[:req.emitted]
-        n_real = min(len(chain), len(ids) * self.block_tokens)
-        self.kv.register_chain(chain, ids, n_real)
-        if self._tier is None:
-            return
-        # Refcounted publish from the retire path: pin the chain's FULL
-        # blocks (their content is final) and queue them for the spill
-        # drain in _post_step — LRU eviction can't beat the extract to
-        # them, and the pins drop the moment the payload is off-device.
-        from ray_tpu.util import blockhash
-
-        bt = self.block_tokens
-        n_full = n_real // bt
-        if n_full < self._tier_min_spill:
-            return
-        digests = blockhash.block_hashes(chain, bt, max_blocks=n_full)
-        head = digests[-1]
-        self._tier_note_chain_locked(head, chain[:n_real], n_real)
-        if not self._tier.is_published(head):
-            full_ids = list(ids[:n_full])
-            self.kv.pin(full_ids)
-            self._tier_spill_q.append(
-                (list(chain), full_ids, n_full, digests))
-
-    def _discard_request_locked(self, req: _Request) -> None:
-        ids, req.blocks = req.blocks, []
-        if ids:
-            self.kv.release(ids)
-
-    # -- disaggregation halves ------------------------------------------------
-    def prefill_to_blocks(self, prompt_ids: Sequence[int], *, seed: int = 0):
-        """Prefill-side half of disaggregated serving: run (suffix-)prefill
-        for ``prompt_ids`` into pool blocks and return host copies for the
-        handoff lane — ``(k [L,nb,bt,H*Dh], v, last_row [V], hit_tokens)``.
-
-        The chain (full blocks AND partial tail — nothing will extend these
-        blocks here) is registered in the LOCAL prefix cache before the pins
-        drop, so a same-prefix prompt later only prefills its suffix even
-        on the prefill side. Uses slot 0 under the step lock; a prefill
-        engine serves no decode traffic, so the slot is exclusive.
-        """
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        real_len = int(prompt.shape[0])
-        if real_len == 0:
-            raise ValueError("empty prompt")
-        bt = self.block_tokens
-        tokens = [int(t) for t in prompt]
-        with self._step_lock:
-            full, tail, hit_len = self.kv.lookup(tokens)
-            try:
-                need = -(-real_len // bt)
-                fresh = self.kv.alloc(need - len(full))
-            except NoFreeBlocks:
-                self.kv.release(full + ([tail] if tail is not None else []))
-                raise
-            ids = list(full)
-            if tail is not None:
-                dst = fresh.pop(0)
-                cf = self._pg.copy_fn()
-                self._pool = cf(self._pool, int(tail), int(dst))
-                self.kv.note_cow()
-                self.kv.release([tail])
-                ids.append(dst)
-            ids.extend(fresh)
-            row = np.zeros(self.blocks_per_seq, np.int32)
-            row[:len(ids)] = ids
-            suffix_len = real_len - hit_len
-            bucket = self._suffix_bucket(suffix_len)
-            padded = np.zeros((1, bucket), np.int32)
-            padded[0, :suffix_len] = prompt[hit_len:]
-            pf = self._pg.prefill_fn(bucket)
-            self._pool, self._last, self._keys, _aux = pf(
-                self.params, self._pool, self._last,
-                self._keys, row, padded, hit_len, suffix_len, 0, seed)
-            ef = self._pg.extract_fn(len(ids))
-            k, v = ef(self._pool, np.asarray(ids, np.int32))
-            k = np.asarray(k)
-            v = np.asarray(v)
-            last_row = np.asarray(self._last[0])
-            self.kv.register_chain(tokens, ids, real_len)
-            self.kv.release(ids)
-        return k, v, last_row, hit_len
-
-    def admit_prefilled(self, prompt_ids: Sequence[int],
-                        k: np.ndarray, v: np.ndarray, last_row: np.ndarray,
-                        *, max_new_tokens: int = 32, temperature: float = 0.0,
-                        seed: int = 0, hit_tokens: int = 0,
-                        submitted_at: Optional[float] = None,
-                        trace_ctx=None, timeout_s: float = 30.0) -> _Request:
-        """Decode-side half of disaggregated serving: upload handed-off KV
-        blocks into the pool and enqueue a decode-only request (admission
-        attaches the table row instead of prefilling). Blocks — briefly —
-        until the pool can supply the sequence's block budget.
-
-        The upload is synchronous (``block_until_ready``): on return the
-        caller may release the shm views ``k``/``v`` point into.
-        """
-        import jax
-
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        real_len = int(prompt.shape[0])
-        if real_len == 0:
-            raise ValueError("empty prompt")
-        bucket = self._bucket_for(real_len)  # validates decode headroom
-        req = _Request(prompt, None, real_len, bucket, int(max_new_tokens),
-                       float(temperature), int(seed),
-                       threading.Condition(self._state_lock))
-        req.trace_ctx = trace_ctx
-        if submitted_at is not None:
-            req.submitted_at = submitted_at
-            req.submitted_ns = int(submitted_at * 1e9)
-        if max_new_tokens <= 0:
-            req.done = True
-            req.finish_reason = "stop"
-            return req
-        nb_in = int(k.shape[1])
-        n_chunks = -(-req.max_new // self.chunk)
-        max_written = min(self.max_len, real_len + n_chunks * self.chunk)
-        need = max(-(-max_written // self.block_tokens), nb_in)
-        deadline = time.monotonic() + timeout_s
-        while True:
-            try:
-                ids = self.kv.alloc(need)
-                break
-            except NoFreeBlocks:
-                if time.monotonic() > deadline:
-                    raise
-                time.sleep(0.002)  # in-flight retires free blocks
-        with self._step_lock:
-            inf = self._pg.insert_fn(nb_in)
-            self._pool = inf(
-                self._pool, (np.asarray(k), np.asarray(v)),
-                np.asarray(ids[:nb_in], np.int32))
-            jax.block_until_ready(self._pool)
-        # Publish the prompt's full blocks for LOCAL hits too — a colocated
-        # follow-up (or affinity-routed repeat) skips the handoff entirely.
-        tokens = [int(t) for t in prompt]
-        n_full = (real_len // self.block_tokens) * self.block_tokens
-        if n_full:
-            self.kv.register_chain(tokens, ids, n_full)
-        req.blocks = ids
-        req.preloaded = np.asarray(last_row, np.float32)
-        req.hit_tokens = int(hit_tokens)
-        with self._state_lock:
-            self._waiting.append(req)
-        return req
+            prop, self._spec_proposed_pending = self._spec_proposed_pending, 0
+            acc, self._spec_accepted_pending = self._spec_accepted_pending, 0
+            if prop:
+                serve_spec_proposed_total().inc(prop, tags)
+            if acc:
+                serve_spec_accepted_total().inc(acc, tags)
+            tot_prop = self._spec_proposed_total
+            if tot_prop:
+                serve_spec_accept_ratio().set(
+                    self._spec_accepted_total / tot_prop, tags)
+            # The spec dispatch IS the first decode chunk for a first
+            # token delivered this step — surface its propose+verify time
+            # as its own TTFT phase next to queued/prefill/decode.
+            if ttfts and self._last_counts is not None:
+                for _ in ttfts:
+                    hist.observe(self._spec_last_dt,
+                                 {**tags, "phase": "spec"})
 
     # -- drain migration (cluster KV tier) ------------------------------------
     def kv_export_chains(self) -> List[tuple]:
@@ -1866,8 +1608,8 @@ class PagedLLMEngine(LLMEngine):
         bt = self.block_tokens
         itm = np.dtype(c.dtype).itemsize
         block_bytes = c.n_layers * bt * c.n_heads * c.head_dim * itm
-        # A chain spans at most one sequence's block budget; size the lane
-        # like the disaggregation lane (K+V of a full table row + meta).
+        # A chain spans at most one sequence's block budget: K+V of a full
+        # table row, plus room for the meta.
         return 2 * self.blocks_per_seq * block_bytes + 65536, 4
 
     def kv_migrate_out(self, lane_name: str) -> int:
@@ -1952,7 +1694,26 @@ class PagedLLMEngine(LLMEngine):
 
     # -- introspection --------------------------------------------------------
     def stats(self) -> Dict[str, float]:
-        out = super().stats()
+        """Slot occupancy, admission queue depth and the KV pool's counters —
+        exported through ``ReplicaActor.get_metrics`` for KV-occupancy-aware
+        routing."""
+        with self._state_lock:
+            busy = sum(1 for r in self._slot_req if r is not None)
+            depth = len(self._waiting)
+        # ``queue_limit`` is this engine's own ``max_queue``: the router
+        # sheds at it, not at the global knob it defaults to.
+        out = {"slots_total": float(self.slots), "slots_busy": float(busy),
+               "queue_depth": float(depth),
+               "queue_limit": float(self.max_queue)}
+        # Cumulative counts at the step's boundaries: they count with
+        # tracing off too. ``steps_total`` counts steps that dispatched a
+        # decode; ``admit_blocked_pool_s`` is the time from the start of a
+        # step whose admission stopped on NoFreeBlocks (a slot free, a
+        # request waiting) to the start of the next; ``step_host_s`` +
+        # ``step_device_wait_s`` is all the time spent inside steps.
+        with self._agg_lock:
+            out.update({k: v / 1e9 if k.endswith("_s") else float(v)
+                        for k, v in self._counts.items()})
         out.update(self.kv.stats())
         if self._tier is not None:
             out["kv_tier_spilled_blocks"] = float(self._tier.spilled_blocks())
@@ -1970,81 +1731,34 @@ class PagedLLMEngine(LLMEngine):
         return out
 
     def describe(self) -> Dict:
-        out = super().describe()
-        out["attention_kernel"] = self._pg.attention_kernel  # as resolved
-        out["block_tokens"] = self.block_tokens
-        out["pool_blocks"] = self.kv.num_blocks
-        out["model_family"] = type(self.config).__name__
-        out["kv_pool_shapes"] = [list(a.shape) for a in self._pool]
-        out["kv_pool_devices"] = sorted(
-            {str(d) for a in self._pool for d in a.devices()})
-        return out
+        """What this engine resolved to at run time, for an operator or a
+        smoke test to print: names and placements, not numbers (``stats``
+        stays all-float — it feeds the router). ``warmed_buckets`` is empty
+        until ``warmup`` has compiled every bucket."""
+        return {
+            "engine": type(self).__name__,
+            "slots": self.slots,
+            "chunk": self.chunk,
+            "max_len": self.max_len,
+            "warmed_buckets": list(self.buckets) if self._steady else [],
+            "trace_id": self.trace_id,
+            "params_devices": sorted(
+                {str(d) for leaf in jax.tree.leaves(self.params)
+                 for d in leaf.devices()}),
+            "attention_kernel": self._pg.attention_kernel,  # as resolved
+            "block_tokens": self.block_tokens,
+            "pool_blocks": self.kv.num_blocks,
+            "model_family": type(self.config).__name__,
+            "kv_pool_shapes": [list(a.shape) for a in self._pool],
+            "kv_pool_devices": sorted(
+                {str(d) for a in self._pool for d in a.devices()}),
+        }
 
-    def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
-        super()._observe(delivered, ttfts)
-        hits, self._hit_pending = self._hit_pending, 0
-        if self._tier is not None:
-            with self._state_lock:
-                tier_hits = dict(self._tier_hits_pending)
-                for src in self._tier_hits_pending:
-                    self._tier_hits_pending[src] = 0
-            spill_b, self._tier_spill_bytes_pending = \
-                self._tier_spill_bytes_pending, 0
-            fetch_b, self._tier_fetch_bytes_pending = \
-                self._tier_fetch_bytes_pending, 0
-        from ray_tpu.core.metrics_export import (metrics_enabled,
-                                                 serve_kv_block_occupancy,
-                                                 serve_kv_hit_tokens_total,
-                                                 serve_kv_spilled_blocks,
-                                                 serve_kv_tier_fetch_bytes_total,
-                                                 serve_kv_tier_hits_total,
-                                                 serve_kv_tier_spill_bytes_total,
-                                                 serve_spec_accept_ratio,
-                                                 serve_spec_accepted_total,
-                                                 serve_spec_proposed_total,
-                                                 serve_ttft_hist)
-
-        if not metrics_enabled():
-            if self._spec:
-                self._spec_proposed_pending = 0
-                self._spec_accepted_pending = 0
-            return
-        tags = {"deployment": self.name}
-        if hits:
-            serve_kv_hit_tokens_total().inc(hits, tags)
-        st = self.kv.stats()
-        gauge = serve_kv_block_occupancy()
-        for state in ("active", "cached", "free"):
-            gauge.set(st[f"kv_blocks_{state}"], {**tags, "state": state})
-        if self._tier is not None:
-            ctr = serve_kv_tier_hits_total()
-            for src, n in tier_hits.items():
-                if n:
-                    ctr.inc(n, {**tags, "source": src})
-            if spill_b:
-                serve_kv_tier_spill_bytes_total().inc(spill_b, tags)
-            if fetch_b:
-                serve_kv_tier_fetch_bytes_total().inc(fetch_b, tags)
-            serve_kv_spilled_blocks().set(self._tier.spilled_blocks(), tags)
-        if self._spec:
-            prop, self._spec_proposed_pending = self._spec_proposed_pending, 0
-            acc, self._spec_accepted_pending = self._spec_accepted_pending, 0
-            if prop:
-                serve_spec_proposed_total().inc(prop, tags)
-            if acc:
-                serve_spec_accepted_total().inc(acc, tags)
-            tot_prop = self._spec_proposed_total
-            if tot_prop:
-                serve_spec_accept_ratio().set(
-                    self._spec_accepted_total / tot_prop, tags)
-            # The spec dispatch IS the first decode chunk for a first
-            # token delivered this step — surface its propose+verify time
-            # as its own TTFT phase next to queued/prefill/decode.
-            if ttfts and self._last_counts is not None:
-                hist = serve_ttft_hist()
-                for _ in ttfts:
-                    hist.observe(self._spec_last_dt,
-                                 {**tags, "phase": "spec"})
+    def decode_tokens_per_sec(self) -> float:
+        with self._agg_lock:
+            if self.decode_seconds == 0:
+                return 0.0
+            return self.decode_tokens / self.decode_seconds
 
     def close(self) -> None:
         """Release this engine's KV-tier publishes — directory refs and
@@ -2052,351 +1766,6 @@ class PagedLLMEngine(LLMEngine):
         the engine owns no threads to stop."""
         if self._tier is not None:
             self._tier.close()
-
-
-class _DisaggTicket:
-    """One request's place in the disaggregated pipeline: queued → prefill
-    → lane → decode-engine ``_Request``. Resolution (req or error) is
-    signalled through the engine's condition variable."""
-
-    __slots__ = ("prompt", "max_new", "temperature", "seed", "req", "error",
-                 "resolved", "cancelled", "trace_ctx", "submitted_at")
-
-    def __init__(self, prompt, max_new, temperature, seed):
-        self.prompt = prompt
-        self.max_new = max_new
-        self.temperature = temperature
-        self.seed = seed
-        self.req: Optional[_Request] = None
-        self.error: Optional[BaseException] = None
-        self.resolved = False
-        self.cancelled = False
-        self.submitted_at = time.perf_counter()
-        self.trace_ctx = (tracing.current_context()
-                          if tracing.is_sampled() else None)
-
-
-class DisaggregatedLLMEngine:
-    """Prefill/decode disaggregation: a prefill-specialized
-    :class:`PagedLLMEngine` feeding a decode-specialized one over a
-    :class:`~ray_tpu.serve.dag_pipeline.KVHandoffLane`.
-
-    Mixed prefill+decode in one engine serializes heterogeneous work — a
-    long prompt's prefill dispatch stalls every in-flight decode chunk
-    behind it (the scaling cliff the TPU concurrency-limits paper maps).
-    Here decode NEVER runs a prompt prefill: a prefill worker turns prompts
-    into KV blocks (with its own prefix cache, so shared prefixes cost
-    their FLOPs once), ships them over the lane's deferred-ack shm ring,
-    and an ingest worker uploads them into the decode pool (donated
-    ``insert_fn``) and enqueues a decode-only request. Streaming contract,
-    shedding, and stats match :class:`LLMEngine`; ``close()`` joins the
-    workers and destroys the lane (leak-check clean).
-
-    In-process both halves share this object; the same lane protocol works
-    cross-process (attach by name, ``create=False``) when prefill and
-    decode live in separate replicas.
-    """
-
-    def __init__(self, params, config, *,
-                 max_len: Optional[int] = None,
-                 prompt_buckets: Optional[Sequence[int]] = None,
-                 chunk: int = 8, slots: Optional[int] = None,
-                 max_queue: Optional[int] = None, name: str = "LLM",
-                 prefill_slots: int = 1,
-                 block_tokens: Optional[int] = None,
-                 pool_blocks: Optional[int] = None,
-                 lane_slots: int = 4):
-        from ray_tpu.core.config import config as _get_config
-        from ray_tpu.serve.dag_pipeline import KVHandoffLane
-
-        if "disaggregation" in paged_family(config).unsupported:
-            raise ValueError(
-                f"{type(config).__name__}: prefill/decode disaggregation "
-                f"(serve_disaggregation_enabled) is not supported for this "
-                f"family yet")
-
-        knobs = _get_config()
-        self.name = name
-        self.chunk = chunk
-        self.max_queue = int(max_queue if max_queue is not None
-                             else knobs.serve_admission_queue_limit)
-        # spec_tokens=0: disaggregated decode admits via KV handoff, where
-        # draft-side KV never exists — speculation is a colocated-engine
-        # feature.
-        self.decode = PagedLLMEngine(
-            params, config, max_len=max_len, prompt_buckets=prompt_buckets,
-            chunk=chunk, slots=slots, max_queue=0, name=name,
-            block_tokens=block_tokens, pool_blocks=pool_blocks,
-            spec_tokens=0)
-        self.prefill = PagedLLMEngine(
-            params, config, max_len=max_len, prompt_buckets=prompt_buckets,
-            chunk=chunk, slots=max(1, prefill_slots), max_queue=0,
-            name=f"{name}-prefill", block_tokens=block_tokens,
-            pool_blocks=pool_blocks, spec_tokens=0)
-        self.slots = self.decode.slots
-        self.finish_reason = "stop"  # single-stream convenience, as LLMEngine
-
-        c = config
-        bt = self.decode.block_tokens
-        itm = np.dtype(c.dtype).itemsize
-        block_bytes = c.n_layers * bt * c.n_heads * c.head_dim * itm
-        cap = (2 * self.decode.blocks_per_seq * block_bytes
-               + self.decode._pg.logits_dim * 4 + 65536)
-        self.lane = KVHandoffLane(capacity=cap, slots=max(2, lane_slots))
-
-        self._lock = threading.Lock()
-        self._cv = threading.Condition(self._lock)
-        self._pq: collections.deque = collections.deque()
-        self._lane_fifo: collections.deque = collections.deque()
-        self._closed = False
-        self._prefill_thread = threading.Thread(
-            target=self._prefill_loop, name=f"{name}-disagg-prefill",
-            daemon=True)
-        self._ingest_thread = threading.Thread(
-            target=self._ingest_loop, name=f"{name}-disagg-ingest",
-            daemon=True)
-        self._prefill_thread.start()
-        self._ingest_thread.start()
-
-    # -- pipeline workers -----------------------------------------------------
-    def _prefill_loop(self) -> None:
-        from ray_tpu.dag.channel import ChannelTimeout
-
-        while True:
-            with self._cv:
-                while not self._pq and not self._closed:
-                    self._cv.wait(timeout=0.1)
-                if self._closed:
-                    return
-                t = self._pq.popleft()
-            if t.cancelled:
-                self._resolve(t, error=None)
-                continue
-            try:
-                k, v, last_row, hit = self.prefill.prefill_to_blocks(
-                    t.prompt, seed=t.seed)
-                meta = {"prompt": t.prompt, "max_new": t.max_new,
-                        "temperature": t.temperature, "seed": t.seed,
-                        "hit_tokens": hit, "last_row": last_row,
-                        "submitted_at": t.submitted_at}
-                with self._cv:
-                    self._lane_fifo.append(t)
-                while True:
-                    try:
-                        self.lane.send(meta, k, v, timeout=1.0)
-                        break
-                    except ChannelTimeout:  # decode side slow to drain
-                        if self._closed:
-                            with self._cv:
-                                try:
-                                    self._lane_fifo.remove(t)
-                                except ValueError:
-                                    pass
-                            self._resolve(
-                                t, error=RuntimeError("engine closed"))
-                            break
-            except BaseException as e:  # noqa: BLE001 — poison one request
-                # The ticket may already sit in _lane_fifo (send can fail
-                # AFTER the append — channel fault, oversized payload);
-                # leaving it there would pair every later handoff with the
-                # wrong ticket. Unqueue before resolving.
-                with self._cv:
-                    try:
-                        self._lane_fifo.remove(t)
-                    except ValueError:
-                        pass
-                self._resolve(t, error=e)
-
-    def _ingest_loop(self) -> None:
-        from ray_tpu.dag.channel import ChannelClosed, ChannelTimeout
-
-        while True:
-            try:
-                meta, k, v, token = self.lane.recv(timeout=0.25)
-            except ChannelTimeout:
-                if self._closed:
-                    return
-                continue
-            except ChannelClosed:
-                return
-            with self._cv:
-                t = self._lane_fifo.popleft() if self._lane_fifo else None
-            if t is None:
-                # Payload with no waiting ticket (its prefill thread
-                # unqueued itself on a send-path error) — drop it and
-                # return the ring slot.
-                self.lane.ack(token)
-                continue
-            try:
-                req = self.decode.admit_prefilled(
-                    meta["prompt"], k, v, meta["last_row"],
-                    max_new_tokens=meta["max_new"],
-                    temperature=meta["temperature"], seed=meta["seed"],
-                    hit_tokens=meta["hit_tokens"],
-                    submitted_at=meta["submitted_at"],
-                    trace_ctx=t.trace_ctx)
-            except BaseException as e:  # noqa: BLE001 — poison one request
-                self.lane.ack(token)
-                self._resolve(t, error=e)
-                continue
-            # The upload landed (admit_prefilled syncs) — release the ring
-            # slot back to the prefill writer. THE deferred-ack handoff.
-            self.lane.ack(token)
-            self._resolve(t, req=req)
-
-    def _resolve(self, t: _DisaggTicket, req: Optional[_Request] = None,
-                 error: Optional[BaseException] = None) -> None:
-        with self._cv:
-            t.req = req
-            t.error = error
-            t.resolved = True
-            cancelled = t.cancelled
-            self._cv.notify_all()
-        if cancelled and req is not None:
-            self.decode._cancel(req)
-
-    # -- request surface (LLMEngine contract) ---------------------------------
-    def submit(self, prompt_ids: Sequence[int], *, max_new_tokens: int = 32,
-               temperature: float = 0.0, seed: int = 0) -> _DisaggTicket:
-        prompt = np.asarray(prompt_ids, np.int32).reshape(-1)
-        if prompt.shape[0] == 0:
-            raise ValueError("empty prompt")
-        _check_token_ids(prompt, self.decode.config.vocab_size, self.name)
-        self.decode._bucket_for(int(prompt.shape[0]))  # validate headroom
-        t = _DisaggTicket(prompt, int(max_new_tokens), float(temperature),
-                          int(seed))
-        if max_new_tokens <= 0:
-            t.resolved = True
-            return t
-        with self._cv:
-            if self._closed:
-                raise RuntimeError(f"engine {self.name} closed")
-            if self.max_queue and len(self._pq) >= self.max_queue:
-                raise _shed(self.name, len(self._pq), self.max_queue,
-                            "already waiting for prefill")
-            self._pq.append(t)
-            self._cv.notify_all()
-        return t
-
-    def stream(self, prompt_ids: Sequence[int], *, max_new_tokens: int = 32,
-               temperature: float = 0.0, seed: int = 0,
-               result: Optional[Dict] = None) -> Iterable[int]:
-        if result is None:
-            result = {}
-        t = self.submit(prompt_ids, max_new_tokens=max_new_tokens,
-                        temperature=temperature, seed=seed)
-
-        def run():
-            raised = None
-            try:
-                with self._cv:
-                    deadline = time.monotonic() + 120.0
-                    while not t.resolved:
-                        # raylint: ignore[blocking-under-lock] — _cv wraps
-                        # self._lock; wait() releases it.
-                        if not self._cv.wait(timeout=0.2) \
-                                and time.monotonic() > deadline:
-                            raise TimeoutError(
-                                "disaggregated prefill stalled")
-                if t.error is not None:
-                    raise t.error
-                if t.req is None:
-                    return
-                for tok in self.decode.drive(t.req):
-                    result["decode_tps"] = t.req.decode_tps()
-                    yield tok
-            except BaseException as e:
-                raised = e
-                raise
-            finally:
-                if t.req is not None:
-                    fr = t.req.finish_reason or "stop"
-                elif t.error is not None or raised is not None:
-                    # The prefill-stall TimeoutError resolves nothing on the
-                    # ticket — without tracking the raise this path would
-                    # claim a clean "stop" for a generator that blew up.
-                    fr = "error"
-                elif t.cancelled:
-                    fr = "cancelled"
-                else:
-                    fr = "stop"
-                result["finish_reason"] = self.finish_reason = fr
-                if t.req is not None and t.req.ttft_s is not None:
-                    result["ttft_s"] = t.req.ttft_s
-
-        gen = run()
-        weakref.finalize(gen, self._cancel_ticket, t)
-        return gen
-
-    def generate(self, prompt_ids: Sequence[int], **kw) -> List[int]:
-        return list(self.stream(prompt_ids, **kw))
-
-    def _cancel_ticket(self, t: _DisaggTicket) -> None:
-        req = None
-        with self._cv:
-            t.cancelled = True
-            try:
-                self._pq.remove(t)
-                t.resolved = True  # never entered the pipeline
-            except ValueError:
-                req = t.req  # mid-pipeline (worker resolves) or decoding
-            self._cv.notify_all()
-        if req is not None:
-            self.decode._cancel(req)
-
-    # -- engine surface delegates ---------------------------------------------
-    def warmup(self) -> None:
-        self.prefill.warmup()
-        self.decode.warmup()
-
-    def stats(self) -> Dict[str, float]:
-        out = self.decode.stats()
-        with self._cv:
-            out["queue_depth"] += float(len(self._pq)
-                                        + len(self._lane_fifo))
-        out["queue_limit"] = float(self.max_queue)  # the inner engines' is 0
-        pf = self.prefill.kv.stats()
-        out["prefill_kv_hit_tokens"] = pf["kv_hit_tokens"]
-        out["prefill_kv_blocks_cached"] = pf["kv_blocks_cached"]
-        return out
-
-    def describe(self) -> Dict:
-        return self.decode.describe()
-
-    def decode_tokens_per_sec(self) -> float:
-        return self.decode.decode_tokens_per_sec()
-
-    def close(self) -> None:
-        """Stop the pipeline workers, poison-pill the lane, destroy it.
-        Pending tickets resolve as errors. Idempotent."""
-        with self._cv:
-            if self._closed:
-                return
-            self._closed = True
-            leftovers = list(self._pq)
-            self._pq.clear()
-            self._cv.notify_all()
-        for t in leftovers:
-            self._resolve(t, error=RuntimeError(f"engine {self.name} closed"))
-        self._prefill_thread.join(timeout=5.0)
-        self.lane.close()  # pill — wakes the ingest loop
-        self._ingest_thread.join(timeout=5.0)
-        if self._ingest_thread.is_alive():
-            # It can be parked in admit_prefilled's alloc retry (bounded by
-            # its timeout_s=30) while holding zero-copy views into the ring
-            # — wait that bound out before touching the mapping.
-            self._ingest_thread.join(timeout=35.0)
-        with self._cv:
-            stranded = list(self._lane_fifo)
-            self._lane_fifo.clear()
-        for t in stranded:
-            self._resolve(t, error=RuntimeError(f"engine {self.name} closed"))
-        if self._ingest_thread.is_alive():
-            # Still wedged: destroy() would unmap shm under the thread's
-            # live views — leak the lane instead and let channel teardown
-            # reclaim it when the views drop.
-            return
-        self.lane.destroy()
 
 
 def llm_deployment(
@@ -2417,10 +1786,10 @@ def llm_deployment(
 
     ``config`` is a model family's config object: a
     ``models.transformer.TransformerConfig`` (GPT-2) or a
-    ``models.longcat.LongCatConfig``; the paged engine finds the family's
-    pool and forward pass through it (``models.generate.PagedFamily``), and
-    what a family cannot run yet (a draft model, disaggregation, the KV
-    tier) raises when the replica builds its engine.
+    ``models.longcat.LongCatConfig``; the engine finds the family's pool and
+    forward pass through it (``models.generate.PagedFamily``), and what a
+    family cannot run yet (a draft model, the KV tier) raises when the
+    replica builds its engine.
 
     ``params_fn`` runs inside the replica (checkpoint load / init) so weights
     never ship through the controller. Request payload::
@@ -2463,26 +1832,15 @@ def llm_deployment(
     @serve.deployment(name=name, **deployment_kwargs)
     class LLMServer:
         def __init__(self):
-            # Engine choice re-reads the knobs HERE (replica process): the
-            # paged engine is the default; serve_kv_paged_enabled=0 falls
-            # back to the PR 8 slotted engine, serve_disaggregation_enabled=1
-            # splits prefill from decode over a KV handoff lane.
-            eng_knobs = _get_config()
             eng_kw = {}
-            if bool(eng_knobs.serve_disaggregation_enabled):
-                cls = DisaggregatedLLMEngine
-            elif bool(eng_knobs.serve_kv_paged_enabled):
-                cls = PagedLLMEngine
-                if draft_params_fn is not None:
-                    # Draft weights load in-replica like the target's —
-                    # speculation turns on when serve_spec_tokens > 0.
-                    eng_kw["draft_params"] = draft_params_fn()
-                    eng_kw["draft_config"] = draft_config
-            else:
-                cls = LLMEngine
-            self.engine = cls(params_fn(), config, slots=n_slots,
-                              chunk=chunk, max_queue=q_limit, name=name,
-                              **eng_kw)
+            if draft_params_fn is not None:
+                # Draft weights load in-replica like the target's —
+                # speculation turns on when serve_spec_tokens > 0.
+                eng_kw["draft_params"] = draft_params_fn()
+                eng_kw["draft_config"] = draft_config
+            self.engine = LLMEngine(params_fn(), config, slots=n_slots,
+                                    chunk=chunk, max_queue=q_limit,
+                                    name=name, **eng_kw)
             self.engine.warmup()
 
         def __call__(self, payload):
@@ -2522,11 +1880,9 @@ def llm_deployment(
 
         # -- drain migration (controller-driven, cluster KV tier) -------------
         def kv_migrate_out(self, lane_name: str) -> int:
-            fn = getattr(self.engine, "kv_migrate_out", None)
-            return int(fn(lane_name)) if fn is not None else 0
+            return int(self.engine.kv_migrate_out(lane_name))
 
         def kv_migrate_in(self, lane_name: str) -> int:
-            fn = getattr(self.engine, "kv_migrate_in", None)
-            return int(fn(lane_name)) if fn is not None else 0
+            return int(self.engine.kv_migrate_in(lane_name))
 
     return LLMServer

@@ -461,11 +461,11 @@ class TestEngineSteadyState:
         triggers ZERO new XLA compilations and zero implicit host reads —
         the invariant every serve perf number rests on."""
         from ray_tpu.models import transformer
-        from ray_tpu.serve.llm import PagedLLMEngine
+        from ray_tpu.serve.llm import LLMEngine
 
         cfg = transformer.tiny(max_seq_len=64)
         params = transformer.init_params(cfg, jax.random.key(0))
-        eng = PagedLLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4,
+        eng = LLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4,
                              slots=2, max_queue=4, name="jitcheck-e2e",
                              block_tokens=8, pool_blocks=65)
         eng.warmup()

@@ -112,7 +112,8 @@ class TestKVCache:
 
 
 class TestChunkedDecode:
-    """serve/llm.py fast path: fused prefill + lax.scan decode chunks."""
+    """serve/llm.py fast path: bucketed prefill + lax.scan decode chunks
+    over the paged pool, against the per-token ``Generator``."""
 
     @pytest.fixture()
     def setup(self):
@@ -130,6 +131,7 @@ class TestChunkedDecode:
         eng = LLMEngine(params, cfg, chunk=4)
         got = eng.generate(prompt, max_new_tokens=12)
         assert got == oracle
+        assert eng.kv.active_blocks() == 0
 
     def test_bucket_padding_is_invisible(self, setup):
         """Prompt of 5 pads to bucket 16; tokens must match the unpadded
@@ -142,6 +144,7 @@ class TestChunkedDecode:
         got = eng.generate(prompt, max_new_tokens=8)
         oracle = Generator(params, cfg, batch=1).generate(prompt, max_new_tokens=8)
         assert got == oracle
+        assert eng.kv.active_blocks() == 0
 
     def test_sampled_stream_runs(self, setup):
         cfg, params = setup
@@ -151,6 +154,7 @@ class TestChunkedDecode:
         toks = eng.generate([1, 2], max_new_tokens=6, temperature=0.8, seed=3)
         assert len(toks) == 6
         assert all(0 <= t < cfg.vocab_size for t in toks)
+        assert eng.kv.active_blocks() == 0
 
     def test_prompt_too_long_raises(self, setup):
         cfg, params = setup
@@ -159,6 +163,7 @@ class TestChunkedDecode:
         eng = LLMEngine(params, cfg, chunk=8)  # max_len 64
         with pytest.raises(ValueError, match="no room"):
             eng.generate(list(range(1, 60)), max_new_tokens=4)
+        assert eng.kv.active_blocks() == 0
 
     def test_length_cap_finish_reason(self, setup):
         cfg, params = setup
@@ -169,3 +174,4 @@ class TestChunkedDecode:
         toks = eng.generate([1] * 16, max_new_tokens=100)
         assert len(toks) == 48
         assert eng.finish_reason == "length_cap"
+        assert eng.kv.active_blocks() == 0

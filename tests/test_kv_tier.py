@@ -29,7 +29,7 @@ from ray_tpu.core.gcs_shards import ShardedPrefixDirectory
 from ray_tpu.models import generate, transformer
 from ray_tpu.serve import kv_tier
 from ray_tpu.serve.handle import Router
-from ray_tpu.serve.llm import PagedLLMEngine
+from ray_tpu.serve.llm import LLMEngine
 from ray_tpu.util import blockhash
 
 BT = 8  # test block size: small enough to exercise multi-block prompts
@@ -81,7 +81,7 @@ def oracle(tiny_model):
 
 def _mk_engine(tiny_model, name):
     cfg, params = tiny_model
-    eng = PagedLLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4,
+    eng = LLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4,
                          slots=2, max_queue=0, name=name, block_tokens=BT,
                          pool_blocks=129)
     eng.warmup()
@@ -282,7 +282,7 @@ class TestClusterWideHit:
 
     def test_flag_off_restores_private_kv(self, tiny_model):
         """kv_tier_enabled=0: no tier object, no directory traffic — the
-        engine is byte-identical to the pre-tier PagedLLMEngine."""
+        engine is byte-identical to the pre-tier LLMEngine."""
         from ray_tpu.core.config import config as get_config
 
         prev = get_config()

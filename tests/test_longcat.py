@@ -26,8 +26,7 @@ from benchmark.manifest import load_file
 from ray_tpu.models import longcat
 from ray_tpu.models.generate import PagedGenerator
 from ray_tpu.ops import moe
-from ray_tpu.serve.llm import (DisaggregatedLLMEngine, LLMEngine,
-                               PagedLLMEngine, llm_deployment)
+from ray_tpu.serve.llm import LLMEngine, llm_deployment
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 ref = load_file(REPO, "benchmark/reference/longcat_plain.py")
@@ -281,10 +280,10 @@ def test_masked_tokens_route_nowhere(layer):
 @pytest.fixture(scope="module")
 def engine(model):
     cfg, params = model
-    eng = PagedLLMEngine(params, cfg, prompt_buckets=(16, 64), chunk=4,
-                         slots=2, max_queue=0, name="longcat-test",
-                         block_tokens=BT, pool_blocks=33,
-                         attention_kernel="interpret")
+    eng = LLMEngine(params, cfg, prompt_buckets=(16, 64), chunk=4,
+                    slots=2, max_queue=0, name="longcat-test",
+                    block_tokens=BT, pool_blocks=33,
+                    attention_kernel="interpret")
     eng.warmup()
     return eng
 
@@ -365,8 +364,7 @@ def test_held_pairs_are_stamped_on_the_step_span(model, engine):
     assert steps and all("moe_held_pairs" in s.attrs for s in steps)
 
 
-@pytest.mark.parametrize("feature", ["draft_model", "disaggregation",
-                                     "kv_tier", "slotted_engine"])
+@pytest.mark.parametrize("feature", ["draft_model", "kv_tier"])
 def test_unsupported_features_raise_at_construction(model, feature):
     from ray_tpu.core.config import Config, config as get_config, set_config
 
@@ -374,20 +372,14 @@ def test_unsupported_features_raise_at_construction(model, feature):
     kw = dict(slots=2, chunk=4, name=f"longcat-{feature}")
     if feature == "draft_model":
         with pytest.raises(ValueError, match="draft model"):
-            PagedLLMEngine(params, cfg, draft_params=params, draft_config=cfg,
-                           spec_tokens=2, **kw)
-    elif feature == "disaggregation":
-        with pytest.raises(ValueError, match="disaggregation"):
-            DisaggregatedLLMEngine(params, cfg, **kw)
-    elif feature == "slotted_engine":
-        with pytest.raises(ValueError, match="slotted engine"):
-            LLMEngine(params, cfg, **kw)
+            LLMEngine(params, cfg, draft_params=params, draft_config=cfg,
+                      spec_tokens=2, **kw)
     else:
         prev = get_config()
         set_config(Config({"kv_tier_enabled": True}))
         try:
             with pytest.raises(ValueError, match="KV tier"):
-                PagedLLMEngine(params, cfg, **kw)
+                LLMEngine(params, cfg, **kw)
         finally:
             set_config(prev)
 

@@ -19,7 +19,7 @@ import pytest
 from ray_tpu.models import generate, transformer
 from ray_tpu.ops.paged_attention import (_blocks_per_group, paged_attention,
                                          paged_attention_reference)
-from ray_tpu.serve.llm import PagedLLMEngine
+from ray_tpu.serve.llm import LLMEngine
 
 BT = 8   # block_tokens
 NB = 6   # blocks per sequence (table width)
@@ -280,9 +280,9 @@ class TestEngineKernelModes:
         params = transformer.init_params(cfg, jax.random.key(0))
         kw = dict(prompt_buckets=(16,), chunk=4, slots=2, max_queue=0,
                   block_tokens=BT, pool_blocks=40)
-        eng_g = PagedLLMEngine(params, cfg, attention_kernel="gather",
+        eng_g = LLMEngine(params, cfg, attention_kernel="gather",
                                name="kern-g", **kw)
-        eng_i = PagedLLMEngine(params, cfg, attention_kernel="interpret",
+        eng_i = LLMEngine(params, cfg, attention_kernel="interpret",
                                name="kern-i", **kw)
         for prompt in ([7, 3, 11], [2, 4, 6, 8, 10, 12, 14]):
             a = eng_g.generate(prompt, max_new_tokens=10)

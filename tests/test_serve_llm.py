@@ -1,10 +1,12 @@
-"""Continuous-batching LLM serving tests (ISSUE 9).
+"""Continuous-batching LLM serving tests: the engine's contract.
 
-Engine-level: the slotted continuous-batching ``LLMEngine`` must be
-token-identical to the single-sequence ``Generator`` oracle under staggered
-concurrent arrivals, retire/refill slots under load, shed with ``Saturated``
-at the admission queue limit while in-flight requests complete, and keep
-decode-rate counters per-request. Serve-level: the same engine behind
+Engine-level: ``LLMEngine`` must be token-identical to the single-sequence
+``Generator`` oracle under staggered concurrent arrivals, retire/refill
+slots under load, shed with ``Saturated`` at the admission queue limit while
+in-flight requests complete, keep decode-rate counters per-request, and pin
+no KV block once it is idle. What needs no GPT-2 oracle (sampling
+determinism, counters, cancellation, the edge cases) runs on both model
+families through the same scheduler. Serve-level: the same engine behind
 ``llm_deployment`` through the full data plane (handle → router → replica),
 plus KV-occupancy-aware routing units on the Router itself.
 """
@@ -17,7 +19,7 @@ import pytest
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu.models import generate, transformer
+from ray_tpu.models import generate, longcat, transformer
 from ray_tpu.serve.errors import Saturated
 from ray_tpu.serve.handle import Router
 from ray_tpu.serve.llm import LLMEngine, llm_deployment
@@ -49,21 +51,45 @@ def oracle(tiny_model):
 
 
 @pytest.fixture(scope="module")
-def engine(tiny_model):
-    """Shared slots=2 engine — tests drain it before finishing."""
-    cfg, params = tiny_model
-    eng = LLMEngine(params, cfg, prompt_buckets=(16,), chunk=4, slots=2,
-                    max_queue=0, name="test")
-    eng.warmup()
-    return eng
+def engines(tiny_model):
+    """One shared slots=2 engine a model family, built when first asked
+    for — tests drain it before finishing."""
+    built = {}
 
+    def get(family):
+        if family not in built:
+            if family == "gpt2":
+                cfg, params = tiny_model
+            else:
+                cfg = longcat.tiny()
+                params = longcat.init_params(cfg, jax.random.key(1))
+            built[family] = LLMEngine(
+                params, cfg, prompt_buckets=(16,), chunk=4, slots=2,
+                max_queue=0, name=f"test-{family}")
+            built[family].warmup()
+        return built[family]
+
+    return get
+
+
+@pytest.fixture
+def engine(request, engines):
+    """GPT-2's engine, or the family a test names through ``both_families``."""
+    return engines(getattr(request, "param", "gpt2"))
+
+
+# The contract tests that compare with no GPT-2 oracle run once a family.
+both_families = pytest.mark.parametrize("engine", ["gpt2", "longcat"],
+                                        indirect=True)
 
 PROMPTS = [[7, 3, 11], [2, 4, 6, 8, 10], [1] * 9, [5, 9] * 7]
 
 
 def _drained(eng):
+    """Idle: no slot busy, nothing queued, and no KV block still pinned."""
     s = eng.stats()
-    return s["slots_busy"] == 0 and s["queue_depth"] == 0
+    return (s["slots_busy"] == 0 and s["queue_depth"] == 0
+            and eng.kv.active_blocks() == 0)
 
 
 class TestEngineEquivalence:
@@ -116,6 +142,7 @@ class TestEngineEquivalence:
             assert outs[i] == oracle(p, n), f"request {i} diverged"
         assert _drained(engine)
 
+    @both_families
     def test_sampled_deterministic_beside_greedy_traffic(self, engine):
         """A sampled request's tokens depend only on its seed — identical
         alone and batched beside concurrent greedy traffic."""
@@ -139,6 +166,7 @@ class TestEngineEquivalence:
         assert outs["sampled"] == alone
         assert _drained(engine)
 
+    @both_families
     def test_per_request_decode_counters(self, engine):
         """decode_tps is per-request (the old engine-level counters raced);
         the aggregate under the lock sums every delivered token."""
@@ -162,26 +190,37 @@ class TestEngineEquivalence:
         with engine._agg_lock:
             assert engine.decode_tokens == base + 16
         assert engine.decode_tokens_per_sec() > 0
+        assert _drained(engine)
 
+    @both_families
     def test_cancellation_frees_slot(self, engine, oracle):
-        """Abandoning a stream mid-generation frees its slot immediately for
-        the next admission."""
+        """Abandoning a stream mid-generation frees its slot and its blocks
+        immediately for the next admission, which decodes what it would have
+        decoded had the cancelled request never run."""
+        after = engine.generate(PROMPTS[0], max_new_tokens=8)
         g = iter(engine.stream(PROMPTS[2], max_new_tokens=32))
-        assert next(g) == oracle(PROMPTS[2], 32)[0]
+        first = next(g)
         g.close()
         assert _drained(engine)
-        assert engine.generate(PROMPTS[0], max_new_tokens=8) == \
-            oracle(PROMPTS[0], 8)
+        assert engine.generate(PROMPTS[0], max_new_tokens=8) == after
+        assert _drained(engine)
+        if isinstance(engine.config, transformer.TransformerConfig):
+            assert first == oracle(PROMPTS[2], 32)[0]
+            assert after == oracle(PROMPTS[0], 8)
 
+    @both_families
     def test_max_new_tokens_zero(self, engine):
         res = {}
         assert list(engine.stream(PROMPTS[0], max_new_tokens=0,
                                   result=res)) == []
         assert res["finish_reason"] == "stop"
+        assert _drained(engine)
 
+    @both_families
     def test_empty_prompt_raises(self, engine):
         with pytest.raises(ValueError, match="empty prompt"):
             engine.generate([], max_new_tokens=4)
+        assert _drained(engine)
 
 
 class TestAdmissionControl:
@@ -206,7 +245,7 @@ class TestAdmissionControl:
         assert _drained(eng)
         assert eng.generate(PROMPTS[2], max_new_tokens=4) == \
             oracle(PROMPTS[2], 4)
-
+        assert _drained(eng)
 
     def test_a_burst_onto_idle_slots_is_not_shed(self, tiny_model):
         """The queue that ``max_queue`` bounds is what waits beyond the free
@@ -224,6 +263,17 @@ class TestAdmissionControl:
         for req in held:
             eng._cancel(req)
         assert _drained(eng)
+
+
+@pytest.mark.parametrize("flag", ["serve_kv_paged_enabled",
+                                  "serve_disaggregation_enabled"])
+def test_engine_selection_flags_are_gone(flag):
+    """One engine: the flags that chose between three are refused as
+    unknown keys, not accepted and ignored."""
+    from ray_tpu.core.config import Config
+
+    with pytest.raises(ValueError, match="Unknown system_config keys"):
+        Config({flag: 1})
 
 
 class _StubReplica:

@@ -1,16 +1,13 @@
-"""Paged KV cache, prefix reuse, disaggregation, and affinity routing
-(ISSUE 11).
+"""Paged KV cache, prefix reuse, and affinity routing (ISSUE 11).
 
-Engine-level: the paged ``PagedLLMEngine`` must be token-identical to the
-single-sequence ``Generator`` oracle (the slotted engine's own oracle) cold
-AND warm — a prefix-cache hit changes FLOPs, never tokens; hit lengths must
-land exactly on hash-block boundaries; COW tail forks must decode in
-isolation and drop every refcount at retire (``active_blocks() == 0`` is
-the leak-check invariant — the suite's ``RAY_TPU_LEAK_CHECK_ENABLED=1``
-teardown guard covers the thread/fd half). Disaggregated: the
-prefill→lane→decode pipeline keeps the same oracle equality and joins its
-workers on ``close()``. Router-level: stale-load eviction on snapshot
-shrink and prefix-affinity picks, as units on ``Router`` itself.
+Engine-level: ``LLMEngine`` must be token-identical to the single-sequence
+``Generator`` oracle cold AND warm — a prefix-cache hit changes FLOPs, never
+tokens; hit lengths must land exactly on hash-block boundaries; COW tail
+forks must decode in isolation and drop every refcount at retire
+(``active_blocks() == 0`` is the leak-check invariant — the suite's
+``RAY_TPU_LEAK_CHECK_ENABLED=1`` teardown guard covers the thread/fd half).
+Router-level: stale-load eviction on snapshot shrink and prefix-affinity
+picks, as units on ``Router`` itself.
 """
 
 import threading
@@ -22,7 +19,7 @@ import pytest
 import ray_tpu
 from ray_tpu.models import generate, transformer
 from ray_tpu.serve.handle import DeploymentHandle, Router
-from ray_tpu.serve.llm import DisaggregatedLLMEngine, PagedLLMEngine
+from ray_tpu.serve.llm import LLMEngine
 from ray_tpu.util.blockhash import prefix_head_hash
 
 BT = 8  # test block size: small enough to exercise multi-block prompts
@@ -58,9 +55,9 @@ def paged(tiny_model):
     """Shared paged engine; pool sized so no test's chains evict another's
     (hit-length deltas below assume no LRU eviction)."""
     cfg, params = tiny_model
-    eng = PagedLLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4,
-                         slots=2, max_queue=0, name="paged-test",
-                         block_tokens=BT, pool_blocks=129)
+    eng = LLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4,
+                    slots=2, max_queue=0, name="paged-test",
+                    block_tokens=BT, pool_blocks=129)
     eng.warmup()
     return eng
 
@@ -220,15 +217,15 @@ class TestCOWForkIsolation:
         """The non-numeric twin of ``stats``: the kernel as resolved (never
         "auto"), the buckets warmup compiled, and where the state lives."""
         d = paged.describe()
-        assert d["engine"] == "PagedLLMEngine"
+        assert d["engine"] == "LLMEngine"
         assert d["attention_kernel"] in ("pallas", "interpret", "gather")
         assert d["warmed_buckets"] == [16, 32]
         assert d["pool_blocks"] == 129 and d["block_tokens"] == BT
         assert d["params_devices"] and d["kv_pool_devices"]
-        cold = PagedLLMEngine(paged.params, paged.config,
-                              prompt_buckets=(16,), slots=1, max_queue=0,
-                              name="paged-cold", block_tokens=BT,
-                              pool_blocks=9)
+        cold = LLMEngine(paged.params, paged.config,
+                         prompt_buckets=(16,), slots=1, max_queue=0,
+                         name="paged-cold", block_tokens=BT,
+                         pool_blocks=9)
         assert cold.describe()["warmed_buckets"] == []
 
 
@@ -447,87 +444,3 @@ class TestPrefixAffinityRouting:
             [{"prompt_ids": prompt[:bt - 1]}]) is None
         assert DeploymentHandle._affinity_hash(["plain-arg"]) is None
         assert DeploymentHandle._affinity_hash([]) is None
-
-
-class TestDisaggregated:
-    @pytest.fixture(scope="class")
-    def disagg(self, tiny_model):
-        cfg, params = tiny_model
-        eng = DisaggregatedLLMEngine(
-            params, cfg, prompt_buckets=(16, 32), chunk=4, slots=2,
-            max_queue=0, name="disagg-test", block_tokens=BT,
-            pool_blocks=65)
-        eng.warmup()
-        yield eng
-        eng.close()
-        eng.close()  # idempotent
-        assert not [t for t in threading.enumerate()
-                    if t.name.startswith("disagg-test-disagg")]
-
-    def test_greedy_matches_oracle(self, disagg, oracle):
-        for p in PROMPTS[:3]:
-            assert disagg.generate(p, max_new_tokens=8) == oracle(p, 8)
-        assert disagg.decode.kv.active_blocks() == 0
-        assert disagg.prefill.kv.active_blocks() == 0
-
-    def test_shared_prefix_hits_prefill_cache(self, disagg, oracle):
-        """Requests sharing a 2-block prefix pay its prefill FLOPs once on
-        the prefill engine; every output stays oracle-equal."""
-        prefix = [151 + i for i in range(2 * BT)]
-        prompts = [prefix + [231 + i] for i in range(3)]
-        before = disagg.stats()["prefill_kv_hit_tokens"]
-        outs = [None] * 3
-        errs = []
-
-        def client(i):
-            try:
-                outs[i] = disagg.generate(prompts[i], max_new_tokens=6)
-            except BaseException as e:  # noqa: BLE001
-                errs.append(e)
-
-        threads = [threading.Thread(target=client, args=(i,))
-                   for i in range(3)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errs
-        for i in range(3):
-            assert outs[i] == oracle(prompts[i], 6), f"request {i} diverged"
-        # At least the two later arrivals hit the first's full blocks.
-        assert disagg.stats()["prefill_kv_hit_tokens"] - before >= \
-            2 * (2 * BT)
-        assert disagg.decode.kv.active_blocks() == 0
-
-    def test_sampled_matches_oracle(self, disagg, oracle):
-        p = PROMPTS[1]
-        out = disagg.generate(p, max_new_tokens=8, temperature=0.7, seed=9)
-        assert out == oracle(p, 8, temperature=0.7, seed=9)
-
-    def test_send_failure_poisons_one_request_only(self, disagg, oracle):
-        """A lane.send failure (non-timeout) resolves ONLY its own ticket as
-        an error and unqueues it from the handoff FIFO — later requests must
-        pair with their own payloads instead of inheriting the dead
-        ticket's, and the stream reports finish_reason "error"."""
-        orig_send = disagg.lane.send
-        calls = {"n": 0}
-
-        def flaky(meta, k, v, timeout=30.0):
-            calls["n"] += 1
-            if calls["n"] == 1:
-                raise ValueError("payload exceeds lane capacity")
-            return orig_send(meta, k, v, timeout=timeout)
-
-        disagg.lane.send = flaky
-        try:
-            result = {}
-            with pytest.raises(ValueError, match="lane capacity"):
-                list(disagg.stream([61, 62, 63], max_new_tokens=4,
-                                   result=result))
-            assert result["finish_reason"] == "error"
-            p = [64, 65, 66, 67]
-            assert disagg.generate(p, max_new_tokens=6) == oracle(p, 6)
-        finally:
-            disagg.lane.send = orig_send
-        assert disagg.decode.kv.active_blocks() == 0
-        assert disagg.prefill.kv.active_blocks() == 0

@@ -20,7 +20,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import generate, transformer
-from ray_tpu.serve.llm import PagedLLMEngine
+from ray_tpu.serve.llm import LLMEngine
 
 BT = 8
 
@@ -49,7 +49,7 @@ PROMPTS = [[5, 9, 3, 77, 21], [1, 2, 3], [9, 8, 7, 6, 5, 4, 3, 2, 1],
 def _spec_engine(models, draft, k=3, **kw):
     cfg, params, drafts = models
     merged = {**ENG_KW, **kw}
-    return PagedLLMEngine(params, cfg, draft_params=drafts[draft],
+    return LLMEngine(params, cfg, draft_params=drafts[draft],
                           draft_config=cfg, spec_tokens=k,
                           name=f"spec-{draft}", **merged)
 
@@ -57,7 +57,7 @@ def _spec_engine(models, draft, k=3, **kw):
 @pytest.fixture(scope="module")
 def plain(models):
     cfg, params, _ = models
-    return PagedLLMEngine(params, cfg, name="spec-base", **ENG_KW)
+    return LLMEngine(params, cfg, name="spec-base", **ENG_KW)
 
 
 class TestGreedyTokenIdentity:
@@ -194,7 +194,7 @@ class TestBlockAccounting:
     def test_draft_requires_config(self, models):
         cfg, params, drafts = models
         with pytest.raises(ValueError):
-            PagedLLMEngine(params, cfg, spec_tokens=2, **ENG_KW)
+            LLMEngine(params, cfg, spec_tokens=2, **ENG_KW)
         with pytest.raises(ValueError):
             generate.PagedGenerator(params, cfg, slots=2, num_blocks=17,
                                     block_tokens=BT,

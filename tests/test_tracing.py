@@ -27,7 +27,7 @@ from ray_tpu.core.config import Config, set_config
 from ray_tpu.core.runtime import get_runtime
 from ray_tpu.dag import InputNode
 from ray_tpu.models import transformer
-from ray_tpu.serve.llm import PagedLLMEngine, llm_deployment
+from ray_tpu.serve.llm import LLMEngine, llm_deployment
 from ray_tpu.util import tracing
 
 
@@ -377,7 +377,7 @@ def tiny_model():
 
 def _engine(tiny_model, pool_blocks, name):
     cfg, params = tiny_model
-    eng = PagedLLMEngine(params, cfg, prompt_buckets=(16,), chunk=4, slots=2,
+    eng = LLMEngine(params, cfg, prompt_buckets=(16,), chunk=4, slots=2,
                          max_queue=0, name=name, block_tokens=8,
                          pool_blocks=pool_blocks)
     eng.warmup()
@@ -439,9 +439,9 @@ class TestEngineStepTrace:
         programs = [s for s in spans if s.name == "llm.warmup.program"]
         assert all(p.parent_id == warm.span_id for p in programs)
         assert [p.attrs["program"] for p in programs] == [
-            "paged_prefill", "paged_decode", "copy_block", "set_last"]
+            "paged_prefill", "paged_decode", "copy_block"]
         assert programs[0].attrs["bucket"] == 16
-        assert warm.attrs["programs"] == 4
+        assert warm.attrs["programs"] == 3
         # JAX reported the time it spent tracing and lowering each one.
         assert all(p.attrs["trace_s"] > 0 and p.attrs["lower_s"] > 0
                    for p in programs[:2])
