@@ -71,7 +71,7 @@ JAX_CHECKS = ("jit-churn", "host-sync", "key-reuse", "donate-uaf")
 #: must exist, so a rename retires the declaration loudly, not silently
 #: (the PR 6 hot-module discipline).
 HOT_SCOPES: Dict[str, Tuple[str, ...]] = {
-    "serve/llm.py": ("_step_inner", "_run_decode"),
+    "serve/llm.py": ("_step_inner", "_deliver", "_run_decode"),
     "models/generate.py": ("generate",),
     "rllib/env_runner.py": ("sample",),
     "rllib/learner.py": ("update",),

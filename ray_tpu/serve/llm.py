@@ -13,15 +13,23 @@ and the hash table that lets a prompt reuse the blocks of an earlier one.
 
 Scheduling is iteration-level (the vLLM/Orca policy): each engine step
 
-1. retires finished slots (max_new_tokens reached, or no room for another
-   chunk before ``max_len`` — ``length_cap``) and immediately
+1. retires finished slots by COUNT (max_new_tokens dispatched, or no room
+   for another chunk before ``max_len`` — ``length_cap``) and immediately
 2. admits queued prompts into the free slots, bounded by a prefill token
    budget per step (``serve_llm_prefill_tokens``) so a burst of long
    prompts can't starve in-flight decode; an admission prefills only the
    suffix the prefix cache does not already hold, and a prompt the pool has
    no blocks for goes back to the head of the queue; then
-3. runs ONE batched decode chunk and distributes each slot's tokens to its
-   request's queue.
+3. dispatches ONE batched decode chunk, and only then
+4. fetches the tokens of the chunk dispatched ONE STEP EARLIER and
+   distributes each slot's tokens to its request's queue.
+
+So one decode program is always queued behind the one that runs, and the
+host's work of a step lies under it: no stop condition reads a token's value
+(there is no stop token), and the device threads last tokens, keys and pool
+from program to program itself. With a draft model a slot's advance IS a
+value (the step's acceptance), so there the chunk is fetched in the step that
+dispatched it.
 
 There is no engine thread: the step loop is driven by whichever request
 thread wins a non-blocking try-lock (``drive``), so an idle engine owns no
@@ -112,6 +120,7 @@ class _Request:
         "submitted_at", "ttft_s", "trace_ctx", "queued_s", "prefill_s",
         "out_ids", "hit_tokens",
         "submitted_ns", "prefill_end_ns", "prefill_span",
+        "scheduled", "retiring", "blocks",
     )
 
     def __init__(self, prompt, padded, real_len, bucket, max_new,
@@ -127,6 +136,14 @@ class _Request:
         self.cond = cond
         self.slot: Optional[int] = None
         self.emitted = 0
+        # Tokens of the answer that dispatched chunks will deliver: the
+        # scheduler's count, ahead of ``emitted`` by the chunk in flight.
+        self.scheduled = 0
+        # Set when the request is retired by count ("stop"/"length_cap"):
+        # its slot is free, its blocks (``blocks``) stay pinned until its
+        # last tokens are delivered.
+        self.retiring: Optional[str] = None
+        self.blocks: List[int] = []
         self.done = False
         self.cancelled = False
         self.error: Optional[BaseException] = None
@@ -310,6 +327,36 @@ class _StepTrace:
                 for (name, start), end in zip(self.marks, ends)]
 
 
+class _Chunk:
+    """A dispatched decode chunk whose tokens are not on the host yet: its
+    device arrays and the host's snapshot of whom they are for. Delivery
+    goes by ``rows``, never by the engine's slot table at fetch time: a
+    slot whose request ends with this chunk is given to the next request
+    before this chunk's tokens are fetched."""
+
+    __slots__ = ("arrays", "rows", "spec", "start_ns")
+
+    def __init__(self, arrays, rows: List[tuple], spec: bool, start_ns: int):
+        # (tokens, the family's count arrays: the decode call's and that
+        # step's prefills'), still on the device.
+        self.arrays = arrays
+        # (slot, request, tokens it may still take) per active slot; the
+        # last is None for a speculative chunk, whose advance is a value.
+        self.rows = rows
+        self.spec = spec
+        # When the device could start on it: its dispatch, or the fetch of
+        # the chunk it was queued behind.
+        self.start_ns = start_ns
+
+    def take(self):
+        """The device arrays, for the one ``device_get``; the record keeps
+        none of them, so that nothing outlives the fetch (PERF.md §6,
+        PR 29: a live reference to a fetched output cost the
+        ``longcat-flash-omni`` cell 12 ms of device idle a step)."""
+        arrays, self.arrays = self.arrays, None
+        return arrays
+
+
 class LLMEngine:
     """Continuous-batching engine over a PAGED KV cache with prefix reuse:
     S cache slots, caller-driven stepping.
@@ -329,10 +376,29 @@ class LLMEngine:
     - a hit on a retired sequence's partial tail block is copy-on-write:
       the block is duplicated into a private block before the divergent
       suffix writes into it, full-block hits share by refcount alone;
-    - at retire the finished prompt+output chain is registered so the NEXT
-      turn of the conversation hits it;
+    - when a finished request's last tokens are delivered its
+      prompt+output chain is registered so the NEXT turn of the
+      conversation hits it;
     - pool exhaustion (after LRU-evicting unpinned cached blocks) requeues
       the request rather than failing it.
+
+    **When a step's tokens are fetched.** A step schedules and dispatches
+    chunk k+1 (retire by count, admit, operands, the decode call) BEFORE it
+    fetches chunk k's tokens, delivers them and observes: the ``device_get``
+    returns when k ends, with k+1 and its prefills already in the device's
+    queue. A step that finds nothing to dispatch but a chunk pending fetches
+    and delivers it; a step on an engine with nothing pending dispatches and
+    returns. The depth is one and is not a setting. An engine with a draft
+    model fetches in the step that dispatched (depth 0): its next operands
+    depend on the step's acceptance, a value.
+
+    **When a retired request's blocks return to the pool.** Retirement by
+    count frees the SLOT at schedule time, one step before the request's
+    last tokens are on the host; its BLOCKS stay pinned until those tokens
+    are delivered (the chain is registered with them), then ``finish_reason``,
+    ``done`` and the ``llm.request`` span follow. Cancellation frees slot
+    and blocks at once; tokens of a cancelled request still in flight are
+    dropped at delivery.
     """
 
     def __init__(self, params, config, *,
@@ -404,6 +470,10 @@ class LLMEngine:
         # and this step's prefills', fetched with the step's tokens.
         self._decode_aux = None
         self._prefill_aux: List = []
+        # The chunk dispatched by the step before, unfetched: the loop runs
+        # one decode program ahead of the tokens it has read (_step_inner).
+        # On the engine because the driver changes between steps.
+        self._pending: Optional[_Chunk] = None
         self._aux_totals = {
             key: 0 for n in self._pg.family.aux_counts
             for key in (n.decode, n.prefill) if key}
@@ -446,8 +516,10 @@ class LLMEngine:
         # written by the step thread, read by stats()). The ``_s`` ones are
         # kept in ns here and given in seconds by stats().
         # Each has a reader under benchmark/metrics: admit_budget_stop_share
-        # (budget stops over steps), pool_blocked_share, step_host_share.
-        self._counts = {"steps_total": 0, "admit_stopped_budget_total": 0,
+        # (budget stops over steps), pool_blocked_share, step_host_share,
+        # dispatch_ahead_share (steps ahead over steps).
+        self._counts = {"steps_total": 0, "steps_ahead_total": 0,
+                        "admit_stopped_budget_total": 0,
                         "admit_blocked_pool_s": 0, "step_host_s": 0,
                         "step_device_wait_s": 0}
         # Start of a step whose admission stopped on NoFreeBlocks with a
@@ -523,6 +595,7 @@ class LLMEngine:
         failed dispatch took the in-flight requests' cache state with it."""
         self._pool, self._last, self._keys = self._pg.init_state()
         self._decode_aux, self._prefill_aux = None, []
+        self._pending = None
         # Pool contents are gone — the prefix cache resets with it. Queued
         # spill entries and tracked chains point into the dead pool, so
         # they go too (their pins die with the replaced manager); chains
@@ -752,6 +825,9 @@ class LLMEngine:
             slot = req.slot
             if slot is not None:
                 self._free_slot_locked(slot)
+            # Retired by count with its last chunk unfetched: the blocks it
+            # still pins go now, and delivery drops its tokens.
+            self._release_blocks_locked(req)
             req.done = True
             if req.finish_reason is None:
                 req.finish_reason = "cancelled"
@@ -776,8 +852,10 @@ class LLMEngine:
         ids = self._slot_blocks[slot]
         if ids:
             self._slot_blocks[slot] = []
-            self._slot_table[slot, :] = 0
             self.kv.release(ids)
+        # Always: a request retired by count took its blocks with it, and a
+        # parked slot's writes must land in the trash block, not in theirs.
+        self._slot_table[slot, :] = 0
         if self._spec:
             self._spec_on[slot] = False
             self._spec_use_pending[slot] = False
@@ -788,11 +866,18 @@ class LLMEngine:
         self._slot_len[slot] = 0
         self._active[slot] = False
 
+    def _release_blocks_locked(self, req: _Request) -> None:
+        """Unpin what a request retired by count still holds. Under
+        _state_lock; idempotent."""
+        ids, req.blocks = req.blocks, []
+        if ids:
+            self.kv.release(ids)
+
     def _on_retire_locked(self, req: _Request) -> None:
-        """A request finished cleanly ("stop"/"length_cap") and still owns
-        its slot: publish its chain into the prefix cache. Under _state_lock,
-        just before the slot frees."""
-        ids = self._slot_blocks[req.slot]
+        """A request finished cleanly ("stop"/"length_cap") and its last
+        tokens are on the host: publish its chain into the prefix cache.
+        Under _state_lock, just before its blocks are released."""
+        ids = req.blocks
         if not ids:
             return
         # Register the finished prompt+output chain (including a partial
@@ -824,13 +909,27 @@ class LLMEngine:
             self._tier_spill_q.append(
                 (list(chain), full_ids, n_full, digests))
 
-    def _finish_locked(self, req: _Request, reason: str) -> None:
-        req.finish_reason = reason
-        req.done = True
+    def _retire_locked(self, req: _Request, reason: str) -> None:
+        """Retire by count: every token the request may take is dispatched
+        ("stop"), or its next chunk would cross ``max_len``
+        ("length_cap"). The SLOT is free for the next admission from here;
+        the BLOCKS stay pinned on the request until its last tokens are
+        delivered (their chain is registered then: it needs the token
+        values). Under _state_lock."""
         slot = req.slot
-        if slot is not None:
-            self._on_retire_locked(req)
-            self._free_slot_locked(slot)
+        req.retiring = reason
+        req.blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
+        self._free_slot_locked(slot)
+        if req.emitted == req.scheduled:    # nothing of it is in flight
+            self._finish_locked(req, slot)
+
+    def _finish_locked(self, req: _Request, slot: int) -> None:
+        """A retired request's last tokens are delivered: register its
+        chain, return its blocks to the pool, tell its consumer."""
+        self._on_retire_locked(req)
+        self._release_blocks_locked(req)
+        req.finish_reason = req.retiring
+        req.done = True
         self._emit_request_span(req, slot)
         req.cond.notify_all()
 
@@ -840,10 +939,17 @@ class LLMEngine:
         with self._state_lock:
             victims = list(self._waiting) + [r for r in self._slot_req
                                              if r is not None]
+            if self._pending is not None:
+                # Requests retired by count whose last chunk was in flight
+                # hold no slot any more.
+                victims += [r for _, r, _ in self._pending.rows]
             self._waiting.clear()
             for slot in range(self.slots):
                 self._free_slot_locked(slot)
             for r in victims:
+                if r.done:
+                    continue
+                r.blocks = []       # the manager they pin dies below
                 r.error = err
                 r.done = True
                 if r.finish_reason is None:
@@ -922,7 +1028,8 @@ class LLMEngine:
         a = st.attrs
         with self._agg_lock:
             c = self._counts
-            c["steps_total"] += 1 if a.get("batch") else 0
+            c["steps_total"] += 1 if a["batch"] else 0
+            c["steps_ahead_total"] += a["ahead"]
             c["admit_stopped_budget_total"] += a["admit_stopped"] == "budget"
             c["step_device_wait_s"] += wait
             c["step_host_s"] += st.end_ns - start - wait
@@ -942,9 +1049,24 @@ class LLMEngine:
                          parent_span_id=sid, export=False)
 
     def _step_inner(self, st: _StepTrace) -> None:
-        # 1. Retire: a slot whose next chunk would cross max_len ends as
+        """Schedule and dispatch the next chunk, THEN fetch and deliver the
+        one dispatched a step earlier (``_pending``). Nothing the next chunk
+        needs depends on the values of the tokens in flight: a request
+        stops on counts (``max_new``, ``max_len``), the decode program
+        reads each slot's last token from device state, and pool, keys and
+        last tokens are threaded from program to program as device arrays.
+        So the host's work of a step (retire, admit, operands, deliver, the
+        hand-off between drivers) lies under a running decode program. The
+        look-ahead is one chunk, and not a setting: a second would add a
+        whole chunk to every new request's wait for admission and hide
+        nothing more."""
+        st.attrs.update(batch=0, tokens=0, ahead=False)
+        # 1. Retire, by count: a request all of whose tokens are dispatched
+        #    ends as stop, a slot whose next chunk would cross max_len as
         #    length_cap BEFORE dispatch (no partial chunks — shapes stay
-        #    static), and cancelled slots free immediately.
+        #    static), and cancelled slots free immediately. The slot is
+        #    free from here; a request whose last chunk is still in flight
+        #    finishes when this step delivers it.
         with self._state_lock:
             for slot in range(self.slots):
                 req = self._slot_req[slot]
@@ -952,8 +1074,10 @@ class LLMEngine:
                     continue
                 if req.cancelled:
                     self._free_slot_locked(slot)
+                elif req.scheduled >= req.max_new:
+                    self._retire_locked(req, "stop")
                 elif self._slot_len[slot] + self.chunk > self.max_len:
-                    self._finish_locked(req, "length_cap")
+                    self._retire_locked(req, "length_cap")
 
         # 2. Admit queued prompts into free slots under the prefill budget.
         #    The FIRST admission always goes through — the budget bounds how
@@ -1026,61 +1150,96 @@ class LLMEngine:
         st.attrs.update(admitted=admitted, admit_stopped=stopped)
 
         st.enter("operands")
+        chunk: Optional[_Chunk] = None
+        rows: List[tuple] = []      # (slot, request, tokens it may take)
         with self._state_lock:
-            if not any(r is not None for r in self._slot_req):
+            if any(r is not None for r in self._slot_req):
+                active = self._active.copy()
+                greedy = self._greedy.copy()
+                temps = self._temps.copy()
+                # Cancel paths mutate the block tables and lengths, so they
+                # are captured atomically with the active mask.
+                tables = self._slot_table.copy()
+                lengths = np.asarray(self._slot_len, np.int32)
+                spec_ops = (self._spec_operands_locked(lengths)
+                            if self._spec else None)
+                # The counts move to dispatch: the next schedule sees the
+                # lengths the device will have. A plain chunk advances every
+                # active slot by ``chunk``; a speculative one by the step's
+                # acceptance, a value, counted when it is delivered.
+                for slot in np.flatnonzero(active):
+                    req, upto = self._slot_req[slot], None
+                    if spec_ops is None:
+                        upto = min(self.chunk, req.max_new - req.scheduled)
+                        req.scheduled += upto
+                        self._slot_len[slot] += self.chunk
+                    rows.append((int(slot), req, upto))
+            elif self._pending is None:
                 # Nothing in flight (and so nothing pins a block): the pool
                 # cannot be what holds an admission back.
                 self._blocked_since_ns = None
-                st.enter("observe")
-                return
-            active = self._active.copy()
-            greedy = self._greedy.copy()
-            temps = self._temps.copy()
-            # Cancel paths mutate the block tables and lengths, so they are
-            # captured atomically with the active mask.
-            tables = self._slot_table.copy()
-            lengths = np.asarray(self._slot_len, np.int32)
-            spec_ops = (self._spec_operands_locked(lengths)
-                        if self._spec else None)
 
-        # 3. One batched decode chunk advancing every active slot: the
-        #    jitted call returns (dispatch), then the step's single device
-        #    sync (device_wait). The speculative program syncs inside its
-        #    dispatch to read acceptance counts, so there the wait is short.
-        st.enter("dispatch")
-        t0_ns = st.marks[-1][1]
-        toks = self._run_decode(active, greedy, temps, tables, lengths,
-                                spec_ops)
+        # 3. One batched decode chunk advancing every active slot, enqueued
+        #    behind the chunk that runs. The speculative program syncs
+        #    inside its dispatch to read acceptance counts.
+        if rows:
+            st.enter("dispatch")
+            st.attrs.update(batch=len(rows), ahead=self._pending is not None)
+            # No local names the tokens: the record alone holds them, and
+            # lets go of them at the fetch.
+            chunk = _Chunk(
+                (self._run_decode(active, greedy, temps, tables, lengths,
+                                  spec_ops), self._take_step_aux()),
+                rows, spec_ops is not None, st.marks[-1][1])
+
+        # 4. Fetch and deliver. When the next operands depend on this
+        #    chunk's values (a draft model: a slot's advance is the step's
+        #    acceptance) the chunk just dispatched is fetched in this same
+        #    step; otherwise the one dispatched a step earlier is, and this
+        #    step's waits its turn. ``_pending`` keeps the chunk being
+        #    fetched until it is delivered, for _fail_inflight.
+        due = chunk if self._spec else self._pending
+        ttfts = self._deliver(st, due, chunk) if due is not None else []
+        if not self._spec:
+            self._pending = chunk
+        st.enter("observe")
+        self._observe(st.attrs["tokens"], ttfts)
+
+    def _deliver(self, st: _StepTrace, due: _Chunk,
+                 queued: Optional[_Chunk]) -> List[tuple]:
+        """The step's single device sync (device_wait): ``due``'s tokens,
+        which returns when ``due`` ends, while ``queued`` (the chunk this
+        step dispatched, if it is another) and its prefills already sit in
+        the device's queue. Then each row's tokens go to its request.
+        Returns (total, queued, prefill) seconds per first token."""
         st.enter("device_wait")
-        host_toks, host_aux = jax.device_get((toks, self._take_step_aux()))
+        host_toks, host_aux = jax.device_get(due.take())
         st.enter("deliver")
         now_ns = st.marks[-1][1]
-        dt = (now_ns - t0_ns) / 1e9
+        dt = (now_ns - due.start_ns) / 1e9
         now = now_ns / 1e9
+        if queued is not None and queued is not due:
+            queued.start_ns = now_ns    # the device starts on it about now
 
-        # 4. Distribute each slot's tokens to its request.
         delivered_total = 0
-        ttfts: List[tuple] = []  # (total, queued, prefill) per first token
-        batch_size = int(active.sum())
+        ttfts: List[tuple] = []
+        batch_size = len(due.rows)
         firsts: List[tuple] = []  # sampled requests' (req, slot, ntok)
-        spec_step = self._spec and self._last_counts is not None
         with self._state_lock:
-            for slot in range(self.slots):
-                req = self._slot_req[slot]
-                if req is None or not active[slot]:
+            for slot, req, upto in due.rows:
+                if req.done:
+                    # Cancelled since the dispatch (slot and blocks went
+                    # then): its tokens are dropped.
                     continue
-                # A plain step emits exactly ``chunk`` tokens a slot; a
+                # A plain chunk emits exactly ``chunk`` tokens a slot; a
                 # speculative one 1..chunk*(k+1), by the step's acceptance.
-                if spec_step:
+                if due.spec:
                     emitted, adv = self._spec_slot_result(host_toks, slot)
+                    self._slot_len[slot] += adv
+                    upto = min(len(emitted), req.max_new - req.emitted)
+                    req.scheduled += upto
                 else:
-                    emitted = [int(t) for t in host_toks[slot][:self.chunk]]
-                    adv = self.chunk
-                self._slot_len[slot] += adv
-                if req.cancelled:
-                    self._free_slot_locked(slot)
-                    continue
-                upto = min(len(emitted), req.max_new - req.emitted)
+                    emitted = host_toks[slot, :upto].tolist()
                 if upto > 0 and req.ttft_s is None:
                     req.ttft_s = now - req.submitted_at
                     ttfts.append((req.ttft_s, req.queued_s, req.prefill_s))
@@ -1093,8 +1252,13 @@ class LLMEngine:
                 req.decode_tokens += upto
                 req.decode_seconds += dt
                 delivered_total += upto
-                if req.emitted >= req.max_new:
-                    self._finish_locked(req, "stop")
+                if req.retiring is not None:
+                    if req.emitted == req.scheduled:
+                        self._finish_locked(req, slot)
+                elif req.emitted >= req.max_new:
+                    # Fetched in the step that dispatched it: the request
+                    # still holds its slot.
+                    self._retire_locked(req, "stop")
                 else:
                     req.cond.notify_all()
             # What the engine still holds once this step's tokens are out:
@@ -1114,17 +1278,15 @@ class LLMEngine:
                          start=req.prefill_end_ns, end=now_ns,
                          attrs={"slot": slot, "tokens": ntok,
                                 "batch": batch_size})
-        st.attrs.update(batch=batch_size, tokens=delivered_total,
-                        inflight_after=inflight)
-        if spec_step:
+        st.attrs.update(tokens=delivered_total, inflight_after=inflight)
+        if due.spec:
             on = self._spec_last_on
             st.attrs.update(
                 spec_proposed=int(on.sum()) * self.chunk * self.spec_k,
                 spec_accepted=int(self._spec_last_accept[on].sum()),
                 spec_s=self._spec_last_dt)
         self._fold_step_aux(host_aux, st)
-        st.enter("observe")
-        self._observe(delivered_total, ttfts)
+        return ttfts
 
     def _dispatch_prefill(self, req: _Request, slot: int) -> None:
         """Take the prompt's blocks and run its (suffix) prefill into
@@ -1302,7 +1464,8 @@ class LLMEngine:
 
     def _spec_operands_locked(self, lengths):
         """The speculative program's extra operands, snapshotted under
-        _state_lock with the step's tables and lengths."""
+        _state_lock with the step's tables and lengths; None when this
+        step runs the plain program."""
         # Headroom gate: a spec step can write chunk*(k+1) positions ahead,
         # so slots without that much table room degrade to one token per
         # step INSIDE the same program — the base retire rule
@@ -1311,6 +1474,15 @@ class LLMEngine:
         cap = self.blocks_per_seq * self.block_tokens
         headroom = lengths + self.chunk * (self.spec_k + 1) <= cap
         spec_on = self._spec_on & headroom & self._active
+        if not (spec_on.any()
+                or (self._spec_use_pending & self._active).any()):
+            # Every slot degraded (low acceptance / no headroom / fetched
+            # from the store) and none still carries a rejection
+            # replacement: the plain one-token program is strictly cheaper
+            # than a spec step that would force-reject everything. (A
+            # just-demoted slot runs one more spec step, which consumes its
+            # pending token and clears the carry.)
+            return None
         return (spec_on, self._spec_tail.copy(), self._spec_pending.copy(),
                 self._spec_use_pending.copy())
 
@@ -1318,16 +1490,9 @@ class LLMEngine:
         """Dispatch the step's decode program; its tokens come back still on
         the device."""
         if spec_ops is not None:
-            spec_on, _tail, _pending, use_pending = spec_ops
-            if spec_on.any() or (use_pending & active).any():
-                return self._run_spec_decode(active, greedy, temps, tables,
-                                             lengths, spec_ops)
-            # Every slot degraded (low acceptance / no headroom / fetched
-            # from the store) and none still carries a rejection
-            # replacement: the plain one-token program is strictly cheaper
-            # than a spec step that would force-reject everything. (A
-            # just-demoted slot runs one more spec step, which consumes its
-            # pending token and clears the carry.)
+            return self._run_spec_decode(active, greedy, temps, tables,
+                                         lengths, spec_ops)
+        if self._spec:
             self._last_counts = None
             self._spec_last_accept[:] = 0
             self._spec_last_on[:] = False
@@ -1395,13 +1560,14 @@ class LLMEngine:
         return out, int(counts.sum())
 
     def _take_step_aux(self):
-        """Device arrays to fetch WITH the step's tokens, in its one
+        """Device arrays to fetch WITH the chunk's tokens, in its one
         ``device_get``: the family's per-call counts, the decode call's and
-        this step's prefills' (None for GPT-2). A method of its own so that
-        no name in ``_step_inner`` keeps these arrays alive past the fetch:
-        held as locals through deliver and observe they cost the
-        ``longcat-flash-omni`` cell 12 ms of device idle a step, 3.4% of its
-        ``serve_out_tok_s`` (PERF.md §6, PR 29)."""
+        this step's prefills' (None for GPT-2). They go straight into the
+        chunk's record and leave it at the fetch (``_Chunk.take``), so that
+        no name keeps them alive past it: held as locals through deliver
+        and observe they cost the ``longcat-flash-omni`` cell 12 ms of
+        device idle a step, 3.4% of its ``serve_out_tok_s`` (PERF.md §6,
+        PR 29)."""
         aux, self._decode_aux = self._decode_aux, None
         pre, self._prefill_aux = self._prefill_aux, []
         return None if aux is None and not pre else (aux, pre)
@@ -1707,7 +1873,9 @@ class LLMEngine:
                "queue_limit": float(self.max_queue)}
         # Cumulative counts at the step's boundaries: they count with
         # tracing off too. ``steps_total`` counts steps that dispatched a
-        # decode; ``admit_blocked_pool_s`` is the time from the start of a
+        # decode, ``steps_ahead_total`` those of them whose decode call was
+        # enqueued while the chunk before it was unfetched;
+        # ``admit_blocked_pool_s`` is the time from the start of a
         # step whose admission stopped on NoFreeBlocks (a slot free, a
         # request waiting) to the start of the next; ``step_host_s`` +
         # ``step_device_wait_s`` is all the time spent inside steps.

@@ -141,6 +141,9 @@ _HOT_HEADER = """
         def _run_decode(self, active):
             return self._decode_fn(self.params, active)
 
+        def _deliver(self, st, due, queued):
+            return jax.device_get(due.take())
+
 """
 
 
@@ -187,6 +190,9 @@ class TestHostSync:
         _write(tmp_path, "serve/llm.py", """
             class Engine:
                 def _step_inner(self):
+                    return None
+
+                def _deliver(self, st, due, queued):
                     return None
             """)
         found = _jax_findings(tmp_path, "host-sync")

@@ -310,6 +310,246 @@ class TestCancelMidDispatchRace:
         assert paged.kv.active_blocks() == 0
 
 
+# -- the look-ahead: one decode chunk queued behind the one that runs ---------
+
+def _ahead_engine(tiny_model, pool_blocks, name):
+    cfg, params = tiny_model
+    eng = LLMEngine(params, cfg, prompt_buckets=(16, 32), chunk=4, slots=2,
+                    max_queue=0, name=name, block_tokens=BT,
+                    pool_blocks=pool_blocks)
+    eng.warmup()
+    return eng
+
+
+@pytest.fixture(scope="module")
+def ahead(tiny_model):
+    return _ahead_engine(tiny_model, 129, "ahead-test")
+
+
+def _step_until_done(eng, reqs, each_step=None, limit=400):
+    """This thread is the driver: step by hand until every request is done,
+    then hand out what each was delivered, in order."""
+    for _ in range(limit):
+        if all(r.done for r in reqs):
+            break
+        eng._step()
+        if each_step is not None:
+            each_step()
+    assert all(r.done for r in reqs)
+    return [list(eng.drive(r)) for r in reqs]
+
+
+# (prompt, max_new, temperature, seed): unequal lengths, so slots retire at
+# different steps; one sampled stream beside the greedy ones.
+AHEAD_JOBS = [(PROMPTS[0], 5, 0.0, 0), (PROMPTS[1], 12, 0.0, 0),
+              (PROMPTS[2], 9, 0.8, 123), (PROMPTS[3], 16, 0.0, 0),
+              (PROMPTS[4], 4, 0.0, 0), (PROMPTS[1], 7, 0.0, 0)]
+
+
+class TestDispatchAhead:
+    @pytest.mark.parametrize("pool_blocks", [129, 7])
+    def test_a_slot_is_reused_while_its_last_chunk_is_unfetched(
+            self, tiny_model, oracle, pool_blocks):
+        """More requests than slots, unequal ``max_new``: a request retired
+        by count gives its slot to the next one a step before its own last
+        tokens are on the host. Every request still gets exactly its
+        tokens: the oracle's, and those of the same requests one at a time.
+        With a pool that holds one of the longer requests but not two
+        (7 blocks of 8: the trash block and six more, a request takes up to
+        five) admission waits for blocks that are released at delivery, and
+        still makes progress."""
+        eng = _ahead_engine(tiny_model, pool_blocks, f"ahead-{pool_blocks}")
+        before = eng.stats()
+        reqs = [eng.submit(p, max_new_tokens=n, temperature=t, seed=sd)
+                for p, n, t, sd in AHEAD_JOBS]
+        def nobody_is_left_retiring():
+            # Inside a step a retired request's chunk is fetched after its
+            # slot is re-used; between steps every pending row's request
+            # still holds its slot, or is done.
+            for slot, req, _upto in (eng._pending.rows if eng._pending
+                                     else ()):
+                assert eng._slot_req[slot] is req or req.done
+
+        outs = _step_until_done(eng, reqs, nobody_is_left_retiring)
+        for (p, n, t, sd), out, req in zip(AHEAD_JOBS, outs, reqs):
+            assert out == oracle(p, n, temperature=t, seed=sd)
+            assert req.finish_reason == "stop" and req.emitted == n
+        st = eng.stats()
+        assert st["steps_ahead_total"] > before["steps_ahead_total"]
+        assert eng._pending is None and eng.kv.active_blocks() == 0
+        if pool_blocks == 7:
+            assert st["admit_blocked_pool_s"] > 0
+        for p, n, t, sd in AHEAD_JOBS:
+            assert eng.generate(p, max_new_tokens=n, temperature=t,
+                                seed=sd) == oracle(p, n, temperature=t,
+                                                   seed=sd)
+        assert eng.kv.active_blocks() == 0
+
+    def test_slot_taken_before_the_former_requests_tokens_are_fetched(
+            self, ahead, oracle):
+        """Inside the step that re-uses a slot, the former request's last
+        chunk is still on the device when the next request is admitted to
+        its slot: delivery goes by the snapshot taken at dispatch."""
+        seen = []
+        orig = ahead._dispatch_prefill
+
+        def spy(req, slot):
+            pend = ahead._pending
+            former = [r for s, r, _ in (pend.rows if pend else ())
+                      if s == slot and r is not req]
+            seen.append((slot, [(r.retiring, r.done, bool(r.blocks))
+                                for r in former]))
+            return orig(req, slot)
+
+        ahead._dispatch_prefill = spy
+        try:
+            reqs = [ahead.submit(p, max_new_tokens=n)
+                    for p, n in [(PROMPTS[0], 4), (PROMPTS[1], 12),
+                                 (PROMPTS[2], 8)]]
+            outs = _step_until_done(ahead, reqs)
+        finally:
+            ahead._dispatch_prefill = orig
+        assert outs == [oracle(PROMPTS[0], 4), oracle(PROMPTS[1], 12),
+                        oracle(PROMPTS[2], 8)]
+        # The third request took the first one's slot while that one was
+        # retired ("stop"), not done, its blocks still pinned.
+        assert seen[2] == (seen[0][0], [("stop", False, True)])
+        assert ahead._pending is None and ahead.kv.active_blocks() == 0
+
+    def test_cancel_with_two_chunks_dispatched(self, ahead, oracle):
+        """A cancel that lands when the request's second chunk has just been
+        enqueued behind its first, neither fetched: slot and blocks are free
+        at once, nothing is delivered to it afterwards, the stream beside
+        it is untouched, and the pool's counts balance at the end."""
+        state = {"calls": 0}
+        orig = ahead._run_decode
+
+        def hooked(*args):
+            toks = orig(*args)
+            state["calls"] += 1
+            if state["calls"] == 2:
+                assert ahead._pending is not None     # chunk 1, unfetched
+                ahead._cancel(victim)
+                state["after_cancel"] = (
+                    victim.slot, list(victim.tokens),
+                    ahead._slot_req.count(None), ahead.kv.active_blocks())
+            return toks
+
+        victim = ahead.submit(PROMPTS[3], max_new_tokens=32)
+        other = ahead.submit(PROMPTS[1], max_new_tokens=12)
+        other_blocks = -(-(len(PROMPTS[1]) + 12) // BT)
+        ahead._run_decode = hooked
+        try:
+            outs = _step_until_done(ahead, [victim, other])
+        finally:
+            ahead._run_decode = orig
+        assert state["after_cancel"] == (None, [], 1, other_blocks)
+        assert victim.finish_reason == "cancelled"
+        assert outs[0] == [] and victim.emitted == 0 and not victim.out_ids
+        assert outs[1] == oracle(PROMPTS[1], 12)
+        assert ahead._pending is None and ahead.kv.active_blocks() == 0
+        st = ahead.kv.stats()
+        assert st["kv_blocks_active"] == 0
+        assert st["kv_blocks_cached"] + st["kv_blocks_free"] \
+            == st["kv_blocks_total"]
+
+    def test_the_last_chunk_is_delivered_by_a_draining_step(self, ahead,
+                                                           oracle):
+        """No further ``submit``: the step after the last dispatch finds
+        nothing to dispatch, fetches the pending chunk and finishes the
+        request. It dispatches nothing and counts no ``steps_total``."""
+        from ray_tpu.util import tracing
+
+        before = ahead.stats()
+        t0 = tracing.now_ns()
+        req = ahead.submit(PROMPTS[2], max_new_tokens=10)   # three chunks
+        ahead._step()
+        assert ahead._pending is not None and not req.tokens
+        ahead._step()
+        ahead._step()
+        # All three chunks are dispatched, two delivered; the slot is still
+        # the request's (retirement is the next step's).
+        assert len(req.tokens) == 8 and not req.done
+        assert ahead.stats()["steps_total"] - before["steps_total"] == 3
+        ahead._step()
+        assert req.done and req.finish_reason == "stop"
+        assert list(ahead.drive(req)) == oracle(PROMPTS[2], 10)
+        st = ahead.stats()
+        assert st["steps_total"] - before["steps_total"] == 3
+        assert st["steps_ahead_total"] - before["steps_ahead_total"] == 2
+        assert ahead._pending is None and ahead.kv.active_blocks() == 0
+        steps = [s for s in tracing.recorded(t0)
+                 if s.name == "llm.step" and s.trace_id == ahead.trace_id]
+        assert [(s.attrs["batch"], s.attrs["ahead"], s.attrs["tokens"])
+                for s in steps] == [(1, False, 0), (1, True, 4),
+                                    (1, True, 4), (0, False, 2)]
+        phases = [[k.name.rsplit(".", 1)[1] for k in tracing.recorded(t0)
+                   if k.parent_id == s.span_id] for s in steps]
+        assert phases[0] == ["retire", "admit", "operands", "dispatch",
+                             "observe"]
+        assert phases[-1] == ["retire", "admit", "operands", "device_wait",
+                              "deliver", "observe"]
+
+    def test_a_dispatch_error_reaches_every_request_and_drops_the_pending(
+            self, ahead, oracle):
+        """The decode call fails with one chunk unfetched: the request that
+        holds a slot, the one just retired by count (it holds none: only
+        the pending record knows it) and the one still waiting all get the
+        error; no pending record is left, and the engine serves again."""
+        boom = RuntimeError("device fell over")
+        state = {"calls": 0}
+        orig = ahead._run_decode
+
+        def hooked(*args):
+            state["calls"] += 1
+            if state["calls"] == 2:
+                state["retiring"] = [r.retiring for r in reqs]
+                raise boom
+            return orig(*args)
+
+        reqs = [ahead.submit(PROMPTS[0], max_new_tokens=4),    # one chunk
+                ahead.submit(PROMPTS[1], max_new_tokens=16),
+                ahead.submit(PROMPTS[2], max_new_tokens=8)]    # waits
+        ahead._run_decode = hooked
+        try:
+            ahead._step()
+            with pytest.raises(RuntimeError, match="fell over"):
+                ahead._step()
+        finally:
+            ahead._run_decode = orig
+        assert state["retiring"] == ["stop", None, None]
+        assert ahead._pending is None
+        for r in reqs:
+            assert r.done and r.error is boom and not r.blocks
+            with pytest.raises(RuntimeError, match="fell over"):
+                list(ahead.drive(r))
+        assert ahead.stats()["slots_busy"] == 0
+        assert ahead.kv.active_blocks() == 0
+        assert ahead.generate(PROMPTS[1], max_new_tokens=8) \
+            == oracle(PROMPTS[1], 8)
+        assert ahead._pending is None and ahead.kv.active_blocks() == 0
+
+    def test_a_parked_slot_writes_to_the_trash_block(self, ahead, oracle):
+        """A request retired by count takes its blocks with it; the slot's
+        table row must be cleared all the same, or the parked slot's writes
+        of later chunks land in blocks that a next turn shares."""
+        p = list(range(60, 78))     # 18 + 8 tokens: three full blocks
+        beside = ahead.submit(PROMPTS[0], max_new_tokens=4)     # slot 0
+        first = ahead.submit(p, max_new_tokens=8)               # slot 1
+        def parked_rows_are_trash():
+            for slot, req in enumerate(ahead._slot_req):
+                assert req is not None or not ahead._slot_table[slot].any()
+
+        outs = _step_until_done(ahead, [first, beside],
+                                parked_rows_are_trash)
+        assert outs[0] == oracle(p, 8)
+        # The next turn goes into slot 0; slot 1 stays parked beside it.
+        turn2 = p + outs[0] + [9, 8, 7]
+        out2, hit = _hit_delta(ahead, turn2, 6)
+        assert hit >= 3 * BT and out2 == oracle(turn2, 6)
+        assert ahead.kv.active_blocks() == 0
+
+
 class _StubReplica:
     def __init__(self, key):
         class _Id:

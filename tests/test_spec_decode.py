@@ -72,6 +72,14 @@ class TestGreedyTokenIdentity:
             assert eng.generate(p, max_new_tokens=20) == plain.generate(
                 p, max_new_tokens=20)
         assert eng.kv.active_blocks() == 0
+        # A slot's advance is the step's acceptance, a value: with a draft
+        # model every chunk is fetched in the step that dispatched it (also
+        # the plain chunks a demoted draft falls back to); without one the
+        # same loop runs a chunk ahead.
+        st = eng.stats()
+        assert st["steps_total"] > 0 and st["steps_ahead_total"] == 0
+        assert eng._pending is None
+        assert plain.stats()["steps_ahead_total"] > 0
 
     def test_acceptance_rates_span_regimes(self, models):
         """The three drafts genuinely exercise different acceptance

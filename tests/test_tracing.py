@@ -408,12 +408,27 @@ class TestEngineStepTrace:
         assert sum(s.attrs["tokens"] for s in steps) == 32
         assert not any(s.name == "llm.decode_chunk"
                        for s in tracing.recorded(t0))
-        for step in decoded:
+        seven = ["retire", "admit", "operands", "dispatch", "device_wait",
+                 "deliver", "observe"]
+        fetched = 0
+        for step in steps:
             kids = [s for s in spans if s.parent_id == step.span_id]
-            assert [k.name for k in kids] == [
-                f"llm.step.{p}" for p in (
-                    "retire", "admit", "operands", "dispatch",
-                    "device_wait", "deliver", "observe")]
+            names = [k.name[len("llm.step."):] for k in kids]
+            # The seven phases keep their names and their order. A step
+            # that dispatched with a chunk pending has all of them; the
+            # first chunk of a busy stretch has nothing to fetch yet, and
+            # the draining step after the last one has nothing to dispatch.
+            assert names == [p for p in seven if p in names]
+            assert names[:3] == seven[:3] and names[-1] == "observe"
+            assert ("dispatch" in names) == bool(step.attrs["batch"])
+            assert ("device_wait" in names) == ("deliver" in names)
+            assert step.attrs["ahead"] == (
+                "dispatch" in names and "device_wait" in names)
+            if "deliver" not in names:
+                assert step.attrs["tokens"] == 0
+            fetched += "device_wait" in names
+            if step.attrs["ahead"]:
+                assert names == seven
             assert kids[0].start_ns == step.start_ns
             assert kids[-1].end_ns == step.end_ns
             total = sum(k.end_ns - k.start_ns for k in kids)
@@ -422,6 +437,13 @@ class TestEngineStepTrace:
             assert step.attrs["admit_stopped"] in (
                 "queue_empty", "no_slot", "budget", "no_blocks")
             assert step.attrs["driver"] == threading.current_thread().name
+        # Every chunk dispatched is fetched, one step later. Two requests
+        # overlap here, so the look-ahead engaged: in every dispatching
+        # step but the first of each busy stretch.
+        assert fetched == len(decoded)
+        ahead = stats["steps_ahead_total"] - before["steps_ahead_total"]
+        assert ahead == sum(s.attrs["ahead"] for s in steps)
+        assert 0 < ahead <= len(decoded) - 1
         # Steps do not overlap; what lies between two is the driver's own.
         for a, b in zip(steps, steps[1:]):
             assert a.end_ns <= b.start_ns
@@ -504,6 +526,8 @@ class TestEngineStepTrace:
         assert tracing.recorded(t0) == []
         st = eng.stats()
         assert st["steps_total"] - before["steps_total"] >= 4
+        assert 0 < st["steps_ahead_total"] - before["steps_ahead_total"] \
+            <= st["steps_total"] - before["steps_total"] - 1
         assert st["admit_stopped_budget_total"] == \
             before["admit_stopped_budget_total"]
         assert st["step_device_wait_s"] > before["step_device_wait_s"]
