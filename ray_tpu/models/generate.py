@@ -299,18 +299,30 @@ class PagedFamily(NamedTuple):
       each ``[layers or sublayers, num_blocks, block_tokens, ...]``: blocks
       are dimension 1 of every one, block 0 the trash block, so block copy,
       extract and insert are the generator's, whatever a row holds;
-    - ``prefill(params, tokens [1, P], pool, table, start_pos, suffix_len,
-      config, block_tokens, kernel)`` -> ``(logits, pool, aux)``;
-    - ``decode(params, tokens [S, T], pool, tables, lengths, config,
-      block_tokens, kernel, active)`` -> ``(logits, pool, aux)``;
-      ``aux`` is None or a small integer array of per-call counts that the
-      programs hand back beside the tokens (summed over a decode chunk);
+    - ``init_slot_state(config, slots)`` -> a tuple of arrays, each
+      ``[layers, slots, ...]``: what a SLOT carries between tokens beside
+      its rows in the pool (a linear-attention layer's recurrent state).
+      None: the family keeps none, its state is the empty tuple and its
+      programs take no operand for it;
+    - ``prefill(params, tokens [1, P], pool, state, table, start_pos,
+      suffix_len, slot, config, block_tokens, kernel)`` -> ``(logits, pool,
+      state, aux)``: writes slot ``slot``'s state from zero. ``logits`` is
+      ``[1, P, V]``, or ``[1, 1, V]`` where the family hands the head the
+      last real position alone;
+    - ``decode(params, tokens [S, T], pool, state, tables, lengths, config,
+      block_tokens, kernel, active)`` -> ``(logits, pool, state, aux)``:
+      advances the state of active slots and leaves a parked slot's bit for
+      bit; ``aux`` is None or a small integer array of per-call counts that
+      the programs hand back beside the tokens (summed over a decode chunk);
     - ``aux_counts``: one :class:`AuxCount` for each entry of ``aux``, in its
       order: the engine folds them into ``stats()`` under these names and
       knows nothing else about them;
     - ``logits_dim(params, config)``: rows of the ``last`` carry;
-    - ``unsupported``: engine features the family cannot run yet, refused
-      when an engine is built: ``draft_model``, ``kv_tier``.
+    - ``unsupported``: engine features the family cannot run yet:
+      ``draft_model`` and ``kv_tier`` are refused when an engine is built,
+      ``prefix_cache`` makes the engine neither look up nor register a
+      chain (a family with a slot state: a K/V hit at position p is usable
+      only with the state at p).
 
     A config object names its family through a ``paged_family()`` method;
     one without it is GPT-2 (``transformer.TransformerConfig``)."""
@@ -320,22 +332,23 @@ class PagedFamily(NamedTuple):
     logits_dim: Callable
     unsupported: Tuple[str, ...] = ()
     aux_counts: Tuple[AuxCount, ...] = ()
+    init_slot_state: Optional[Callable] = None
 
 
-def _gpt2_prefill(params, tokens, pool, table, start_pos, suffix_len, config,
-                  block_tokens, kernel="gather"):
+def _gpt2_prefill(params, tokens, pool, state, table, start_pos, suffix_len,
+                  slot, config, block_tokens, kernel="gather"):
     logits, k_pool, v_pool = _forward_prefill_paged(
         params, tokens, *pool, table, start_pos, suffix_len, config,
         block_tokens, kernel=kernel)
-    return logits, (k_pool, v_pool), None
+    return logits, (k_pool, v_pool), state, None
 
 
-def _gpt2_decode(params, tokens, pool, tables, lengths, config, block_tokens,
-                 kernel="gather", active=None):
+def _gpt2_decode(params, tokens, pool, state, tables, lengths, config,
+                 block_tokens, kernel="gather", active=None):
     logits, k_pool, v_pool = _forward_decode_paged(
         params, tokens, *pool, tables, lengths, config, block_tokens,
         kernel=kernel)
-    return logits, (k_pool, v_pool), None
+    return logits, (k_pool, v_pool), state, None
 
 
 GPT2_FAMILY = PagedFamily(
@@ -358,10 +371,13 @@ class PagedGenerator:
     layout that makes hash-based prefix reuse, copy-on-write forks and
     moving a chain's blocks between pools possible.
 
-    Device state is ``(pool, last, keys)`` threaded with buffer donation:
-    ``pool`` is the family's tuple of pool arrays (:class:`PagedFamily`; K
-    and V for GPT-2, one latent array for LongCat), a pytree that every
-    program takes and returns whole. Block tables and per-slot lengths are
+    Device state is ``(pool, slot_state, last, keys)`` threaded with buffer
+    donation: ``pool`` is the family's tuple of pool arrays
+    (:class:`PagedFamily`; K and V for GPT-2, one latent array for LongCat),
+    ``slot_state`` its tuple of per-slot arrays (empty for those two; a
+    recurrent state and a convolution tail a linear-attention layer for
+    Olmo-Hybrid), pytrees that the prefill and decode programs take and
+    return whole. Block tables and per-slot lengths are
     plain numpy operands owned by the host-side :class:`KVBlockManager` +
     engine.
     """
@@ -412,9 +428,11 @@ class PagedGenerator:
     def init_state(self):
         pool = tuple(self.family.init_pool(self.config, self.num_blocks,
                                            self.block_tokens))
+        make = self.family.init_slot_state
+        state = () if make is None else tuple(make(self.config, self.slots))
         last = jnp.zeros((self.slots, self.logits_dim), jnp.float32)
         keys = jnp.zeros((self.slots, 2), jnp.uint32)
-        return pool, last, keys
+        return pool, state, last, keys
 
     def init_draft_state(self):
         """Draft-model pool mirroring the target pool's block geometry: the
@@ -424,11 +442,12 @@ class PagedGenerator:
             self.draft_config, self.num_blocks, self.block_tokens))
 
     def prefill_fn(self, bucket: int):
-        """paged_prefill(params, pool, last, keys, table [NB], padded [1,P],
-        start_pos, suffix_len, slot, seed) -> (pool, last, keys, aux):
-        prefill the SUFFIX bucket at start_pos (the prefix-hit length) and
-        park last-token logits + PRNG key in the slot rows. ``aux`` is the
-        family's per-call counts (None for GPT-2)."""
+        """paged_prefill(params, pool, state, last, keys, table [NB], padded
+        [1,P], start_pos, suffix_len, slot, seed) -> (pool, state, last,
+        keys, aux): prefill the SUFFIX bucket at start_pos (the prefix-hit
+        length), write the slot's state from zero and park last-token
+        logits + PRNG key in the slot rows. ``aux`` is the family's per-call
+        counts (None for GPT-2)."""
         fn = self._prefill_fns.get(bucket)
         if fn is not None:
             return fn
@@ -437,28 +456,30 @@ class PagedGenerator:
         kernel = self.attention_kernel
         forward = self.family.prefill
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
-        def paged_prefill(params, pool, last, keys, table, padded,
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+        def paged_prefill(params, pool, state, last, keys, table, padded,
                           start_pos, suffix_len, slot, seed):
-            logits, pool, aux = forward(
-                params, padded, pool, table, start_pos, suffix_len, c, bt,
-                kernel=kernel)
-            row = jax.lax.dynamic_index_in_dim(
-                logits, suffix_len - 1, axis=1, keepdims=False)     # [1, V]
+            logits, pool, state, aux = forward(
+                params, padded, pool, state, table, start_pos, suffix_len,
+                slot, c, bt, kernel=kernel)
+            # A family that gave the head one row gave the last real one.
+            row = logits[:, 0] if logits.shape[1] == 1 else (
+                jax.lax.dynamic_index_in_dim(
+                    logits, suffix_len - 1, axis=1, keepdims=False))  # [1, V]
             last = lax.dynamic_update_slice(last, row, (slot, 0))
             keys = lax.dynamic_update_slice(
                 keys, jax.random.PRNGKey(seed)[None], (slot, 0))
-            return pool, last, keys, aux
+            return pool, state, last, keys, aux
 
         self._prefill_fns[bucket] = paged_prefill
         return paged_prefill
 
     def decode_fn(self, chunk: int):
-        """paged_decode(params, pool, last, keys, tables [S,NB], lengths [S],
-        active, greedy, temps) -> (toks [S, chunk], pool, last, keys, aux):
-        ``chunk`` scan steps advancing every active slot through its block
-        table in one dispatch; ``aux`` the family's counts summed over the
-        chunk (None for GPT-2)."""
+        """paged_decode(params, pool, state, last, keys, tables [S,NB],
+        lengths [S], active, greedy, temps) -> (toks [S, chunk], pool,
+        state, last, keys, aux): ``chunk`` scan steps advancing every active
+        slot through its block table in one dispatch; ``aux`` the family's
+        counts summed over the chunk (None for GPT-2)."""
         fn = self._decode_fns.get(chunk)
         if fn is not None:
             return fn
@@ -467,15 +488,15 @@ class PagedGenerator:
         kernel = self.attention_kernel
         forward = self.family.decode
 
-        @functools.partial(jax.jit, donate_argnums=(1, 2, 3))
-        def paged_decode(params, pool, last, keys, tables, lengths,
+        @functools.partial(jax.jit, donate_argnums=(1, 2, 3, 4))
+        def paged_decode(params, pool, state, last, keys, tables, lengths,
                          active, greedy, temps):
             adv = active.astype(jnp.int32)
             act_col = active[:, None]
             temp_safe = jnp.maximum(temps, 1e-6)[:, None]
 
             def step(carry, _):
-                pool, lens, last, keys = carry
+                pool, state, lens, last, keys = carry
                 # Scope names are what a profiler's op metadata carries:
                 # stable across refactors of the code inside them.
                 with jax.named_scope("sample"):
@@ -487,20 +508,20 @@ class PagedGenerator:
                     nxt = jnp.where(greedy, jnp.argmax(real, axis=-1),
                                     samp).astype(jnp.int32)
                 with jax.named_scope("decode_step"):
-                    logits, pool, aux = forward(
-                        params, nxt[:, None], pool, tables, lens, c, bt,
-                        kernel=kernel, active=active)
+                    logits, pool, state, aux = forward(
+                        params, nxt[:, None], pool, state, tables, lens, c,
+                        bt, kernel=kernel, active=active)
                 lens = lens + adv
                 last = jnp.where(act_col, logits[:, -1], last)
                 keys = jnp.where(act_col, keys2, keys)
-                return (pool, lens, last, keys), (nxt, aux)
+                return (pool, state, lens, last, keys), (nxt, aux)
 
-            (pool, _lens, last, keys), (toks, aux) = lax.scan(
-                step, (pool, jnp.asarray(lengths), last, keys),
+            (pool, state, _lens, last, keys), (toks, aux) = lax.scan(
+                step, (pool, state, jnp.asarray(lengths), last, keys),
                 None, length=chunk)
             if aux is not None:
                 aux = jnp.sum(aux, axis=0)
-            return toks.T, pool, last, keys, aux
+            return toks.T, pool, state, last, keys, aux
 
         self._decode_fns[chunk] = paged_decode
         return paged_decode
@@ -524,9 +545,10 @@ class PagedGenerator:
         @functools.partial(jax.jit, donate_argnums=(1,))
         def draft_prefill(draft_params, draft_pool, table, padded,
                           start_pos, suffix_len):
-            _, draft_pool, _aux = forward(
-                draft_params, padded, draft_pool, table, start_pos,
-                suffix_len, dc, bt, kernel=kernel)
+            # A family that keeps a slot state takes no draft model: ().
+            _, draft_pool, _state, _aux = forward(
+                draft_params, padded, draft_pool, (), table, start_pos,
+                suffix_len, 0, dc, bt, kernel=kernel)
             return draft_pool
 
         self._draft_prefill_fns[bucket] = draft_prefill
@@ -617,9 +639,9 @@ class PagedGenerator:
                 cur_pos = jnp.maximum(lens - 1, 0)
                 proposals, dlogits = [], []
                 for i in range(k + 1):
-                    dl, draft_pool, _aux = draft_forward(
-                        draft_params, cur_tok[:, None], draft_pool, tables,
-                        cur_pos, dc, bt, kernel=kernel)
+                    dl, draft_pool, _state, _aux = draft_forward(
+                        draft_params, cur_tok[:, None], draft_pool, (),
+                        tables, cur_pos, dc, bt, kernel=kernel)
                     if i == 0:
                         # Forward 0 only (re)writes tail's draft KV at
                         # lens-1; its logits are superseded by n0's chain.
@@ -636,8 +658,8 @@ class PagedGenerator:
 
                 # Single batched target verify over [n0, d_1..d_k].
                 verify = jnp.stack([n0] + proposals, axis=1)   # [S, k+1]
-                logits, pool, _aux = target_forward(
-                    params, verify, pool, tables, lens, c, bt,
+                logits, pool, _state, _aux = target_forward(
+                    params, verify, pool, (), tables, lens, c, bt,
                     kernel=kernel)
                 treal = logits[:, :, :V]                       # [S, k+1, V]
 

@@ -42,7 +42,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.generate import AuxCount, PagedFamily
 from ray_tpu.ops import moe
-from ray_tpu.ops.layers import rms_norm, rope
+from ray_tpu.ops.layers import gated_ffn as _ffn, mm as _mm, rms_norm, rope
 from ray_tpu.ops.paged_attention import (latent_paged_attention,
                                          latent_paged_attention_reference)
 
@@ -207,10 +207,6 @@ def init_latent_pool(config: LongCatConfig, num_blocks: int,
                        c.pool_width), c.dtype),)
 
 
-def _mm(eq: str, a, b, dtype):
-    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32).astype(dtype)
-
-
 def _attend(q_abs, pool, tables, lengths, sub, c: LongCatConfig, kernel: str):
     scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
     if kernel in ("pallas", "interpret"):
@@ -253,15 +249,6 @@ def _mla(ap, x, pool, sub: int, blk, off, tables, lengths, positions,
     o_lat = _attend(q_abs, pool, tables, lengths, sub, c, kernel)
     o = _mm("sthr,rhv->sthv", o_lat, ap["w_vb"], dt)
     return _mm("sthv,hvd->std", o, ap["w_o"], dt), pool
-
-
-def _ffn(fp, x, dt):
-    g = jnp.einsum("...d,df->...f", x, fp["w_gate"],
-                   preferred_element_type=jnp.float32)
-    u = jnp.einsum("...d,df->...f", x, fp["w_up"],
-                   preferred_element_type=jnp.float32)
-    return _mm("...f,fd->...d", (jax.nn.silu(g) * u).astype(dt),
-               fp["w_down"], dt)
 
 
 def _moe(lp, x, valid, c: LongCatConfig):
@@ -307,14 +294,15 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     return logits, pool, jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
 
 
-def forward_prefill_paged(params, tokens, pool, table, start_pos, suffix_len,
-                          config: LongCatConfig, block_tokens: int,
-                          kernel: str = "gather"):
+def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
+                          suffix_len, slot, config: LongCatConfig,
+                          block_tokens: int, kernel: str = "gather"):
     """The family's ``prefill``: ``tokens`` [1, P] (a suffix bucket) at
     positions [start_pos, start_pos + P) through ``table`` [NB]; positions
     below ``start_pos`` are a prefix hit, read back from the pool. Pad writes
     go to trash block 0, pad tokens route to no expert. Same contract as
-    ``generate._forward_prefill_paged``, plus the pick counts."""
+    ``generate._forward_prefill_paged``, plus the pick counts; the family
+    keeps no slot state (``state`` is the empty tuple, handed back)."""
     (pool,) = pool
     P = tokens.shape[1]
     NB, bt = table.shape[0], block_tokens
@@ -325,10 +313,10 @@ def forward_prefill_paged(params, tokens, pool, table, start_pos, suffix_len,
     logits, pool, counts = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
         blk[None], (positions % bt)[None], valid[None], config, kernel)
-    return logits, (pool,), counts
+    return logits, (pool,), state, counts
 
 
-def forward_decode_paged(params, tokens, pool, tables, lengths,
+def forward_decode_paged(params, tokens, pool, state, tables, lengths,
                          config: LongCatConfig, block_tokens: int,
                          kernel: str = "gather",
                          active: Optional[jax.Array] = None):
@@ -350,7 +338,7 @@ def forward_decode_paged(params, tokens, pool, tables, lengths,
     logits, pool, counts = _forward(
         params, tokens, pool, tables, lengths, positions, blk, pos_c % bt,
         valid, config, kernel)
-    return logits, (pool,), counts
+    return logits, (pool,), state, counts
 
 
 # ``stats()`` names of ``_forward``'s counts. A prefill's picks are kept apart

@@ -39,6 +39,22 @@ def linear(x, w, b=None):
     return y.astype(x.dtype)
 
 
+def mm(eq: str, a, b, dtype):
+    """``einsum`` with float32 accumulation, cast to ``dtype``."""
+    return jnp.einsum(eq, a, b, preferred_element_type=jnp.float32).astype(dtype)
+
+
+def gated_ffn(fp, x, dtype):
+    """``W_down (silu(W_gate x) * W_up x)``; ``fp`` holds ``w_gate``,
+    ``w_up`` [D, F] and ``w_down`` [F, D]."""
+    g = jnp.einsum("...d,df->...f", x, fp["w_gate"],
+                   preferred_element_type=jnp.float32)
+    u = jnp.einsum("...d,df->...f", x, fp["w_up"],
+                   preferred_element_type=jnp.float32)
+    return mm("...f,fd->...d", (jax.nn.silu(g) * u).astype(dtype),
+              fp["w_down"], dtype)
+
+
 def softmax_cross_entropy(logits, labels, ignore_index: int = -100):
     """Token-level CE with f32 logits; ignores masked positions.
 

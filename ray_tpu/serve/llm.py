@@ -465,7 +465,18 @@ class LLMEngine:
                                   draft_params=draft_params,
                                   draft_config=draft_config)
         self.kv = KVBlockManager(num_blocks, self.block_tokens)
-        self._pool, self._last, self._keys = self._pg.init_state()
+        (self._pool, self._slot_state, self._last,
+         self._keys) = self._pg.init_state()
+        # A family that keeps a state a slot (``PagedFamily.init_slot_state``)
+        # is served no prefix hit: rows at position p are usable only with
+        # the state at p, which nothing keeps. What the state weighs and how
+        # often it moved is counted always and reported (stats()) for such a
+        # family alone.
+        self._prefix_cache = "prefix_cache" not in self._pg.family.unsupported
+        self._state_bytes = sum(a.nbytes for a in self._slot_state)
+        self._state_counts = {"state_slot_steps_total": 0,
+                              "state_resets_total": 0,
+                              "prefix_lookups_refused_total": 0}
         # The family's per-call counts (None for GPT-2): the decode call's
         # and this step's prefills', fetched with the step's tokens.
         self._decode_aux = None
@@ -593,7 +604,11 @@ class LLMEngine:
     def _reset_device_state(self) -> None:
         """A fresh empty engine on the device: after warm-up, and after a
         failed dispatch took the in-flight requests' cache state with it."""
-        self._pool, self._last, self._keys = self._pg.init_state()
+        # Let go of the old arrays first: a deployment fills the chip, and
+        # the old and the new pool and slot state do not fit side by side.
+        self._pool = self._slot_state = self._last = self._keys = None
+        (self._pool, self._slot_state, self._last,
+         self._keys) = self._pg.init_state()
         self._decode_aux, self._prefill_aux = None, []
         self._pending = None
         # Pool contents are gone — the prefix cache resets with it. Queued
@@ -620,14 +635,16 @@ class LLMEngine:
             for b in self.buckets:
                 with wt.program("paged_prefill", b):
                     pf = self._pg.prefill_fn(b)
-                    self._pool, self._last, self._keys, _aux = pf(
-                        self.params, self._pool, self._last,
-                        self._keys, zero_row, np.zeros((1, b), np.int32),
-                        0, b, 0, 0)
+                    (self._pool, self._slot_state, self._last, self._keys,
+                     _aux) = pf(
+                        self.params, self._pool, self._slot_state,
+                        self._last, self._keys, zero_row,
+                        np.zeros((1, b), np.int32), 0, b, 0, 0)
             with wt.program("paged_decode"):
                 df = self._pg.decode_fn(self.chunk)
-                toks, self._pool, self._last, self._keys, _aux = df(
-                    self.params, self._pool, self._last,
+                (toks, self._pool, self._slot_state, self._last, self._keys,
+                 _aux) = df(
+                    self.params, self._pool, self._slot_state, self._last,
                     self._keys, np.zeros((self.slots, self.blocks_per_seq),
                                          np.int32),
                     np.zeros(self.slots, np.int32),
@@ -878,7 +895,7 @@ class LLMEngine:
         tokens are on the host: publish its chain into the prefix cache.
         Under _state_lock, just before its blocks are released."""
         ids = req.blocks
-        if not ids:
+        if not ids or not self._prefix_cache:
             return
         # Register the finished prompt+output chain (including a partial
         # tail entry) — the conversation's next turn extends exactly this
@@ -1033,6 +1050,9 @@ class LLMEngine:
             c["admit_stopped_budget_total"] += a["admit_stopped"] == "budget"
             c["step_device_wait_s"] += wait
             c["step_host_s"] += st.end_ns - start - wait
+            # Active slots x token steps the decode program advanced.
+            self._state_counts["state_slot_steps_total"] += (
+                a.get("state_slots", 0) * self.chunk)
         if not st.on:
             return
         ctx = (self.trace_id, None, True)
@@ -1098,7 +1118,8 @@ class LLMEngine:
                 nxt = self._waiting[0]
                 # What this admission charges against the budget: the bucket
                 # of the suffix the prefix cache does not already hold.
-                hit = self.kv.peek_hit_len([int(t) for t in nxt.prompt])
+                hit = (self.kv.peek_hit_len([int(t) for t in nxt.prompt])
+                       if self._prefix_cache else 0)
                 cost = self._suffix_bucket(max(1, nxt.real_len - hit))
                 if admitted_tokens and (
                         admitted_tokens + cost > self.prefill_budget):
@@ -1144,7 +1165,8 @@ class LLMEngine:
                     start=t_admit, end=nxt.prefill_end_ns,
                     attrs={"slot": free, "bucket": nxt.bucket,
                            "prompt_len": nxt.real_len,
-                           "hit_tokens": nxt.hit_tokens})
+                           "hit_tokens": nxt.hit_tokens,
+                           "state_reset": bool(self._slot_state)})
             admitted_tokens += cost
             admitted += 1
         st.attrs.update(admitted=admitted, admit_stopped=stopped)
@@ -1185,6 +1207,8 @@ class LLMEngine:
         if rows:
             st.enter("dispatch")
             st.attrs.update(batch=len(rows), ahead=self._pending is not None)
+            if self._slot_state:
+                st.attrs["state_slots"] = len(rows)
             # No local names the tokens: the record alone holds them, and
             # lets go of them at the fetch.
             chunk = _Chunk(
@@ -1297,7 +1321,12 @@ class LLMEngine:
         t_alloc = tracing.now_ns()
         evicted0 = self.kv.evicted_blocks
         tokens = [int(t) for t in req.prompt]
-        full, tail, hit_len = self.kv.lookup(tokens)
+        if self._prefix_cache:
+            full, tail, hit_len = self.kv.lookup(tokens)
+        else:
+            # Neither looked up nor, below, registered: a hit could not be
+            # honoured without the slot state at the hit's position.
+            full, tail, hit_len = [], None, 0
         digests: List[bytes] = []
         fetched = None          # (payload, from_block, to_block)
         if self._tier is not None:
@@ -1373,11 +1402,18 @@ class LLMEngine:
         padded = np.zeros((1, req.bucket), np.int32)
         padded[0, :suffix_len] = req.prompt[hit_len:]
         pf = self._pg.prefill_fn(req.bucket)
-        self._pool, self._last, self._keys, aux = pf(
-            self.params, self._pool, self._last, self._keys,
-            row, padded, hit_len, suffix_len, slot, req.seed)
+        self._pool, self._slot_state, self._last, self._keys, aux = pf(
+            self.params, self._pool, self._slot_state, self._last,
+            self._keys, row, padded, hit_len, suffix_len, slot, req.seed)
         if aux is not None:
             self._prefill_aux.append(aux)
+        # Counted per admission, together: a stats() from another thread
+        # never reads one without the other.
+        with self._agg_lock:
+            if self._slot_state:    # the prefill wrote the slot's from zero
+                self._state_counts["state_resets_total"] += 1
+            if not self._prefix_cache:
+                self._state_counts["prefix_lookups_refused_total"] += 1
         if self._spec:
             # Warm the draft pool over the same suffix/table so the draft
             # chain starts from draft-KV covering every committed position.
@@ -1400,7 +1436,7 @@ class LLMEngine:
                 return
             self._slot_table[slot, :] = row
             self._slot_blocks[slot] = ids
-            if n_full_prompt:
+            if n_full_prompt and self._prefix_cache:
                 self.kv.register_chain(tokens, ids, n_full_prompt)
             self._hit_pending += hit_len
             if self._tier is not None:
@@ -1497,9 +1533,9 @@ class LLMEngine:
             self._spec_last_accept[:] = 0
             self._spec_last_on[:] = False
         df = self._pg.decode_fn(self.chunk)
-        (toks, self._pool, self._last, self._keys,
+        (toks, self._pool, self._slot_state, self._last, self._keys,
          self._decode_aux) = df(
-            self.params, self._pool, self._last,
+            self.params, self._pool, self._slot_state, self._last,
             self._keys, tables, lengths, active, greedy, temps)
         return toks
 
@@ -1896,6 +1932,15 @@ class LLMEngine:
             out["spec_accept_ratio"] = float(acc) / prop if prop else 0.0
         with self._agg_lock:
             out.update({k: float(v) for k, v in self._aux_totals.items()})
+            if self._slot_state or not self._prefix_cache:
+                # What the slot state weighs (all slots; a live
+                # slot's share is slots_busy / slots_total of it), the slot
+                # steps the decode programs advanced it by, the admissions
+                # that wrote one from zero and those that made no prefix
+                # lookup because the family keeps one.
+                out["state_bytes"] = float(self._state_bytes)
+                out.update({k: float(v)
+                            for k, v in self._state_counts.items()})
         return out
 
     def describe(self) -> Dict:
@@ -1918,6 +1963,7 @@ class LLMEngine:
             "pool_blocks": self.kv.num_blocks,
             "model_family": type(self.config).__name__,
             "kv_pool_shapes": [list(a.shape) for a in self._pool],
+            "slot_state_shapes": [list(a.shape) for a in self._slot_state],
             "kv_pool_devices": sorted(
                 {str(d) for a in self._pool for d in a.devices()}),
         }
@@ -1953,11 +1999,13 @@ def llm_deployment(
     :class:`LLMEngine`.
 
     ``config`` is a model family's config object: a
-    ``models.transformer.TransformerConfig`` (GPT-2) or a
-    ``models.longcat.LongCatConfig``; the engine finds the family's pool and
-    forward pass through it (``models.generate.PagedFamily``), and what a
-    family cannot run yet (a draft model, the KV tier) raises when the
-    replica builds its engine.
+    ``models.transformer.TransformerConfig`` (GPT-2), a
+    ``models.longcat.LongCatConfig`` or a
+    ``models.olmo_hybrid.OlmoHybridConfig``; the engine finds the family's
+    pool, its per-slot state and its forward pass through it
+    (``models.generate.PagedFamily``), and what a family cannot run yet (a
+    draft model, the KV tier) raises when the replica builds its engine; a
+    family that keeps a state a slot is served no prefix hit.
 
     ``params_fn`` runs inside the replica (checkpoint load / init) so weights
     never ship through the controller. Request payload::

@@ -2,15 +2,16 @@
 
 The benchmark's own tests live in ``benchmark/tests/`` (``python -m pytest
 benchmark/tests -q``), which the tier-1 command does not run. This thin file
-runs, from there, the checks of the shipped manifest, the newest
-configuration's counts against hand-worked numbers and the ``--rehearse``
-run of its cell, so that a PR that breaks what a cell reads from the program
+runs, from there, the checks of the shipped manifest, the two newest
+configurations' counts against hand-worked numbers and the ``--rehearse``
+runs of their cells, so that a PR that breaks what a cell reads from the program
 (a program's name, a counter, the family seam) fails tier-1."""
 
 import pytest
 
 pytest.register_assert_rewrite("benchmark.tests.test_manifest",
-                               "benchmark.tests.test_longcat_cell")
+                               "benchmark.tests.test_longcat_cell",
+                               "benchmark.tests.test_olmo_hybrid_cell")
 
 from benchmark.tests.test_longcat_cell import (  # noqa: E402,F401
     config,
@@ -20,6 +21,19 @@ from benchmark.tests.test_longcat_cell import (  # noqa: E402,F401
     test_rehearsal_of_the_cell,
     test_the_file_states_the_cut_and_every_published_width,
     test_the_rehearsal_overlay_is_the_tiny_models_sizes,
+)
+from benchmark.tests.test_olmo_hybrid_cell import (  # noqa: E402,F401
+    olmo_config,
+    test_a_state_zeroed_every_16th_step_is_not_correct,
+    test_no_new_reader_names_an_architecture,
+    test_olmo_counter_readers_by_hand,
+    test_olmo_counts_by_hand,
+    test_olmo_readers_find_nothing_on_a_program_without_the_counters,
+    test_rehearsal_of_the_olmo_cell,
+    test_the_bfloat16_launcher_rounds_the_state_it_says,
+    test_the_olmo_file_states_the_cut_and_every_published_width,
+    test_the_olmo_rehearsal_overlay_is_the_tiny_models_sizes,
+    test_the_program_holds_what_the_counts_say,
 )
 from benchmark.tests.test_manifest import (  # noqa: E402,F401
     test_check_names_a_configuration_that_is_not_whole,

@@ -78,7 +78,7 @@ def test_paged_prefill_and_decode_match_the_reference(model, kernel):
     V = cfg.vocab_size
     gen = PagedGenerator(params, cfg, slots=2, num_blocks=8, block_tokens=BT,
                          max_len=64, attention_kernel=kernel)
-    pool, last, keys = gen.init_state()
+    pool, _state, last, keys = gen.init_state()
     rng = np.random.default_rng(0)
     a = [int(t) for t in rng.integers(1, V, 37)]
     b = a + [int(t) for t in rng.integers(1, V, 6)]
@@ -86,9 +86,10 @@ def test_paged_prefill_and_decode_match_the_reference(model, kernel):
     def prefill(pool, last, keys, table, suffix, start, slot, bucket):
         padded = np.zeros((1, bucket), np.int32)
         padded[0, :len(suffix)] = suffix
-        return gen.prefill_fn(bucket)(
-            params, pool, last, keys, np.asarray(table, np.int32), padded,
+        pool, _state, last, keys, aux = gen.prefill_fn(bucket)(
+            params, pool, (), last, keys, np.asarray(table, np.int32), padded,
             start, len(suffix), slot, 0)
+        return pool, last, keys, aux
 
     pool, last, keys, aux = prefill(pool, last, keys, [1, 2, 3, 0], a, 0, 0, 64)
     np.testing.assert_allclose(np.asarray(last[0]), ref_logits(model, a)[36],
@@ -106,8 +107,8 @@ def test_paged_prefill_and_decode_match_the_reference(model, kernel):
     np.testing.assert_array_equal(np.asarray(pool[0][:, 3]), block3)
 
     tables = np.asarray([[1, 2, 3, 0], [1, 2, 5, 0]], np.int32)
-    toks, pool, last, keys, aux = gen.decode_fn(4)(
-        params, pool, last, keys, tables, np.asarray([37, 43], np.int32),
+    toks, pool, _state, last, keys, aux = gen.decode_fn(4)(
+        params, pool, (), last, keys, tables, np.asarray([37, 43], np.int32),
         np.ones(2, bool), np.ones(2, bool), np.zeros(2, np.float32))
     toks = np.asarray(toks)
     assert int(aux[0]) == 2 * 4 * cfg.moe_topk * cfg.num_layers
@@ -127,9 +128,9 @@ def test_an_idle_slot_routes_to_no_expert(model):
     cfg, params = model
     gen = PagedGenerator(params, cfg, slots=2, num_blocks=8, block_tokens=BT,
                          max_len=64, attention_kernel="gather")
-    pool, last, keys = gen.init_state()
-    _t, _p, _l, _k, aux = gen.decode_fn(2)(
-        params, pool, last, keys, np.zeros((2, 4), np.int32),
+    pool, _state, last, keys = gen.init_state()
+    _t, _p, _s, _l, _k, aux = gen.decode_fn(2)(
+        params, pool, (), last, keys, np.zeros((2, 4), np.int32),
         np.zeros(2, np.int32), np.asarray([True, False]), np.ones(2, bool),
         np.zeros(2, np.float32))
     assert int(aux[0]) == 1 * 2 * cfg.moe_topk * cfg.num_layers
