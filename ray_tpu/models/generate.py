@@ -176,17 +176,19 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
     gathers them back through the table without recomputing. Only the first
     ``suffix_len`` positions are real — pad writes redirect to trash block
     0 and pad queries are causally ahead of every real row, so their
-    garbage never reaches a real position's softmax."""
+    garbage never reaches a real position's softmax.
+
+    ``params`` is the working tree of :func:`gpt2_working_params`: every
+    matrix an array of its own in the compute type, read where it lies."""
     c = config
-    cast = lambda p: p.astype(c.dtype)
     B, P = tokens.shape  # B == 1
     NB = table.shape[0]
     bt = block_tokens
-    h = jnp.take(cast(params["tok_embed"]), tokens, axis=0)
+    h = jnp.take(params["tok_embed"], tokens, axis=0)[..., :c.d_model]
     positions = start_pos + jnp.arange(P)
     if c.pos == "learned":
-        h = h + cast(params["pos_embed"])[jnp.minimum(
-            positions, c.max_seq_len - 1)][None]
+        h = h + params["pos_embed"][jnp.minimum(
+            positions, c.max_seq_len - 1)][None, :, :c.d_model]
     scale = 1.0 / c.head_dim**0.5
     lengths1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
     write_ok = jnp.arange(P) < suffix_len
@@ -194,8 +196,7 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
                     table[jnp.clip(positions // bt, 0, NB - 1)], 0)
     off = positions % bt
 
-    for layer in range(c.n_layers):
-        bp = jax.tree.map(lambda p: cast(p[layer]), params["blocks"])
+    for layer, bp in enumerate(params["layers"]):
         x = layer_norm(h, bp["ln1_g"], bp["ln1_b"])
         q = jnp.einsum("btd,dhk->bthk", x, bp["wq"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bq"]
         k = jnp.einsum("btd,dhk->bthk", x, bp["wk"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bk"]
@@ -214,9 +215,8 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
         u = gelu(linear(x, bp["w_up"], bp["b_up"]))
         h = h + linear(u, bp["w_down"], bp["b_down"])
 
-    h = layer_norm(h, cast(params["lnf_g"]), cast(params["lnf_b"]))
-    w_out = params["tok_embed"].T if c.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", h, cast(w_out), preferred_element_type=jnp.float32)
+    h = layer_norm(h, params["lnf_g"], params["lnf_b"])
+    logits = jnp.einsum("btd,dv->btv", h, params["head"], preferred_element_type=jnp.float32)
     return logits, k_pool, v_pool
 
 
@@ -234,27 +234,27 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
     block 0 rather than clamping onto the last cell — a slot at capacity
     must be finished as ``length_cap`` by the engine BEFORE dispatch, so
     in-range rows never see a silently overwritten chain; the redirect only
-    shields parked/speculative overhang writes."""
+    shields parked/speculative overhang writes.
+
+    ``params`` is the working tree of :func:`gpt2_working_params`."""
     c = config
-    cast = lambda p: p.astype(c.dtype)
     S, T = tokens.shape
     NB = tables.shape[1]
     bt = block_tokens
     max_len = NB * bt
-    h = jnp.take(cast(params["tok_embed"]), tokens, axis=0)
+    h = jnp.take(params["tok_embed"], tokens, axis=0)[..., :c.d_model]
     positions = lengths[:, None] + jnp.arange(T)[None, :]  # [S, T]
     write_ok = positions < max_len
     pos_c = jnp.minimum(positions, max_len - 1)
     if c.pos == "learned":
-        h = h + cast(params["pos_embed"])[jnp.minimum(
-            positions, c.max_seq_len - 1)]
+        h = h + params["pos_embed"][jnp.minimum(
+            positions, c.max_seq_len - 1)][..., :c.d_model]
     scale = 1.0 / c.head_dim**0.5
     rows = jnp.arange(S)[:, None]
     blk = jnp.where(write_ok, tables[rows, pos_c // bt], 0)
     off = pos_c % bt
 
-    for layer in range(c.n_layers):
-        bp = jax.tree.map(lambda p: cast(p[layer]), params["blocks"])
+    for layer, bp in enumerate(params["layers"]):
         x = layer_norm(h, bp["ln1_g"], bp["ln1_b"])
         q = jnp.einsum("btd,dhk->bthk", x, bp["wq"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bq"]
         k = jnp.einsum("btd,dhk->bthk", x, bp["wk"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bk"]
@@ -273,9 +273,8 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
         u = gelu(linear(x, bp["w_up"], bp["b_up"]))
         h = h + linear(u, bp["w_down"], bp["b_down"])
 
-    h = layer_norm(h, cast(params["lnf_g"]), cast(params["lnf_b"]))
-    w_out = params["tok_embed"].T if c.tie_embeddings else params["lm_head"]
-    logits = jnp.einsum("btd,dv->btv", h, cast(w_out), preferred_element_type=jnp.float32)
+    h = layer_norm(h, params["lnf_g"], params["lnf_b"])
+    logits = jnp.einsum("btd,dv->btv", h, params["head"], preferred_element_type=jnp.float32)
     return logits, k_pool, v_pool
 
 
@@ -318,6 +317,17 @@ class PagedFamily(NamedTuple):
       order: the engine folds them into ``stats()`` under these names and
       knows nothing else about them;
     - ``logits_dim(params, config)``: rows of the ``last`` carry;
+    - ``working_params(params, config)`` -> the tree the family's programs
+      are CALLED with, made once from the stored tree when a generator is
+      built (jitted; the stored tree is not donated: it stays whoever's it
+      was). None: the programs read the stored tree as it is (LongCat and
+      Olmo-Hybrid store bfloat16, some of Olmo-Hybrid's leaves float32 on
+      purpose, one array a matrix). GPT-2 shares ``transformer.init_params``
+      with training, which keeps float32 masters in stacked ``[layers, ...]``
+      slabs: read as they are stored, every serve program began by
+      converting all of them, cutting each layer's matrices out of the
+      converted slab and transposing the tied embedding for the head, on
+      every call (:func:`gpt2_working_params`);
     - ``unsupported``: engine features the family cannot run yet:
       ``draft_model`` and ``kv_tier`` are refused when an engine is built,
       ``prefix_cache`` makes the engine neither look up nor register a
@@ -333,6 +343,35 @@ class PagedFamily(NamedTuple):
     unsupported: Tuple[str, ...] = ()
     aux_counts: Tuple[AuxCount, ...] = ()
     init_slot_state: Optional[Callable] = None
+    working_params: Optional[Callable] = None
+
+
+def gpt2_working_params(params, config: TransformerConfig) -> Dict:
+    """GPT-2's weights in the form its serve programs' products read, so
+    that no program converts, slices out or transposes one again: every leaf
+    in ``config.dtype`` (the value ``astype`` gives is the same whenever it
+    is taken), ``blocks`` cut into ``layers``, a tuple of one dict a layer
+    with one array a matrix (XLA copies each matrix out of a stacked slab
+    before it multiplies by it), and ``head`` ``[d_model, vocab]``, the
+    matrix the logits' product contracts with: the tied embedding's
+    transposed copy, or ``lm_head``. The two embedding tables' rows are
+    padded to whole 128-lane tiles (the forwards cut a gathered row back to
+    ``d_model``; nothing at GPT-2's aligned widths)."""
+    cast = lambda p: p.astype(config.dtype)
+    blocks = jax.tree.map(cast, params["blocks"])
+    out = {name: cast(leaf) for name, leaf in params.items()
+           if name not in ("blocks", "lm_head")}
+    out["layers"] = tuple(jax.tree.map(lambda p: p[layer], blocks)
+                          for layer in range(config.n_layers))
+    out["head"] = (out["tok_embed"].T if config.tie_embeddings
+                   else cast(params["lm_head"]))
+    # A table is read by rows: rows of whole 128-lane tiles keep it
+    # row-major on the device (gpt2-xl's 1,600 lanes do not, and each call
+    # then copied the table row-major before its gather).
+    lanes = -config.d_model % 128
+    for name in {"tok_embed", "pos_embed"} & set(out):
+        out[name] = jnp.pad(out[name], ((0, 0), (0, lanes)))
+    return out
 
 
 def _gpt2_prefill(params, tokens, pool, state, table, start_pos, suffix_len,
@@ -355,12 +394,27 @@ GPT2_FAMILY = PagedFamily(
     init_pool=init_block_pool, prefill=_gpt2_prefill, decode=_gpt2_decode,
     logits_dim=lambda params, config: (
         params["tok_embed"].shape[0] if config.tie_embeddings
-        else params["lm_head"].shape[-1]))
+        else params["lm_head"].shape[-1]),
+    working_params=gpt2_working_params)
 
 
 def paged_family(config) -> PagedFamily:
     named = getattr(config, "paged_family", None)
     return named() if named is not None else GPT2_FAMILY
+
+
+@functools.partial(jax.jit, static_argnums=(0, 2))
+def _make_working(make, params, config):
+    return make(params, config)
+
+
+def working_params(params, config):
+    """The tree ``config``'s family's serve programs are called with
+    (:attr:`PagedFamily.working_params`): made from the stored tree in one
+    jitted call, or the stored tree itself for a family that gives no
+    function."""
+    make = paged_family(config).working_params
+    return params if make is None else _make_working(make, params, config)
 
 
 class PagedGenerator:
@@ -380,6 +434,13 @@ class PagedGenerator:
     return whole. Block tables and per-slot lengths are
     plain numpy operands owned by the host-side :class:`KVBlockManager` +
     engine.
+
+    ``params`` (and ``draft_params``) is the WORKING tree, what every
+    program here is called with: :func:`working_params` of the stored tree
+    the generator was built from, made once in the constructor. The
+    generator keeps no reference to the stored tree: a float32 tree that
+    training or a reference shares stays its owner's, and a deployment whose
+    factory lets go of it holds the working copy alone.
     """
 
     def __init__(self, params, config, *, slots: int,
@@ -388,7 +449,6 @@ class PagedGenerator:
                  attention_kernel: str = "auto",
                  draft_params=None,
                  draft_config=None):
-        self.params = params
         self.config = config
         self.family = paged_family(config)
         self.slots = slots
@@ -414,9 +474,11 @@ class PagedGenerator:
             raise ValueError(
                 f"draft vocab {draft_config.vocab_size} != target vocab "
                 f"{config.vocab_size} — speculative verify needs one vocab")
-        self.draft_params = draft_params
         self.draft_config = draft_config
         self.logits_dim = self.family.logits_dim(params, config)
+        self.set_params(params)
+        self.draft_params = (None if draft_params is None
+                             else working_params(draft_params, draft_config))
         self._prefill_fns = {}   # suffix bucket -> jitted paged prefill
         self._decode_fns = {}    # chunk -> jitted paged decode
         self._extract_fns = {}   # nb -> jitted block gather (KV tier out)
@@ -424,6 +486,21 @@ class PagedGenerator:
         self._copy_fn = None
         self._draft_prefill_fns = {}  # suffix bucket -> jitted draft prefill
         self._spec_decode_fns = {}    # (chunk, k) -> jitted spec decode
+
+    def set_params(self, params) -> None:
+        """Make the working tree anew from a stored tree of the same shapes
+        (a weight update; the compiled programs stay). The caller resets
+        whatever device state the old weights wrote."""
+        self.params = None      # let go first: two copies may not fit
+        self.params = working_params(params, self.config)
+
+    @property
+    def params_working_bytes(self) -> int:
+        """Bytes of the working copy made for the target model's programs;
+        0 where they read the stored tree."""
+        if self.family.working_params is None:
+            return 0
+        return sum(leaf.nbytes for leaf in jax.tree.leaves(self.params))
 
     def init_state(self):
         pool = tuple(self.family.init_pool(self.config, self.num_blocks,

@@ -87,9 +87,11 @@ class GenerationActor:
 
         self.model_config = model_config
         self._seed = seed
-        params = transformer.init_params(model_config, jax.random.key(seed))
+        # The stored tree is the actor's: the engine reads a working copy.
+        self._params = transformer.init_params(model_config,
+                                               jax.random.key(seed))
         self._engine = LLMEngine(
-            params, model_config, slots=slots,
+            self._params, model_config, slots=slots,
             max_len=model_config.max_seq_len, chunk=4, name="llm-rl-gen")
         self._max_len = int(model_config.max_seq_len)
         # Behavior log-probs under the params that SAMPLED the tokens (the
@@ -107,12 +109,10 @@ class GenerationActor:
         return jnp.concatenate([jnp.zeros_like(logp[:, :1]), logp], axis=1)
 
     def set_weights(self, params) -> bool:
-        self._engine.params = jax.tree.map(jnp.asarray, params)
-        # Cached KV blocks hold K/V computed under the OLD params — a prefix
-        # hit after this sync would splice stale activations into the new
-        # policy's rollouts. No requests are in flight between rollout()
-        # calls, so the reset is safe.
-        self._engine._reset_device_state()
+        # No requests are in flight between rollout() calls, so the
+        # engine's reset (its cached K/V is the OLD params') is safe.
+        self._params = jax.tree.map(jnp.asarray, params)
+        self._engine.set_params(self._params)
         return True
 
     def ping(self) -> bool:
@@ -139,7 +139,7 @@ class GenerationActor:
             prompt_len[b] = p
             gen_len[b] = n - p
             logp = np.asarray(self._logp_fn(
-                self._engine.params, tokens[b][None]))[0]
+                self._params, tokens[b][None]))[0]
             behavior_logp[b] = logp * gen_mask[b]
         return {
             "tokens": tokens,

@@ -424,7 +424,6 @@ class LLMEngine:
             raise ValueError(
                 f"{type(config).__name__}: the cluster KV tier "
                 f"(kv_tier_enabled) is not supported for this family yet")
-        self.params = params
         self.config = config
         self.max_len = max_len or config.max_seq_len
         self.buckets = sorted(prompt_buckets or _default_buckets(self.max_len))
@@ -445,7 +444,6 @@ class LLMEngine:
             raise ValueError(
                 "serve_spec_tokens > 0 needs a draft model "
                 "(draft_params/draft_config)")
-        self._draft_params = draft_params
         self._spec = self.spec_k > 0
         self._spec_floor = float(knobs.serve_spec_accept_floor)
         self._spec_alpha = float(knobs.serve_spec_accept_alpha)
@@ -601,6 +599,16 @@ class LLMEngine:
         self._spec_proposed_total = 0
         self._spec_accepted_total = 0
 
+    def set_params(self, params) -> None:
+        """Serve new weights of the same shapes (a policy update between
+        rollouts; no request may be in flight). The generator makes its
+        working tree anew, and the pool goes: cached blocks hold K/V the old
+        weights computed, and a prefix hit would splice them into the new
+        weights' streams."""
+        with self._step_lock:
+            self._pg.set_params(params)
+            self._reset_device_state()
+
     def _reset_device_state(self) -> None:
         """A fresh empty engine on the device: after warm-up, and after a
         failed dispatch took the in-flight requests' cache state with it."""
@@ -637,14 +645,14 @@ class LLMEngine:
                     pf = self._pg.prefill_fn(b)
                     (self._pool, self._slot_state, self._last, self._keys,
                      _aux) = pf(
-                        self.params, self._pool, self._slot_state,
+                        self._pg.params, self._pool, self._slot_state,
                         self._last, self._keys, zero_row,
                         np.zeros((1, b), np.int32), 0, b, 0, 0)
             with wt.program("paged_decode"):
                 df = self._pg.decode_fn(self.chunk)
                 (toks, self._pool, self._slot_state, self._last, self._keys,
                  _aux) = df(
-                    self.params, self._pool, self._slot_state, self._last,
+                    self._pg.params, self._pool, self._slot_state, self._last,
                     self._keys, np.zeros((self.slots, self.blocks_per_seq),
                                          np.int32),
                     np.zeros(self.slots, np.int32),
@@ -668,13 +676,14 @@ class LLMEngine:
                     with wt.program("draft_prefill", b):
                         dpf = self._pg.draft_prefill_fn(b)
                         self._draft_pool = dpf(
-                            self._draft_params, self._draft_pool,
+                            self._pg.draft_params, self._draft_pool,
                             zero_row, np.zeros((1, b), np.int32), 0, b)
                 self._draft_pool = cf(self._draft_pool, 0, 0)
                 with wt.program("spec_decode"):
                     sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
-                    out = sf(self.params, self._draft_params, self._pool,
-                             self._draft_pool, self._last, self._keys,
+                    out = sf(self._pg.params, self._pg.draft_params,
+                             self._pool, self._draft_pool, self._last,
+                             self._keys,
                              np.zeros((self.slots, self.blocks_per_seq),
                                       np.int32),
                              np.zeros(self.slots, np.int32),
@@ -1403,7 +1412,7 @@ class LLMEngine:
         padded[0, :suffix_len] = req.prompt[hit_len:]
         pf = self._pg.prefill_fn(req.bucket)
         self._pool, self._slot_state, self._last, self._keys, aux = pf(
-            self.params, self._pool, self._slot_state, self._last,
+            self._pg.params, self._pool, self._slot_state, self._last,
             self._keys, row, padded, hit_len, suffix_len, slot, req.seed)
         if aux is not None:
             self._prefill_aux.append(aux)
@@ -1419,7 +1428,7 @@ class LLMEngine:
             # chain starts from draft-KV covering every committed position.
             dpf = self._pg.draft_prefill_fn(req.bucket)
             self._draft_pool = dpf(
-                self._draft_params, self._draft_pool, row,
+                self._pg.draft_params, self._draft_pool, row,
                 padded, hit_len, suffix_len)
         # Commit ATOMICALLY with the cancel path: this runs outside
         # _state_lock, so a concurrent _cancel may have freed the slot
@@ -1535,7 +1544,7 @@ class LLMEngine:
         df = self._pg.decode_fn(self.chunk)
         (toks, self._pool, self._slot_state, self._last, self._keys,
          self._decode_aux) = df(
-            self.params, self._pool, self._slot_state, self._last,
+            self._pg.params, self._pool, self._slot_state, self._last,
             self._keys, tables, lengths, active, greedy, temps)
         return toks
 
@@ -1547,7 +1556,7 @@ class LLMEngine:
         (toks, counts, accepted, self._pool, self._draft_pool,
          self._last, self._keys, tail_j, pending_j,
          up_j) = sf(
-            self.params, self._draft_params, self._pool,
+            self._pg.params, self._pg.draft_params, self._pool,
             self._draft_pool, self._last, self._keys, tables,
             lengths, active, greedy, temps, spec_on, tail, pending,
             use_pending)
@@ -1956,8 +1965,9 @@ class LLMEngine:
             "warmed_buckets": list(self.buckets) if self._steady else [],
             "trace_id": self.trace_id,
             "params_devices": sorted(
-                {str(d) for leaf in jax.tree.leaves(self.params)
+                {str(d) for leaf in jax.tree.leaves(self._pg.params)
                  for d in leaf.devices()}),
+            "params_working_bytes": self._pg.params_working_bytes,
             "attention_kernel": self._pg.attention_kernel,  # as resolved
             "block_tokens": self.block_tokens,
             "pool_blocks": self.kv.num_blocks,

@@ -278,6 +278,7 @@ def _program_operands(cfg, params, slots=2):
     gen = PagedGenerator(params, cfg, slots=slots, num_blocks=9,
                          block_tokens=BT, max_len=64, attention_kernel="gather")
     pool, state, last, keys = gen.init_state()
+    params = gen.params      # what the programs are called with
     n_dev = len(jax.tree.leaves((params, pool, state, last, keys)))
     nb = gen.blocks_per_seq
     decode = gen.decode_fn(4).lower(
@@ -288,12 +289,14 @@ def _program_operands(cfg, params, slots=2):
         params, pool, state, last, keys, np.zeros(nb, np.int32),
         np.zeros((1, 16), np.int32), 0, 16, 0, 0)
     count = lambda low: len(jax.tree.leaves(low.args_info))  # noqa: E731
-    return state, n_dev, count(decode), count(pre)
+    return (state, n_dev, count(decode), count(pre),
+            len(jax.tree.leaves(params)))
 
 
 @pytest.mark.parametrize("family", ["gpt2", "longcat", "olmo_hybrid"])
 def test_an_empty_slot_state_adds_no_operand(family, model):
-    """GPT-2's and LongCat's programs take the operands they took: weights,
+    """GPT-2's and LongCat's programs take the operands they took: weights
+    (GPT-2's as its working tree holds them: one array a matrix a layer),
     the pool's arrays, ``last`` and ``keys``, then 5 (decode) or 6 (prefill)
     host operands; the slot state of a family that keeps one adds exactly
     its two arrays."""
@@ -310,9 +313,11 @@ def test_an_empty_slot_state_adds_no_operand(family, model):
     else:
         cfg, params = model
         n_pool, n_state = 2, 2
-    state, n_dev, n_decode, n_prefill = _program_operands(cfg, params)
+    state, n_dev, n_decode, n_prefill, n_params = _program_operands(
+        cfg, params)
     assert len(state) == n_state
-    n_params = len(jax.tree.leaves(params))
+    if family != "gpt2":
+        assert n_params == len(jax.tree.leaves(params))
     assert n_dev == n_params + n_pool + n_state + 2
     assert n_decode == n_dev + 5
     assert n_prefill == n_dev + 6

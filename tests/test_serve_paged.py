@@ -10,10 +10,13 @@ Router-level: stale-load eviction on snapshot shrink and prefix-affinity
 picks, as units on ``Router`` itself.
 """
 
+import dataclasses
 import threading
 import time
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 
 import ray_tpu
@@ -213,7 +216,7 @@ class TestCOWForkIsolation:
         assert (s["kv_blocks_active"] + s["kv_blocks_cached"]
                 + s["kv_blocks_free"]) == s["kv_blocks_total"]
 
-    def test_describe_names_what_was_resolved(self, paged):
+    def test_describe_names_what_was_resolved(self, paged, tiny_model):
         """The non-numeric twin of ``stats``: the kernel as resolved (never
         "auto"), the buckets warmup compiled, and where the state lives."""
         d = paged.describe()
@@ -222,11 +225,149 @@ class TestCOWForkIsolation:
         assert d["warmed_buckets"] == [16, 32]
         assert d["pool_blocks"] == 129 and d["block_tokens"] == BT
         assert d["params_devices"] and d["kv_pool_devices"]
-        cold = LLMEngine(paged.params, paged.config,
+        cold = LLMEngine(tiny_model[1], paged.config,
                          prompt_buckets=(16,), slots=1, max_queue=0,
                          name="paged-cold", block_tokens=BT,
                          pool_blocks=9)
         assert cold.describe()["warmed_buckets"] == []
+
+
+def _per_call_cast(params, config):
+    """The oracle: GPT-2's stored tree brought into the form the forwards
+    read INSIDE the program, on every call, as the forwards themselves did
+    before the working copy: ``cast(p[layer])`` of each stacked leaf, the
+    head as the cast of the float32 table's transpose."""
+    cast = lambda p: p.astype(config.dtype)
+    out = {name: cast(params[name])
+           for name in ("tok_embed", "pos_embed", "lnf_g", "lnf_b")
+           if name in params}
+    out["layers"] = tuple(
+        jax.tree.map(lambda p: cast(p[layer]), params["blocks"])
+        for layer in range(config.n_layers))
+    out["head"] = cast(params["tok_embed"].T if config.tie_embeddings
+                       else params["lm_head"])
+    return out
+
+
+def _oracle_prefill(params, tokens, pool, state, table, start_pos,
+                    suffix_len, slot, config, block_tokens, **kw):
+    return generate.GPT2_FAMILY.prefill(
+        _per_call_cast(params, config), tokens, pool, state, table,
+        start_pos, suffix_len, slot, config, block_tokens, **kw)
+
+
+def _oracle_decode(params, tokens, pool, state, tables, lengths, config,
+                   block_tokens, **kw):
+    return generate.GPT2_FAMILY.decode(
+        _per_call_cast(params, config), tokens, pool, state, tables,
+        lengths, config, block_tokens, **kw)
+
+
+_ORACLE_FAMILY = generate.GPT2_FAMILY._replace(
+    working_params=None, prefill=_oracle_prefill, decode=_oracle_decode)
+
+
+@dataclasses.dataclass(frozen=True)
+class _PerCallCastConfig(transformer.TransformerConfig):
+    """The same model, its serve programs handed the stored tree."""
+
+    def paged_family(self):
+        return _ORACLE_FAMILY
+
+
+class TestWorkingParams:
+    """GPT-2's serve programs read a working copy made once when the
+    generator is built; the same programs fed a forward that casts per call
+    give the same bits."""
+
+    @staticmethod
+    def _serve(gen):
+        """Prefill two slots, then one decode chunk: slot 0 greedy, slot 1
+        sampled. Returns (tokens, last logits, K pool) as numpy."""
+        state = gen.init_state()
+        table = np.zeros((2, gen.blocks_per_seq), np.int32)
+        table[0, :2], table[1, :2] = (1, 2), (3, 4)
+        prompts = ([5, 9, 3, 77, 21, 8, 1, 30, 2], [4, 4, 19])
+        for slot, prompt in enumerate(prompts):
+            padded = np.zeros((1, 16), np.int32)
+            padded[0, :len(prompt)] = prompt
+            *state, _aux = gen.prefill_fn(16)(
+                gen.params, *state, table[slot], padded, 0, len(prompt),
+                slot, 11 + slot)
+        lengths = np.array([len(p) for p in prompts], np.int32)
+        toks, pool, _state, last, _keys, _aux = gen.decode_fn(4)(
+            gen.params, *state, table, lengths, np.ones(2, bool),
+            np.array([True, False]), np.array([0.0, 0.8], np.float32))
+        return np.asarray(toks), np.asarray(last), np.asarray(
+            pool[0].astype(jnp.float32))
+
+    @pytest.mark.parametrize("dtype,tied", [
+        (jnp.bfloat16, True), (jnp.bfloat16, False), (jnp.float32, True)])
+    def test_bitwise_equal_to_the_per_call_cast(self, dtype, tied):
+        kw = dict(max_seq_len=64, dtype=dtype, tie_embeddings=tied)
+        cfg = transformer.tiny(**kw)
+        params = transformer.init_params(cfg, jax.random.key(3))
+        assert params["tok_embed"].dtype == jnp.float32   # stored masters
+        geometry = dict(slots=2, num_blocks=9, block_tokens=BT)
+        gen = generate.PagedGenerator(params, cfg, **geometry)
+        oracle = generate.PagedGenerator(
+            params, _PerCallCastConfig(**dataclasses.asdict(cfg)),
+            **geometry)
+        assert oracle.params is params and oracle.params_working_bytes == 0
+        # one array a matrix a layer, every leaf in the compute type
+        assert len(gen.params["layers"]) == cfg.n_layers
+        assert gen.params["layers"][0]["w_up"].shape == (cfg.d_model, cfg.d_ff)
+        assert {leaf.dtype for leaf in jax.tree.leaves(gen.params)} == {
+            jnp.dtype(dtype)}
+        toks, last, k_pool = self._serve(gen)
+        o_toks, o_last, o_pool = self._serve(oracle)
+        np.testing.assert_array_equal(toks, o_toks)
+        assert last.dtype == np.float32 and np.abs(last).max() > 0
+        np.testing.assert_array_equal(last.view(np.uint32),
+                                      o_last.view(np.uint32))
+        np.testing.assert_array_equal(k_pool, o_pool)
+
+    @pytest.mark.parametrize("tied", [True, False])
+    def test_describe_counts_the_working_copy(self, tied):
+        """Two bytes a parameter, and the tied table a second time as the
+        head's matrix; at a width of whole lanes nothing is padded."""
+        cfg = transformer.tiny(max_seq_len=64, dtype=jnp.bfloat16,
+                               d_model=128, tie_embeddings=tied)
+        params = transformer.init_params(cfg, jax.random.key(0))
+        kw = dict(prompt_buckets=(16,), slots=1, max_queue=0,
+                  block_tokens=BT, pool_blocks=9)
+        eng = LLMEngine(params, cfg, name="working-gpt2", **kw)
+        n_params = sum(leaf.size for leaf in jax.tree.leaves(params))
+        held = n_params + (params["tok_embed"].size if tied else 0)
+        assert eng.describe()["params_working_bytes"] == 2 * held
+        assert not hasattr(eng, "params")      # the stored tree is not kept
+        assert eng.generate([5, 9, 3], max_new_tokens=4)
+
+    def test_a_family_without_the_function_reads_its_stored_tree(self):
+        kw = dict(prompt_buckets=(16,), slots=1, max_queue=0,
+                  block_tokens=BT, pool_blocks=9)
+        from ray_tpu.models import longcat
+
+        lcfg = longcat.tiny()
+        lparams = longcat.init_params(lcfg, jax.random.key(0))
+        leng = LLMEngine(lparams, lcfg, name="working-longcat", **kw)
+        assert leng.describe()["params_working_bytes"] == 0
+        assert leng._pg.params is lparams
+
+    def test_set_params_serves_the_new_weights(self, tiny_model):
+        cfg, params = tiny_model
+        other = transformer.init_params(cfg, jax.random.key(9))
+        kw = dict(prompt_buckets=(16,), slots=1, max_queue=0,
+                  block_tokens=BT, pool_blocks=9)
+        eng = LLMEngine(params, cfg, name="swap", **kw)
+        fresh = LLMEngine(other, cfg, name="swap-fresh", **kw)
+        # sampled: a tiny random model's greedy stream repeats its prompt
+        ask = dict(max_new_tokens=12, temperature=1.0, seed=3)
+        before = eng.generate([5, 9, 3, 77], **ask)
+        eng.set_params(other)
+        after = eng.generate([5, 9, 3, 77], **ask)
+        assert after == fresh.generate([5, 9, 3, 77], **ask) != before
+        assert eng.kv.stats()["kv_hit_tokens"] == 0  # the old K/V is gone
 
 
 class TestPagedMetrics:

@@ -254,7 +254,8 @@ class TestLengthCapRegression:
         lengths = jnp.asarray(np.array([cap], np.int32))
         toks = jnp.asarray(np.array([[4]], np.int32))
         logits, k2, v2 = generate._forward_decode_paged(
-            params, toks, k_pool, v_pool, tables, lengths, cfg, BT)
+            generate.working_params(params, cfg), toks, k_pool, v_pool,
+            tables, lengths, cfg, BT)
         assert np.isfinite(np.asarray(logits)).all()
         # Every live block — in particular the last cell of block 3 —
         # keeps its sentinel; only trash block 0 absorbed the write.

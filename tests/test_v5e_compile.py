@@ -15,6 +15,7 @@ libtpu at once collide on its lock file.
 """
 
 import json
+import math
 import os
 import re
 import subprocess
@@ -100,6 +101,79 @@ def pool_shaped_data_movers(hlo: str, n_layers: int, num_blocks: int,
     return found
 
 
+# What the TPU compiler's own prefetch of an operand into VMEM (memory space
+# 1) looks like: an asynchronous copy or slice, and the call that joins the
+# slices. It stands in for the read the product would make, once.
+_PREFETCH = ("copy-done", "slice-done", "async-done")
+# Op kinds that write what they read, in another type, order or cut.
+_MOVES = {"convert", "copy", "transpose", "slice", "dynamic-slice",
+          "reshape", "concatenate"}
+
+
+def weight_shapes(*trees) -> list:
+    """The shapes a weight matrix of these parameter trees can have in a
+    program: each leaf of a million elements or more as it is, one layer of
+    a stacked one, and each of those folded to two dimensions."""
+    shapes = set()
+    for tree in trees:
+        for leaf in tree:
+            shape = tuple(leaf)
+            if math.prod(shape) < 1 << 20:
+                continue
+            forms = {shape}
+            if len(shape) > 2:
+                forms |= {shape[1:], (1,) + shape[1:]}
+            for form in list(forms):
+                if len(form) > 2:
+                    forms |= {(form[0], math.prod(form[1:])),
+                              (math.prod(form[:-1]), form[-1])}
+            shapes |= {f for f in forms if math.prod(f) >= 1 << 20}
+    return sorted(shapes)
+
+
+def weight_shaped_data_movers(hlo: str, shapes) -> list:
+    """[[op kind, instruction, shape, computation], ...]: every instruction
+    of the optimized module, outside its fusions' bodies, that only MOVES
+    data and writes an array of a weight's shape: a ``convert``, a ``copy``,
+    a ``transpose``, a ``slice``, or a fusion of nothing but such
+    (``slice_bitcast_fusion``). A serve program that is handed its weights
+    in the form its products read has none: each is a weight's bytes read
+    and written again on every call (``ENTRY``) or every token step (a
+    loop's body). Not counted: the compiler's prefetch of an operand into
+    VMEM (``S(1)`` in the output's layout, by an asynchronous copy or slice
+    and the ``ConcatBitcast`` that joins the slices), which is the product's
+    own read made early."""
+    wanted = ["[" + ",".join(map(str, shape)) + "]" for shape in shapes]
+    bodies, current = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w\-.]+) \(.*\{\s*$", line)
+        if head:
+            current = bodies.setdefault(
+                "ENTRY" if head.group(1) else head.group(2), [])
+        elif current is not None and _INSTR.match(line):
+            current.append((line, *_INSTR.match(line).groups()))
+    fused = {called for body in bodies.values() for line, *_ in body
+             for called in re.findall(r"fusion\(.*calls=%([\w\-.]+)", line)}
+    found = []
+    for computation, body in bodies.items():
+        if computation in fused:
+            continue
+        for line, name, shape, op in body:
+            if not any(w in shape for w in wanted):
+                continue
+            if op == "fusion":
+                called = re.search(r"calls=%([\w\-.]+)", line).group(1)
+                moves = all(inner in _MOVES or inner in _NO_DATA
+                            for *_, inner in bodies[called])
+            else:
+                moves = op in _MOVES or op in _PREFETCH
+            prefetch = "S(1)" in shape and (
+                op in _PREFETCH or '"ConcatBitcast"' in line)
+            if moves and not prefetch:
+                found.append([op, name, shape[:80], computation])
+    return found
+
+
 def pallas_grids(jaxpr) -> list:
     """The grid of every ``pallas_call`` in a jaxpr, nested ones included."""
     found = []
@@ -123,6 +197,7 @@ def compile_all() -> dict:
     "temp_bytes": {serve program: temporaries the compiler reports},
     "need_bytes": {LongCat or Olmo serve program: arguments + temporaries},
     "state_movers": {Olmo serve program: the same scan for its slot state},
+    "weight_movers": {serve program: weight_shaped_data_movers() of it},
     "state_roundings": {Olmo serve program: [calls of the state kernel,
     ``reduce-precision`` instructions that feed them]}}."""
     import jax
@@ -155,9 +230,9 @@ def compile_all() -> dict:
     one = SingleDeviceSharding(devices[0])
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
     need_bytes, grids, scoped_vmem, state_movers = {}, {}, {}, {}
-    state_roundings = {}
+    state_roundings, weight_movers = {}, {}
 
-    def attempt(name, trace, pool=None, state=None):
+    def attempt(name, trace, pool=None, state=None, weights=None):
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
@@ -173,6 +248,8 @@ def compile_all() -> dict:
                 temp_bytes[name] = mem.temp_size_in_bytes
                 need_bytes[name] = (mem.argument_size_in_bytes
                                     + mem.temp_size_in_bytes)
+            if weights is not None:
+                weight_movers[name] = weight_shaped_data_movers(text, weights)
             if state is not None:
                 state_movers[name] = pool_shaped_data_movers(text, *state)
                 state_roundings[name] = [
@@ -186,6 +263,10 @@ def compile_all() -> dict:
 
     def arr(shape, dtype=jnp.bfloat16, sharding=one):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    def shapes_of(*trees):
+        return weight_shapes(*([leaf.shape for leaf in jax.tree.leaves(tree)]
+                               for tree in trees))
 
     qkv = arr((2, ctx, H, D))
     attempt("flash_fwd_bwd", lambda: jax.jit(jax.value_and_grad(
@@ -224,14 +305,19 @@ def compile_all() -> dict:
     for width, full in SERVE_WIDTHS.items():
         scfg = getattr(transformer, full)(max_seq_len=ctx).replace(
             n_layers=SERVE_LAYERS)
-        params = jax.tree.map(
-            lambda x: arr(x.shape, x.dtype),
+        # The generator makes its working tree when it is built, so it is
+        # built from a real (zero) stored tree; the programs are traced with
+        # the working tree's shapes, placed on the described chip.
+        stored = jax.tree.map(
+            lambda x: jnp.zeros(x.shape, x.dtype),
             jax.eval_shape(lambda key, c=scfg: transformer.init_params(c, key),
                            jax.random.key(0)))
         for num_blocks in SERVE_POOLS:
-            gen = PagedGenerator(params, scfg, slots=SERVE_SLOTS,
+            gen = PagedGenerator(stored, scfg, slots=SERVE_SLOTS,
                                  num_blocks=num_blocks, block_tokens=bt,
                                  attention_kernel="pallas")
+            params = jax.tree.map(lambda x: arr(x.shape, x.dtype), gen.params)
+            weights = shapes_of(stored, params)
             kv = arr((scfg.n_layers, num_blocks, bt,
                       scfg.n_heads * scfg.head_dim))
             state = (params, kv, kv, arr((SERVE_SLOTS, gen.logits_dim),
@@ -248,12 +334,12 @@ def compile_all() -> dict:
                                     jnp.int32),
                         per_slot(jnp.int32), per_slot(jnp.bool_),
                         per_slot(jnp.bool_), per_slot(jnp.float32)),
-                    pool=geometry)
+                    pool=geometry, weights=weights)
             attempt(f"serve_prefill_{width}_{num_blocks}",
                     lambda: gen.prefill_fn(256).trace(
                         *state, arr((gen.blocks_per_seq,), jnp.int32),
                         arr((1, 256), jnp.int32), i32, i32, i32, i32),
-                    pool=geometry)
+                    pool=geometry, weights=weights)
 
     # LongCat-Flash's serve programs whole, at the cell's own sizes: the
     # latent kernels under their names, no pool-shaped copy, and the bytes
@@ -279,12 +365,13 @@ def compile_all() -> dict:
             lambda: lgen.decode_fn(8).trace(
                 *lstate, arr((LONGCAT_SLOTS, lgen.blocks_per_seq), jnp.int32),
                 l_slot(jnp.int32), l_slot(jnp.bool_), l_slot(jnp.bool_),
-                l_slot(jnp.float32)), pool=l_geometry)
+                l_slot(jnp.float32)), pool=l_geometry,
+            weights=shapes_of(lparams))
     attempt("longcat_prefill_1024",
             lambda: lgen.prefill_fn(1024).trace(
                 *lstate, arr((lgen.blocks_per_seq,), jnp.int32),
                 arr((1, 1024), jnp.int32), i32, i32, i32, i32),
-            pool=l_geometry)
+            pool=l_geometry, weights=shapes_of(lparams))
 
     # Olmo-Hybrid's serve programs whole, at the cell's own sizes: the state
     # kernel under its name, the K/V pool AND the per-slot recurrent state
@@ -313,12 +400,13 @@ def compile_all() -> dict:
             lambda: ogen.decode_fn(8).trace(
                 *ostate, arr((OLMO_SLOTS, ogen.blocks_per_seq), jnp.int32),
                 o_slot(jnp.int32), o_slot(jnp.bool_), o_slot(jnp.bool_),
-                o_slot(jnp.float32)), pool=o_geometry, state=o_state)
+                o_slot(jnp.float32)), pool=o_geometry, state=o_state,
+            weights=shapes_of(oparams))
     attempt(f"olmo_prefill_{OLMO_BUCKET}",
             lambda: ogen.prefill_fn(OLMO_BUCKET).trace(
                 *ostate, arr((ogen.blocks_per_seq,), jnp.int32),
                 arr((1, OLMO_BUCKET), jnp.int32), i32, i32, i32, i32),
-            pool=o_geometry, state=o_state)
+            pool=o_geometry, state=o_state, weights=shapes_of(oparams))
 
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
@@ -343,7 +431,7 @@ def compile_all() -> dict:
     return {"programs": programs, "kernels": kernels, "grids": grids,
             "scoped_vmem": scoped_vmem, "pool_movers": pool_movers,
             "temp_bytes": temp_bytes, "need_bytes": need_bytes,
-            "state_movers": state_movers,
+            "state_movers": state_movers, "weight_movers": weight_movers,
             "state_roundings": state_roundings}
 
 
@@ -413,6 +501,44 @@ def test_serve_programs_move_no_pool_sized_data(verdict, program, width,
     assert kernel == ("paged_decode_attn" if program == "decode"
                       else "paged_prefill_attn")
     assert re.fullmatch(r"bf16\[\d+,\d+,\d+,64\]", shape), shape
+
+
+@pytest.mark.parametrize("num_blocks", SERVE_POOLS)
+@pytest.mark.parametrize("width", sorted(SERVE_WIDTHS))
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_gpt2_serve_programs_read_their_weights_where_they_lie(
+        verdict, program, width, num_blocks):
+    """``paged_decode`` and ``paged_prefill`` are called with the working
+    tree ``generate.gpt2_working_params`` made when the generator was built:
+    every leaf in the compute type, one array a matrix a layer, the head's
+    matrix as its product reads it, a table's rows in whole lanes. Handed
+    the float32 stacked tree, each call began by converting 355M parameters,
+    copying each layer's matrix out of the converted slab and transposing
+    the tied table: 13-16% of the GPT-2 serve cells' device time (PERF.md,
+    PR 32). Neither ``ENTRY`` nor a loop's body writes a weight-sized array
+    now."""
+    name = f"serve_{program}_{width}_{num_blocks}"
+    assert verdict["programs"][name] == "ok", verdict["programs"][name]
+    assert verdict["weight_movers"][name] == []
+
+
+@pytest.mark.parametrize("program,found", [
+    ("longcat_decode", [["copy", "bf16[512,64,128]", "ENTRY"]] * 16),
+    ("longcat_prefill_1024", []),
+    ("olmo_decode", []), ("olmo_prefill_2048", [])])
+def test_what_the_weight_scan_finds_in_the_other_families(verdict, program,
+                                                          found):
+    """The families that store bfloat16, one array a matrix, and give no
+    ``working_params``: what the same scan finds in their programs TODAY.
+    LongCat's decode re-lays the two absorbed projections of each of its
+    eight latent sublayers on every call (16 x 8.4 MB written: ~0.3 ms of a
+    230 ms call; a working tree of its own would hold them as the kernel's
+    products read them). Olmo-Hybrid's programs and LongCat's prefill move
+    none: their trace's ``slice-done bf16[960,11520]`` and ``copy-done
+    f32[3840]`` are the compiler's prefetches into VMEM."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    assert [[op, shape.split("{")[0], where] for op, _name, shape, where
+            in verdict["weight_movers"][program]] == found
 
 
 @pytest.mark.parametrize("num_blocks", SERVE_POOLS)
@@ -561,6 +687,67 @@ ENTRY %main (s: f32[12,48,96,5760]) -> f32[12,48,96,5760] {
 """
     assert [(op, name) for op, name, _s in pool_shaped_data_movers(
         state, 12, 48, 96)] == [("copy", "copy.7")]
+
+
+
+def test_weight_mover_scan_sees_what_the_per_call_cast_did():
+    """The scan itself, on the instructions the parent's decode program held
+    (compile-only, PR 32) and on what the compiler's prefetches look like."""
+    hlo = """
+%fused_computation.7 (p0: bf16[2,1024,4096]) -> (bf16[1024,4096], bf16[1024,4096]) {
+  %p0 = bf16[2,1024,4096]{2,1,0} parameter(0)
+  %slice.1 = bf16[1,1024,4096]{2,1,0} slice(%p0), slice={[0:1]}
+  %bitcast.1 = bf16[1024,4096]{1,0} bitcast(%slice.1)
+  %slice.2 = bf16[1,1024,4096]{2,1,0} slice(%p0), slice={[1:2]}
+  %bitcast.2 = bf16[1024,4096]{1,0} bitcast(%slice.2)
+  ROOT %tuple.9 = (bf16[1024,4096]{1,0}, bf16[1024,4096]{1,0}) tuple(%bitcast.1, %bitcast.2)
+}
+%fused_computation.8 (p0: bf16[36,1024], p1: bf16[1024,4096]) -> bf16[1024,4096] {
+  %p0 = bf16[36,1024]{1,0} parameter(0)
+  %p1 = bf16[1024,4096]{1,0} parameter(1)
+  %convert.9 = f32[1024,4096]{1,0} convert(%p1)
+  ROOT %multiply.1 = bf16[1024,4096]{1,0} multiply(%p1, %p1)
+}
+%body.3 (c: (s32[], bf16[1024,4096])) -> (s32[], bf16[1024,4096]) {
+  %c = (s32[], bf16[1024,4096]{1,0}) parameter(0)
+  %w = bf16[1024,4096]{1,0} get-tuple-element(%c), index=1
+  %copy.5 = bf16[1024,4096]{0,1} copy(%w)
+  ROOT %t = (s32[], bf16[1024,4096]{1,0}) tuple(%i, %w)
+}
+ENTRY %main (w: f32[2,1024,4096], e: f32[50304,1024], h: bf16[4096,1024]) -> bf16[36,4096] {
+  %w = f32[2,1024,4096]{2,1,0} parameter(0)
+  %e = f32[50304,1024]{1,0} parameter(1)
+  %h = bf16[4096,1024]{1,0} parameter(2)
+  %convert.37 = bf16[2,1024,4096]{2,1,0:T(8,128)(2,1)S(1)} convert(%w)
+  %slice_bitcast_fusion.5 = (bf16[1024,4096]{1,0}, bf16[1024,4096]{1,0}) fusion(%convert.37), kind=kLoop, calls=%fused_computation.7
+  %convert_element_type.175 = bf16[50304,1024]{1,0} convert(%e)
+  %copy.29 = bf16[50304,1024]{0,1:T(8,128)(2,1)S(1)} copy(%convert_element_type.175)
+  %fusion.77 = bf16[1024,4096]{1,0} fusion(%x, %slice_bitcast_fusion.5), kind=kLoop, calls=%fused_computation.8
+  %slice-start.1 = ((bf16[4096,1024]{1,0}), bf16[1024,1024]{1,0:S(1)}, s32[]) slice-start(%h), slice={[0:1024], [0:1024]}
+  %slice-done.1 = bf16[1024,1024]{1,0:T(8,128)(2,1)S(1)} slice-done(%slice-start.1)
+  %custom-call.14 = bf16[4096,1024]{1,0:T(8,128)(2,1)S(1)} custom-call(%slice-done.1), custom_call_target="ConcatBitcast"
+  %copy-start.2 = (bf16[4096,1024]{1,0:S(1)}, bf16[4096,1024]{1,0}, u32[]) copy-start(%h)
+  %copy-done.2 = bf16[4096,1024]{1,0:T(8,128)(2,1)S(1)} copy-done(%copy-start.2)
+  %copy-start.3 = (bf16[4096,1024]{1,0}, bf16[4096,1024]{1,0:S(1)}, u32[]) copy-start(%copy-done.2)
+  %copy-done.3 = bf16[4096,1024]{1,0:T(8,128)(2,1)} copy-done(%copy-start.3)
+  ROOT %while.1 = (s32[], bf16[1024,4096]{1,0}) while(%t0), condition=%cond.3, body=%body.3
+}
+"""
+    shapes = weight_shapes([(2, 1024, 4096), (50304, 1024), (1024, 1024)],
+                           [(1024, 4096), (4096, 1024)])
+    assert (1024, 4096) in shapes and (1, 1024, 4096) in shapes
+    assert (36, 1024) not in shapes                  # no activation's
+    found = weight_shaped_data_movers(hlo, shapes)
+    assert sorted((op, name, where) for op, name, _s, where in found) == [
+        ("convert", "convert.37", "ENTRY"),
+        ("convert", "convert_element_type.175", "ENTRY"),
+        ("copy", "copy.29", "ENTRY"),
+        ("copy", "copy.5", "body.3"),                # once a token step
+        ("copy-done", "copy-done.3", "ENTRY"),       # back out to HBM
+        ("fusion", "slice_bitcast_fusion.5", "ENTRY")]
+    # not: a fusion that computes (fusion.77), anything inside a fusion's
+    # body (convert.9, slice.1), the prefetches into VMEM (slice-done.1,
+    # custom-call.14, copy-done.2) nor what starts one.
 
 
 if __name__ == "__main__":
