@@ -328,6 +328,9 @@ class PagedFamily(NamedTuple):
       converting all of them, cutting each layer's matrices out of the
       converted slab and transposing the tied embedding for the head, on
       every call (:func:`gpt2_working_params`);
+    - ``describe(config)`` -> a dict of names and counts that the engine's
+      ``describe()`` carries beside its own (how many of a stack's layers
+      are of which kind): for an operator to print, read by no code path;
     - ``unsupported``: engine features the family cannot run yet:
       ``draft_model`` and ``kv_tier`` are refused when an engine is built,
       ``prefix_cache`` makes the engine neither look up nor register a
@@ -344,6 +347,7 @@ class PagedFamily(NamedTuple):
     aux_counts: Tuple[AuxCount, ...] = ()
     init_slot_state: Optional[Callable] = None
     working_params: Optional[Callable] = None
+    describe: Optional[Callable] = None
 
 
 def gpt2_working_params(params, config: TransformerConfig) -> Dict:

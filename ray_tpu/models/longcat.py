@@ -42,9 +42,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.generate import AuxCount, PagedFamily
 from ray_tpu.ops import moe
-from ray_tpu.ops.layers import gated_ffn as _ffn, mm as _mm, rms_norm, rope
-from ray_tpu.ops.paged_attention import (latent_paged_attention,
-                                         latent_paged_attention_reference)
+from ray_tpu.ops.layers import gated_ffn as _ffn, rms_norm
+from ray_tpu.ops.mla import LatentSpec, latent_attention
 
 
 @dataclass(frozen=True)
@@ -88,6 +87,21 @@ class LongCatConfig:
     @property
     def attn_sublayers(self) -> int:
         return 2 * self.num_layers
+
+    def latent_spec(self) -> LatentSpec:
+        """This family's latent attention (``ops/mla.py``): both latents
+        scaled up (``mla_scale_q_lora``, ``mla_scale_kv_lora``), plain
+        rotary, scores over the root of a query head's width."""
+        D = self.hidden_size
+        return LatentSpec(
+            nope=self.qk_nope_head_dim, rope=self.qk_rope_head_dim,
+            rank=self.kv_lora_rank, pool_width=self.pool_width,
+            eps=self.rms_norm_eps, dtype=self.dtype,
+            softmax_scale=(self.qk_nope_head_dim
+                           + self.qk_rope_head_dim) ** -0.5,
+            rope_theta=self.rope_theta,
+            q_scale=(D / self.q_lora_rank) ** 0.5,
+            kv_scale=(D / self.kv_lora_rank) ** 0.5)
 
     def replace(self, **kw) -> "LongCatConfig":
         return replace(self, **kw)
@@ -207,48 +221,11 @@ def init_latent_pool(config: LongCatConfig, num_blocks: int,
                        c.pool_width), c.dtype),)
 
 
-def _attend(q_abs, pool, tables, lengths, sub, c: LongCatConfig, kernel: str):
-    scale = (c.qk_nope_head_dim + c.qk_rope_head_dim) ** -0.5
-    if kernel in ("pallas", "interpret"):
-        return latent_paged_attention(
-            q_abs, pool, tables, lengths, sub, value_lanes=c.kv_lora_rank,
-            scale=scale, interpret=kernel == "interpret")
-    return latent_paged_attention_reference(
-        q_abs, pool, tables, lengths, sub, value_lanes=c.kv_lora_rank,
-        scale=scale)
-
-
 def _mla(ap, x, pool, sub: int, blk, off, tables, lengths, positions,
          c: LongCatConfig, kernel: str):
-    """One latent-attention sublayer over the paged rows, ``W_kvb`` absorbed.
-
-    ``x`` [S, T, D] (normed); the T new rows are written to pool blocks
-    ``blk`` [S, T] at offsets ``off`` first, then attended with the rest
-    through ``tables``. Returns (out [S, T, D], pool)."""
-    dt = c.dtype
-    D, R = c.hidden_size, c.kv_lora_rank
-    S, T, _ = x.shape
-    cq = rms_norm(_mm("std,dr->str", x, ap["w_qa"], dt), ap["q_norm"],
-                  c.rms_norm_eps)
-    q = (_mm("str,rhk->sthk", cq, ap["w_qb"], jnp.float32)
-         * (D / c.q_lora_rank) ** 0.5).astype(dt)            # mla_scale_q_lora
-    q_nope, q_rope = q[..., :c.qk_nope_head_dim], q[..., c.qk_nope_head_dim:]
-    kva = _mm("std,dw->stw", x, ap["w_kva"], dt)
-    c_kv = (rms_norm(kva[..., :R], ap["kv_norm"], c.rms_norm_eps)
-            .astype(jnp.float32) * (D / R) ** 0.5).astype(dt)  # mla_scale_kv_lora
-    k_rope = rope(kva[..., None, R:], positions, base=c.rope_theta)[:, :, 0]
-    q_rope = rope(q_rope, positions, base=c.rope_theta)
-    pad = c.pool_width - c.latent_width
-    row = jnp.concatenate(
-        [c_kv, k_rope, jnp.zeros((S, T, pad), dt)], axis=-1)
-    with jax.named_scope("kv_pool_write"):
-        pool = pool.at[sub, blk, off].set(row)
-    q_abs = jnp.concatenate(
-        [_mm("sthn,rhn->sthr", q_nope, ap["w_kb"], dt), q_rope,
-         jnp.zeros((S, T, c.num_attention_heads, pad), dt)], axis=-1)
-    o_lat = _attend(q_abs, pool, tables, lengths, sub, c, kernel)
-    o = _mm("sthr,rhv->sthv", o_lat, ap["w_vb"], dt)
-    return _mm("sthv,hvd->std", o, ap["w_o"], dt), pool
+    """The shared latent sublayer (``ops/mla.py``) with this family's spec."""
+    return latent_attention(ap, x, pool, sub, blk, off, tables, lengths,
+                            positions, c.latent_spec(), kernel)
 
 
 def _moe(lp, x, valid, c: LongCatConfig):

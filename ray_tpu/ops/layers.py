@@ -8,6 +8,8 @@ bf16 params hit the MXU with f32 accumulation.
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
@@ -70,12 +72,47 @@ def softmax_cross_entropy(logits, labels, ignore_index: int = -100):
     return nll.sum() / n, n
 
 
-def rope(x, positions, *, base: float = 10000.0):
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rope_frequencies(base: float, dim: int, scaling=None):
+    """The ``dim // 2`` rotary frequencies of a head of ``dim`` numbers,
+    float32: ``base ** (-i / (dim // 2))``, or YaRN's blend of them where
+    ``scaling`` is a mapping with ``factor``, ``original_max_position_
+    embeddings``, ``beta_fast`` and ``beta_slow`` (a model's ``rope_scaling``
+    of type yarn; Peng et al., arXiv:2309.00071). A pair that turns more than
+    ``beta_fast`` times over the original context keeps its frequency, one
+    that turns fewer than ``beta_slow`` times is slowed by ``factor``
+    (positions interpolated), and the pairs between the two correction
+    dimensions blend linearly. No position enters: the blend is the same at
+    any context."""
+    half = dim // 2
+    freqs = jnp.exp(-jnp.log(base) * jnp.arange(half, dtype=jnp.float32) / half)
+    if scaling is None:
+        return freqs
+    span = float(scaling["original_max_position_embeddings"])
+
+    def correction_dim(turns):
+        return dim * math.log(span / (turns * 2 * math.pi)) / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(float(scaling["beta_fast"]))), 0)
+    high = min(math.ceil(correction_dim(float(scaling["beta_slow"]))), dim - 1)
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low)
+                    / max(high - low, 0.001), 0.0, 1.0)
+    keep = 1.0 - ramp                  # 1: the frequency as it is, 0: / factor
+    return freqs / float(scaling["factor"]) * (1.0 - keep) + freqs * keep
+
+
+def rope(x, positions, *, base: float = 10000.0, freqs=None):
     """Rotary position embedding on the last dim (pairs interleaved as
-    [even|odd] halves). x: [..., L, H, D]."""
+    [even|odd] halves). x: [..., L, H, D]. ``freqs`` [D // 2] replaces the
+    plain ``base`` frequencies (:func:`rope_frequencies`)."""
     d = x.shape[-1]
     half = d // 2
-    freqs = jnp.exp(-jnp.log(base) * jnp.arange(half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = rope_frequencies(base, d)
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., L, half]
     cos = jnp.cos(angles)[..., None, :]
     sin = jnp.sin(angles)[..., None, :]

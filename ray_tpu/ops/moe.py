@@ -125,19 +125,31 @@ PICK_COUNTS = len(PICK_COUNT_NAMES)
 
 
 def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
-               topk: int, scale: float) -> Tuple[jax.Array, jax.Array]:
+               topk: int, scale: float, score: str = "softmax",
+               renormalise: bool = False) -> Tuple[jax.Array, jax.Array]:
     """h [N, D] -> (idx [N, topk] int32, weights [N, topk] float32).
 
-    ``s = softmax(W_r h)`` in float32 over every router output (routed and
-    zero-compute experts alike); the ``topk`` largest of ``s + bias`` are
+    ``s = score(W_r h)`` in float32 over every router output (routed and
+    zero-compute experts alike): a ``"softmax"`` over the outputs, or each
+    output's own ``"sigmoid"``. The ``topk`` largest of ``s + bias`` are
     picked (the bias selects, it never weighs), and a pick's weight is
-    ``scale * s`` there, not renormalised."""
+    ``scale * s`` there; with ``renormalise`` the picked ``s`` are first
+    divided by their sum, so a token's weights sum to ``scale`` wherever its
+    picks lie: the sum runs over ALL its picks, those on experts that are
+    held elsewhere too, which is what lets the shares of an expert-parallel
+    deployment add up to the whole layer."""
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"route_topk: no scoring rule {score!r}")
     logits = jnp.einsum("nd,de->ne", h.astype(jnp.float32),
                         w_router.astype(jnp.float32),
                         precision=jax.lax.Precision.HIGHEST)
-    s = jax.nn.softmax(logits, axis=-1)
+    s = (jax.nn.softmax(logits, axis=-1) if score == "softmax"
+         else jax.nn.sigmoid(logits))
     _, idx = jax.lax.top_k(s + bias.astype(jnp.float32), topk)
-    return idx.astype(jnp.int32), scale * jnp.take_along_axis(s, idx, axis=-1)
+    picked = jnp.take_along_axis(s, idx, axis=-1)
+    if renormalise:
+        picked = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return idx.astype(jnp.int32), scale * picked
 
 
 def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,

@@ -1976,6 +1976,9 @@ class LLMEngine:
             "slot_state_shapes": [list(a.shape) for a in self._slot_state],
             "kv_pool_devices": sorted(
                 {str(d) for a in self._pool for d in a.devices()}),
+            # What the family says its stack is made of (PagedFamily.describe).
+            **(self._pg.family.describe(self.config)
+               if self._pg.family.describe is not None else {}),
         }
 
     def decode_tokens_per_sec(self) -> float:

@@ -2,7 +2,7 @@
 
 The benchmark's own tests live in ``benchmark/tests/`` (``python -m pytest
 benchmark/tests -q``), which the tier-1 command does not run. This thin file
-runs, from there, the checks of the shipped manifest, the two newest
+runs, from there, the checks of the shipped manifest, the three newest
 configurations' counts against hand-worked numbers and the ``--rehearse``
 runs of their cells, so that a PR that breaks what a cell reads from the program
 (a program's name, a counter, the family seam) fails tier-1."""
@@ -11,7 +11,8 @@ import pytest
 
 pytest.register_assert_rewrite("benchmark.tests.test_manifest",
                                "benchmark.tests.test_longcat_cell",
-                               "benchmark.tests.test_olmo_hybrid_cell")
+                               "benchmark.tests.test_olmo_hybrid_cell",
+                               "benchmark.tests.test_kimi_k2_cell")
 
 from benchmark.tests.test_longcat_cell import (  # noqa: E402,F401
     config,
@@ -34,6 +35,20 @@ from benchmark.tests.test_olmo_hybrid_cell import (  # noqa: E402,F401
     test_the_olmo_file_states_the_cut_and_every_published_width,
     test_the_olmo_rehearsal_overlay_is_the_tiny_models_sizes,
     test_the_program_holds_what_the_counts_say,
+)
+from benchmark.tests.test_kimi_k2_cell import (  # noqa: E402,F401
+    kimi_config,
+    test_each_launcher_plants_the_fault_it_says,
+    test_kimi_counter_readers_by_hand,
+    test_kimi_counts_by_hand,
+    test_kimi_readers_find_nothing_where_there_is_nothing_to_read,
+    test_rehearsal_of_the_kimi_cell,
+    test_the_cell_joins_the_lists_the_issue_names,
+    test_the_kimi_file_states_the_cut_the_floors_and_every_published_width,
+    test_the_kimi_program_holds_what_the_counts_say,
+    test_the_kimi_rehearsal_overlay_is_the_tiny_models_sizes,
+    test_the_new_reader_file_names_no_architecture,
+    test_without_the_shared_expert_the_cell_is_not_correct,
 )
 from benchmark.tests.test_manifest import (  # noqa: E402,F401
     test_check_names_a_configuration_that_is_not_whole,

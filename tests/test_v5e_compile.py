@@ -41,6 +41,12 @@ LONGCAT_SLOTS, LONGCAT_POOL = 128, 7297
 OLMO_SLOTS, OLMO_POOL, OLMO_BUCKET = 48, 3265, 2048
 
 
+# Kimi-K2.5 at the sizes of the cell kimi-k2.5.agent-decode: published
+# widths, the dense layer and six expert layers, 12 experts held, 96 slots,
+# pool 16993 x 16, the 2048 bucket and the 3072 one (the largest program).
+KIMI_SLOTS, KIMI_POOL, KIMI_BUCKETS = 96, 16993, (2048, 3072)
+
+
 # An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
 # custom-call(...), custom_call_target="tpu_custom_call"``. The profiler names
 # a device operation by this same text, and the benchmark's kernel metrics
@@ -174,6 +180,27 @@ def weight_shaped_data_movers(hlo: str, shapes) -> list:
     return found
 
 
+def named_ops(hlo: str, pattern: str) -> list:
+    """[[name, ``op_name`` metadata], ...] of the instructions outside the
+    fusions' bodies whose name, as the profiler gives it and the benchmark
+    shortens it (``reduce/trace.py:short_name``), matches ``pattern``: what a
+    metric file's ``pattern`` would count in this program's device trace."""
+    from benchmark.reduce.trace import short_name
+
+    fused = set(re.findall(r"fusion\(.*calls=%([\w\-.]+)", hlo))
+    found, current = [], None
+    for line in hlo.splitlines():
+        head = re.match(r"^(ENTRY )?%([\w\-.]+) \(.*\{\s*$", line)
+        if head:
+            current = head.group(2)
+        elif current not in fused and _INSTR.match(line):
+            name = short_name(line.strip().removeprefix("ROOT "))
+            if re.search(pattern, name):
+                scope = re.search(r'op_name="([^"]*)"', line)
+                found.append([name, scope.group(1) if scope else ""])
+    return found
+
+
 def pallas_grids(jaxpr) -> list:
     """The grid of every ``pallas_call`` in a jaxpr, nested ones included."""
     found = []
@@ -199,7 +226,9 @@ def compile_all() -> dict:
     "state_movers": {Olmo serve program: the same scan for its slot state},
     "weight_movers": {serve program: weight_shaped_data_movers() of it},
     "state_roundings": {Olmo serve program: [calls of the state kernel,
-    ``reduce-precision`` instructions that feed them]}}."""
+    ``reduce-precision`` instructions that feed them]},
+    "shared_expert_ops": {Kimi serve program: named_ops() of the pattern of
+    ``shared_expert_ms_per_step.batch``}}."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -230,9 +259,13 @@ def compile_all() -> dict:
     one = SingleDeviceSharding(devices[0])
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
     need_bytes, grids, scoped_vmem, state_movers = {}, {}, {}, {}
-    state_roundings, weight_movers = {}, {}
+    state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
+    with open(os.path.join(REPO, "benchmark", "metrics",
+                           "shared_expert_ms_per_step.batch.json")) as f:
+        shared_pattern = json.load(f)["pattern"]
 
-    def attempt(name, trace, pool=None, state=None, weights=None):
+    def attempt(name, trace, pool=None, state=None, weights=None,
+                shared=False):
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
@@ -250,6 +283,8 @@ def compile_all() -> dict:
                                     + mem.temp_size_in_bytes)
             if weights is not None:
                 weight_movers[name] = weight_shaped_data_movers(text, weights)
+            if shared:
+                shared_expert_ops[name] = named_ops(text, shared_pattern)
             if state is not None:
                 state_movers[name] = pool_shaped_data_movers(text, *state)
                 state_roundings[name] = [
@@ -408,6 +443,39 @@ def compile_all() -> dict:
                 arr((1, OLMO_BUCKET), jnp.int32), i32, i32, i32, i32),
             pool=o_geometry, state=o_state, weights=shapes_of(oparams))
 
+    # Kimi-K2.5's serve programs whole, at the cell's own sizes: the latent
+    # kernels under their names, no weight re-laid on a call, what the shared
+    # expert's metric would count, and the bytes the chip must hold (4.85B
+    # bf16 parameters and a 2.44 GB latent pool).
+    from ray_tpu.models import kimi_k2
+
+    kcfg = kimi_k2.kimi_k2_share()
+    kparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: kimi_k2.init_params(kcfg, key),
+                       jax.random.key(0)))
+    kgen = PagedGenerator(kparams, kcfg, slots=KIMI_SLOTS,
+                          num_blocks=KIMI_POOL, block_tokens=bt,
+                          attention_kernel="pallas")
+    kstate = (kparams,
+              (arr((kcfg.attn_sublayers, KIMI_POOL, bt, kcfg.pool_width)),),
+              (), arr((KIMI_SLOTS, kgen.logits_dim), jnp.float32),
+              arr((KIMI_SLOTS, 2), jnp.uint32))
+    k_slot = lambda dtype: arr((KIMI_SLOTS,), dtype)  # noqa: E731
+    k_geometry = (kcfg.attn_sublayers, KIMI_POOL, bt)
+    attempt("kimi_decode",
+            lambda: kgen.decode_fn(8).trace(
+                *kstate, arr((KIMI_SLOTS, kgen.blocks_per_seq), jnp.int32),
+                k_slot(jnp.int32), k_slot(jnp.bool_), k_slot(jnp.bool_),
+                k_slot(jnp.float32)), pool=k_geometry,
+            weights=shapes_of(kparams), shared=True)
+    for bucket in KIMI_BUCKETS:
+        attempt(f"kimi_prefill_{bucket}",
+                lambda bucket=bucket: kgen.prefill_fn(bucket).trace(
+                    *kstate, arr((kgen.blocks_per_seq,), jnp.int32),
+                    arr((1, bucket), jnp.int32), i32, i32, i32, i32),
+                pool=k_geometry, shared=True)
+
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
     for name, spec in [("train_step_data4", MeshSpec(data=4)),
@@ -432,7 +500,8 @@ def compile_all() -> dict:
             "scoped_vmem": scoped_vmem, "pool_movers": pool_movers,
             "temp_bytes": temp_bytes, "need_bytes": need_bytes,
             "state_movers": state_movers, "weight_movers": weight_movers,
-            "state_roundings": state_roundings}
+            "state_roundings": state_roundings,
+            "shared_expert_ops": shared_expert_ops}
 
 
 @pytest.fixture(scope="module")
@@ -441,7 +510,7 @@ def verdict():
     env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
     child = subprocess.run([sys.executable, os.path.abspath(__file__)],
                            env=env, capture_output=True, text=True,
-                           timeout=400)
+                           timeout=700)
     assert child.returncode == 0, child.stderr[-3000:]
     out = json.loads(child.stdout.strip().splitlines()[-1])
     if "skip" in out:
@@ -628,6 +697,59 @@ def test_olmo_hybrid_serve_programs_fit_the_chip(verdict, program, kernels):
                if re.search(PAGED_ATTN_PATTERN, f"{name}:custom-call:{shape}")]
     assert matched == (["paged_decode_attn"] if program == "olmo_decode"
                        else []), found
+
+
+@pytest.mark.parametrize("program,kernel,shape,need", [
+    ("kimi_decode", "mla_decode_attn", "bf16[96,1,64,512]", (12.1e9, 12.4e9)),
+    ("kimi_prefill_2048", "mla_prefill_attn", "bf16[1,128,1024,512]",
+     (13.2e9, 13.6e9)),
+    ("kimi_prefill_3072", "mla_prefill_attn", "bf16[1,192,1024,512]",
+     (13.8e9, 14.2e9))])
+def test_kimi_serve_programs_fit_the_chip(verdict, program, kernel, shape,
+                                          need):
+    """Kimi-K2.5's ``paged_decode`` and its two largest ``paged_prefill``
+    buckets at the sizes of ``kimi-k2.5.agent-decode`` (``_default_buckets``
+    ends with ``max_len`` itself, and the steady-state start submits prompts
+    of up to 2,816 tokens, so the 3,072 bucket is compiled, warmed and used):
+    they compile for a v5e, arguments plus temporaries stay under 15 GB (the
+    check's float32 pass needs 0.78 GB of temporaries and 0.25 GB of logits
+    beside the 12.14 GB resident), the latent kernels carry LongCat's names
+    (the benchmark's ``mla_attn_*`` metrics match ``^mla_decode_attn:``),
+    the expert layer is the grouped product and nothing copies or slices
+    pool-shaped data. ``paged_attn_roofline``'s shape pattern matches no
+    call of this family."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    # weights 9.70 GB + pool 2.44 GB, + temporaries 0.04 / 1.21 / 1.80 GB
+    low, high = need
+    assert low < verdict["need_bytes"][program] < high < 15e9, \
+        verdict["need_bytes"]
+    found = verdict["kernels"][program]
+    assert [s for n, s in found if n == kernel] == [shape], found
+    assert {n for n, _s in found} == {kernel, "ragged-dot-none",
+                                      "ragged-dot-metadata"}, found
+    assert verdict["pool_movers"][program] == []
+    assert not [n for n, s in found
+                if re.search(PAGED_ATTN_PATTERN, f"{n}:custom-call:{s}")]
+
+
+def test_kimi_decode_reads_its_weights_where_they_lie(verdict):
+    """The two absorbed projections are stored heads-major, as the absorbed
+    products read them (LongCat's decode re-lays 16 of them a call, above):
+    neither ``ENTRY`` nor the loop's body writes a weight-sized array."""
+    assert verdict["weight_movers"]["kimi_decode"] == []
+
+
+def test_the_shared_experts_pattern_matches_its_products_alone(verdict):
+    """``shared_expert_ms_per_step.batch`` finds the shared expert by the
+    output shape of its gate and up products: in the compiled decode program
+    the pattern matches two fusions an expert layer, both written under the
+    ``moe_shared`` scope, and nothing else. (The prefill buckets' rows are
+    not ``slots``; the metric counts inside the decode calls alone.)"""
+    ops = verdict["shared_expert_ops"]["kimi_decode"]
+    assert len(ops) == 2 * 6, ops
+    assert sorted({name for name, _scope in ops}) == [
+        "fusion:fusion:bf16[96,2048]", "fusion:fusion:f32[96,2048]"]
+    assert all("/moe_shared/" in scope for _name, scope in ops), ops
 
 
 def test_the_state_kernels_operands_keep_their_three_parts(verdict):
