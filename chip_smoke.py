@@ -8,7 +8,9 @@ a seed:
 
 - *kernels*: ``flash_attention`` forward and gradients against its dense
   reference, ``paged_attention`` (decode, verify, prefill at every bucket)
-  against its gather reference, on random inputs at the model's shapes;
+  against its gather reference, on random inputs at the model's shapes, and
+  ``latent_paged_attention`` against its own at the two latent cells' shapes
+  (64 heads on 640-lane rows; 128 slots x 64 table entries, 96 x 192);
 - *train*: ``make_mesh`` over every local chip -> ``make_train_step`` with
   ``transformer.lm_loss`` (the path of ``bench.py``): a few adamw steps on one
   batch, the loss must fall and the lowered step must hold the Mosaic kernels.
@@ -61,7 +63,9 @@ def phase_kernels(cfg, interpret: bool) -> dict:
 
     from ray_tpu.core.config import config as knobs
     from ray_tpu.ops.flash_attention import _dense_reference, flash_attention
-    from ray_tpu.ops.paged_attention import (paged_attention,
+    from ray_tpu.ops.paged_attention import (latent_paged_attention,
+                                             latent_paged_attention_reference,
+                                             paged_attention,
                                              paged_attention_reference)
     from ray_tpu.serve.llm import _default_buckets
 
@@ -118,6 +122,37 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     for b in _default_buckets(ctx):
         paged_case(f"paged_prefill_{b}", b, [0])
     paged_case("paged_prefill_prefix_hit", ctx // 4, [3 * bt])
+
+    # The latent kernel at the shapes of longcat-flash-omni.moe-decode and
+    # kimi-k2.5.agent-decode: slots, table entries, a prefill bucket; the
+    # second of two sublayers, contexts spread over the table, a block's
+    # and a lane row's edges and the table's end among them.
+    heads, width, values = 64, 640, 512
+    cells = {"longcat": (128, 64, 1024), "kimi": (96, 192, 2048)}
+    if interpret:
+        cells = {"longcat": (4, 8, 32), "kimi": (3, 12, 40)}
+    latent = dict(value_lanes=values, scale=width ** -0.5)
+    latent_kernel = jax.jit(lambda *a: latent_paged_attention(
+        *a, 1, interpret=interpret, **latent))
+    latent_oracle = jax.jit(lambda *a: latent_paged_attention_reference(
+        *a, 1, **latent))
+    for cell, (slots, nb, bucket) in cells.items():
+        kp, kq = jax.random.split(jax.random.key(SEED + slots))
+        pool = jax.random.normal(kp, (2, slots * nb + 1, bt, width), dt)
+        chains = jnp.asarray(rng.permutation(np.arange(1, slots * nb + 1))
+                             .reshape(slots, nb), jnp.int32)
+        spread = rng.integers(0, nb * bt, slots)
+        edges = np.minimum([0, bt - 1, 129, nb * bt - 1], nb * bt - 1)[:slots]
+        spread[:len(edges)] = edges
+        for name, t_tokens, lengths in (
+                (f"latent_decode_{cell}", 1, spread),
+                (f"latent_prefill_{cell}_{bucket}", bucket, [3 * bt])):
+            n = len(lengths)
+            qq = jax.random.normal(kq, (n, t_tokens, heads, width), dt)
+            ops = (qq, pool, chains[:n], jnp.asarray(lengths, jnp.int32))
+            with jax.default_matmul_precision("highest"):
+                want = latent_oracle(*ops)
+            errs[name] = _rel_err(latent_kernel(*ops), want)
 
     worst = max(errs, key=errs.get)
     return {"ok": all(e <= REL_TOL for e in errs.values()),

@@ -56,10 +56,15 @@ cannot take the loop: Mosaic (jaxlib 0.9.0) pads such a ref's last
 dimension to whole lane tiles and then refuses every slice of it that is
 not a multiple of 128 wide, the full width included, so no DMA can name one
 block. There the same groups are a third grid axis and a group's blocks come
-in through ``G`` BlockSpecs (``_paged_kernel_unaligned``; the form
-``latent_paged_attention`` has): same body, same bound, dead steps cost a
-grid step each. A pool padded to whole lanes would take the loop at every
-width; it is ``models/generate.py``'s to make (ROADMAP L1).
+in through ``G`` BlockSpecs (``_paged_kernel_unaligned``): same body, same
+bound, dead steps cost a grid step each. A pool padded to whole lanes would
+take the loop at every width; it is ``models/generate.py``'s to make
+(ROADMAP L1).
+
+The walk itself is one function, ``_walk_live_groups``, that both kernels
+of this module call: this one with two pools (K and V) and the latent kernel
+(``latent_paged_attention``, below) with one pool whose rows are key and
+value at once, so one DMA a block.
 
 Runs compiled on TPU and in interpret mode on CPU (the tier-1 path);
 ``paged_attention_reference`` is the gather-path oracle the kernel is
@@ -111,19 +116,18 @@ def _tile_last_block(lengths_ref, s, i, q_tile: int, total: int,
 
 
 def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int,
-                         offset: int, nb_seq: int,
-                         total: Optional[int] = None):
-    """The index map of a kernel whose table walk is a grid axis: grid step
-    ``j`` of slot ``s``, query tile ``i`` takes ``step_blocks`` table entries,
-    the pool handed in once per entry, each with its ``offset``; this one
-    maps to pool block ``tables[s, b]`` of layer ``layer[0]``, ``b`` clamped
-    onto the tile's last live block (by its real queries where ``total``,
-    their number over all tiles, is given), so that a dead entry is never
-    dereferenced and a step that maps where the last one did costs no DMA."""
+                         offset: int, nb_seq: int, total: int):
+    """The index map of the kernel whose table walk is a grid axis
+    (``_paged_kernel_unaligned``): grid step ``j`` of slot ``s``, query tile
+    ``i`` takes ``step_blocks`` table entries, the pool handed in once per
+    entry, each with its ``offset``; this one maps to pool block
+    ``tables[s, b]`` of layer ``layer[0]``, ``b`` clamped onto the tile's
+    last live block (by its real queries, ``total`` over all tiles), so that
+    a dead entry is never dereferenced and a step that maps where the last
+    one did costs no DMA."""
     def kv_index(s, i, j, tbl, ln, lyr):
-        last_blk = _tile_last_block(
-            ln, s, i, q_tile, q_tile * (i + 1) if total is None else total,
-            block_tokens, nb_seq)
+        last_blk = _tile_last_block(ln, s, i, q_tile, total, block_tokens,
+                                    nb_seq)
         blk = jnp.minimum(j * step_blocks + offset, last_blk)
         return (lyr[0], tbl[s, blk], 0, 0)
 
@@ -143,12 +147,14 @@ def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
     return min(num_heads, max(1, 128 // head_dim))
 
 
-def _blocks_per_group(block_tokens: int, width: int, itemsize: int) -> int:
-    """How many consecutive table entries one iteration of the kernel's walk
-    takes: ``_GROUP_KV`` kv positions, one lane row of scores, halved while
-    the four halves of the K and V buffers (two each, ``[G*bt, width]``)
+def _blocks_per_group(block_tokens: int, width: int, itemsize: int,
+                      group_kv: int = _GROUP_KV) -> int:
+    """How many consecutive table entries one iteration of a kernel's walk
+    takes: ``group_kv`` kv positions (``_GROUP_KV``: one lane row of scores),
+    halved while the four halves of the K and V buffers (two each,
+    ``[G*bt, width]``; a latent pool's one buffer is held to the same)
     would pass ``_GROUP_VMEM_BYTES``."""
-    g = max(1, _GROUP_KV // block_tokens)
+    g = max(1, group_kv // block_tokens)
     while g > 1 and 4 * g * block_tokens * width * itemsize > _GROUP_VMEM_BYTES:
         g //= 2
     return g
@@ -216,28 +222,31 @@ def _finalize(o_ref, l_scr, acc_scr):
         o_ref[0, h] = out[h * T:(h + 1) * T, e0:e0 + D]
 
 
-def _paged_kernel(
-    tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
-    layer_ref,                 # scalar prefetch: [1] int32
-    q_ref,                     # [1, H*T, C*D] block — T = one q tile
-    k_hbm, v_hbm,              # the whole pools [L, num_blocks, bt, H*D], in HBM
-    o_ref,                     # [1, H, T, D] block
-    m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, C*D]
-    k_buf, v_buf,              # VMEM scratch: [2, G*bt, H*D] each
-    sems,                      # DMA semaphores [2 (K, V), 2 (buffer half)]
+def _walk_live_groups(
+    tables_ref, lengths_ref, layer_ref,   # scalar prefetch
+    pools,                     # the pools in HBM, each [L, num_blocks, bt, W]
+    bufs,                      # VMEM scratch, one a pool: [2, G*bt, W]
+    sems,                      # DMA semaphores [pools, 2 (buffer half)]
     half_ref,                  # SMEM [1]: the half this step's first group is in
+    accumulators,              # VMEM scratch (m, l, acc), reset here
+    attend,                    # attend(g, ctx, half, fetched): group g is in
     *,
-    scale: float,
     block_tokens: int,
     q_tile: int,
     total: int,                # queries over all tiles (the last may be ragged)
     nb_seq: int,
     group_blocks: int,
+    unroll_full: bool = False,
 ):
-    """The walk: grid step ``(s, i)`` loops over the groups of ``G`` table
-    entries its query tile attends, the bound read from ``lengths[s]``. A
-    group's live blocks arrive by one DMA each into one half of ``k_buf`` /
-    ``v_buf`` while the other half is computed on."""
+    """The walk both kernels share: grid step ``(s, i)`` resets its
+    accumulators and loops over the groups of ``G`` table entries its query
+    tile attends, the bound read from ``lengths[s]``. A group's live blocks
+    arrive by one DMA a pool each into one half of every ``buf`` while the
+    other half is computed on; ``attend`` gets the group's index, the tile's
+    first position ``ctx``, the half the group lies in and ``fetched``
+    ``[G*bt, 1]``, which rows of it were brought in. ``unroll_full``: a full
+    group's copies are started and awaited as ``G`` straight-line DMAs, not
+    by a loop (see ``live_copies``)."""
     s = pl.program_id(0)
     i = pl.program_id(1)
     n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
@@ -253,21 +262,36 @@ def _paged_kernel(
         return jnp.clip(last - g * G + 1, 0, G)
 
     def live_copies(s_, g, n_live, half, act):
-        """``act`` on the K and the V copy of the ``n_live`` live entries of
+        """``act`` on every pool's copy of the ``n_live`` live entries of
         group ``g`` of slot ``s_``, into rows ``j * bt`` of buffer ``half``.
         Start and wait both go through here, so a wait meets exactly the
-        copies that were started. (A loop, not unrolled: a serve program
-        lowers this kernel, and an unrolled ``pl.when`` an entry tripled the
-        time that takes.)"""
+        copies that were started. (A loop, not an unrolled ``pl.when`` an
+        entry: a serve program lowers this kernel, and that tripled the time
+        it takes.) A copy costs the scalar core ~46 ns to start and await
+        inside the loop, twice what a 20 KB block takes to arrive, so the
+        walk of a pool of small blocks is bound by that; ``unroll_full``
+        takes a FULL group's, every group of a slot but its last, as ``G``
+        straight-line copies at static offsets (~33 ns each: PERF.md, PR 34)
+        and leaves the loop to the last."""
         def entry(j, _):
             blk = tables_ref[s_, g * G + j]
             rows = pl.ds(pl.multiple_of(j * bt, bt), bt)
-            act(pltpu.make_async_copy(
-                k_hbm.at[layer, blk], k_buf.at[half, rows], sems.at[0, half]))
-            act(pltpu.make_async_copy(
-                v_hbm.at[layer, blk], v_buf.at[half, rows], sems.at[1, half]))
+            for n, (pool, buf) in enumerate(zip(pools, bufs)):
+                act(pltpu.make_async_copy(
+                    pool.at[layer, blk], buf.at[half, rows], sems.at[n, half]))
 
-        jax.lax.fori_loop(0, n_live, entry, None)
+        if not unroll_full:
+            jax.lax.fori_loop(0, n_live, entry, None)
+            return
+
+        @pl.when(n_live == G)
+        def _full():
+            for j in range(G):
+                entry(j, None)
+
+        @pl.when(n_live < G)
+        def _last():
+            jax.lax.fori_loop(0, n_live, entry, None)
 
     last_blk = last_block(s, i)
     n_groups = jax.lax.div(last_blk + G, G)           # ceil((last_blk+1)/G)
@@ -278,7 +302,7 @@ def _paged_kernel(
         live_copies(s, 0, live_entries(last_blk, 0), 0, lambda c: c.start())
 
     first_half = half_ref[0]
-    _init_accumulators(m_scr, l_scr, acc_scr)
+    _init_accumulators(*accumulators)
 
     # The step after this one: the next q tile of the slot, else the next
     # slot's first (clamped where there is none; ``has_next`` guards it).
@@ -306,20 +330,45 @@ def _paged_kernel(
         n_live = live_entries(last_blk, g)
         live_copies(s, g, n_live, half, lambda c: c.wait())
         # Entries past ``last_blk`` (the last group's tail) were not fetched:
-        # their rows hold an earlier group's data, or nothing yet. K's are
-        # masked whatever they hold; V's meet p = 0, and 0 x NaN is NaN, so
-        # they are read as zero.
+        # their rows hold an earlier group's data, or nothing yet.
         fetched = row < n_live * bt
-        _attend_group(
-            q_ref, lambda d0, d1: k_buf[half, :, d0:d1],
-            lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
-            g, lengths_ref[s] + i * T, m_scr, l_scr, acc_scr, scale=scale,
-            num_heads=o_ref.shape[1], q_tile=T, head_dim=o_ref.shape[-1],
-            group_tokens=G * bt)
+        attend(g, lengths_ref[s] + i * T, half, fetched)
 
     jax.lax.fori_loop(0, n_groups, group, None)
     # The half the prefetched first group of the next step went into.
     half_ref[0] = jax.lax.rem(first_half + n_groups, 2)
+
+
+def _paged_kernel(
+    tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
+    layer_ref,                 # scalar prefetch: [1] int32
+    q_ref,                     # [1, H*T, C*D] block — T = one q tile
+    k_hbm, v_hbm,              # the whole pools [L, num_blocks, bt, H*D], in HBM
+    o_ref,                     # [1, H, T, D] block
+    m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, C*D]
+    k_buf, v_buf,              # VMEM scratch: [2, G*bt, H*D] each
+    sems,                      # DMA semaphores [2 (K, V), 2 (buffer half)]
+    half_ref,                  # SMEM [1], the walk's
+    *,
+    scale: float,
+    **walk,                    # _walk_live_groups' static arguments
+):
+    """Two pools, K and V, heads folded into the lanes: the walk
+    (``_walk_live_groups``) with ``_attend_group`` on every group."""
+    def attend(g, ctx, half, fetched):
+        # Unfetched rows of K are masked whatever they hold; V's meet p = 0,
+        # and 0 x NaN is NaN, so they are read as zero.
+        _attend_group(
+            q_ref, lambda d0, d1: k_buf[half, :, d0:d1],
+            lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
+            g, ctx, m_scr, l_scr, acc_scr, scale=scale,
+            num_heads=o_ref.shape[1], q_tile=walk["q_tile"],
+            head_dim=o_ref.shape[-1],
+            group_tokens=walk["group_blocks"] * walk["block_tokens"])
+
+    _walk_live_groups(
+        tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
+        sems, half_ref, (m_scr, l_scr, acc_scr), attend, **walk)
     _finalize(o_ref, l_scr, acc_scr)
 
 
@@ -501,56 +550,56 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
 # ``value_lanes`` lanes, and the up-projections are absorbed into q and into
 # the output by the caller, so the kernel's dot is a real ``[T*H, W] x
 # [W, tokens]`` product: the rows are the heads, where the kernel above needs
-# a block-diagonal q to give each head its own lanes. The grid is (slots,
-# query tiles, table steps), kv innermost, as ``_paged_kernel_unaligned``'s:
-# same scalar prefetch (tables, lengths, layer), same clamped index map (the
-# loop walk of ``_paged_kernel`` is the next step for it); a grid step takes
-# ``_LATENT_STEP_BLOCKS`` pool blocks (the pool handed in once per block),
-# because one 16-token block of one shared row is 20 KB, far too little work
-# for a step's fixed cost.
+# a block-diagonal q to give each head its own lanes. The table walk is the
+# kernel above's (``_walk_live_groups``: grid (slots, query tiles), a loop
+# over a slot's live groups, the pool in HBM) with ONE pool and so one DMA a
+# block: keys and values are read out of the same buffer. A row's width is
+# padded to whole 128-lane tiles by its family (``LatentSpec.pool_width``),
+# so there is no unaligned form.
 
-_LATENT_STEP_BLOCKS = 8      # x 16-token blocks = one 128-lane row of scores
 _LATENT_Q_TILE = 16          # x 64 heads = 1024 rows of f32 accumulators
+
+
+def _latent_group_kv(rows: int) -> int:
+    """kv positions one iteration of the latent walk takes, by the rows
+    (queries x heads) of its dots. An iteration is a chain dot -> max -> exp
+    -> dot that costs ~0.5 us whatever it holds, so decode's 64 rows want
+    many positions; a group is computed whole, so a short context pays for
+    all of them. Measured on a v5e (PERF.md, PR 34; the copies still
+    started in a loop), 128 / 256 / 512 / 1,024 positions: 96 slots of ~1,700 tokens 1.24 / 0.91 / 0.77 / 0.74 ms a
+    call, 128 slots of ~480 tokens 0.50 / 0.39 / 0.35 / 0.36. A prefill
+    tile's 1,024 rows fill the MXU at any of them (the 2,048 bucket 4.9 /
+    2.75 / 2.76 ms), and at 512 its float32 scores leave 2 of the 16 MB of
+    scoped VMEM."""
+    return 512 if rows <= 128 else 256
 
 
 def _latent_kernel(
     tables_ref, lengths_ref, layer_ref,   # scalar prefetch, as _paged_kernel
-    q_ref,                                # [1, T*H, W] block; row = (query, head)
-    *rest,                                # G pool blocks [1, bt, W]; out; scratch
+    q_ref,                     # [1, T*H, W] block; row = (query, head)
+    pool_hbm,                  # the whole pool [A, num_blocks, bt, W], in HBM
+    o_ref,                     # [1, T*H, value_lanes] block
+    m_scr, l_scr, acc_scr,     # VMEM scratch: [T*H, 1] x 2, [T*H, value_lanes]
+    kv_buf,                    # VMEM scratch: [2, G*bt, W]
+    sems,                      # DMA semaphores [1, 2 (buffer half)]
+    half_ref,                  # SMEM [1], the walk's
+    *,
     scale: float,
-    block_tokens: int,
     num_heads: int,
-    q_tile: int,
-    nb_steps: int,
-    step_blocks: int,
     value_lanes: int,
+    **walk,                    # _walk_live_groups' static arguments
 ):
-    kv_refs = rest[:step_blocks]
-    o_ref = rest[step_blocks]              # [1, T*H, value_lanes]
-    m_scr, l_scr, acc_scr = rest[step_blocks + 1:]
-    s = pl.program_id(0)
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    bt, H, T, G = block_tokens, num_heads, q_tile, step_blocks
-    ctx = lengths_ref[s] + i * T
-    last_blk = _last_block(ctx, T, bt)
+    H, T = num_heads, walk["q_tile"]
+    rows, tokens = T * H, walk["group_blocks"] * walk["block_tokens"]
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    @pl.when(j * G <= last_blk)
-    def _body():
-        rows = T * H
-        kv = jnp.concatenate([r[0] for r in kv_refs], axis=0)   # [G*bt, W]
+    def attend(g, ctx, half, fetched):
+        kv = kv_buf[half]                                       # [G*bt, W]
         scores = jax.lax.dot_general(
             q_ref[0], kv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale         # [rows, G*bt]
-        kv_pos = j * (G * bt) + jax.lax.broadcasted_iota(
-            jnp.int32, (rows, G * bt), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (rows, G * bt), 0)
+        kv_pos = g * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, tokens), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0)
         q_pos = ctx + (0 if T == 1 else jax.lax.div(row, H))
         scores = jnp.where(kv_pos <= q_pos, scores, _NEG_INF)
         m_prev = m_scr[:]
@@ -558,16 +607,19 @@ def _latent_kernel(
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(scores - m_new)
         l_scr[:] = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
+        # Unfetched rows are masked as keys whatever they hold; as values
+        # they meet p = 0, and 0 x NaN is NaN, so they are read as zero.
         pv = jax.lax.dot_general(
-            p.astype(kv.dtype), kv[:, :value_lanes], (((1,), (0,)), ((), ())),
+            p.astype(kv.dtype), jnp.where(fetched, kv[:, :value_lanes], 0),
+            (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)                 # [rows, V]
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
 
-    @pl.when(j == nb_steps - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(
-            o_ref.dtype)
+    _walk_live_groups(
+        tables_ref, lengths_ref, layer_ref, (pool_hbm,), (kv_buf,), sems,
+        half_ref, (m_scr, l_scr, acc_scr), attend, **walk)
+    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
 
 
 def latent_paged_attention(
@@ -585,50 +637,65 @@ def latent_paged_attention(
     as [S, T, H, value_lanes]: the caller up-projects it per head. Query t of
     slot s sits at ``lengths[s] + t`` and attends positions ``<=`` it; the T
     new rows must already be in the pool."""
+    W = q.shape[3]
+    if pool.ndim != 4 or pool.shape[3] != W or W % 128:
+        raise ValueError(
+            f"pool {pool.shape} is not [A, num_blocks, bt, {W}] of whole "
+            f"128-lane tiles")
+    return _latent_attention(
+        q, pool, tables.astype(jnp.int32), lengths.astype(jnp.int32),
+        jnp.asarray(layer, jnp.int32).reshape(1), value_lanes=value_lanes,
+        scale=float(scale), interpret=interpret)
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("value_lanes", "scale", "interpret"))
+def _latent_attention(q, pool, tables, lengths, layer, *, value_lanes, scale,
+                      interpret):
+    """:func:`latent_paged_attention` on checked operands: a jit of its own,
+    as :func:`_paged_attention` is and for its reason (a serve program calls
+    it once a sublayer, and there are ten of them a family)."""
     S, T, H, W = q.shape
-    if pool.ndim != 4 or pool.shape[3] != W:
-        raise ValueError(f"pool {pool.shape} is not [A, num_blocks, bt, {W}]")
     bt = pool.shape[2]
-    nb_seq = tables.shape[1]
-    G = _LATENT_STEP_BLOCKS
-    nb_steps = pl.cdiv(nb_seq, G)
     tq = min(T, _LATENT_Q_TILE)
     q_tiles = pl.cdiv(T, tq)
     if T % tq:
+        # Ragged last tile: pad queries are causally AHEAD of every real one
+        # and their rows are sliced off below.
         q = jnp.pad(q, ((0, 0), (0, q_tiles * tq - T), (0, 0), (0, 0)))
     qr = q.reshape(S, q_tiles, tq * H, W)
-    tables = tables.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-    layer = jnp.asarray(layer, jnp.int32).reshape(1)
+    G = _blocks_per_group(bt, W, pool.dtype.itemsize,
+                          _latent_group_kv(tq * H))
 
-    def q_index(s, i, j, tbl, ln, lyr):
+    def q_index(s, i, *_):
         return (s, i, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(S, q_tiles, nb_steps),
-        in_specs=[pl.BlockSpec((None, 1, tq * H, W), q_index)] + [
-            pl.BlockSpec((None, 1, bt, W),
-                         _clamped_block_index(tq, bt, G, g, nb_seq))
-            for g in range(G)],
+        grid=(S, q_tiles),
+        in_specs=[pl.BlockSpec((None, 1, tq * H, W), q_index),
+                  pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((None, 1, tq * H, value_lanes), q_index),
         scratch_shapes=[
             pltpu.VMEM((tq * H, 1), jnp.float32),
             pltpu.VMEM((tq * H, 1), jnp.float32),
             pltpu.VMEM((tq * H, value_lanes), jnp.float32),
+            pltpu.VMEM((2, G * bt, W), pool.dtype),
+            pltpu.SemaphoreType.DMA((1, 2)),
+            pltpu.SMEM((1,), jnp.int32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _latent_kernel, scale=scale, block_tokens=bt, num_heads=H,
-            q_tile=tq, nb_steps=nb_steps, step_blocks=G,
-            value_lanes=value_lanes),
+            _latent_kernel, scale=scale, num_heads=H, value_lanes=value_lanes,
+            block_tokens=bt, q_tile=tq, total=T, nb_seq=tables.shape[1],
+            group_blocks=G, unroll_full=True),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, q_tiles, tq * H, value_lanes),
                                        q.dtype),
         interpret=interpret,
         name="mla_decode_attn" if T == 1 else "mla_prefill_attn",
-    )(tables, lengths, layer, qr, *([pool] * G))
+    )(tables, lengths, layer, qr, pool)
     return out.reshape(S, q_tiles * tq, H, value_lanes)[:, :T]
 
 

@@ -201,17 +201,36 @@ def named_ops(hlo: str, pattern: str) -> list:
     return found
 
 
+def _inner_jaxprs(eqn):
+    """The jaxprs an equation carries in its params (a jit's, a loop's)."""
+    for sub in eqn.params.values():
+        for inner in sub if isinstance(sub, (list, tuple)) else [sub]:
+            inner = getattr(inner, "jaxpr", inner)
+            if hasattr(inner, "eqns"):
+                yield inner
+
+
 def pallas_grids(jaxpr) -> list:
     """The grid of every ``pallas_call`` in a jaxpr, nested ones included."""
     found = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             found.append(list(eqn.params["grid_mapping"].grid))
-        for sub in eqn.params.values():
-            for inner in sub if isinstance(sub, (list, tuple)) else [sub]:
-                inner = getattr(inner, "jaxpr", inner)
-                if hasattr(inner, "eqns"):
-                    found.extend(pallas_grids(inner))
+        for inner in _inner_jaxprs(eqn):
+            found.extend(pallas_grids(inner))
+    return found
+
+
+def jit_calls(jaxpr, name: str) -> list:
+    """The traced body (its ``id``) of every call of the jitted function
+    ``name`` in a jaxpr, nested ones included: a call is a ``jit`` equation
+    of that name, and calls that share one body are lowered once."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "jit" and eqn.params["name"] == name:
+            found.append(id(eqn.params["jaxpr"]))
+        for inner in _inner_jaxprs(eqn):
+            found.extend(jit_calls(inner, name))
     return found
 
 
@@ -228,7 +247,10 @@ def compile_all() -> dict:
     "state_roundings": {Olmo serve program: [calls of the state kernel,
     ``reduce-precision`` instructions that feed them]},
     "shared_expert_ops": {Kimi serve program: named_ops() of the pattern of
-    ``shared_expert_ms_per_step.batch``}}."""
+    ``shared_expert_ms_per_step.batch``},
+    "latent_calls": {name: [calls of the latent kernel's jit, distinct
+    traced bodies among them]},
+    "latent_vmem": {name: scoped VMEM of each latent kernel call}}."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -260,6 +282,7 @@ def compile_all() -> dict:
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
     need_bytes, grids, scoped_vmem, state_movers = {}, {}, {}, {}
     state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
+    latent_calls, latent_vmem = {}, {}
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
@@ -269,12 +292,18 @@ def compile_all() -> dict:
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
+            bodies = jit_calls(traced.jaxpr.jaxpr, "_latent_attention")
+            latent_calls[name] = [len(bodies), len(set(bodies))]
             compiled = traced.lower().compile()
             text = compiled.as_text()
             kernels[name] = sorted(set(_KERNEL.findall(text)))
             scoped_vmem[name] = [
                 int(n) for line in text.splitlines()
                 if "tpu_custom_call" in line for n in _SCOPED.findall(line)]
+            latent_vmem[name] = [
+                int(n) for line in text.splitlines()
+                if "tpu_custom_call" in line and re.match(r"\s*%mla_", line)
+                for n in _SCOPED.findall(line)]
             if pool is not None:
                 pool_movers[name] = pool_shaped_data_movers(text, *pool)
                 mem = compiled.memory_analysis()
@@ -501,7 +530,8 @@ def compile_all() -> dict:
             "temp_bytes": temp_bytes, "need_bytes": need_bytes,
             "state_movers": state_movers, "weight_movers": weight_movers,
             "state_roundings": state_roundings,
-            "shared_expert_ops": shared_expert_ops}
+            "shared_expert_ops": shared_expert_ops,
+            "latent_calls": latent_calls, "latent_vmem": latent_vmem}
 
 
 @pytest.fixture(scope="module")
@@ -730,6 +760,28 @@ def test_kimi_serve_programs_fit_the_chip(verdict, program, kernel, shape,
     assert verdict["pool_movers"][program] == []
     assert not [n for n, s in found
                 if re.search(PAGED_ATTN_PATTERN, f"{n}:custom-call:{s}")]
+
+
+@pytest.mark.parametrize("program,steps,calls", [
+    ("longcat_decode", [LONGCAT_SLOTS, 1], 8),
+    ("kimi_decode", [KIMI_SLOTS, 1], 7),
+    ("longcat_prefill_1024", [1, 64], 8),
+    ("kimi_prefill_2048", [1, 128], 7),
+    ("kimi_prefill_3072", [1, 192], 7)])
+def test_latent_kernel_walks_the_table_itself(verdict, program, steps, calls):
+    """The latent kernel's table is no grid axis either: a call is ``slots x
+    query tiles`` grid steps (tiles of 16 queries x 64 heads), once a latent
+    sublayer, the walk over a slot's live blocks a loop inside each whose one
+    buffer fits the chip's scoped VMEM; the pool goes in where it lies. The
+    call is a jit of its own: a program's sublayers share ONE traced body, so
+    the kernel is lowered once a shape, not once a sublayer."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    assert verdict["grids"][program] == [steps] * calls
+    assert verdict["latent_calls"][program] == [calls, 1]
+    vmem = verdict["latent_vmem"][program]
+    assert len(vmem) == calls and all(0 < v < V5E_SCOPED_VMEM for v in vmem), \
+        vmem
+    assert verdict["pool_movers"][program] == []
 
 
 def test_kimi_decode_reads_its_weights_where_they_lie(verdict):
