@@ -1,0 +1,141 @@
+"""What the serve engine's span ring says about a run, beyond its counters.
+
+    python -m ray_tpu.devtools.stepspans OUT.json SCRIPT [ARGS...]
+
+runs SCRIPT in this process (as ``python SCRIPT ARGS...`` would: it has to
+hold the engine in-process, as ``benchmark/run.py`` does), then reads
+``tracing.recorded()`` and writes :func:`summarise`'s dict to OUT.json. It is
+an operator's tool for a run the profiler did not trace: ``stats()`` says how
+much of the window was host CPU, host waiting, ``device_get`` and hand-off;
+this says WHERE in the step (wall and ``cpu_ns`` of each ``llm.step.<phase>``,
+the prefill's jitted call by quantile) and WHEN in the run (by 5 s bucket
+before the last span: the hand-off's ``handoff_ns``, the rows a decode
+carried, and how late a request's last item reached its client).
+"""
+from __future__ import annotations
+
+import json
+import os
+import runpy
+import sys
+from typing import Dict, Iterable, List, Optional
+
+BUCKET_S = 5
+
+
+def _quantile(values: List[float], q: float) -> Optional[float]:
+    values = sorted(values)
+    return values[min(len(values) - 1, int(q * len(values)))] if values else None
+
+
+def _ms(spans: list) -> List[float]:
+    return [(s.end_ns - s.start_ns) / 1e6 for s in spans]
+
+
+def _attr(span, key: str):
+    return (span.attrs or {}).get(key, 0)
+
+
+def _mean(values: List[float]) -> Optional[float]:
+    return sum(values) / len(values) if values else None
+
+
+def summarise(spans: Iterable) -> Dict:
+    """``spans``: ``tracing.recorded()``'s list. Times in ms unless named."""
+    by: Dict[str, list] = {}
+    for s in spans:
+        by.setdefault(s.name, []).append(s)
+    t_end = max((s.end_ns for ss in by.values() for s in ss), default=0)
+
+    def bucket(ns: int) -> int:     # seconds before the last span's end
+        return int((ns - t_end) / 1e9 // BUCKET_S) * BUCKET_S
+
+    # The steps that dispatched a decode, a phase's mean wall and CPU a step.
+    steps = by.get("llm.step", [])
+    decode = {s.span_id for s in steps if _attr(s, "batch")}
+    phases: Dict[str, list] = {}
+    for name, ss in by.items():
+        if name.startswith("llm.step."):
+            for s in ss:
+                if s.parent_id in decode:
+                    p = phases.setdefault(name[len("llm.step."):], [0.0, 0.0])
+                    p[0] += (s.end_ns - s.start_ns) / 1e6
+                    p[1] += _attr(s, "cpu_ns") / 1e6
+    n = max(1, len(decode))
+    calls = by.get("llm.prefill.dispatch", [])
+    steps_by: Dict[int, list] = {}
+    for s in steps:
+        b = steps_by.setdefault(bucket(s.start_ns), [0, 0.0, 0, 0])
+        b[0] += 1
+        b[1] += _attr(s, "handoff_ns") / 1e9
+        b[2] += _attr(s, "batch")
+        b[3] += _attr(s, "admit_stopped") == "queue_empty"
+
+    # A request's way around the engine, by the first span of each name in
+    # its trace: client -> engine's queue, engine's finish -> client's end.
+    def first(name: str) -> Dict[str, object]:
+        out: Dict[str, object] = {}
+        for s in by.get(name, []):
+            out.setdefault(s.trace_id, s)
+        return out
+
+    outer, waits = first("serve.request"), first("llm.admission_wait")
+    requests_by: Dict[int, list] = {}
+    submit, tail = [], []
+    for tid, inner in first("llm.request").items():
+        if tid in outer and tid in waits:
+            submit.append((waits[tid].start_ns - outer[tid].start_ns) / 1e6)
+            tail.append((outer[tid].end_ns - inner.end_ns) / 1e6)
+            requests_by.setdefault(bucket(inner.end_ns), []).append(tail[-1])
+    return {
+        "spans": sum(len(ss) for ss in by.values()),
+        "decode_steps": len(decode),
+        "phases": {k: {"wall_ms_a_step": w / n, "cpu_ms_a_step": c / n}
+                   for k, (w, c) in sorted(phases.items())},
+        "prefill": {
+            "calls": len(calls),
+            "prefill_ms": _mean(_ms(by.get("llm.prefill", []))),
+            "kv_alloc_ms": _mean(_ms(by.get("kv.alloc", []))),
+            "dispatch_ms": _mean(_ms(calls)),
+            "dispatch_cpu_ms": _mean(
+                [_attr(s, "cpu_ns") / 1e6 for s in calls]),
+            "dispatch_ms_p10_p50_p90": [
+                _quantile(_ms(calls), q) for q in (0.1, 0.5, 0.9)]},
+        "requests": len(tail),
+        "submit_ms": {"p50": _quantile(submit, 0.5),
+                      "p90": _quantile(submit, 0.9)},
+        "tail_ms": {"p50": _quantile(tail, 0.5), "p90": _quantile(tail, 0.9),
+                    "max": _quantile(tail, 1.0)},
+        "steps_by_bucket_s": {
+            str(b): {"steps": v[0], "handoff_s": v[1],
+                     "mean_batch": v[2] / v[0], "queue_empty": v[3]}
+            for b, v in sorted(steps_by.items())},
+        "requests_by_bucket_s_of_engine_finish": {
+            str(b): {"n": len(v), "tail_p50_ms": _quantile(v, 0.5),
+                     "tail_p90_ms": _quantile(v, 0.9)}
+            for b, v in sorted(requests_by.items())},
+    }
+
+
+def main(argv: List[str]) -> int:
+    if len(argv) < 2:
+        print(__doc__, file=sys.stderr)
+        return 2
+    out_path, script = argv[0], argv[1]
+    sys.argv = argv[1:]
+    sys.path.insert(0, os.getcwd())
+    try:
+        runpy.run_path(script, run_name="__main__")
+    except SystemExit as e:
+        if e.code not in (None, 0):
+            return e.code if isinstance(e.code, int) else 1
+    from ray_tpu.util import tracing
+    summary = summarise(tracing.recorded())
+    os.makedirs(os.path.dirname(out_path) or ".", exist_ok=True)
+    with open(out_path, "w") as f:
+        json.dump(summary, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

@@ -198,6 +198,21 @@ _compile_listeners_lock = threading.Lock()
 _compile_listeners_on = False
 
 
+# Cached ``metrics_export`` module: ``_observe`` runs once a step on the
+# driver's thread, where an import statement per call is a sys.modules lookup
+# and a dozen attribute reads (tracing._cfg caches its accessor the same way).
+_metrics_mod = None
+
+
+def _metrics():
+    global _metrics_mod
+    if _metrics_mod is None:
+        from ray_tpu.core import metrics_export
+
+        _metrics_mod = metrics_export
+    return _metrics_mod
+
+
 def _install_compile_listeners() -> None:
     """Once per process, at the first traced warm-up. JAX compiles on the
     calling thread, so a thread-local sink attributes each event to the
@@ -285,20 +300,27 @@ class _WarmupTrace:
 
 class _StepTrace:
     """One engine step's clock. A ``perf_counter_ns`` stamp at every phase
-    boundary, always: the ``stats()`` counters are sums of them. With
-    tracing on the same intervals are open ``jax.profiler.TraceAnnotation``s
-    while they run (an operator's profiler session shows them above the
-    device lines) and become the ``llm.step`` span tree when the step ends.
+    boundary, always, and beside it the thread's CPU time
+    (``time.thread_time_ns``): the ``stats()`` counters are sums of them. A
+    step runs on one thread and its phases tile it, so a phase's CPU is a
+    difference of stamps, and wall less CPU is what the phase spent off the
+    CPU (the GIL, a lock, a blocking runtime call). With tracing on the same
+    intervals are open ``jax.profiler.TraceAnnotation``s while they run (an
+    operator's profiler session shows them above the device lines) and
+    become the ``llm.step`` span tree when the step ends.
 
     Phases tile the step: each ends where the next starts."""
 
-    __slots__ = ("on", "marks", "end_ns", "attrs", "_open")
+    __slots__ = ("on", "marks", "end_ns", "end_cpu_ns", "attrs", "_open",
+                 "prefill_ns", "prefill_cpu_ns")
 
     def __init__(self, on: bool):
         self.on = on
-        self.marks: List[tuple] = []    # (phase, start_ns), in order
-        self.end_ns = 0
+        self.marks: List[tuple] = []    # (phase, start_ns, cpu_ns), in order
+        self.end_ns = self.end_cpu_ns = 0
         self.attrs: Dict = {}
+        # Inside the jitted prefill calls of this step's admissions.
+        self.prefill_ns = self.prefill_cpu_ns = 0
         self._open: List = []           # the step's annotation, the phase's
         if on:
             self._push("llm.step")
@@ -311,20 +333,24 @@ class _StepTrace:
     def enter(self, phase: str) -> None:
         if len(self._open) > 1:
             self._open.pop().__exit__(None, None, None)
-        self.marks.append((phase, tracing.now_ns()))
+        self.marks.append((phase, tracing.now_ns(), time.thread_time_ns()))
         if self.on:
             self._push("llm.step." + phase)
 
     def close(self) -> None:
+        # CPU before wall here, wall before CPU in enter(): the step's CPU
+        # time can never read longer than its wall time.
+        self.end_cpu_ns = time.thread_time_ns()
         self.end_ns = tracing.now_ns()
         while self._open:
             self._open.pop().__exit__(None, None, None)
 
     def phases(self) -> List[tuple]:
-        """(phase, start_ns, end_ns) of every phase entered."""
-        ends = [m[1] for m in self.marks[1:]] + [self.end_ns]
-        return [(name, start, end)
-                for (name, start), end in zip(self.marks, ends)]
+        """(phase, start_ns, end_ns, cpu_ns) of every phase entered."""
+        nxt = self.marks[1:] + [(None, self.end_ns, self.end_cpu_ns)]
+        return [(name, start, end, cpu_end - cpu)
+                for (name, start, cpu), (_, end, cpu_end)
+                in zip(self.marks, nxt)]
 
 
 class _Chunk:
@@ -522,15 +548,26 @@ class LLMEngine:
         # roots of it (steps belong to no request).
         self.trace_id = tracing.new_span_id()
         # Cumulative counts at the step's boundaries (under _agg_lock;
-        # written by the step thread, read by stats()). The ``_s`` ones are
-        # kept in ns here and given in seconds by stats().
-        # Each has a reader under benchmark/metrics: admit_budget_stop_share
-        # (budget stops over steps), pool_blocked_share, step_host_share,
-        # dispatch_ahead_share (steps ahead over steps).
-        self._counts = {"steps_total": 0, "steps_ahead_total": 0,
-                        "admit_stopped_budget_total": 0,
-                        "admit_blocked_pool_s": 0, "step_host_s": 0,
-                        "step_device_wait_s": 0}
+        # written by the elected driver, read by stats()). The ``_s`` ones
+        # are kept in ns here and given in seconds by stats(). What each
+        # counts is said in stats(); each has a reader under
+        # benchmark/metrics (PERF.md §3 pairs them).
+        self._counts = dict.fromkeys((
+            "steps_total", "steps_ahead_total", "step_driver_switches_total",
+            "step_host_s", "step_host_cpu_s", "step_device_wait_s",
+            "step_handoff_s", "prefill_dispatch_s", "prefill_dispatch_cpu_s",
+            "slot_steps_total", "slot_steps_offered_total",
+            "admit_stopped_budget_total", "admit_stopped_queue_empty_total",
+            "admit_stopped_no_slot_total", "admit_stopped_no_blocks_total",
+            "admit_starved_total", "admit_blocked_pool_s"), 0)
+        # The end of the last step if the engine still held a request then
+        # (_holds_request_locked), else None: the next step charges the
+        # stretch up to its start to step_handoff_s. Written under
+        # _state_lock: _cancel clears it when the last request held goes
+        # between two steps. And the thread that ran that step (under
+        # _step_lock).
+        self._held_since_ns: Optional[int] = None
+        self._last_driver: Optional[int] = None
         # Start of a step whose admission stopped on NoFreeBlocks with a
         # slot free and a request waiting; charged to admit_blocked_pool_s
         # when the next step starts. Step-thread-owned.
@@ -591,8 +628,6 @@ class LLMEngine:
         self._spec_ewma = np.ones(self.slots, np.float32)
         self._spec_on = np.zeros(self.slots, bool)
         self._last_counts = None        # last spec step's [S, chunk] advances
-        self._spec_last_accept = np.zeros(self.slots, np.int64)
-        self._spec_last_on = np.zeros(self.slots, bool)
         self._spec_last_dt = 0.0
         self._spec_proposed_pending = 0  # await metric flush (step thread)
         self._spec_accepted_pending = 0
@@ -859,6 +894,19 @@ class LLMEngine:
                 req.finish_reason = "cancelled"
             self._emit_request_span(req, slot)
             req.cond.notify_all()
+            if not self._holds_request_locked():
+                # The last request held went without a step: what follows
+                # is an empty engine's time, nobody's hand-off.
+                self._held_since_ns = None
+
+    def _holds_request_locked(self) -> bool:
+        """A request in a slot, waiting for one, or retired by count with
+        its last chunk unfetched (its consumer still waits for tokens)."""
+        due = self._pending
+        return (bool(self._waiting)
+                or any(r is not None for r in self._slot_req)
+                or (due is not None
+                    and any(not r.done for _, r, _ in due.rows)))
 
     def _emit_request_span(self, req: _Request, slot: Optional[int]) -> None:
         """``llm.request``: submit to finish, whatever the finish was. A ring
@@ -970,6 +1018,7 @@ class LLMEngine:
                 # hold no slot any more.
                 victims += [r for _, r, _ in self._pending.rows]
             self._waiting.clear()
+            self._held_since_ns = None      # nothing is held any more
             for slot in range(self.slots):
                 self._free_slot_locked(slot)
             for r in victims:
@@ -992,6 +1041,9 @@ class LLMEngine:
         # is installed; steady_state() is a no-op otherwise).
         st = _StepTrace(tracing.trace_enabled())
         st.enter("retire")
+        held = self._held_since_ns
+        st.attrs["handoff_ns"] = (st.marks[0][1] - held
+                                  if held is not None else 0)
         if self._blocked_since_ns is not None:
             # The step before stopped admitting on an exhausted pool: the
             # whole stretch from its start to this one's is time a free slot
@@ -1044,24 +1096,45 @@ class LLMEngine:
 
     def _record_step(self, st: _StepTrace) -> None:
         """Fold one finished step into the counters and, traced, record it
-        as ``llm.step`` with one child per phase. The gap between one
-        ``llm.step``'s end and the next one's start (the driver yielding its
-        own tokens, the hand-off to another driver) is read off consecutive
-        spans."""
+        as ``llm.step`` with one child per phase. The stretch between one
+        step's end and the next one's start (the driver yielding its own
+        tokens, the election of the next driver) is no step's: it is
+        counted as ``step_handoff_s`` when the engine held work through it,
+        and rides the later step as ``handoff_ns``."""
         phases = st.phases()
         start = phases[0][1]
-        wait = sum(e - b for name, b, e in phases if name == "device_wait")
+        wait = sum(e - b for name, b, e, _ in phases if name == "device_wait")
+        cpu = sum(c for name, _, _, c in phases if name != "device_wait")
         a = st.attrs
+        rows, stopped = a["batch"], a["admit_stopped"]
+        me = threading.get_ident()
         with self._agg_lock:
             c = self._counts
-            c["steps_total"] += 1 if a["batch"] else 0
+            c["steps_total"] += 1 if rows else 0
             c["steps_ahead_total"] += a["ahead"]
-            c["admit_stopped_budget_total"] += a["admit_stopped"] == "budget"
+            c["step_driver_switches_total"] += self._last_driver not in (
+                None, me)
+            c["admit_stopped_" + stopped + "_total"] += 1
+            # A decode went out with a slot empty and nobody waiting for it.
+            c["admit_starved_total"] += (
+                stopped == "queue_empty" and 0 < rows < self.slots)
             c["step_device_wait_s"] += wait
             c["step_host_s"] += st.end_ns - start - wait
-            # Active slots x token steps the decode program advanced.
+            c["step_host_cpu_s"] += cpu
+            c["step_handoff_s"] += a["handoff_ns"]
+            c["prefill_dispatch_s"] += st.prefill_ns
+            c["prefill_dispatch_cpu_s"] += st.prefill_cpu_ns
+            # Token steps the decode program advanced active slots by, and
+            # those it had slots for.
+            c["slot_steps_total"] += rows * self.chunk
+            c["slot_steps_offered_total"] += (
+                self.slots * self.chunk if rows else 0)
             self._state_counts["state_slot_steps_total"] += (
                 a.get("state_slots", 0) * self.chunk)
+        self._last_driver = me
+        with self._state_lock:
+            self._held_since_ns = (
+                st.end_ns if self._holds_request_locked() else None)
         if not st.on:
             return
         ctx = (self.trace_id, None, True)
@@ -1073,9 +1146,10 @@ class LLMEngine:
         # (the annotations) are where a step is read.
         sid = tracing.emit("llm.step", ctx, start=start, end=st.end_ns,
                            attrs=a, export=False)
-        for name, b, e in phases:
+        for name, b, e, cpu_ns in phases:
             tracing.emit("llm.step." + name, ctx, start=b, end=e,
-                         parent_span_id=sid, export=False)
+                         parent_span_id=sid, attrs={"cpu_ns": cpu_ns},
+                         export=False)
 
     def _step_inner(self, st: _StepTrace) -> None:
         """Schedule and dispatch the next chunk, THEN fetch and deliver the
@@ -1147,7 +1221,7 @@ class LLMEngine:
             if nxt.trace_ctx is not None:
                 nxt.prefill_span = tracing.new_span_id()
             try:
-                self._dispatch_prefill(nxt, free)
+                self._dispatch_prefill(nxt, free, st)
             except NoFreeBlocks:
                 # Paged pool exhausted even after cache eviction: put the
                 # request back at the head and stop admitting — in-flight
@@ -1312,20 +1386,15 @@ class LLMEngine:
                          attrs={"slot": slot, "tokens": ntok,
                                 "batch": batch_size})
         st.attrs.update(tokens=delivered_total, inflight_after=inflight)
-        if due.spec:
-            on = self._spec_last_on
-            st.attrs.update(
-                spec_proposed=int(on.sum()) * self.chunk * self.spec_k,
-                spec_accepted=int(self._spec_last_accept[on].sum()),
-                spec_s=self._spec_last_dt)
         self._fold_step_aux(host_aux, st)
         return ttfts
 
-    def _dispatch_prefill(self, req: _Request, slot: int) -> None:
+    def _dispatch_prefill(self, req: _Request, slot: int,
+                          st: _StepTrace) -> None:
         """Take the prompt's blocks and run its (suffix) prefill into
         ``slot``. May raise :class:`NoFreeBlocks` (pool exhausted) — the
         scheduler requeues the request at the head and stops admitting this
-        step."""
+        step. The jitted call's own time goes onto ``st``."""
         bt = self.block_tokens
         t_alloc = tracing.now_ns()
         evicted0 = self.kv.evicted_blocks
@@ -1411,9 +1480,21 @@ class LLMEngine:
         padded = np.zeros((1, req.bucket), np.int32)
         padded[0, :suffix_len] = req.prompt[hit_len:]
         pf = self._pg.prefill_fn(req.bucket)
+        # The jitted call alone: its operands' transfer and the enqueue. It
+        # returns before the device runs it, unless the runtime makes the
+        # caller wait; wall less CPU says which.
+        t_call, cpu_0 = tracing.now_ns(), time.thread_time_ns()
         self._pool, self._slot_state, self._last, self._keys, aux = pf(
             self._pg.params, self._pool, self._slot_state, self._last,
             self._keys, row, padded, hit_len, suffix_len, slot, req.seed)
+        cpu_call = time.thread_time_ns() - cpu_0
+        t_called = tracing.now_ns()
+        st.prefill_ns += t_called - t_call
+        st.prefill_cpu_ns += cpu_call
+        if req.trace_ctx is not None:
+            tracing.emit("llm.prefill.dispatch", req.trace_ctx,
+                         parent_span_id=req.prefill_span, start=t_call,
+                         end=t_called, attrs={"cpu_ns": cpu_call})
         if aux is not None:
             self._prefill_aux.append(aux)
         # Counted per admission, together: a stats() from another thread
@@ -1539,8 +1620,6 @@ class LLMEngine:
                                          lengths, spec_ops)
         if self._spec:
             self._last_counts = None
-            self._spec_last_accept[:] = 0
-            self._spec_last_on[:] = False
         df = self._pg.decode_fn(self.chunk)
         (toks, self._pool, self._slot_state, self._last, self._keys,
          self._decode_aux) = df(
@@ -1577,8 +1656,6 @@ class LLMEngine:
         # below the floor stop proposing for the rest of the request (their
         # draft passes would cost more than the accepted tokens buy).
         acc = accepted_np.sum(axis=1)
-        self._spec_last_accept = acc
-        self._spec_last_on = spec_on
         prop = np.where(spec_on, self.chunk * self.spec_k, 0)
         live = prop > 0
         if live.any():
@@ -1649,28 +1726,16 @@ class LLMEngine:
                 self._tier_spill_bytes_pending, 0
             fetch_b, self._tier_fetch_bytes_pending = \
                 self._tier_fetch_bytes_pending, 0
-        from ray_tpu.core.metrics_export import (metrics_enabled,
-                                                 serve_kv_block_occupancy,
-                                                 serve_kv_hit_tokens_total,
-                                                 serve_kv_spilled_blocks,
-                                                 serve_kv_tier_fetch_bytes_total,
-                                                 serve_kv_tier_hits_total,
-                                                 serve_kv_tier_spill_bytes_total,
-                                                 serve_spec_accept_ratio,
-                                                 serve_spec_accepted_total,
-                                                 serve_spec_proposed_total,
-                                                 serve_tokens_total,
-                                                 serve_ttft_hist)
-
-        if not metrics_enabled():
+        m = _metrics()
+        if not m.metrics_enabled():
             if self._spec:
                 self._spec_proposed_pending = 0
                 self._spec_accepted_pending = 0
             return
         tags = {"deployment": self.name}
         if delivered:
-            serve_tokens_total().inc(delivered, tags)
-        hist = serve_ttft_hist()
+            m.serve_tokens_total().inc(delivered, tags)
+        hist = m.serve_ttft_hist()
         for total, queued, prefill in ttfts:
             # Phase split: queued (submit→admission), prefill (the prefill
             # dispatch), decode (the remainder — first chunk + distribution).
@@ -1680,31 +1745,31 @@ class LLMEngine:
             hist.observe(max(0.0, total - queued - prefill),
                          {**tags, "phase": "decode"})
         if hits:
-            serve_kv_hit_tokens_total().inc(hits, tags)
+            m.serve_kv_hit_tokens_total().inc(hits, tags)
         st = self.kv.stats()
-        gauge = serve_kv_block_occupancy()
+        gauge = m.serve_kv_block_occupancy()
         for state in ("active", "cached", "free"):
             gauge.set(st[f"kv_blocks_{state}"], {**tags, "state": state})
         if self._tier is not None:
-            ctr = serve_kv_tier_hits_total()
+            ctr = m.serve_kv_tier_hits_total()
             for src, n in tier_hits.items():
                 if n:
                     ctr.inc(n, {**tags, "source": src})
             if spill_b:
-                serve_kv_tier_spill_bytes_total().inc(spill_b, tags)
+                m.serve_kv_tier_spill_bytes_total().inc(spill_b, tags)
             if fetch_b:
-                serve_kv_tier_fetch_bytes_total().inc(fetch_b, tags)
-            serve_kv_spilled_blocks().set(self._tier.spilled_blocks(), tags)
+                m.serve_kv_tier_fetch_bytes_total().inc(fetch_b, tags)
+            m.serve_kv_spilled_blocks().set(self._tier.spilled_blocks(), tags)
         if self._spec:
             prop, self._spec_proposed_pending = self._spec_proposed_pending, 0
             acc, self._spec_accepted_pending = self._spec_accepted_pending, 0
             if prop:
-                serve_spec_proposed_total().inc(prop, tags)
+                m.serve_spec_proposed_total().inc(prop, tags)
             if acc:
-                serve_spec_accepted_total().inc(acc, tags)
+                m.serve_spec_accepted_total().inc(acc, tags)
             tot_prop = self._spec_proposed_total
             if tot_prop:
-                serve_spec_accept_ratio().set(
+                m.serve_spec_accept_ratio().set(
                     self._spec_accepted_total / tot_prop, tags)
             # The spec dispatch IS the first decode chunk for a first
             # token delivered this step — surface its propose+verify time
@@ -1922,8 +1987,26 @@ class LLMEngine:
         # enqueued while the chunk before it was unfetched;
         # ``admit_blocked_pool_s`` is the time from the start of a
         # step whose admission stopped on NoFreeBlocks (a slot free, a
-        # request waiting) to the start of the next; ``step_host_s`` +
-        # ``step_device_wait_s`` is all the time spent inside steps.
+        # request waiting) to the start of the next.
+        # The engine's time, whole: ``step_host_s`` + ``step_device_wait_s``
+        # is all the time spent inside steps, ``step_handoff_s`` the time
+        # between two steps while the engine held work (no thread was
+        # stepping it), so on an engine that is never empty the three grow
+        # by the wall time. ``step_host_cpu_s`` is the CPU time of the
+        # driver's thread inside ``step_host_s`` (the rest it waited: the
+        # GIL, a lock, a blocking runtime call), ``prefill_dispatch_s`` /
+        # ``prefill_dispatch_cpu_s`` the part of both inside the prefills'
+        # jitted calls; ``step_driver_switches_total`` counts steps run by
+        # another thread than the step before: a few in a hundred while a
+        # consumer drives for its request's life, most of them once drivers
+        # are held in their own ``yield`` and the next step waits for
+        # another consumer's poll (read it beside ``step_handoff_s``).
+        # Occupancy: ``slot_steps_total`` over ``slot_steps_offered_total``
+        # is active slots x token steps over slots x token steps of the
+        # decode dispatches. Every step's admission ends in one of
+        # ``admit_stopped_{queue_empty,no_slot,budget,no_blocks}_total``;
+        # ``admit_starved_total`` counts the steps that dispatched a decode
+        # with a slot empty and the queue empty: the clients were elsewhere.
         with self._agg_lock:
             out.update({k: v / 1e9 if k.endswith("_s") else float(v)
                         for k, v in self._counts.items()})

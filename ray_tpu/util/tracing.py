@@ -76,12 +76,7 @@ def current_context() -> Optional[Tuple[str, str, bool]]:
     return _CTX.get()
 
 
-def set_context(ctx: Optional[tuple]) -> None:
-    # Accept legacy (trace_id, span_id) pairs from pre-sampling TaskSpecs —
-    # absent a carried decision the trace counts as sampled, matching the
-    # always-collect behavior those specs were submitted under.
-    if ctx is not None and len(ctx) < 3:
-        ctx = (ctx[0], ctx[1], True)
+def set_context(ctx: Optional[Tuple[str, str, bool]]) -> None:
     _CTX.set(ctx)
 
 
@@ -380,7 +375,8 @@ def flush(runtime=None) -> None:
 
 # ====================== span emission ======================
 
-def emit(name: str, ctx: Optional[tuple], *, start: int, end: int,
+def emit(name: str, ctx: Optional[Tuple[str, str, bool]], *, start: int,
+         end: int,
          parent_span_id: Optional[str] = None,
          span_id: Optional[str] = None,
          attrs: Optional[dict] = None,
@@ -397,7 +393,7 @@ def emit(name: str, ctx: Optional[tuple], *, start: int, end: int,
     alone (the engine's step tree: eight spans a step, read in-process and
     never worth a place in the GCS's task-event ring). Returns the new span
     id, or None when the trace is unsampled / ctx is absent."""
-    if ctx is None or (len(ctx) > 2 and not ctx[2]):
+    if ctx is None or not ctx[2]:
         return None
     sid = span_id or _new_id()
     _record(Span(name, start, max(start, end), sid,
@@ -432,7 +428,7 @@ def span(name: str, *, runtime=None,
     (children inherit the negative decision) but nothing is emitted."""
     parent = current_context()
     if parent is not None:
-        trace_id, sampled = parent[0], (len(parent) < 3 or parent[2])
+        trace_id, sampled = parent[0], parent[2]
     else:
         trace_id = _new_id()
         sampled = trace_enabled() and _decide_sampled()

@@ -534,13 +534,13 @@ class TestDispatchAhead:
         seen = []
         orig = ahead._dispatch_prefill
 
-        def spy(req, slot):
+        def spy(req, slot, st):
             pend = ahead._pending
             former = [r for s, r, _ in (pend.rows if pend else ())
                       if s == slot and r is not req]
             seen.append((slot, [(r.retiring, r.done, bool(r.blocks))
                                 for r in former]))
-            return orig(req, slot)
+            return orig(req, slot, st)
 
         ahead._dispatch_prefill = spy
         try:

@@ -17,6 +17,7 @@ counts at its boundaries live in ``stats()``.
 
 import collections
 import threading
+import time
 
 import jax
 import pytest
@@ -571,3 +572,257 @@ class TestEngineStepTrace:
                  if s.name == "llm.step" and s.trace_id == eng.trace_id]
         assert steps and all(s.attrs["driver"] == "engine-driver"
                              for s in steps if s.attrs["batch"])
+
+
+# ISSUE 35: the engine accounts for every second it holds work. All of it is
+# counters in stats() (tracing off too) and attrs on spans already emitted.
+
+TIME3 = ("step_host_s", "step_device_wait_s", "step_handoff_s")
+STOPS = ("queue_empty", "no_slot", "budget", "no_blocks")
+
+
+def _delta(after, before):
+    return {k: after[k] - before[k] for k in after if k in before}
+
+
+def _steps_of(eng, t0):
+    return [s for s in tracing.recorded(t0)
+            if s.name == "llm.step" and s.trace_id == eng.trace_id]
+
+
+def _closed_loop(eng, clients, requests_each, think_s=0.0, step_sleep_s=0.0):
+    """``clients`` threads, each sending its next request when the last one
+    ended: after ``think_s`` (a client on its way back through a router),
+    and consuming with ``step_sleep_s`` between tokens (a consumer that is
+    slow to come back for its next step). Returns the counters' growth."""
+    before = eng.stats()
+    errors = []
+
+    def client(k):
+        try:
+            for i in range(requests_each):
+                req = eng.submit([7, 3, 11 + k, 5 + i], max_new_tokens=16)
+                n = 0
+                for _ in eng.drive(req):
+                    n += 1
+                    if step_sleep_s:
+                        time.sleep(step_sleep_s)
+                assert n == 16
+                if think_s:
+                    time.sleep(think_s)
+        except BaseException as e:  # noqa: BLE001 — reported by the test
+            errors.append(e)
+
+    threads = [threading.Thread(target=client, args=(k,), name=f"client-{k}")
+               for k in range(clients)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=120)
+    assert not errors and not any(th.is_alive() for th in threads), errors
+    return _delta(eng.stats(), before)
+
+
+class TestStepAccounting:
+    @pytest.mark.parametrize("traced", [True, False])
+    def test_host_wait_and_handoff_add_up_to_the_wall_time(
+            self, tiny_model, fresh_config, traced):
+        """An engine that is never empty from its first step to its last:
+        the time inside steps and the time between them is all of it, with
+        tracing off too; CPU never exceeds wall."""
+        eng = _engine(tiny_model, 65, f"whole{int(traced)}")
+        if not traced:
+            set_config(Config({"trace_enabled": False}))
+        reqs = [eng.submit([7, 3, 11 + i], max_new_tokens=48)
+                for i in range(2)]
+        before = eng.stats()
+        t0 = time.perf_counter_ns()
+        outs = [list(eng.drive(r)) for r in reqs]
+        wall = (time.perf_counter_ns() - t0) / 1e9
+        d = _delta(eng.stats(), before)
+        assert [len(o) for o in outs] == [48, 48]
+        assert (len(tracing.recorded(t0)) > 0) == traced
+        assert sum(d[k] for k in TIME3) == pytest.approx(wall, rel=0.05)
+        assert d["step_handoff_s"] > 0
+        assert 0 < d["step_host_cpu_s"] <= d["step_host_s"]
+        assert (0 < d["prefill_dispatch_cpu_s"] <= d["prefill_dispatch_s"]
+                <= d["step_host_s"])
+        assert 0 < d["slot_steps_total"] <= d["slot_steps_offered_total"]
+        assert d["slot_steps_offered_total"] == d["steps_total"] * 2 * 4
+        assert d["step_driver_switches_total"] == 0     # one thread drove
+        assert all(isinstance(v, float) for v in eng.stats().values())
+
+    def test_slot_steps_are_the_rows_of_every_dispatch(self, tiny_model):
+        eng = _engine(tiny_model, 65, "rows")
+        t0 = tracing.now_ns()
+        d = _closed_loop(eng, clients=3, requests_each=2)
+        steps = _steps_of(eng, t0)
+        assert d["slot_steps_total"] == 4 * sum(s.attrs["batch"] for s in steps)
+        assert d["slot_steps_offered_total"] == 4 * 2 * sum(
+            1 for s in steps if s.attrs["batch"])
+        assert d["slot_steps_total"] == 3 * 2 * 16      # every token step
+        # Three threads took turns: the step names its driver, the counter
+        # counts the changes, and what lay between two steps rides the later.
+        drivers = [s.attrs["driver"] for s in steps]
+        assert d["step_driver_switches_total"] == sum(
+            a != b for a, b in zip(drivers, drivers[1:]))
+        assert d["step_handoff_s"] == pytest.approx(
+            sum(s.attrs["handoff_ns"] for s in steps) / 1e9, rel=1e-6)
+        for a, b in zip(steps, steps[1:]):
+            assert b.attrs["handoff_ns"] in (0, b.start_ns - a.end_ns)
+        # A phase's CPU is on its span and fits inside its wall time.
+        kids = [s for s in tracing.recorded(t0)
+                if s.name.startswith("llm.step.")
+                and s.trace_id == eng.trace_id]
+        assert len(kids) >= 3 * len(steps)
+        host_cpu = sum(k.attrs["cpu_ns"] for k in kids
+                       if k.name != "llm.step.device_wait")
+        assert d["step_host_cpu_s"] == pytest.approx(host_cpu / 1e9, rel=1e-6)
+
+    @pytest.mark.parametrize("outcome,pool_blocks,budget,requests", [
+        ("queue_empty", 65, None, 2), ("no_slot", 65, None, 3),
+        ("budget", 65, 16, 2), ("no_blocks", 5, None, 2)])
+    def test_every_admission_ends_in_one_counted_outcome(
+            self, tiny_model, outcome, pool_blocks, budget, requests):
+        eng = _engine(tiny_model, pool_blocks, f"stop-{outcome}")
+        if budget:
+            eng.prefill_budget = budget
+        before = eng.stats()
+        t0 = tracing.now_ns()
+        reqs = [eng.submit([7, 3, 11 + i], max_new_tokens=16)
+                for i in range(requests)]
+        assert [len(list(eng.drive(r))) for r in reqs] == [16] * requests
+        d = _delta(eng.stats(), before)
+        stops = [s.attrs["admit_stopped"] for s in _steps_of(eng, t0)]
+        for name in STOPS:
+            assert d[f"admit_stopped_{name}_total"] == stops.count(name)
+        assert d[f"admit_stopped_{outcome}_total"] > 0
+        assert sum(d[f"admit_stopped_{n}_total"] for n in STOPS) == len(stops)
+
+    def test_a_planted_starvation_shows_in_the_new_counters(self, tiny_model):
+        """Twelve requests twice on two slots. Sound: four clients that come
+        straight back, so somebody always waits. Starved: the clients are
+        elsewhere (one is left, away 50 ms before it resubmits) and the
+        consumer sleeps 5 ms after every token. Decodes go out half empty
+        with nobody waiting, the engine holds work with no thread stepping
+        it, and the ratio of the two in-step counters cannot say so: it is
+        a share of the time inside steps."""
+        eng = _engine(tiny_model, 65, "starved")
+        sound = _closed_loop(eng, clients=4, requests_each=3)
+        starved = _closed_loop(eng, clients=1, requests_each=12,
+                               think_s=0.05, step_sleep_s=0.005)
+
+        def occupancy(d):       # slots_active_share's reading
+            return d["slot_steps_total"] / d["slot_steps_offered_total"]
+
+        def starved_share(d):   # admit_starved_share's reading
+            return d["admit_starved_total"] / d["steps_total"]
+
+        def host_share(d):      # step_host_share's reading
+            return d["step_host_s"] / (d["step_host_s"]
+                                       + d["step_device_wait_s"])
+
+        def handoff_share(d):
+            return d["step_handoff_s"] / sum(d[k] for k in TIME3)
+
+        assert sound["slot_steps_total"] == starved["slot_steps_total"]
+        assert occupancy(starved) == 0.5 < 0.7 < occupancy(sound)
+        assert starved_share(starved) == 1.0 > 0.5 > starved_share(sound)
+        # Three of a request's four chunks are followed by four sleeps with
+        # the request still held: 60 ms a request against microseconds.
+        assert starved["step_handoff_s"] > max(0.3, 5 * sound["step_handoff_s"])
+        assert (abs(host_share(starved) - host_share(sound)) < 0.35
+                < handoff_share(starved) - handoff_share(sound))
+        for d in (sound, starved):
+            assert d["step_host_cpu_s"] <= d["step_host_s"]
+
+    def test_handoff_is_not_counted_while_the_engine_holds_nothing(
+            self, tiny_model):
+        eng = _engine(tiny_model, 65, "idle")
+        before = eng.stats()
+        assert len(eng.generate([7, 3, 11], max_new_tokens=8)) == 8
+        time.sleep(0.2)                 # nothing held: nobody's hand-off
+        t0 = tracing.now_ns()
+        assert len(eng.generate([7, 3, 12], max_new_tokens=8)) == 8
+        d = _delta(eng.stats(), before)
+        assert d["step_handoff_s"] < 0.1
+        assert _steps_of(eng, t0)[0].attrs["handoff_ns"] == 0
+        assert d["admit_starved_total"] > 0     # one stream on two slots
+
+    @pytest.mark.parametrize("others", [0, 1])
+    def test_handoff_is_not_counted_after_the_last_request_is_cancelled(
+            self, tiny_model, others):
+        """A client that goes away (its generator closed) frees its slot
+        without a step running. If it was the last request held, the idle
+        stretch that follows is nobody's hand-off, however much later the
+        next request comes; if another is still held, the clock runs on."""
+        eng = _engine(tiny_model, 65, f"gone{others}")
+        before = eng.stats()
+        rest = [eng.submit([7, 3, 13], max_new_tokens=16)
+                for _ in range(others)]
+        gen = eng.drive(eng.submit([7, 3, 11], max_new_tokens=64))
+        assert next(gen) is not None        # steps ran: the engine holds it
+        assert eng._held_since_ns is not None
+        gen.close()                         # drive()'s finally cancels it
+        assert (eng._held_since_ns is not None) == bool(others)
+        time.sleep(0.2)
+        t0 = tracing.now_ns()
+        for r in rest:
+            assert len(list(eng.drive(r))) == 16
+        assert len(eng.generate([7, 3, 12], max_new_tokens=8)) == 8
+        d = _delta(eng.stats(), before)
+        first = _steps_of(eng, t0)[0].attrs["handoff_ns"]
+        if others:
+            assert first >= 0.2e9 and d["step_handoff_s"] >= 0.2
+        else:
+            assert first == 0 and d["step_handoff_s"] < 0.1
+
+    def test_stepspans_summarises_the_ring_of_an_untraced_run(
+            self, tiny_model):
+        """The operator's reader of ``cpu_ns`` and ``handoff_ns``
+        (``python -m ray_tpu.devtools.stepspans``) agrees with stats()."""
+        from ray_tpu.devtools import stepspans
+        eng = _engine(tiny_model, 65, "ringsum")
+        t0 = tracing.now_ns()
+        before = eng.stats()
+        for i in range(2):
+            with tracing.span("serve.request"):
+                assert len(eng.generate([7, 3, 11 + i], max_new_tokens=8)) == 8
+        d = _delta(eng.stats(), before)
+        out = stepspans.summarise(tracing.recorded(t0))
+        assert out["decode_steps"] == d["steps_total"] > 0
+        assert set(out["phases"]) >= {"admit", "dispatch", "device_wait"}
+        for p in out["phases"].values():
+            assert 0 <= p["cpu_ms_a_step"] <= p["wall_ms_a_step"] + 0.05
+        pf = out["prefill"]
+        assert pf["calls"] == 2
+        assert 0 < pf["dispatch_cpu_ms"] <= pf["dispatch_ms"] <= pf["prefill_ms"]
+        assert pf["dispatch_ms_p10_p50_p90"][1] > 0
+        assert out["requests"] == 2
+        assert out["submit_ms"]["p50"] >= 0 and out["tail_ms"]["max"] >= 0
+        assert sum(b["handoff_s"] for b in out["steps_by_bucket_s"].values()
+                   ) == pytest.approx(d["step_handoff_s"], rel=1e-6)
+        assert sum(b["n"] for b in
+                   out["requests_by_bucket_s_of_engine_finish"].values()) == 2
+        empty = stepspans.summarise([])
+        assert empty["spans"] == empty["decode_steps"] == empty["requests"] == 0
+
+    def test_the_prefill_call_is_a_span_inside_llm_prefill(self, tiny_model):
+        eng = _engine(tiny_model, 65, "pfspan")
+        t0 = tracing.now_ns()
+        before = eng.stats()
+        with tracing.span("caller") as (trace_id, _sid):
+            assert len(eng.generate([7, 3, 11], max_new_tokens=8)) == 8
+        mine = [s for s in tracing.recorded(t0) if s.trace_id == trace_id]
+        [prefill] = [s for s in mine if s.name == "llm.prefill"]
+        [call] = [s for s in mine if s.name == "llm.prefill.dispatch"]
+        [alloc] = [s for s in mine if s.name == "kv.alloc"]
+        assert call.parent_id == alloc.parent_id == prefill.span_id
+        assert prefill.start_ns <= alloc.end_ns <= call.start_ns
+        assert call.end_ns <= prefill.end_ns
+        assert 0 < call.attrs["cpu_ns"] <= call.end_ns - call.start_ns
+        d = _delta(eng.stats(), before)
+        assert d["prefill_dispatch_s"] == pytest.approx(
+            (call.end_ns - call.start_ns) / 1e9, rel=1e-6)
+        assert d["prefill_dispatch_cpu_s"] == pytest.approx(
+            call.attrs["cpu_ns"] / 1e9, rel=1e-6)
