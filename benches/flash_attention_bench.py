@@ -1,6 +1,7 @@
 """Flash vs dense attention, forward + backward, on the real chip.
 
-The long-context story: the Pallas kernels (block-512, O(L) memory) against
+The long-context story: the Pallas kernels (blocks by the kernel's own rule
+under the 512 bound handed here, O(L) memory) against
 the XLA dense path (O(L²) memory) across sequence lengths (B=4, H=12, D=64,
 bf16, causal). On this installation: not measured.
 

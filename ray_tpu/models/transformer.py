@@ -223,10 +223,11 @@ def _make_flash_attention(scale: float, mesh: Optional[Mesh],
     interpret = platform != "tpu"
 
     def flash(q, k, v):
-        # Largest block ≤512 that divides the sequence, so lengths like 1280
-        # run the kernel; a length no block divides raises inside it.
+        # The kernel's own block rule picks the blocks (ops/flash_attention:
+        # ``_blocks``) for any multiple of 128, so lengths like 1280 run it;
+        # the bound 128 makes any other length raise inside it.
         l = q.shape[1]
-        blk = next((b for b in (512, 256, 128) if l % b == 0), 128)
+        blk = l if l % 128 == 0 else 128
         return flash_attention(q, k, v, True, scale, blk, blk, interpret)
 
     if mesh is None:

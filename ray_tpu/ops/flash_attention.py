@@ -1,24 +1,52 @@
 """Flash attention — Pallas TPU kernels, forward AND backward.
 
 The hot op of the transformer stack. The reference delegates attention math to
-torch/framework kernels; TPU-native it is a Pallas kernel: grid over
-(batch*heads, q-blocks, kv-blocks) with the kv axis innermost (sequential on
-TPU), online-softmax accumulators (m, l, acc) held in VMEM scratch across the
-kv sweep, causal blocks fully skipped via ``pl.when``, and the MXU fed
-(block_q × d) @ (d × block_k) tiles in f32 accumulation.
+torch/framework kernels; TPU-native it is two Pallas kernels, ``flash_fwd`` and
+``flash_bwd``, integrated via ``jax.custom_vjp``. O(L) memory: no L×L
+probability matrix is ever materialized.
 
-Training integrates via ``jax.custom_vjp``. The forward kernel additionally
-emits the row log-sum-exp; the backward is TWO Pallas kernels in the standard
-flash-attention-2 decomposition — O(L) memory, no materialized L×L
-probability matrix:
+**A score tile is ``[kv chunk, q block]``**: keys on the sublanes, queries on
+the lanes. Every per-query statistic (the running max ``m``, the sum ``l``,
+``lse``, ``delta``) is then a lane-dense ``[1, block_q]`` row of a few vector
+registers, where the query-major tile made it a ``[block_q, 1]`` column that
+costs as many registers as a 128-wide tile and is touched ten times a tile;
+reductions over keys are elementwise across registers, and ``p`` and ``ds``
+enter the products that follow them as they lie. The output accumulator is
+kept transposed, ``[D, block_q]``, and turned once a q block.
 
-- dQ kernel: fix a q block, sweep kv blocks; p is recomputed from (q, k,
-  lse), ``ds = p * (dO·Vᵀ - delta)``, ``dq += ds @ k``.
-- dK/dV kernel: fix a kv block, sweep q blocks; ``dv += pᵀ @ dO``,
-  ``dk += dsᵀ @ q``.
+**Operands meet in the dtype they arrive in** (bfloat16 in the train step:
+exact products summed in float32 by ``preferred_element_type``); ``p`` and
+``ds`` are rounded to that dtype as operands, as every other product of the
+block rounds its operands. ``m``, ``l``, ``lse``, ``delta``, the ``exp`` and
+the accumulators stay float32. ``scale`` rides the ``[block_q, D]`` q operand
+when that is exact (a power of two: 1/8 at D = 64), else the float32 scores.
 
+**Causality is walked, not masked**: a head's K and V sit in VMEM whole, and
+the kv sweep is a loop inside the kernel whose bound is read from the q
+block's position. Chunks wholly under the diagonal take the unmasked body (a
+``fori_loop``); the ``block_q // block_k`` chunks the diagonal crosses are
+straight-line code, each against only the queries at or past its first key
+and masked by one static lower-triangle compare. With one q block a head
+(L ≤ 1,024) nothing is left to loop over: the kernel is ``L // block_k``
+tiles, the scores computed 1 + block_k / L of what causality needs.
+
+**The backward is one kernel** in place of flash-attention-2's two: scores,
+``p``, ``dp`` and ``ds`` once a tile, five products (``dv += pᵀ·dO``,
+``dk += dsᵀ·q``, ``dq += ds·k`` and the two that make ``p`` and ``dp``) where
+a dQ and a dK/dV kernel make seven and two ``exp``. ``dk`` and ``dv`` of the
+head accumulate in float32 VMEM over the q blocks.
 ``delta = rowsum(dO * O)`` is a cheap elementwise reduce left to XLA fusion.
-Sequence lengths must divide the block size: a ragged length raises (there is
+
+**Which lengths take which grid.** Up to ``_RESIDENT_ROWS`` (2,048) keys the
+grid is ``(batch*heads, 1, 1)``: one step a head. Beyond that a head's blocks and
+accumulators (~4.5 KB a key) outgrow the 16 MB of scoped VMEM, and the kv axis
+goes back onto the grid in spans of ``_RESIDENT_ROWS`` keys: ``flash_fwd``
+carries ``m``, ``l`` and the accumulator across a q block's spans (innermost,
+sequential), ``flash_bwd`` runs the q blocks inside a span and hands back one
+float32 ``dq`` a span for XLA to add. Same kernels, same walk: chosen from
+the shape alone.
+
+Sequence lengths must divide the blocks: a ragged length raises (there is
 no padded kernel); ``models.transformer``'s ``attn_impl="auto"`` picks the
 dense path for those. Numerics are validated against
 ``parallel.ring_attention.reference_attention`` in interpret mode on CPU.
@@ -31,6 +59,7 @@ by GSPMD. Under a mesh the caller wraps ``flash_attention`` in
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -40,67 +69,186 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
+# Block rule (a sweep of the kernels alone at bf16[256, 1024, 64] on a v5e,
+# PERF.md §6, PR 37): constants of the shape, upper-bounded by the caller's
+# ``block_q`` / ``block_k``.
+# Queries a tile (lanes): the widest that divides the sequence. A tile's fixed
+# costs (loop step, accumulator read-modify-write, the MXU's weight loads) are
+# paid once however wide it is, and one q block a head leaves no loop at all.
+_BLOCK_Q = 1024
+# Keys a chunk (sublanes). The diagonal wastes block_k / 2 scores a query and a
+# smaller chunk pays a tile's fixed costs more often. The forward's fixed cost
+# is large (the [D, block_q] accumulator rescaled and rewritten every tile)
+# and a wasted score costs it two products: 512. The backward has no running
+# statistic to carry and a wasted score costs it five products: 128.
+_BLOCK_K_FWD = 512
+_BLOCK_K_BWD = 128
+# Keys a head may hold in VMEM whole (see the module docstring).
+_RESIDENT_ROWS = 2048
+
+_A_BT = (((1,), (1,)), ((), ()))   # a @ b.T
+_A_B = (((1,), (0,)), ((), ()))    # a @ b
+_AT_B = (((0,), (0,)), ((), ()))   # a.T @ b
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+def _split_scale(scale: float):
+    """``(pre, post)``: ``pre`` multiplies the q operand, exactly (a power of
+    two shifts the exponent in any float type); ``post`` multiplies the
+    float32 scores. One of them is 1."""
+    if math.frexp(scale)[0] == 0.5:
+        return scale, 1.0
+    return 1.0, scale
+
+
+def _aligned(x, multiple: int):
+    return x if isinstance(x, int) else pl.multiple_of(x, multiple)
+
+
+def _when(cond, fn):
+    """``pl.when`` that folds a condition known while tracing."""
+    if isinstance(cond, bool):
+        if cond:
+            fn()
+    else:
+        pl.when(cond)(fn)
+
+
+def _walk(tile, q_start, span_index, *, causal, block_q, block_k, span,
+          n_spans):
+    """Run ``tile(lane0, lanes, k_local, masked)`` over what causality leaves
+    of the q block at ``q_start`` against kv span ``span_index``: whole
+    chunks under the diagonal in a loop, then the chunks the diagonal crosses,
+    each against the queries ``[lane0, block_q)`` of the block that see it."""
+    chunks = span // block_k
+    if not causal:
+        under = chunks
+    else:
+        under = q_start // block_k - span_index * chunks
+        if isinstance(under, int):
+            under = max(0, min(under, chunks))
+        else:
+            under = jnp.clip(under, 0, chunks)
+
+    def whole(j, carry):
+        tile(0, block_q, _aligned(j * block_k, block_k), False)
+        return carry
+    jax.lax.fori_loop(0, under, whole, 0)
+    if not causal:
+        return
+
+    def crossed():
+        local = q_start - span_index * span
+        for c in range(block_q // block_k):
+            tile(c * block_k, block_q - c * block_k,
+                 _aligned(local + c * block_k, block_k), True)
+    # ``span`` is a multiple of ``block_q``: a q block's diagonal lies in
+    # one span.
+    _when(True if n_spans == 1 else q_start // span == span_index, crossed)
+
+
+def _scores(q, k, post, masked):
+    """``[kv chunk, queries]`` float32 scores; ``masked``: the chunk's first
+    key is the queries' first position, so key j is seen by query i >= j."""
+    s = _dot(k, q, _A_BT)
+    if post != 1.0:
+        s = s * post
+    if masked:
+        j = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        i = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(j <= i, s, _NEG_INF)
+    return s
+
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref,  # [1, block_q, d], [1, block_k, d]
-    o_ref,                # [1, block_q, d]
-    lse_ref,              # [1, block_q, 1]
-    m_scr, l_scr, acc_scr,  # VMEM scratch: [block_q, 1], [block_q, 1], [block_q, d]
+    q_ref, k_ref, v_ref,    # [1, nq * block_q, d], [1, span, d] x 2
+    o_ref,                  # [1, nq * block_q, d]
+    lse_ref,                # [1, nq, 1, block_q]
+    m_scr, l_scr, acc_scr,  # VMEM f32: [1, block_q] x 2, [d, block_q]
     *,
     scale: float,
     causal: bool,
     block_q: int,
     block_k: int,
-    kv_blocks: int,
+    nq: int,
+    span: int,
+    n_spans: int,
 ):
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    group = pl.program_id(1)
+    span_index = 0 if n_spans == 1 else pl.program_id(2)
+    pre, post = _split_scale(scale)
+    first = True if n_spans == 1 else span_index == 0
+    last = True if n_spans == 1 else span_index == n_spans - 1
 
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
+    def q_block(t, carry):
+        row0 = _aligned(t * block_q, block_q)
+        q_start = row0 if n_spans == 1 else group * block_q
 
-    q_start = qi * block_q
-    k_start = ki * block_k
+        def init():
+            m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+        _when(first, init)
 
-    # Causal: a kv block strictly after the q block contributes nothing.
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
+        def tile(lane0, lanes, k_local, masked):
+            q = q_ref[0, pl.ds(row0 + lane0, lanes), :]
+            if pre != 1.0:
+                q = q * pre
+            k = k_ref[0, pl.ds(k_local, block_k), :]
+            v = v_ref[0, pl.ds(k_local, block_k), :]
+            s = _scores(q, k, post, masked)                  # [bk, lanes]
+            cols = slice(lane0, lane0 + lanes)
+            m_prev = m_scr[:, cols]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)    # rescale of old accumulators
+            p = jnp.exp(s - m_new)
+            l_scr[:, cols] = (alpha * l_scr[:, cols]
+                              + jnp.sum(p, axis=0, keepdims=True))
+            acc_scr[:, cols] = (acc_scr[:, cols] * alpha
+                                + _dot(v, p.astype(v.dtype), _AT_B))
+            m_scr[:, cols] = m_new
 
-    @pl.when(run if causal else True)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)          # [bq, d]
-        k = k_ref[0].astype(jnp.float32)          # [bk, d]
-        v = v_ref[0].astype(jnp.float32)          # [bk, d]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                  # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_start
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + k_start
-            scores = jnp.where(rows >= cols, scores, _NEG_INF)
+        _walk(tile, q_start, span_index, causal=causal, block_q=block_q,
+              block_k=block_k, span=span, n_spans=n_spans)
 
-        m_prev = m_scr[:]                          # [bq, 1]
-        m_cur = jnp.max(scores, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)            # rescale of old accumulators
-        p = jnp.exp(scores - m_new)                # [bq, bk]
-        l_new = alpha * l_scr[:] + jnp.sum(p, axis=-1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-        acc_scr[:] = acc_scr[:] * alpha + pv
-        m_scr[:] = m_new
-        l_scr[:] = l_new
+        def finalize():
+            denom = jnp.maximum(l_scr[:], 1e-30)
+            o_ref[0, pl.ds(row0, block_q), :] = (
+                acc_scr[:] / denom).T.astype(o_ref.dtype)
+            lse_ref[0, t] = m_scr[:] + jnp.log(denom)
+        _when(last, finalize)
+        return carry
 
-    @pl.when(ki == kv_blocks - 1)
-    def _finalize():
-        denom = jnp.maximum(l_scr[:], 1e-30)
-        o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[:] + jnp.log(denom)).astype(lse_ref.dtype)
+    if nq == 1:
+        q_block(0, 0)
+    else:
+        jax.lax.fori_loop(0, nq, q_block, 0)
+
+
+def _tile(length: int, bound: int) -> int:
+    """The largest multiple of 128 up to ``bound`` that divides ``length``;
+    where there is none (a test's small blocks), the largest divisor."""
+    for size in range(bound - bound % 128, 0, -128):
+        if length % size == 0:
+            return size
+    return next(size for size in range(bound, 0, -1) if length % size == 0)
+
+
+def _span(lk: int) -> int:
+    """Keys a kv span: a head's all while they fit VMEM."""
+    return lk if lk <= _RESIDENT_ROWS else _tile(lk, _RESIDENT_ROWS)
+
+
+def _grid(lq: int, lk: int, block_q: int):
+    """``(span, n_spans, nq)``: keys a span, spans a head, q blocks a grid
+    step. One span: a head's q blocks are a loop inside its one step.
+    Several: one q block a step."""
+    span = _span(lk)
+    n_spans = lk // span
+    return span, n_spans, lq // block_q if n_spans == 1 else 1
 
 
 def _flash_forward(
@@ -109,157 +257,119 @@ def _flash_forward(
     interpret: bool,
 ):
     """q/k/v: [BH, L, D] (batch*heads flattened). Returns (o, lse):
-    o [BH, L, D], lse [BH, L, 1] (row log-sum-exp of scaled scores)."""
+    o [BH, L, D], lse [BH, L // block_q, 1, block_q] (row log-sum-exp of
+    scaled scores, a lane-dense row a q block)."""
     bh, lq, d = q.shape
     lk = k.shape[1]
-    q_blocks = lq // block_q
-    kv_blocks = lk // block_k
+    span, n_spans, nq = _grid(lq, lk, block_q)
+
+    def kv_block(b, i, s):
+        if causal and n_spans > 1:
+            # A step past its q block's diagonal walks nothing: it re-reads
+            # the span it has, which is no copy.
+            s = jnp.minimum(s, (i * block_q + block_q - 1) // span)
+        return b, s, 0
 
     kernel = functools.partial(
         _flash_kernel,
-        scale=scale, causal=causal,
-        block_q=block_q, block_k=block_k, kv_blocks=kv_blocks,
+        scale=scale, causal=causal, block_q=block_q, block_k=block_k,
+        nq=nq, span=span, n_spans=n_spans,
     )
+    q_spec = pl.BlockSpec((1, nq * block_q, d), lambda b, i, s: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, span, d), kv_block)
     return pl.pallas_call(
         kernel,
         name="flash_fwd",
-        grid=(bh, q_blocks, kv_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
-        ],
+        grid=(bh, lq // (nq * block_q), n_spans),
+        in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            q_spec,
+            pl.BlockSpec((1, nq, 1, block_q), lambda b, i, s: (b, i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, lq, 1), jnp.float32),
+            jax.ShapeDtypeStruct((bh, lq // block_q, 1, block_q),
+                                 jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((1, block_q), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v)
 
 
-def _dq_kernel(
-    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,  # blocks (see specs)
-    dq_ref,                                           # [1, block_q, d]
-    dq_scr,                                           # VMEM [block_q, d] f32
+def _bwd_kernel(
+    q_ref, k_ref, v_ref, g_ref,   # [1, nq * block_q, d] (q, g), [1, span, d]
+    lse_ref, delta_ref,           # [1, nq, 1, block_q] f32
+    dk_ref, dv_ref,               # [1, span, d]
+    dq_ref,                       # [1, nq * block_q, d]
+    dk_scr, dv_scr,               # VMEM f32 [span, d]
+    dq_scr,                       # VMEM f32 [d, block_q]
     *,
     scale: float,
     causal: bool,
     block_q: int,
     block_k: int,
-    kv_blocks: int,
+    nq: int,
+    span: int,
+    n_spans: int,
+    n_groups: int,
 ):
-    """Fix a q block, sweep kv blocks (innermost): accumulate dq."""
-    qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    """One kv span against the q blocks of one group: ``dk`` and ``dv``
+    accumulate over the groups (innermost on the grid), ``dq`` of a q block
+    over the span's chunks."""
+    span_index = 0 if n_spans == 1 else pl.program_id(1)
+    group = pl.program_id(2)
+    pre, post = _split_scale(scale)
 
-    @pl.when(ki == 0)
-    def _init():
-        dq_scr[:] = jnp.zeros_like(dq_scr)
-
-    q_start = qi * block_q
-    k_start = ki * block_k
-    run = True
-    if causal:
-        run = k_start <= q_start + block_q - 1
-
-    @pl.when(run if causal else True)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)            # [bq, d]
-        k = k_ref[0].astype(jnp.float32)            # [bk, d]
-        v = v_ref[0].astype(jnp.float32)            # [bk, d]
-        g = g_ref[0].astype(jnp.float32)            # [bq, d]
-        lse = lse_ref[0]                            # [bq, 1] f32
-        delta = delta_ref[0]                        # [bq, 1] f32
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                    # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_start
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + k_start
-            scores = jnp.where(rows >= cols, scores, _NEG_INF)
-        p = jnp.exp(scores - lse)                    # [bq, bk]
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )                                            # [bq, bk]
-        ds = p * (dp - delta) * scale                # [bq, bk]
-        dq_scr[:] += jax.lax.dot_general(
-            ds, k, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )
-
-    @pl.when(ki == kv_blocks - 1)
-    def _finalize():
-        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(
-    q_ref, k_ref, v_ref, g_ref, lse_ref, delta_ref,
-    dk_ref, dv_ref,                                  # [1, block_k, d]
-    dk_scr, dv_scr,                                  # VMEM [block_k, d] f32
-    *,
-    scale: float,
-    causal: bool,
-    block_q: int,
-    block_k: int,
-    q_blocks: int,
-):
-    """Fix a kv block, sweep q blocks (innermost): accumulate dk, dv."""
-    ki = pl.program_id(1)
-    qi = pl.program_id(2)
-
-    @pl.when(qi == 0)
-    def _init():
+    def init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
+    _when(True if n_groups == 1 else group == 0, init)
 
-    q_start = qi * block_q
-    k_start = ki * block_k
-    run = True
-    if causal:
-        # A q block strictly before the kv block sees none of it.
-        run = q_start + block_q - 1 >= k_start
+    def q_block(t, carry):
+        row0 = _aligned(t * block_q, block_q)
+        q_start = row0 if n_spans == 1 else group * block_q
+        dq_scr[:] = jnp.zeros_like(dq_scr)
 
-    @pl.when(run if causal else True)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)            # [bq, d]
-        k = k_ref[0].astype(jnp.float32)            # [bk, d]
-        v = v_ref[0].astype(jnp.float32)            # [bk, d]
-        g = g_ref[0].astype(jnp.float32)            # [bq, d]
-        lse = lse_ref[0]                            # [bq, 1]
-        delta = delta_ref[0]                        # [bq, 1]
-        scores = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * scale                                    # [bq, bk]
-        if causal:
-            rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) + q_start
-            cols = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) + k_start
-            scores = jnp.where(rows >= cols, scores, _NEG_INF)
-        p = jnp.exp(scores - lse)                    # [bq, bk]
-        # dv += pᵀ @ g
-        dv_scr[:] += jax.lax.dot_general(
-            p, g, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )                                            # [bk, d]
-        dp = jax.lax.dot_general(
-            g, v, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        )                                            # [bq, bk]
-        ds = p * (dp - delta) * scale                # [bq, bk]
-        # dk += dsᵀ @ q
-        dk_scr[:] += jax.lax.dot_general(
-            ds, q, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
-        )                                            # [bk, d]
+        def tile(lane0, lanes, k_local, masked):
+            rows = pl.ds(row0 + lane0, lanes)
+            keys = pl.ds(k_local, block_k)
+            q = q_ref[0, rows, :]
+            if pre != 1.0:
+                q = q * pre
+            g = g_ref[0, rows, :]
+            k = k_ref[0, keys, :]
+            v = v_ref[0, keys, :]
+            s = _scores(q, k, post, masked)                  # [bk, lanes]
+            cols = slice(lane0, lane0 + lanes)
+            p = jnp.exp(s - lse_ref[0, t, :, cols])
+            dp = _dot(v, g, _A_BT)                           # [bk, lanes]
+            ds = (p * (dp - delta_ref[0, t, :, cols])).astype(k.dtype)
+            dv_scr[keys, :] += _dot(p.astype(g.dtype), g, _A_B)
+            dk_scr[keys, :] += _dot(ds, q, _A_B)     # q carries ``pre``
+            dq_scr[:, cols] += _dot(k, ds, _AT_B)            # [d, lanes]
 
-    @pl.when(qi == q_blocks - 1)
-    def _finalize():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
+        _walk(tile, q_start, span_index, causal=causal, block_q=block_q,
+              block_k=block_k, span=span, n_spans=n_spans)
+        dq_ref[0, pl.ds(row0, block_q), :] = (
+            dq_scr[:] * scale).T.astype(dq_ref.dtype)
+        return carry
+
+    if nq == 1:
+        q_block(0, 0)
+    else:
+        jax.lax.fori_loop(0, nq, q_block, 0)
+
+    def finalize():
+        dk = dk_scr[:]
+        if post != 1.0:
+            dk = dk * post
+        dk_ref[0] = dk.astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+    _when(True if n_groups == 1 else group == n_groups - 1, finalize)
 
 
 def _flash_backward(
@@ -267,61 +377,50 @@ def _flash_backward(
     *, scale: float, causal: bool, block_q: int, block_k: int,
     interpret: bool,
 ):
-    """All inputs [BH, L, D] (lse [BH, L, 1]); returns (dq, dk, dv)."""
+    """All inputs [BH, L, D] (lse as ``_flash_forward`` returns it); returns
+    (dq, dk, dv)."""
     bh, lq, d = q.shape
     lk = k.shape[1]
-    q_blocks = lq // block_q
-    kv_blocks = lk // block_k
+    span, n_spans, nq = _grid(lq, lk, block_q)
+    n_groups = lq // (nq * block_q)
     # delta_i = Σ_d dO_id · O_id — cheap rowwise reduce; XLA fuses it.
     delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1, keepdims=True)          # [BH, L, 1]
+                    axis=-1).reshape(lse.shape)
 
-    q_spec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kv_spec_for_dq = pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0))
-    row_spec = pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0))
-
-    dq = pl.pallas_call(
+    q_spec = pl.BlockSpec((1, nq * block_q, d), lambda b, s, i: (b, i, 0))
+    kv_spec = pl.BlockSpec((1, span, d), lambda b, s, i: (b, s, 0))
+    row_spec = pl.BlockSpec((1, nq, 1, block_q), lambda b, s, i: (b, i, 0, 0))
+    dk, dv, dq = pl.pallas_call(
         functools.partial(
-            _dq_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, kv_blocks=kv_blocks,
+            _bwd_kernel, scale=scale, causal=causal, block_q=block_q,
+            block_k=block_k, nq=nq, span=span, n_spans=n_spans,
+            n_groups=n_groups,
         ),
-        name="flash_bwd_dq",
-        grid=(bh, q_blocks, kv_blocks),
-        in_specs=[q_spec, kv_spec_for_dq, kv_spec_for_dq, q_spec,
-                  row_spec, row_spec],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        interpret=interpret,
-    )(q, k, v, g, lse, delta)
-
-    # dk/dv: transposed sweep — kv block outer, q block inner.
-    q_spec_t = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
-    kv_spec_t = pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0))
-    row_spec_t = pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(
-            _dkv_kernel, scale=scale, causal=causal,
-            block_q=block_q, block_k=block_k, q_blocks=q_blocks,
-        ),
-        name="flash_bwd_dkv",
-        grid=(bh, kv_blocks, q_blocks),
-        in_specs=[q_spec_t, kv_spec_t, kv_spec_t, q_spec_t,
-                  row_spec_t, row_spec_t],
+        name="flash_bwd",
+        grid=(bh, n_spans, n_groups),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
+            kv_spec, kv_spec,
+            # A span's share of dq: one array a span, added below.
+            pl.BlockSpec((1, nq * block_q, d),
+                         lambda b, s, i: (s * bh + b, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((bh, lk, d), k.dtype),
             jax.ShapeDtypeStruct((bh, lk, d), v.dtype),
+            jax.ShapeDtypeStruct(
+                (n_spans * bh, lq, d),
+                q.dtype if n_spans == 1 else jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((block_k, d), jnp.float32),
-            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((span, d), jnp.float32),
+            pltpu.VMEM((span, d), jnp.float32),
+            pltpu.VMEM((d, block_q), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
+    if n_spans > 1:
+        dq = dq.reshape(n_spans, bh, lq, d).sum(axis=0).astype(q.dtype)
     return dq, dk, dv
 
 
@@ -342,12 +441,14 @@ def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array,
     causal: bool = True,
     scale: Optional[float] = None,
-    block_q: int = 512,
+    block_q: int = 1024,
     block_k: int = 512,
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-head attention, [B, L, H, D] layout (matches
-    ``models.transformer``). Heads fold into the grid's batch dim."""
+    ``models.transformer``). Heads fold into the grid's batch dim.
+    ``block_q`` / ``block_k`` are upper bounds: the blocks run are the block
+    rule's (``_blocks``)."""
     return _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret)[0]
 
 
@@ -361,16 +462,36 @@ def _unfold(x, b, h):
     return x.reshape(b, h, l, d).transpose(0, 2, 1, 3)
 
 
+def _blocks(lq: int, lk: int, block_q: int, block_k: int, causal: bool):
+    """(queries a tile, keys a chunk of the forward, of the backward): the
+    block rule under the caller's bounds. A span holds whole q blocks; causal:
+    the chunk divides the q block, so that the diagonal crosses whole
+    chunks."""
+    bq, bk = min(block_q, lq), min(block_k, lk)
+    if lq % bq != 0 or lk % bk != 0:
+        raise ValueError(
+            f"flash_attention: sequence lengths ({lq}, {lk}) must be "
+            f"multiples of the blocks ({bq}, {bk}); use the dense path for "
+            f"ragged lengths")
+    if causal and lq != lk:
+        raise ValueError(
+            f"flash_attention: causal attention needs queries and keys of "
+            f"one length, got ({lq}, {lk})")
+    span = _span(lk)
+    bq = _tile(lq, min(bq, _BLOCK_Q))
+    if causal:
+        bq = math.gcd(bq, span)
+    chunks = [_tile(span, min(bk, rule))
+              for rule in (_BLOCK_K_FWD, _BLOCK_K_BWD)]
+    if causal:
+        chunks = [math.gcd(bq, chunk) for chunk in chunks]
+    return (bq, *chunks)
+
+
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     b, l, h, d = q.shape
     s = scale if scale is not None else 1.0 / d**0.5
-    bq = min(block_q, l)
-    bk = min(block_k, k.shape[1])
-    if l % bq != 0 or k.shape[1] % bk != 0:
-        raise ValueError(
-            f"flash_attention: sequence lengths ({l}, {k.shape[1]}) must be "
-            f"multiples of the blocks ({bq}, {bk}); use the dense path for "
-            f"ragged lengths")
+    bq, bk, _ = _blocks(l, k.shape[1], block_q, block_k, causal)
     qf, kf, vf = _fold(q), _fold(k), _fold(v)
     of, lse = _flash_forward(
         qf, kf, vf,
@@ -383,8 +504,7 @@ def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
     q, k, v, of, lse = res
     b, l, h, d = q.shape
     s = scale if scale is not None else 1.0 / d**0.5
-    bq = min(block_q, l)
-    bk = min(block_k, k.shape[1])
+    bq, _, bk = _blocks(l, k.shape[1], block_q, block_k, causal)
     dqf, dkf, dvf = _flash_backward(
         _fold(q), _fold(k), _fold(v), _fold(g), of, lse,
         scale=s, causal=causal, block_q=bq, block_k=bk, interpret=interpret,

@@ -153,3 +153,130 @@ class TestFlashUnderMesh:
             cfg, cpu_mesh(MeshSpec(data=2, seq=2)), ShardingRules())
         jaxpr = str(jax.make_jaxpr(auto)(*_qkv(b=4, l=128, h=4, d=16)))
         assert "ppermute" in jaxpr and "pallas_call" not in jaxpr
+
+
+def _rel_err(got, want):
+    """chip_smoke.py's criterion: max error over max magnitude."""
+    got = np.asarray(got, np.float32)
+    want = np.asarray(want, np.float32)
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+def _f32_oracle(q, k, v, g, causal):
+    """Dense attention and its VJP, every product in float32."""
+    q, k, v, g = (x.astype(jnp.float32) for x in (q, k, v, g))
+    with jax.default_matmul_precision("highest"):
+        out, vjp = jax.vjp(
+            lambda q, k, v: reference_attention(q, k, v, causal=causal),
+            q, k, v)
+        return (out,) + vjp(g)
+
+
+class TestFlashBf16:
+    """bfloat16 in, as both train cells feed it: the products take bfloat16
+    operands (``p`` and ``ds`` rounded to it) and sum in float32. Forward
+    AND backward against the float32 dense oracle at ``chip_smoke.py``'s
+    criterion, ``REL_TOL`` 2e-2 for out, dq, dk, dv."""
+
+    REL_TOL = 2e-2
+
+    # (L, block_q, block_k): the blocks are upper bounds; 3 heads (an odd
+    # B*H); 1,280 is the length the model's block rule hands 256-blocks;
+    # (512, 256, 128) and (1024, 512, 128): the diagonal crosses kv chunks
+    # INSIDE a q block; (384, 128, 384): block_k above block_q.
+    @pytest.mark.parametrize("l,block_q,block_k", [
+        (128, 512, 512), (384, 512, 512), (1024, 512, 512),
+        (1280, 256, 256), (512, 256, 128), (1024, 512, 128),
+        (384, 128, 384)])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_forward_and_backward_match_f32_oracle(self, causal, l, block_q,
+                                                   block_k):
+        ks = jax.random.split(jax.random.key(l + block_k), 4)
+        q, k, v, g = (jax.random.normal(kk, (1, l, 3, 64), jnp.float32)
+                      .astype(jnp.bfloat16) for kk in ks)
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal, None, block_q,
+                                            block_k, True), q, k, v)
+        got = (out,) + vjp(g)
+        assert all(x.dtype == jnp.bfloat16 for x in got)
+        want = _f32_oracle(q, k, v, g, causal)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            assert _rel_err(a, b) <= self.REL_TOL, (name, _rel_err(a, b))
+
+    def test_scale_not_a_power_of_two(self):
+        """D = 48: 1/sqrt(D) cannot ride the bfloat16 operand exactly, so it
+        stays on the float32 scores."""
+        ks = jax.random.split(jax.random.key(48), 4)
+        q, k, v, g = (jax.random.normal(kk, (1, 256, 3, 48), jnp.float32)
+                      .astype(jnp.bfloat16) for kk in ks)
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, True, None, 128, 64,
+                                            True), q, k, v)
+        for a, b in zip((out,) + vjp(g), _f32_oracle(q, k, v, g, True)):
+            assert _rel_err(a, b) <= self.REL_TOL
+
+
+class TestFlashSpans:
+    """Past ``_RESIDENT_ROWS`` keys a head's K and V no longer sit in VMEM
+    whole: the kv axis goes onto the grid in spans, the forward carrying its
+    statistics across them and the backward handing back one ``dq`` a span.
+    The rule reads the shape alone, so the test shrinks its constant."""
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("l,resident", [(512, 128), (384, 128),
+                                            (512, 256)])
+    def test_spans_match_dense(self, monkeypatch, causal, l, resident):
+        from ray_tpu.ops import flash_attention as fa
+
+        monkeypatch.setattr(fa, "_RESIDENT_ROWS", resident)
+        assert fa._grid(l, l, 128)[1] == l // resident  # spans
+        q, k, v = _qkv(b=1, l=l, h=3, d=32, seed=l)
+        g = jax.random.normal(jax.random.key(5), q.shape, jnp.float32)
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal, None, 128, 64,
+                                            True), q, k, v)
+        want, vjp_d = jax.vjp(
+            lambda q, k, v: reference_attention(
+                q, k, v, causal=causal).astype(jnp.float32), q, k, v)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                                   rtol=2e-4, atol=2e-4)
+        for a, b in zip(vjp(g), vjp_d(g)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=2e-3, atol=2e-3)
+
+    def test_cross_attention_spans(self, monkeypatch):
+        """Not causal, fewer queries than keys, keys in two spans."""
+        from ray_tpu.ops import flash_attention as fa
+
+        monkeypatch.setattr(fa, "_RESIDENT_ROWS", 128)
+        ks = jax.random.split(jax.random.key(2), 3)
+        q = jax.random.normal(ks[0], (1, 64, 2, 32), jnp.float32)
+        k, v = (jax.random.normal(kk, (1, 256, 2, 32), jnp.float32)
+                for kk in ks[1:])
+        out = flash_attention(q, k, v, False, None, 64, 64, True)
+        oracle = reference_attention(q, k, v, causal=False)
+        np.testing.assert_allclose(np.asarray(out), np.asarray(oracle),
+                                   rtol=2e-4, atol=2e-4)
+
+
+class TestBlockRule:
+    def test_blocks_by_shape(self):
+        from ray_tpu.ops.flash_attention import _blocks
+
+        # (q block, forward chunk, backward chunk) under no bound but the
+        # rule's: one q block a head up to 1,024, else the widest multiple
+        # of 128 that divides; the chunk divides the q block.
+        assert _blocks(1024, 1024, 1024, 1024, True) == (1024, 512, 128)
+        assert _blocks(2048, 2048, 2048, 2048, True) == (1024, 512, 128)
+        assert _blocks(1280, 1280, 1280, 1280, True) == (640, 128, 128)
+        assert _blocks(1152, 1152, 1152, 1152, True) == (384, 384, 128)
+        assert _blocks(128, 128, 512, 512, True) == (128, 128, 128)
+        # the arguments are upper bounds
+        assert _blocks(1024, 1024, 512, 512, True) == (512, 512, 128)
+        assert _blocks(1024, 1024, 256, 64, True) == (256, 64, 64)
+        assert _blocks(256, 256, 64, 32, True) == (64, 32, 32)
+
+    def test_causal_needs_one_length(self):
+        q, k, v = _qkv(b=1, l=128, h=2, d=16)
+        with pytest.raises(ValueError, match="one length"):
+            flash_attention(q[:, :64], k, v, True, None, 64, 64, True)

@@ -221,6 +221,32 @@ def pallas_grids(jaxpr) -> list:
     return found
 
 
+def pallas_products(jaxpr, prefix: str) -> dict:
+    """{kernel name: [[lhs dtype, rhs dtype, output dtype], ...]}: every
+    ``dot_general`` inside the ``pallas_call``s whose name starts with
+    ``prefix``, loops and branches of the kernel included."""
+    def products(inner):
+        found = []
+        for eqn in inner.eqns:
+            if eqn.primitive.name == "dot_general":
+                found.append([str(v.aval.dtype)
+                              for v in (*eqn.invars, *eqn.outvars)])
+            for sub in _inner_jaxprs(eqn):
+                found.extend(products(sub))
+        return found
+
+    found = {}
+    for eqn in jaxpr.eqns:
+        if (eqn.primitive.name == "pallas_call"
+                and eqn.params["name"].startswith(prefix)):
+            found.setdefault(eqn.params["name"], []).extend(
+                products(eqn.params["jaxpr"]))
+        for inner in _inner_jaxprs(eqn):
+            for name, dots in pallas_products(inner, prefix).items():
+                found.setdefault(name, []).extend(dots)
+    return found
+
+
 def jit_calls(jaxpr, name: str) -> list:
     """The traced body (its ``id``) of every call of the jitted function
     ``name`` in a jaxpr, nested ones included: a call is a ``jit`` equation
@@ -250,6 +276,7 @@ def compile_all() -> dict:
     ``shared_expert_ms_per_step.batch``},
     "latent_calls": {name: [calls of the latent kernel's jit, distinct
     traced bodies among them]},
+    "flash_products": {name: pallas_products() of the flash kernels},
     "latent_vmem": {name: scoped VMEM of each latent kernel call}}."""
     import jax
     import jax.numpy as jnp
@@ -282,7 +309,7 @@ def compile_all() -> dict:
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
     need_bytes, grids, scoped_vmem, state_movers = {}, {}, {}, {}
     state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
-    latent_calls, latent_vmem = {}, {}
+    latent_calls, latent_vmem, flash_products = {}, {}, {}
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
@@ -292,6 +319,8 @@ def compile_all() -> dict:
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
+            flash_products[name] = pallas_products(traced.jaxpr.jaxpr,
+                                                   "flash_")
             bodies = jit_calls(traced.jaxpr.jaxpr, "_latent_attention")
             latent_calls[name] = [len(bodies), len(set(bodies))]
             compiled = traced.lower().compile()
@@ -337,6 +366,14 @@ def compile_all() -> dict:
         lambda q, k, v: flash_attention(
             q, k, v, True, None, 512, 512, False).astype(jnp.float32).sum(),
         argnums=(0, 1, 2))).trace(qkv, qkv, qkv))
+
+    # Past 2,048 keys a head's K and V leave VMEM for spans on the grid.
+    long_qkv = arr((1, 4 * ctx, 2, D))
+    attempt("flash_fwd_bwd_long", lambda: jax.jit(jax.value_and_grad(
+        lambda q, k, v: flash_attention(
+            q, k, v, True, None, 4 * ctx, 4 * ctx,
+            False).astype(jnp.float32).sum(),
+        argnums=(0, 1, 2))).trace(long_qkv, long_qkv, long_qkv))
 
     nb_seq = ctx // bt
     pool = arr((1, 2 * slots * nb_seq + 1, bt, H * D))   # one layer's: [None]
@@ -531,7 +568,8 @@ def compile_all() -> dict:
             "state_movers": state_movers, "weight_movers": weight_movers,
             "state_roundings": state_roundings,
             "shared_expert_ops": shared_expert_ops,
-            "latent_calls": latent_calls, "latent_vmem": latent_vmem}
+            "latent_calls": latent_calls, "latent_vmem": latent_vmem,
+            "flash_products": flash_products}
 
 
 @pytest.fixture(scope="module")
@@ -572,12 +610,33 @@ def test_kernels_carry_stable_names_and_unchanged_shapes(verdict):
     for program in ("flash_fwd_bwd", "train_step_data4",
                     "train_step_data2_tensor2"):
         found = kernels[program]
-        for kernel in ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"):
+        # Two kernels since PR 37: the backward is one (``flash_bwd``, first
+        # output dk), where it was ``flash_bwd_dq`` and ``flash_bwd_dkv``.
+        for kernel in ("flash_fwd", "flash_bwd"):
             hits = [shape for name, shape in found if kernel in name]
             assert hits, (program, kernel, found)
             assert all(re.fullmatch(r"bf16\[\d+,\d+,\d+\]", s)
                        for s in hits), (program, hits)
         assert all("flash_" in name for name, _shape in found), found
+
+
+def test_flash_kernels_multiply_in_the_input_type(verdict):
+    """Traced with bfloat16 inputs, no product inside a flash kernel takes a
+    float32 operand (q, k, v and dO as they arrive; ``p`` and ``ds`` rounded
+    to that type), and every one sums in float32: two products in the
+    forward, five in the one backward kernel (a kernel's loop body and its
+    straight-line diagonal chunks each carry the set)."""
+    # One step a head while its K and V fit VMEM; (heads, q blocks, spans)
+    # and (heads, spans, q blocks) past that.
+    assert verdict["grids"]["flash_fwd_bwd"] == [[2 * 12, 1, 1]] * 2
+    assert verdict["grids"]["flash_fwd_bwd_long"] == [[2, 4, 2], [2, 2, 4]]
+    products = verdict["flash_products"]["flash_fwd_bwd"]
+    assert sorted(products) == ["flash_bwd", "flash_fwd"], products
+    for kernel, per_tile in (("flash_fwd", 2), ("flash_bwd", 5)):
+        dots = products[kernel]
+        assert dots and len(dots) % per_tile == 0, (kernel, len(dots))
+        assert all(dot == ["bfloat16", "bfloat16", "float32"]
+                   for dot in dots), (kernel, dots)
 
 
 @pytest.mark.parametrize("num_blocks", SERVE_POOLS)
