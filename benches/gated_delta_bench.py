@@ -7,7 +7,7 @@ decode kernel ``gdn_decode`` against its plain form: the interpreted kernel
 is exact on the CPU whatever XLA does around it, and the first compiled form
 was not (XLA dropped the float32 -> bfloat16 -> float32 round trips that
 split q, k and the gates into bfloat16 parts, leaving them 8 bits: state off
-by 0.025 of 10; ``ops/gated_delta.py:_split3``); (3) the kernel's time a
+by 0.025 of 10; ``ops/layers.py:split3``); (3) the kernel's time a
 layer. Prints one JSON line. Run it through the chip tool from the repo's
 root: ``python3 benches/gated_delta_bench.py``."""
 

@@ -137,15 +137,18 @@ def init_block_pool(config: TransformerConfig, num_blocks: int,
     it, so out-of-range scatter writes land somewhere harmless instead of
     corrupting a live sequence.
 
-    Shape ``[n_layers, num_blocks, block_tokens, n_heads * head_dim]``: the
-    heads are folded into the lane dimension, so a token's K (or V) row is
-    dense in the layout the array has in HBM, and the one layout serves the
-    scatter that writes it, the kernel that reads it in place
-    (``ops/paged_attention.py``) and every program it passes through. With a
-    trailing ``[.., n_heads, head_dim]`` the 64-wide minor dimension made
-    every serve program re-tile the whole pool on entry and on exit."""
+    Shape ``[n_layers, num_blocks, block_tokens, KV heads * head_dim]``: a
+    row holds the heads that are STORED, ``config.n_kv_heads``: fewer than
+    ``n_heads`` where the family groups its queries (query head ``h`` reads
+    KV head ``h // (n_heads // n_kv_heads)``). The heads are folded into the
+    lane dimension, so a token's K (or V) row is dense in the layout the array
+    has in HBM, and the one layout serves the scatter that writes it, the
+    kernel that reads it in place (``ops/paged_attention.py``) and every
+    program it passes through. With a trailing ``[.., n_heads, head_dim]`` the
+    64-wide minor dimension made every serve program re-tile the whole pool on
+    entry and on exit."""
     c = config
-    shape = (c.n_layers, num_blocks, block_tokens, c.n_heads * c.head_dim)
+    shape = (c.n_layers, num_blocks, block_tokens, c.n_kv_heads * c.head_dim)
     return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
 
 
@@ -154,14 +157,19 @@ def _paged_attend(q, k_pool, v_pool, tables, lengths, layer, *, scale,
     """Attention over ``layer`` of the whole paged pool, switched by
     ``kernel``: the Pallas kernel streams only live blocks, in place
     (compiled on TPU, interpret on CPU); ``gather`` is the legacy
-    table-gather + dense-mask path."""
+    table-gather + dense-mask path. A pool row narrower than ``q``'s heads
+    holds grouped KV heads (``init_block_pool``): both paths give query head
+    ``h`` KV head ``h // (H // KV)``."""
     if kernel in ("pallas", "interpret"):
         return paged_attention(q, k_pool, v_pool, tables, lengths, layer,
                                scale=scale, interpret=kernel == "interpret")
     S, T, H, D = q.shape
     max_len = tables.shape[1] * k_pool.shape[2]
-    kc = k_pool[layer, tables].reshape(S, max_len, H, D)
-    vc = v_pool[layer, tables].reshape(S, max_len, H, D)
+    KV = k_pool.shape[3] // D
+    kc = k_pool[layer, tables].reshape(S, max_len, KV, D)
+    vc = v_pool[layer, tables].reshape(S, max_len, KV, D)
+    if KV != H:
+        kc, vc = (jnp.repeat(a, H // KV, axis=2) for a in (kc, vc))
     return _attend_cached(q, kc, vc, lengths + T, scale=scale)
 
 

@@ -109,12 +109,21 @@ class OlmoHybridConfig:
                 f"whole periods, got {self.layer_types}")
         if (self.num_key_value_heads != self.num_attention_heads
                 or self.linear_num_key_heads != self.linear_num_value_heads):
-            raise ValueError("grouped key/value heads are not supported here")
+            raise ValueError(
+                "Olmo-Hybrid is published with as many key/value heads as "
+                "query heads, in both kinds of layer, and this family states "
+                "no other: grouped heads are ops/paged_attention.py:"
+                "paged_attention's (a pool row of n_kv_heads, as "
+                "models/falcon_h1.py), not gdn_decode's")
 
     # What the generator and the GPT-2 pool read.
     @property
     def n_heads(self) -> int:
         return self.num_attention_heads
+
+    @property
+    def n_kv_heads(self) -> int:
+        return self.num_key_value_heads
 
     @property
     def head_dim(self) -> int:

@@ -63,6 +63,11 @@ class TransformerConfig:
         return self.d_model // self.n_heads
 
     @property
+    def n_kv_heads(self) -> int:
+        """K/V heads a pool row stores: every query head has its own."""
+        return self.n_heads
+
+    @property
     def padded_vocab(self) -> int:
         return pad_vocab(self.vocab_size, self.vocab_multiple)
 

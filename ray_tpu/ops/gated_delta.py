@@ -38,10 +38,11 @@ elementwise on ``[dk, Hg * dv]`` with two sums over the sublanes; a head's
 ``q``, ``k``, ``alpha`` and ``beta`` reach its ``dv`` lanes through one small
 product with a 0/1 matrix (``_expander``). That product is exact in ONE
 bfloat16 pass: each float32 operand comes in as three bfloat16 parts (``x =
-hi + mid + lo``, ``_split3``) laid side by side along the contraction, which
-the MXU pads to 128 anyway, against the 0/1 matrix stacked three times. (As a
-float32 product at ``highest`` precision, six passes, it was the kernel's
-bound: 4.1 us a grid step against 1.8 us of DMA; my chip run, PR 31.)
+hi + mid + lo``, ``ops/layers.py:split3``) laid side by side along the
+contraction, which the MXU pads to 128 anyway, against the 0/1 matrix stacked
+three times. (As a float32 product at ``highest`` precision, six passes, it
+was the kernel's bound: 4.1 us a grid step against 1.8 us of DMA; my chip
+run, PR 31.)
 The whole state array goes in, with ``layer`` a scalar-prefetch operand, as
 the pool does in ``ops/paged_attention.py``: a Mosaic call cannot read
 through an XLA slice. A slot that is not ``active`` gets its state back bit
@@ -57,6 +58,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.layers import SPLIT_PARTS, split3
 
 _HI = jax.lax.Precision.HIGHEST
 CHUNK = 64
@@ -200,28 +203,11 @@ def _heads_per_group(heads: int, dk: int, dv: int) -> int:
     return max(fits) if fits else heads
 
 
-_PARTS = 3
-
-
 def _expander(hg: int, dv: int):
     """[3 * Hg, Hg * dv] of 0 and 1, bfloat16: row h (of each of the three
     parts) is one on head h's lanes."""
     return jnp.tile(jnp.repeat(jnp.eye(hg, dtype=jnp.bfloat16), dv, axis=1),
-                    (_PARTS, 1))
-
-
-def _split3(x):
-    """float32 [..., n] -> bfloat16 [..., 3 n]: ``hi | mid | lo`` with ``hi +
-    mid + lo == x`` to float32's last bit (8 + 8 + 8 mantissa bits)."""
-    parts, rest = [], x.astype(jnp.float32)
-    for _ in range(_PARTS):
-        # An explicit rounding: XLA may drop a float32 -> bfloat16 -> float32
-        # round trip (xla_allow_excess_precision), which left ``mid`` and
-        # ``lo`` zero on the chip and the kernel's q, k and gates at 8 bits.
-        part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
-        parts.append(part.astype(jnp.bfloat16))
-        rest = rest - part
-    return jnp.concatenate(parts, axis=-1)
+                    (SPLIT_PARTS, 1))
 
 
 def _gdn_kernel(layer_ref, active_ref,      # scalar prefetch: [1], [S] int32
@@ -275,15 +261,15 @@ def _gdn_decode(state, q, k, v, alpha, beta, active, layer, *, interpret):
     f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
     # [S, H, dk] -> [S, G, dk, 3 Hg]: a head a lane (three bfloat16 parts
     # side by side), keys down the sublanes.
-    heads_last = lambda a: _split3(  # noqa: E731
+    heads_last = lambda a: split3(  # noqa: E731
         f32(a).reshape(S, G, hg, dk).transpose(0, 1, 3, 2))
     ab = jnp.stack([f32(alpha), f32(beta)], axis=1).reshape(S, 2, G, hg)
-    ab = _split3(jnp.pad(ab.transpose(0, 2, 1, 3),
-                         ((0, 0), (0, 0), (0, 14), (0, 0))))
+    ab = split3(jnp.pad(ab.transpose(0, 2, 1, 3),
+                        ((0, 0), (0, 0), (0, 14), (0, 0))))
     per_group = lambda width: pl.BlockSpec(  # noqa: E731
         (1, 1, width), lambda s, g, lyr, act: (s, 0, g))
     per_head = lambda rows: pl.BlockSpec(  # noqa: E731
-        (1, 1, rows, _PARTS * hg), lambda s, g, lyr, act: (s, g, 0, 0))
+        (1, 1, rows, SPLIT_PARTS * hg), lambda s, g, lyr, act: (s, g, 0, 0))
     state_spec = pl.BlockSpec(
         (1, 1, dk, W), lambda s, g, lyr, act: (lyr[0], s, 0, g))
     state, o = pl.pallas_call(
@@ -293,7 +279,7 @@ def _gdn_decode(state, q, k, v, alpha, beta, active, layer, *, interpret):
             grid=(S, G),
             in_specs=[state_spec, per_head(dk), per_head(dk), per_group(W),
                       per_head(16),
-                      pl.BlockSpec((_PARTS * hg, W),
+                      pl.BlockSpec((SPLIT_PARTS * hg, W),
                                    lambda s, g, lyr, act: (0, 0))],
             out_specs=[state_spec, per_group(W)]),
         out_shape=[jax.ShapeDtypeStruct(state.shape, jnp.float32),

@@ -57,6 +57,25 @@ def gated_ffn(fp, x, dtype):
               fp["w_down"], dtype)
 
 
+SPLIT_PARTS = 3
+
+
+def split3(x):
+    """float32 [..., n] -> bfloat16 [..., 3 n]: ``hi | mid | lo`` with ``hi +
+    mid + lo == x`` to float32's last bit (8 + 8 + 8 mantissa bits). What the
+    state kernels (``ops/gated_delta.py``, ``ops/ssd.py``) hand the MXU so
+    that one bfloat16 pass against a 0/1 matrix is exact."""
+    parts, rest = [], x.astype(jnp.float32)
+    for _ in range(SPLIT_PARTS):
+        # An explicit rounding: XLA may drop a float32 -> bfloat16 -> float32
+        # round trip (xla_allow_excess_precision), which left ``mid`` and
+        # ``lo`` zero on the chip and the kernel's q, k and gates at 8 bits.
+        part = jax.lax.reduce_precision(rest, exponent_bits=8, mantissa_bits=7)
+        parts.append(part.astype(jnp.bfloat16))
+        rest = rest - part
+    return jnp.concatenate(parts, axis=-1)
+
+
 def softmax_cross_entropy(logits, labels, ignore_index: int = -100):
     """Token-level CE with f32 logits; ignores masked positions.
 

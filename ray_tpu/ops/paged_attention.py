@@ -40,8 +40,19 @@ gather path). Queries are tiled ``_Q_TILE`` at a time so that VMEM is
 bounded by the tile, not by T; decode and verify are a single tile, and each
 tile walks only the blocks at or below its own last real query.
 
-The pool is the WHOLE model's, ``[L, num_blocks, bt, H*D]``: heads folded
-into the lane dimension, so a block is one dense ``[bt, H*D]`` tile in the
+Grouped queries: a pool row holds the ``KV`` heads that are STORED, ``KV`` a
+divisor of the ``H`` query heads (``KV == H`` is plain multi-head attention
+and the program it always was: the kernel's traced body is unchanged, byte
+for byte). Query head ``h`` reads KV head ``h // R``, ``R = H // KV``; a KV
+head's ``R`` query heads are ``R * T`` ROWS of the same dot against that
+head's lanes of the fetched block (as the 64 heads of the latent kernel are
+rows against one shared row), so a block is fetched once for the group and
+the K/V bytes a step reads are the KV heads', not the query heads'. Below,
+"heads" of the pool and of a lane chunk are KV heads; the accumulators' rows
+and the output are the query heads'.
+
+The pool is the WHOLE model's, ``[L, num_blocks, bt, KV*D]``: heads folded
+into the lane dimension, so a block is one dense ``[bt, KV*D]`` tile in the
 layout the array already has in HBM, read where it lies: a Mosaic call
 cannot read through an XLA slice (handed ``pool[layer]`` it made XLA copy
 that layer's slab out of the pool every call), so no program copies
@@ -135,13 +146,14 @@ def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int,
 
 
 def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
-    """How many heads the kernel takes in one dot. The block's lanes are cut
-    into chunks of C heads; a chunk's C*T query rows, each zero outside its
-    own head's lanes, meet the chunk's C*D lanes of K in ONE dot, so no head
-    is sliced out of a 128-lane register on every block. The dot computes C
-    times the products it needs: free while the rows fit one MXU pass
-    (decode, verify: all heads at once), so beyond that C is only what fills
-    128 lanes (prefill: two heads of 64)."""
+    """How many KV heads the kernel takes in one dot; ``q_tile`` is the rows
+    a KV head brings (its query heads x the tile's queries). The block's
+    lanes are cut into chunks of C heads; a chunk's C*T query rows, each zero
+    outside its own head's lanes, meet the chunk's C*D lanes of K in ONE
+    dot, so no head is sliced out of a 128-lane register on every block. The
+    dot computes C times the products it needs: free while the rows fit one
+    MXU pass (decode, verify: all heads at once), so beyond that C is only
+    what fills 128 lanes (prefill: two heads of 64)."""
     if num_heads * q_tile <= 128:
         return num_heads
     return min(num_heads, max(1, 128 // head_dim))
@@ -161,26 +173,31 @@ def _blocks_per_group(block_tokens: int, width: int, itemsize: int,
 
 
 def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
-                  scale: float, num_heads: int, q_tile: int, head_dim: int,
-                  group_tokens: int):
+                  scale: float, num_heads: int, kv_heads: int, q_tile: int,
+                  head_dim: int, group_tokens: int):
     """One online-softmax update over group ``g`` of a slot's kv positions,
     ``[g * group_tokens, (g+1) * group_tokens)``. ``k_rows(d0, d1)`` and
-    ``v_rows(d0, d1)`` give the group's ``[group_tokens, d1 - d0]`` lanes."""
-    H, T, D = num_heads, q_tile, head_dim
-    C = q_ref.shape[-1] // D                   # heads per lane chunk
+    ``v_rows(d0, d1)`` give the group's ``[group_tokens, d1 - d0]`` lanes.
+    The ``num_heads`` query heads share the pool's ``kv_heads`` in
+    consecutive runs (query head ``h`` reads KV head ``h // R``): a KV
+    head's ``R`` query heads are ``R * q_tile`` rows of the same dot, so a
+    fetched block is read once for all of them."""
+    KV, T, D = kv_heads, q_tile, head_dim
+    C = q_ref.shape[-1] // D                   # KV heads per lane chunk
+    RT = num_heads // KV * T                   # rows a KV head brings
     # Causal + validity in one mask: kv position vs absolute q position.
-    # Row r of a chunk is (head r // T, query r % T).
-    rows = C * T
+    # Row r of a chunk is (query head r // T, query r % T).
+    rows = C * RT
     kv_pos = g * group_tokens + jax.lax.broadcasted_iota(
         jnp.int32, (rows, group_tokens), 1)
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, group_tokens), 0)
     q_pos = ctx + (0 if T == 1 else jax.lax.rem(row, T))
     mask_all = kv_pos <= q_pos
-    for c0 in range(0, H, C):                    # static unroll
-        c = min(C, H - c0)                       # heads of this chunk
+    for c0 in range(0, KV, C):                   # static unroll
+        c = min(C, KV - c0)                      # KV heads of this chunk
         d0, d1 = c0 * D, (c0 + c) * D            # their lanes
-        r0, r1 = c0 * T, (c0 + c) * T            # their rows
-        mask = mask_all[: c * T]
+        r0, r1 = c0 * RT, (c0 + c) * RT          # their query heads' rows
+        mask = mask_all[: c * RT]
         # q and K meet in the dtype they share (bfloat16 on the chip: the
         # products are exact in the float32 they are summed in).
         qb = q_ref[0, r0:r1, : c * D]                       # [c*T, c*D]
@@ -213,12 +230,13 @@ def _init_accumulators(m_scr, l_scr, acc_scr):
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
-def _finalize(o_ref, l_scr, acc_scr):
+def _finalize(o_ref, l_scr, acc_scr, kv_heads: int):
     _, H, T, D = o_ref.shape
     C = acc_scr.shape[-1] // D
+    R = H // kv_heads                                 # query heads a KV head
     out = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
     for h in range(H):                                # static unroll
-        e0 = (h % C) * D                              # head h's lanes in its chunk
+        e0 = (h // R % C) * D                # its KV head's lanes in its chunk
         o_ref[0, h] = out[h * T:(h + 1) * T, e0:e0 + D]
 
 
@@ -343,17 +361,18 @@ def _paged_kernel(
     tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
     layer_ref,                 # scalar prefetch: [1] int32
     q_ref,                     # [1, H*T, C*D] block — T = one q tile
-    k_hbm, v_hbm,              # the whole pools [L, num_blocks, bt, H*D], in HBM
+    k_hbm, v_hbm,              # the whole pools [L, num_blocks, bt, KV*D], in HBM
     o_ref,                     # [1, H, T, D] block
     m_scr, l_scr, acc_scr,     # VMEM scratch: [H*T, 1], [H*T, 1], [H*T, C*D]
-    k_buf, v_buf,              # VMEM scratch: [2, G*bt, H*D] each
+    k_buf, v_buf,              # VMEM scratch: [2, G*bt, KV*D] each
     sems,                      # DMA semaphores [2 (K, V), 2 (buffer half)]
     half_ref,                  # SMEM [1], the walk's
     *,
     scale: float,
+    kv_heads: int,
     **walk,                    # _walk_live_groups' static arguments
 ):
-    """Two pools, K and V, heads folded into the lanes: the walk
+    """Two pools, K and V, KV heads folded into the lanes: the walk
     (``_walk_live_groups``) with ``_attend_group`` on every group."""
     def attend(g, ctx, half, fetched):
         # Unfetched rows of K are masked whatever they hold; V's meet p = 0,
@@ -362,22 +381,23 @@ def _paged_kernel(
             q_ref, lambda d0, d1: k_buf[half, :, d0:d1],
             lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
             g, ctx, m_scr, l_scr, acc_scr, scale=scale,
-            num_heads=o_ref.shape[1], q_tile=walk["q_tile"],
-            head_dim=o_ref.shape[-1],
+            num_heads=o_ref.shape[1], kv_heads=kv_heads,
+            q_tile=walk["q_tile"], head_dim=o_ref.shape[-1],
             group_tokens=walk["group_blocks"] * walk["block_tokens"])
 
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
         sems, half_ref, (m_scr, l_scr, acc_scr), attend, **walk)
-    _finalize(o_ref, l_scr, acc_scr)
+    _finalize(o_ref, l_scr, acc_scr, kv_heads)
 
 
 def _paged_kernel_unaligned(
     tables_ref, lengths_ref, layer_ref,   # scalar prefetch, as _paged_kernel
     q_ref,                                # [1, H*T, C*D] block
-    *rest,                                # G K blocks, G V blocks [1, bt, H*D];
+    *rest,                                # G K blocks, G V blocks [1, bt, KV*D];
                                           # out; m, l, acc scratch
     scale: float,
+    kv_heads: int,
     block_tokens: int,
     q_tile: int,
     total: int,                # queries over all tiles (the last may be ragged)
@@ -408,17 +428,18 @@ def _paged_kernel_unaligned(
             [r[0, :, d0:d1] for r in refs], axis=0)
         _attend_group(
             q_ref, gather(k_refs), gather(v_refs), j, ctx, m_scr, l_scr,
-            acc_scr, scale=scale, num_heads=o_ref.shape[1], q_tile=T,
-            head_dim=o_ref.shape[-1], group_tokens=G * bt)
+            acc_scr, scale=scale, num_heads=o_ref.shape[1],
+            kv_heads=kv_heads, q_tile=T, head_dim=o_ref.shape[-1],
+            group_tokens=G * bt)
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _done():
-        _finalize(o_ref, l_scr, acc_scr)
+        _finalize(o_ref, l_scr, acc_scr, kv_heads)
 
 
 def paged_attention(
     q: jax.Array,                # [S, T, H, D]
-    k_pool: jax.Array,           # [L, num_blocks, bt, H*D] (the whole pool)
+    k_pool: jax.Array,           # [L, num_blocks, bt, KV*D] (the whole pool)
     v_pool: jax.Array,
     tables: jax.Array,           # [S, NB] int32 — pool block ids, 0 = trash
     lengths: jax.Array,          # [S] int32 — valid context BEFORE the T tokens
@@ -433,12 +454,19 @@ def paged_attention(
     positions ``<= lengths[s] + t`` of layer ``layer`` gathered through
     ``tables[s]``. No ``[S, max_len, H, D]`` intermediate exists at any
     point, and the pool is read in place: a caller holding one layer's pool
-    passes ``pool[None]`` and layer 0."""
+    passes ``pool[None]`` and layer 0.
+
+    The pool's row holds ``KV`` heads of ``D``, ``KV`` a divisor of ``H``
+    (grouped-query attention): query head ``h`` reads KV head ``h // (H //
+    KV)``, and a fetched block serves all of a KV head's query heads. With
+    ``KV == H`` it is the multi-head program it always was."""
     S, T, H, D = q.shape
-    if k_pool.ndim != 4 or k_pool.shape[3] != H * D:
+    if (k_pool.ndim != 4 or k_pool.shape[3] % D
+            or H % max(1, k_pool.shape[3] // D)):
         raise ValueError(
-            f"pool {k_pool.shape} is not [L, num_blocks, bt, {H}*{D}]: the "
-            f"kernel reads the whole folded pool (one layer's: pool[None])")
+            f"pool {k_pool.shape} is not [L, num_blocks, bt, KV*{D}] with KV "
+            f"a divisor of {H} heads: the kernel reads the whole folded pool "
+            f"(one layer's: pool[None])")
     return _paged_attention(
         q, k_pool, v_pool, tables.astype(jnp.int32),
         lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
@@ -455,6 +483,9 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
     once and calls it 24 times; XLA inlines the calls."""
     S, T, H, D = q.shape
     bt = k_pool.shape[2]
+    W = k_pool.shape[3]                               # a row: KV heads x D
+    KV = W // D
+    R = H // KV                                       # query heads a KV head
     nb_seq = tables.shape[1]
     qt = q.transpose(0, 2, 1, 3)                      # [S, H, T, D]
     tq = min(T, _Q_TILE)
@@ -464,27 +495,28 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
         # and their rows are sliced off below.
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, q_tiles * tq - T), (0, 0)))
 
-    C = _heads_per_chunk(H, tq, D)
-    # q of head h sits in lanes (h % C) * D of a C*D-wide row, zeros beside
-    # it: one dot of a chunk's rows against the chunk's lanes then gives
-    # every head its own scores. Rows of a tile are (head, query).
-    own = jnp.arange(C)[None, :] == (jnp.arange(H) % C)[:, None]   # [H, C]
+    C = _heads_per_chunk(KV, R * tq, D)
+    # q of head h sits in the lanes of its KV head, (h // R % C) * D of a
+    # C*D-wide row, zeros beside it: one dot of a chunk's rows against the
+    # chunk's lanes then gives every head its own scores. Rows of a tile are
+    # (head, query).
+    own = jnp.arange(C)[None, :] == (jnp.arange(H) // R % C)[:, None]  # [H, C]
     qw = jnp.where(own[None, :, None, :, None], qt[:, :, :, None, :], 0)
     qw = qw.reshape(S, H, q_tiles, tq, C * D).transpose(0, 2, 1, 3, 4)
     qw = qw.reshape(S, q_tiles, H * tq, C * D)
 
-    G = _blocks_per_group(bt, H * D, k_pool.dtype.itemsize)
+    G = _blocks_per_group(bt, W, k_pool.dtype.itemsize)
     accumulators = [
         pltpu.VMEM((H * tq, 1), jnp.float32),
         pltpu.VMEM((H * tq, 1), jnp.float32),
         pltpu.VMEM((H * tq, C * D), jnp.float32),
     ]
-    if (H * D) % 128 == 0:
+    if W % 128 == 0:
         kernel, grid = _paged_kernel, (S, q_tiles)
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = accumulators + [
-            pltpu.VMEM((2, G * bt, H * D), k_pool.dtype),
-            pltpu.VMEM((2, G * bt, H * D), v_pool.dtype),
+            pltpu.VMEM((2, G * bt, W), k_pool.dtype),
+            pltpu.VMEM((2, G * bt, W), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ]
@@ -492,7 +524,7 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
     else:
         kernel, grid = _paged_kernel_unaligned, (S, q_tiles, pl.cdiv(nb_seq, G))
         kv_specs = [
-            pl.BlockSpec((None, 1, bt, H * D),
+            pl.BlockSpec((None, 1, bt, W),
                          _clamped_block_index(tq, bt, G, g, nb_seq, T))
             for g in range(G)] * 2
         scratch = accumulators
@@ -508,8 +540,8 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
     )
     out = pl.pallas_call(
         functools.partial(
-            kernel, scale=scale, block_tokens=bt, q_tile=tq, total=T,
-            nb_seq=nb_seq, group_blocks=G),
+            kernel, scale=scale, kv_heads=KV, block_tokens=bt, q_tile=tq,
+            total=T, nb_seq=nb_seq, group_blocks=G),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype),
         interpret=interpret,
@@ -529,8 +561,11 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
     bt = k_pool.shape[2]
     nb_seq = tables.shape[1]
     s_val = scale if scale is not None else 1.0 / D**0.5
-    kc = k_pool[layer, tables].reshape(S, nb_seq * bt, H, D)
-    vc = v_pool[layer, tables].reshape(S, nb_seq * bt, H, D)
+    KV = k_pool.shape[3] // D
+    kc = k_pool[layer, tables].reshape(S, nb_seq * bt, KV, D)
+    vc = v_pool[layer, tables].reshape(S, nb_seq * bt, KV, D)
+    if KV != H:             # grouped: query head h reads KV head h // (H // KV)
+        kc, vc = (jnp.repeat(a, H // KV, axis=2) for a in (kc, vc))
     scores = jnp.einsum("bthd,bshd->bhts", q, kc,
                         preferred_element_type=jnp.float32) * s_val
     kv_pos = jnp.arange(nb_seq * bt)[None, None, None, :]

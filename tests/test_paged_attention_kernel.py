@@ -174,6 +174,55 @@ class TestKernelOracleEquivalence:
             paged_attention(q, k_pool[..., :-D], v_pool[..., :-D], tables,
                             lens, layer, interpret=True)
 
+    @pytest.mark.parametrize("kv_heads,dim", [
+        (2, 64),      # 5:1 over a 128-lane row: the walk, one chunk of lanes
+        (4, 32),      # 5:1, two KV heads a 128-lane chunk in the prefill
+        (5, 16),      # 2:1 over 80 lanes: the form with the groups on the grid
+        (8, 16),      # 1:1: the multi-head program, one query head a KV head
+    ])
+    @pytest.mark.parametrize("t_tokens", [1, 3, 40])
+    def test_grouped_query_heads_share_a_kv_head(self, kv_heads, dim,
+                                                 t_tokens):
+        """Query head ``h`` reads KV head ``h // R`` of a pool whose row
+        holds the KV heads alone: decode, a verify of 3 and a prefill of
+        40, a parked slot, lengths on and around block edges. Against the reference, which repeats the KV heads, and
+        against an explicit multi-head pool that holds every KV head ``R``
+        times: the same numbers from a fifth of the bytes."""
+        ratio = {2: 5, 4: 5, 5: 2, 8: 1}[kv_heads]
+        heads = kv_heads * ratio
+        lengths = [0, BT - 1, 2 * BT + 1, None] if t_tokens < 40 else [3]
+        _, k_pool, v_pool, tables, lens, layer = _setup(
+            lengths, t_tokens, seed=23, layers=2, layer=1, heads=kv_heads,
+            dim=dim, nb=8, pool_blocks=32)
+        q = jnp.asarray(np.random.default_rng(5).standard_normal(
+            (len(lengths), t_tokens, heads, dim)).astype(np.float32))
+        out = paged_attention(q, k_pool, v_pool, tables, lens, layer,
+                              interpret=True)
+        assert out.shape == q.shape
+        _assert_close(out, paged_attention_reference(
+            q, k_pool, v_pool, tables, lens, layer))
+        _assert_close(out, generate._paged_attend(
+            q, k_pool, v_pool, tables, lens, layer, scale=dim ** -0.5,
+            kernel="gather"))
+        spread = lambda pool: jnp.repeat(  # noqa: E731
+            pool.reshape(pool.shape[:3] + (kv_heads, dim)), ratio,
+            axis=3).reshape(pool.shape[:3] + (heads * dim,))
+        _assert_close(out, paged_attention_reference(
+            q, spread(k_pool), spread(v_pool), tables, lens, layer))
+
+    def test_a_query_head_on_the_wrong_kv_head_is_seen(self):
+        """The map shifted by one group moves the output: the test above
+        would not pass on a kernel that gave every head KV head 0."""
+        _, k_pool, v_pool, tables, lens, layer = _setup(
+            [2 * BT + 1], 1, seed=3, heads=2, dim=64)
+        q = jnp.asarray(np.random.default_rng(1).standard_normal(
+            (1, 1, 10, 64)).astype(np.float32))
+        out = paged_attention(q, k_pool, v_pool, tables, lens, layer,
+                              interpret=True)
+        shifted = paged_attention(jnp.roll(q, 5, axis=2), k_pool, v_pool,
+                                  tables, lens, layer, interpret=True)
+        assert float(jnp.abs(jnp.roll(shifted, -5, axis=2) - out).max()) > 0.1
+
     def test_scale_override(self):
         ops = _setup([11], 1, seed=5)
         out = paged_attention(*ops, scale=0.25, interpret=True)
