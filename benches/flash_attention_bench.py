@@ -5,6 +5,12 @@ under the 512 bound handed here, O(L) memory) against
 the XLA dense path (O(L²) memory) across sequence lengths (B=4, H=12, D=64,
 bf16, causal). On this installation: not measured.
 
+The kernels alone at the shapes the two train cells hand them (16 sequences
+of 16 heads and 8 of 25, 1,024 positions, one q block a head), operands in
+the layout the kernels take (``[B, H*D, L]``), in DEVICE milliseconds a call
+read from a profiler trace: a host clock around a 0.6 ms call measures the
+dispatch (PERF.md §6, PR 41, has the table this reproduces).
+
 Also benches the paged-attention decode kernel (block-table-native, scalar
 prefetch) against the gather reference that materializes the whole
 ``[S, max_len, H, D]`` cache per step — the serve-engine roofline story.
@@ -23,7 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops.flash_attention import _dense_reference, flash_attention
+from ray_tpu.ops.flash_attention import (_blocks, _dense_reference,
+                                         _flash_backward, _flash_forward,
+                                         _lay, flash_attention)
 from ray_tpu.ops.paged_attention import (paged_attention,
                                          paged_attention_reference)
 
@@ -38,6 +46,52 @@ def _bench(fn, *args, iters=20):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e3
+
+
+def _device_ms(fn, *args, kernel: str, iters=20) -> float:
+    """Device ms a call of the operations named ``kernel`` in ``fn``, read
+    from a profiler trace with the benchmark's own reader."""
+    import glob
+    import shutil
+    import tempfile
+
+    from benchmark.reduce import trace
+
+    for _ in range(2):
+        jax.block_until_ready(fn(*args))
+    out_dir = tempfile.mkdtemp(prefix="flash_bench_")
+    jax.profiler.start_trace(out_dir)
+    for _ in range(iters):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    jax.profiler.stop_trace()
+    [path] = glob.glob(f"{out_dir}/plugins/profile/*/*.xplane.pb")
+    seconds = trace.op_seconds(trace.load_xplane(path, ()), kernel)["seconds"]
+    shutil.rmtree(out_dir, ignore_errors=True)
+    return round(seconds * 1e3 / iters, 4)
+
+
+def bench_train_shapes() -> None:
+    """``flash_fwd`` and ``flash_bwd`` alone at the train cells' shapes."""
+    for b, l, h, d in ((16, 1024, 16, 64), (8, 1024, 25, 64)):
+        q, k, v, g = (_lay(jax.random.normal(kk, (b, l, h, d), jnp.bfloat16))
+                      for kk in jax.random.split(jax.random.key(0), 4))
+        bq, bk_fwd, bk_bwd = _blocks(l, l, l, l, True)
+        kw = dict(heads=h, scale=d ** -0.5, causal=True, block_q=bq,
+                  interpret=False)
+        fwd = jax.jit(lambda q, k, v: _flash_forward(
+            q, k, v, block_k=bk_fwd, **kw))
+        o, lse = fwd(q, k, v)
+        delta = jnp.sum((g.astype(jnp.float32) * o.astype(jnp.float32))
+                        .reshape(b, h, d, l), axis=2)[:, :, None, :]
+        bwd = jax.jit(lambda q, k, v, g: _flash_backward(
+            q, k, v, g, lse, delta, block_k=bk_bwd, **kw))
+        print(json.dumps({
+            "metric": f"flash_kernels_b{b}_l{l}_h{h}_d{d}",
+            "blocks": [bq, bk_fwd, bk_bwd],
+            "fwd_device_ms": _device_ms(fwd, q, k, v, kernel="flash_fwd"),
+            "bwd_device_ms": _device_ms(bwd, q, k, v, g, kernel="flash_bwd"),
+            "platform": jax.devices()[0].platform}))
 
 
 def bench_paged(quick: bool) -> None:
@@ -94,6 +148,8 @@ def main():
     if args.skip_flash or args.quick:
         return
     on_tpu = jax.devices()[0].platform != "cpu"
+    if on_tpu:
+        bench_train_shapes()
     seqs = (1024, 2048, 4096) if on_tpu else (256,)
     for L in seqs:
         ks = jax.random.split(jax.random.key(0), 3)

@@ -7,7 +7,8 @@ d_model 768, 12 heads, vocab 50257, context 1024, bf16), random weights from
 a seed:
 
 - *kernels*: ``flash_attention`` forward and gradients against its dense
-  reference, ``paged_attention`` (decode, verify, prefill at every bucket)
+  reference (at the model's shape under 512-blocks and at the shape
+  gpt2-medium's train step hands it, one q block a head), ``paged_attention`` (decode, verify, prefill at every bucket)
   against its gather reference, on random inputs at the model's shapes, and
   ``latent_paged_attention`` against its own at the two latent cells' shapes
   (64 heads on 640-lane rows; 128 slots x 64 table entries, 96 x 192);
@@ -70,26 +71,31 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     from ray_tpu.serve.llm import _default_buckets
 
     ctx, H, D, dt = cfg.max_seq_len, cfg.n_heads, cfg.head_dim, cfg.dtype
-    scale = D ** -0.5
     errs = {}
 
-    q, k, v, g = (jax.random.normal(kk, (2, ctx, H, D), dt)
-                  for kk in jax.random.split(jax.random.key(SEED), 4))
-    blk = min(512, ctx)
+    # The model's shape under 512-blocks (two q blocks a head: the looped
+    # walk), and the shape gpt2-medium's train step hands the kernels (16
+    # sequences of 16 heads, one q block a head: no loop at all).
+    step_shape = (1, ctx, H, D) if interpret else (16, 1024, 16, 64)
+    for tag, shape, blk in (("flash", (2, ctx, H, D), min(512, ctx)),
+                            ("flash_step", step_shape, step_shape[1])):
+        q, k, v, g = (jax.random.normal(kk, shape, dt)
+                      for kk in jax.random.split(jax.random.key(SEED), 4))
+        scale = shape[3] ** -0.5
 
-    def out_and_grads(attn):
-        def f(q, k, v):
-            out, vjp = jax.vjp(attn, q, k, v)
-            return (out,) + vjp(g)
-        return jax.jit(f)(q, k, v)
+        def out_and_grads(attn):
+            def f(q, k, v):
+                out, vjp = jax.vjp(attn, q, k, v)
+                return (out,) + vjp(g)
+            return jax.jit(f)(q, k, v)
 
-    got = out_and_grads(lambda q, k, v: flash_attention(
-        q, k, v, True, scale, blk, blk, interpret))
-    with jax.default_matmul_precision("highest"):
-        want = out_and_grads(lambda q, k, v: _dense_reference(
-            q, k, v, scale=scale, causal=True))
-    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
-        errs[f"flash_{name}"] = _rel_err(a, b)
+        got = out_and_grads(lambda q, k, v: flash_attention(
+            q, k, v, True, scale, blk, blk, interpret))
+        with jax.default_matmul_precision("highest"):
+            want = out_and_grads(lambda q, k, v: _dense_reference(
+                q, k, v, scale=scale, causal=True))
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            errs[f"{tag}_{name}"] = _rel_err(a, b)
 
     bt = int(knobs().serve_kv_block_tokens)
     nb_seq = ctx // bt

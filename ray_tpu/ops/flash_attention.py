@@ -5,20 +5,38 @@ torch/framework kernels; TPU-native it is two Pallas kernels, ``flash_fwd`` and
 ``flash_bwd``, integrated via ``jax.custom_vjp``. O(L) memory: no L×L
 probability matrix is ever materialized.
 
+**The kernels take the array where the projections leave it.** The interface
+is ``[B, L, H, D]``; the kernels' operands and results are ``[B, H*D, L]``:
+a head is ``D`` whole sublanes, positions lie on the lanes. That is the order
+in which XLA lays what a projection writes on the chip (the compiled train
+step holds ``bf16[B,L,H,D]{1,3,2,0}``: L minor, then D, then H), so ``_lay``
+and ``_unlay``, a reshape and a transpose in the program, move nothing there,
+and neither does the way back into the output projection and the gradients'
+products. A kernel that asks for ``[B*H, L, D]`` or for ``[B, L, H*D]``
+row-major makes XLA turn every operand and result, 32 MB each at the train
+step's shape. One head is one grid step, a block ``(1, D, L)``
+cut out of the ``H*D`` axis by its ``BlockSpec``: any number of heads, any
+``D`` that is whole sublane tiles (16 rows in bfloat16). No padded lanes in
+HBM either: a ``[.., L, 64]`` array is laid 128 lanes wide.
+
 **A score tile is ``[kv chunk, q block]``**: keys on the sublanes, queries on
 the lanes. Every per-query statistic (the running max ``m``, the sum ``l``,
 ``lse``, ``delta``) is then a lane-dense ``[1, block_q]`` row of a few vector
 registers, where the query-major tile made it a ``[block_q, 1]`` column that
 costs as many registers as a 128-wide tile and is touched ten times a tile;
 reductions over keys are elementwise across registers, and ``p`` and ``ds``
-enter the products that follow them as they lie. The output accumulator is
-kept transposed, ``[D, block_q]``, and turned once a q block.
+enter the products that follow them as they lie. With positions on the lanes
+every accumulator (``o`` and ``dq`` ``[D, block_q]``, ``dk`` and ``dv``
+``[D, span]``) is lane-dense and is written out as it lies; the one operand
+turned is the ``[D, chunk]`` of ``k`` (and of ``v`` in the backward) that a
+score tile contracts over its sublanes, by the product itself (turning a
+head's ``k`` once a grid step into scratch measured 3% slower).
 
 **Operands meet in the dtype they arrive in** (bfloat16 in the train step:
 exact products summed in float32 by ``preferred_element_type``); ``p`` and
 ``ds`` are rounded to that dtype as operands, as every other product of the
 block rounds its operands. ``m``, ``l``, ``lse``, ``delta``, the ``exp`` and
-the accumulators stay float32. ``scale`` rides the ``[block_q, D]`` q operand
+the accumulators stay float32. ``scale`` rides the ``[D, block_q]`` q operand
 when that is exact (a power of two: 1/8 at D = 64), else the float32 scores.
 
 **Causality is walked, not masked**: a head's K and V sit in VMEM whole, and
@@ -31,20 +49,25 @@ and masked by one static lower-triangle compare. With one q block a head
 tiles, the scores computed 1 + block_k / L of what causality needs.
 
 **The backward is one kernel** in place of flash-attention-2's two: scores,
-``p``, ``dp`` and ``ds`` once a tile, five products (``dv += pᵀ·dO``,
-``dk += dsᵀ·q``, ``dq += ds·k`` and the two that make ``p`` and ``dp``) where
+``p``, ``dp`` and ``ds`` once a tile, five products (``dv += dO·pᵀ``,
+``dk += q·dsᵀ``, ``dq += k·ds`` and the two that make ``p`` and ``dp``) where
 a dQ and a dK/dV kernel make seven and two ``exp``. ``dk`` and ``dv`` of the
 head accumulate in float32 VMEM over the q blocks.
-``delta = rowsum(dO * O)`` is a cheap elementwise reduce left to XLA fusion.
+``delta = rowsum(dO * O)`` is a cheap elementwise reduce left to XLA fusion,
+over ``O`` as the forward returned it: the saved residual is that array.
 
 **Which lengths take which grid.** Up to ``_RESIDENT_ROWS`` (2,048) keys the
-grid is ``(batch*heads, 1, 1)``: one step a head. Beyond that a head's blocks and
-accumulators (~4.5 KB a key) outgrow the 16 MB of scoped VMEM, and the kv axis
-goes back onto the grid in spans of ``_RESIDENT_ROWS`` keys: ``flash_fwd``
-carries ``m``, ``l`` and the accumulator across a q block's spans (innermost,
-sequential), ``flash_bwd`` runs the q blocks inside a span and hands back one
-float32 ``dq`` a span for XLA to add. Same kernels, same walk: chosen from
-the shape alone.
+grid is ``(batch, heads, 1, 1)``: one step a head. A key then costs VMEM
+128 B an operand or result block (``D`` = 64 bfloat16 rows of one lane),
+twice for the pipeline's second buffer, and 256 B a float32 accumulator:
+~1.3 KB a key in the forward, ~2.3 KB in the backward, with the
+``[chunk, block_q]`` float32 tiles beside them: 5.8 and 5.5 MB at 2,048 keys
+(compile-only; 2.8 and 2.6 MB at the train step's 1,024) of the 16 MB of scoped
+VMEM. Beyond that the kv axis goes back onto the grid in spans of
+``_RESIDENT_ROWS`` keys: ``flash_fwd`` carries ``m``, ``l`` and the
+accumulator across a q block's spans (innermost, sequential), ``flash_bwd``
+runs the q blocks inside a span and hands back one float32 ``dq`` a span for
+XLA to add. Same kernels, same walk: chosen from the shape alone.
 
 Sequence lengths must divide the blocks: a ragged length raises (there is
 no padded kernel); ``models.transformer``'s ``attn_impl="auto"`` picks the
@@ -69,9 +92,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-# Block rule (a sweep of the kernels alone at bf16[256, 1024, 64] on a v5e,
-# PERF.md §6, PR 37): constants of the shape, upper-bounded by the caller's
-# ``block_q`` / ``block_k``.
+# Block rule (sweeps of the kernels alone on a v5e: at bf16[256, 1024, 64],
+# PERF.md §6, PR 37; again at bf16[16, 1024, 1024], positions on the lanes,
+# PR 41): constants of the shape, upper-bounded by the caller's ``block_q`` /
+# ``block_k``.
 # Queries a tile (lanes): the widest that divides the sequence. A tile's fixed
 # costs (loop step, accumulator read-modify-write, the MXU's weight loads) are
 # paid once however wide it is, and one q block a head leaves no loop at all.
@@ -79,10 +103,12 @@ _BLOCK_Q = 1024
 # Keys a chunk (sublanes). The diagonal wastes block_k / 2 scores a query and a
 # smaller chunk pays a tile's fixed costs more often. The forward's fixed cost
 # is large (the [D, block_q] accumulator rescaled and rewritten every tile)
-# and a wasted score costs it two products: 512. The backward has no running
-# statistic to carry and a wasted score costs it five products: 128.
+# and a wasted score costs it two products: 512 (0.63 ms a call against 0.72
+# at 256 and 0.78 at 1,024). The backward has no running statistic to carry
+# and a wasted score costs it five products: 256 (0.88 ms against 0.93 at 128
+# and 0.99 at 512).
 _BLOCK_K_FWD = 512
-_BLOCK_K_BWD = 128
+_BLOCK_K_BWD = 256
 # Keys a head may hold in VMEM whole (see the module docstring).
 _RESIDENT_ROWS = 2048
 
@@ -106,6 +132,12 @@ def _split_scale(scale: float):
 
 def _aligned(x, multiple: int):
     return x if isinstance(x, int) else pl.multiple_of(x, multiple)
+
+
+def _lanes(row0, lane0: int, lanes: int, block_q: int):
+    """Positions ``[row0 + lane0, + lanes)`` of a q block that starts at
+    ``row0``, a multiple of ``block_q``; ``lane0`` is static."""
+    return pl.ds(_aligned(row0 + lane0, math.gcd(block_q, lane0)), lanes)
 
 
 def _when(cond, fn):
@@ -150,10 +182,11 @@ def _walk(tile, q_start, span_index, *, causal, block_q, block_k, span,
     _when(True if n_spans == 1 else q_start // span == span_index, crossed)
 
 
-def _scores(q, k, post, masked):
-    """``[kv chunk, queries]`` float32 scores; ``masked``: the chunk's first
-    key is the queries' first position, so key j is seen by query i >= j."""
-    s = _dot(k, q, _A_BT)
+def _scores(k, q, post, masked):
+    """``[kv chunk, queries]`` float32 scores of ``k`` ``[D, chunk]`` and
+    ``q`` ``[D, queries]``; ``masked``: the chunk's first key is the queries'
+    first position, so key j is seen by query i >= j."""
+    s = _dot(k, q, _AT_B)
     if post != 1.0:
         s = s * post
     if masked:
@@ -164,9 +197,9 @@ def _scores(q, k, post, masked):
 
 
 def _flash_kernel(
-    q_ref, k_ref, v_ref,    # [1, nq * block_q, d], [1, span, d] x 2
-    o_ref,                  # [1, nq * block_q, d]
-    lse_ref,                # [1, nq, 1, block_q]
+    q_ref, k_ref, v_ref,    # [1, d, nq * block_q], [1, d, span] x 2
+    o_ref,                  # [1, d, nq * block_q]
+    lse_ref,                # [1, 1, 1, nq * block_q]
     m_scr, l_scr, acc_scr,  # VMEM f32: [1, block_q] x 2, [d, block_q]
     *,
     scale: float,
@@ -177,8 +210,8 @@ def _flash_kernel(
     span: int,
     n_spans: int,
 ):
-    group = pl.program_id(1)
-    span_index = 0 if n_spans == 1 else pl.program_id(2)
+    group = pl.program_id(2)
+    span_index = 0 if n_spans == 1 else pl.program_id(3)
     pre, post = _split_scale(scale)
     first = True if n_spans == 1 else span_index == 0
     last = True if n_spans == 1 else span_index == n_spans - 1
@@ -194,12 +227,11 @@ def _flash_kernel(
         _when(first, init)
 
         def tile(lane0, lanes, k_local, masked):
-            q = q_ref[0, pl.ds(row0 + lane0, lanes), :]
+            q = q_ref[0, :, _lanes(row0, lane0, lanes, block_q)]
             if pre != 1.0:
                 q = q * pre
-            k = k_ref[0, pl.ds(k_local, block_k), :]
-            v = v_ref[0, pl.ds(k_local, block_k), :]
-            s = _scores(q, k, post, masked)                  # [bk, lanes]
+            keys = pl.ds(k_local, block_k)
+            s = _scores(k_ref[0, :, keys], q, post, masked)  # [bk, lanes]
             cols = slice(lane0, lane0 + lanes)
             m_prev = m_scr[:, cols]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=0, keepdims=True))
@@ -207,8 +239,9 @@ def _flash_kernel(
             p = jnp.exp(s - m_new)
             l_scr[:, cols] = (alpha * l_scr[:, cols]
                               + jnp.sum(p, axis=0, keepdims=True))
-            acc_scr[:, cols] = (acc_scr[:, cols] * alpha
-                                + _dot(v, p.astype(v.dtype), _AT_B))
+            acc_scr[:, cols] = (
+                acc_scr[:, cols] * alpha
+                + _dot(v_ref[0, :, keys], p.astype(v_ref.dtype), _A_B))
             m_scr[:, cols] = m_new
 
         _walk(tile, q_start, span_index, causal=causal, block_q=block_q,
@@ -216,9 +249,9 @@ def _flash_kernel(
 
         def finalize():
             denom = jnp.maximum(l_scr[:], 1e-30)
-            o_ref[0, pl.ds(row0, block_q), :] = (
-                acc_scr[:] / denom).T.astype(o_ref.dtype)
-            lse_ref[0, t] = m_scr[:] + jnp.log(denom)
+            at = pl.ds(row0, block_q)
+            o_ref[0, :, at] = (acc_scr[:] / denom).astype(o_ref.dtype)
+            lse_ref[0, 0, :, at] = m_scr[:] + jnp.log(denom)
         _when(last, finalize)
         return carry
 
@@ -253,43 +286,43 @@ def _grid(lq: int, lk: int, block_q: int):
 
 def _flash_forward(
     q: jax.Array, k: jax.Array, v: jax.Array,
-    *, scale: float, causal: bool, block_q: int, block_k: int,
+    *, heads: int, scale: float, causal: bool, block_q: int, block_k: int,
     interpret: bool,
 ):
-    """q/k/v: [BH, L, D] (batch*heads flattened). Returns (o, lse):
-    o [BH, L, D], lse [BH, L // block_q, 1, block_q] (row log-sum-exp of
-    scaled scores, a lane-dense row a q block)."""
-    bh, lq, d = q.shape
-    lk = k.shape[1]
+    """q/k/v: [B, H*D, L]. Returns (o, lse): o [B, H*D, L], lse [B, H, 1, L]
+    (row log-sum-exp of scaled scores, a lane-dense row a head)."""
+    b, hd, lq = q.shape
+    lk = k.shape[2]
+    d = hd // heads
     span, n_spans, nq = _grid(lq, lk, block_q)
 
-    def kv_block(b, i, s):
+    def kv_block(b, h, i, s):
         if causal and n_spans > 1:
             # A step past its q block's diagonal walks nothing: it re-reads
             # the span it has, which is no copy.
             s = jnp.minimum(s, (i * block_q + block_q - 1) // span)
-        return b, s, 0
+        return b, h, s
 
     kernel = functools.partial(
         _flash_kernel,
         scale=scale, causal=causal, block_q=block_q, block_k=block_k,
         nq=nq, span=span, n_spans=n_spans,
     )
-    q_spec = pl.BlockSpec((1, nq * block_q, d), lambda b, i, s: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, span, d), kv_block)
+    q_spec = pl.BlockSpec((1, d, nq * block_q), lambda b, h, i, s: (b, h, i))
+    kv_spec = pl.BlockSpec((1, d, span), kv_block)
     return pl.pallas_call(
         kernel,
         name="flash_fwd",
-        grid=(bh, lq // (nq * block_q), n_spans),
+        grid=(b, heads, lq // (nq * block_q), n_spans),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[
             q_spec,
-            pl.BlockSpec((1, nq, 1, block_q), lambda b, i, s: (b, i, 0, 0)),
+            pl.BlockSpec((1, 1, 1, nq * block_q),
+                         lambda b, h, i, s: (b, h, 0, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lq, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, lq // block_q, 1, block_q),
-                                 jnp.float32),
+            jax.ShapeDtypeStruct((b, hd, lq), q.dtype),
+            jax.ShapeDtypeStruct((b, heads, 1, lq), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((1, block_q), jnp.float32),
@@ -301,11 +334,11 @@ def _flash_forward(
 
 
 def _bwd_kernel(
-    q_ref, k_ref, v_ref, g_ref,   # [1, nq * block_q, d] (q, g), [1, span, d]
-    lse_ref, delta_ref,           # [1, nq, 1, block_q] f32
-    dk_ref, dv_ref,               # [1, span, d]
-    dq_ref,                       # [1, nq * block_q, d]
-    dk_scr, dv_scr,               # VMEM f32 [span, d]
+    q_ref, k_ref, v_ref, g_ref,   # [1, d, nq * block_q] (q, g), [1, d, span]
+    lse_ref, delta_ref,           # [1, 1, 1, nq * block_q] f32
+    dk_ref, dv_ref,               # [1, d, span]
+    dq_ref,                       # [1, d, nq * block_q]
+    dk_scr, dv_scr,               # VMEM f32 [d, span]
     dq_scr,                       # VMEM f32 [d, block_q]
     *,
     scale: float,
@@ -320,8 +353,8 @@ def _bwd_kernel(
     """One kv span against the q blocks of one group: ``dk`` and ``dv``
     accumulate over the groups (innermost on the grid), ``dq`` of a q block
     over the span's chunks."""
-    span_index = 0 if n_spans == 1 else pl.program_id(1)
-    group = pl.program_id(2)
+    span_index = 0 if n_spans == 1 else pl.program_id(2)
+    group = pl.program_id(3)
     pre, post = _split_scale(scale)
 
     def init():
@@ -335,27 +368,25 @@ def _bwd_kernel(
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
         def tile(lane0, lanes, k_local, masked):
-            rows = pl.ds(row0 + lane0, lanes)
+            at = _lanes(row0, lane0, lanes, block_q)
             keys = pl.ds(k_local, block_k)
-            q = q_ref[0, rows, :]
+            q = q_ref[0, :, at]
             if pre != 1.0:
                 q = q * pre
-            g = g_ref[0, rows, :]
-            k = k_ref[0, keys, :]
-            v = v_ref[0, keys, :]
-            s = _scores(q, k, post, masked)                  # [bk, lanes]
+            g = g_ref[0, :, at]
+            s = _scores(k_ref[0, :, keys], q, post, masked)  # [bk, lanes]
             cols = slice(lane0, lane0 + lanes)
-            p = jnp.exp(s - lse_ref[0, t, :, cols])
-            dp = _dot(v, g, _A_BT)                           # [bk, lanes]
-            ds = (p * (dp - delta_ref[0, t, :, cols])).astype(k.dtype)
-            dv_scr[keys, :] += _dot(p.astype(g.dtype), g, _A_B)
-            dk_scr[keys, :] += _dot(ds, q, _A_B)     # q carries ``pre``
-            dq_scr[:, cols] += _dot(k, ds, _AT_B)            # [d, lanes]
+            p = jnp.exp(s - lse_ref[0, 0, :, at])
+            dp = _dot(v_ref[0, :, keys], g, _AT_B)           # [bk, lanes]
+            ds = (p * (dp - delta_ref[0, 0, :, at])).astype(q.dtype)
+            dv_scr[:, keys] += _dot(g, p.astype(g.dtype), _A_BT)  # [d, bk]
+            dk_scr[:, keys] += _dot(q, ds, _A_BT)    # q carries ``pre``
+            dq_scr[:, cols] += _dot(k_ref[0, :, keys], ds, _A_B)
 
         _walk(tile, q_start, span_index, causal=causal, block_q=block_q,
               block_k=block_k, span=span, n_spans=n_spans)
-        dq_ref[0, pl.ds(row0, block_q), :] = (
-            dq_scr[:] * scale).T.astype(dq_ref.dtype)
+        dq_ref[0, :, pl.ds(row0, block_q)] = (
+            dq_scr[:] * scale).astype(dq_ref.dtype)
         return carry
 
     if nq == 1:
@@ -373,23 +404,22 @@ def _bwd_kernel(
 
 
 def _flash_backward(
-    q, k, v, g, o, lse,
-    *, scale: float, causal: bool, block_q: int, block_k: int,
+    q, k, v, g, lse, delta,
+    *, heads: int, scale: float, causal: bool, block_q: int, block_k: int,
     interpret: bool,
 ):
-    """All inputs [BH, L, D] (lse as ``_flash_forward`` returns it); returns
-    (dq, dk, dv)."""
-    bh, lq, d = q.shape
-    lk = k.shape[1]
+    """q, k, v, g: [B, H*D, L]; lse as ``_flash_forward`` returns it and
+    ``delta = rowsum(dO * O)`` in its shape; returns (dq, dk, dv)."""
+    batch, hd, lq = q.shape
+    lk = k.shape[2]
+    d = hd // heads
     span, n_spans, nq = _grid(lq, lk, block_q)
     n_groups = lq // (nq * block_q)
-    # delta_i = Σ_d dO_id · O_id — cheap rowwise reduce; XLA fuses it.
-    delta = jnp.sum(g.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(lse.shape)
 
-    q_spec = pl.BlockSpec((1, nq * block_q, d), lambda b, s, i: (b, i, 0))
-    kv_spec = pl.BlockSpec((1, span, d), lambda b, s, i: (b, s, 0))
-    row_spec = pl.BlockSpec((1, nq, 1, block_q), lambda b, s, i: (b, i, 0, 0))
+    q_spec = pl.BlockSpec((1, d, nq * block_q), lambda b, h, s, i: (b, h, i))
+    kv_spec = pl.BlockSpec((1, d, span), lambda b, h, s, i: (b, h, s))
+    row_spec = pl.BlockSpec((1, 1, 1, nq * block_q),
+                            lambda b, h, s, i: (b, h, 0, i))
     dk, dv, dq = pl.pallas_call(
         functools.partial(
             _bwd_kernel, scale=scale, causal=causal, block_q=block_q,
@@ -397,30 +427,30 @@ def _flash_backward(
             n_groups=n_groups,
         ),
         name="flash_bwd",
-        grid=(bh, n_spans, n_groups),
+        grid=(batch, heads, n_spans, n_groups),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
         out_specs=[
             kv_spec, kv_spec,
             # A span's share of dq: one array a span, added below.
-            pl.BlockSpec((1, nq * block_q, d),
-                         lambda b, s, i: (s * bh + b, i, 0)),
+            pl.BlockSpec((1, d, nq * block_q),
+                         lambda b, h, s, i: (s * batch + b, h, i)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, lk, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, lk, d), v.dtype),
+            jax.ShapeDtypeStruct((batch, hd, lk), k.dtype),
+            jax.ShapeDtypeStruct((batch, hd, lk), v.dtype),
             jax.ShapeDtypeStruct(
-                (n_spans * bh, lq, d),
+                (n_spans * batch, hd, lq),
                 q.dtype if n_spans == 1 else jnp.float32),
         ],
         scratch_shapes=[
-            pltpu.VMEM((span, d), jnp.float32),
-            pltpu.VMEM((span, d), jnp.float32),
+            pltpu.VMEM((d, span), jnp.float32),
+            pltpu.VMEM((d, span), jnp.float32),
             pltpu.VMEM((d, block_q), jnp.float32),
         ],
         interpret=interpret,
     )(q, k, v, g, lse, delta)
     if n_spans > 1:
-        dq = dq.reshape(n_spans, bh, lq, d).sum(axis=0).astype(q.dtype)
+        dq = dq.reshape(n_spans, batch, hd, lq).sum(axis=0).astype(q.dtype)
     return dq, dk, dv
 
 
@@ -446,20 +476,24 @@ def flash_attention(
     interpret: bool = False,
 ) -> jax.Array:
     """Multi-head attention, [B, L, H, D] layout (matches
-    ``models.transformer``). Heads fold into the grid's batch dim.
-    ``block_q`` / ``block_k`` are upper bounds: the blocks run are the block
-    rule's (``_blocks``)."""
+    ``models.transformer``). A head is a grid step; the kernels read and
+    write ``[B, H*D, L]`` (``_lay``). ``block_q`` / ``block_k`` are upper
+    bounds: the blocks run are the block rule's (``_blocks``)."""
     return _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret)[0]
 
 
-def _fold(x):
+def _lay(x):
+    """``[B, L, H, D]`` as the kernels take it, ``[B, H*D, L]``: a head is
+    ``D`` whole sublanes, positions lie on the lanes. On the chip no data
+    moves: it is how XLA lays the array a projection writes (see the module
+    docstring)."""
     b, l, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, l, d)
+    return x.reshape(b, l, h * d).transpose(0, 2, 1)
 
 
-def _unfold(x, b, h):
-    bh, l, d = x.shape
-    return x.reshape(b, h, l, d).transpose(0, 2, 1, 3)
+def _unlay(x, shape):
+    """``_lay``'s inverse, to ``shape`` = ``(B, L, H, D)``."""
+    return x.transpose(0, 2, 1).reshape(shape)
 
 
 def _blocks(lq: int, lk: int, block_q: int, block_k: int, causal: bool):
@@ -489,27 +523,31 @@ def _blocks(lq: int, lk: int, block_q: int, block_k: int, causal: bool):
 
 
 def _fa_fwd(q, k, v, causal, scale, block_q, block_k, interpret):
-    b, l, h, d = q.shape
+    heads, d = q.shape[2:]
     s = scale if scale is not None else 1.0 / d**0.5
-    bq, bk, _ = _blocks(l, k.shape[1], block_q, block_k, causal)
-    qf, kf, vf = _fold(q), _fold(k), _fold(v)
-    of, lse = _flash_forward(
-        qf, kf, vf,
+    bq, bk, _ = _blocks(q.shape[1], k.shape[1], block_q, block_k, causal)
+    o, lse = _flash_forward(
+        _lay(q), _lay(k), _lay(v), heads=heads,
         scale=s, causal=causal, block_q=bq, block_k=bk, interpret=interpret,
     )
-    return _unfold(of, b, h), (q, k, v, of, lse)
+    o = _unlay(o, q.shape)
+    return o, (q, k, v, o, lse)
 
 
 def _fa_bwd(causal, scale, block_q, block_k, interpret, res, g):
-    q, k, v, of, lse = res
-    b, l, h, d = q.shape
+    q, k, v, o, lse = res
+    heads, d = q.shape[2:]
     s = scale if scale is not None else 1.0 / d**0.5
-    bq, _, bk = _blocks(l, k.shape[1], block_q, block_k, causal)
-    dqf, dkf, dvf = _flash_backward(
-        _fold(q), _fold(k), _fold(v), _fold(g), of, lse,
+    bq, _, bk = _blocks(q.shape[1], k.shape[1], block_q, block_k, causal)
+    # delta_i = Σ_d dO_id · O_id, a row a head as ``lse`` has them: a cheap
+    # reduce left to XLA, over ``o`` as it was returned.
+    delta = jnp.einsum("blhd,blhd->bhl", g.astype(jnp.float32),
+                       o.astype(jnp.float32))[:, :, None, :]
+    dq, dk, dv = _flash_backward(
+        _lay(q), _lay(k), _lay(v), _lay(g), lse, delta, heads=heads,
         scale=s, causal=causal, block_q=bq, block_k=bk, interpret=interpret,
     )
-    return _unfold(dqf, b, h), _unfold(dkf, b, h), _unfold(dvf, b, h)
+    return _unlay(dq, q.shape), _unlay(dk, k.shape), _unlay(dv, v.shape)
 
 
 flash_attention.defvjp(_fa_fwd, _fa_bwd)

@@ -216,6 +216,103 @@ class TestFlashBf16:
             assert _rel_err(a, b) <= self.REL_TOL
 
 
+class TestFlashHeadsInPlace:
+    """The kernels cut a head out of ``[B, H*D, L]`` by their block specs
+    (PR 41): any number of heads, even or odd, any head width, and a width
+    that makes ``H*D`` no multiple of 128 go the same way, with no head
+    folded into the batch. Forward AND gradients against the float32 oracle
+    at ``chip_smoke.py``'s criterion; two q blocks a head, the diagonal
+    crossing chunks inside a block."""
+
+    REL_TOL = 2e-2
+
+    # (heads, head width): pairs of 64-wide heads (2, 4, 16), an odd head
+    # left over (3, 5: gpt2-xl has 25), one head a lane tile (D = 128),
+    # H*D = 144 and 96 (no multiple of 128), a single head.
+    @pytest.mark.parametrize("h,d", [(2, 64), (4, 64), (16, 64), (3, 64),
+                                     (5, 64), (2, 128), (3, 48), (3, 32),
+                                     (1, 64)])
+    @pytest.mark.parametrize("causal", [True, False])
+    def test_forward_and_backward_match_f32_oracle(self, causal, h, d):
+        ks = jax.random.split(jax.random.key(17 * h + d), 4)
+        q, k, v, g = (jax.random.normal(kk, (2, 256, h, d), jnp.float32)
+                      .astype(jnp.bfloat16) for kk in ks)
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal, None, 128, 64,
+                                            True), q, k, v)
+        got = (out,) + vjp(g)
+        assert all(x.dtype == jnp.bfloat16 and x.shape == q.shape
+                   for x in got)
+        want = _f32_oracle(q, k, v, g, causal)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+            assert _rel_err(a, b) <= self.REL_TOL, (name, _rel_err(a, b))
+
+    @pytest.mark.parametrize("causal", [True, False])
+    @pytest.mark.parametrize("h", [2, 3])
+    def test_spans_with_heads_side_by_side(self, monkeypatch, causal, h):
+        """The spans path (``_RESIDENT_ROWS`` shrunk) at bfloat16 with the
+        heads of one lane tile, and an odd one, side by side in the array."""
+        from ray_tpu.ops import flash_attention as fa
+
+        monkeypatch.setattr(fa, "_RESIDENT_ROWS", 128)
+        assert fa._grid(512, 512, 128)[1] == 4  # spans
+        ks = jax.random.split(jax.random.key(h), 4)
+        q, k, v, g = (jax.random.normal(kk, (1, 512, h, 64), jnp.float32)
+                      .astype(jnp.bfloat16) for kk in ks)
+        out, vjp = jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, causal, None, 128, 64,
+                                            True), q, k, v)
+        want = _f32_oracle(q, k, v, g, causal)
+        for name, a, b in zip(("out", "dq", "dk", "dv"), (out,) + vjp(g),
+                              want):
+            assert _rel_err(a, b) <= self.REL_TOL, (name, _rel_err(a, b))
+
+    def test_neighbouring_heads_do_not_leak(self):
+        """Head 0 has ``v`` all zeros, head 1 has not: head 0's output is
+        zero to the bit, and so are ``dq`` and ``dk`` of head 0 (its scores
+        move nothing) while its ``dv`` is not; head 1 reads as it does
+        alone."""
+        ks = jax.random.split(jax.random.key(41), 4)
+        q, k, v, g = (jax.random.normal(kk, (1, 256, 2, 64), jnp.float32)
+                      .astype(jnp.bfloat16) for kk in ks)
+        v = v.at[:, :, 0, :].set(0)
+
+        def attend(q, k, v):
+            return flash_attention(q, k, v, True, None, 128, 64, True)
+
+        out, vjp = jax.vjp(attend, q, k, v)
+        dq, dk, dv = vjp(g)
+        for name, x in (("out", out), ("dq", dq), ("dk", dk)):
+            assert not np.asarray(x[:, :, 0, :], np.float32).any(), name
+        assert np.asarray(dv[:, :, 0, :], np.float32).any()
+        alone, vjp1 = jax.vjp(attend, q[:, :, 1:], k[:, :, 1:], v[:, :, 1:])
+        for a, b in zip((out, dq, dk, dv), (alone,) + vjp1(g[:, :, 1:])):
+            np.testing.assert_array_equal(
+                np.asarray(a[:, :, 1:], np.float32),
+                np.asarray(b, np.float32))
+
+    def test_kernels_read_and_write_heads_by_positions(self):
+        """What the two Pallas calls are handed: ``[B, H*D, L]`` operands
+        and first outputs, three-dimensional bfloat16 (the benchmark's
+        ``flash_attn_roofline`` finds the kernels by that), statistics
+        ``[B, H, 1, L]`` float32; one grid step a head."""
+        q = jnp.zeros((2, 256, 3, 64), jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: jax.vjp(
+            lambda q, k, v: flash_attention(q, k, v, True, None, 256, 256,
+                                            True), q, k, v)[1](q))(q, q, q)
+        calls = {e.params["name"]: e for e in jaxpr.jaxpr.eqns
+                 if e.primitive.name == "pallas_call"}
+        assert sorted(calls) == ["flash_bwd", "flash_fwd"]
+        for name, n_in in (("flash_fwd", 3), ("flash_bwd", 4)):
+            call = calls[name]
+            assert list(call.params["grid_mapping"].grid) == [2, 3, 1, 1]
+            for var in (*call.invars[:n_in], call.outvars[0]):
+                assert var.aval.shape == (2, 3 * 64, 256), (name, var.aval)
+                assert var.aval.dtype == jnp.bfloat16
+        assert calls["flash_fwd"].outvars[1].aval.shape == (2, 3, 1, 256)
+        assert calls["flash_fwd"].outvars[1].aval.dtype == jnp.float32
+
+
 class TestFlashSpans:
     """Past ``_RESIDENT_ROWS`` keys a head's K and V no longer sit in VMEM
     whole: the kv axis goes onto the grid in spans, the forward carrying its
@@ -266,13 +363,13 @@ class TestBlockRule:
         # (q block, forward chunk, backward chunk) under no bound but the
         # rule's: one q block a head up to 1,024, else the widest multiple
         # of 128 that divides; the chunk divides the q block.
-        assert _blocks(1024, 1024, 1024, 1024, True) == (1024, 512, 128)
-        assert _blocks(2048, 2048, 2048, 2048, True) == (1024, 512, 128)
+        assert _blocks(1024, 1024, 1024, 1024, True) == (1024, 512, 256)
+        assert _blocks(2048, 2048, 2048, 2048, True) == (1024, 512, 256)
         assert _blocks(1280, 1280, 1280, 1280, True) == (640, 128, 128)
         assert _blocks(1152, 1152, 1152, 1152, True) == (384, 384, 128)
         assert _blocks(128, 128, 512, 512, True) == (128, 128, 128)
         # the arguments are upper bounds
-        assert _blocks(1024, 1024, 512, 512, True) == (512, 512, 128)
+        assert _blocks(1024, 1024, 512, 512, True) == (512, 512, 256)
         assert _blocks(1024, 1024, 256, 64, True) == (256, 64, 64)
         assert _blocks(256, 256, 64, 32, True) == (64, 32, 32)
 

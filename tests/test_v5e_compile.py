@@ -186,6 +186,73 @@ def weight_shaped_data_movers(hlo: str, shapes) -> list:
     return found
 
 
+def flash_layout_movers(hlo: str, heads: int, seq: int,
+                        head_dim: int) -> list:
+    """[[op kind, instruction, shape], ...]: what carrying ``[B, L, H, D]``
+    to a flash kernel's own layout and back leaves in an optimized module
+    (``_fold`` / ``_unfold``, until PR 41). (1) Every instruction outside the
+    fusions' bodies that writes a bfloat16 array ``[b, heads, seq,
+    head_dim]``: the projections' products labelled head-major and the
+    copies that turned them. (2) Every ``copy`` or ``transpose``, or fusion
+    of nothing but such, that a flash kernel reads an operand from or that
+    reads a flash kernel's result, through bitcasts and tuple elements: a
+    kernel that asks for a layout XLA does not write (``[B, L, H*D]``
+    row-major was one) is handed copies whatever their shape. Only
+    bfloat16 arrays count: q, k, v, o and their gradients; the float32
+    statistics are a 4 KB row a head, and a ``copy`` of those is the
+    compiler's prefetch into VMEM. A kernel that takes the array where the
+    projections leave it has neither."""
+    head_major = re.compile(rf"^\(?bf16\[\d+,{heads},{seq},{head_dim}\]")
+    bodies, instrs, current = {}, {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?%([\w\-.]+) \(.*\{\s*$", line)
+        if head:
+            current = bodies.setdefault(head.group(1), [])
+        elif current is not None and _INSTR.match(line):
+            name, shape, op = _INSTR.match(line).groups()
+            args = line.split(f" {op}(", 1)[1].split(")", 1)[0]
+            current.append(name)
+            instrs[name] = (op, shape, re.findall(r"%([\w\-.]+)", args),
+                            line)
+    fused = {called for _op, _shape, _args, line in instrs.values()
+             for called in re.findall(r"fusion\(.*calls=%([\w\-.]+)", line)}
+
+    def source(name):
+        while name in instrs and instrs[name][0] in ("bitcast",
+                                                     "get-tuple-element"):
+            name = instrs[name][2][0]
+        return name
+
+    def moves(name):
+        op, shape, _args, line = instrs.get(name, ("", "", [], ""))
+        if not shape.lstrip("(").startswith("bf16["):
+            return False
+        if op == "fusion":
+            called = re.search(r"calls=%([\w\-.]+)", line).group(1)
+            return all(instrs[inner][0] in _MOVES
+                       or instrs[inner][0] in _NO_DATA
+                       for inner in bodies[called])
+        return op in ("copy", "transpose")
+
+    def is_flash(name):
+        return (name.startswith("flash_") and name in instrs
+                and "tpu_custom_call" in instrs[name][3])
+
+    found = set()
+    for computation, names in bodies.items():
+        if computation in fused:
+            continue
+        for name in names:
+            op, shape, args, _line = instrs[name]
+            if head_major.match(shape) and op not in _NO_DATA:
+                found.add(name)
+            if is_flash(name):
+                found |= {source(a) for a in args if moves(source(a))}
+            elif moves(name) and any(is_flash(source(a)) for a in args):
+                found.add(name)
+    return sorted([instrs[n][0], n, instrs[n][1][:60]] for n in found)
+
+
 def named_ops(hlo: str, pattern: str) -> list:
     """[[name, ``op_name`` metadata], ...] of the instructions outside the
     fusions' bodies whose name, as the profiler gives it and the benchmark
@@ -283,6 +350,7 @@ def compile_all() -> dict:
     "latent_calls": {name: [calls of the latent kernel's jit, distinct
     traced bodies among them]},
     "flash_products": {name: pallas_products() of the flash kernels},
+    "flash_movers": {name: flash_layout_movers() of a program that runs them},
     "latent_vmem": {name: scoped VMEM of each latent kernel call}}."""
     import jax
     import jax.numpy as jnp
@@ -315,7 +383,7 @@ def compile_all() -> dict:
     programs, kernels, pool_movers, temp_bytes = {}, {}, {}, {}
     need_bytes, grids, scoped_vmem, state_movers = {}, {}, {}, {}
     state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
-    latent_calls, latent_vmem, flash_products = {}, {}, {}
+    latent_calls, latent_vmem, flash_products, flash_movers = {}, {}, {}, {}
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
@@ -332,6 +400,8 @@ def compile_all() -> dict:
             compiled = traced.lower().compile()
             text = compiled.as_text()
             kernels[name] = sorted(set(_KERNEL.findall(text)))
+            if flash_products[name]:
+                flash_movers[name] = flash_layout_movers(text, H, ctx, D)
             scoped_vmem[name] = [
                 int(n) for line in text.splitlines()
                 if "tpu_custom_call" in line for n in _SCOPED.findall(line)]
@@ -613,7 +683,7 @@ def compile_all() -> dict:
             "state_roundings": state_roundings,
             "shared_expert_ops": shared_expert_ops,
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
-            "flash_products": flash_products}
+            "flash_products": flash_products, "flash_movers": flash_movers}
 
 
 @pytest.fixture(scope="module")
@@ -670,10 +740,11 @@ def test_flash_kernels_multiply_in_the_input_type(verdict):
     to that type), and every one sums in float32: two products in the
     forward, five in the one backward kernel (a kernel's loop body and its
     straight-line diagonal chunks each carry the set)."""
-    # One step a head while its K and V fit VMEM; (heads, q blocks, spans)
-    # and (heads, spans, q blocks) past that.
-    assert verdict["grids"]["flash_fwd_bwd"] == [[2 * 12, 1, 1]] * 2
-    assert verdict["grids"]["flash_fwd_bwd_long"] == [[2, 4, 2], [2, 2, 4]]
+    # One step a head (batch, heads: a block of the ``H*D`` axis) while its
+    # K and V fit VMEM; then (q blocks, spans) and (spans, q blocks) past that.
+    assert verdict["grids"]["flash_fwd_bwd"] == [[2, 12, 1, 1]] * 2
+    assert verdict["grids"]["flash_fwd_bwd_long"] == [[1, 2, 4, 2],
+                                                      [1, 2, 2, 4]]
     products = verdict["flash_products"]["flash_fwd_bwd"]
     assert sorted(products) == ["flash_bwd", "flash_fwd"], products
     for kernel, per_tile in (("flash_fwd", 2), ("flash_bwd", 5)):
@@ -681,6 +752,78 @@ def test_flash_kernels_multiply_in_the_input_type(verdict):
         assert dots and len(dots) % per_tile == 0, (kernel, len(dots))
         assert all(dot == ["bfloat16", "bfloat16", "float32"]
                    for dot in dots), (kernel, dots)
+
+
+@pytest.mark.parametrize("program", ["flash_fwd_bwd", "train_step_data4",
+                                     "train_step_data2_tensor2"])
+def test_flash_kernels_take_the_array_where_the_projections_leave_it(
+        verdict, program):
+    """No ``copy``, ``transpose`` or layout fusion between the projections
+    and the flash kernels, either way, and no ``bf16[B,H,L,D]`` left: the
+    kernels read and write ``[B, H*D, L]``, which is how XLA lays what the
+    ``bld,dhk->blhk`` products write (PR 41; ``_fold`` / ``_unfold`` paid
+    ten such copies a layer)."""
+    assert verdict["flash_movers"][program] == []
+
+
+def test_flash_layout_scan_sees_what_fold_and_unfold_did():
+    """The scan itself, on what the parent's compiled train step held
+    (compile-only, PR 41), on a kernel that asks for ``[B, L, H*D]``
+    row-major, and on the program as it is now."""
+    kernel = 'custom_call_target="tpu_custom_call"'
+    folded = f"""
+%fused_computation.5 (p0: bf16[4,1024,768], p1: bf16[768,12,64]) -> bf16[4,12,1024,64] {{
+  %p0 = bf16[4,1024,768]{{1,2,0}} parameter(0)
+  %p1 = bf16[768,12,64]{{0,2,1}} parameter(1)
+  ROOT %convolution.1 = bf16[4,12,1024,64]{{2,3,1,0}} convolution(%p0, %p1), dim_labels=0bf_io0->0bf
+}}
+ENTRY %main (x: bf16[4,1024,768], w: bf16[768,12,64]) -> bf16[4,12,1024,64] {{
+  %x = bf16[4,1024,768]{{1,2,0}} parameter(0)
+  %w = bf16[768,12,64]{{0,2,1}} parameter(1)
+  %fusion.516 = bf16[4,12,1024,64]{{2,3,1,0:T(8,128)(2,1)}} fusion(%x, %w), kind=kOutput, calls=%fused_computation.5
+  %copy.289 = bf16[4,12,1024,64]{{3,2,1,0:T(8,128)(2,1)}} copy(%fusion.516)
+  %bitcast.1 = bf16[48,1024,64]{{2,1,0:T(8,128)(2,1)}} bitcast(%copy.289)
+  %flash_fwd.3 = (bf16[48,1024,64]{{2,1,0}}, f32[48,1,1,1024]{{3,2,1,0}}) custom-call(%bitcast.1, %bitcast.1, %bitcast.1), {kernel}
+  %get-tuple-element.7 = bf16[48,1024,64]{{2,1,0}} get-tuple-element(%flash_fwd.3), index=0
+  %bitcast.2 = bf16[4,12,1024,64]{{3,2,1,0}} bitcast(%get-tuple-element.7)
+  ROOT %copy.292 = bf16[4,12,1024,64]{{2,3,1,0}} copy(%bitcast.2)
+}}
+"""
+    assert [(op, name) for op, name, _s in flash_layout_movers(
+        folded, 12, 1024, 64)] == [
+        ("copy", "copy.289"), ("copy", "copy.292"), ("fusion", "fusion.516")]
+    row_major = f"""
+%fused_computation.9 (p0: bf16[4,1024,12,64]) -> bf16[4,1024,768] {{
+  %p0 = bf16[4,1024,12,64]{{1,3,2,0}} parameter(0)
+  %bitcast.8 = bf16[4,1024,768]{{1,2,0}} bitcast(%p0)
+  ROOT %copy.3 = bf16[4,1024,768]{{2,1,0}} copy(%bitcast.8)
+}}
+ENTRY %main (q: bf16[4,1024,12,64]) -> bf16[4,1024,768] {{
+  %q = bf16[4,1024,12,64]{{1,3,2,0}} parameter(0)
+  %bitcast.537 = bf16[4,1024,768]{{1,2,0}} bitcast(%q)
+  %copy.284 = bf16[4,1024,768]{{2,1,0}} copy(%bitcast.537)
+  %copy_fusion.2 = bf16[4,1024,768]{{2,1,0}} fusion(%q), kind=kLoop, calls=%fused_computation.9
+  %flash_fwd.16 = (bf16[4,1024,768]{{2,1,0}}, f32[4,12,1,1024]{{3,2,1,0}}) custom-call(%copy.284, %copy_fusion.2, %copy.284), {kernel}
+  %pallas_call.59 = bf16[4,1024,768]{{2,1,0}} get-tuple-element(%flash_fwd.16), index=0
+  %copy.287 = bf16[4,1024,768]{{1,2,0}} copy(%pallas_call.59)
+  %copy.9 = bf16[4,1024,768]{{1,2,0}} copy(%bitcast.537)
+  ROOT %add.1 = bf16[4,1024,768]{{1,2,0}} add(%copy.287, %copy.9)
+}}
+"""
+    assert [(op, name) for op, name, _s in flash_layout_movers(
+        row_major, 12, 1024, 64)] == [
+        ("copy", "copy.284"), ("copy", "copy.287"),
+        ("fusion", "copy_fusion.2")]        # not copy.9: no kernel's
+    in_place = f"""
+ENTRY %main (q: bf16[4,1024,12,64]) -> bf16[4,1024,12,64] {{
+  %q = bf16[4,1024,12,64]{{1,3,2,0}} parameter(0)
+  %bitcast.5 = bf16[4,768,1024]{{2,1,0}} bitcast(%q)
+  %flash_fwd.16 = (bf16[4,768,1024]{{2,1,0}}, f32[4,12,1,1024]{{3,2,1,0}}) custom-call(%bitcast.5, %bitcast.5, %bitcast.5), {kernel}
+  %pallas_call.59 = bf16[4,768,1024]{{2,1,0}} get-tuple-element(%flash_fwd.16), index=0
+  ROOT %bitcast.6 = bf16[4,1024,12,64]{{1,3,2,0}} bitcast(%pallas_call.59)
+}}
+"""
+    assert flash_layout_movers(in_place, 12, 1024, 64) == []
 
 
 @pytest.mark.parametrize("num_blocks", SERVE_POOLS)
