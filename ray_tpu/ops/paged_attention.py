@@ -51,6 +51,20 @@ the K/V bytes a step reads are the KV heads', not the query heads'. Below,
 "heads" of the pool and of a lane chunk are KV heads; the accumulators' rows
 and the output are the query heads'.
 
+A window (``window=W``: a sliding-window layer) gives the walk a LOWER
+bound too: query ``i`` sees key ``j`` iff ``j <= i`` and ``i - j < W``, so a
+tile starts at the block that holds its first query's oldest visible key and
+starts no copy of a block wholly behind it; the window's trailing edge is
+masked inside a block. The table is then read MODULO its width, so that a
+slot's rows may be a RING of ``nb_seq`` blocks (logical block ``b`` lies in
+entry ``b mod nb_seq``; the ring holds at least ``W + T - 1`` rows and a
+block more, so that the blocks a tile attends are distinct entries) whatever
+the context: what a window layer keeps for a slot is bounded by the window.
+A table that covers the whole context (a prefill over its fresh rows) is the
+same walk, its modulo the identity. With no window the traced body is the
+one it was, byte for byte (``tests/test_paged_attention_kernel.py`` holds
+its digest).
+
 The pool is the WHOLE model's, ``[L, num_blocks, bt, KV*D]``: heads folded
 into the lane dimension, so a block is one dense ``[bt, KV*D]`` tile in the
 layout the array already has in HBM, read where it lies: a Mosaic call
@@ -115,15 +129,25 @@ def _last_block(first_pos, q_tile: int, block_tokens: int):
 
 
 def _tile_last_block(lengths_ref, s, i, q_tile: int, total: int,
-                     block_tokens: int, nb_seq: int):
+                     block_tokens: int, nb_seq: int, ring: bool = False):
     """The last table entry that query tile ``i`` of slot ``s`` attends, by
     its last REAL query: of ``total`` queries the last tile may hold fewer
     than ``q_tile``, and its pad queries, like a slot at capacity, would pass
-    the slot's live blocks or the table's end."""
+    the slot's live blocks or the table's end. ``ring``: the LOGICAL block,
+    which a table read modulo its width has no end for."""
     real = jnp.minimum(q_tile, total - i * q_tile)
-    return jnp.minimum(
-        _last_block(lengths_ref[s] + i * q_tile, real, block_tokens),
-        nb_seq - 1)
+    last = _last_block(lengths_ref[s] + i * q_tile, real, block_tokens)
+    return last if ring else jnp.minimum(last, nb_seq - 1)
+
+
+def _tile_first_block(lengths_ref, s, i, q_tile: int, block_tokens: int,
+                      window: int):
+    """The block that holds the oldest key tile ``i``'s FIRST query sees
+    through a window of ``window`` positions (itself included): no query of
+    the tile sees a block before it."""
+    return jax.lax.div(
+        jnp.maximum(lengths_ref[s] + i * q_tile - (window - 1), 0),
+        block_tokens)
 
 
 def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int,
@@ -174,14 +198,16 @@ def _blocks_per_group(block_tokens: int, width: int, itemsize: int,
 
 def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
                   scale: float, num_heads: int, kv_heads: int, q_tile: int,
-                  head_dim: int, group_tokens: int):
+                  head_dim: int, group_tokens: int,
+                  window: Optional[int] = None):
     """One online-softmax update over group ``g`` of a slot's kv positions,
     ``[g * group_tokens, (g+1) * group_tokens)``. ``k_rows(d0, d1)`` and
     ``v_rows(d0, d1)`` give the group's ``[group_tokens, d1 - d0]`` lanes.
     The ``num_heads`` query heads share the pool's ``kv_heads`` in
     consecutive runs (query head ``h`` reads KV head ``h // R``): a KV
     head's ``R`` query heads are ``R * q_tile`` rows of the same dot, so a
-    fetched block is read once for all of them."""
+    fetched block is read once for all of them. ``window``: a key more than
+    ``window - 1`` positions behind its query is masked too."""
     KV, T, D = kv_heads, q_tile, head_dim
     C = q_ref.shape[-1] // D                   # KV heads per lane chunk
     RT = num_heads // KV * T                   # rows a KV head brings
@@ -193,6 +219,8 @@ def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, group_tokens), 0)
     q_pos = ctx + (0 if T == 1 else jax.lax.rem(row, T))
     mask_all = kv_pos <= q_pos
+    if window is not None:
+        mask_all = jnp.logical_and(mask_all, kv_pos > q_pos - window)
     for c0 in range(0, KV, C):                   # static unroll
         c = min(C, KV - c0)                      # KV heads of this chunk
         d0, d1 = c0 * D, (c0 + c) * D            # their lanes
@@ -255,6 +283,7 @@ def _walk_live_groups(
     nb_seq: int,
     group_blocks: int,
     unroll_full: bool = False,
+    window: Optional[int] = None,
 ):
     """The walk both kernels share: grid step ``(s, i)`` resets its
     accumulators and loops over the groups of ``G`` table entries its query
@@ -264,23 +293,36 @@ def _walk_live_groups(
     first position ``ctx``, the half the group lies in and ``fetched``
     ``[G*bt, 1]``, which rows of it were brought in. ``unroll_full``: a full
     group's copies are started and awaited as ``G`` straight-line DMAs, not
-    by a loop (see ``live_copies``)."""
+    by a loop (see ``live_copies``). ``window``: the walk starts at the
+    group of the tile's first visible block (``_tile_first_block``), copies
+    no entry before that block, and reads the table modulo ``nb_seq`` (a
+    ring); group indices stay LOGICAL, so ``attend`` masks by position as
+    ever."""
     s = pl.program_id(0)
     i = pl.program_id(1)
     n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
     bt, T, G = block_tokens, q_tile, group_blocks
     layer = layer_ref[0]
+    ring = window is not None
+    if ring and unroll_full:
+        raise ValueError("the windowed walk starts its copies in a loop")
 
     def last_block(s_, i_):
-        return _tile_last_block(lengths_ref, s_, i_, T, total, bt, nb_seq)
+        return _tile_last_block(lengths_ref, s_, i_, T, total, bt, nb_seq,
+                                ring)
 
     def live_entries(last, g):
         """How many of group ``g``'s ``G`` entries are live, 0..G, where the
         tile's last live entry is ``last``."""
         return jnp.clip(last - g * G + 1, 0, G)
 
-    def live_copies(s_, g, n_live, half, act):
-        """``act`` on every pool's copy of the ``n_live`` live entries of
+    def dead_entries(first, g):
+        """How many of group ``g``'s leading entries lie wholly behind the
+        window, 0..G, where the tile's first visible entry is ``first``."""
+        return jnp.clip(first - g * G, 0, G)
+
+    def live_copies(s_, g, n_live, half, act, n_dead=0):
+        """``act`` on every pool's copy of entries ``n_dead .. n_live`` of
         group ``g`` of slot ``s_``, into rows ``j * bt`` of buffer ``half``.
         Start and wait both go through here, so a wait meets exactly the
         copies that were started. (A loop, not an unrolled ``pl.when`` an
@@ -292,14 +334,15 @@ def _walk_live_groups(
         straight-line copies at static offsets (~33 ns each: PERF.md, PR 34)
         and leaves the loop to the last."""
         def entry(j, _):
-            blk = tables_ref[s_, g * G + j]
+            b = g * G + j
+            blk = tables_ref[s_, jax.lax.rem(b, nb_seq) if ring else b]
             rows = pl.ds(pl.multiple_of(j * bt, bt), bt)
             for n, (pool, buf) in enumerate(zip(pools, bufs)):
                 act(pltpu.make_async_copy(
                     pool.at[layer, blk], buf.at[half, rows], sems.at[n, half]))
 
         if not unroll_full:
-            jax.lax.fori_loop(0, n_live, entry, None)
+            jax.lax.fori_loop(n_dead, n_live, entry, None)
             return
 
         @pl.when(n_live == G)
@@ -311,13 +354,23 @@ def _walk_live_groups(
         def _last():
             jax.lax.fori_loop(0, n_live, entry, None)
 
+    def first_group(s_, i_):
+        """(first visible entry, its group) of a tile; the walk's start."""
+        first = _tile_first_block(lengths_ref, s_, i_, T, bt, window)
+        return first, jax.lax.div(first, G)
+
     last_blk = last_block(s, i)
     n_groups = jax.lax.div(last_blk + G, G)           # ceil((last_blk+1)/G)
+    if ring:
+        first_blk, g0 = first_group(s, i)
 
     @pl.when(jnp.logical_and(s == 0, i == 0))
     def _first_step():
         half_ref[0] = 0
-        live_copies(s, 0, live_entries(last_blk, 0), 0, lambda c: c.start())
+        first_g = g0 if ring else 0
+        live_copies(s, first_g, live_entries(last_blk, first_g), 0,
+                    lambda c: c.start(),
+                    *([dead_entries(first_blk, first_g)] if ring else []))
 
     first_half = half_ref[0]
     _init_accumulators(*accumulators)
@@ -326,12 +379,15 @@ def _walk_live_groups(
     # slot's first (clamped where there is none; ``has_next`` guards it).
     more_tiles = i + 1 < n_tiles
     next_s = jnp.minimum(jnp.where(more_tiles, s, s + 1), n_slots - 1)
-    next_last = last_block(next_s, jnp.where(more_tiles, i + 1, 0))
+    next_i = jnp.where(more_tiles, i + 1, 0)
+    next_last = last_block(next_s, next_i)
     has_next = jnp.logical_or(more_tiles, s + 1 < n_slots)
     row = jax.lax.broadcasted_iota(jnp.int32, (G * bt, 1), 0)
+    if ring:
+        next_first, next_g0 = first_group(next_s, next_i)
 
     def group(g, _):
-        half = jax.lax.rem(first_half + g, 2)
+        half = jax.lax.rem(first_half + (g - g0 if ring else g), 2)
         # Start what comes next into the other half before computing on this
         # one: this step's next group, or the FIRST group of the next step,
         # so that no step opens with an exposed DMA.
@@ -339,22 +395,29 @@ def _walk_live_groups(
 
         @pl.when(jnp.logical_or(in_step, has_next))
         def _prefetch():
-            next_g = jnp.where(in_step, g + 1, 0)
+            next_g = jnp.where(in_step, g + 1, next_g0 if ring else 0)
             live_copies(
                 jnp.where(in_step, s, next_s), next_g,
                 live_entries(jnp.where(in_step, last_blk, next_last), next_g),
-                1 - half, lambda c: c.start())
+                1 - half, lambda c: c.start(),
+                *([dead_entries(jnp.where(in_step, first_blk, next_first),
+                                next_g)] if ring else []))
 
         n_live = live_entries(last_blk, g)
-        live_copies(s, g, n_live, half, lambda c: c.wait())
-        # Entries past ``last_blk`` (the last group's tail) were not fetched:
-        # their rows hold an earlier group's data, or nothing yet.
+        n_dead = dead_entries(first_blk, g) if ring else 0
+        live_copies(s, g, n_live, half, lambda c: c.wait(), n_dead)
+        # Entries past ``last_blk`` (the last group's tail) were not fetched,
+        # nor those behind a window: their rows hold an earlier group's
+        # data, or nothing yet.
         fetched = row < n_live * bt
+        if ring:
+            fetched = jnp.logical_and(fetched, row >= n_dead * bt)
         attend(g, lengths_ref[s] + i * T, half, fetched)
 
-    jax.lax.fori_loop(0, n_groups, group, None)
+    jax.lax.fori_loop(g0 if ring else 0, n_groups, group, None)
     # The half the prefetched first group of the next step went into.
-    half_ref[0] = jax.lax.rem(first_half + n_groups, 2)
+    half_ref[0] = jax.lax.rem(
+        first_half + (n_groups - g0 if ring else n_groups), 2)
 
 
 def _paged_kernel(
@@ -383,7 +446,8 @@ def _paged_kernel(
             g, ctx, m_scr, l_scr, acc_scr, scale=scale,
             num_heads=o_ref.shape[1], kv_heads=kv_heads,
             q_tile=walk["q_tile"], head_dim=o_ref.shape[-1],
-            group_tokens=walk["group_blocks"] * walk["block_tokens"])
+            group_tokens=walk["group_blocks"] * walk["block_tokens"],
+            window=walk.get("window"))
 
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
@@ -447,6 +511,7 @@ def paged_attention(
     *,
     scale: Optional[float] = None,
     interpret: bool = False,
+    window: Optional[int] = None,
 ) -> jax.Array:
     """Fused paged-attention over the block pool; returns [S, T, H, D].
 
@@ -459,8 +524,19 @@ def paged_attention(
     The pool's row holds ``KV`` heads of ``D``, ``KV`` a divisor of ``H``
     (grouped-query attention): query head ``h`` reads KV head ``h // (H //
     KV)``, and a fetched block serves all of a KV head's query heads. With
-    ``KV == H`` it is the multi-head program it always was."""
+    ``KV == H`` it is the multi-head program it always was.
+
+    ``window``: a sliding-window layer. Query ``i`` sees key ``j`` iff ``j <=
+    i`` and ``i - j < window``; the walk starts at the first visible block
+    and ``tables[s]`` is read modulo its width: a RING of ``NB`` blocks in
+    which position ``p`` lies in entry ``(p // bt) mod NB`` (the caller's to
+    keep at ``NB * bt >= window + T - 1 + bt`` rows, or to cover the whole
+    context with, as a prefill over its fresh rows does)."""
     S, T, H, D = q.shape
+    if window is not None and (window < 1 or k_pool.shape[3] % 128):
+        raise ValueError(
+            f"window {window} over a pool row of {k_pool.shape[3]} lanes: "
+            f"the windowed walk is the loop over whole 128-lane rows")
     if (k_pool.ndim != 4 or k_pool.shape[3] % D
             or H % max(1, k_pool.shape[3] // D)):
         raise ValueError(
@@ -471,12 +547,18 @@ def paged_attention(
         q, k_pool, v_pool, tables.astype(jnp.int32),
         lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
         scale=float(scale) if scale is not None else 1.0 / D**0.5,
-        interpret=interpret)
+        interpret=interpret, window=None if window is None else int(window))
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
+# Rows (query heads x queries) of a tile's float32 accumulators: m and l are
+# padded to 128 lanes, so three buffers of rows x 512 bytes. 30 heads of 128
+# at the full tile fit the 16 MB of scoped VMEM; 48 heads take half a tile.
+_Q_TILE_ROWS = 4096
+
+
+@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
-                     interpret):
+                     interpret, window=None):
     """:func:`paged_attention` on checked operands, ``layer`` an int32[1]
     VALUE: a jit of its own, so that a program that calls it once a layer
     (24 unrolled layers, eight serve programs) traces and lowers the kernel
@@ -489,6 +571,8 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
     nb_seq = tables.shape[1]
     qt = q.transpose(0, 2, 1, 3)                      # [S, H, T, D]
     tq = min(T, _Q_TILE)
+    while H * tq > _Q_TILE_ROWS and tq > 8:
+        tq //= 2
     q_tiles = pl.cdiv(T, tq)
     if T % tq:
         # Ragged last tile: pad queries are causally AHEAD of every real one
@@ -511,7 +595,11 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
         pltpu.VMEM((H * tq, 1), jnp.float32),
         pltpu.VMEM((H * tq, C * D), jnp.float32),
     ]
+    walk = dict(block_tokens=bt, q_tile=tq, total=T, nb_seq=nb_seq,
+                group_blocks=G)
     if W % 128 == 0:
+        if window is not None:
+            walk["window"] = window
         kernel, grid = _paged_kernel, (S, q_tiles)
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = accumulators + [
@@ -539,29 +627,50 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
-        functools.partial(
-            kernel, scale=scale, kv_heads=KV, block_tokens=bt, q_tile=tq,
-            total=T, nb_seq=nb_seq, group_blocks=G),
+        functools.partial(kernel, scale=scale, kv_heads=KV, **walk),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype),
         interpret=interpret,
-        # The name a profiler prints for the kernel, whatever calls it.
-        name="paged_decode_attn" if T == 1 else "paged_prefill_attn",
+        # The name a profiler prints for the kernel, whatever calls it: a
+        # windowed call has names of its own, so that a trace tells a stack's
+        # window layers from its full ones.
+        name=("paged" if window is None else "window")
+        + ("_decode_attn" if T == 1 else "_prefill_attn"),
     )(tables, lengths, layer, qw, *pools)
     return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
-                              scale: Optional[float] = None) -> jax.Array:
+                              scale: Optional[float] = None,
+                              window: Optional[int] = None) -> jax.Array:
     """Gather-path oracle over the same operands: materializes
     [S, NB*bt, H, D] of ``layer`` through the table and runs masked dense
     attention — numerically what the pre-kernel decode did, kept as the
-    equivalence target and the CPU fallback reference."""
+    equivalence target and the CPU fallback reference. ``window``: every
+    query gathers the ``window`` positions at and before its own through the
+    table read modulo its width (the ring of :func:`paged_attention`), so
+    [S, T, window, H, D]: an oracle and a CPU path, for no chip's sizes."""
     S, T, H, D = q.shape
     bt = k_pool.shape[2]
     nb_seq = tables.shape[1]
     s_val = scale if scale is not None else 1.0 / D**0.5
     KV = k_pool.shape[3] // D
+    q_pos = lengths.reshape(-1, 1) + jnp.arange(T)[None, :]         # [S, T]
+    if window is not None:
+        pos = q_pos[:, :, None] - jnp.arange(window)[None, None, :]  # [S, T, W]
+        seen = pos >= 0
+        pos = jnp.maximum(pos, 0)
+        blk = tables[jnp.arange(S)[:, None, None], (pos // bt) % nb_seq]
+        kc, vc = (p[layer, blk, pos % bt].reshape(S, T, window, KV, D)
+                  for p in (k_pool, v_pool))
+        if KV != H:
+            kc, vc = (jnp.repeat(a, H // KV, axis=3) for a in (kc, vc))
+        scores = jnp.einsum("bthd,btshd->bhts", q, kc,
+                            preferred_element_type=jnp.float32) * s_val
+        scores = jnp.where(seen[:, None], scores, _NEG_INF)
+        probs = jax.nn.softmax(scores, axis=-1)
+        out = jnp.einsum("bhts,btshd->bthd", probs, vc.astype(jnp.float32))
+        return out.astype(q.dtype)
     kc = k_pool[layer, tables].reshape(S, nb_seq * bt, KV, D)
     vc = v_pool[layer, tables].reshape(S, nb_seq * bt, KV, D)
     if KV != H:             # grouped: query head h reads KV head h // (H // KV)
@@ -569,9 +678,7 @@ def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
     scores = jnp.einsum("bthd,bshd->bhts", q, kc,
                         preferred_element_type=jnp.float32) * s_val
     kv_pos = jnp.arange(nb_seq * bt)[None, None, None, :]
-    q_pos = (lengths.reshape(-1, 1, 1, 1)
-             + jnp.arange(T)[None, None, :, None])
-    scores = jnp.where(kv_pos <= q_pos, scores, _NEG_INF)
+    scores = jnp.where(kv_pos <= q_pos[:, None, :, None], scores, _NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhts,bshd->bthd", probs, vc.astype(jnp.float32))
     return out.astype(q.dtype)

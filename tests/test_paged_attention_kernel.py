@@ -411,6 +411,206 @@ class TestNoGatherMaterialization:
         assert gathered in shapes(paged_attention_reference)
 
 
+# -- a walk with a LOWER bound: sliding-window layers over a ring ---------------
+
+def _dense_window(q, k, v, length, window):
+    """The oracle, written out: q [T, H, D] at positions ``length + t`` over
+    the sequence's own rows k / v [L, KV, D]; key j is seen by query i iff
+    j <= i and i - j < window; query head h reads KV head h // (H // KV)."""
+    T, heads, dim = q.shape
+    ratio = heads // k.shape[1]
+    out = np.zeros_like(q)
+    for t in range(T):
+        i = length + t
+        lo = max(0, i - window + 1)
+        for h in range(heads):
+            s = k[lo:i + 1, h // ratio] @ q[t, h] / np.sqrt(dim)
+            p = np.exp(s - s.max())
+            out[t, h] = (p / p.sum()) @ v[lo:i + 1, h // ratio]
+    return out
+
+
+def _ring_setup(lengths, t_tokens, window, bt, ring, *, heads=4, kv_heads=2,
+                dim=64, seed=0, poison=False):
+    """Every slot's rows written into a RING of ``ring`` blocks of ``bt``
+    (position p in entry (p // bt) mod ring of the slot's shuffled table,
+    later positions over earlier ones), in layer 1 of a two-layer pool.
+    ``poison``: every entry wholly behind the first query's window is NaN.
+    Returns (operands, the dense oracle's output)."""
+    rng = np.random.default_rng(seed)
+    S = len(lengths)
+    q = rng.standard_normal((S, t_tokens, heads, dim)).astype(np.float32)
+    shape = (2, S * ring + 1, bt, kv_heads * dim)
+    k_pool = rng.standard_normal(shape).astype(np.float32)
+    v_pool = rng.standard_normal(shape).astype(np.float32)
+    tables = np.zeros((S, ring), np.int32)
+    want = []
+    for s, ln in enumerate(lengths):
+        tables[s] = 1 + s * ring + rng.permutation(ring)
+        total = ln + t_tokens
+        k = rng.standard_normal((total, kv_heads, dim)).astype(np.float32)
+        v = rng.standard_normal((total, kv_heads, dim)).astype(np.float32)
+        for p in range(total):
+            entry = tables[s, (p // bt) % ring]
+            k_pool[1, entry, p % bt] = k[p].reshape(-1)
+            v_pool[1, entry, p % bt] = v[p].reshape(-1)
+        if poison:
+            first, last = max(0, ln - window + 1) // bt, (total - 1) // bt
+            live = {tables[s, b % ring] for b in range(first, last + 1)}
+            for entry in set(tables[s]) - live:
+                k_pool[1, entry] = v_pool[1, entry] = np.nan
+        want.append(_dense_window(q[s], k, v, ln, window))
+    ops = (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+           jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)), 1)
+    return ops, np.stack(want)
+
+
+class TestWindowedWalk:
+    """``paged_attention(window=W)``: the walk's lower bound, the window's
+    trailing edge masked inside a block, the table read modulo its width."""
+
+    @pytest.mark.parametrize("lengths,t_tokens,window,bt,ring", [
+        # decode: contexts under, at and over the window, across ring wraps
+        ([0, 3, 15, 16, 17, 40, 100], 1, 16, 8, 3),
+        ([0, 5, 31, 32, 33, 200], 1, 32, 16, 3),
+        ([0, 9, 300], 1, 16, 128, 2),          # one block a group
+        # prefill from position 0 over a table that covers the prompt
+        ([0], 40, 16, 8, 7), ([0], 64, 16, 8, 8), ([0], 128, 16, 16, 8),
+        ([0], 300, 64, 16, 19),                # three query tiles, the last ragged
+        # T > 1 from a nonzero start, with and without a wrap
+        ([7], 20, 16, 8, 5), ([100, 5], 3, 16, 8, 4),
+    ])
+    def test_against_a_dense_masked_oracle(self, lengths, t_tokens, window,
+                                           bt, ring):
+        ops, want = _ring_setup(lengths, t_tokens, window, bt, ring)
+        out = paged_attention(*ops, window=window, interpret=True)
+        _assert_close(out, want)
+        _assert_close(paged_attention_reference(*ops, window=window), want)
+
+    @pytest.mark.parametrize("lengths,t_tokens", [([17, 40, 100, 999], 1),
+                                                   ([100], 3)])
+    def test_no_block_wholly_behind_the_window_is_read(self, lengths, t_tokens):
+        """Every ring entry wholly behind the window holds NaN: a copied
+        block's rows, masked as keys, would still meet p = 0 as values, and
+        0 x NaN is NaN."""
+        ops, want = _ring_setup(lengths, t_tokens, 16, 8, 4, poison=True)
+        out = paged_attention(*ops, window=16, interpret=True)
+        assert np.isfinite(np.asarray(out)).all()
+        _assert_close(out, want)
+
+    def test_the_walks_bounds(self):
+        """What one decode step of a window layer may copy, counted from the
+        walk's own bounds: never more than the window's blocks and one, at
+        any context, where the unwindowed walk's count grows with it."""
+        from ray_tpu.ops.paged_attention import (_tile_first_block,
+                                                 _tile_last_block)
+
+        window, bt = 4096, 128
+        lengths = jnp.asarray([0, 1, 4095, 4096, 4097, 5000, 8191, 100000])
+        slots = jnp.arange(len(lengths))
+        first = np.asarray(jax.vmap(lambda s: _tile_first_block(
+            lengths, s, 0, 1, bt, window))(slots))
+        last = np.asarray(jax.vmap(lambda s: _tile_last_block(
+            lengths, s, 0, 1, 1, bt, 33, ring=True))(slots))
+        assert list(first) == [0, 0, 0, 0, 0, 7, 32, 749]
+        assert list(last) == [0, 0, 31, 32, 32, 39, 63, 781]
+        assert (last - first + 1).max() == 33 == window // bt + 1
+        clamped = np.asarray(jax.vmap(lambda s: _tile_last_block(
+            lengths, s, 0, 1, 1, bt, 33))(slots))
+        assert list(clamped) == [0, 0, 31, 32, 32, 32, 32, 32]
+
+    def test_a_window_over_an_unaligned_row_is_refused(self):
+        ops = _setup([5], 1, heads=5, dim=16)
+        with pytest.raises(ValueError, match="128-lane"):
+            paged_attention(*ops, window=8, interpret=True)
+
+    def test_forty_eight_heads_take_half_a_query_tile(self):
+        """A tile's float32 accumulators are (heads x queries) rows: past
+        4,096 rows the tile is halved, below it the tile is what it was."""
+        def grid(heads, kv_heads, t_tokens):
+            q = jax.ShapeDtypeStruct((1, t_tokens, heads, 128), jnp.bfloat16)
+            pool = jax.ShapeDtypeStruct((1, 9, 16, kv_heads * 128), jnp.bfloat16)
+            jaxpr = jax.make_jaxpr(lambda q, k, v: paged_attention(
+                q, k, v, jnp.zeros((1, 8), jnp.int32),
+                jnp.zeros((1,), jnp.int32), 0, interpret=True))(q, pool, pool)
+            found = []
+
+            def walk(jp):
+                for e in jp.eqns:
+                    if e.primitive.name == "pallas_call":
+                        found.append(tuple(e.params["grid_mapping"].grid))
+                    for v in e.params.values():
+                        if hasattr(v, "jaxpr"):
+                            walk(v.jaxpr)
+            walk(jaxpr.jaxpr)
+            return found
+        assert grid(48, 8, 256) == [(1, 4)]      # tiles of 64
+        assert grid(30, 30, 256) == [(1, 2)]     # tiles of 128, as ever
+        assert grid(20, 4, 256) == [(1, 2)]
+
+    # sha256 of ``str(jax.make_jaxpr(...))`` of the kernel path on the commit
+    # BEFORE the window went in (jax 0.9.0, matmul precision "highest" as
+    # conftest pins it). A PR that changes the unwindowed
+    # kernel on purpose takes new digests the same way; one that means to
+    # leave it alone (three cells run it) finds out here.
+    PARENT = {
+        (3, 1, 8, 8, 16, 8, 6, "float32"):
+            "9b13fb6bdabc2883f6b50d6b3b3f658e8456920dd54c69e141897c2c6ee49007",
+        (1, 40, 8, 8, 16, 8, 6, "float32"):
+            "c5f6a958042c3ceccfbaf309dbb6291d63d48a53af55a0e8f607faddf6e15692",
+        (4, 1, 20, 4, 128, 16, 8, "bfloat16"):
+            "00ff550acf632f1244da3641e40f359e52c8fee1d0f38c6049c831439a990295",
+        (1, 256, 20, 4, 128, 16, 16, "bfloat16"):
+            "8a8c69d93285c3ea86293e7ddae2b923bb50abe472fcfb57d8c617d38330870f",
+        (2, 1, 10, 5, 16, 8, 6, "float32"):
+            "a4a15332394375403485789e27cbffb72f0d49f39fe03259137aa2ffa4389b29",
+    }
+
+    @pytest.mark.parametrize("case", sorted(PARENT))
+    def test_without_a_window_the_traced_kernel_is_the_parents(self, case):
+        """Byte for byte: multi-head and grouped, decode and prefill, the
+        loop and the form with the groups on the grid."""
+        import hashlib
+
+        S, T, heads, kv_heads, dim, bt, nb, dtype = case
+        q = jax.ShapeDtypeStruct((S, T, heads, dim), dtype)
+        pool = jax.ShapeDtypeStruct((2, 40, bt, kv_heads * dim), dtype)
+        with jax.default_matmul_precision("highest"):
+            text = str(jax.make_jaxpr(lambda q, k, v, t, ln: paged_attention(
+                q, k, v, t, ln, 1, interpret=True))(
+                    q, pool, pool, jax.ShapeDtypeStruct((S, nb), jnp.int32),
+                    jax.ShapeDtypeStruct((S,), jnp.int32)))
+        assert hashlib.sha256(text.encode()).hexdigest() == self.PARENT[case]
+        assert "window" not in text
+
+
+    # The latent kernel runs the same walk (``_walk_live_groups``): its
+    # traced body too is the parent's.
+    PARENT_LATENT = {
+        (3, 1, 8, 128, 8, 6, "float32"):
+            "e2a273840c20fc54c89ddec480a683a9bc5bbccc8d0c1247d028db8708d6785a",
+        (1, 40, 8, 128, 8, 6, "float32"):
+            "4c0d1940381ee1704b25ca86423c284418f0705bdf26f09d40fe011990fb8569",
+        (4, 1, 64, 640, 16, 12, "bfloat16"):
+            "b861f925462d46cc879b9076a66f440b7ac3bd1a17105b4b2996cdc380d894a6",
+    }
+
+    @pytest.mark.parametrize("case", sorted(PARENT_LATENT))
+    def test_the_latent_kernels_traced_body_is_the_parents(self, case):
+        import hashlib
+
+        S, T, heads, width, bt, nb, dtype = case
+        q = jax.ShapeDtypeStruct((S, T, heads, width), dtype)
+        pool = jax.ShapeDtypeStruct((2, 40, bt, width), dtype)
+        with jax.default_matmul_precision("highest"):
+            text = str(jax.make_jaxpr(lambda q, p, t, ln: latent_paged_attention(
+                q, p, t, ln, 1, value_lanes=width // 2, scale=0.1,
+                interpret=True))(
+                    q, pool, jax.ShapeDtypeStruct((S, nb), jnp.int32),
+                    jax.ShapeDtypeStruct((S,), jnp.int32)))
+        assert hashlib.sha256(text.encode()).hexdigest() == self.PARENT_LATENT[case]
+
+
 class TestEngineKernelModes:
     def test_resolve_modes(self):
         assert generate.resolve_attention_kernel("gather") == "gather"

@@ -53,6 +53,13 @@ FALCON_SLOTS, FALCON_POOL, FALCON_BUCKET = 64, 3905, 1024
 KIMI_SLOTS, KIMI_POOL, KIMI_BUCKETS = 96, 16993, (2048, 3072)
 
 
+# Trinity-Large-Preview at the sizes of the cell
+# trinity-large-preview.window-decode: published widths, one dense and four
+# expert layers (four sliding, one full), 16 experts held, 64 slots, pool
+# 30785 x 16 for the ONE full layer, the 8192 bucket (the largest).
+TRINITY_SLOTS, TRINITY_POOL, TRINITY_BUCKET = 64, 30785, 8192
+
+
 # An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
 # custom-call(...), custom_call_target="tpu_custom_call"``. The profiler names
 # a device operation by this same text, and the benchmark's kernel metrics
@@ -656,6 +663,42 @@ def compile_all() -> dict:
                     arr((1, bucket), jnp.int32), i32, i32, i32, i32),
                 pool=k_geometry, shared=True)
 
+    # Trinity's serve programs whole, at the cell's own sizes: the window
+    # layers' kernel under a name of its own beside the full layer's, on
+    # rings viewed as blocks (no ring-sized copy), 48 query heads over a
+    # 1,024-lane row, and the bytes the chip must hold (2.51B bf16
+    # parameters, 4.43 GB of rings, a 2.02 GB pool).
+    from ray_tpu.models import afmoe
+
+    tcfg = afmoe.trinity_large_share()
+    tparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: afmoe.init_params(tcfg, key),
+                       jax.random.key(0)))
+    tgen = PagedGenerator(tparams, tcfg, slots=TRINITY_SLOTS,
+                          num_blocks=TRINITY_POOL, block_tokens=bt,
+                          attention_kernel="pallas")
+    tkv = arr((tcfg.n_layers, TRINITY_POOL, bt, tcfg.n_kv_heads * tcfg.head_dim))
+    tslot = tuple(arr(x.shape, x.dtype) for x in jax.eval_shape(
+        lambda: afmoe.init_slot_state(tcfg, TRINITY_SLOTS)))
+    tstate = (tparams, (tkv, tkv), tslot,
+              arr((TRINITY_SLOTS, tgen.logits_dim), jnp.float32),
+              arr((TRINITY_SLOTS, 2), jnp.uint32))
+    t_slot = lambda dtype: arr((TRINITY_SLOTS,), dtype)  # noqa: E731
+    t_geometry = (tcfg.n_layers, TRINITY_POOL, bt)
+    t_rings = (tcfg.window_layers, TRINITY_SLOTS, tcfg.ring_blocks)
+    attempt("trinity_decode",
+            lambda: tgen.decode_fn(8).trace(
+                *tstate, arr((TRINITY_SLOTS, tgen.blocks_per_seq), jnp.int32),
+                t_slot(jnp.int32), t_slot(jnp.bool_), t_slot(jnp.bool_),
+                t_slot(jnp.float32)), pool=t_geometry, state=t_rings,
+            weights=shapes_of(tparams))
+    attempt(f"trinity_prefill_{TRINITY_BUCKET}",
+            lambda: tgen.prefill_fn(TRINITY_BUCKET).trace(
+                *tstate, arr((tgen.blocks_per_seq,), jnp.int32),
+                arr((1, TRINITY_BUCKET), jnp.int32), i32, i32, i32, i32),
+            pool=t_geometry, state=t_rings)
+
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
     for name, spec in [("train_step_data4", MeshSpec(data=4)),
@@ -1022,6 +1065,44 @@ def test_falcon_h1_serve_programs_fit_the_chip(verdict, program, kernels):
         assert math.prod(shape) == 6 * 64 * 1_048_576 and shape[:2] == [6, 64]
         calls, roundings = verdict["state_roundings"][program]
         assert calls == 6 and roundings >= 6 * calls, (calls, roundings)
+
+
+@pytest.mark.parametrize("program,kernels,need", [
+    ("trinity_decode", {"window_decode_attn": "bf16[64,48,1,128]",
+                        "paged_decode_attn": "bf16[64,48,1,128]"},
+     (11.4e9, 11.8e9)),
+    ("trinity_prefill_8192", {"window_prefill_attn": "bf16[1,48,8192,128]",
+                              "paged_prefill_attn": "bf16[1,48,8192,128]"},
+     (12.9e9, 13.6e9))])
+def test_trinity_serve_programs_fit_the_chip(verdict, program, kernels, need):
+    """Trinity's ``paged_decode`` and its largest ``paged_prefill`` at the
+    sizes of ``trinity-large-preview.window-decode``: they compile for a v5e
+    (48 query heads of 128: a prefill tile of 64 queries keeps the kernel's
+    accumulators inside the 16 MB of scoped VMEM), arguments plus
+    temporaries stay under 14 GB (the check's float32 pass at 8,192 tokens
+    runs beside the 11.5 GB resident). The window layers' kernel runs under a
+    name of its OWN, so that a trace tells the two kinds of attention apart;
+    both have all 48 query heads in their output over a pool row of the 8 KV
+    heads. No instruction copies or slices data the size of the pool, of the
+    rings (viewed as blocks by a reshape that must stay free) or, in the
+    decode program, of a weight."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    # weights 5.02 GB + rings 4.43 GB + pool 2.02 GB
+    assert need[0] < verdict["need_bytes"][program] < need[1], verdict["need_bytes"]
+    found = {n: s for n, s in verdict["kernels"][program]
+             if not n.startswith("ragged-dot")}
+    assert found == kernels, verdict["kernels"][program]
+    assert verdict["pool_movers"][program] == []
+    assert verdict["state_movers"][program] == []
+    if program == "trinity_decode":
+        assert verdict["weight_movers"][program] == []
+    # the grouped product's metadata call reports none; the attention
+    # kernels' 1.3 MB (decode) and 10.0 MB (a prefill tile of 64 queries)
+    vmem = verdict["scoped_vmem"][program]
+    assert max(vmem) > 1 << 20 and all(0 <= v < V5E_SCOPED_VMEM for v in vmem)
+    # one window call a sliding layer, one paged call for the full layer
+    assert sorted(verdict["grids"][program]) == sorted(
+        [[64, 1]] * 5 if program == "trinity_decode" else [[1, 128]] * 5)
 
 
 @pytest.mark.parametrize("program,kernel,shape,need", [
