@@ -16,13 +16,20 @@ and ``layer`` ride in as scalar-prefetch operands
 axis. Inside a grid step a loop runs over GROUPS of ``G`` consecutive table
 entries (``_blocks_per_group``: 128 kv positions, one lane row of scores),
 its bound computed from ``lengths[s]`` and the tile's last real query: a slot
-of 400 tokens does 4 iterations at 16-token blocks, a parked slot one, and
-there is no step that does nothing. A group's live blocks are fetched by one
-``pltpu.make_async_copy`` each, ``pool.at[layer, tables[s, b]]`` into rows
+of 400 tokens does 4 iterations at 16-token blocks. A PARKED slot (the
+engine dispatches every slot every step; one with no request has an
+all-trash table) does none: its step is read off ``tables[s, 0] == 0``,
+the trash block where a live slot's first block would be, and it starts and
+awaits no copy, attends and finalizes nothing and writes zeros to its output
+in one store (``_walk_live_groups`` says why that test is sound): a chat
+engine with one stream in flight pays for one slot, not for 36. A group's
+live blocks are fetched by one ``pltpu.make_async_copy`` each,
+``pool.at[layer, tables[s, b]]`` into rows
 ``j * bt`` of one half of a double-buffered ``[2, G*bt, H*D]`` scratch, all
 in flight at once; the next group's copies are started before the current
 group is computed on, and a step's last iteration starts the FIRST group of
-the next grid step, so no slot opens on an exposed DMA. Entries past the
+the next grid step, so no slot opens on an exposed DMA but the call's first
+and one that follows a parked slot (whose step starts it). Entries past the
 slot's last live block are NOT fetched (dead table entries are never
 dereferenced); their rows of the V half are zeroed before the dot, because
 a masked score gives p = 0 and 0 x NaN is NaN, whatever the scratch held.
@@ -61,9 +68,12 @@ entry ``b mod nb_seq``; the ring holds at least ``W + T - 1`` rows and a
 block more, so that the blocks a tile attends are distinct entries) whatever
 the context: what a window layer keeps for a slot is bounded by the window.
 A table that covers the whole context (a prefill over its fresh rows) is the
-same walk, its modulo the identity. With no window the traced body is the
-one it was, byte for byte (``tests/test_paged_attention_kernel.py`` holds
-its digest).
+same walk, its modulo the identity. Rings are a pool with NO trash block:
+slot 0's ring starts at block 0 (``models/afmoe.py:_window_attend``) and a
+windowed prefill's table is ``arange`` over its fresh rows, so under a
+window no slot is taken for parked and every step is walked. With no window
+the traced body is held by digest (``tests/test_paged_attention_kernel.py``;
+a PR that changes the walk on purpose takes new ones).
 
 The pool is the WHOLE model's, ``[L, num_blocks, bt, KV*D]``: heads folded
 into the lane dimension, so a block is one dense ``[bt, KV*D]`` tile in the
@@ -276,6 +286,8 @@ def _walk_live_groups(
     half_ref,                  # SMEM [1]: the half this step's first group is in
     accumulators,              # VMEM scratch (m, l, acc), reset here
     attend,                    # attend(g, ctx, half, fetched): group g is in
+    finalize,                  # finalize(): the accumulators into ``o_ref``
+    o_ref,                     # the step's output block
     *,
     block_tokens: int,
     q_tile: int,
@@ -285,19 +297,38 @@ def _walk_live_groups(
     unroll_full: bool = False,
     window: Optional[int] = None,
 ):
-    """The walk both kernels share: grid step ``(s, i)`` resets its
-    accumulators and loops over the groups of ``G`` table entries its query
-    tile attends, the bound read from ``lengths[s]``. A group's live blocks
-    arrive by one DMA a pool each into one half of every ``buf`` while the
-    other half is computed on; ``attend`` gets the group's index, the tile's
-    first position ``ctx``, the half the group lies in and ``fetched``
-    ``[G*bt, 1]``, which rows of it were brought in. ``unroll_full``: a full
-    group's copies are started and awaited as ``G`` straight-line DMAs, not
-    by a loop (see ``live_copies``). ``window``: the walk starts at the
-    group of the tile's first visible block (``_tile_first_block``), copies
-    no entry before that block, and reads the table modulo ``nb_seq`` (a
-    ring); group indices stay LOGICAL, so ``attend`` masks by position as
-    ever."""
+    """The walk both kernels share: grid step ``(s, i)`` of a LIVE slot
+    resets its accumulators, loops over the groups of ``G`` table entries its
+    query tile attends, the bound read from ``lengths[s]``, and calls
+    ``finalize``. A group's live blocks arrive by one DMA a pool each into
+    one half of every ``buf`` while the other half is computed on; ``attend``
+    gets the group's index, the tile's first position ``ctx``, the half the
+    group lies in and ``fetched`` ``[G*bt, 1]``, which rows of it were
+    brought in.
+
+    A PARKED slot's step does none of that. Without a window, slot ``s`` is
+    parked iff ``tables[s, 0] == 0``: block 0 is the trash block of every
+    paged pool (``PagedFamily.init_pool``), the block manager never hands it
+    out, ``serve/llm.py`` zeroes a freed slot's row (``_free_slot_locked``)
+    and writes a live slot's row whole, its first entry the first block of
+    the slot's chain (``_dispatch_prefill``; the tier and speculative paths
+    write no row of their own), so a live slot's first entry is a real
+    block. (Not ``lengths[s] == 0``: a prefill from position 0 has it.) Its
+    last block reads -1, so it has no group and no live entry, on its own
+    side and on the side of the step before it: no copy is started or awaited
+    for it, nothing is attended or finalized, ``o_ref`` is zeros in one store
+    (the caller's rows flow on into products and the trash block: finite),
+    and, since every step counts on its first group being on its way, it
+    starts the next step's into the half its own would have taken.
+
+    ``unroll_full``: a full group's copies are started and awaited as ``G``
+    straight-line DMAs, not by a loop (see ``live_copies``). ``window``: the
+    walk starts at the group of the tile's first visible block
+    (``_tile_first_block``), copies no entry before that block, and reads
+    the table modulo ``nb_seq`` (a ring); group indices stay LOGICAL, so
+    ``attend`` masks by position as ever. A ring's pool has NO trash block
+    (slot 0's ring starts at block 0), so under a window every slot is
+    walked."""
     s = pl.program_id(0)
     i = pl.program_id(1)
     n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
@@ -308,12 +339,13 @@ def _walk_live_groups(
         raise ValueError("the windowed walk starts its copies in a loop")
 
     def last_block(s_, i_):
-        return _tile_last_block(lengths_ref, s_, i_, T, total, bt, nb_seq,
+        last = _tile_last_block(lengths_ref, s_, i_, T, total, bt, nb_seq,
                                 ring)
+        return last if ring else jnp.where(tables_ref[s_, 0] == 0, -1, last)
 
     def live_entries(last, g):
         """How many of group ``g``'s ``G`` entries are live, 0..G, where the
-        tile's last live entry is ``last``."""
+        tile's last live entry is ``last`` (-1: a parked slot's, none)."""
         return jnp.clip(last - g * G + 1, 0, G)
 
     def dead_entries(first, g):
@@ -373,7 +405,6 @@ def _walk_live_groups(
                     *([dead_entries(first_blk, first_g)] if ring else []))
 
     first_half = half_ref[0]
-    _init_accumulators(*accumulators)
 
     # The step after this one: the next q tile of the slot, else the next
     # slot's first (clamped where there is none; ``has_next`` guards it).
@@ -414,10 +445,30 @@ def _walk_live_groups(
             fetched = jnp.logical_and(fetched, row >= n_dead * bt)
         attend(g, lengths_ref[s] + i * T, half, fetched)
 
-    jax.lax.fori_loop(g0 if ring else 0, n_groups, group, None)
-    # The half the prefetched first group of the next step went into.
-    half_ref[0] = jax.lax.rem(
-        first_half + (n_groups - g0 if ring else n_groups), 2)
+    def live_step():
+        _init_accumulators(*accumulators)
+        jax.lax.fori_loop(g0 if ring else 0, n_groups, group, None)
+        # The half the prefetched first group of the next step went into.
+        half_ref[0] = jax.lax.rem(
+            first_half + (n_groups - g0 if ring else n_groups), 2)
+        finalize()
+
+    if ring:
+        live_step()
+        return
+    pl.when(last_blk >= 0)(live_step)
+
+    @pl.when(last_blk < 0)
+    def _parked_step():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+        # No group of this step's started the next step's first one: a live
+        # slot that follows a parked one opens on that copy (a parked one's
+        # is none), in the half ``half_ref`` still names.
+        @pl.when(has_next)
+        def _hand_on():
+            live_copies(next_s, 0, live_entries(next_last, 0), first_half,
+                        lambda c: c.start())
 
 
 def _paged_kernel(
@@ -451,8 +502,8 @@ def _paged_kernel(
 
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
-        sems, half_ref, (m_scr, l_scr, acc_scr), attend, **walk)
-    _finalize(o_ref, l_scr, acc_scr, kv_heads)
+        sems, half_ref, (m_scr, l_scr, acc_scr), attend,
+        lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref, **walk)
 
 
 def _paged_kernel_unaligned(
@@ -520,6 +571,11 @@ def paged_attention(
     ``tables[s]``. No ``[S, max_len, H, D]`` intermediate exists at any
     point, and the pool is read in place: a caller holding one layer's pool
     passes ``pool[None]`` and layer 0.
+
+    Block 0 is the pool's trash block, never a live slot's: a slot whose
+    table begins with it (``tables[s, 0] == 0``, no window) is PARKED, is not
+    walked, and its rows of the result are zeros (over a pool row of whole
+    128-lane tiles; the form for other widths attends its trash block).
 
     The pool's row holds ``KV`` heads of ``D``, ``KV`` a divisor of ``H``
     (grouped-query attention): query head ``h`` reads KV head ``h // (H //
@@ -758,10 +814,13 @@ def _latent_kernel(
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
 
+    def finalize():
+        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(
+            o_ref.dtype)
+
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (pool_hbm,), (kv_buf,), sems,
-        half_ref, (m_scr, l_scr, acc_scr), attend, **walk)
-    o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+        half_ref, (m_scr, l_scr, acc_scr), attend, finalize, o_ref, **walk)
 
 
 def latent_paged_attention(
@@ -778,7 +837,9 @@ def latent_paged_attention(
     """Softmax over the latent rows, returns ``sum_j p_j row_j[:value_lanes]``
     as [S, T, H, value_lanes]: the caller up-projects it per head. Query t of
     slot s sits at ``lengths[s] + t`` and attends positions ``<=`` it; the T
-    new rows must already be in the pool."""
+    new rows must already be in the pool. A slot whose table begins with the
+    trash block 0 is parked: not walked, its rows zeros (as
+    :func:`paged_attention`)."""
     W = q.shape[3]
     if pool.ndim != 4 or pool.shape[3] != W or W % 128:
         raise ValueError(
