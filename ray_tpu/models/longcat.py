@@ -319,9 +319,12 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
 
 
 # ``stats()`` names of ``_forward``'s counts. A prefill's picks are kept apart
-# (the per-step means stay the decode step's); its busiest expert, experts
-# hit and step are not kept. The decode chunk's held pairs ride ``llm.step``.
-_PREFILL_KEPT = ("picks", "picks_zero", "picks_held")
+# (the per-step means stay the decode step's), and how often its expert
+# layers took the bounded row buffer and walked past its first window; its
+# busiest expert, experts hit and step are not kept. The decode chunk's held
+# pairs ride ``llm.step``.
+_PREFILL_KEPT = ("picks", "picks_zero", "picks_held", "bounded_calls",
+                 "extra_windows")
 AUX_COUNTS = tuple(
     AuxCount(f"moe_{n}_total",
              f"moe_prefill_{n}_total" if n in _PREFILL_KEPT else None,

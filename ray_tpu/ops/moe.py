@@ -118,10 +118,35 @@ def moe_ffn(params: Dict, x: jax.Array, cfg: MoEConfig) -> Tuple[jax.Array, Dict
 # Per-call pick counts, in the order of the array ``held_experts_ffn`` returns
 # (a model family names its serve counters after them): picks, zero-compute
 # picks, picks on held experts, the busiest held expert's pairs, held experts
-# with at least one pair.
+# with at least one pair; then 1 for a call that took the bounded form (below)
+# and the windows it walked beyond the first.
 PICK_COUNT_NAMES = ("picks", "picks_zero", "picks_held", "held_pairs_max",
-                    "experts_hit")
+                    "experts_hit", "bounded_calls", "extra_windows")
 PICK_COUNTS = len(PICK_COUNT_NAMES)
+
+# The row buffer of the grouped products. ``N * k`` rows is the most that can
+# land on this chip; what does land is its share of the picks, ``N * k *
+# count / n_routed`` under an even router: 3% of the worst case where 12 of
+# 384 experts are held. So the buffer is bounded by twice that share, rounded
+# up to the grouped product's row tile, and the pairs, sorted by expert, are
+# walked in windows of that many rows until none is left: one window under an
+# even load, as many as the load asks otherwise. No pick is dropped. Where
+# the bound is not clearly under ``N * k`` (past a quarter of it: every decode
+# program, the smallest prefill buckets) the buffer keeps its ``N * k`` rows
+# and there is no walk. The bound is read off the static shapes alone.
+_ROW_TILE = 512
+_SHARE_FACTOR = 2
+
+
+def held_row_bound(n_tokens: int, topk: int, held: Tuple[int, int],
+                   n_routed: int) -> Optional[int]:
+    """Rows of ``held_experts_ffn``'s buffer where it is bounded by the
+    chip's share of the picks (a multiple of the row tile), None where it
+    keeps all ``n_tokens * topk`` rows."""
+    pairs = n_tokens * topk
+    tiles = -(-_SHARE_FACTOR * pairs * held[1] // (n_routed * _ROW_TILE))
+    rows = max(tiles, 1) * _ROW_TILE
+    return rows if 4 * rows <= pairs else None
 
 
 def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
@@ -152,6 +177,71 @@ def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
     return idx.astype(jnp.int32), scale * picked
 
 
+def _add_rows_by_token(out, y, tok, in_group, seg_max: int):
+    """``out[tok[r]] += y[r]`` over the rows ``in_group``, in float32 and
+    with no scatter of rows (on the chip ``out.at[tok].add(y)`` of 1,024
+    rows of 7,168 took 1.56 ms, a layer 1.4 ms more than with this): the
+    window's rows are sorted by token, each token's rows (``seg_max`` at
+    most) are summed onto its first by doubling strides, and every token
+    that has a row gathers its first."""
+    N, R = out.shape[0], y.shape[0]
+    key = jnp.where(in_group, tok, N)
+    perm = jnp.argsort(key, stable=True)
+    ts, ys = key[perm], y[perm]
+    stride = 1
+    while stride < seg_max:
+        same = jnp.concatenate(
+            [ts[stride:] == ts[:-stride], jnp.zeros((stride,), bool)])
+        ahead = jnp.concatenate(
+            [ys[stride:], jnp.zeros((stride, ys.shape[1]), ys.dtype)])
+        ys = ys + jnp.where(same[:, None], ahead, 0.0)
+        stride *= 2
+    rows_of = jnp.zeros((N + 1,), jnp.int32).at[key].add(1)[:N]
+    head = jnp.minimum(jnp.cumsum(rows_of) - rows_of, R - 1)
+    return out + jnp.where((rows_of > 0)[:, None], ys[head], 0.0)
+
+
+def _walk_held_pairs(h, weights, w_gate_up, w_down, order, sizes, rows: int):
+    """The held pairs' weighted sum [N, D] float32 through a buffer of
+    ``rows`` rows: window ``i`` takes sorted pairs ``[i * rows, (i + 1) *
+    rows)``, each group's size clipped to it (a group that straddles an edge
+    is multiplied part by part), and adds each row, weighed by its pick's
+    weight, to its token's. Returns (sum, windows walked)."""
+    N, D = h.shape
+    k = weights.shape[1]
+    F = w_down.shape[1]
+    held_pairs = jnp.sum(sizes)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    order = jnp.pad(order, (0, -(N * k) % rows))     # whole windows to slice
+    w_flat = weights.reshape(-1)
+    seg_max = min(k, sizes.shape[0])     # a token's pairs: k distinct experts
+
+    def window(carry):
+        i, out = carry
+        lo = i * rows
+        pair = jax.lax.dynamic_slice(order, (lo,), (rows,))
+        tok = pair // k
+        in_group = lo + jnp.arange(rows, dtype=jnp.int32) < held_pairs
+        cut = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo),
+                       0)
+        with jax.named_scope("moe_experts"):
+            gu = jax.lax.ragged_dot(h[tok], w_gate_up, cut,
+                                    preferred_element_type=jnp.float32)
+            a = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
+            y = jax.lax.ragged_dot(a, w_down, cut,
+                                   preferred_element_type=jnp.float32)
+        # Rows past the last pair belong to no group: their product is not
+        # defined, so they are cut out before the weights touch them.
+        y = jnp.where(in_group[:, None], y * w_flat[pair][:, None], 0.0)
+        return i + 1, _add_rows_by_token(out, y, tok, in_group, seg_max)
+
+    windows, out = jax.lax.while_loop(
+        lambda carry: carry[0] * rows < held_pairs, window,
+        (jnp.int32(0), jnp.zeros((N, D), jnp.float32)))
+    return out, windows
+
+
 def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
                      w_gate_up: jax.Array, w_down: jax.Array, *,
                      held: Tuple[int, int], n_routed: int,
@@ -171,8 +261,17 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     Only routed pairs are multiplied: the (token, expert) pairs that land on
     held experts are sorted by expert and go through ``jax.lax.ragged_dot``
     (a grouped matrix product; on TPU a Mosaic kernel that visits only the
-    row tiles that hold pairs). The row buffer is ``N * k`` long, the most
-    that can land here, so no pick is ever dropped however uneven the load.
+    row tiles that hold pairs). The row buffer is bounded by the chip's
+    share of the picks (:func:`held_row_bound`: twice ``N * k * count /
+    n_routed``, a prefill bucket's 1,024 rows where ``N * k`` is 16,384) and
+    the sorted pairs are walked in windows of that many rows until none is
+    left; where that bound is past a quarter of ``N * k`` (a decode step,
+    a small bucket) the buffer is ``N * k`` long, the most that can land
+    here, and is filled once. Either way no pick is ever dropped however
+    uneven the load: an uneven load walks more windows. The products and
+    their precision are the same in both forms (bfloat16 operands, float32
+    accumulation, float32 weights and sum, one cast at the end); only the
+    order in which a token's picks are summed differs.
 
     Returns (out [N, D] in ``h.dtype``, counts int32 [PICK_COUNTS], one a
     name of ``PICK_COUNT_NAMES``)."""
@@ -188,22 +287,30 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     key = jnp.where(on_held, local, count).reshape(-1)           # [N*k]
     order = jnp.argsort(key, stable=True)
     sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
-    tok = (jnp.arange(N * k, dtype=jnp.int32) // k)[order]
-    x = h[tok]                                                   # [N*k, D]
-    with jax.named_scope("moe_experts"):
-        gu = jax.lax.ragged_dot(x, w_gate_up, sizes,
-                                preferred_element_type=jnp.float32)
-        a = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
-        y = jax.lax.ragged_dot(a, w_down, sizes,
-                               preferred_element_type=jnp.float32)
-    # Rows past the last pair belong to no group: their product is not
-    # defined, so they are cut out before the weights touch them.
-    in_group = jnp.arange(N * k) < jnp.sum(sizes)
-    y = jnp.where(in_group[:, None], y, 0.0)
-    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
-        jnp.arange(N * k, dtype=jnp.int32))
-    w_held = jnp.where(on_held, weights, 0.0)                    # [N, k]
-    out = jnp.einsum("nk,nkd->nd", w_held, y[back].reshape(N, k, D))
+    rows = held_row_bound(N, k, held, n_routed)
+    if rows is not None:
+        out, windows = _walk_held_pairs(h, weights, w_gate_up, w_down, order,
+                                        sizes, rows)
+        bounded = {"bounded_calls": 1,
+                   "extra_windows": jnp.maximum(windows - 1, 0)}
+    else:
+        tok = (jnp.arange(N * k, dtype=jnp.int32) // k)[order]
+        x = h[tok]                                               # [N*k, D]
+        with jax.named_scope("moe_experts"):
+            gu = jax.lax.ragged_dot(x, w_gate_up, sizes,
+                                    preferred_element_type=jnp.float32)
+            a = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
+            y = jax.lax.ragged_dot(a, w_down, sizes,
+                                   preferred_element_type=jnp.float32)
+        # Rows past the last pair belong to no group: their product is not
+        # defined, so they are cut out before the weights touch them.
+        in_group = jnp.arange(N * k) < jnp.sum(sizes)
+        y = jnp.where(in_group[:, None], y, 0.0)
+        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+            jnp.arange(N * k, dtype=jnp.int32))
+        w_held = jnp.where(on_held, weights, 0.0)                # [N, k]
+        out = jnp.einsum("nk,nkd->nd", w_held, y[back].reshape(N, k, D))
+        bounded = {"bounded_calls": 0, "extra_windows": 0}
 
     is_zero = (idx >= n_routed) & live_k
     w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)  # [N]
@@ -211,6 +318,6 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
 
     counts = {"picks": jnp.sum(live) * k, "picks_zero": jnp.sum(is_zero),
               "picks_held": jnp.sum(on_held), "held_pairs_max": jnp.max(sizes),
-              "experts_hit": jnp.sum(sizes > 0)}
+              "experts_hit": jnp.sum(sizes > 0), **bounded}
     return out.astype(h.dtype), jnp.stack(
         [counts[n] for n in PICK_COUNT_NAMES]).astype(jnp.int32)

@@ -244,7 +244,7 @@ def test_a_zero_compute_pick_adds_w_times_h(layer):
         h, idx, w, layer["w_gate_up"], layer["w_down"], held=(0, E),
         n_routed=E)
     np.testing.assert_allclose(out, w.sum(-1, keepdims=True) * h, atol=1e-5)
-    assert [int(c) for c in counts] == [3 * N, 3 * N, 0, 0, 0]
+    assert [int(c) for c in counts] == [3 * N, 3 * N, 0, 0, 0, 0, 0]
 
 
 def test_nothing_is_dropped_when_every_token_picks_one_held_expert(layer):
@@ -259,7 +259,7 @@ def test_nothing_is_dropped_when_every_token_picks_one_held_expert(layer):
     want = w[:, :1] * _expert(layer["w_gate_up"], layer["w_down"], 5, h)
     np.testing.assert_allclose(out, want, atol=1e-5)
     # all N pairs on one expert, one expert hit, none dropped
-    assert [int(c) for c in counts] == [3 * N, 0, N, N, 1]
+    assert [int(c) for c in counts] == [3 * N, 0, N, N, 1, 0, 0]
 
 
 def test_masked_tokens_route_nowhere(layer):
@@ -342,10 +342,13 @@ def test_the_family_names_its_counts_and_the_engine_only_folds_them(model):
     assert [a.decode for a in fam.aux_counts] == [
         "moe_picks_total", "moe_picks_zero_total", "moe_picks_held_total",
         "moe_held_pairs_max_total", "moe_experts_hit_total",
+        "moe_bounded_calls_total", "moe_extra_windows_total",
         "moe_steps_total"]
     assert [a.prefill for a in fam.aux_counts] == [
         "moe_prefill_picks_total", "moe_prefill_picks_zero_total",
-        "moe_prefill_picks_held_total", None, None, None]
+        "moe_prefill_picks_held_total", None, None,
+        "moe_prefill_bounded_calls_total", "moe_prefill_extra_windows_total",
+        None]
     assert {a.step_attr: a.decode for a in fam.aux_counts if a.step_attr} \
         == {"moe_held_pairs": "moe_picks_held_total"}
     assert len(fam.aux_counts) == moe.PICK_COUNTS + 1
