@@ -306,47 +306,6 @@ class Config:
     # "gather" force a mode ("interpret" runs the same Pallas kernel in
     # interpreter mode, the CPU-testable twin of the TPU path).
     serve_paged_attention_kernel = _Flag("auto")
-    # Speculative decoding: how many draft-model tokens each slot proposes
-    # per scan step, all verified in ONE batched target forward. 0 disables
-    # speculation; > 0 requires a draft model (LLMEngine draft_params/
-    # draft_config, or llm_deployment draft_params_fn). Acceptance is
-    # rejection-sampled so emitted tokens follow the TARGET distribution
-    # exactly (greedy output is token-identical to non-speculative greedy).
-    serve_spec_tokens = _Flag(0)
-    # Per-slot acceptance-rate floor: a slot whose acceptance EWMA sinks
-    # below this stops proposing for the rest of its request (one token per
-    # step, zero draft cost) so a badly-matched draft never costs
-    # throughput. Reset optimistic at each admission.
-    serve_spec_accept_floor = _Flag(0.35)
-    # EWMA smoothing factor for the per-slot acceptance rate feeding the
-    # floor above (new = (1-a)*old + a*step_rate). Larger = faster demotion
-    # of low-acceptance slots, noisier signal.
-    serve_spec_accept_alpha = _Flag(0.3)
-    # Cluster-wide KV tier (serve/kv_tier.py): 1 spills retired prefix
-    # chains to the object plane as content-addressed blobs, publishes them
-    # in the GCS prefix directory for cross-replica fetch, and turns
-    # autoscaler scale-down into drain-by-migration (victim ships its warm
-    # chains to a survivor over a KVHandoffLane before retiring). 0 (the
-    # default) keeps KV engine-private and downscale sweep-only — exact
-    # pre-tier behavior.
-    kv_tier_enabled = _Flag(False)
-    # Minimum FULL blocks a retired chain must hold before the engine
-    # spills it to the store: chains below this recompute faster than they
-    # fetch, so publishing them only churns the directory.
-    kv_tier_min_spill_blocks = _Flag(1)
-    # Prefix-directory capacity (entries, cluster-wide). Publishing past
-    # the cap evicts the least-recently-matched entries and frees their
-    # spilled objects — the directory is a bounded index, not an archive.
-    kv_tier_dir_max_entries = _Flag(4096)
-    # Prefix-directory entry TTL (seconds) since last publish/match touch;
-    # expired entries are swept opportunistically on directory mutations
-    # and their objects freed. <= 0 disables the TTL (LRU cap still holds).
-    kv_tier_dir_ttl_s = _Flag(600.0)
-    # Upper bound (seconds) the controller waits for a drain migration
-    # (victim kv_migrate_out + survivor kv_migrate_in) to settle before
-    # retiring the victim anyway — a wedged lane must never block
-    # scale-down forever. The store tier catches anything unshipped.
-    kv_tier_drain_timeout_s = _Flag(10.0)
 
     # -- rllib (Podracer-scale RL) ---------------------------------------------
     # Rollout transport for IMPALA/APPO: 1 parks the env runners in a

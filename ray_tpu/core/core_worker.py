@@ -132,27 +132,6 @@ class _GcsClientAdapter:
     def kv_keys(self, prefix="", namespace="default"):
         return self._client.call("kv_keys", prefix, namespace)
 
-    # -- KV-tier prefix directory ---------------------------------------------
-
-    def prefix_publish(self, digest, meta, token_count, n_blocks, hint=""):
-        return self._client.call("prefix_publish", digest, meta,
-                                 token_count, n_blocks, hint)
-
-    def prefix_match(self, digests):
-        return self._client.call("prefix_match", digests)
-
-    def prefix_release(self, digest):
-        return self._client.call("prefix_release", digest)
-
-    def prefix_drop(self, digest):
-        return self._client.call("prefix_drop", digest)
-
-    def prefix_sweep(self):
-        return self._client.call("prefix_sweep")
-
-    def prefix_stats(self):
-        return self._client.call("prefix_stats")
-
     # -- observability --------------------------------------------------------
 
     def record_task_event(self, event: dict) -> None:

@@ -18,7 +18,7 @@ from typing import Any, Callable, Dict, List, Optional, Tuple
 
 from ray_tpu.core.ids import ActorID, JobID, NodeID
 from ray_tpu.core.resources import ResourceSet
-from ray_tpu.utils.logging import get_logger, log_swallowed
+from ray_tpu.utils.logging import get_logger
 
 logger = get_logger("gcs")
 
@@ -134,21 +134,6 @@ class GlobalControlStore:
         from ray_tpu.util.metrics import MetricsAggregator
 
         self.metrics = MetricsAggregator()
-        # Cluster KV-tier prefix directory: chain digest -> spilled-object
-        # locator, sharded like the KV. Bounds come from config at
-        # construction; the serve tier re-reads them per publish so env
-        # overrides in tests apply without a GCS restart.
-        from ray_tpu.core.gcs_shards import ShardedPrefixDirectory
-
-        try:
-            from ray_tpu.core.config import config as _cfg
-
-            dir_max = int(_cfg().kv_tier_dir_max_entries)
-            dir_ttl = float(_cfg().kv_tier_dir_ttl_s)
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            dir_max, dir_ttl = 4096, 600.0
-        self.prefix_dir = ShardedPrefixDirectory(
-            n_shards, max_entries=dir_max, ttl_s=dir_ttl)
 
     # -- nodes (gcs_node_manager.cc) -----------------------------------------
 
@@ -268,25 +253,14 @@ class GlobalControlStore:
                            if k.startswith(prefix))
         return out
 
-    # Reserved kv_dump namespace carrying the prefix directory through the
-    # PR 12 snapshot path (never stored in the KV shards themselves).
-    _PREFIX_DIR_NS = "__kv_tier_prefix_dir__"
-
     def kv_dump(self) -> Dict[str, Dict[str, bytes]]:
         """Merged ``{namespace: {key: value}}`` view across every shard —
-        the (shard-count-independent) snapshot format. The KV-tier prefix
-        directory rides along under a reserved namespace so GCS snapshot /
-        restore round-trips it for free."""
+        the (shard-count-independent) snapshot format."""
         merged: Dict[str, Dict[str, bytes]] = {}
         for i, shard in enumerate(self._kv_shards):
             with self._kv_locks[i]:
                 for ns, kv in shard.items():
                     merged.setdefault(ns, {}).update(kv)
-        dir_dump = self.prefix_dir.dump()
-        if dir_dump:
-            import pickle
-
-            merged[self._PREFIX_DIR_NS] = {"directory": pickle.dumps(dir_dump)}
         return merged
 
     def kv_load(self, data: Dict[str, Dict[str, bytes]]) -> None:
@@ -295,58 +269,12 @@ class GlobalControlStore:
         for shard, lock in zip(self._kv_shards, self._kv_locks):
             with lock:
                 shard.clear()
-        data = dict(data or {})
-        dir_blob = data.pop(self._PREFIX_DIR_NS, None)
-        if dir_blob is not None and "directory" in dir_blob:
-            import pickle
-
-            try:
-                self.prefix_dir.load(pickle.loads(dir_blob["directory"]))
-            except Exception:  # noqa: BLE001 — a torn snapshot must not
-                logger.exception("prefix directory restore failed")  # block KV
-        else:
-            self.prefix_dir.load({})
-        for ns, kv in data.items():
+        for ns, kv in (data or {}).items():
             for key, value in kv.items():
                 self.kv_put(key, value, namespace=ns)
 
     def kv_shard_count(self) -> int:
         return len(self._kv_shards)
-
-    # -- KV-tier prefix directory (serve/kv_tier.py index) -------------------
-
-    def prefix_publish(self, digest: bytes, meta: bytes, token_count: int,
-                       n_blocks: int, hint: str = "") -> bool:
-        self._prefix_apply_bounds()
-        return self.prefix_dir.publish(digest, meta, token_count, n_blocks,
-                                       hint=hint)
-
-    def prefix_match(self, digests: List[bytes]):
-        return self.prefix_dir.match(list(digests))
-
-    def prefix_release(self, digest: bytes) -> bool:
-        return self.prefix_dir.release(digest)
-
-    def prefix_drop(self, digest: bytes) -> bool:
-        return self.prefix_dir.drop(digest)
-
-    def prefix_sweep(self) -> int:
-        self._prefix_apply_bounds()
-        return self.prefix_dir.sweep()
-
-    def prefix_stats(self) -> Dict[str, int]:
-        return self.prefix_dir.stats()
-
-    def _prefix_apply_bounds(self) -> None:
-        # Directory bounds track live config (tests shrink them via env
-        # overrides long after this store was built).
-        try:
-            from ray_tpu.core.config import config
-
-            self.prefix_dir.max_entries = int(config().kv_tier_dir_max_entries)
-            self.prefix_dir.ttl_s = float(config().kv_tier_dir_ttl_s)
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            log_swallowed(logger, "prefix directory bounds")
 
     # -- function/code store (gcs_function_manager.h) ------------------------
 

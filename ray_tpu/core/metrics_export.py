@@ -125,9 +125,7 @@ def serve_ttft_hist() -> um.Histogram:
     return _metric(
         um.Histogram, "ray_tpu_serve_ttft_s",
         "LLM serving time-to-first-token (request submit to first token), "
-        "phase-split: total | queued | prefill | decode | spec "
-        "(spec = the fused propose+verify dispatch of the first chunk, "
-        "speculative engines only)",
+        "phase-split: total | queued | prefill | decode",
         boundaries=_LATENCY_BOUNDS, tag_keys=("deployment", "phase"))
 
 
@@ -155,26 +153,6 @@ def serve_kv_hit_tokens_total() -> um.Counter:
     return _metric(um.Counter, "ray_tpu_serve_kv_hit_tokens_total",
                    "Prompt tokens served from the paged KV prefix cache "
                    "(prefill FLOPs avoided)",
-                   tag_keys=("deployment",))
-
-
-def serve_spec_proposed_total() -> um.Counter:
-    return _metric(um.Counter, "ray_tpu_serve_spec_proposed_total",
-                   "Draft tokens proposed by speculative decoding",
-                   tag_keys=("deployment",))
-
-
-def serve_spec_accepted_total() -> um.Counter:
-    return _metric(um.Counter, "ray_tpu_serve_spec_accepted_total",
-                   "Draft tokens accepted by the target model's "
-                   "speculative verify",
-                   tag_keys=("deployment",))
-
-
-def serve_spec_accept_ratio() -> um.Gauge:
-    return _metric(um.Gauge, "ray_tpu_serve_spec_accept_ratio",
-                   "Cumulative speculative-decoding acceptance ratio "
-                   "(accepted / proposed draft tokens)",
                    tag_keys=("deployment",))
 
 
@@ -219,36 +197,6 @@ def serve_kv_block_occupancy() -> um.Gauge:
                    "Paged KV pool blocks by state "
                    "(active=pinned, cached=prefix-reusable, free)",
                    tag_keys=("deployment", "state"))
-
-
-def serve_kv_tier_hits_total() -> um.Counter:
-    return _metric(um.Counter, "ray_tpu_serve_kv_tier_hits_total",
-                   "Prompt tokens served warm by KV source: local=this "
-                   "engine's prefix cache, store=fetched from the cluster "
-                   "KV tier's spilled objects, migrated=chains shipped in "
-                   "by a draining replica",
-                   tag_keys=("deployment", "source"))
-
-
-def serve_kv_tier_spill_bytes_total() -> um.Counter:
-    return _metric(um.Counter, "ray_tpu_serve_kv_tier_spill_bytes_total",
-                   "KV bytes spilled to the cluster tier's object store "
-                   "(chain publishes from the engine retire path)",
-                   tag_keys=("deployment",))
-
-
-def serve_kv_tier_fetch_bytes_total() -> um.Counter:
-    return _metric(um.Counter, "ray_tpu_serve_kv_tier_fetch_bytes_total",
-                   "KV bytes fetched back from the cluster tier on a "
-                   "directory hit (prefill recompute avoided)",
-                   tag_keys=("deployment",))
-
-
-def serve_kv_spilled_blocks() -> um.Gauge:
-    return _metric(um.Gauge, "ray_tpu_serve_kv_spilled_blocks",
-                   "KV blocks this engine currently has published in the "
-                   "cluster tier (directory entries it holds a ref on)",
-                   tag_keys=("deployment",))
 
 
 def dag_tick_hist() -> um.Histogram:

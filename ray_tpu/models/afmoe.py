@@ -539,8 +539,8 @@ PAGED_FAMILY = PagedFamily(
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
     init_slot_state=init_slot_state,
     # As the other families with a state a slot: a hit at position p would
-    # need every ring at p; a draft model a state of its own.
-    unsupported=("draft_model", "kv_tier", "prefix_cache"),
+    # need every ring at p.
+    unsupported=("prefix_cache",),
     aux_counts=AUX_COUNTS,
     describe=describe,
 )

@@ -524,9 +524,8 @@ PAGED_FAMILY = PagedFamily(
     decode=forward_decode_paged,
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
     init_slot_state=init_slot_state,
-    # As olmo_hybrid: a draft model would need a state of its own, the KV
-    # tier and the prefix cache hand out rows at a position p, usable only
-    # with every layer's state at p (ROADMAP R4).
-    unsupported=("draft_model", "kv_tier", "prefix_cache"),
+    # As olmo_hybrid: the prefix cache hands out rows at a position p,
+    # usable only with every layer's state at p (ROADMAP R4).
+    unsupported=("prefix_cache",),
     describe=describe,
 )

@@ -232,8 +232,8 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
                           config: TransformerConfig, block_tokens: int,
                           kernel: str = "gather"):
     """Decode ``tokens`` [S, T] for S sequences over the paged pool: slot
-    s's token t sits at absolute position ``lengths[s] + t`` (T > 1 is the
-    speculative-decoding verify), its K/V scattered into block
+    s's token t sits at absolute position ``lengths[s] + t``, its K/V
+    scattered into block
     ``tables[s, pos // bt]`` row ``pos % bt`` and attention run back through
     the table row. Inactive slots carry all-trash tables, so their writes
     land in block 0 and their outputs are dead.
@@ -242,7 +242,7 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
     block 0 rather than clamping onto the last cell — a slot at capacity
     must be finished as ``length_cap`` by the engine BEFORE dispatch, so
     in-range rows never see a silently overwritten chain; the redirect only
-    shields parked/speculative overhang writes.
+    shields a parked slot's overhang writes.
 
     ``params`` is the working tree of :func:`gpt2_working_params`."""
     c = config
@@ -304,8 +304,8 @@ class PagedFamily(NamedTuple):
 
     - ``init_pool(config, num_blocks, block_tokens)`` -> a tuple of arrays,
       each ``[layers or sublayers, num_blocks, block_tokens, ...]``: blocks
-      are dimension 1 of every one, block 0 the trash block, so block copy,
-      extract and insert are the generator's, whatever a row holds;
+      are dimension 1 of every one, block 0 the trash block, so the block
+      copy is the generator's, whatever a row holds;
     - ``init_slot_state(config, slots)`` -> a tuple of arrays, each
       ``[layers, slots, ...]``: what a SLOT carries between tokens beside
       its rows in the pool (a linear-attention layer's recurrent state).
@@ -339,8 +339,7 @@ class PagedFamily(NamedTuple):
     - ``describe(config)`` -> a dict of names and counts that the engine's
       ``describe()`` carries beside its own (how many of a stack's layers
       are of which kind): for an operator to print, read by no code path;
-    - ``unsupported``: engine features the family cannot run yet:
-      ``draft_model`` and ``kv_tier`` are refused when an engine is built,
+    - ``unsupported``: engine features the family cannot run:
       ``prefix_cache`` makes the engine neither look up nor register a
       chain (a family with a slot state: a K/V hit at position p is usable
       only with the state at p).
@@ -434,8 +433,8 @@ class PagedGenerator:
     program per prompt bucket, one per chunk size, greedy and sampled slots
     riding the same program through per-slot operands. K/V lives in a
     SHARED block pool addressed through per-sequence block tables — the
-    layout that makes hash-based prefix reuse, copy-on-write forks and
-    moving a chain's blocks between pools possible.
+    layout that makes hash-based prefix reuse and copy-on-write forks
+    possible.
 
     Device state is ``(pool, slot_state, last, keys)`` threaded with buffer
     donation: ``pool`` is the family's tuple of pool arrays
@@ -447,7 +446,7 @@ class PagedGenerator:
     plain numpy operands owned by the host-side :class:`KVBlockManager` +
     engine.
 
-    ``params`` (and ``draft_params``) is the WORKING tree, what every
+    ``params`` is the WORKING tree, what every
     program here is called with: :func:`working_params` of the stored tree
     the generator was built from, made once in the constructor. The
     generator keeps no reference to the stored tree: a float32 tree that
@@ -458,9 +457,7 @@ class PagedGenerator:
     def __init__(self, params, config, *, slots: int,
                  num_blocks: int, block_tokens: int,
                  max_len: Optional[int] = None,
-                 attention_kernel: str = "auto",
-                 draft_params=None,
-                 draft_config=None):
+                 attention_kernel: str = "auto"):
         self.config = config
         self.family = paged_family(config)
         self.slots = slots
@@ -473,31 +470,11 @@ class PagedGenerator:
         self.blocks_per_seq = self.max_len // self.block_tokens
         self.num_blocks = int(num_blocks)
         self.attention_kernel = resolve_attention_kernel(attention_kernel)
-        if (draft_params is None) != (draft_config is None):
-            raise ValueError("draft_params and draft_config go together")
-        if draft_config is not None and (
-                "draft_model" in self.family.unsupported
-                or "draft_model" in paged_family(draft_config).unsupported):
-            raise ValueError(
-                f"{type(config).__name__}: speculative decoding with a "
-                f"draft model is not supported for this family yet")
-        if draft_config is not None and (
-                draft_config.vocab_size != config.vocab_size):
-            raise ValueError(
-                f"draft vocab {draft_config.vocab_size} != target vocab "
-                f"{config.vocab_size} — speculative verify needs one vocab")
-        self.draft_config = draft_config
         self.logits_dim = self.family.logits_dim(params, config)
         self.set_params(params)
-        self.draft_params = (None if draft_params is None
-                             else working_params(draft_params, draft_config))
         self._prefill_fns = {}   # suffix bucket -> jitted paged prefill
         self._decode_fns = {}    # chunk -> jitted paged decode
-        self._extract_fns = {}   # nb -> jitted block gather (KV tier out)
-        self._insert_fns = {}    # nb -> jitted block scatter (KV tier in)
         self._copy_fn = None
-        self._draft_prefill_fns = {}  # suffix bucket -> jitted draft prefill
-        self._spec_decode_fns = {}    # (chunk, k) -> jitted spec decode
 
     def set_params(self, params) -> None:
         """Make the working tree anew from a stored tree of the same shapes
@@ -522,13 +499,6 @@ class PagedGenerator:
         last = jnp.zeros((self.slots, self.logits_dim), jnp.float32)
         keys = jnp.zeros((self.slots, 2), jnp.uint32)
         return pool, state, last, keys
-
-    def init_draft_state(self):
-        """Draft-model pool mirroring the target pool's block geometry: the
-        SAME block tables index both, so advance/rollback bookkeeping is
-        shared and speculation adds zero KVBlockManager state."""
-        return tuple(paged_family(self.draft_config).init_pool(
-            self.draft_config, self.num_blocks, self.block_tokens))
 
     def prefill_fn(self, bucket: int):
         """paged_prefill(params, pool, state, last, keys, table [NB], padded
@@ -615,221 +585,6 @@ class PagedGenerator:
         self._decode_fns[chunk] = paged_decode
         return paged_decode
 
-    def draft_prefill_fn(self, bucket: int):
-        """draft_prefill(draft_params, draft_pool, table [NB], padded [1,P],
-        start_pos, suffix_len) -> draft_pool: run the
-        DRAFT model over the same suffix bucket through the same block
-        table so its pool holds draft-KV for every position the target
-        holds — the draft chain in :meth:`spec_decode_fn` then starts from
-        a warm cache. Logits are discarded (the first proposal conditions
-        on the verified tail, not on prefill output)."""
-        fn = self._draft_prefill_fns.get(bucket)
-        if fn is not None:
-            return fn
-        dc = self.draft_config
-        bt = self.block_tokens
-        kernel = self.attention_kernel
-        forward = paged_family(dc).prefill
-
-        @functools.partial(jax.jit, donate_argnums=(1,))
-        def draft_prefill(draft_params, draft_pool, table, padded,
-                          start_pos, suffix_len):
-            # A family that keeps a slot state takes no draft model: ().
-            _, draft_pool, _state, _aux = forward(
-                draft_params, padded, draft_pool, (), table, start_pos,
-                suffix_len, 0, dc, bt, kernel=kernel)
-            return draft_pool
-
-        self._draft_prefill_fns[bucket] = draft_prefill
-        return draft_prefill
-
-    def spec_decode_fn(self, chunk: int, k: int):
-        """Speculative decode: ``chunk`` scan steps, each proposing ``k``
-        draft tokens and verifying them in ONE batched target forward.
-
-        spec_decode(params, draft_params, pool, draft_pool, last, keys,
-        tables, lengths, active, greedy, temps, spec_on, tail, pending,
-        use_pending) -> (toks [S, chunk, k+1], counts [S, chunk],
-        accepted [S, chunk], pool, draft_pool, last, keys, tail, pending,
-        use_pending).
-
-        Per step and slot: token n0 comes from ``last`` (or the carried
-        rejection replacement when ``use_pending``); the draft runs k+1
-        single-token forwards — forward 0 re-consumes ``tail`` (the last
-        accepted token) at position len-1, an idempotent KV rewrite that
-        also fills the one draft-KV hole a fully-accepted previous step
-        leaves, then forwards 1..k consume n0, d_1, ..., d_{k-1} and emit
-        proposals d_1..d_k with their logits. The target verifies
-        [n0, d_1..d_k] in one [S, k+1] forward. Acceptance is rejection
-        sampling — u < p(d)/q(d) preserves the target distribution for ANY
-        draft; the greedy path is exact argmax prefix match — and the slot
-        advances 1 + a tokens where a is the accepted prefix length. On
-        rejection at a < k, a replacement is drawn from the normalized
-        residual max(p - q, 0) (greedy: target argmax) and carried as
-        ``pending`` to be next step's n0; on full acceptance ``last``
-        becomes the verify logits at position k. Only valid positions
-        (< lengths + 1 + a) survive in the pools — overhang writes are
-        overwritten by the next step before they become attendable, and
-        retirement publishes only real tokens.
-
-        ``toks[s, t, :counts[s, t]]`` are the emitted tokens of step t.
-        ``spec_on`` False (acceptance EWMA below floor, or no table
-        headroom for chunk*(k+1)) degrades the slot to the plain one-token
-        path inside the same program: proposals are force-rejected so
-        a == 0 and exactly n0 is emitted per step."""
-        key_ck = (chunk, k)
-        fn = self._spec_decode_fns.get(key_ck)
-        if fn is not None:
-            return fn
-        if self.draft_config is None:
-            raise ValueError("spec_decode_fn requires a draft model")
-        if k < 1:
-            raise ValueError("serve_spec_tokens must be >= 1 when "
-                             "speculative decoding is enabled")
-        c = self.config
-        dc = self.draft_config
-        bt = self.block_tokens
-        kernel = self.attention_kernel
-        V = c.vocab_size
-        target_forward = self.family.decode
-        draft_forward = paged_family(dc).decode
-
-        @functools.partial(jax.jit, donate_argnums=(2, 3, 4, 5))
-        def spec_decode(params, draft_params, pool, draft_pool, last, keys,
-                        tables, lengths, active, greedy, temps, spec_on,
-                        tail, pending, use_pending):
-            adv_gate = active.astype(jnp.int32)
-            act_col = active[:, None]
-            temp_safe = jnp.maximum(temps, 1e-6)[:, None]
-
-            def step(carry, _):
-                (pool, draft_pool, lens, last, keys, tail, pending,
-                 use_pending) = carry
-                nsub = 2 * k + 3
-                split = jax.vmap(
-                    lambda kk: jax.random.split(kk, nsub))(keys)
-                keys2 = split[:, 0]
-                sub_n0 = split[:, 1]
-                sub_draft = split[:, 2:2 + k]            # [S, k, 2]
-                sub_acc = split[:, 2 + k:2 + 2 * k]      # [S, k, 2]
-                sub_res = split[:, 2 + 2 * k]            # [S, 2]
-
-                real = last[:, :V]
-                samp = jax.vmap(jax.random.categorical)(
-                    sub_n0, real / temp_safe)
-                n0 = jnp.where(
-                    use_pending, pending,
-                    jnp.where(greedy, jnp.argmax(real, axis=-1),
-                              samp)).astype(jnp.int32)
-
-                # Draft chain: k+1 single-token forwards through the SHARED
-                # block tables into the draft pool.
-                cur_tok = tail
-                cur_pos = jnp.maximum(lens - 1, 0)
-                proposals, dlogits = [], []
-                for i in range(k + 1):
-                    dl, draft_pool, _state, _aux = draft_forward(
-                        draft_params, cur_tok[:, None], draft_pool, (),
-                        tables, cur_pos, dc, bt, kernel=kernel)
-                    if i == 0:
-                        # Forward 0 only (re)writes tail's draft KV at
-                        # lens-1; its logits are superseded by n0's chain.
-                        cur_tok, cur_pos = n0, lens
-                        continue
-                    dreal = dl[:, 0, :V]
-                    d_samp = jax.vmap(jax.random.categorical)(
-                        sub_draft[:, i - 1], dreal / temp_safe)
-                    d_i = jnp.where(greedy, jnp.argmax(dreal, axis=-1),
-                                    d_samp).astype(jnp.int32)
-                    proposals.append(d_i)
-                    dlogits.append(dreal)
-                    cur_tok, cur_pos = d_i, lens + i
-
-                # Single batched target verify over [n0, d_1..d_k].
-                verify = jnp.stack([n0] + proposals, axis=1)   # [S, k+1]
-                logits, pool, _state, _aux = target_forward(
-                    params, verify, pool, (), tables, lens, c, bt,
-                    kernel=kernel)
-                treal = logits[:, :, :V]                       # [S, k+1, V]
-
-                props = jnp.stack(proposals, axis=1)           # [S, k]
-                dreal_all = jnp.stack(dlogits, axis=1)         # [S, k, V]
-                # Greedy acceptance: exact argmax prefix match. Sampled:
-                # u < p(d)/q(d) (target/draft probability of the proposal).
-                match = props == jnp.argmax(treal[:, :k], axis=-1)
-                tcol = temp_safe[:, :, None]
-                p_probs = jax.nn.softmax(treal[:, :k] / tcol, axis=-1)
-                q_probs = jax.nn.softmax(dreal_all / tcol, axis=-1)
-                p_d = jnp.take_along_axis(
-                    p_probs, props[..., None], axis=-1)[..., 0]
-                q_d = jnp.take_along_axis(
-                    q_probs, props[..., None], axis=-1)[..., 0]
-                u = jax.vmap(jax.vmap(
-                    lambda kk: jax.random.uniform(kk)))(sub_acc)
-                samp_ok = u * jnp.maximum(q_d, 1e-30) < p_d
-                ok = jnp.where(greedy[:, None], match, samp_ok)
-                ok = ok & spec_on[:, None] & active[:, None]
-                run = jnp.cumprod(ok.astype(jnp.int32), axis=1)
-                a = jnp.sum(run, axis=1)                       # [S] in 0..k
-                full = a == k
-                adv = (1 + a) * adv_gate
-                lens_new = lens + adv
-
-                # Replacement at the rejection point: residual sampling
-                # max(p - q, 0) keeps the OVERALL emitted distribution equal
-                # to the target's (greedy: plain target argmax).
-                t_at_a = jnp.take_along_axis(
-                    treal, a[:, None, None], axis=1)[:, 0]     # [S, V]
-                q_at_a = jnp.take_along_axis(
-                    dreal_all, jnp.minimum(a, k - 1)[:, None, None],
-                    axis=1)[:, 0]
-                p_a = jax.nn.softmax(t_at_a / temp_safe, axis=-1)
-                q_a = jax.nn.softmax(q_at_a / temp_safe, axis=-1)
-                resid = jnp.maximum(p_a - q_a, 0.0)
-                rsum = jnp.sum(resid, axis=-1, keepdims=True)
-                resid = jnp.where(rsum > 0, resid / rsum, p_a)
-                r_samp = jax.vmap(jax.random.categorical)(
-                    sub_res, jnp.log(resid + 1e-30))
-                repl = jnp.where(greedy, jnp.argmax(t_at_a, axis=-1),
-                                 r_samp).astype(jnp.int32)
-
-                tail_new = jnp.take_along_axis(
-                    verify, a[:, None], axis=1)[:, 0]
-                tail = jnp.where(active, tail_new, tail)
-                pending = jnp.where(active, repl, pending)
-                # A spec_on slot that rejected carries the residual draw as
-                # next step's n0 (use_pending); a fully-accepted slot
-                # refreshes `last` from verify position k. A spec_OFF slot
-                # never really rejected (the gate force-fails acceptance),
-                # so the residual draw would be the WRONG distribution —
-                # it refreshes `last` from verify position 0 (its n0's
-                # logits, exactly the plain decode chain) and drops any
-                # pending carry.
-                use_pending = jnp.where(active, ~full & spec_on,
-                                        use_pending)
-                refresh = active & (full | ~spec_on)
-                row_idx = jnp.where(spec_on, k, 0)
-                row = jnp.take_along_axis(
-                    logits, row_idx[:, None, None], axis=1)[:, 0]
-                last = jnp.where(refresh[:, None], row, last)
-                keys = jnp.where(act_col, keys2, keys)
-                return ((pool, draft_pool, lens_new, last, keys, tail,
-                         pending, use_pending),
-                        (verify, adv, a * adv_gate))
-
-            carry0 = (pool, draft_pool,
-                      jnp.asarray(lengths), last, keys, jnp.asarray(tail),
-                      jnp.asarray(pending), jnp.asarray(use_pending))
-            (pool, draft_pool, _lens, last, keys, tail,
-             pending, use_pending), (toks, counts, accepted) = lax.scan(
-                step, carry0, None, length=chunk)
-            return (toks.transpose(1, 0, 2), counts.T, accepted.T,
-                    pool, draft_pool, last, keys, tail,
-                    pending, use_pending)
-
-        self._spec_decode_fns[key_ck] = spec_decode
-        return spec_decode
-
     def copy_fn(self):
         """copy_block(pool, src, dst) -> pool: the copy-on-write primitive
         — duplicate one shared block (a prefix-hit partial tail) into a
@@ -843,37 +598,6 @@ class PagedGenerator:
 
             self._copy_fn = copy_block
         return self._copy_fn
-
-    def extract_fn(self, nb: int):
-        """extract(pool, block_ids [nb]) -> the blocks of every pool array
-        (GPT-2: ``(k [L,nb,bt,H*Dh], v)``): gather a chain's blocks for the
-        KV tier's spill or drain migration (the pool itself is NOT donated
-        — the engine keeps serving from it)."""
-        fn = self._extract_fns.get(nb)
-        if fn is None:
-
-            @jax.jit
-            def extract(pool, block_ids):
-                return jax.tree.map(lambda a: a[:, block_ids], pool)
-
-            fn = self._extract_fns[nb] = extract
-        return fn
-
-    def insert_fn(self, nb: int):
-        """insert(pool, blocks, block_ids [nb]) -> pool: scatter fetched or
-        migrated blocks (a tuple shaped as ``extract`` gives them) into the
-        pool — donated, so the upload lands in place of the old pool
-        buffers."""
-        fn = self._insert_fns.get(nb)
-        if fn is None:
-
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            def insert(pool, blocks, block_ids):
-                return jax.tree.map(
-                    lambda a, b: a.at[:, block_ids].set(b), pool, blocks)
-
-            fn = self._insert_fns[nb] = insert
-        return fn
 
 
 class NoFreeBlocks(RuntimeError):
@@ -1079,55 +803,6 @@ class KVBlockManager:
                 if key in self._tail_by_key:
                     return hit_len + t
             return hit_len
-
-    def pin(self, block_ids: Sequence[int]) -> None:
-        """Refcount-bump blocks the caller already holds ids for (CACHED ->
-        ACTIVE as needed) — the KV-tier spill/migrate paths pin a retired
-        chain before copying it off-device so eviction can't race the
-        extract."""
-        with self._lock:
-            for b in block_ids:
-                if self._ref.get(b, 0) == 0:
-                    self._cached.pop(b, None)
-                self._ref[b] = self._ref.get(b, 0) + 1
-
-    def pin_chain(self, tokens: Sequence[int],
-                  n_real: int) -> Tuple[List[int], int]:
-        """Pin a registered chain EXACTLY as :meth:`register_chain` laid it
-        out: every full block of ``tokens[:n_real]`` plus the exact partial
-        tail entry. Unlike :meth:`lookup` (whose ``len - 1`` cap can never
-        see a chain's own full-length tail), this is the export walk for
-        spill/migration. Returns ``(pinned_ids, covered_tokens)`` — empty
-        when even the first block is gone (counters untouched; not a serving
-        lookup)."""
-        from ray_tpu.util import blockhash
-
-        bt = self.block_tokens
-        n_full = n_real // bt
-        digests = blockhash.block_hashes(tokens[:n_real], bt,
-                                         max_blocks=n_full)
-        with self._lock:
-            ids: List[int] = []
-            parent = blockhash.SEED
-            for d in digests:
-                b = self._by_hash.get(d)
-                if b is None:
-                    break
-                ids.append(b)
-                parent = d
-            covered = len(ids) * bt
-            if len(ids) == n_full and n_real > covered:
-                key = (parent, tuple(int(x) for x in
-                                     tokens[covered:n_real]))
-                b = self._tail_by_key.get(key)
-                if b is not None:
-                    ids.append(b)
-                    covered = n_real
-            for b in ids:
-                if self._ref.get(b, 0) == 0:
-                    self._cached.pop(b, None)      # CACHED -> ACTIVE
-                self._ref[b] = self._ref.get(b, 0) + 1
-            return ids, covered
 
     def note_cow(self) -> None:
         with self._lock:

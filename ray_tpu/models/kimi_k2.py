@@ -370,9 +370,6 @@ PAGED_FAMILY = PagedFamily(
     prefill=forward_prefill_paged,
     decode=forward_decode_paged,
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
-    # As LongCat: a draft model of its own family and pool, and a tier
-    # payload that carries one latent array, are work not done yet.
-    unsupported=("draft_model", "kv_tier"),
     aux_counts=longcat.AUX_COUNTS,
     describe=describe,
 )

@@ -336,9 +336,5 @@ PAGED_FAMILY = PagedFamily(
     prefill=forward_prefill_paged,
     decode=forward_decode_paged,
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
-    # Each needs work this family has not had: a draft model of its own
-    # family and pool, a handoff lane and a tier payload that carry one
-    # latent array instead of a (k, v) pair.
-    unsupported=("draft_model", "kv_tier"),
     aux_counts=AUX_COUNTS,
 )

@@ -487,9 +487,8 @@ PAGED_FAMILY = PagedFamily(
     decode=forward_decode_paged,
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
     init_slot_state=init_slot_state,
-    # A draft model would need a state of its own and a verify that advances
-    # a state several tokens; the KV tier and the prefix cache hand out rows
-    # at a position p, usable only with every linear layer's state at p,
-    # which nothing keeps yet (ROADMAP R4: snapshots at block boundaries).
-    unsupported=("draft_model", "kv_tier", "prefix_cache"),
+    # The prefix cache hands out rows at a position p, usable only with
+    # every linear layer's state at p, which nothing keeps yet (ROADMAP R4:
+    # snapshots at block boundaries).
+    unsupported=("prefix_cache",),
 )

@@ -85,12 +85,6 @@ class SLOPolicy:
         self._low_since: Optional[float] = None
         # When the deployment FIRST became continuously idle.
         self._idle_since: Optional[float] = None
-        # Drain-by-migration pacing (cluster KV tier): retire at most ONE
-        # replica per downscale decision so each victim gets a migration
-        # target and the controller never drains two replicas into each
-        # other. Set by the controller when the tier is on; the
-        # downscale_delay_s cooldown then paces the steps.
-        self.drain_single_step: bool = False
 
     # -- signal math ----------------------------------------------------------
 
@@ -136,8 +130,6 @@ class SLOPolicy:
                     and current > lo):
                 self._low_since = None
                 self._last_resize_t = now
-                if self.drain_single_step:
-                    return max(lo, current - 1)
                 return lo
         else:
             self._idle_since = None
@@ -171,8 +163,6 @@ class SLOPolicy:
                 target = max(lo, min(current, math.ceil(current * p)))
                 if target == current:
                     target = current - 1
-                if self.drain_single_step:
-                    target = max(target, current - 1)
                 target = max(lo, target)
                 if target < current:
                     self._last_resize_t = now

@@ -110,15 +110,6 @@ class ServeControllerActor:
         # scale-up loop respawns replacements — a replica lost mid-scale-up
         # must still converge to the target count.
         self._dead: set = set()
-        # Drain-then-retire (cluster KV tier): deployment -> {victim actor
-        # key -> survivor actor key}. Published in get_snapshot as
-        # "migrations" so routers REWRITE the victim's prefix-affinity
-        # entries to the survivor instead of sweeping them.
-        self._drain_map: Dict[str, Dict[str, str]] = {}
-        # victim actor key -> (out_ref, in_ref, started_at): in-flight KV
-        # migrations; _collect_retired holds the kill until the victim's
-        # kv_migrate_out resolves (or the drain timeout lapses).
-        self._migrations: Dict[str, tuple] = {}
         self._reconcile_thread = threading.Thread(target=self._loop, daemon=True)
         self._reconcile_thread.start()
 
@@ -193,8 +184,6 @@ class ServeControllerActor:
                         for r, _since, _ref in lst]
             self._replicas.clear()
             self._retiring.clear()
-            self._migrations.clear()
-            self._drain_map.clear()
         for r in victims:
             try:
                 ray_tpu.kill(r)
@@ -243,11 +232,6 @@ class ServeControllerActor:
                     # Per-tenant admission quotas (serve/admission.py);
                     # handles enforce them in front of the router.
                     "tenant_quotas": t.config.tenant_quotas,
-                    # Drain-then-retire rewrites: victim actor key ->
-                    # survivor actor key. Routers follow these to move a
-                    # drained replica's prefix-affinity entries to the
-                    # replica that imported its KV chains.
-                    "migrations": dict(self._drain_map.get(name, {})),
                 }
             return self._version, table
 
@@ -356,9 +340,6 @@ class ServeControllerActor:
                 # policy (cooldown timers reset with the new targets).
                 policy = SLOPolicy(asc)
                 self._policies[t.name] = policy
-            # With the KV tier on every downscale is a drain-by-migration:
-            # one victim per decision so each gets a survivor to drain to.
-            policy.drain_single_step = bool(config().kv_tier_enabled)
             sig = self._build_signals(t, asc, now)
             desired = policy.desired(t.target_replicas, sig, now)
             if desired > t.target_replicas and policy.ttft_violated(sig):
@@ -565,13 +546,6 @@ class ServeControllerActor:
                                 done = True
                             probe = None
                 if done:
-                    # Drain-THEN-retire (cluster KV tier): the replica has
-                    # finished its in-flight streams — before the kill,
-                    # migrate its warm prefix chains (now including those
-                    # streams' final turns) to a survivor and hold the
-                    # kill until the migration resolves or times out.
-                    done = self._migration_settled(name, replica, now)
-                if done:
                     try:
                         ray_tpu.kill(replica)
                     except Exception:  # noqa: BLE001 — already dead
@@ -582,75 +556,6 @@ class ServeControllerActor:
                 self._retiring[name] = keep
             else:
                 self._retiring.pop(name, None)
-
-    def _migration_settled(self, name: str, replica, now: float) -> bool:
-        """True when the drained replica's KV migration is complete (or the
-        tier is off / no survivor exists / the drain timed out) — only then
-        may the kill proceed. First call starts the migration."""
-        try:
-            if not bool(config().kv_tier_enabled):
-                return True
-        except Exception:  # noqa: BLE001 — config gone mid-teardown
-            return True
-        key = replica.actor_id.hex()
-        mig = self._migrations.get(key)
-        if mig is None:
-            return not self._start_migration(name, replica)
-        out_ref, in_ref, started = mig
-        try:
-            timeout = float(config().kv_tier_drain_timeout_s)
-        except Exception:  # noqa: BLE001
-            timeout = 10.0
-        resolved, _ = ray_tpu.wait([out_ref], num_returns=1, timeout=0)
-        # +2s: the survivor's kv_migrate_in holds the lane open for the
-        # same drain timeout — give the victim's send loop that long too.
-        if not resolved and now - started <= timeout + 2.0:
-            return False
-        self._migrations.pop(key, None)
-        for ref in (out_ref, in_ref):  # harvest so errors don't go unread
-            try:
-                n = ray_tpu.get(ref, timeout=0.5)
-                flightrec.record("serve", name, f"kv drain moved {n}")
-            except Exception:  # noqa: BLE001 — victim died / timed out
-                log_swallowed(logger, "kv drain migration result")
-        return True
-
-    def _start_migration(self, name: str, victim) -> bool:
-        """Kick off victim -> survivor KV migration: survivor CREATES the
-        lane (kv_migrate_in), victim attaches and ships (kv_migrate_out),
-        and the routing snapshot learns the affinity rewrite. False when
-        there is nothing to migrate to (last replica / none ready)."""
-        vkey = victim.actor_id.hex()
-        with self._lock:
-            t = self._targets.get(name)
-            if t is None:
-                return False
-            fresh = [r for v, r in self._replicas.get(name, [])
-                     if v == t.version]
-            ready = [r for r in fresh if r.actor_id.hex() in self._ready]
-        candidates = [r for r in (ready or fresh)
-                      if r.actor_id.hex() != vkey]
-        if not candidates:
-            return False
-        survivor = candidates[0]
-        skey = survivor.actor_id.hex()
-        lane = f"kvdrain-{name}-{vkey[:12]}"
-        try:
-            in_ref = survivor.kv_migrate_in.remote(lane)
-            out_ref = victim.kv_migrate_out.remote(lane)
-        except Exception:  # noqa: BLE001 — either side already dead
-            log_swallowed(logger, "kv drain migration start")
-            return False
-        self._migrations[vkey] = (out_ref, in_ref, time.monotonic())
-        with self._lock:
-            dm = self._drain_map.setdefault(name, {})
-            dm[vkey] = skey
-            while len(dm) > 64:  # bounded history; routers refresh fast
-                dm.pop(next(iter(dm)))
-            self._version += 1  # long-poll: routers must see the rewrite
-        flightrec.record("serve", name,
-                         f"kv drain {vkey[:12]} -> {skey[:12]}")
-        return True
 
 
 def get_or_create_controller():

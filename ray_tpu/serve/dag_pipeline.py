@@ -25,12 +25,8 @@ the A/B baseline ``benches/dag_tick.py`` measures against.
 from __future__ import annotations
 
 import itertools
-import struct
 import threading
-import time
-from typing import Any, List, Optional, Tuple
-
-import numpy as np
+from typing import Any, List, Optional
 
 import ray_tpu
 from ray_tpu.dag.dag_node import InputNode
@@ -164,130 +160,3 @@ def build_compiled_pipeline(controller, stage_names: List[str], *,
                 log_swallowed(logger, "pipeline build unwind")
         raise
     return PipelineHandle(stage_names, compiled_lanes)
-
-
-class KVHandoffLane:
-    """Engine→engine KV-block transport over one multi-slot shm
-    :class:`~ray_tpu.dag.channel.Channel` — the lane the cluster KV tier's
-    drain-by-migration ships a retiring replica's warm chains over
-    (``serve/llm.py LLMEngine.kv_migrate_out`` / ``kv_migrate_in``).
-
-    A chain's pool blocks travel as one framed payload::
-
-        [meta_len, k_len, v_len : <QQQ>] [pickled meta] [raw K] [raw V]
-
-    where meta carries the chain (its tokens and how many are real) and the
-    K/V dtype+shape needed to reinterpret the raw bytes.
-    ``send`` lands the arrays DIRECTLY in the ring slot via the channel's
-    ``_wait_writable``/``_publish`` split (no intermediate buffer), and
-    ``recv`` returns zero-copy ``np.frombuffer`` views into the slot plus an
-    ack token: the DEFERRED-ACK protocol (``_consume_view``/``_ack``) built
-    for DMA in PR 7 — the receiving engine uploads the views into its own
-    pool (a donated ``insert_fn`` dispatch), blocks until the transfer
-    lands, and only then releases the slot back to the writer. Up to
-    ``slots`` handoffs ride in flight, so the sender keeps extracting while
-    the receiver drains.
-
-    Single-writer (the victim) / single-reader (the survivor), in- or
-    cross-process: the other endpoint attaches by ``name`` with
-    ``create=False``, same as every other channel endpoint.
-    """
-
-    _HDR = struct.Struct("<QQQ")
-
-    def __init__(self, name: Optional[str] = None,
-                 capacity: int = 8 * 1024 * 1024,
-                 slots: Optional[int] = None, create: bool = True):
-        from ray_tpu.dag.channel import Channel
-
-        self.chan = Channel(name=name, capacity=capacity, create=create,
-                            slots=slots)
-        self.name = self.chan.name
-
-    @classmethod
-    def attach(cls, name: str, timeout: float = 10.0,
-               capacity: int = 8 * 1024 * 1024,
-               slots: Optional[int] = None) -> Optional["KVHandoffLane"]:
-        """Attach to a lane some OTHER endpoint creates, retrying until it
-        appears or ``timeout`` lapses (None on timeout). The KV-tier drain
-        path races lane creation against attachment — the survivor creates,
-        the retiring victim attaches — so the attach side polls instead of
-        requiring create-before-attach ordering. ``capacity``/``slots``
-        must MATCH the creator's (the shm mapping is sized from them; both
-        drain endpoints derive them from the same model config)."""
-        deadline = time.monotonic() + timeout
-        while True:
-            try:
-                return cls(name=name, capacity=capacity, slots=slots,
-                           create=False)
-            except Exception:  # noqa: BLE001 — shm segment not there yet
-                if time.monotonic() > deadline:
-                    return None
-                time.sleep(0.01)
-
-    # -- writer half (the sending engine) -------------------------------------
-    def send(self, meta: dict, k: np.ndarray, v: np.ndarray,
-             timeout: Optional[float] = 30.0) -> None:
-        from ray_tpu.core import serialization
-
-        k = np.ascontiguousarray(k)
-        v = np.ascontiguousarray(v)
-        meta = dict(meta)
-        meta["dtype"] = str(k.dtype)
-        meta["shape"] = tuple(int(d) for d in k.shape)
-        blob = serialization.dumps(meta)
-        total = self._HDR.size + len(blob) + k.nbytes + v.nbytes
-        if total > self.chan.capacity:
-            raise ValueError(
-                f"KV handoff of {total} bytes exceeds lane capacity "
-                f"{self.chan.capacity}")
-        self.chan._wait_writable(timeout)
-        mm = self.chan._mm
-        off = self.chan._wpayload_off
-        self._HDR.pack_into(mm, off, len(blob), k.nbytes, v.nbytes)
-        off += self._HDR.size
-        mm[off:off + len(blob)] = blob
-        off += len(blob)
-        np.frombuffer(mm, np.uint8, k.nbytes, off)[:] = \
-            k.reshape(-1).view(np.uint8)
-        off += k.nbytes
-        np.frombuffer(mm, np.uint8, v.nbytes, off)[:] = \
-            v.reshape(-1).view(np.uint8)
-        self.chan._publish(total)
-
-    # -- reader half (the receiving engine) -----------------------------------
-    def recv(self, timeout: Optional[float] = 30.0
-             ) -> Tuple[dict, np.ndarray, np.ndarray, Tuple[int, int]]:
-        """Return ``(meta, k, v, ack_token)``. ``k``/``v`` are views into
-        the ring slot — they stay valid (the writer cannot reuse the slot)
-        until ``ack(ack_token)``; copy or upload them first."""
-        from ray_tpu.core import serialization
-        from ray_tpu.dag.channel import _CLOSE, ChannelClosed
-
-        view, length, slot, seq = self.chan._consume_view(timeout)
-        if length == len(_CLOSE) and bytes(view[:length]) == _CLOSE:
-            self.chan._ack(slot, seq)
-            raise ChannelClosed(self.name)
-        meta_len, k_len, v_len = self._HDR.unpack_from(view, 0)
-        off = self._HDR.size
-        meta = serialization.loads(bytes(view[off:off + meta_len]))
-        off += meta_len
-        dt = np.dtype(meta["dtype"])
-        shape = tuple(meta["shape"])
-        k = np.frombuffer(view, dt, k_len // dt.itemsize, off).reshape(shape)
-        off += k_len
-        v = np.frombuffer(view, dt, v_len // dt.itemsize, off).reshape(shape)
-        return meta, k, v, (slot, seq)
-
-    def ack(self, token: Tuple[int, int]) -> None:
-        self.chan._ack(*token)
-
-    # -- lifecycle ------------------------------------------------------------
-    def close(self) -> None:
-        self.chan.close()
-
-    def detach(self) -> None:
-        self.chan.detach()
-
-    def destroy(self) -> None:
-        self.chan.destroy()

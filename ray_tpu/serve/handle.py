@@ -223,8 +223,9 @@ class Router:
                         if k in live}
                     for k in [k for k in self._ongoing if k not in live]:
                         del self._ongoing[k]
-                    self._sweep_affinity_locked(
-                        live, entry.get("migrations") or {})
+                    aff = self._affinity_map()
+                    for h in [h for h, k in aff.items() if k not in live]:
+                        del aff[h]
                 # Quota table rides the same snapshot: serve.run updates
                 # apply to in-flight handles on their next refresh.
                 self._admission().update(entry.get("tenant_quotas"))
@@ -237,26 +238,6 @@ class Router:
 
     def _key(self, replica) -> str:
         return replica.actor_id.hex()
-
-    def _sweep_affinity_locked(self, live: set,
-                               migrations: Dict[str, str]) -> None:
-        """Affinity entries for a replica that left the snapshot: a DRAINED
-        replica's entries are REWRITTEN to its migration target (the
-        survivor imported its KV chains, so the prefix is warm there),
-        chain-following in case the target itself drained since; only
-        entries with no live target are swept. Under ``_lock``."""
-        aff = self._affinity_map()
-        for h, k in list(aff.items()):
-            if k in live:
-                continue
-            seen = set()
-            while k in migrations and k not in live and k not in seen:
-                seen.add(k)
-                k = migrations[k]
-            if k in live:
-                aff[h] = k
-            else:
-                del aff[h]
 
     def _dec(self, key: str) -> None:
         with self._lock:

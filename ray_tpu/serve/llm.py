@@ -27,9 +27,7 @@ Scheduling is iteration-level (the vLLM/Orca policy): each engine step
 So one decode program is always queued behind the one that runs, and the
 host's work of a step lies under it: no stop condition reads a token's value
 (there is no stop token), and the device threads last tokens, keys and pool
-from program to program itself. With a draft model a slot's advance IS a
-value (the step's acceptance), so there the chunk is fetched in the step that
-dispatched it.
+from program to program itself.
 
 There is no engine thread: the step loop is driven by whichever request
 thread wins a non-blocking try-lock (``drive``), so an idle engine owns no
@@ -58,7 +56,7 @@ import numpy as np
 
 from ray_tpu.devtools import jitcheck
 from ray_tpu.models.generate import (KVBlockManager, NoFreeBlocks,
-                                     PagedGenerator, paged_family)
+                                     PagedGenerator)
 from ray_tpu.serve.errors import Saturated
 from ray_tpu.util import tracing
 from ray_tpu.utils.logging import get_logger
@@ -360,16 +358,14 @@ class _Chunk:
     slot whose request ends with this chunk is given to the next request
     before this chunk's tokens are fetched."""
 
-    __slots__ = ("arrays", "rows", "spec", "start_ns")
+    __slots__ = ("arrays", "rows", "start_ns")
 
-    def __init__(self, arrays, rows: List[tuple], spec: bool, start_ns: int):
+    def __init__(self, arrays, rows: List[tuple], start_ns: int):
         # (tokens, the family's count arrays: the decode call's and that
         # step's prefills'), still on the device.
         self.arrays = arrays
-        # (slot, request, tokens it may still take) per active slot; the
-        # last is None for a speculative chunk, whose advance is a value.
+        # (slot, request, tokens it may still take) per active slot.
         self.rows = rows
-        self.spec = spec
         # When the device could start on it: its dispatch, or the fetch of
         # the chunk it was queued behind.
         self.start_ns = start_ns
@@ -414,9 +410,7 @@ class LLMEngine:
     returns when k ends, with k+1 and its prefills already in the device's
     queue. A step that finds nothing to dispatch but a chunk pending fetches
     and delivers it; a step on an engine with nothing pending dispatches and
-    returns. The depth is one and is not a setting. An engine with a draft
-    model fetches in the step that dispatched (depth 0): its next operands
-    depend on the step's acceptance, a value.
+    returns. The depth is one and is not a setting.
 
     **When a retired request's blocks return to the pool.** Retirement by
     count frees the SLOT at schedule time, one step before the request's
@@ -436,20 +430,10 @@ class LLMEngine:
                  name: str = "LLM",
                  block_tokens: Optional[int] = None,
                  pool_blocks: Optional[int] = None,
-                 attention_kernel: Optional[str] = None,
-                 draft_params=None,
-                 draft_config=None,
-                 spec_tokens: Optional[int] = None):
+                 attention_kernel: Optional[str] = None):
         from ray_tpu.core.config import config as _get_config
-        from ray_tpu.serve.kv_tier import kv_tier_enabled
 
         knobs = _get_config()
-        # What the family cannot run yet is refused HERE, not in a step
-        # (a draft model: by the generator, below).
-        if kv_tier_enabled() and "kv_tier" in paged_family(config).unsupported:
-            raise ValueError(
-                f"{type(config).__name__}: the cluster KV tier "
-                f"(kv_tier_enabled) is not supported for this family yet")
         self.config = config
         self.max_len = max_len or config.max_seq_len
         self.buckets = sorted(prompt_buckets or _default_buckets(self.max_len))
@@ -464,15 +448,6 @@ class LLMEngine:
         self.attention_kernel = str(
             attention_kernel if attention_kernel is not None
             else knobs.serve_paged_attention_kernel)
-        self.spec_k = int(spec_tokens if spec_tokens is not None
-                          else knobs.serve_spec_tokens)
-        if self.spec_k > 0 and draft_params is None:
-            raise ValueError(
-                "serve_spec_tokens > 0 needs a draft model "
-                "(draft_params/draft_config)")
-        self._spec = self.spec_k > 0
-        self._spec_floor = float(knobs.serve_spec_accept_floor)
-        self._spec_alpha = float(knobs.serve_spec_accept_alpha)
 
         self.blocks_per_seq = -(-self.max_len // self.block_tokens)
         num_blocks = int(pool_blocks if pool_blocks is not None
@@ -485,9 +460,7 @@ class LLMEngine:
                                   num_blocks=num_blocks,
                                   block_tokens=self.block_tokens,
                                   max_len=self.max_len,
-                                  attention_kernel=self.attention_kernel,
-                                  draft_params=draft_params,
-                                  draft_config=draft_config)
+                                  attention_kernel=self.attention_kernel)
         self.kv = KVBlockManager(num_blocks, self.block_tokens)
         (self._pool, self._slot_state, self._last,
          self._keys) = self._pg.init_state()
@@ -516,8 +489,6 @@ class LLMEngine:
                                     np.int32)
         self._slot_blocks: List[List[int]] = [[] for _ in range(self.slots)]
         self._hit_pending = 0  # hit tokens awaiting metric flush (step thread)
-        self._init_tier_state()
-        self._init_spec_state()
 
         # Lock order: _step_lock (try-acquired, never under others) →
         # _state_lock (request/slot bookkeeping; also every req.cond) →
@@ -573,67 +544,6 @@ class LLMEngine:
         # when the next step starts. Step-thread-owned.
         self._blocked_since_ns: Optional[int] = None
 
-    def _init_tier_state(self) -> None:
-        # Cluster KV tier (serve/kv_tier.py). All tier state is touched
-        # under the locks noted inline; with the flag off every field stays
-        # empty and every tier branch is dead — exact engine-private
-        # behavior.
-        from ray_tpu.serve.kv_tier import KVTier, kv_tier_enabled
-
-        self._tier = KVTier(self.name) if kv_tier_enabled() else None
-        from ray_tpu.core.config import config as _get_config
-
-        try:
-            knobs = _get_config()
-            self._tier_min_spill = max(
-                1, int(knobs.kv_tier_min_spill_blocks))
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            self._tier_min_spill = 1
-        # Retired chains pinned for spill: (chain, full_ids, n_full,
-        # digests — the chain's full-block hash list).
-        # Appended under _state_lock by the step thread's retire phase,
-        # drained by _post_step — both inside the _step_lock scope.
-        self._tier_spill_q: List[tuple] = []
-        # head digest -> (chain tuple, n_real): the drain-migration export
-        # set (active sessions' chains). Insertion-ordered LRU, bounded.
-        self._tier_chains: "Dict[bytes, tuple]" = {}
-        # Digests of chains that arrived via drain migration (ordered-set
-        # dict, bounded) — attributes their local hits to source=migrated.
-        self._tier_migrated: "Dict[bytes, None]" = {}
-        self._tier_hits_pending = {"local": 0, "store": 0, "migrated": 0}
-        self._tier_hits_total = {"local": 0, "store": 0, "migrated": 0}
-        self._tier_spill_bytes_pending = 0
-        self._tier_fetch_bytes_pending = 0
-
-    _TIER_CHAIN_CAP = 512       # migration export set
-    _TIER_MIGRATED_CAP = 4096   # migrated-digest attribution set
-
-    def _tier_note_chain_locked(self, head: bytes, chain, n_real: int) -> None:
-        # Under _state_lock. LRU re-insert, like the KV manager's cache.
-        self._tier_chains.pop(head, None)
-        self._tier_chains[head] = (tuple(int(t) for t in chain), int(n_real))
-        while len(self._tier_chains) > self._TIER_CHAIN_CAP:
-            self._tier_chains.pop(next(iter(self._tier_chains)))
-
-    def _init_spec_state(self) -> None:
-        # Speculative-decoding host state — all [S], step-thread-owned
-        # except the per-slot resets at admission/release (under
-        # _state_lock, which the step thread also holds there).
-        if not self._spec:
-            return
-        self._draft_pool = self._pg.init_draft_state()
-        self._spec_tail = np.zeros(self.slots, np.int32)
-        self._spec_pending = np.zeros(self.slots, np.int32)
-        self._spec_use_pending = np.zeros(self.slots, bool)
-        self._spec_ewma = np.ones(self.slots, np.float32)
-        self._spec_on = np.zeros(self.slots, bool)
-        self._last_counts = None        # last spec step's [S, chunk] advances
-        self._spec_last_dt = 0.0
-        self._spec_proposed_pending = 0  # await metric flush (step thread)
-        self._spec_accepted_pending = 0
-        self._spec_proposed_total = 0
-        self._spec_accepted_total = 0
-
     def set_params(self, params) -> None:
         """Serve new weights of the same shapes (a policy update between
         rollouts; no request may be in flight). The generator makes its
@@ -654,18 +564,10 @@ class LLMEngine:
          self._keys) = self._pg.init_state()
         self._decode_aux, self._prefill_aux = None, []
         self._pending = None
-        # Pool contents are gone — the prefix cache resets with it. Queued
-        # spill entries and tracked chains point into the dead pool, so
-        # they go too (their pins die with the replaced manager); chains
-        # ALREADY published to the tier survive — those payloads are host
-        # copies in the object plane, not pool references.
+        # Pool contents are gone — the prefix cache resets with it.
         self.kv = KVBlockManager(self.kv.num_blocks, self.block_tokens)
         self._slot_table[:] = 0
         self._slot_blocks = [[] for _ in range(self.slots)]
-        self._tier_spill_q = []
-        self._tier_chains = {}
-        self._tier_migrated = {}
-        self._init_spec_state()
 
     # -- public single-request surface ---------------------------------------
     def warmup(self) -> None:
@@ -696,40 +598,6 @@ class LLMEngine:
             with wt.program("copy_block"):
                 cf = self._pg.copy_fn()
                 self._pool = cf(self._pool, 0, 0)
-            if self._tier is not None:
-                # Tier upload/download programs: compile HERE so a cold
-                # replica's first store fetch never pays XLA on its TTFT
-                # (block 0 is the padding block — inserting zeros is inert).
-                with wt.program("kv_tier_blocks"):
-                    k_pool = self._pool[0]
-                    zb = np.zeros((k_pool.shape[0], 1)
-                                  + tuple(k_pool.shape[2:]), k_pool.dtype)
-                    self._tier_insert_blocks(zb, zb, [0])
-                    self._tier_extract_blocks([0])
-            if self._spec:
-                for b in self.buckets:
-                    with wt.program("draft_prefill", b):
-                        dpf = self._pg.draft_prefill_fn(b)
-                        self._draft_pool = dpf(
-                            self._pg.draft_params, self._draft_pool,
-                            zero_row, np.zeros((1, b), np.int32), 0, b)
-                self._draft_pool = cf(self._draft_pool, 0, 0)
-                with wt.program("spec_decode"):
-                    sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
-                    out = sf(self._pg.params, self._pg.draft_params,
-                             self._pool, self._draft_pool, self._last,
-                             self._keys,
-                             np.zeros((self.slots, self.blocks_per_seq),
-                                      np.int32),
-                             np.zeros(self.slots, np.int32),
-                             np.zeros(self.slots, bool), self._greedy,
-                             self._temps, np.zeros(self.slots, bool),
-                             np.zeros(self.slots, np.int32),
-                             np.zeros(self.slots, np.int32),
-                             np.zeros(self.slots, bool))
-                    np.asarray(out[0])
-                (self._pool, self._draft_pool,
-                 self._last, self._keys) = out[3:7]
             self._reset_device_state()
             self._steady = True
         wt.close()
@@ -930,9 +798,6 @@ class LLMEngine:
         # Always: a request retired by count took its blocks with it, and a
         # parked slot's writes must land in the trash block, not in theirs.
         self._slot_table[slot, :] = 0
-        if self._spec:
-            self._spec_on[slot] = False
-            self._spec_use_pending[slot] = False
         r = self._slot_req[slot]
         if r is not None:
             r.slot = None
@@ -962,26 +827,6 @@ class LLMEngine:
         chain = [int(t) for t in req.prompt] + req.out_ids[:req.emitted]
         n_real = min(len(chain), len(ids) * self.block_tokens)
         self.kv.register_chain(chain, ids, n_real)
-        if self._tier is None:
-            return
-        # Refcounted publish from the retire path: pin the chain's FULL
-        # blocks (their content is final) and queue them for the spill
-        # drain in _post_step — LRU eviction can't beat the extract to
-        # them, and the pins drop the moment the payload is off-device.
-        from ray_tpu.util import blockhash
-
-        bt = self.block_tokens
-        n_full = n_real // bt
-        if n_full < self._tier_min_spill:
-            return
-        digests = blockhash.block_hashes(chain, bt, max_blocks=n_full)
-        head = digests[-1]
-        self._tier_note_chain_locked(head, chain[:n_real], n_real)
-        if not self._tier.is_published(head):
-            full_ids = list(ids[:n_full])
-            self.kv.pin(full_ids)
-            self._tier_spill_q.append(
-                (list(chain), full_ids, n_full, digests))
 
     def _retire_locked(self, req: _Request, reason: str) -> None:
         """Retire by count: every token the request may take is dispatched
@@ -1062,37 +907,9 @@ class LLMEngine:
             except BaseException as err:
                 self._fail_inflight(err)
                 raise
-            self._post_step()
         finally:
             st.close()      # whatever happened, no annotation stays open
         self._record_step(st)
-
-    def _post_step(self) -> None:
-        """Drain the KV tier's spill queue (chains pinned at retire), still
-        under _step_lock: extract the full blocks off-device and publish
-        them to the cluster tier, then unpin. EVERY step runs it, including
-        the one that retires the last request, so spill pins never strand on
-        an idle engine. Best-effort — a tier failure must never poison
-        serving (the chain stays locally cached either way)."""
-        if self._tier is None or not self._tier_spill_q:
-            return
-        q, self._tier_spill_q = self._tier_spill_q, []
-        for chain, ids, n_full, digests in q:
-            try:
-                if not self._tier.is_published(digests[-1]):
-                    k, v = self._tier_extract_blocks(ids)
-                    payload = {"k": k, "v": v,
-                               "tokens": list(chain[:n_full
-                                                    * self.block_tokens])}
-                    self._tier.publish_chain(digests, payload,
-                                             n_full * self.block_tokens,
-                                             n_full)
-                    self._tier_spill_bytes_pending += (
-                        payload["k"].nbytes + payload["v"].nbytes)
-            except Exception:  # noqa: BLE001 — spill is best-effort
-                logger.exception("kv tier spill failed on %s", self.name)
-            finally:
-                self.kv.release(ids)
 
     def _record_step(self, st: _StepTrace) -> None:
         """Fold one finished step into the counters and, traced, record it
@@ -1266,18 +1083,14 @@ class LLMEngine:
                 # are captured atomically with the active mask.
                 tables = self._slot_table.copy()
                 lengths = np.asarray(self._slot_len, np.int32)
-                spec_ops = (self._spec_operands_locked(lengths)
-                            if self._spec else None)
                 # The counts move to dispatch: the next schedule sees the
-                # lengths the device will have. A plain chunk advances every
-                # active slot by ``chunk``; a speculative one by the step's
-                # acceptance, a value, counted when it is delivered.
+                # lengths the device will have. A chunk advances every
+                # active slot by ``chunk``.
                 for slot in np.flatnonzero(active):
-                    req, upto = self._slot_req[slot], None
-                    if spec_ops is None:
-                        upto = min(self.chunk, req.max_new - req.scheduled)
-                        req.scheduled += upto
-                        self._slot_len[slot] += self.chunk
+                    req = self._slot_req[slot]
+                    upto = min(self.chunk, req.max_new - req.scheduled)
+                    req.scheduled += upto
+                    self._slot_len[slot] += self.chunk
                     rows.append((int(slot), req, upto))
             elif self._pending is None:
                 # Nothing in flight (and so nothing pins a block): the pool
@@ -1285,8 +1098,7 @@ class LLMEngine:
                 self._blocked_since_ns = None
 
         # 3. One batched decode chunk advancing every active slot, enqueued
-        #    behind the chunk that runs. The speculative program syncs
-        #    inside its dispatch to read acceptance counts.
+        #    behind the chunk that runs.
         if rows:
             st.enter("dispatch")
             st.attrs.update(batch=len(rows), ahead=self._pending is not None)
@@ -1295,20 +1107,16 @@ class LLMEngine:
             # No local names the tokens: the record alone holds them, and
             # lets go of them at the fetch.
             chunk = _Chunk(
-                (self._run_decode(active, greedy, temps, tables, lengths,
-                                  spec_ops), self._take_step_aux()),
-                rows, spec_ops is not None, st.marks[-1][1])
+                (self._run_decode(active, greedy, temps, tables, lengths),
+                 self._take_step_aux()),
+                rows, st.marks[-1][1])
 
-        # 4. Fetch and deliver. When the next operands depend on this
-        #    chunk's values (a draft model: a slot's advance is the step's
-        #    acceptance) the chunk just dispatched is fetched in this same
-        #    step; otherwise the one dispatched a step earlier is, and this
+        # 4. Fetch and deliver the chunk dispatched a step earlier; this
         #    step's waits its turn. ``_pending`` keeps the chunk being
         #    fetched until it is delivered, for _fail_inflight.
-        due = chunk if self._spec else self._pending
-        ttfts = self._deliver(st, due, chunk) if due is not None else []
-        if not self._spec:
-            self._pending = chunk
+        ttfts = (self._deliver(st, self._pending, chunk)
+                 if self._pending is not None else [])
+        self._pending = chunk
         st.enter("observe")
         self._observe(st.attrs["tokens"], ttfts)
 
@@ -1316,8 +1124,8 @@ class LLMEngine:
                  queued: Optional[_Chunk]) -> List[tuple]:
         """The step's single device sync (device_wait): ``due``'s tokens,
         which returns when ``due`` ends, while ``queued`` (the chunk this
-        step dispatched, if it is another) and its prefills already sit in
-        the device's queue. Then each row's tokens go to its request.
+        step dispatched, if any) and its prefills already sit in the
+        device's queue. Then each row's tokens go to its request.
         Returns (total, queued, prefill) seconds per first token."""
         st.enter("device_wait")
         host_toks, host_aux = jax.device_get(due.take())
@@ -1325,7 +1133,7 @@ class LLMEngine:
         now_ns = st.marks[-1][1]
         dt = (now_ns - due.start_ns) / 1e9
         now = now_ns / 1e9
-        if queued is not None and queued is not due:
+        if queued is not None:
             queued.start_ns = now_ns    # the device starts on it about now
 
         delivered_total = 0
@@ -1338,34 +1146,23 @@ class LLMEngine:
                     # Cancelled since the dispatch (slot and blocks went
                     # then): its tokens are dropped.
                     continue
-                # A plain chunk emits exactly ``chunk`` tokens a slot; a
-                # speculative one 1..chunk*(k+1), by the step's acceptance.
-                if due.spec:
-                    emitted, adv = self._spec_slot_result(host_toks, slot)
-                    self._slot_len[slot] += adv
-                    upto = min(len(emitted), req.max_new - req.emitted)
-                    req.scheduled += upto
-                else:
-                    emitted = host_toks[slot, :upto].tolist()
                 if upto > 0 and req.ttft_s is None:
                     req.ttft_s = now - req.submitted_at
                     ttfts.append((req.ttft_s, req.queued_s, req.prefill_s))
                     if req.trace_ctx is not None:
                         firsts.append((req, slot, upto))
-                new_toks = emitted[:upto]
+                new_toks = host_toks[slot, :upto].tolist()
                 req.tokens.extend(new_toks)
                 req.out_ids.extend(new_toks)
                 req.emitted += upto
                 req.decode_tokens += upto
                 req.decode_seconds += dt
                 delivered_total += upto
-                if req.retiring is not None:
-                    if req.emitted == req.scheduled:
-                        self._finish_locked(req, slot)
-                elif req.emitted >= req.max_new:
-                    # Fetched in the step that dispatched it: the request
-                    # still holds its slot.
-                    self._retire_locked(req, "stop")
+                # A request whose last chunk this is was retired by count
+                # in phase 1 of this step (``scheduled`` reached ``max_new``
+                # when the chunk was dispatched, a step ago).
+                if req.retiring is not None and req.emitted == req.scheduled:
+                    self._finish_locked(req, slot)
                 else:
                     req.cond.notify_all()
             # What the engine still holds once this step's tokens are out:
@@ -1405,14 +1202,6 @@ class LLMEngine:
             # Neither looked up nor, below, registered: a hit could not be
             # honoured without the slot state at the hit's position.
             full, tail, hit_len = [], None, 0
-        digests: List[bytes] = []
-        fetched = None          # (payload, from_block, to_block)
-        if self._tier is not None:
-            from ray_tpu.util import blockhash
-
-            cap = len(tokens) - 1
-            digests = blockhash.block_hashes(tokens, bt, max_blocks=cap // bt)
-            fetched = self._tier_probe(digests, len(full), hit_len)
         try:
             # The table must cover every position this sequence can ever
             # write: the prompt plus whole decode chunks until max_new is
@@ -1430,32 +1219,10 @@ class LLMEngine:
             self.kv.release(full + ([tail] if tail is not None else []))
             raise
         ids = list(full)
-        local_hit = hit_len
-        if fetched is not None:
-            # Cluster-tier hit past the local cache: upload the fetched
-            # full blocks into fresh pool blocks at their chain positions
-            # and prefill from there. The store chain supersedes a local
-            # tail hit (full blocks reach further than any partial tail).
-            payload, b_from, b_to = fetched
-            if tail is not None:
-                self.kv.release([tail])
-                tail = None
-            n_f = b_to - b_from
-            fb, fresh = fresh[:n_f], fresh[n_f:]
-            k_in = np.ascontiguousarray(payload["k"][:, b_from:b_to])
-            v_in = np.ascontiguousarray(payload["v"][:, b_from:b_to])
-            self._tier_insert_blocks(k_in, v_in, fb)
-            ids.extend(fb)
-            hit_len = b_to * bt
-            self._tier_fetch_bytes_pending += k_in.nbytes + v_in.nbytes
         if tail is not None:
             dst = fresh.pop(0)
             cf = self._pg.copy_fn()
             self._pool = cf(self._pool, int(tail), int(dst))
-            if self._spec:
-                # The draft pool mirrors the block tables, so a COW fork
-                # must duplicate the draft-side content of the tail too.
-                self._draft_pool = cf(self._draft_pool, int(tail), int(dst))
             self.kv.note_cow()
             self.kv.release([tail])  # pin the private copy, not the original
             ids.append(dst)
@@ -1504,13 +1271,6 @@ class LLMEngine:
                 self._state_counts["state_resets_total"] += 1
             if not self._prefix_cache:
                 self._state_counts["prefix_lookups_refused_total"] += 1
-        if self._spec:
-            # Warm the draft pool over the same suffix/table so the draft
-            # chain starts from draft-KV covering every committed position.
-            dpf = self._pg.draft_prefill_fn(req.bucket)
-            self._draft_pool = dpf(
-                self._pg.draft_params, self._draft_pool, row,
-                padded, hit_len, suffix_len)
         # Commit ATOMICALLY with the cancel path: this runs outside
         # _state_lock, so a concurrent _cancel may have freed the slot
         # mid-dispatch. Attaching first and registering later would let
@@ -1529,157 +1289,16 @@ class LLMEngine:
             if n_full_prompt and self._prefix_cache:
                 self.kv.register_chain(tokens, ids, n_full_prompt)
             self._hit_pending += hit_len
-            if self._tier is not None:
-                # Hit attribution by source: tokens past the local hit came
-                # from the store; local full-block hits on a chain a drain
-                # migration shipped in count as migrated.
-                store_part = hit_len - local_hit if fetched is not None else 0
-                local_part = hit_len - store_part
-                src = "local"
-                if local_part and any(d in self._tier_migrated
-                                      for d in digests[:len(full)]):
-                    src = "migrated"
-                self._tier_hits_pending[src] += local_part
-                self._tier_hits_total[src] += local_part
-                self._tier_hits_pending["store"] += store_part
-                self._tier_hits_total["store"] += store_part
-                if n_full_prompt and digests:
-                    nf = min(len(digests), n_full_prompt // bt)
-                    self._tier_note_chain_locked(
-                        digests[nf - 1], tokens[:nf * bt], nf * bt)
-            if self._spec and fetched is not None:
-                # Store-fetched blocks carry no draft-side KV — speculation
-                # stays off for this request rather than proposing from
-                # garbage draft state.
-                self._spec_on[slot] = False
-                self._spec_ewma[slot] = 0.0
-                self._spec_use_pending[slot] = False
-            elif self._spec:
-                # Fresh speculation state: the draft chain's first forward
-                # re-consumes the last prompt token at real_len - 1, so the
-                # tail starts as exactly that token. EWMA starts optimistic;
-                # the per-step headroom gate and acceptance feedback take it
-                # from there.
-                self._spec_tail[slot] = tokens[-1]
-                self._spec_pending[slot] = 0
-                self._spec_use_pending[slot] = False
-                self._spec_ewma[slot] = 1.0
-                self._spec_on[slot] = True
 
-    def _tier_probe(self, digests: List[bytes], n_local_full: int,
-                    hit_len: int):
-        """Probe the cluster directory for a chain longer than the local
-        hit; returns ``(payload, from_block, to_block)`` or None. Runs on
-        the step thread outside _state_lock (the fetch is an object-store
-        pull)."""
-        if len(digests) <= n_local_full:
-            return None      # local cache already covers every full block
-        m = self._tier.match(digests)
-        if m is None:
-            return None
-        j, entry = m
-        if (j + 1) * self.block_tokens <= hit_len:
-            return None      # the local hit reaches at least as far
-        payload = self._tier.fetch(digests[j], entry)
-        if not isinstance(payload, dict):
-            return None
-        k = payload.get("k")
-        if k is None or k.shape[1] < j + 1:
-            return None
-        return payload, n_local_full, j + 1
-
-    def _spec_operands_locked(self, lengths):
-        """The speculative program's extra operands, snapshotted under
-        _state_lock with the step's tables and lengths; None when this
-        step runs the plain program."""
-        # Headroom gate: a spec step can write chunk*(k+1) positions ahead,
-        # so slots without that much table room degrade to one token per
-        # step INSIDE the same program — the base retire rule
-        # (slot_len + chunk > max_len → length_cap before dispatch) stays
-        # valid either way.
-        cap = self.blocks_per_seq * self.block_tokens
-        headroom = lengths + self.chunk * (self.spec_k + 1) <= cap
-        spec_on = self._spec_on & headroom & self._active
-        if not (spec_on.any()
-                or (self._spec_use_pending & self._active).any()):
-            # Every slot degraded (low acceptance / no headroom / fetched
-            # from the store) and none still carries a rejection
-            # replacement: the plain one-token program is strictly cheaper
-            # than a spec step that would force-reject everything. (A
-            # just-demoted slot runs one more spec step, which consumes its
-            # pending token and clears the carry.)
-            return None
-        return (spec_on, self._spec_tail.copy(), self._spec_pending.copy(),
-                self._spec_use_pending.copy())
-
-    def _run_decode(self, active, greedy, temps, tables, lengths, spec_ops):
+    def _run_decode(self, active, greedy, temps, tables, lengths):
         """Dispatch the step's decode program; its tokens come back still on
         the device."""
-        if spec_ops is not None:
-            return self._run_spec_decode(active, greedy, temps, tables,
-                                         lengths, spec_ops)
-        if self._spec:
-            self._last_counts = None
         df = self._pg.decode_fn(self.chunk)
         (toks, self._pool, self._slot_state, self._last, self._keys,
          self._decode_aux) = df(
             self._pg.params, self._pool, self._slot_state, self._last,
             self._keys, tables, lengths, active, greedy, temps)
         return toks
-
-    def _run_spec_decode(self, active, greedy, temps, tables, lengths,
-                         spec_ops):
-        spec_on, tail, pending, use_pending = spec_ops
-        sf = self._pg.spec_decode_fn(self.chunk, self.spec_k)
-        t0 = time.perf_counter()
-        (toks, counts, accepted, self._pool, self._draft_pool,
-         self._last, self._keys, tail_j, pending_j,
-         up_j) = sf(
-            self._pg.params, self._pg.draft_params, self._pool,
-            self._draft_pool, self._last, self._keys, tables,
-            lengths, active, greedy, temps, spec_on, tail, pending,
-            use_pending)
-        # One batched fetch syncs the step: counts/accepted plus the spec
-        # chain state carried back to host. Safe wholesale: only the step
-        # thread writes these between operand snapshot and here, and
-        # per-slot admission resets happen before the NEXT step's snapshot.
-        (counts_np, accepted_np, tail_np, pending_np, up_np) = \
-            jax.device_get((counts, accepted, tail_j, pending_j, up_j))
-        self._spec_last_dt = time.perf_counter() - t0
-        self._last_counts = counts_np
-        # device_get views are read-only; the chain state is mutated
-        # in place by slot admission/free, so take writable copies.
-        self._spec_tail = np.array(tail_np)
-        self._spec_pending = np.array(pending_np)
-        self._spec_use_pending = np.array(up_np)
-        # Acceptance EWMA feeds next step's gate: slots whose EWMA sinks
-        # below the floor stop proposing for the rest of the request (their
-        # draft passes would cost more than the accepted tokens buy).
-        acc = accepted_np.sum(axis=1)
-        prop = np.where(spec_on, self.chunk * self.spec_k, 0)
-        live = prop > 0
-        if live.any():
-            rate = np.zeros(self.slots, np.float32)
-            rate[live] = acc[live] / prop[live]
-            a = self._spec_alpha
-            self._spec_ewma[live] = ((1.0 - a) * self._spec_ewma[live]
-                                     + a * rate[live])
-            self._spec_on[live] = self._spec_ewma[live] >= self._spec_floor
-        self._spec_proposed_pending += int(prop.sum())
-        self._spec_accepted_pending += int(acc.sum())
-        self._spec_proposed_total += int(prop.sum())
-        self._spec_accepted_total += int(acc.sum())
-        return toks
-
-    def _spec_slot_result(self, host_toks, slot: int):
-        """A speculative step's emitted tokens for ``slot`` and its
-        device-length advance. Under _state_lock."""
-        counts = self._last_counts[slot]          # [chunk] advances
-        toks = host_toks[slot]                    # [chunk, k+1]
-        out: List[int] = []
-        for t in range(counts.shape[0]):
-            out.extend(int(x) for x in toks[t, :counts[t]])
-        return out, int(counts.sum())
 
     def _take_step_aux(self):
         """Device arrays to fetch WITH the chunk's tokens, in its one
@@ -1717,20 +1336,8 @@ class LLMEngine:
 
     def _observe(self, delivered: int, ttfts: List[tuple]) -> None:
         hits, self._hit_pending = self._hit_pending, 0
-        if self._tier is not None:
-            with self._state_lock:
-                tier_hits = dict(self._tier_hits_pending)
-                for src in self._tier_hits_pending:
-                    self._tier_hits_pending[src] = 0
-            spill_b, self._tier_spill_bytes_pending = \
-                self._tier_spill_bytes_pending, 0
-            fetch_b, self._tier_fetch_bytes_pending = \
-                self._tier_fetch_bytes_pending, 0
         m = _metrics()
         if not m.metrics_enabled():
-            if self._spec:
-                self._spec_proposed_pending = 0
-                self._spec_accepted_pending = 0
             return
         tags = {"deployment": self.name}
         if delivered:
@@ -1750,223 +1357,6 @@ class LLMEngine:
         gauge = m.serve_kv_block_occupancy()
         for state in ("active", "cached", "free"):
             gauge.set(st[f"kv_blocks_{state}"], {**tags, "state": state})
-        if self._tier is not None:
-            ctr = m.serve_kv_tier_hits_total()
-            for src, n in tier_hits.items():
-                if n:
-                    ctr.inc(n, {**tags, "source": src})
-            if spill_b:
-                m.serve_kv_tier_spill_bytes_total().inc(spill_b, tags)
-            if fetch_b:
-                m.serve_kv_tier_fetch_bytes_total().inc(fetch_b, tags)
-            m.serve_kv_spilled_blocks().set(self._tier.spilled_blocks(), tags)
-        if self._spec:
-            prop, self._spec_proposed_pending = self._spec_proposed_pending, 0
-            acc, self._spec_accepted_pending = self._spec_accepted_pending, 0
-            if prop:
-                m.serve_spec_proposed_total().inc(prop, tags)
-            if acc:
-                m.serve_spec_accepted_total().inc(acc, tags)
-            tot_prop = self._spec_proposed_total
-            if tot_prop:
-                m.serve_spec_accept_ratio().set(
-                    self._spec_accepted_total / tot_prop, tags)
-            # The spec dispatch IS the first decode chunk for a first
-            # token delivered this step — surface its propose+verify time
-            # as its own TTFT phase next to queued/prefill/decode.
-            if ttfts and self._last_counts is not None:
-                for _ in ttfts:
-                    hist.observe(self._spec_last_dt,
-                                 {**tags, "phase": "spec"})
-
-    # -- drain migration (cluster KV tier) ------------------------------------
-    def kv_export_chains(self) -> List[tuple]:
-        """Snapshot the drain-migration export set — ``(tokens, n_real,
-        head_digest)`` per tracked chain, least-recently-used first. Tracked
-        chains are the active sessions' registered prefixes (noted at
-        admission commit and at retire); shipping them to a survivor is what
-        makes downscale lossless for warm multi-turn state."""
-        with self._state_lock:
-            return [(list(chain), n_real, head)
-                    for head, (chain, n_real) in self._tier_chains.items()]
-
-    def _tier_insert_blocks(self, k_in, v_in, ids) -> None:
-        """Upload fetched/migrated blocks ONE AT A TIME: ``insert_fn(1)``
-        is the only insert program (compiled at warmup) — a per-chain-
-        length variant would pay XLA compilation on every novel chain
-        length, right on the cold-fetch TTFT path."""
-        inf = self._pg.insert_fn(1)
-        for i, b in enumerate(ids):
-            self._pool = inf(
-                self._pool,
-                (np.ascontiguousarray(k_in[:, i:i + 1]),
-                 np.ascontiguousarray(v_in[:, i:i + 1])),
-                np.asarray([b], np.int32))
-
-    def _tier_extract_blocks(self, ids):
-        """Gather blocks one at a time (same one-program rationale as
-        ``_tier_insert_blocks``; spill/migration extraction runs off the
-        decode hot path, so the extra dispatches cost little)."""
-        ef = self._pg.extract_fn(1)
-        ks, vs = [], []
-        for b in ids:
-            k, v = ef(self._pool, np.asarray([b], np.int32))
-            ks.append(np.asarray(k))
-            vs.append(np.asarray(v))
-        return np.concatenate(ks, axis=1), np.concatenate(vs, axis=1)
-
-    def kv_export_chain_payload(self, tokens: Sequence[int],
-                                n_real: int) -> Optional[dict]:
-        """Extract one tracked chain off device for the migration lane —
-        ``{"k", "v", "tokens", "n_real"}`` covering as much of the chain as
-        the prefix cache still holds (full blocks AND the exact partial
-        tail). None when the chain was evicted since being tracked."""
-        tokens = [int(t) for t in tokens]
-        with self._step_lock:
-            ids, covered = self.kv.pin_chain(tokens, int(n_real))
-            if not ids:
-                return None
-            try:
-                k, v = self._tier_extract_blocks(ids)
-                return {"k": k, "v": v,
-                        "tokens": tokens[:covered], "n_real": covered}
-            finally:
-                self.kv.release(ids)
-
-    def kv_import_chain(self, payload: dict) -> int:
-        """Survivor half of drain migration: upload a handed-off chain into
-        the pool and register it as CACHED prefix state, so the migrated
-        session's next turn hits it exactly like a local retire would.
-        Returns the number of tokens now warm (0 if the pool stayed full)."""
-        import jax
-
-        tokens = [int(t) for t in payload["tokens"]]
-        n_real = int(payload.get("n_real", len(tokens)))
-        k = np.asarray(payload["k"])
-        v = np.asarray(payload["v"])
-        nb = int(k.shape[1])
-        if nb == 0 or n_real == 0:
-            return 0
-        deadline = time.monotonic() + 2.0
-        while True:
-            try:
-                ids = self.kv.alloc(nb)
-                break
-            except NoFreeBlocks:
-                if time.monotonic() > deadline:
-                    return 0  # pool saturated — the store tier still covers it
-                time.sleep(0.002)  # in-flight retires free blocks
-        with self._step_lock:
-            self._tier_insert_blocks(k, v, ids)
-            jax.block_until_ready(self._pool)
-        self.kv.register_chain(tokens, ids, n_real)
-        self.kv.release(ids)  # ACTIVE -> CACHED: pure prefix-cache state
-        from ray_tpu.util import blockhash
-
-        digests = blockhash.block_hashes(tokens, self.block_tokens,
-                                         max_blocks=n_real // self.block_tokens)
-        with self._state_lock:
-            for d in digests:
-                self._tier_migrated.pop(d, None)
-                self._tier_migrated[d] = None
-            while len(self._tier_migrated) > self._TIER_MIGRATED_CAP:
-                self._tier_migrated.pop(next(iter(self._tier_migrated)))
-            if digests:
-                self._tier_note_chain_locked(digests[-1], tokens[:n_real],
-                                             n_real)
-        return n_real
-
-    def _tier_lane_params(self) -> tuple:
-        """(capacity, slots) for a drain-migration lane. Both endpoints
-        derive these from the same model config — the shm mapping is sized
-        from them, so creator and attacher MUST agree."""
-        c = self.config
-        bt = self.block_tokens
-        itm = np.dtype(c.dtype).itemsize
-        block_bytes = c.n_layers * bt * c.n_heads * c.head_dim * itm
-        # A chain spans at most one sequence's block budget: K+V of a full
-        # table row, plus room for the meta.
-        return 2 * self.blocks_per_seq * block_bytes + 65536, 4
-
-    def kv_migrate_out(self, lane_name: str) -> int:
-        """Victim half of drain-then-retire: attach to the survivor's named
-        handoff lane, ship every tracked chain, send the close pill. Returns
-        chains sent; 0 (never raises) when the survivor's lane never appears
-        or the drain deadline lapses — the store tier is the fallback."""
-        from ray_tpu.core.config import config as _get_config
-        from ray_tpu.serve.dag_pipeline import KVHandoffLane
-        from ray_tpu.util import flightrec
-
-        try:
-            timeout = float(_get_config().kv_tier_drain_timeout_s)
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            timeout = 10.0
-        deadline = time.monotonic() + timeout
-        cap, slots = self._tier_lane_params()
-        lane = KVHandoffLane.attach(lane_name, timeout=timeout,
-                                    capacity=cap, slots=slots)
-        if lane is None:
-            return 0  # survivor never opened the lane
-        sent = 0
-        try:
-            for tokens, n_real, _head in self.kv_export_chains():
-                if time.monotonic() > deadline:
-                    break
-                payload = self.kv_export_chain_payload(tokens, n_real)
-                if payload is None:
-                    continue  # evicted since tracking — store tier covers it
-                meta = {"tokens": payload["tokens"],
-                        "n_real": payload["n_real"]}
-                try:
-                    lane.send(meta, payload["k"], payload["v"],
-                              timeout=max(0.1, deadline - time.monotonic()))
-                except ValueError:
-                    continue  # larger than the lane — store tier covers it
-                sent += 1
-            lane.close()  # pill: tells the survivor the drain is complete
-        finally:
-            lane.detach()
-        flightrec.record("serve", self.name, f"kv migrate out {sent}")
-        return sent
-
-    def kv_migrate_in(self, lane_name: str) -> int:
-        """Survivor half: CREATE the named handoff lane (the victim retry-
-        attaches), import chains until the victim's close pill or the drain
-        deadline, registering each as warm prefix state and recording its
-        digests for migrated-hit attribution. Returns chains imported."""
-        from ray_tpu.core.config import config as _get_config
-        from ray_tpu.dag.channel import ChannelClosed
-        from ray_tpu.serve.dag_pipeline import KVHandoffLane
-        from ray_tpu.util import flightrec
-
-        try:
-            timeout = float(_get_config().kv_tier_drain_timeout_s)
-        except Exception:  # noqa: BLE001 — config unavailable mid-teardown
-            timeout = 10.0
-        cap, slots = self._tier_lane_params()
-        lane = KVHandoffLane(name=lane_name, capacity=cap, slots=slots)
-        got = 0
-        deadline = time.monotonic() + timeout
-        try:
-            while True:
-                left = deadline - time.monotonic()
-                if left <= 0:
-                    break
-                try:
-                    meta, k, v, tok = lane.recv(timeout=left)
-                except (ChannelClosed, TimeoutError):
-                    break
-                try:
-                    if self.kv_import_chain(
-                            {"k": k, "v": v, "tokens": meta["tokens"],
-                             "n_real": meta["n_real"]}):
-                        got += 1
-                finally:
-                    lane.ack(tok)  # upload landed — slot back to the victim
-        finally:
-            lane.destroy()
-        flightrec.record("serve", self.name, f"kv migrate in {got}")
-        return got
 
     # -- introspection --------------------------------------------------------
     def stats(self) -> Dict[str, float]:
@@ -2011,17 +1401,6 @@ class LLMEngine:
             out.update({k: v / 1e9 if k.endswith("_s") else float(v)
                         for k, v in self._counts.items()})
         out.update(self.kv.stats())
-        if self._tier is not None:
-            out["kv_tier_spilled_blocks"] = float(self._tier.spilled_blocks())
-            with self._state_lock:
-                for src, n in self._tier_hits_total.items():
-                    out[f"kv_tier_hits_{src}"] = float(n)
-        if self._spec:
-            prop = self._spec_proposed_total
-            acc = self._spec_accepted_total
-            out["spec_proposed_total"] = float(prop)
-            out["spec_accepted_total"] = float(acc)
-            out["spec_accept_ratio"] = float(acc) / prop if prop else 0.0
         with self._agg_lock:
             out.update({k: float(v) for k, v in self._aux_totals.items()})
             if self._slot_state or not self._prefix_cache:
@@ -2070,13 +1449,6 @@ class LLMEngine:
                 return 0.0
             return self.decode_tokens / self.decode_seconds
 
-    def close(self) -> None:
-        """Release this engine's KV-tier publishes — directory refs and
-        object pins drain to zero (the leak-check invariant). Idempotent;
-        the engine owns no threads to stop."""
-        if self._tier is not None:
-            self._tier.close()
-
 
 def llm_deployment(
     config,
@@ -2087,8 +1459,6 @@ def llm_deployment(
     slots: Optional[int] = None,
     chunk: int = 8,
     max_queue: Optional[int] = None,
-    draft_config=None,
-    draft_params_fn: Optional[Callable[[], Dict]] = None,
     **deployment_kwargs,
 ):
     """Build a Serve deployment class around a continuous-batching
@@ -2099,9 +1469,8 @@ def llm_deployment(
     ``models.longcat.LongCatConfig`` or a
     ``models.olmo_hybrid.OlmoHybridConfig``; the engine finds the family's
     pool, its per-slot state and its forward pass through it
-    (``models.generate.PagedFamily``), and what a family cannot run yet (a
-    draft model, the KV tier) raises when the replica builds its engine; a
-    family that keeps a state a slot is served no prefix hit.
+    (``models.generate.PagedFamily``); a family that keeps a state a slot
+    is served no prefix hit.
 
     ``params_fn`` runs inside the replica (checkpoint load / init) so weights
     never ship through the controller. Request payload::
@@ -2144,15 +1513,9 @@ def llm_deployment(
     @serve.deployment(name=name, **deployment_kwargs)
     class LLMServer:
         def __init__(self):
-            eng_kw = {}
-            if draft_params_fn is not None:
-                # Draft weights load in-replica like the target's —
-                # speculation turns on when serve_spec_tokens > 0.
-                eng_kw["draft_params"] = draft_params_fn()
-                eng_kw["draft_config"] = draft_config
             self.engine = LLMEngine(params_fn(), config, slots=n_slots,
                                     chunk=chunk, max_queue=q_limit,
-                                    name=name, **eng_kw)
+                                    name=name)
             self.engine.warmup()
 
         def __call__(self, payload):
@@ -2189,12 +1552,5 @@ def llm_deployment(
 
         def describe(self):
             return self.engine.describe()
-
-        # -- drain migration (controller-driven, cluster KV tier) -------------
-        def kv_migrate_out(self, lane_name: str) -> int:
-            return int(self.engine.kv_migrate_out(lane_name))
-
-        def kv_migrate_in(self, lane_name: str) -> int:
-            return int(self.engine.kv_migrate_in(lane_name))
 
     return LLMServer

@@ -81,21 +81,6 @@ class ReplicaActor:
 
         return loaded_model_ids()
 
-    def kv_migrate_out(self, lane_name: str) -> int:
-        """Drain-then-retire victim half (cluster KV tier): ship the hosted
-        engine's warm prefix chains over the named handoff lane. 0 when the
-        callable doesn't serve a paged engine."""
-        if not self._is_function and hasattr(self._callable, "kv_migrate_out"):
-            return int(self._callable.kv_migrate_out(lane_name))
-        return 0
-
-    def kv_migrate_in(self, lane_name: str) -> int:
-        """Drain-then-retire survivor half: create the lane and import the
-        victim's chains as warm prefix state. 0 when not applicable."""
-        if not self._is_function and hasattr(self._callable, "kv_migrate_in"):
-            return int(self._callable.kv_migrate_in(lane_name))
-        return 0
-
     # -- data plane ----------------------------------------------------------
 
     def _trace_queue_wait(self, kwargs) -> None:

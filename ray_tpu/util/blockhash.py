@@ -53,9 +53,3 @@ def prefix_head_hash(tokens: Sequence[int], block_tokens: int,
     digests = block_hashes(tokens, block_tokens, max_blocks=blocks)
     return digests[-1] if digests else None
 
-
-def chain_store_key(digest: bytes) -> str:
-    """Canonical string key for a spilled chain blob keyed by its head
-    digest — the KV-tier object/directory namespace shared by every
-    publisher (content addressing: same chain, same key, cluster-wide)."""
-    return "kvchain:" + bytes(digest).hex()

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_contract
 from benchmark.manifest import load_file
 from ray_tpu.models import afmoe
 from ray_tpu.models.generate import PagedGenerator
@@ -410,7 +411,7 @@ def test_the_engine_and_the_manager_needed_no_edit_for_the_family(model):
     assert not any(word in src for word in (
         "afmoe", "sliding", "window_layers", "ring_"))
     fam = model[0].paged_family()
-    assert fam.unsupported == ("draft_model", "kv_tier", "prefix_cache")
+    assert fam.unsupported == ("prefix_cache",)
     assert [n.decode for n in fam.aux_counts][-2:] == [
         "moe_steps_total", "window_capped_slot_steps_total"]
 
@@ -435,24 +436,13 @@ def test_the_programs_carry_the_named_scopes_and_kernel_names(model):
     assert "window_decode_attn" in jaxpr and "paged_decode_attn" in jaxpr
 
 
-@pytest.mark.parametrize("feature", ["draft_model", "kv_tier"])
-def test_unsupported_features_raise_at_construction(model, feature):
-    from ray_tpu.core.config import Config, config as get_config, set_config
-
+# What the engine owes a request whatever it serves (tests/engine_contract.py);
+# the streams a check hands back are held to the reference.
+@engine_contract.each_check
+def test_engine_contract(model, check):
     cfg, params = model
-    kw = dict(slots=2, chunk=4, name=f"afmoe-{feature}")
-    if feature == "draft_model":
-        with pytest.raises(ValueError, match="draft model"):
-            LLMEngine(params, cfg, draft_params=params, draft_config=cfg,
-                      spec_tokens=2, **kw)
-    else:
-        prev = get_config()
-        set_config(Config({"kv_tier_enabled": True}))
-        try:
-            with pytest.raises(ValueError, match="KV tier"):
-                LLMEngine(params, cfg, **kw)
-        finally:
-            set_config(prev)
+    for prompt, toks in check(params, cfg, engine_contract.ENGINE_KW):
+        assert served_gap(model, prompt, toks) < TOL
 
 
 def test_llm_deployment_streams_the_family(ray_start_regular, model):

@@ -193,16 +193,28 @@ def test_streaming_generator_items_arrive_before_completion(driver):
     assert first_latency < total - 1.5, (first_latency, total)
 
 
-def test_streaming_generator_error_surfaces_mid_stream(driver):
+def test_streaming_generator_error_surfaces_mid_stream(driver, tmp_path):
+    """An item's report and the task's error reply ride different
+    connections, and the owner seals the error after the items it HOLDS
+    (``CoreWorker._record_task_error``): the producer fails only once the
+    consumer holds item 0, so the error is the stream's second item
+    whichever connection is served first."""
+    held = str(tmp_path / "consumer-holds-item-0")
+
     @ray_tpu.remote(num_returns="streaming", max_retries=0)
     def bad():
         yield 1
+        deadline = time.time() + 120
+        while not os.path.exists(held) and time.time() < deadline:
+            time.sleep(0.01)
         raise ValueError("stream kaboom")
 
-    refs = list(bad.remote())
-    assert ray_tpu.get(refs[0], timeout=120) == 1
+    it = iter(bad.remote())
+    assert ray_tpu.get(next(it), timeout=120) == 1
+    open(held, "w").close()
     with pytest.raises(ValueError, match="stream kaboom"):
-        ray_tpu.get(refs[1], timeout=120)
+        ray_tpu.get(next(it), timeout=120)
+    assert list(it) == []
 
 
 def test_streaming_generator_backpressure_bounds_producer(driver, mp_cluster):

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_contract
 from benchmark.manifest import load_file
 from ray_tpu.models import falcon_h1
 from ray_tpu.models.generate import PagedGenerator
@@ -331,6 +332,15 @@ def test_a_program_lowers_one_layer(model):
         np.zeros(2, np.float32)).as_text()
     assert text.count("func.func private @layer") == 1
     assert text.count("call @layer") == cfg.num_hidden_layers
+
+
+# What the engine owes a request whatever it serves (tests/engine_contract.py);
+# the streams a check hands back are held to the reference.
+@engine_contract.each_check
+def test_engine_contract(model, check):
+    cfg, params = model
+    for prompt, toks in check(params, cfg, engine_contract.ENGINE_KW):
+        assert served_gap(model, prompt, toks) < TOL
 
 
 def test_llm_deployment_streams_the_family(ray_start_regular, model):

@@ -26,6 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_contract
 from benchmark.manifest import load_file
 from ray_tpu.models import kimi_k2
 from ray_tpu.models.generate import PagedGenerator
@@ -467,7 +468,7 @@ def test_the_family_names_its_counts_as_longcat_does(model):
 
     fam = model[0].paged_family()
     assert fam.aux_counts == longcat.PAGED_FAMILY.aux_counts
-    assert fam.unsupported == ("draft_model", "kv_tier")
+    assert fam.unsupported == ()
     assert fam.init_slot_state is None and fam.working_params is None
     import inspect
 
@@ -499,24 +500,13 @@ def test_the_programs_carry_the_named_scopes(model):
         assert scope in text, scope
 
 
-@pytest.mark.parametrize("feature", ["draft_model", "kv_tier"])
-def test_unsupported_features_raise_at_construction(model, feature):
-    from ray_tpu.core.config import Config, config as get_config, set_config
-
+# What the engine owes a request whatever it serves (tests/engine_contract.py);
+# the streams a check hands back are held to the reference.
+@engine_contract.each_check
+def test_engine_contract(model, check):
     cfg, params = model
-    kw = dict(slots=2, chunk=4, name=f"kimi-{feature}")
-    if feature == "draft_model":
-        with pytest.raises(ValueError, match="draft model"):
-            LLMEngine(params, cfg, draft_params=params, draft_config=cfg,
-                      spec_tokens=2, **kw)
-    else:
-        prev = get_config()
-        set_config(Config({"kv_tier_enabled": True}))
-        try:
-            with pytest.raises(ValueError, match="KV tier"):
-                LLMEngine(params, cfg, **kw)
-        finally:
-            set_config(prev)
+    for prompt, toks in check(params, cfg, engine_contract.ENGINE_KW):
+        assert _served_gap(model, prompt, toks) < TOL
 
 
 def test_llm_deployment_streams_the_family(ray_start_regular, model):

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_contract
 from benchmark.manifest import load_file
 from ray_tpu.models import longcat
 from ray_tpu.models.generate import PagedGenerator
@@ -370,24 +371,13 @@ def test_held_pairs_are_stamped_on_the_step_span(model, engine):
     assert steps and all("moe_held_pairs" in s.attrs for s in steps)
 
 
-@pytest.mark.parametrize("feature", ["draft_model", "kv_tier"])
-def test_unsupported_features_raise_at_construction(model, feature):
-    from ray_tpu.core.config import Config, config as get_config, set_config
-
+# What the engine owes a request whatever it serves (tests/engine_contract.py);
+# the streams a check hands back are held to the reference.
+@engine_contract.each_check
+def test_engine_contract(model, check):
     cfg, params = model
-    kw = dict(slots=2, chunk=4, name=f"longcat-{feature}")
-    if feature == "draft_model":
-        with pytest.raises(ValueError, match="draft model"):
-            LLMEngine(params, cfg, draft_params=params, draft_config=cfg,
-                      spec_tokens=2, **kw)
-    else:
-        prev = get_config()
-        set_config(Config({"kv_tier_enabled": True}))
-        try:
-            with pytest.raises(ValueError, match="KV tier"):
-                LLMEngine(params, cfg, **kw)
-        finally:
-            set_config(prev)
+    for prompt, toks in check(params, cfg, engine_contract.ENGINE_KW):
+        assert _served_gap(model, prompt, toks) < TOL
 
 
 def test_llm_deployment_streams_the_family(ray_start_regular, model):

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_contract
 from benchmark.manifest import load_file
 from ray_tpu.models import longcat, olmo_hybrid, transformer
 from ray_tpu.models.generate import PagedGenerator
@@ -176,6 +177,7 @@ def test_engine_serves_the_family_and_refuses_the_prefix_cache(model, engine):
     """Concurrent streams through the one engine and block manager agree
     with the reference; the same prompt again returns the same tokens with
     no prefix hit, nothing registered, and the refusals counted."""
+    assert model[0].paged_family().unsupported == ("prefix_cache",)
     prompts = [[7, 3, 11, 200, 5], list(range(30, 52))]
     outs = [None, None]
 
@@ -252,28 +254,6 @@ def test_steps_and_prefills_say_what_the_state_did(model, engine):
                          for s in steps)
 
 
-@pytest.mark.parametrize("feature", ["draft_model", "kv_tier"])
-def test_unsupported_features_raise_at_construction(model, feature):
-    from ray_tpu.core.config import Config, config as get_config, set_config
-
-    cfg, params = model
-    assert cfg.paged_family().unsupported == (
-        "draft_model", "kv_tier", "prefix_cache")
-    kw = dict(slots=2, chunk=4, name=f"olmo-{feature}")
-    if feature == "draft_model":
-        with pytest.raises(ValueError, match="draft model"):
-            LLMEngine(params, cfg, draft_params=params, draft_config=cfg,
-                      spec_tokens=2, **kw)
-    else:
-        prev = get_config()
-        set_config(Config({"kv_tier_enabled": True}))
-        try:
-            with pytest.raises(ValueError, match="KV tier"):
-                LLMEngine(params, cfg, **kw)
-        finally:
-            set_config(prev)
-
-
 def _program_operands(cfg, params, slots=2):
     gen = PagedGenerator(params, cfg, slots=slots, num_blocks=9,
                          block_tokens=BT, max_len=64, attention_kernel="gather")
@@ -344,6 +324,15 @@ def test_a_program_lowers_one_period(model):
         np.zeros(2, np.float32)).as_text()
     assert text.count("func.func private @period") == 1
     assert text.count("call @period") == cfg.n_periods
+
+
+# What the engine owes a request whatever it serves (tests/engine_contract.py);
+# the streams a check hands back are held to the reference.
+@engine_contract.each_check
+def test_engine_contract(model, check):
+    cfg, params = model
+    for prompt, toks in check(params, cfg, engine_contract.ENGINE_KW):
+        assert served_gap(model, prompt, toks) < TOL
 
 
 def test_llm_deployment_streams_the_family(ray_start_regular, model):

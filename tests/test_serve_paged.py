@@ -19,6 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import engine_contract
 import ray_tpu
 from ray_tpu.models import generate, transformer
 from ray_tpu.serve.handle import DeploymentHandle, Router
@@ -181,6 +182,7 @@ class TestCOWForkIsolation:
         out = paged.generate(base, max_new_tokens=6)
         chain = base + out  # 18 tokens: 2 full blocks + 2-token tail
         cows0 = paged.kv.stats()["kv_cow_copies"]
+        hits0 = paged.kv.stats()["kv_hit_tokens"]
         forks = [chain + [211, 212, 213], chain + [221, 222, 223]]
         outs = [None, None]
         errs = []
@@ -200,7 +202,9 @@ class TestCOWForkIsolation:
         assert not errs
         for i in range(2):
             assert outs[i] == oracle(forks[i], 8), f"fork {i} diverged"
-        # Each fork hit the 2-token tail -> one private COW copy apiece.
+        # Each fork hit the whole chain, its 2-token tail included -> one
+        # private COW copy apiece.
+        assert paged.kv.stats()["kv_hit_tokens"] - hits0 == 2 * len(chain)
         assert paged.kv.stats()["kv_cow_copies"] - cows0 >= 2
         # Leak-check invariant: nothing stays pinned after retire.
         assert paged.kv.active_blocks() == 0
@@ -451,6 +455,15 @@ class TestCancelMidDispatchRace:
         assert paged.kv.active_blocks() == 0
 
 
+# What the engine owes a request whatever it serves (tests/engine_contract.py);
+# the streams a check hands back are held to the oracle.
+@engine_contract.each_check
+def test_engine_contract(tiny_model, oracle, check):
+    cfg, params = tiny_model
+    for prompt, toks in check(params, cfg, engine_contract.ENGINE_KW):
+        assert toks == oracle(prompt, len(toks))
+
+
 # -- the look-ahead: one decode chunk queued behind the one that runs ---------
 
 def _ahead_engine(tiny_model, pool_blocks, name):
@@ -689,6 +702,39 @@ class TestDispatchAhead:
         out2, hit = _hit_delta(ahead, turn2, 6)
         assert hit >= 3 * BT and out2 == oracle(turn2, 6)
         assert ahead.kv.active_blocks() == 0
+
+    def test_at_capacity_write_redirects_to_trash(self, tiny_model):
+        """Direct forward unit: lengths == table capacity redirects the
+        scatter to trash block 0 instead of clamping onto the last cell
+        (the pre-fix behavior corrupted position cap-1)."""
+        cfg, params = tiny_model
+        nb_seq = 3
+        pool = 8
+        k_pool, v_pool = generate.init_block_pool(cfg, pool, BT)
+        # Heads folded into the lanes; blocks stay dimension 1, so the
+        # [:, 0] / [:, 1:] reads below index trash and live blocks as before.
+        assert k_pool.shape == v_pool.shape == (
+            cfg.n_layers, pool, BT, cfg.n_heads * cfg.head_dim)
+        k_pool = k_pool + 1.5  # sentinel content
+        v_pool = v_pool + 2.5
+        tables = jnp.asarray(
+            np.array([[1, 2, 3]], np.int32))          # fully live table
+        cap = nb_seq * BT
+        lengths = jnp.asarray(np.array([cap], np.int32))
+        toks = jnp.asarray(np.array([[4]], np.int32))
+        logits, k2, v2 = generate._forward_decode_paged(
+            generate.working_params(params, cfg), toks, k_pool, v_pool,
+            tables, lengths, cfg, BT)
+        assert np.isfinite(np.asarray(logits)).all()
+        # Every live block — in particular the last cell of block 3 —
+        # keeps its sentinel; only trash block 0 absorbed the write.
+        np.testing.assert_array_equal(np.asarray(k2[:, 1:]),
+                                      np.asarray(k_pool[:, 1:]))
+        np.testing.assert_array_equal(np.asarray(v2[:, 1:]),
+                                      np.asarray(v_pool[:, 1:]))
+        assert not np.array_equal(np.asarray(k2[:, 0]),
+                                  np.asarray(k_pool[:, 0]))
+        assert k2.shape == k_pool.shape and v2.shape == v_pool.shape
 
 
 class _StubReplica:
