@@ -72,7 +72,7 @@ from jax import lax
 
 from ray_tpu.models.generate import (PagedFamily, _paged_attend,
                                      init_block_pool)
-from ray_tpu.ops import ssd
+from ray_tpu.ops import causal_conv, ssd
 from ray_tpu.ops.layers import mm as _mm, rms_norm, rope, rope_frequencies
 
 
@@ -336,23 +336,16 @@ def _mixer_prefill(lw, u, state, layer, slot, suffix_len, c: FalconH1Config):
     ``suffix_len`` positions are real. Writes slot ``slot``'s state of
     ``layer`` as it stands after them."""
     S, tail = state
-    K = c.mamba_d_conv
     P = u.shape[1]
     z, pre, dt_raw = _in_proj(lw, u[0], c)
-    padded = jnp.concatenate([jnp.zeros((K - 1, pre.shape[1]), c.dtype), pre])
-    w = lw["conv"].astype(jnp.float32)
-    y = sum(padded[j:j + P].astype(jnp.float32) * w[j] for j in range(K))
-    y = jax.nn.silu(y + lw["conv_bias"].astype(jnp.float32))
-    x, B, C, dt, A = _ssm_operands(lw, y, dt_raw, c)
+    y, tail = causal_conv.prefill(pre, lw["conv"], lw["conv_bias"], tail,
+                                  layer, slot, suffix_len)
+    x, B, C, dt, A = _ssm_operands(lw, jax.nn.silu(y), dt_raw, c)
     real = (jnp.arange(P) < suffix_len)[:, None]
     o, S_new = ssd.chunked(x, jnp.where(real, dt, 0.0), A, B, C, lw["D"],
                            chunk=c.mamba_chunk_size)
     S = lax.dynamic_update_slice(
         S, ssd.fold_state(S_new)[None, None], (layer, slot, 0, 0))
-    # Rows suffix_len - (K-1) .. suffix_len - 1 of the pre-convolution input.
-    new_tail = lax.dynamic_slice_in_dim(padded, suffix_len, K - 1, axis=0)
-    tail = lax.dynamic_update_slice(tail, new_tail[None, :, None],
-                                    (layer, 0, slot, 0))
     return _gated_out(lw, o, z, c)[None], (S, tail)
 
 
@@ -361,14 +354,9 @@ def _mixer_decode(lw, u, state, layer, active, c: FalconH1Config, kernel: str):
     parked ones stay bit for bit."""
     S, tail = state
     z, pre, dt_raw = _in_proj(lw, u[:, 0], c)
-    old = lax.dynamic_index_in_dim(tail, layer, axis=0, keepdims=False)
-    window = jnp.concatenate([old, pre[None]], axis=0)          # [K, S, C]
-    y = jnp.sum(window.astype(jnp.float32)
-                * lw["conv"].astype(jnp.float32)[:, None], axis=0)
-    y = jax.nn.silu(y + lw["conv_bias"].astype(jnp.float32))
-    new_tail = jnp.where(active[None, :, None], window[1:], old)
-    tail = lax.dynamic_update_slice(tail, new_tail[None], (layer, 0, 0, 0))
-    x, B, C, dt, A = _ssm_operands(lw, y, dt_raw, c)
+    y, tail = causal_conv.decode(pre, lw["conv"], lw["conv_bias"], tail,
+                                 layer, active)
+    x, B, C, dt, A = _ssm_operands(lw, jax.nn.silu(y), dt_raw, c)
     if kernel in ("pallas", "interpret"):
         S, o = ssd.ssd_decode(S, x, dt, A, B, C, active, layer,
                               interpret=kernel == "interpret")

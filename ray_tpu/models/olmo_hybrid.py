@@ -71,7 +71,7 @@ from jax import lax
 
 from ray_tpu.models.generate import (PagedFamily, _paged_attend,
                                      init_block_pool)
-from ray_tpu.ops import gated_delta
+from ray_tpu.ops import causal_conv, gated_delta
 from ray_tpu.ops.layers import gated_ffn as _ffn, mm as _mm, rms_norm
 
 LINEAR, FULL = "linear_attention", "full_attention"
@@ -310,12 +310,10 @@ def _linear_prefill(lw, x, state, layer, slot, suffix_len, c: OlmoHybridConfig):
     ``suffix_len`` positions are real. Writes slot ``slot``'s state of
     ``layer`` as it stands after them."""
     S, tail = state
-    K = c.linear_conv_kernel_dim
     P = x.shape[1]
     pre = _mm("pd,dc->pc", x[0], lw["w_qkv"], c.dtype)          # [P, C]
-    padded = jnp.concatenate([jnp.zeros((K - 1, pre.shape[1]), c.dtype), pre])
-    w = lw["conv"].astype(jnp.float32)
-    y = sum(padded[j:j + P].astype(jnp.float32) * w[j] for j in range(K))
+    y, tail = causal_conv.prefill(pre, lw["conv"], None, tail, layer, slot,
+                                  suffix_len)
     q, k, v = _heads(jax.nn.silu(y), c)
     g, beta = _gates(lw, x[0])
     real = (jnp.arange(P) < suffix_len)[:, None]
@@ -323,10 +321,6 @@ def _linear_prefill(lw, x, state, layer, slot, suffix_len, c: OlmoHybridConfig):
                                    jnp.where(real, beta, 0.0))
     S = lax.dynamic_update_slice(
         S, gated_delta.fold_state(S_new)[None, None], (layer, slot, 0, 0))
-    # Rows suffix_len - (K-1) .. suffix_len - 1 of the pre-convolution input.
-    new_tail = lax.dynamic_slice_in_dim(padded, suffix_len, K - 1, axis=0)
-    tail = lax.dynamic_update_slice(tail, new_tail[None, :, None],
-                                    (layer, 0, slot, 0))
     return _gated_out(lw, o[None], x, c), (S, tail)
 
 
@@ -337,12 +331,7 @@ def _linear_decode(lw, x, state, layer, active, c: OlmoHybridConfig,
     S, tail = state
     x1 = x[:, 0]
     pre = _mm("sd,dc->sc", x1, lw["w_qkv"], c.dtype)            # [S, C]
-    old = lax.dynamic_index_in_dim(tail, layer, axis=0, keepdims=False)
-    window = jnp.concatenate([old, pre[None]], axis=0)          # [K, S, C]
-    y = jnp.sum(window.astype(jnp.float32)
-                * lw["conv"].astype(jnp.float32)[:, None], axis=0)
-    new_tail = jnp.where(active[None, :, None], window[1:], old)
-    tail = lax.dynamic_update_slice(tail, new_tail[None], (layer, 0, 0, 0))
+    y, tail = causal_conv.decode(pre, lw["conv"], None, tail, layer, active)
     q, k, v = _heads(jax.nn.silu(y), c)
     g, beta = _gates(lw, x1)
     if kernel in ("pallas", "interpret"):

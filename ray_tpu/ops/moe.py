@@ -119,7 +119,8 @@ def moe_ffn(params: Dict, x: jax.Array, cfg: MoEConfig) -> Tuple[jax.Array, Dict
 # (a model family names its serve counters after them): picks, zero-compute
 # picks, picks on held experts, the busiest held expert's pairs, held experts
 # with at least one pair; then 1 for a call that took the bounded form (below)
-# and the windows it walked beyond the first.
+# and the windows it walked beyond the first (or, for a call of the capacity
+# form, 1 if an expert's rows overflowed it into the all-rows product).
 PICK_COUNT_NAMES = ("picks", "picks_zero", "picks_held", "held_pairs_max",
                     "experts_hit", "bounded_calls", "extra_windows")
 PICK_COUNTS = len(PICK_COUNT_NAMES)
@@ -147,6 +148,35 @@ def held_row_bound(n_tokens: int, topk: int, held: Tuple[int, int],
     tiles = -(-_SHARE_FACTOR * pairs * held[1] // (n_routed * _ROW_TILE))
     rows = max(tiles, 1) * _ROW_TILE
     return rows if 4 * rows <= pairs else None
+
+
+# Where MANY experts are held and each gets a few rows (a decode step of a
+# chip that holds 64 experts: 6 rows an expert), the grouped product is the
+# wrong tool: it visits every group with a whole row tile, so its work grows
+# with the groups, not the pairs (on the chip, 64 groups over 768 rows ran at
+# 11% of its bytes' time, 6.4 ms a product where the weights stream in 0.8;
+# at 12-16 groups the same product reads 60%). There the pairs are laid out a
+# fixed ``_CAPACITY`` rows an expert and multiplied by a plain batched product,
+# which streams each expert's matrices once. A call in which some expert got
+# more rows than that takes the all-rows grouped product instead (both are in
+# the program, a ``lax.cond`` picks): no pick is dropped.
+_CAPACITY = 64
+_CAPACITY_MIN_EXPERTS = 32
+_CAPACITY_MAX_MEAN = 8
+
+
+def held_capacity(n_tokens: int, topk: int, held: Tuple[int, int],
+                  n_routed: int) -> Optional[int]:
+    """Rows an expert of the capacity form, where ``held_experts_ffn`` takes
+    it: at least ``_CAPACITY_MIN_EXPERTS`` experts held and a mean of at most
+    ``_CAPACITY_MAX_MEAN`` pairs an expert under an even router (so that
+    ``_CAPACITY`` rows are eight times the mean and more: a seeded router's
+    busiest expert got 3.9 times the mean on the chip); None elsewhere. Read
+    off the static shapes alone."""
+    if (held[1] >= _CAPACITY_MIN_EXPERTS
+            and n_tokens * topk <= _CAPACITY_MAX_MEAN * n_routed):
+        return _CAPACITY
+    return None
 
 
 def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
@@ -177,6 +207,19 @@ def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
     return idx.astype(jnp.int32), scale * picked
 
 
+# What one expert computes, and so what its first matrix holds.
+EXPERT_FORMS = ("silu_gate", "relu2")
+
+
+def _expert_hidden(p, F: int, form: str):
+    """The first product's rows ``p`` -> what ``w_down`` multiplies, float32:
+    ``silu(gate) * up`` of ``[rows, 2F]`` (gate | up), or ``relu(up)^2`` of
+    ``[rows, F]`` (two matrices an expert, no gate)."""
+    if form == "silu_gate":
+        return jax.nn.silu(p[:, :F]) * p[:, F:]
+    return jnp.square(jax.nn.relu(p))
+
+
 def _add_rows_by_token(out, y, tok, in_group, seg_max: int):
     """``out[tok[r]] += y[r]`` over the rows ``in_group``, in float32 and
     with no scatter of rows (on the chip ``out.at[tok].add(y)`` of 1,024
@@ -201,7 +244,8 @@ def _add_rows_by_token(out, y, tok, in_group, seg_max: int):
     return out + jnp.where((rows_of > 0)[:, None], ys[head], 0.0)
 
 
-def _walk_held_pairs(h, weights, w_gate_up, w_down, order, sizes, rows: int):
+def _walk_held_pairs(h, weights, w_in, w_down, order, sizes, rows: int,
+                     form: str):
     """The held pairs' weighted sum [N, D] float32 through a buffer of
     ``rows`` rows: window ``i`` takes sorted pairs ``[i * rows, (i + 1) *
     rows)``, each group's size clipped to it (a group that straddles an edge
@@ -226,9 +270,9 @@ def _walk_held_pairs(h, weights, w_gate_up, w_down, order, sizes, rows: int):
         cut = jnp.clip(jnp.minimum(ends, lo + rows) - jnp.maximum(starts, lo),
                        0)
         with jax.named_scope("moe_experts"):
-            gu = jax.lax.ragged_dot(h[tok], w_gate_up, cut,
+            gu = jax.lax.ragged_dot(h[tok], w_in, cut,
                                     preferred_element_type=jnp.float32)
-            a = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
+            a = _expert_hidden(gu, F, form).astype(h.dtype)
             y = jax.lax.ragged_dot(a, w_down, cut,
                                    preferred_element_type=jnp.float32)
         # Rows past the last pair belong to no group: their product is not
@@ -242,16 +286,52 @@ def _walk_held_pairs(h, weights, w_gate_up, w_down, order, sizes, rows: int):
     return out, windows
 
 
+def _capacity_held_pairs(h, w_held, key, w_in, w_down, order, sizes,
+                         cap: int, form: str):
+    """The held pairs' weighted sum [N, D] float32 with the pairs laid out
+    ``cap`` rows an expert: expert ``e``'s rows are sorted pairs ``[start_e,
+    start_e + size_e)``, the rest of its ``cap`` rows zeros. Two batched
+    products ``[E, cap, D] x [E, D, F]`` and ``[E, cap, F] x [E, F, D]``;
+    then every pair gathers its row. The caller has checked ``max(sizes) <=
+    cap``."""
+    N, D = h.shape
+    k = w_held.shape[1]
+    E, F = sizes.shape[0], w_down.shape[1]
+    starts = jnp.cumsum(sizes) - sizes
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    pos = jnp.minimum(starts[:, None] + slot[None, :], N * k - 1)
+    live = slot[None, :] < sizes[:, None]                        # [E, cap]
+    x = jnp.where(live[..., None], h[order[pos] // k], 0)        # [E, cap, D]
+    with jax.named_scope("moe_experts"):
+        p = jnp.einsum("ecd,edf->ecf", x, w_in,
+                       preferred_element_type=jnp.float32)
+        a = _expert_hidden(p.reshape(E * cap, -1), F, form).astype(h.dtype)
+        y = jnp.einsum("ecf,efd->ecd", a.reshape(E, cap, F), w_down,
+                       preferred_element_type=jnp.float32)
+    # Pair (n, j) on held expert e lies at row ``its place in the sorted
+    # order - start_e`` of e's block; a pair on no held expert weighs 0.
+    back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+        jnp.arange(N * k, dtype=jnp.int32))
+    e = jnp.minimum(key, E - 1)
+    row = e * cap + jnp.clip(back - starts[e], 0, cap - 1)
+    return jnp.einsum("nk,nkd->nd", w_held,
+                      y.reshape(E * cap, D)[row].reshape(N, k, D))
+
+
 def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
-                     w_gate_up: jax.Array, w_down: jax.Array, *,
+                     w_in: jax.Array, w_down: jax.Array, *,
                      held: Tuple[int, int], n_routed: int,
                      valid: Optional[jax.Array] = None,
+                     form: str = "silu_gate",
                      ) -> Tuple[jax.Array, jax.Array]:
     """This chip's part of a top-k expert layer, dropless.
 
     ``h`` [N, D]; ``idx`` / ``weights`` [N, k] from :func:`route_topk`;
-    ``w_gate_up`` [E_held, D, 2F] (gate | up) and ``w_down`` [E_held, F, D]
-    are the gated FFNs of experts ``held = (first, count)`` of ``n_routed``.
+    ``w_in`` and ``w_down`` [E_held, F, D] are the FFNs of experts ``held =
+    (first, count)`` of ``n_routed``, of the ``form`` (``EXPERT_FORMS``)
+    ``"silu_gate"``: ``w_in`` [E_held, D, 2F] (gate | up), ``W_down(silu(gate)
+    * up)``; or ``"relu2"``: ``w_in`` [E_held, D, F], ``W_down relu(W_in
+    h)^2``, two matrices an expert and no gate.
     A pick ``i >= n_routed`` is a zero-compute (identity) expert and adds
     ``w_i * h`` with no matrix product; a pick on a held expert adds
     ``w_i * Expert_i(h)``; a pick on an absent expert adds nothing here.
@@ -271,7 +351,11 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     uneven the load: an uneven load walks more windows. The products and
     their precision are the same in both forms (bfloat16 operands, float32
     accumulation, float32 weights and sum, one cast at the end); only the
-    order in which a token's picks are summed differs.
+    order in which a token's picks are summed differs. Where many experts
+    are held and each gets a few rows (:func:`held_capacity`) the all-rows
+    buffer is laid out a fixed number of rows an expert and multiplied by a
+    batched product instead, unless some expert got more rows than that
+    (the call then counts one ``extra_windows``): dropless too.
 
     Returns (out [N, D] in ``h.dtype``, counts int32 [PICK_COUNTS], one a
     name of ``PICK_COUNT_NAMES``)."""
@@ -279,6 +363,10 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     k = idx.shape[1]
     first, count = held
     F = w_down.shape[1]
+    if form not in EXPERT_FORMS or w_in.shape[2] != (
+            2 * F if form == "silu_gate" else F):
+        raise ValueError(f"held_experts_ffn: w_in {w_in.shape} is no first "
+                         f"matrix of a {form!r} expert of {F} channels")
     live = jnp.ones((N,), bool) if valid is None else valid
     live_k = live[:, None]
 
@@ -289,28 +377,41 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     sizes = jnp.zeros((count + 1,), jnp.int32).at[key].add(1)[:count]
     rows = held_row_bound(N, k, held, n_routed)
     if rows is not None:
-        out, windows = _walk_held_pairs(h, weights, w_gate_up, w_down, order,
-                                        sizes, rows)
+        out, windows = _walk_held_pairs(h, weights, w_in, w_down, order,
+                                        sizes, rows, form)
         bounded = {"bounded_calls": 1,
                    "extra_windows": jnp.maximum(windows - 1, 0)}
     else:
-        tok = (jnp.arange(N * k, dtype=jnp.int32) // k)[order]
-        x = h[tok]                                               # [N*k, D]
-        with jax.named_scope("moe_experts"):
-            gu = jax.lax.ragged_dot(x, w_gate_up, sizes,
-                                    preferred_element_type=jnp.float32)
-            a = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(h.dtype)
-            y = jax.lax.ragged_dot(a, w_down, sizes,
-                                   preferred_element_type=jnp.float32)
-        # Rows past the last pair belong to no group: their product is not
-        # defined, so they are cut out before the weights touch them.
-        in_group = jnp.arange(N * k) < jnp.sum(sizes)
-        y = jnp.where(in_group[:, None], y, 0.0)
-        back = jnp.zeros((N * k,), jnp.int32).at[order].set(
-            jnp.arange(N * k, dtype=jnp.int32))
-        w_held = jnp.where(on_held, weights, 0.0)                # [N, k]
-        out = jnp.einsum("nk,nkd->nd", w_held, y[back].reshape(N, k, D))
-        bounded = {"bounded_calls": 0, "extra_windows": 0}
+        def all_rows():
+            tok = (jnp.arange(N * k, dtype=jnp.int32) // k)[order]
+            x = h[tok]                                           # [N*k, D]
+            with jax.named_scope("moe_experts"):
+                gu = jax.lax.ragged_dot(x, w_in, sizes,
+                                        preferred_element_type=jnp.float32)
+                a = _expert_hidden(gu, F, form).astype(h.dtype)
+                y = jax.lax.ragged_dot(a, w_down, sizes,
+                                       preferred_element_type=jnp.float32)
+            # Rows past the last pair belong to no group: their product is
+            # not defined, so they are cut out before the weights touch them.
+            in_group = jnp.arange(N * k) < jnp.sum(sizes)
+            y = jnp.where(in_group[:, None], y, 0.0)
+            back = jnp.zeros((N * k,), jnp.int32).at[order].set(
+                jnp.arange(N * k, dtype=jnp.int32))
+            w_held = jnp.where(on_held, weights, 0.0)            # [N, k]
+            return jnp.einsum("nk,nkd->nd", w_held, y[back].reshape(N, k, D))
+
+        cap = held_capacity(N, k, held, n_routed)
+        if cap is None:
+            out = all_rows()
+            bounded = {"bounded_calls": 0, "extra_windows": 0}
+        else:
+            fits = jnp.max(sizes) <= cap
+            out = jax.lax.cond(
+                fits, lambda: _capacity_held_pairs(
+                    h, jnp.where(on_held, weights, 0.0), key, w_in, w_down,
+                    order, sizes, cap, form), all_rows)
+            bounded = {"bounded_calls": 0,
+                       "extra_windows": 1 - fits.astype(jnp.int32)}
 
     is_zero = (idx >= n_routed) & live_k
     w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)  # [N]

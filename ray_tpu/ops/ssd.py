@@ -156,11 +156,14 @@ def unfold_state(folded, heads: int):
 
 def _lanes_per_block(heads: int, groups: int, P: int, N: int) -> int:
     """Lanes a grid step takes: the widest run of whole heads inside ONE
-    group (its ``B`` and ``C`` are the block's) whose ``[N, W]`` float32
-    block stays under ``_BLOCK_BYTES`` in whole 128-lane tiles; a group's
-    whole run where no such split exists (tiny sizes)."""
+    group (its ``B`` and ``C`` are the block's), the whole group included,
+    whose ``[N, W]`` float32 block stays under ``_BLOCK_BYTES`` in whole
+    128-lane tiles; a group's whole run where no such run exists (tiny
+    sizes). Falcon-H1 (32 heads of 128 in 2 groups, N 256): 8 heads, 1,024
+    lanes, 1 MiB, half a group. Nemotron-H (64 heads of 64 in 8 groups, N
+    128): a whole group's 8 heads, 512 lanes, 256 KiB."""
     per_group = heads // groups
-    fits = [h * P for h in range(1, per_group) if per_group % h == 0
+    fits = [h * P for h in range(1, per_group + 1) if per_group % h == 0
             and (h * P) % 128 == 0 and N * h * P * 4 <= _BLOCK_BYTES]
     return max(fits) if fits else per_group * P
 

@@ -21,13 +21,18 @@ H, P, G, N = 4, 16, 2, 8
 CHUNK = 16
 
 
-def inputs(T, seed=0, heads=H, groups=G):
+# Nemotron-H's published mixer: 64 heads of 64 channels (half a 128-lane tile
+# a head), 8 groups of 8 heads, state 128; a kernel block is a whole group.
+NEMOTRON = (64, 64, 8, 128)
+
+
+def inputs(T, seed=0, heads=H, groups=G, channels=P, state=N):
     ks = jax.random.split(jax.random.key(seed), 6)
-    x = jax.random.normal(ks[0], (T, heads, P))
+    x = jax.random.normal(ks[0], (T, heads, channels))
     dt = jax.nn.softplus(jax.random.normal(ks[1], (T, heads)) - 2.0)
     A = -jnp.exp(jax.random.uniform(ks[2], (heads,), minval=0.0, maxval=2.7))
-    B = jax.random.normal(ks[3], (T, groups, N))
-    C = jax.random.normal(ks[4], (T, groups, N))
+    B = jax.random.normal(ks[3], (T, groups, state))
+    C = jax.random.normal(ks[4], (T, groups, state))
     D = jax.random.normal(ks[5], (heads,))
     return x, dt, A, B, C, D
 
@@ -40,6 +45,18 @@ def test_chunked_prefill_equals_the_recurrence(T):
     y_c, s_c = ssd.chunked(*args, chunk=CHUNK)
     np.testing.assert_allclose(y_c, y_r, atol=TOL)
     np.testing.assert_allclose(s_c, s_r, atol=TOL)
+
+
+def test_chunked_prefill_at_nemotrons_shape():
+    """H=64, P=64, G=8, N=128 in the published chunks of 128, the sequence a
+    chunk and two tokens: outputs are sums of 128 products of order one
+    (magnitudes up to tens), so the order of summation is held relatively."""
+    h, p, g, n = NEMOTRON
+    args = inputs(130, seed=3, heads=h, groups=g, channels=p, state=n)
+    y_r, s_r = ssd.recurrence(*args)
+    y_c, s_c = ssd.chunked(*args, chunk=128)
+    np.testing.assert_allclose(y_c, y_r, rtol=1e-4, atol=4 * TOL)
+    np.testing.assert_allclose(s_c, s_r, rtol=1e-4, atol=4 * TOL)
 
 
 def test_a_state_carried_in_is_carried_on():
@@ -113,14 +130,20 @@ def test_fold_and_unfold_are_inverses():
     assert float(folded[3, 2 * P + 5]) == float(s[2, 5, 3])
 
 
-@pytest.mark.parametrize("decode", ["kernel", "reference"])
-def test_decode_follows_the_recurrence_step_by_step(decode):
+@pytest.mark.parametrize("decode,shape", [
+    ("kernel", (H, P, G, N)), ("reference", (H, P, G, N)),
+    pytest.param("kernel", NEMOTRON, id="kernel-nemotron"),
+    pytest.param("reference", NEMOTRON, id="reference-nemotron")])
+def test_decode_follows_the_recurrence_step_by_step(decode, shape):
     """Three slots, two layers of state, six token steps of layer 1: slot 1
     is parked throughout and slot 2 from step 3. Every step's output and
     state against the oracle advanced one token; the parked slots and the
-    other layer bit for bit what they were."""
+    other layer bit for bit what they were. At the tiny shape and at
+    Nemotron-H's (a grid of 3 slots x 8 whole groups)."""
     S, L, T = 3, 2, 6
-    seqs = [inputs(T, seed=10 + s) for s in range(S)]
+    H, P, G, N = shape
+    seqs = [inputs(T, seed=10 + s, heads=H, groups=G, channels=P, state=N)
+            for s in range(S)]
     A, D = seqs[0][2], seqs[0][5]
     start = [jax.random.normal(jax.random.key(20 + s), (H, P, N))
              for s in range(S)]
@@ -182,7 +205,9 @@ def test_the_kernel_refuses_a_state_that_is_not_its_own():
 @pytest.mark.parametrize("heads,groups,P_,N_,want", [
     (32, 2, 128, 256, 1024),      # the published sizes: 8 heads, 1 MiB
     (4, 2, 16, 8, 32),            # tiny: a group's whole run
-    (16, 1, 64, 128, 512),        # one group: still under the block's bytes
+    (16, 1, 64, 128, 1024),       # one group, whole: 512 KiB, under the block's bytes
+    (64, 8, 64, 128, 512),        # Nemotron-H: a WHOLE group's 8 heads, 256 KiB
+    (32, 2, 128, 128, 2048),      # a whole group where it fits the block: 1 MiB
 ])
 def test_a_block_is_whole_heads_of_one_group(heads, groups, P_, N_, want):
     W = ssd._lanes_per_block(heads, groups, P_, N_)

@@ -60,6 +60,14 @@ KIMI_SLOTS, KIMI_POOL, KIMI_BUCKETS = 96, 16993, (2048, 3072)
 TRINITY_SLOTS, TRINITY_POOL, TRINITY_BUCKET = 64, 30785, 8192
 
 
+# Nemotron-3-Nano-30B-A3B at the sizes of the cell
+# nemotron-3-nano-30b-a3b.reason-decode: published widths, 13 layers
+# MEMEM*EMEMEM* (6 mixers, 5 expert layers of 64 held, 2 attention layers),
+# 128 slots, pool 16513 x 16 for the TWO attention layers, the 2176 bucket
+# (max_seq_len: the largest program the warm-up compiles).
+NEMOTRON_SLOTS, NEMOTRON_POOL, NEMOTRON_BUCKET = 128, 16513, 2176
+
+
 # An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
 # custom-call(...), custom_call_target="tpu_custom_call"``. The profiler names
 # a device operation by this same text, and the benchmark's kernel metrics
@@ -390,6 +398,8 @@ def compile_all() -> dict:
     ``reduce-precision`` instructions that feed them]},
     "shared_expert_ops": {Kimi serve program: named_ops() of the pattern of
     ``shared_expert_ms_per_step.batch``},
+    "capacity_ops": {Nemotron decode: named_ops() of the pattern of
+    ``expert_capacity_ffn_roofline``},
     "latent_calls": {name: [calls of the latent kernel's jit, distinct
     traced bodies among them]},
     "flash_products": {name: pallas_products() of the flash kernels},
@@ -432,8 +442,14 @@ def compile_all() -> dict:
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
 
+    with open(os.path.join(REPO, "benchmark", "metrics",
+                           "expert_capacity_ffn_roofline.json")) as f:
+        capacity_pattern = json.load(f)["pattern"]
+    capacity_ops = {}
+
     def attempt(name, trace, pool=None, state=None, weights=None,
-                shared=False, state_kernel="gdn_decode", pairs=None):
+                shared=False, state_kernel="gdn_decode", pairs=None,
+                capacity=False):
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
@@ -463,6 +479,8 @@ def compile_all() -> dict:
                 weight_movers[name] = weight_shaped_data_movers(text, weights)
             if shared:
                 shared_expert_ops[name] = named_ops(text, shared_pattern)
+            if capacity:
+                capacity_ops[name] = named_ops(text, capacity_pattern)
             if pairs is not None:
                 pair_rows[name] = pair_row_arrays(text, *pairs)
             if state is not None:
@@ -745,6 +763,47 @@ def compile_all() -> dict:
             pairs=(TRINITY_BUCKET, tcfg.num_experts_per_tok, expert_widths(
                 tcfg.hidden_size, tcfg.moe_intermediate_size)))
 
+    # Nemotron-H's serve programs whole, at the cell's own sizes: a layer is
+    # ONE thing, so the state kernel is called by the six mixer layers alone
+    # on a float32 state of its own depth, the attention kernel by the two
+    # attention layers alone on a pool of its own depth, the grouped product
+    # by the five expert layers; no weight re-laid on a call, and the bytes
+    # the chip must hold (3.93B bf16 parameters, 1.64 GB of slot state, a
+    # 0.54 GB pool).
+    from ray_tpu.models import nemotron_h
+
+    ncfg = nemotron_h.nemotron_nano_share()
+    nparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: nemotron_h.init_params(ncfg, key),
+                       jax.random.key(0)))
+    ngen = PagedGenerator(nparams, ncfg, slots=NEMOTRON_SLOTS,
+                          num_blocks=NEMOTRON_POOL, block_tokens=bt,
+                          attention_kernel="pallas")
+    nkv = arr((ncfg.n_layers, NEMOTRON_POOL, bt,
+               ncfg.n_kv_heads * ncfg.head_dim))
+    nslot = tuple(arr(x.shape, x.dtype) for x in jax.eval_shape(
+        lambda: nemotron_h.init_slot_state(ncfg, NEMOTRON_SLOTS)))
+    nstate = (nparams, (nkv, nkv), nslot,
+              arr((NEMOTRON_SLOTS, ngen.logits_dim), jnp.float32),
+              arr((NEMOTRON_SLOTS, 2), jnp.uint32))
+    n_slot = lambda dtype: arr((NEMOTRON_SLOTS,), dtype)  # noqa: E731
+    n_geometry = (ncfg.n_layers, NEMOTRON_POOL, bt)
+    n_state = (ncfg.mixer_layers, NEMOTRON_SLOTS, ncfg.ssm_state_size)
+    attempt("nemotron_decode",
+            lambda: ngen.decode_fn(8).trace(
+                *nstate, arr((NEMOTRON_SLOTS, ngen.blocks_per_seq), jnp.int32),
+                n_slot(jnp.int32), n_slot(jnp.bool_), n_slot(jnp.bool_),
+                n_slot(jnp.float32)), pool=n_geometry, state=n_state,
+            weights=shapes_of(nparams), state_kernel="ssd_decode",
+            capacity=True)
+    attempt(f"nemotron_prefill_{NEMOTRON_BUCKET}",
+            lambda: ngen.prefill_fn(NEMOTRON_BUCKET).trace(
+                *nstate, arr((ngen.blocks_per_seq,), jnp.int32),
+                arr((1, NEMOTRON_BUCKET), jnp.int32), i32, i32, i32, i32),
+            pool=n_geometry, state=n_state, weights=shapes_of(nparams),
+            state_kernel="ssd_decode")
+
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
     for name, spec in [("train_step_data4", MeshSpec(data=4)),
@@ -771,6 +830,7 @@ def compile_all() -> dict:
             "state_movers": state_movers, "weight_movers": weight_movers,
             "state_roundings": state_roundings,
             "shared_expert_ops": shared_expert_ops,
+            "capacity_ops": capacity_ops,
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
             "flash_products": flash_products, "flash_movers": flash_movers,
             "pair_rows": pair_rows}
@@ -1156,6 +1216,61 @@ def test_trinity_serve_programs_fit_the_chip(verdict, program, kernels, need):
         [[64, 1]] * 5 if program == "trinity_decode" else [[1, 128]] * 5)
 
 
+@pytest.mark.parametrize("program,kernels,need", [
+    ("nemotron_decode", {"ssd_decode": "f32[6,128,128,4096]",
+                         "paged_decode_attn": "bf16[128,32,1,128]"},
+     (10.2e9, 10.5e9)),
+    ("nemotron_prefill_2176", {"paged_prefill_attn": "bf16[1,32,2176,128]"},
+     (10.5e9, 10.9e9))])
+def test_nemotron_h_serve_programs_fit_the_chip(verdict, program, kernels,
+                                                need):
+    """Nemotron-H's ``paged_decode`` and its largest ``paged_prefill`` at the
+    sizes of ``nemotron-3-nano-30b-a3b.reason-decode``: they compile for a
+    v5e and arguments plus temporaries leave room for the check's float32
+    pass (0.57 GB of logits) beside them. A layer is ONE thing: the state
+    kernel's operand is float32 ``[6 mixer layers, 128 slots, 128, 4096]``
+    and it is called six times (a whole group's 512 lanes a block: a grid of
+    128 slots x 8), the attention kernel twice on a pool of TWO layers with
+    all 32 query heads in its output over a row of the two KV heads, the
+    grouped product by the five expert layers. No instruction copies or
+    slices data the size of the pool, of the slot state or, in the decode
+    program, of a weight; of the decode program's Pallas calls the
+    benchmark's ``paged_attn_roofline`` pattern matches the attention kernel
+    ALONE and ``ssd_state_roofline``'s the state kernel alone; B and C reach
+    the state kernel as three bfloat16 parts each."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    # weights 8.07 GB as stored (the experts 1,920 wide) + slot state 1.64 GB
+    # + pool 0.54 GB + last 0.03 GB; temporaries 0.03 GB (decode), 0.39 GB
+    assert need[0] < verdict["need_bytes"][program] < need[1], verdict["need_bytes"]
+    found = [[n, s] for n, s in verdict["kernels"][program]
+             if not n.startswith("ragged-dot")]
+    assert dict(found) == kernels, verdict["kernels"][program]
+    assert verdict["pool_movers"][program] == []
+    assert verdict["state_movers"][program] == []
+    assert all(0 <= v < V5E_SCOPED_VMEM
+               for v in verdict["scoped_vmem"][program])
+    with open(os.path.join(REPO, "benchmark", "metrics",
+                           "ssd_state_roofline.json")) as f:
+        ssd_pattern = json.load(f)["pattern"]
+    names = [f"{name}:custom-call:{shape}" for name, shape in found]
+    decode = program == "nemotron_decode"
+    assert [n.split(":")[0] for n in names
+            if re.search(PAGED_ATTN_PATTERN, n)] == (
+        ["paged_decode_attn"] if decode else [])
+    assert [n.split(":")[0] for n in names if re.search(ssd_pattern, n)] == (
+        ["ssd_decode"] if decode else [])
+    # the routed experts' first matrix is stored in whole lane tiles (1,920
+    # for the published 1,856) and K/V's weight D-minor: stored otherwise
+    # each was copied on every token step (3.2 GB of temporaries)
+    assert verdict["weight_movers"][program] == []
+    assert verdict["temp_bytes"][program] < 0.5e9, verdict["temp_bytes"]
+    if decode:
+        calls, roundings = verdict["state_roundings"][program]
+        assert calls == 6 and roundings >= 6 * calls, (calls, roundings)
+        grids = verdict["grids"][program]
+        assert grids.count([128, 8]) == 6 and grids.count([128, 1]) == 2, grids
+
+
 @pytest.mark.parametrize("program,kernel,shape,need", [
     ("kimi_decode", "mla_decode_attn", "bf16[96,1,64,512]", (12.1e9, 12.4e9)),
     ("kimi_prefill_2048", "mla_prefill_attn", "bf16[1,128,1024,512]",
@@ -1254,6 +1369,21 @@ def test_the_shared_experts_pattern_matches_its_products_alone(verdict):
     assert sorted({name for name, _scope in ops}) == [
         "fusion:fusion:bf16[96,2048]", "fusion:fusion:f32[96,2048]"]
     assert all("/moe_shared/" in scope for _name, scope in ops), ops
+
+
+def test_the_capacity_forms_pattern_matches_its_products_alone(verdict):
+    """``expert_capacity_ffn_roofline`` and ``..._ms_per_step.batch`` find
+    the routed experts' batched products by their output's shape: in the
+    compiled decode program the pattern matches ONE fusion an expert layer
+    (both products and the activation between them), written under the
+    ``moe_experts`` scope, and nothing else; the grouped product is in the
+    program too, behind the ``cond`` that an overflowing expert takes."""
+    ops = verdict["capacity_ops"]["nemotron_decode"]
+    assert len(ops) == 5, ops
+    assert {name for name, _scope in ops} == {"fusion:fusion:f32[64,64,2688]"}
+    assert all("/moe_experts/" in scope for _name, scope in ops), ops
+    assert ["ragged-dot-none", "f32[768,1920]"] in verdict["kernels"][
+        "nemotron_decode"]
 
 
 def test_the_state_kernels_operands_keep_their_three_parts(verdict):
