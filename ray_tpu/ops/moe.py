@@ -118,9 +118,11 @@ def moe_ffn(params: Dict, x: jax.Array, cfg: MoEConfig) -> Tuple[jax.Array, Dict
 # Per-call pick counts, in the order of the array ``held_experts_ffn`` returns
 # (a model family names its serve counters after them): picks, zero-compute
 # picks, picks on held experts, the busiest held expert's pairs, held experts
-# with at least one pair; then 1 for a call that took the bounded form (below)
-# and the windows it walked beyond the first (or, for a call of the capacity
-# form, 1 if an expert's rows overflowed it into the all-rows product).
+# with at least one pair; then 1 for a call that walked its pairs (the bounded
+# row buffer's windows or the capacity form's passes, below) and the windows or
+# passes it walked beyond the first (or, for a small call of the capacity form,
+# which walks nothing, 1 if an expert's rows overflowed it into the all-rows
+# product).
 PICK_COUNT_NAMES = ("picks", "picks_zero", "picks_held", "held_pairs_max",
                     "experts_hit", "bounded_calls", "extra_windows")
 PICK_COUNTS = len(PICK_COUNT_NAMES)
@@ -150,33 +152,57 @@ def held_row_bound(n_tokens: int, topk: int, held: Tuple[int, int],
     return rows if 4 * rows <= pairs else None
 
 
-# Where MANY experts are held and each gets a few rows (a decode step of a
-# chip that holds 64 experts: 6 rows an expert), the grouped product is the
-# wrong tool: it visits every group with a whole row tile, so its work grows
-# with the groups, not the pairs (on the chip, 64 groups over 768 rows ran at
-# 11% of its bytes' time, 6.4 ms a product where the weights stream in 0.8;
-# at 12-16 groups the same product reads 60%). There the pairs are laid out a
-# fixed ``_CAPACITY`` rows an expert and multiplied by a plain batched product,
-# which streams each expert's matrices once. A call in which some expert got
-# more rows than that takes the all-rows grouped product instead (both are in
-# the program, a ``lax.cond`` picks): no pick is dropped.
+# Where MANY experts are held (a chip that holds 64 of 128), the grouped
+# product is the wrong tool: it visits every group with a whole row tile, so
+# its work grows with the groups, not the pairs (on the chip, 64 groups over
+# 768 rows ran at 11% of its bytes' time, 6.4 ms a product where the weights
+# stream in 0.8, and a layer took 15-17 ms over 1,536, 3,072 or 6,144 rows
+# alike; at 12-16 groups the same product reads 60%). There the pairs are laid
+# out ``cap`` rows an expert and multiplied by a plain batched product, which
+# streams each expert's matrices once. A small call (a decode step, the
+# smallest buckets: a mean of at most ``_CAPACITY_MAX_MEAN`` pairs an expert)
+# takes ``_CAPACITY`` rows, eight times the mean and more, and a call in which
+# some expert got more rows than that takes the all-rows grouped product
+# instead (both are in the program, a ``lax.cond`` picks). A larger call (a
+# prefill bucket) WALKS: pass ``i`` multiplies each expert's pairs ``[i * cap,
+# (i + 1) * cap)``, until the busiest expert has none left, and there is no
+# grouped product in the program. A pass streams every held expert's matrices
+# again (2.2-2.5 ms at 64 experts of 2,688 x 1,920 up to 128 rows an expert;
+# 3.0-3.8 ms at 256), so ``cap`` is ``_CAPACITY_FACTOR`` times the even
+# router's mean, rounded up to a power of two: the busiest expert of a seeded
+# router gets ~4 times the mean (one maximum among 64 spreads wider than the
+# sum over the chip's share, which ``_SHARE_FACTOR`` bounds), and twice the
+# mean walked a second pass in 41-62 of 100 calls where four times walks one
+# in 8-10. Past ``_CAPACITY_MAX`` rows a pass is bound by its products, not by
+# the weights it streams, and two passes cost what one of twice the rows
+# would: ``cap`` stops there. Either way no pick is dropped.
 _CAPACITY = 64
 _CAPACITY_MIN_EXPERTS = 32
 _CAPACITY_MAX_MEAN = 8
+_CAPACITY_FACTOR = 4
+_CAPACITY_MAX = 256
+
+
+def _one_product_call(n_tokens: int, topk: int, n_routed: int) -> bool:
+    """A call small enough for ONE product of ``_CAPACITY`` rows an expert
+    (a decode step, the smallest buckets); a larger one walks in passes."""
+    return n_tokens * topk <= _CAPACITY_MAX_MEAN * n_routed
 
 
 def held_capacity(n_tokens: int, topk: int, held: Tuple[int, int],
                   n_routed: int) -> Optional[int]:
     """Rows an expert of the capacity form, where ``held_experts_ffn`` takes
-    it: at least ``_CAPACITY_MIN_EXPERTS`` experts held and a mean of at most
-    ``_CAPACITY_MAX_MEAN`` pairs an expert under an even router (so that
-    ``_CAPACITY`` rows are eight times the mean and more: a seeded router's
-    busiest expert got 3.9 times the mean on the chip); None elsewhere. Read
-    off the static shapes alone."""
-    if (held[1] >= _CAPACITY_MIN_EXPERTS
-            and n_tokens * topk <= _CAPACITY_MAX_MEAN * n_routed):
+    it: at least ``_CAPACITY_MIN_EXPERTS`` experts held; None elsewhere. Up to
+    a mean of ``_CAPACITY_MAX_MEAN`` pairs an expert under an even router,
+    ``_CAPACITY`` rows; past it ``_CAPACITY_FACTOR`` times the mean, rounded
+    up to a power of two, from ``_CAPACITY`` to ``_CAPACITY_MAX``. Read off
+    the static shapes alone."""
+    if held[1] < _CAPACITY_MIN_EXPERTS:
+        return None
+    if _one_product_call(n_tokens, topk, n_routed):
         return _CAPACITY
-    return None
+    rows = -(-_CAPACITY_FACTOR * n_tokens * topk // n_routed)
+    return min(max(_CAPACITY, 1 << (rows - 1).bit_length()), _CAPACITY_MAX)
 
 
 def route_topk(h: jax.Array, w_router: jax.Array, bias: jax.Array, *,
@@ -287,17 +313,21 @@ def _walk_held_pairs(h, weights, w_in, w_down, order, sizes, rows: int,
 
 
 def _capacity_held_pairs(h, w_held, key, w_in, w_down, order, sizes,
-                         cap: int, form: str):
+                         cap: int, form: str, lo=None):
     """The held pairs' weighted sum [N, D] float32 with the pairs laid out
     ``cap`` rows an expert: expert ``e``'s rows are sorted pairs ``[start_e,
     start_e + size_e)``, the rest of its ``cap`` rows zeros. Two batched
     products ``[E, cap, D] x [E, D, F]`` and ``[E, cap, F] x [E, F, D]``;
-    then every pair gathers its row. The caller has checked ``max(sizes) <=
-    cap``."""
+    then every pair gathers its row. With no ``lo`` the caller has checked
+    ``max(sizes) <= cap``; with ``lo`` (a pass of :func:`_walk_capacity`) the
+    rows are each expert's pairs ``[lo, lo + cap)`` and a pair outside them
+    weighs 0."""
     N, D = h.shape
     k = w_held.shape[1]
     E, F = sizes.shape[0], w_down.shape[1]
     starts = jnp.cumsum(sizes) - sizes
+    if lo is not None:
+        starts, sizes = starts + lo, sizes - lo
     slot = jnp.arange(cap, dtype=jnp.int32)
     pos = jnp.minimum(starts[:, None] + slot[None, :], N * k - 1)
     live = slot[None, :] < sizes[:, None]                        # [E, cap]
@@ -313,9 +343,38 @@ def _capacity_held_pairs(h, w_held, key, w_in, w_down, order, sizes,
     back = jnp.zeros((N * k,), jnp.int32).at[order].set(
         jnp.arange(N * k, dtype=jnp.int32))
     e = jnp.minimum(key, E - 1)
-    row = e * cap + jnp.clip(back - starts[e], 0, cap - 1)
-    return jnp.einsum("nk,nkd->nd", w_held,
-                      y.reshape(E * cap, D)[row].reshape(N, k, D))
+    block = e * cap
+    place = back - starts[e]
+    if lo is not None:
+        w_held = jnp.where(((place >= 0) & (place < cap)).reshape(N, k),
+                           w_held, 0.0)
+    row = block + jnp.clip(place, 0, cap - 1)
+    rows = y.reshape(E * cap, D)
+    if lo is None:
+        return jnp.einsum("nk,nkd->nd", w_held, rows[row].reshape(N, k, D))
+    # A pass gathers one pick of every token at a time, so no [N, k, D]
+    # array is written (at 2,176 tokens and 256 rows 3.7 against 4.4 ms and
+    # 80 MB less; the form above is the decode program's and stays as it is).
+    row = row.reshape(N, k)
+    return sum(w_held[:, j, None] * rows[row[:, j]] for j in range(k))
+
+
+def _walk_capacity(h, w_held, key, w_in, w_down, order, sizes, cap: int,
+                   form: str):
+    """The held pairs' weighted sum [N, D] float32 in passes of the capacity
+    form: pass ``i`` gives expert ``e`` its sorted pairs ``[start_e + i *
+    cap, start_e + min((i + 1) * cap, size_e))`` and each pair gathers its
+    row from the pass it fell in. Returns (sum, passes walked)."""
+    def one_pass(carry):
+        i, out = carry
+        return i + 1, out + _capacity_held_pairs(
+            h, w_held, key, w_in, w_down, order, sizes, cap, form,
+            lo=i * cap)
+
+    passes, out = jax.lax.while_loop(
+        lambda carry: carry[0] * cap < jnp.max(sizes), one_pass,
+        (jnp.int32(0), jnp.zeros(h.shape, jnp.float32)))
+    return out, passes
 
 
 def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
@@ -352,10 +411,15 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
     their precision are the same in both forms (bfloat16 operands, float32
     accumulation, float32 weights and sum, one cast at the end); only the
     order in which a token's picks are summed differs. Where many experts
-    are held and each gets a few rows (:func:`held_capacity`) the all-rows
-    buffer is laid out a fixed number of rows an expert and multiplied by a
-    batched product instead, unless some expert got more rows than that
-    (the call then counts one ``extra_windows``): dropless too.
+    are held (:func:`held_capacity`) no grouped product fits: the pairs are
+    laid out a capacity of rows an expert and multiplied by a batched
+    product. A small call (a decode step) takes one such product, unless
+    some expert got more rows than the capacity: the all-rows grouped
+    product then takes the call, which counts one ``extra_windows`` and no
+    ``bounded_calls``. A larger call (a prefill bucket) walks the capacity
+    form in passes until the busiest expert has no pair left and holds no
+    grouped product: it counts one ``bounded_calls`` and the passes beyond
+    the first as ``extra_windows``. Dropless either way.
 
     Returns (out [N, D] in ``h.dtype``, counts int32 [PICK_COUNTS], one a
     name of ``PICK_COUNT_NAMES``)."""
@@ -404,7 +468,7 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
         if cap is None:
             out = all_rows()
             bounded = {"bounded_calls": 0, "extra_windows": 0}
-        else:
+        elif _one_product_call(N, k, n_routed):
             fits = jnp.max(sizes) <= cap
             out = jax.lax.cond(
                 fits, lambda: _capacity_held_pairs(
@@ -412,6 +476,12 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
                     order, sizes, cap, form), all_rows)
             bounded = {"bounded_calls": 0,
                        "extra_windows": 1 - fits.astype(jnp.int32)}
+        else:
+            out, passes = _walk_capacity(
+                h, jnp.where(on_held, weights, 0.0), key, w_in, w_down,
+                order, sizes, cap, form)
+            bounded = {"bounded_calls": 1,
+                       "extra_windows": jnp.maximum(passes - 1, 0)}
 
     is_zero = (idx >= n_routed) & live_k
     w_zero = jnp.sum(jnp.where(is_zero, weights, 0.0), axis=-1)  # [N]

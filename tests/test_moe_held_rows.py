@@ -2,11 +2,14 @@
 
 A prefill bucket's expert layer multiplies the pairs it holds through a
 buffer of the chip's share of the picks, walked in windows; a decode step
-keeps the ``N * k`` rows. Each case below is held to a per-token loop over
-the picks in float64 (not to the unbounded form), and its counts exactly.
+keeps the ``N * k`` rows; where many experts are held the pairs are laid out
+a capacity of rows an expert, a prefill bucket's walked in passes. Each case
+below is held to a per-token loop over the picks in float64 (not to the
+unbounded form), and its counts exactly.
 """
 
 import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -218,14 +221,138 @@ def test_row_bound_is_read_off_the_shapes(N, k, held, n_routed, rows):
     # the three accepted expert cells' decode steps keep the grouped product
     (96, 8, (0, 12), 384, None), (128, 12, (0, 16), 512, None),
     (64, 4, (0, 16), 256, None),
-    # Nemotron-H: the decode step and the buckets up to 128 tokens; past a
-    # mean of 8 pairs an expert the grouped product again
+    # Nemotron-H: the decode step and the buckets up to 128 tokens take 64
+    # rows; past a mean of 8 pairs an expert four times the mean, a power of
+    # two from 64 to 256
     (128, 6, (0, 64), 128, 64), (16, 6, (0, 64), 128, 64),
-    (256, 6, (0, 64), 128, None), (2048, 6, (0, 64), 128, None),
+    (256, 6, (0, 64), 128, 64), (2048, 6, (0, 64), 128, 256),
     (128, 6, (0, 16), 128, None),
+    (512, 6, (0, 64), 128, 128), (1024, 6, (0, 64), 128, 256),
+    (2176, 6, (0, 64), 128, 256), (8192, 6, (0, 64), 128, 256),
+    # a prefill bucket of a chip that holds 12: the bounded row buffer's
+    (2048, 8, (0, 12), 384, None),
 ])
 def test_capacity_form_is_read_off_the_shapes(N, k, held, n_routed, cap):
     assert moe.held_capacity(N, k, held, n_routed) == cap
+    if cap is not None:
+        assert cap in (64, 128, 256)
+        assert cap >= min(256, 4 * N * k / n_routed)
+
+
+def _picks_with_one_busy_expert(key, N, k, held, n_routed, live, busy):
+    """Even picks (k distinct outputs a token), then held expert ``first``
+    picked by exactly the first ``busy`` of the ``live`` tokens and by no
+    other: a token that has it and must not swaps it for an output it has
+    not, one that lacks it and must have it swaps its first pick for it."""
+    idx = np.array(_picks("even", key, N, k, held, n_routed, 0))
+    first = held[0]
+    must = np.zeros((N,), bool)
+    must[np.flatnonzero(live)[:busy]] = True
+    for n in range(N):
+        has = idx[n] == first
+        if has.any() and not must[n]:
+            idx[n, has] = next(e for e in range(n_routed - 1, -1, -1)
+                               if e != first and e not in idx[n])
+        elif must[n] and not has.any():
+            idx[n, 0] = first
+    return jnp.asarray(idx, jnp.int32)
+
+
+# The capacity form WALKED (a prefill bucket of a chip that holds many
+# experts): 32 of 64 held, top-4, 2,048 tokens: a mean of 128 pairs an expert
+# under an even router, 256 rows a pass (four times the mean is past the
+# most a pass takes). (load, dead rows of the padded tail, pairs of the
+# busiest expert or None for what the even load gives, passes)
+WALK_CASES = [
+    pytest.param("even", 64, None, 1, id="even_one_pass"),
+    # one expert gets five times the mean: 256 + 256 + 128 rows
+    pytest.param("busy", 64, 640, 3, id="one_expert_5x_three_passes"),
+    # every live token picks one expert: ceil(1,984 / 256) passes
+    pytest.param("busy", 64, 1984, 8, id="every_token_on_one_expert"),
+    pytest.param("busy", 0, 2048, 8, id="every_token_no_dead_row"),
+    pytest.param("none_held", 64, None, 0, id="no_pair_no_pass"),
+]
+
+
+@pytest.mark.parametrize("form", moe.EXPERT_FORMS)
+@pytest.mark.parametrize("load,dead,busy,passes", WALK_CASES)
+def test_capacity_passes_give_the_plain_loops_sum_and_counts(
+        load, dead, busy, passes, form):
+    N, k, held, n_routed, cap = 2048, 4, (16, 32), 64, 256
+    keys = jax.random.split(jax.random.key(dead + (busy or 0)), 5)
+    h = jax.random.normal(keys[0], (N, D))
+    w_in = jax.random.normal(
+        keys[1], (held[1], D, F if form == "relu2" else 2 * F)) * 0.2
+    w_down = jax.random.normal(keys[2], (held[1], F, D)) * 0.2
+    w = jax.random.uniform(keys[4], (N, k)) + 0.1
+    valid = np.arange(N) < N - dead             # a bucket's padded tail
+    idx = (_picks_with_one_busy_expert(keys[3], N, k, held, n_routed, valid,
+                                       busy) if load == "busy"
+           else _picks(load, keys[3], N, k, held, n_routed, 0))
+
+    assert moe.held_row_bound(N, k, held, n_routed) is None
+    assert moe.held_capacity(N, k, held, n_routed) == cap
+    run = jax.jit(functools.partial(moe.held_experts_ffn, held=held,
+                                    n_routed=n_routed, form=form))
+    out, counts = run(h, idx, w, w_in, w_down, valid=jnp.asarray(valid))
+    want, want_counts = _plain(h, idx, w, w_in, w_down, held, n_routed, valid,
+                               form=form)
+    np.testing.assert_allclose(out, want, rtol=5e-5, atol=5e-5)
+    assert not np.asarray(out)[~valid].any()
+    if busy is not None:
+        assert want_counts[3] == busy
+    assert -(-want_counts[3] // cap) == passes
+    # every held pick is in the sum and in the counts: none dropped
+    assert dict(zip(moe.PICK_COUNT_NAMES, map(int, counts))) == dict(
+        zip(moe.PICK_COUNT_NAMES, want_counts + [1, max(passes - 1, 0)]))
+
+
+def _traced(N, k, held, n_routed, form="silu_gate"):
+    args = (jnp.zeros((N, D)), jnp.zeros((N, k), jnp.int32),
+            jnp.zeros((N, k)),
+            jnp.zeros((held[1], D, F if form == "relu2" else 2 * F)),
+            jnp.zeros((held[1], F, D)))
+    return str(jax.make_jaxpr(functools.partial(
+        moe.held_experts_ffn, held=held, n_routed=n_routed, form=form))(
+            *args, valid=jnp.ones((N,), bool)))
+
+
+@pytest.mark.parametrize("N", [256, 512, 1024, 2176])
+def test_a_walked_call_holds_no_grouped_product(N):
+    """64 of 128 held, a bucket of 256 tokens or more: the traced call holds
+    the batched products in a loop and no ``ragged_dot``."""
+    text = _traced(N, 6, (0, 64), 128, "relu2")
+    assert "ragged_dot" not in text and "while[" in text
+    assert "cond[" not in text
+
+
+# sha256 of ``str(jax.make_jaxpr(held_experts_ffn))`` at ``D, F = 32, 16``,
+# under ``tests/conftest.py``'s configuration, taken on the commit BEFORE the
+# walk in passes (PR 46's tree): the calls whose rule did not change trace to
+# the program they were. A PR that changes one of these forms on purpose takes
+# new digests.
+UNCHANGED = [
+    # Kimi-K2.5 (12 held): decode step, the bounded 2,048 bucket
+    (96, 8, (0, 12), 384, "silu_gate", "f47db6413b891cbb"),
+    (2048, 8, (0, 12), 384, "silu_gate", "b18a8aff4d299768"),
+    # LongCat-Flash (16 held): decode step, the 512 bucket
+    (128, 12, (0, 16), 512, "silu_gate", "fb048aa26ac8ee70"),
+    (512, 12, (0, 16), 512, "silu_gate", "a1ca11c28abba609"),
+    # Trinity (16 held): decode step, the 8,192 bucket
+    (64, 4, (0, 16), 256, "silu_gate", "ca1ad4951d9508dd"),
+    (8192, 4, (0, 16), 256, "silu_gate", "c47e42f5077afa7d"),
+    # Nemotron-H (64 held): decode step and the 16 bucket: 64 rows, the cond
+    (128, 6, (0, 64), 128, "relu2", "825d0f5c95ee8a76"),
+    (16, 6, (0, 64), 128, "relu2", "274e1af567f74d0d"),
+]
+
+
+@pytest.mark.parametrize("N,k,held,n_routed,form,digest", UNCHANGED)
+def test_the_other_calls_trace_to_the_program_they_were(
+        N, k, held, n_routed, form, digest):
+    text = _traced(N, k, held, n_routed, form)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    assert "ragged_dot" in text
 
 
 def test_the_capacity_form_serves_the_gated_expert_too():

@@ -14,6 +14,7 @@ process has JAX pinned to its CPU configuration, and two processes loading
 libtpu at once collide on its lock file.
 """
 
+import hashlib
 import json
 import math
 import os
@@ -303,6 +304,19 @@ def flash_layout_movers(hlo: str, heads: int, seq: int,
     return sorted([instrs[n][0], n, instrs[n][1][:60]] for n in found)
 
 
+def instruction_multiset(hlo: str) -> list:
+    """[instructions, sha256 of the sorted (op kind, output shape) of every
+    one]: an optimized module's instructions whatever their names and order
+    (the fusions' bodies included). Two compiles of one traced program give
+    the same pair; a program whose products moved, or whose fusions were cut
+    otherwise, does not."""
+    bodies, _fused = _computations(hlo)
+    found = sorted(f"{op} {shape}" for body in bodies.values()
+                   for _line, _name, shape, op in body)
+    return [len(found),
+            hashlib.sha256("\n".join(found).encode()).hexdigest()[:16]]
+
+
 def named_ops(hlo: str, pattern: str) -> list:
     """[[name, ``op_name`` metadata], ...] of the instructions outside the
     fusions' bodies whose name, as the profiler gives it and the benchmark
@@ -400,6 +414,7 @@ def compile_all() -> dict:
     ``shared_expert_ms_per_step.batch``},
     "capacity_ops": {Nemotron decode: named_ops() of the pattern of
     ``expert_capacity_ffn_roofline``},
+    "multisets": {name: instruction_multiset() of the compiled program},
     "latent_calls": {name: [calls of the latent kernel's jit, distinct
     traced bodies among them]},
     "flash_products": {name: pallas_products() of the flash kernels},
@@ -445,7 +460,7 @@ def compile_all() -> dict:
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "expert_capacity_ffn_roofline.json")) as f:
         capacity_pattern = json.load(f)["pattern"]
-    capacity_ops = {}
+    capacity_ops, multisets = {}, {}
 
     def attempt(name, trace, pool=None, state=None, weights=None,
                 shared=False, state_kernel="gdn_decode", pairs=None,
@@ -483,6 +498,7 @@ def compile_all() -> dict:
                 capacity_ops[name] = named_ops(text, capacity_pattern)
             if pairs is not None:
                 pair_rows[name] = pair_row_arrays(text, *pairs)
+            multisets[name] = instruction_multiset(text)
             if state is not None:
                 state_movers[name] = pool_shaped_data_movers(text, *state)
                 state_roundings[name] = [
@@ -830,7 +846,7 @@ def compile_all() -> dict:
             "state_movers": state_movers, "weight_movers": weight_movers,
             "state_roundings": state_roundings,
             "shared_expert_ops": shared_expert_ops,
-            "capacity_ops": capacity_ops,
+            "capacity_ops": capacity_ops, "multisets": multisets,
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
             "flash_products": flash_products, "flash_movers": flash_movers,
             "pair_rows": pair_rows}
@@ -1221,7 +1237,7 @@ def test_trinity_serve_programs_fit_the_chip(verdict, program, kernels, need):
                          "paged_decode_attn": "bf16[128,32,1,128]"},
      (10.2e9, 10.5e9)),
     ("nemotron_prefill_2176", {"paged_prefill_attn": "bf16[1,32,2176,128]"},
-     (10.5e9, 10.9e9))])
+     (10.5e9, 10.685e9))])
 def test_nemotron_h_serve_programs_fit_the_chip(verdict, program, kernels,
                                                 need):
     """Nemotron-H's ``paged_decode`` and its largest ``paged_prefill`` at the
@@ -1232,7 +1248,11 @@ def test_nemotron_h_serve_programs_fit_the_chip(verdict, program, kernels,
     and it is called six times (a whole group's 512 lanes a block: a grid of
     128 slots x 8), the attention kernel twice on a pool of TWO layers with
     all 32 query heads in its output over a row of the two KV heads, the
-    grouped product by the five expert layers. No instruction copies or
+    routed experts' capacity form by the five expert layers: the decode
+    step's behind a ``cond`` whose other branch is the grouped product, the
+    prefill bucket's walked in passes with NO grouped product in the program
+    (``ops/moe.py:held_capacity``: 256 rows an expert here), its need at or
+    under the 10.68 GB it took with one. No instruction copies or
     slices data the size of the pool, of the slot state or, in the decode
     program, of a weight; of the decode program's Pallas calls the
     benchmark's ``paged_attn_roofline`` pattern matches the attention kernel
@@ -1242,6 +1262,10 @@ def test_nemotron_h_serve_programs_fit_the_chip(verdict, program, kernels,
     # weights 8.07 GB as stored (the experts 1,920 wide) + slot state 1.64 GB
     # + pool 0.54 GB + last 0.03 GB; temporaries 0.03 GB (decode), 0.39 GB
     assert need[0] < verdict["need_bytes"][program] < need[1], verdict["need_bytes"]
+    grouped = sorted(s for n, s in verdict["kernels"][program]
+                     if n == "ragged-dot-none")
+    assert grouped == (["f32[768,1920]", "f32[768,2688]"]
+                       if program == "nemotron_decode" else []), grouped
     found = [[n, s] for n, s in verdict["kernels"][program]
              if not n.startswith("ragged-dot")]
     assert dict(found) == kernels, verdict["kernels"][program]
@@ -1384,6 +1408,16 @@ def test_the_capacity_forms_pattern_matches_its_products_alone(verdict):
     assert all("/moe_experts/" in scope for _name, scope in ops), ops
     assert ["ragged-dot-none", "f32[768,1920]"] in verdict["kernels"][
         "nemotron_decode"]
+
+
+def test_nemotron_decode_is_the_program_it_was(verdict):
+    """The walk in passes is a prefill bucket's: the decode program (128
+    slots x top-6: 64 rows an expert, the ``cond``) compiles to the
+    instructions it had on PR 46's tree, so the fusion the capacity metrics
+    name is the one they were accepted on. A PR that changes this family's
+    decode program on purpose takes a new pair."""
+    assert verdict["multisets"]["nemotron_decode"] == [
+        10854, "72e88c77065503af"]
 
 
 def test_the_state_kernels_operands_keep_their_three_parts(verdict):
