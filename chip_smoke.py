@@ -67,6 +67,7 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     from ray_tpu.ops.paged_attention import (latent_paged_attention,
                                              latent_paged_attention_reference,
                                              paged_attention,
+                                             paged_attention_append,
                                              paged_attention_reference)
     from ray_tpu.serve.llm import _default_buckets
 
@@ -120,9 +121,42 @@ def phase_kernels(cfg, interpret: bool) -> dict:
             want = oracle(*ops)
         errs[name] = _rel_err(kernel(*ops), want)
 
+    appending = jax.jit(lambda *a: paged_attention_append(
+        *a, interpret=interpret))
+
+    def append_case(name, lengths):
+        """The decode step's call that takes the new rows as operands: its
+        output against the oracle over a pool they were scattered into, and
+        both pools against that scatter, the trash block aside (a parked
+        slot, length None, and one at table capacity write nothing; the
+        scatter sent their rows to block 0)."""
+        n = len(lengths)
+        qq, k_row, v_row = (jnp.asarray(rng.standard_normal(shape, np.float32), dt)
+                            for shape in ((n, 1, H, D), (n, H * D), (n, H * D)))
+        parked = np.asarray([ln is None for ln in lengths])
+        lens = np.asarray([ln or 0 for ln in lengths])
+        tbl = np.where(parked[:, None], 0, np.asarray(tables[:n]))
+        pos = np.minimum(lens, ctx - 1)
+        blk = np.where(lens < ctx, tbl[np.arange(n), pos // bt], 0)
+        k_ref, v_ref = (pool.at[0, blk, pos % bt].set(row) for pool, row
+                        in ((k_pool, k_row), (v_pool, v_row)))
+        ops = (jnp.asarray(tbl), jnp.asarray(lens, jnp.int32), 0)
+        with jax.default_matmul_precision("highest"):
+            want = oracle(qq, k_ref, v_ref, *ops)
+        out, k_new, v_new = appending(qq, k_row, v_row, k_pool, v_pool, *ops)
+        live = ~parked & (lens < ctx)
+        errs[name] = _rel_err(out[live], want[live])
+        errs[name + "_pools"] = float(
+            bool(np.asarray(out, np.float32)[parked].any()) or not all(
+                bool(jnp.array_equal(new[:, 1:], ref[:, 1:]))
+                for new, ref in ((k_new, k_ref), (v_new, v_ref))))
+
     # around a block's edge and around a group of blocks' (128 positions)
     paged_case("paged_decode_t1", 1,
                [0, bt - 1, bt, 127, 128, 129, ctx // 2, ctx - 1])
+    append_case("paged_decode_append",
+                [0, bt - 1, bt, 127, 128, 129, ctx // 2, ctx - 1])
+    append_case("paged_decode_append_idle", [ctx, None, bt + 1, None, ctx - 1])
     paged_case("paged_verify_t5", 5,
                [0, 3, bt - 1, bt, 5 * bt + 4, ctx // 2, ctx - 6, ctx - 5])
     for b in _default_buckets(ctx):
@@ -389,7 +423,10 @@ def main(argv=None) -> int:
     from ray_tpu.models import transformer
 
     if args.rehearse:
-        model = lambda **kw: transformer.tiny(max_seq_len=1024, **kw)
+        # 4 heads of 32: a pool row of one whole 128-lane tile, so that the
+        # rehearsal's decode takes the kernel that writes its own rows
+        model = lambda **kw: transformer.tiny(d_model=128, max_seq_len=1024,
+                                              **kw)
         batch_per_chip = 1
     else:
         model = lambda **kw: transformer.gpt2_small(max_seq_len=1024, **kw)

@@ -24,7 +24,8 @@ from jax import lax
 
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.ops.layers import gelu, layer_norm, linear, rope
-from ray_tpu.ops.paged_attention import paged_attention
+from ray_tpu.ops.paged_attention import (append_rows_fit, paged_attention,
+                                         paged_attention_append)
 
 
 def resolve_attention_kernel(mode: Optional[str]) -> str:
@@ -233,16 +234,21 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
                           kernel: str = "gather"):
     """Decode ``tokens`` [S, T] for S sequences over the paged pool: slot
     s's token t sits at absolute position ``lengths[s] + t``, its K/V
-    scattered into block
+    written into block
     ``tables[s, pos // bt]`` row ``pos % bt`` and attention run back through
-    the table row. Inactive slots carry all-trash tables, so their writes
-    land in block 0 and their outputs are dead.
+    the table row. One token a slot through the Pallas kernel (the serve
+    engine's decode step) hands the kernel the new rows and gets the pools
+    back with them in (``paged_attention_append``: no pass over HBM to
+    write a row that the next operation fetches); otherwise they are
+    scattered first. Inactive slots carry all-trash tables: the kernel
+    writes nothing for them, the scatter writes block 0, and their outputs
+    are dead.
 
-    Positions at or past table capacity redirect their writes to trash
-    block 0 rather than clamping onto the last cell — a slot at capacity
-    must be finished as ``length_cap`` by the engine BEFORE dispatch, so
-    in-range rows never see a silently overwritten chain; the redirect only
-    shields a parked slot's overhang writes.
+    Positions at or past table capacity write nothing (the scatter:
+    redirect to trash block 0) rather than clamping onto the last cell — a
+    slot at capacity must be finished as ``length_cap`` by the engine BEFORE
+    dispatch, so in-range rows never see a silently overwritten chain; the
+    redirect only shields a parked slot's overhang writes.
 
     ``params`` is the working tree of :func:`gpt2_working_params`."""
     c = config
@@ -252,15 +258,23 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
     max_len = NB * bt
     h = jnp.take(params["tok_embed"], tokens, axis=0)[..., :c.d_model]
     positions = lengths[:, None] + jnp.arange(T)[None, :]  # [S, T]
-    write_ok = positions < max_len
-    pos_c = jnp.minimum(positions, max_len - 1)
     if c.pos == "learned":
         h = h + params["pos_embed"][jnp.minimum(
             positions, c.max_seq_len - 1)][..., :c.d_model]
     scale = 1.0 / c.head_dim**0.5
-    rows = jnp.arange(S)[:, None]
-    blk = jnp.where(write_ok, tables[rows, pos_c // bt], 0)
-    off = pos_c % bt
+    # One token a slot through the kernel that walks by DMA: the kernel takes
+    # the new rows as operands and writes them itself. Otherwise (the gather
+    # path, several tokens a slot, a pool row off the 128-lane grid, more
+    # rows than the kernel holds in VMEM) the rows are scattered into the
+    # pool first and attended there.
+    appends = (kernel in ("pallas", "interpret") and T == 1
+               and append_rows_fit(S, k_pool.shape[3]))
+    if not appends:
+        write_ok = positions < max_len
+        pos_c = jnp.minimum(positions, max_len - 1)
+        blk = jnp.where(write_ok, tables[jnp.arange(S)[:, None], pos_c // bt],
+                        0)
+        off = pos_c % bt
 
     for layer, bp in enumerate(params["layers"]):
         x = layer_norm(h, bp["ln1_g"], bp["ln1_b"])
@@ -270,11 +284,16 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
         if c.pos == "rope":
             q = rope(q, positions)
             k = rope(k, positions)
-        with jax.named_scope("kv_pool_write"):
-            k_pool = k_pool.at[layer, blk, off].set(k.reshape(S, T, -1))
-            v_pool = v_pool.at[layer, blk, off].set(v.reshape(S, T, -1))
-        o = _paged_attend(q, k_pool, v_pool, tables, lengths, layer,
-                          scale=scale, kernel=kernel)
+        if appends:
+            o, k_pool, v_pool = paged_attention_append(
+                q, k.reshape(S, -1), v.reshape(S, -1), k_pool, v_pool, tables,
+                lengths, layer, scale=scale, interpret=kernel == "interpret")
+        else:
+            with jax.named_scope("kv_pool_write"):
+                k_pool = k_pool.at[layer, blk, off].set(k.reshape(S, T, -1))
+                v_pool = v_pool.at[layer, blk, off].set(v.reshape(S, T, -1))
+            o = _paged_attend(q, k_pool, v_pool, tables, lengths, layer,
+                              scale=scale, kernel=kernel)
         o = jnp.einsum("bthk,hkd->btd", o, bp["wo"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bo"]
         h = h + o
         x = layer_norm(h, bp["ln2_g"], bp["ln2_b"])

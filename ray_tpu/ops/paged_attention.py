@@ -41,11 +41,26 @@ kernel had ``G``.
 Layout: ``q`` [S, T, H, D] — T > 1 is the multi-token speculative-decoding
 verify (and the paged prefill, S == 1): query t of slot s sits at absolute
 position ``lengths[s] + t`` and attends kv positions ``<= lengths[s] + t``.
-The T new tokens' K/V must already be scattered into the pool at those
-positions (the caller writes K/V first, then attends — same order as the
-gather path). Queries are tiled ``_Q_TILE`` at a time so that VMEM is
+``paged_attention`` reads: the T new tokens' K/V must already be scattered
+into the pool at those positions (the caller writes K/V first, then attends:
+the gather path's order; a prefill, the windowed walk and every family but
+GPT-2 call it so). Queries are tiled ``_Q_TILE`` at a time so that VMEM is
 bounded by the tile, not by T; decode and verify are a single tile, and each
 tile walks only the blocks at or below its own last real query.
+
+``paged_attention_append`` reads AND writes: a decode step (T = 1) hands it
+each slot's new K and V row as operands beside pools that do not hold them
+yet, and gets the pools back with the rows in (aliased outputs: the same
+buffers for a caller that donates them). The walk is the same; when a slot's
+last group has arrived, which holds the block of position ``lengths[s]``, the
+row is laid into its place in VMEM, attended there, and the block is copied
+back whole under the rest of the step (``_paged_append_kernel``). What it
+replaces is a scatter a layer for K and one for V, each a fusion of its own
+that wrote the row to HBM for the kernel to fetch back: 48 a token step, the
+first operation of a chat token (PERF.md, PR 48). A parked slot and one at
+table capacity write nothing. Which of the two a caller gets is what it
+passes (rows or none), not a flag: with no row the traced body is the one
+the digests hold.
 
 Grouped queries: a pool row holds the ``KV`` heads that are STORED, ``KV`` a
 divisor of the ``H`` query heads (``KV == H`` is plain multi-head attention
@@ -471,6 +486,22 @@ def _walk_live_groups(
                         lambda c: c.start())
 
 
+def _attend_buffers(q_ref, o_ref, k_buf, v_buf, accumulators, g, ctx, half,
+                    fetched, *, scale: float, kv_heads: int, **walk):
+    """``_attend_group`` on group ``g`` as it lies in half ``half`` of the K
+    and V buffers of the kernels with two pools."""
+    # Unfetched rows of K are masked whatever they hold; V's meet p = 0,
+    # and 0 x NaN is NaN, so they are read as zero.
+    _attend_group(
+        q_ref, lambda d0, d1: k_buf[half, :, d0:d1],
+        lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
+        g, ctx, *accumulators, scale=scale,
+        num_heads=o_ref.shape[1], kv_heads=kv_heads,
+        q_tile=walk["q_tile"], head_dim=o_ref.shape[-1],
+        group_tokens=walk["group_blocks"] * walk["block_tokens"],
+        window=walk.get("window"))
+
+
 def _paged_kernel(
     tables_ref, lengths_ref,   # scalar prefetch: [S, NB] int32, [S] int32
     layer_ref,                 # scalar prefetch: [1] int32
@@ -489,21 +520,88 @@ def _paged_kernel(
     """Two pools, K and V, KV heads folded into the lanes: the walk
     (``_walk_live_groups``) with ``_attend_group`` on every group."""
     def attend(g, ctx, half, fetched):
-        # Unfetched rows of K are masked whatever they hold; V's meet p = 0,
-        # and 0 x NaN is NaN, so they are read as zero.
-        _attend_group(
-            q_ref, lambda d0, d1: k_buf[half, :, d0:d1],
-            lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
-            g, ctx, m_scr, l_scr, acc_scr, scale=scale,
-            num_heads=o_ref.shape[1], kv_heads=kv_heads,
-            q_tile=walk["q_tile"], head_dim=o_ref.shape[-1],
-            group_tokens=walk["group_blocks"] * walk["block_tokens"],
-            window=walk.get("window"))
+        _attend_buffers(q_ref, o_ref, k_buf, v_buf, (m_scr, l_scr, acc_scr),
+                        g, ctx, half, fetched, scale=scale, kv_heads=kv_heads,
+                        **walk)
 
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
         sems, half_ref, (m_scr, l_scr, acc_scr), attend,
         lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref, **walk)
+
+
+def _paged_append_kernel(
+    tables_ref, lengths_ref, layer_ref,   # scalar prefetch, as _paged_kernel
+    q_ref,                     # [1, H, C*D] block: one query a head
+    k_in, v_in,                # the pools as they came in: aliased, not read
+    k_row_ref, v_row_ref,      # [S, KV*D] float32: every slot's new K and V row
+    o_ref,                     # [1, H, 1, D] block
+    k_hbm, v_hbm,              # the pools [L, num_blocks, bt, KV*D], in HBM
+    m_scr, l_scr, acc_scr, k_buf, v_buf, sems, half_ref,   # _paged_kernel's
+    back_sems,                 # DMA semaphores [2 (K, V)]: the write back
+    *,
+    scale: float,
+    kv_heads: int,
+    **walk,                    # _walk_live_groups' static arguments
+):
+    """``_paged_kernel`` for a decode step whose new row is not in the pool
+    yet. The walk is the same (the slot's last group holds the block of
+    position ``lengths[s]``, fetched as ever); when that group has arrived
+    the row is laid into its place in the buffer, so the dots see it where
+    the scatter would have put it, and the block goes back to
+    ``pool[layer, tables[s, lengths[s] // bt]]`` whole, the rows beside the
+    new one as they were fetched: a copy started before the group is
+    attended and awaited after the output is written, before the next step
+    may fetch into that half. (One row cannot go alone: two bfloat16 rows
+    share the 32-bit words of the pool's tiles, and Mosaic slices neither
+    side of a copy finer than a tile.) A block a decode step writes is its
+    slot's own: the engine copies a shared one first. Nothing is laid in or
+    written for a slot at table capacity (its output is dead) nor into the
+    trash block, and a parked slot has no group at all."""
+    del k_in, v_in
+    s = pl.program_id(0)
+    bt, nb_seq, G = walk["block_tokens"], walk["nb_seq"], walk["group_blocks"]
+    pos = lengths_ref[s]
+    entry = jnp.minimum(jax.lax.div(pos, bt), nb_seq - 1)
+    blk = tables_ref[s, entry]
+    # What is started in the slot's last group is awaited after the walk:
+    # the walk's own test of a parked slot, which has no group, is in it.
+    writes = jnp.logical_and(
+        jnp.logical_and(tables_ref[s, 0] != 0, pos < nb_seq * bt), blk != 0)
+    # the block's rows of its group's buffer
+    rows = pl.ds(pl.multiple_of(jax.lax.rem(entry, G) * bt, bt), bt)
+
+    def write_back(half, act):
+        for n, (buf, pool) in enumerate(((k_buf, k_hbm), (v_buf, v_hbm))):
+            act(pltpu.make_async_copy(
+                buf.at[half, rows], pool.at[layer_ref[0], blk],
+                back_sems.at[n]))
+
+    def lay_in(half):
+        new = jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0) == jax.lax.rem(
+            pos, bt)
+        for buf, row in ((k_buf, k_row_ref), (v_buf, v_row_ref)):
+            # in float32: exact, and no select of packed rows
+            buf[half, rows] = jnp.where(
+                new, row[pl.ds(s, 1), :],
+                buf[half, rows].astype(jnp.float32)).astype(buf.dtype)
+        write_back(half, lambda c: c.start())
+
+    def attend(g, ctx, half, fetched):
+        pl.when(jnp.logical_and(writes, g == jax.lax.div(entry, G)))(
+            lambda: lay_in(half))
+        _attend_buffers(q_ref, o_ref, k_buf, v_buf, (m_scr, l_scr, acc_scr),
+                        g, ctx, half, fetched, scale=scale, kv_heads=kv_heads,
+                        **walk)
+
+    _walk_live_groups(
+        tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
+        sems, half_ref, (m_scr, l_scr, acc_scr), attend,
+        lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref, **walk)
+    # The half the last group lay in: the one before the half the walk left
+    # for the next step's first group.
+    # raylint: ignore[untimed-wait] — a DMA semaphore inside the kernel
+    pl.when(writes)(lambda: write_back(1 - half_ref[0], lambda c: c.wait()))
 
 
 def _paged_kernel_unaligned(
@@ -606,6 +704,69 @@ def paged_attention(
         interpret=interpret, window=None if window is None else int(window))
 
 
+# The appending call holds every slot's new K and V row in VMEM for the whole
+# call, in float32 and double-buffered: 2 operands x 2 buffers x S x W x 4
+# bytes (0.6 MB at 36 slots of 1,024 lanes) beside the walk's 4 MB of groups
+# under the 16 MB of scoped VMEM.
+_APPEND_ROWS_VMEM_BYTES = 2 << 20
+
+
+def append_rows_fit(slots: int, width: int) -> bool:
+    """Whether :func:`paged_attention_append` takes ``slots`` rows of
+    ``width`` lanes: whole 128-lane tiles, and all of them in VMEM at once
+    (``slots * width <= 131,072``: 128 slots of 1,024 lanes). A caller that
+    gets False scatters its rows and calls :func:`paged_attention`."""
+    return width % 128 == 0 and 16 * slots * width <= _APPEND_ROWS_VMEM_BYTES
+
+
+def paged_attention_append(
+    q: jax.Array,                # [S, 1, H, D]: one decode step's queries
+    k_row: jax.Array,            # [S, KV*D]: the step's new K row a slot
+    v_row: jax.Array,
+    k_pool: jax.Array,           # [L, num_blocks, bt, KV*D] (the whole pool)
+    v_pool: jax.Array,
+    tables: jax.Array,           # [S, NB] int32
+    lengths: jax.Array,          # [S] int32: rows of the slot in the pool
+    layer,
+    *,
+    scale: Optional[float] = None,
+    interpret: bool = False,
+):
+    """A decode step's attention AND its pool write in one call; returns
+    ``(out [S, 1, H, D], k_pool, v_pool)``, the pools updated where they lie
+    (the call aliases them through: donate them, or XLA copies them first).
+
+    Slot ``s``'s query sits at position ``lengths[s]`` and attends the
+    slot's ``lengths[s]`` rows of the pool and ``k_row[s]`` / ``v_row[s]``,
+    its own, which the kernel takes from the operand (rounded to the pool's
+    type) and leaves at ``pool[layer, tables[s, lengths[s] // bt],
+    lengths[s] % bt]``, its block written back under the walk: what
+    ``pool.at[layer, blk, off].set(row)`` followed by
+    :func:`paged_attention` gives, bit for bit, without the scatter's pass
+    over HBM and the fetch of the row back. A parked slot (``tables[s, 0]
+    == 0``) writes nothing and reads zeros, a slot at table capacity
+    (``lengths[s] >= NB * bt``) writes nothing and its output is dead, and
+    no row goes to the trash block: the scatter sent all three there. Pools
+    whose row is whole 128-lane tiles only (the kernel that walks by DMA),
+    and no more rows than :func:`append_rows_fit` allows: they all lie in
+    VMEM for the whole call."""
+    S, T, H, D = q.shape
+    W = k_pool.shape[3] if k_pool.ndim == 4 else 0
+    if (T != 1 or not W or W % D or H % (W // D) or not append_rows_fit(S, W)
+            or k_row.shape != (S, W) or v_row.shape != (S, W)):
+        raise ValueError(
+            f"q {q.shape} and rows {k_row.shape} over a pool {k_pool.shape}: "
+            f"the appending call takes one query a slot, [S, KV*{D}] rows "
+            f"and a pool [L, num_blocks, bt, KV*{D}] of whole 128-lane tiles, "
+            f"S x KV*{D} at most {_APPEND_ROWS_VMEM_BYTES // 16}")
+    return _paged_attention(
+        q, k_pool, v_pool, tables.astype(jnp.int32),
+        lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        (k_row.astype(k_pool.dtype), v_row.astype(v_pool.dtype)),
+        scale=float(scale) if scale is not None else 1.0 / D**0.5,
+        interpret=interpret)
+
+
 # Rows (query heads x queries) of a tile's float32 accumulators: m and l are
 # padded to 128 lanes, so three buffers of rows x 512 bytes. 30 heads of 128
 # at the full tile fit the 16 MB of scoped VMEM; 48 heads take half a tile.
@@ -613,12 +774,14 @@ _Q_TILE_ROWS = 4096
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
-def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
-                     interpret, window=None):
+def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
+                     scale, interpret, window=None):
     """:func:`paged_attention` on checked operands, ``layer`` an int32[1]
     VALUE: a jit of its own, so that a program that calls it once a layer
     (24 unrolled layers, eight serve programs) traces and lowers the kernel
-    once and calls it 24 times; XLA inlines the calls."""
+    once and calls it 24 times; XLA inlines the calls. ``rows``: the new
+    ``(k_row, v_row)`` of :func:`paged_attention_append`, whose kernel and
+    results (the pools beside the output) these are then."""
     S, T, H, D = q.shape
     bt = k_pool.shape[2]
     W = k_pool.shape[3]                               # a row: KV heads x D
@@ -674,24 +837,49 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, *, scale,
         scratch = accumulators
         pools = (k_pool,) * G + (v_pool,) * G
 
+    q_spec = pl.BlockSpec((None, 1, H * tq, C * D),
+                          lambda s, i, *_: (s, i, 0, 0))
+    out_spec = pl.BlockSpec((1, H, tq, D), lambda s, i, *_: (s, 0, i, 0))
+    out_shape = jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype)
+    # The name a profiler prints for the kernel, whatever calls it: a
+    # windowed call has names of its own, so that a trace tells a stack's
+    # window layers from its full ones.
+    name = (("paged" if window is None else "window")
+            + ("_decode_attn" if T == 1 else "_prefill_attn"))
+    if rows is not None:
+        # Every slot's row in VMEM for the whole call, in float32: the step
+        # picks its own out, one row of 32-bit words (a bfloat16 row shares
+        # its words with a neighbour, and a block of one row a slot made XLA
+        # re-tile the projections' output before every call).
+        row_spec = pl.BlockSpec((S, W), lambda s, i, *_: (0, 0))
+        out, k_pool, v_pool = pl.pallas_call(
+            functools.partial(_paged_append_kernel, scale=scale, kv_heads=KV,
+                              **walk),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=grid,
+                in_specs=[q_spec] + kv_specs + [row_spec] * 2,
+                out_specs=[out_spec] + kv_specs,
+                scratch_shapes=scratch + [pltpu.SemaphoreType.DMA((2,))]),
+            out_shape=[out_shape, k_pool, v_pool],
+            # operands 4 and 5 (after the three prefetched and q): the pools
+            input_output_aliases={4: 1, 5: 2},
+            interpret=interpret, name=name,
+        )(tables, lengths, layer, qw, *pools,
+          *(r.astype(jnp.float32) for r in rows))
+        return out.transpose(0, 2, 1, 3), k_pool, v_pool
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
-        in_specs=[pl.BlockSpec((None, 1, H * tq, C * D),
-                               lambda s, i, *_: (s, i, 0, 0))] + kv_specs,
-        out_specs=pl.BlockSpec((1, H, tq, D), lambda s, i, *_: (s, 0, i, 0)),
+        in_specs=[q_spec] + kv_specs,
+        out_specs=out_spec,
         scratch_shapes=scratch,
     )
     out = pl.pallas_call(
         functools.partial(kernel, scale=scale, kv_heads=KV, **walk),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
-        # The name a profiler prints for the kernel, whatever calls it: a
-        # windowed call has names of its own, so that a trace tells a stack's
-        # window layers from its full ones.
-        name=("paged" if window is None else "window")
-        + ("_decode_attn" if T == 1 else "_prefill_attn"),
+        name=name,
     )(tables, lengths, layer, qw, *pools)
     return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
 

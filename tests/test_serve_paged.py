@@ -464,6 +464,29 @@ def test_engine_contract(tiny_model, oracle, check):
         assert toks == oracle(prompt, len(toks))
 
 
+# The same seven over a pool row of whole 128-lane tiles and the interpreted
+# Pallas kernel: there the decode program hands the kernel each step's new K
+# and V row (``paged_attention_append``) where the engines above scatter it
+# (64 lanes, the gather path). Held to the same single-sequence oracle: the
+# length cap (a slot at table capacity writes nothing), a cancelled slot
+# parked beside a live one, a requeue on an exhausted pool.
+@pytest.fixture(scope="module")
+def lane_model():
+    cfg = transformer.tiny(d_model=128, max_seq_len=64)
+    params = transformer.init_params(cfg, jax.random.key(0))
+    gen = generate.Generator(params, cfg)
+    return cfg, params, lambda prompt, n: gen.generate(
+        list(prompt), max_new_tokens=n)
+
+
+@engine_contract.each_check
+def test_engine_contract_with_the_appending_kernel(lane_model, check):
+    cfg, params, lane_oracle = lane_model
+    kw = dict(engine_contract.ENGINE_KW, attention_kernel="interpret")
+    for prompt, toks in check(params, cfg, kw):
+        assert toks == lane_oracle(prompt, len(toks))
+
+
 # -- the look-ahead: one decode chunk queued behind the one that runs ---------
 
 def _ahead_engine(tiny_model, pool_blocks, name):

@@ -28,9 +28,11 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 # The serve programs compiled whole: gpt2-medium's width (16 heads of 64 fold
 # to 1024 lanes) and gpt2-xl's (25 x 64 = 1600, not a multiple of 128), depth
-# cut; the benchmark's pool and the larger one PR 23 found a cliff at.
+# cut; the benchmark's pool, the larger one PR 23 found a cliff at (an operand
+# XLA would not update in place became a copy of the pool a call) and one
+# well past it.
 SERVE_WIDTHS = {"medium": "gpt2_medium", "xl": "gpt2_xl"}
-SERVE_LAYERS, SERVE_SLOTS, SERVE_POOLS = 2, 36, (1281, 1537)
+SERVE_LAYERS, SERVE_SLOTS, SERVE_POOLS = 2, 36, (1281, 1537, 2049)
 
 # LongCat-Flash at the sizes of the cell longcat-flash-omni.moe-decode:
 # published widths, 4 layers, 16 experts held, 128 slots, pool 7297 x 16.
@@ -67,6 +69,15 @@ TRINITY_SLOTS, TRINITY_POOL, TRINITY_BUCKET = 64, 30785, 8192
 # 128 slots, pool 16513 x 16 for the TWO attention layers, the 2176 bucket
 # (max_seq_len: the largest program the warm-up compiles).
 NEMOTRON_SLOTS, NEMOTRON_POOL, NEMOTRON_BUCKET = 128, 16513, 2176
+
+
+# instruction_multiset() of three families' compiled decode programs on PR
+# 47's tree (Nemotron-H's is in ``test_nemotron_decode_is_the_program_it_was``):
+# they call the paged kernel without a row, and PR 48, which gave the GPT-2
+# family's decode a kernel that takes one, left them what they were.
+OLMO_DECODE = [8752, "4aca802a7068f431"]
+FALCON_DECODE = [5598, "18bfcfbe696d2b2e"]
+TRINITY_DECODE = [5994, "232c74127b5f0121"]
 
 
 # An optimized module's Pallas calls: ``%<name>.N = <first output shape>...
@@ -126,6 +137,35 @@ def pool_shaped_data_movers(hlo: str, n_layers: int, num_blocks: int,
                     for inner in bodies.get(called.group(1), [])):
                 continue
             found.append([op, name, shape[:80]])
+    return found
+
+
+def pool_writes(hlo: str, n_layers: int, num_blocks: int,
+                block_tokens: int) -> list:
+    """[[op kind, instruction], ...]: every ``scatter`` or
+    ``dynamic-update-slice`` of the optimized module, inside a fusion or
+    not, whose output is the KV pool: the program's own writes of K and V
+    rows, each a pass of its own over HBM before the kernel that reads the
+    row back."""
+    shape = f"[{n_layers},{num_blocks},{block_tokens},"
+    found = []
+    for line in hlo.splitlines():
+        m = _INSTR.match(line)
+        if m and m.group(3) in _IN_PLACE and shape in m.group(2):
+            found.append([m.group(3), m.group(1)])
+    return found
+
+
+def kernel_aliases(hlo: str, kernel: str) -> list:
+    """[[[output, operand], ...], ...]: for each call of the Pallas kernel
+    ``kernel`` in the optimized module, the outputs that ARE one of its
+    operands' buffers (``output_to_operand_aliasing``)."""
+    found = []
+    for line in hlo.splitlines():
+        if (re.match(rf"\s*(?:ROOT )?%{kernel}[.\d]* = ", line)
+                and "tpu_custom_call" in line):
+            found.append([[int(o), int(i)] for o, i in re.findall(
+                r"\{(\d+)\}: \((\d+), \{\}\)", line)])
     return found
 
 
@@ -419,7 +459,9 @@ def compile_all() -> dict:
     traced bodies among them]},
     "flash_products": {name: pallas_products() of the flash kernels},
     "flash_movers": {name: flash_layout_movers() of a program that runs them},
-    "latent_vmem": {name: scoped VMEM of each latent kernel call}}."""
+    "latent_vmem": {name: scoped VMEM of each latent kernel call},
+    "pool_writes": {serve program: pool_writes() of it},
+    "pool_aliases": {serve program: kernel_aliases() of its decode kernel}}."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -452,7 +494,7 @@ def compile_all() -> dict:
     need_bytes, grids, scoped_vmem, state_movers = {}, {}, {}, {}
     state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
     latent_calls, latent_vmem, flash_products, flash_movers = {}, {}, {}, {}
-    pair_rows = {}
+    pair_rows, pool_writers, pool_aliases = {}, {}, {}
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
@@ -486,6 +528,8 @@ def compile_all() -> dict:
                 for n in _SCOPED.findall(line)]
             if pool is not None:
                 pool_movers[name] = pool_shaped_data_movers(text, *pool)
+                pool_writers[name] = pool_writes(text, *pool)
+                pool_aliases[name] = kernel_aliases(text, "paged_decode_attn")
                 mem = compiled.memory_analysis()
                 temp_bytes[name] = mem.temp_size_in_bytes
                 need_bytes[name] = (mem.argument_size_in_bytes
@@ -849,7 +893,8 @@ def compile_all() -> dict:
             "capacity_ops": capacity_ops, "multisets": multisets,
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
             "flash_products": flash_products, "flash_movers": flash_movers,
-            "pair_rows": pair_rows}
+            "pair_rows": pair_rows, "pool_writes": pool_writers,
+            "pool_aliases": pool_aliases}
 
 
 @pytest.fixture(scope="module")
@@ -1012,6 +1057,79 @@ def test_serve_programs_move_no_pool_sized_data(verdict, program, width,
     assert kernel == ("paged_decode_attn" if program == "decode"
                       else "paged_prefill_attn")
     assert re.fullmatch(r"bf16\[\d+,\d+,\d+,64\]", shape), shape
+
+
+@pytest.mark.parametrize("num_blocks", SERVE_POOLS)
+def test_gpt2_decode_writes_its_rows_inside_the_kernel(verdict, num_blocks):
+    """``paged_decode`` over a pool row of whole lanes hands each step's new
+    K and V row to the kernel (``paged_attention_append``): the compiled
+    program holds no ``scatter`` and no ``dynamic-update-slice`` into the
+    pool (they were 48 fusions a token step, the first operation of a chat
+    token: PERF.md, PR 48), no ``copy`` of the pool's shape in their place
+    at any of the three pool sizes, and every kernel call gives back both
+    pools in the buffers they came in. gpt2-xl's 1,600 lanes take the form
+    with the groups on the grid, which writes nothing: its two scatters a
+    layer stay. A prefill writes a bucket of rows and scatters them."""
+    name = f"serve_decode_medium_{num_blocks}"
+    assert verdict["programs"][name] == "ok", verdict["programs"][name]
+    assert verdict["pool_writes"][name] == []
+    assert verdict["pool_movers"][name] == []
+    assert verdict["pool_aliases"][name] == [[[1, 4], [2, 5]]] * SERVE_LAYERS
+    xl = verdict["pool_writes"][f"serve_decode_xl_{num_blocks}"]
+    assert [op for op, _name in xl] == ["scatter"] * 2 * SERVE_LAYERS, xl
+    assert verdict["pool_aliases"][f"serve_decode_xl_{num_blocks}"] == [
+        []] * SERVE_LAYERS
+    prefill = verdict["pool_writes"][f"serve_prefill_medium_{num_blocks}"]
+    assert [op for op, _name in prefill] == ["scatter"] * 2 * SERVE_LAYERS
+
+
+@pytest.mark.parametrize("program,layers,multiset", [
+    ("olmo_decode", 4, OLMO_DECODE), ("falcon_decode", 6, FALCON_DECODE),
+    ("nemotron_decode", 2, None), ("trinity_decode", None, TRINITY_DECODE)])
+def test_the_other_families_decode_programs_are_what_they_were(
+        verdict, program, layers, multiset):
+    """They call the kernel WITHOUT a row, each after the write of its own
+    forward: two scatters an attention layer (Trinity's one full layer's
+    write is fused into another shape and not counted), no aliased kernel
+    output, and the instructions the compiled program had on PR 47's tree
+    (Nemotron-H's pair is held by
+    ``test_nemotron_decode_is_the_program_it_was``). A PR that changes one
+    of these programs on purpose takes a new pair."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    writes = verdict["pool_writes"][program]
+    if layers is not None:
+        assert [op for op, _name in writes] == ["scatter"] * 2 * layers, writes
+    calls = verdict["pool_aliases"][program]
+    assert calls and not any(calls), calls
+    if multiset is not None:
+        assert verdict["multisets"][program] == multiset
+
+
+def test_the_pool_write_scan_sees_a_scatter_and_an_aliased_kernel():
+    """The two scans themselves, on the parent's decode step and on this
+    tree's."""
+    kernel = 'custom_call_target="tpu_custom_call"'
+    parent = f"""
+%fused_computation.3 (p0: bf16[2,1281,16,1024]) -> bf16[2,1281,16,1024] {{
+  %p0 = bf16[2,1281,16,1024]{{3,2,1,0}} parameter(0)
+  ROOT %scatter.57 = bf16[2,1281,16,1024]{{3,2,1,0:T(8,128)(2,1)}} scatter(%p0, %i, %u)
+}}
+ENTRY %main (k: bf16[2,1281,16,1024]) -> bf16[36,16,1,64] {{
+  %k = bf16[2,1281,16,1024]{{3,2,1,0}} parameter(0)
+  %steps = s32[8,36]{{1,0}} dynamic-update-slice(%t, %u, %i)
+  %fusion.301 = bf16[2,1281,16,1024]{{3,2,1,0}} fusion(%k), kind=kCustom, calls=%fused_computation.3
+  ROOT %paged_decode_attn.10 = bf16[36,16,1,64]{{3,2,1,0}} custom-call(%t, %fusion.301), {kernel}
+}}
+"""
+    assert pool_writes(parent, 2, 1281, 16) == [["scatter", "scatter.57"]]
+    assert kernel_aliases(parent, "paged_decode_attn") == [[]]
+    change = f"""
+ENTRY %main (k: bf16[2,1281,16,1024]) -> bf16[36,16,1,64] {{
+  %paged_decode_attn.10 = (bf16[36,16,1,64]{{3,2,1,0}}, bf16[2,1281,16,1024]{{3,2,1,0}}, bf16[2,1281,16,1024]{{3,2,1,0}}) custom-call(%t, %k, %v), {kernel}, output_to_operand_aliasing={{{{1}}: (4, {{}}), {{2}}: (5, {{}})}}, metadata={{}}
+}}
+"""
+    assert pool_writes(change, 2, 1281, 16) == []
+    assert kernel_aliases(change, "paged_decode_attn") == [[[1, 4], [2, 5]]]
 
 
 @pytest.mark.parametrize("num_blocks", SERVE_POOLS)
