@@ -90,6 +90,19 @@ window no slot is taken for parked and every step is walked. With no window
 the traced body is held by digest (``tests/test_paged_attention_kernel.py``;
 a PR that changes the walk on purpose takes new ones).
 
+Two things a stack may ask of the walk besides (``models/mimo_v2.py``; a
+caller that asks for neither traces the body the digests hold). A head of V
+NARROWER than a head of K (192 / 128): the V pool's row is ``KV * Dv`` lanes
+beside the K pool's ``KV * D``, each with a buffer of its own width, the
+accumulators and the output ``Dv`` wide; a lane chunk is then as many KV
+heads as make whole 128-lane tiles on BOTH sides (two heads of 192: three
+tiles), and a query tile is halved again while its q block, that much
+longer a row, would pass ``_Q_BLOCK_VMEM_BYTES``. And a SINK (``sinks`` [H]):
+a learned score a query head that joins the softmax's denominator and
+carries no value, which in the online form is only where the accumulators
+START (``_init_accumulators``: ``m = b_h``, ``l = 1``, ``acc = 0``), in a
+decode step and a prefill tile alike.
+
 The pool is the WHOLE model's, ``[L, num_blocks, bt, KV*D]``: heads folded
 into the lane dimension, so a block is one dense ``[bt, KV*D]`` tile in the
 layout the array already has in HBM, read where it lies: a Mosaic call
@@ -124,6 +137,7 @@ validated against.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -194,7 +208,8 @@ def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int,
     return kv_index
 
 
-def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
+def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int,
+                     v_dim: Optional[int] = None) -> int:
     """How many KV heads the kernel takes in one dot; ``q_tile`` is the rows
     a KV head brings (its query heads x the tile's queries). The block's
     lanes are cut into chunks of C heads; a chunk's C*T query rows, each zero
@@ -202,10 +217,13 @@ def _heads_per_chunk(num_heads: int, q_tile: int, head_dim: int) -> int:
     dot, so no head is sliced out of a 128-lane register on every block. The
     dot computes C times the products it needs: free while the rows fit one
     MXU pass (decode, verify: all heads at once), so beyond that C is only
-    what fills 128 lanes (prefill: two heads of 64)."""
+    what makes whole 128-lane tiles of a chunk on BOTH sides, K's ``head_dim``
+    and V's ``v_dim`` (prefill: two heads of 64, one of 128, two of 192: three
+    tiles)."""
     if num_heads * q_tile <= 128:
         return num_heads
-    return min(num_heads, max(1, 128 // head_dim))
+    per = math.lcm(*(math.lcm(d, 128) // d for d in (head_dim, v_dim or head_dim)))
+    return min(num_heads, per)
 
 
 def _blocks_per_group(block_tokens: int, width: int, itemsize: int,
@@ -224,7 +242,7 @@ def _blocks_per_group(block_tokens: int, width: int, itemsize: int,
 def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
                   scale: float, num_heads: int, kv_heads: int, q_tile: int,
                   head_dim: int, group_tokens: int,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, v_dim: Optional[int] = None):
     """One online-softmax update over group ``g`` of a slot's kv positions,
     ``[g * group_tokens, (g+1) * group_tokens)``. ``k_rows(d0, d1)`` and
     ``v_rows(d0, d1)`` give the group's ``[group_tokens, d1 - d0]`` lanes.
@@ -232,8 +250,11 @@ def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
     consecutive runs (query head ``h`` reads KV head ``h // R``): a KV
     head's ``R`` query heads are ``R * q_tile`` rows of the same dot, so a
     fetched block is read once for all of them. ``window``: a key more than
-    ``window - 1`` positions behind its query is masked too."""
+    ``window - 1`` positions behind its query is masked too. ``v_dim``: the
+    width of a head of V (and of the accumulators and the output) where it
+    is not K's ``head_dim``."""
     KV, T, D = kv_heads, q_tile, head_dim
+    Dv = D if v_dim is None else v_dim
     C = q_ref.shape[-1] // D                   # KV heads per lane chunk
     RT = num_heads // KV * T                   # rows a KV head brings
     # Causal + validity in one mask: kv position vs absolute q position.
@@ -248,7 +269,8 @@ def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
         mask_all = jnp.logical_and(mask_all, kv_pos > q_pos - window)
     for c0 in range(0, KV, C):                   # static unroll
         c = min(C, KV - c0)                      # KV heads of this chunk
-        d0, d1 = c0 * D, (c0 + c) * D            # their lanes
+        d0, d1 = c0 * D, (c0 + c) * D            # their lanes of K
+        e0, e1 = c0 * Dv, (c0 + c) * Dv          # and of V
         r0, r1 = c0 * RT, (c0 + c) * RT          # their query heads' rows
         mask = mask_all[: c * RT]
         # q and K meet in the dtype they share (bfloat16 on the chip: the
@@ -270,16 +292,32 @@ def _attend_group(q_ref, k_rows, v_rows, g, ctx, m_scr, l_scr, acc_scr, *,
         l_scr[r0:r1] = alpha * l_scr[r0:r1] + jnp.sum(
             p, axis=-1, keepdims=True)
         pv = jax.lax.dot_general(
-            p, v_rows(d0, d1).astype(jnp.float32), (((1,), (0,)), ((), ())),
+            p, v_rows(e0, e1).astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                         # [c*T, c*D]
-        acc_scr[r0:r1, : c * D] = acc_scr[r0:r1, : c * D] * alpha + pv
+        )                                         # [c*T, c*Dv]
+        acc_scr[r0:r1, : c * Dv] = acc_scr[r0:r1, : c * Dv] * alpha + pv
         m_scr[r0:r1] = m_new
 
 
-def _init_accumulators(m_scr, l_scr, acc_scr):
-    m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
-    l_scr[:] = jnp.zeros_like(l_scr)
+def _init_accumulators(m_scr, l_scr, acc_scr, sink_ref=None):
+    """The online softmax's start. ``sink_ref`` [H, 1] float32: a SINK a
+    query head, a learned score that enters the softmax's denominator and
+    carries no value: ``p_ij = exp(s_ij) / (exp(b_h) + sum_j' exp(s_ij'))``.
+    In the ``(m, l, acc)`` form it is where the accumulators start: ``m =
+    b_h``, ``l = 1`` (``exp(b_h - m)``), ``acc = 0``; rows are (head, query)."""
+    if sink_ref is None:
+        m_scr[:] = jnp.full_like(m_scr, _NEG_INF)
+        l_scr[:] = jnp.zeros_like(l_scr)
+    else:
+        H = sink_ref.shape[0]
+        T = m_scr.shape[0] // H
+        if T == 1:                                    # a decode step: one store
+            m_scr[:] = sink_ref[:]
+        else:
+            for h in range(H):                        # static unroll
+                m_scr[h * T:(h + 1) * T] = jnp.broadcast_to(
+                    sink_ref[h:h + 1], (T, 1))
+        l_scr[:] = jnp.ones_like(l_scr)
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
@@ -311,6 +349,7 @@ def _walk_live_groups(
     group_blocks: int,
     unroll_full: bool = False,
     window: Optional[int] = None,
+    sink_ref=None,             # VMEM [H, 1]: where the softmax starts, or None
 ):
     """The walk both kernels share: grid step ``(s, i)`` of a LIVE slot
     resets its accumulators, loops over the groups of ``G`` table entries its
@@ -461,7 +500,7 @@ def _walk_live_groups(
         attend(g, lengths_ref[s] + i * T, half, fetched)
 
     def live_step():
-        _init_accumulators(*accumulators)
+        _init_accumulators(*accumulators, sink_ref)
         jax.lax.fori_loop(g0 if ring else 0, n_groups, group, None)
         # The half the prefetched first group of the next step went into.
         half_ref[0] = jax.lax.rem(
@@ -497,9 +536,9 @@ def _attend_buffers(q_ref, o_ref, k_buf, v_buf, accumulators, g, ctx, half,
         lambda d0, d1: jnp.where(fetched, v_buf[half, :, d0:d1], 0),
         g, ctx, *accumulators, scale=scale,
         num_heads=o_ref.shape[1], kv_heads=kv_heads,
-        q_tile=walk["q_tile"], head_dim=o_ref.shape[-1],
+        q_tile=walk["q_tile"], head_dim=k_buf.shape[-1] // kv_heads,
         group_tokens=walk["group_blocks"] * walk["block_tokens"],
-        window=walk.get("window"))
+        window=walk.get("window"), v_dim=o_ref.shape[-1])
 
 
 def _paged_kernel(
@@ -515,10 +554,13 @@ def _paged_kernel(
     *,
     scale: float,
     kv_heads: int,
+    sink_ref=None,             # [H, 1] float32 (``_paged_sink_kernel``)
     **walk,                    # _walk_live_groups' static arguments
 ):
     """Two pools, K and V, KV heads folded into the lanes: the walk
-    (``_walk_live_groups``) with ``_attend_group`` on every group."""
+    (``_walk_live_groups``) with ``_attend_group`` on every group. A head of
+    V may be narrower than a head of K: the pools' rows are ``KV * D`` and
+    ``KV * Dv`` lanes, the accumulators and the output ``Dv`` wide."""
     def attend(g, ctx, half, fetched):
         _attend_buffers(q_ref, o_ref, k_buf, v_buf, (m_scr, l_scr, acc_scr),
                         g, ctx, half, fetched, scale=scale, kv_heads=kv_heads,
@@ -527,7 +569,15 @@ def _paged_kernel(
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
         sems, half_ref, (m_scr, l_scr, acc_scr), attend,
-        lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref, **walk)
+        lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref,
+        sink_ref=sink_ref, **walk)
+
+
+def _paged_sink_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                       sink_ref, *rest, **kw):
+    """``_paged_kernel`` with one operand more, the sinks ``[H, 1]``."""
+    _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                  *rest, sink_ref=sink_ref, **kw)
 
 
 def _paged_append_kernel(
@@ -661,8 +711,9 @@ def paged_attention(
     scale: Optional[float] = None,
     interpret: bool = False,
     window: Optional[int] = None,
+    sinks: Optional[jax.Array] = None,
 ) -> jax.Array:
-    """Fused paged-attention over the block pool; returns [S, T, H, D].
+    """Fused paged-attention over the block pool; returns [S, T, H, Dv].
 
     Query t of slot s is at absolute position ``lengths[s] + t`` and attends
     positions ``<= lengths[s] + t`` of layer ``layer`` gathered through
@@ -685,7 +736,15 @@ def paged_attention(
     and ``tables[s]`` is read modulo its width: a RING of ``NB`` blocks in
     which position ``p`` lies in entry ``(p // bt) mod NB`` (the caller's to
     keep at ``NB * bt >= window + T - 1 + bt`` rows, or to cover the whole
-    context with, as a prefill over its fresh rows does)."""
+    context with, as a prefill over its fresh rows does).
+
+    A head of V may be NARROWER (or wider) than a head of K: ``v_pool``
+    ``[L, num_blocks, bt, KV*Dv]`` beside ``k_pool`` ``[.., KV*D]``; the
+    result is ``Dv`` wide. ``sinks`` [H] float32: a learned score a query
+    head that enters the softmax's denominator and carries no value, ``p_ij
+    = exp(s_ij) / (exp(sinks[h]) + sum_j' exp(s_ij'))`` (not scaled by
+    ``scale``). Both take the walk over whole 128-lane rows, of both pools;
+    a call that passes neither traces the body it always did."""
     S, T, H, D = q.shape
     if window is not None and (window < 1 or k_pool.shape[3] % 128):
         raise ValueError(
@@ -697,9 +756,23 @@ def paged_attention(
             f"pool {k_pool.shape} is not [L, num_blocks, bt, KV*{D}] with KV "
             f"a divisor of {H} heads: the kernel reads the whole folded pool "
             f"(one layer's: pool[None])")
+    W, Wv = k_pool.shape[3], v_pool.shape[3]
+    if v_pool.shape[:3] != k_pool.shape[:3] or Wv % (W // D):
+        raise ValueError(
+            f"V pool {v_pool.shape} beside K pool {k_pool.shape}: want the "
+            f"same blocks and a row of the same {W // D} KV heads")
+    if (Wv != W or sinks is not None) and (W % 128 or Wv % 128):
+        raise ValueError(
+            f"pool rows of {W} and {Wv} lanes: a V head of another width and "
+            f"a sink take the loop over whole 128-lane rows")
+    if sinks is not None:
+        if sinks.shape != (H,):
+            raise ValueError(f"sinks {sinks.shape}: want one a query head, [{H}]")
+        sinks = sinks.astype(jnp.float32).reshape(H, 1)
     return _paged_attention(
         q, k_pool, v_pool, tables.astype(jnp.int32),
         lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
+        sinks=sinks,
         scale=float(scale) if scale is not None else 1.0 / D**0.5,
         interpret=interpret, window=None if window is None else int(window))
 
@@ -771,26 +844,37 @@ def paged_attention_append(
 # padded to 128 lanes, so three buffers of rows x 512 bytes. 30 heads of 128
 # at the full tile fit the 16 MB of scoped VMEM; 48 heads take half a tile.
 _Q_TILE_ROWS = 4096
+# What a tile's q block (double-buffered) may take of VMEM: 64 heads of 192
+# in chunks of two take a tile of 32 queries (compile-only for a v5e).
+_Q_BLOCK_VMEM_BYTES = 4 << 20
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
-def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
-                     scale, interpret, window=None):
+def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None,
+                     sinks=None, *, scale, interpret, window=None):
     """:func:`paged_attention` on checked operands, ``layer`` an int32[1]
     VALUE: a jit of its own, so that a program that calls it once a layer
     (24 unrolled layers, eight serve programs) traces and lowers the kernel
     once and calls it 24 times; XLA inlines the calls. ``rows``: the new
     ``(k_row, v_row)`` of :func:`paged_attention_append`, whose kernel and
-    results (the pools beside the output) these are then."""
+    results (the pools beside the output) these are then. ``sinks``
+    [H, 1] float32 or None."""
     S, T, H, D = q.shape
     bt = k_pool.shape[2]
     W = k_pool.shape[3]                               # a row: KV heads x D
     KV = W // D
     R = H // KV                                       # query heads a KV head
+    Wv = v_pool.shape[3]                              # V's row: KV heads x Dv
+    Dv = Wv // KV
     nb_seq = tables.shape[1]
     qt = q.transpose(0, 2, 1, 3)                      # [S, H, T, D]
     tq = min(T, _Q_TILE)
     while H * tq > _Q_TILE_ROWS and tq > 8:
+        tq //= 2
+    # ... and a tile's q block (two buffers of it): a chunk of heads wider
+    # than 128 lanes (two heads of 192) makes its rows that much longer.
+    while (H * tq > 128 and 2 * H * tq * _heads_per_chunk(KV, R * tq, D, Dv)
+           * D * q.dtype.itemsize > _Q_BLOCK_VMEM_BYTES and tq > 8):
         tq //= 2
     q_tiles = pl.cdiv(T, tq)
     if T % tq:
@@ -798,7 +882,7 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
         # and their rows are sliced off below.
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, q_tiles * tq - T), (0, 0)))
 
-    C = _heads_per_chunk(KV, R * tq, D)
+    C = _heads_per_chunk(KV, R * tq, D, Dv)
     # q of head h sits in the lanes of its KV head, (h // R % C) * D of a
     # C*D-wide row, zeros beside it: one dot of a chunk's rows against the
     # chunk's lanes then gives every head its own scores. Rows of a tile are
@@ -808,11 +892,12 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
     qw = qw.reshape(S, H, q_tiles, tq, C * D).transpose(0, 2, 1, 3, 4)
     qw = qw.reshape(S, q_tiles, H * tq, C * D)
 
-    G = _blocks_per_group(bt, W, k_pool.dtype.itemsize)
+    # (the mean of the two rows: the K and V buffers' four halves together)
+    G = _blocks_per_group(bt, (W + Wv) // 2, k_pool.dtype.itemsize)
     accumulators = [
         pltpu.VMEM((H * tq, 1), jnp.float32),
         pltpu.VMEM((H * tq, 1), jnp.float32),
-        pltpu.VMEM((H * tq, C * D), jnp.float32),
+        pltpu.VMEM((H * tq, C * Dv), jnp.float32),
     ]
     walk = dict(block_tokens=bt, q_tile=tq, total=T, nb_seq=nb_seq,
                 group_blocks=G)
@@ -823,7 +908,7 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
         kv_specs = [pl.BlockSpec(memory_space=pl.ANY)] * 2
         scratch = accumulators + [
             pltpu.VMEM((2, G * bt, W), k_pool.dtype),
-            pltpu.VMEM((2, G * bt, W), v_pool.dtype),
+            pltpu.VMEM((2, G * bt, Wv), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),
             pltpu.SMEM((1,), jnp.int32),
         ]
@@ -839,8 +924,8 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
 
     q_spec = pl.BlockSpec((None, 1, H * tq, C * D),
                           lambda s, i, *_: (s, i, 0, 0))
-    out_spec = pl.BlockSpec((1, H, tq, D), lambda s, i, *_: (s, 0, i, 0))
-    out_shape = jax.ShapeDtypeStruct((S, H, q_tiles * tq, D), q.dtype)
+    out_spec = pl.BlockSpec((1, H, tq, Dv), lambda s, i, *_: (s, 0, i, 0))
+    out_shape = jax.ShapeDtypeStruct((S, H, q_tiles * tq, Dv), q.dtype)
     # The name a profiler prints for the kernel, whatever calls it: a
     # windowed call has names of its own, so that a trace tells a stack's
     # window layers from its full ones.
@@ -867,10 +952,15 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
         )(tables, lengths, layer, qw, *pools,
           *(r.astype(jnp.float32) for r in rows))
         return out.transpose(0, 2, 1, 3), k_pool, v_pool
+    extra = []
+    if sinks is not None:
+        # one block, the same at every grid step: fetched once
+        kernel = _paged_sink_kernel
+        extra = [pl.BlockSpec((H, 1), lambda s, i, *_: (0, 0))]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=grid,
-        in_specs=[q_spec] + kv_specs,
+        in_specs=[q_spec] + kv_specs + extra,
         out_specs=out_spec,
         scratch_shapes=scratch,
     )
@@ -880,50 +970,64 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None, *,
         out_shape=out_shape,
         interpret=interpret,
         name=name,
-    )(tables, lengths, layer, qw, *pools)
-    return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, D]
+    )(tables, lengths, layer, qw, *pools, *([] if sinks is None else [sinks]))
+    return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, Dv]
+
+
+def _softmax_with_sinks(scores, sinks):
+    """Softmax over the last axis of ``scores`` [S, H, T, n]; ``sinks`` [H]
+    adds one column a head that takes probability and is then dropped."""
+    if sinks is None:
+        return jax.nn.softmax(scores, axis=-1)
+    col = jnp.broadcast_to(sinks.astype(jnp.float32)[None, :, None, None],
+                           scores.shape[:3] + (1,))
+    return jax.nn.softmax(jnp.concatenate([scores, col], axis=-1),
+                          axis=-1)[..., :-1]
 
 
 def paged_attention_reference(q, k_pool, v_pool, tables, lengths, layer, *,
                               scale: Optional[float] = None,
-                              window: Optional[int] = None) -> jax.Array:
+                              window: Optional[int] = None,
+                              sinks: Optional[jax.Array] = None) -> jax.Array:
     """Gather-path oracle over the same operands: materializes
     [S, NB*bt, H, D] of ``layer`` through the table and runs masked dense
     attention — numerically what the pre-kernel decode did, kept as the
     equivalence target and the CPU fallback reference. ``window``: every
     query gathers the ``window`` positions at and before its own through the
     table read modulo its width (the ring of :func:`paged_attention`), so
-    [S, T, window, H, D]: an oracle and a CPU path, for no chip's sizes."""
+    [S, T, window, H, D]: an oracle and a CPU path, for no chip's sizes.
+    ``sinks`` and a V row of another width as :func:`paged_attention`."""
     S, T, H, D = q.shape
     bt = k_pool.shape[2]
     nb_seq = tables.shape[1]
     s_val = scale if scale is not None else 1.0 / D**0.5
     KV = k_pool.shape[3] // D
+    Dv = v_pool.shape[3] // KV
     q_pos = lengths.reshape(-1, 1) + jnp.arange(T)[None, :]         # [S, T]
     if window is not None:
         pos = q_pos[:, :, None] - jnp.arange(window)[None, None, :]  # [S, T, W]
         seen = pos >= 0
         pos = jnp.maximum(pos, 0)
         blk = tables[jnp.arange(S)[:, None, None], (pos // bt) % nb_seq]
-        kc, vc = (p[layer, blk, pos % bt].reshape(S, T, window, KV, D)
-                  for p in (k_pool, v_pool))
+        kc = k_pool[layer, blk, pos % bt].reshape(S, T, window, KV, D)
+        vc = v_pool[layer, blk, pos % bt].reshape(S, T, window, KV, Dv)
         if KV != H:
             kc, vc = (jnp.repeat(a, H // KV, axis=3) for a in (kc, vc))
         scores = jnp.einsum("bthd,btshd->bhts", q, kc,
                             preferred_element_type=jnp.float32) * s_val
         scores = jnp.where(seen[:, None], scores, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
+        probs = _softmax_with_sinks(scores, sinks)
         out = jnp.einsum("bhts,btshd->bthd", probs, vc.astype(jnp.float32))
         return out.astype(q.dtype)
     kc = k_pool[layer, tables].reshape(S, nb_seq * bt, KV, D)
-    vc = v_pool[layer, tables].reshape(S, nb_seq * bt, KV, D)
+    vc = v_pool[layer, tables].reshape(S, nb_seq * bt, KV, Dv)
     if KV != H:             # grouped: query head h reads KV head h // (H // KV)
         kc, vc = (jnp.repeat(a, H // KV, axis=2) for a in (kc, vc))
     scores = jnp.einsum("bthd,bshd->bhts", q, kc,
                         preferred_element_type=jnp.float32) * s_val
     kv_pos = jnp.arange(nb_seq * bt)[None, None, None, :]
     scores = jnp.where(kv_pos <= q_pos[:, None, :, None], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
+    probs = _softmax_with_sinks(scores, sinks)
     out = jnp.einsum("bhts,bshd->bthd", probs, vc.astype(jnp.float32))
     return out.astype(q.dtype)
 

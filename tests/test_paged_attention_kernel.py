@@ -858,6 +858,147 @@ class TestWindowedWalk:
         assert hashlib.sha256(text.encode()).hexdigest() == self.PARENT_LATENT[case]
 
 
+def _dense_sink(q, k, v, length, window, sinks):
+    """The oracle with a sink and a V head of its own width, written out: q
+    [T, H, D] at positions ``length + t``, k [L, KV, D], v [L, KV, Dv]; key j
+    is seen iff j <= i (and i - j < window where there is one); ``sinks[h]``
+    (or nothing) joins the denominator and carries no value."""
+    T, heads, _ = q.shape
+    ratio = heads // k.shape[1]
+    out = np.zeros((T, heads, v.shape[2]), q.dtype)
+    for t in range(T):
+        i = length + t
+        lo = 0 if window is None else max(0, i - window + 1)
+        for h in range(heads):
+            s = k[lo:i + 1, h // ratio] @ q[t, h] / np.sqrt(q.shape[2])
+            top = s.max() if sinks is None else max(s.max(), sinks[h])
+            p = np.exp(s - top)
+            rest = 0.0 if sinks is None else np.exp(sinks[h] - top)
+            out[t, h] = (p / (p.sum() + rest)) @ v[lo:i + 1, h // ratio]
+    return out
+
+
+def _sink_setup(lengths, t_tokens, window, bt, nb, *, heads, kv_heads, dim,
+                v_dim, sink, seed=0):
+    """Every slot's rows in layer 1 of two-layer K and V pools whose rows are
+    ``kv_heads * dim`` and ``kv_heads * v_dim`` lanes, through a shuffled
+    table of ``nb`` entries read modulo its width under a window (a ring)
+    and covering the context without one (block 0 the trash block). Returns
+    (operands, sinks or None, the dense oracle's output)."""
+    rng = np.random.default_rng(seed)
+    S = len(lengths)
+    q = rng.standard_normal((S, t_tokens, heads, dim)).astype(np.float32)
+    k_pool = rng.standard_normal((2, S * nb + 1, bt, kv_heads * dim)).astype(np.float32)
+    v_pool = rng.standard_normal((2, S * nb + 1, bt, kv_heads * v_dim)).astype(np.float32)
+    sinks = (rng.standard_normal(heads) * 2 + 1).astype(np.float32) if sink else None
+    tables = np.zeros((S, nb), np.int32)
+    want = []
+    for s, ln in enumerate(lengths):
+        tables[s] = 1 + s * nb + rng.permutation(nb)
+        total = ln + t_tokens
+        assert window is not None or total <= nb * bt
+        k = rng.standard_normal((total, kv_heads, dim)).astype(np.float32)
+        v = rng.standard_normal((total, kv_heads, v_dim)).astype(np.float32)
+        for p in range(total):
+            entry = tables[s, (p // bt) % nb]
+            k_pool[1, entry, p % bt] = k[p].reshape(-1)
+            v_pool[1, entry, p % bt] = v[p].reshape(-1)
+        want.append(_dense_sink(q[s], k, v, ln, window, sinks))
+    ops = (jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
+           jnp.asarray(tables), jnp.asarray(np.asarray(lengths, np.int32)), 1)
+    return ops, None if sinks is None else jnp.asarray(sinks), np.stack(want)
+
+
+class TestSinkAndValueWidth:
+    """``paged_attention(sinks=)`` and a V pool whose heads are narrower
+    than K's (192 / 128): each alone and both, windowed and not, a decode
+    step and prefill tiles, against the dense oracle written out and the
+    gather path."""
+
+    @pytest.mark.parametrize("sink,dims", [
+        (True, (64, 64)), (False, (192, 128)), (True, (192, 128))])
+    @pytest.mark.parametrize("lengths,t_tokens,window,bt,nb", [
+        ([0, 3, 15, 16, 17, 40, 100], 1, 16, 8, 3),     # decode over rings
+        ([0, 9, 300], 1, 16, 128, 2),                   # one block a group
+        ([0, 5, 17, 40], 1, None, 8, 6),                # decode over a pool
+        ([0], 300, 64, 16, 19),                         # windowed prefill tiles
+        ([0], 200, None, 16, 13),                       # full prefill tiles
+        ([7], 20, 16, 8, 5),                            # T > 1 from a start
+    ])
+    def test_against_a_dense_oracle(self, lengths, t_tokens, window, bt, nb,
+                                    sink, dims):
+        dim, v_dim = dims
+        ops, sinks, want = _sink_setup(
+            lengths, t_tokens, window, bt, nb, heads=4, kv_heads=2, dim=dim,
+            v_dim=v_dim, sink=sink)
+        out = paged_attention(*ops, window=window, sinks=sinks, interpret=True)
+        assert out.shape == want.shape == ops[0].shape[:3] + (v_dim,)
+        _assert_close(out, want)
+        _assert_close(paged_attention_reference(
+            *ops, window=window, sinks=sinks), want)
+
+    def test_a_sink_left_out_is_seen(self):
+        ops, sinks, want = _sink_setup([5, 40], 1, 16, 8, 3, heads=4,
+                                       kv_heads=2, dim=64, v_dim=64, sink=True)
+        out = paged_attention(*ops, window=16, interpret=True)
+        assert np.abs(np.asarray(out) - want).max() > 0.05
+
+    def test_eight_and_four_kv_heads_of_192_and_128(self):
+        """The two kinds of layer of the configuration that brought these:
+        64 query heads over 8 KV heads (a ring) and over 4 (the pool), a
+        decode step, bfloat16 pools."""
+        for kv_heads, window, nb in ((8, 128, 3), (4, None, 12)):
+            ops, sinks, want = _sink_setup(
+                [130, 17], 1, window, 64 if window else 16, nb, heads=64,
+                kv_heads=kv_heads, dim=192, v_dim=128, sink=window is not None)
+            ops = tuple(a.astype(jnp.bfloat16) if a.dtype == jnp.float32 else a
+                        for a in ops[:3]) + ops[3:]
+            out = paged_attention(*ops, window=window, sinks=sinks,
+                                  interpret=True)
+            ref = paged_attention_reference(*ops, window=window, sinks=sinks)
+            assert out.shape == (2, 1, 64, 128)
+            _assert_close(out.astype(jnp.float32), ref.astype(jnp.float32),
+                          tol=2e-2)
+
+    @pytest.mark.parametrize("kw,match", [
+        (dict(sinks=jnp.zeros(5)), "128-lane"),          # 5 x 16 lanes
+        (dict(v_width=5 * 32), "128-lane"),
+        (dict(v_width=7 * 16), "KV heads"),
+    ])
+    def test_what_the_two_refuse(self, kw, match):
+        q, k_pool, v_pool, *rest = _setup([5], 1, heads=5, dim=16)
+        if "v_width" in kw:
+            v_pool = jnp.zeros(v_pool.shape[:3] + (kw.pop("v_width"),))
+        with pytest.raises(ValueError, match=match):
+            paged_attention(q, k_pool, v_pool, *rest, interpret=True, **kw)
+        with pytest.raises(ValueError, match="one a query head"):
+            paged_attention(*_setup([5], 1), sinks=jnp.zeros(3), interpret=True)
+
+    def test_a_tile_of_q_is_held_to_its_share_of_vmem(self):
+        """64 heads of 192 in chunks of two: a q block of 4,096 rows x 384
+        lanes would be 3 MB a buffer; the tile is halved to 32 queries."""
+        def grid(heads, kv_heads, dim, v_dim, t_tokens):
+            q = jax.ShapeDtypeStruct((1, t_tokens, heads, dim), jnp.bfloat16)
+            pools = [jax.ShapeDtypeStruct((1, 9, 16, kv_heads * d), jnp.bfloat16)
+                     for d in (dim, v_dim)]
+            jaxpr = jax.make_jaxpr(lambda q, k, v: paged_attention(
+                q, k, v, jnp.zeros((1, 8), jnp.int32),
+                jnp.zeros((1,), jnp.int32), 0, interpret=True))(q, *pools)
+            found = []
+
+            def walk(jp):
+                for e in jp.eqns:
+                    if e.primitive.name == "pallas_call":
+                        found.append(tuple(e.params["grid_mapping"].grid))
+                    for v in e.params.values():
+                        if hasattr(v, "jaxpr"):
+                            walk(v.jaxpr)
+            walk(jaxpr.jaxpr)
+            return found
+        assert grid(64, 4, 192, 128, 256) == [(1, 8)]      # tiles of 32
+        assert grid(64, 8, 128, 128, 256) == [(1, 4)]      # tiles of 64, as ever
+
+
 class TestEngineKernelModes:
     def test_resolve_modes(self):
         assert generate.resolve_attention_kernel("gather") == "gather"

@@ -63,6 +63,13 @@ KIMI_SLOTS, KIMI_POOL, KIMI_BUCKETS = 96, 16993, (2048, 3072)
 TRINITY_SLOTS, TRINITY_POOL, TRINITY_BUCKET = 64, 30785, 8192
 
 
+# MiMo-V2-Flash at the sizes of the cell mimo-v2-flash.swa-decode: published
+# widths, the first seven layers (two full, five window), 8 experts held, 128
+# slots, pool 32769 x 16 for the TWO full layers, the 4096 bucket (the
+# largest).
+MIMO_SLOTS, MIMO_POOL, MIMO_BUCKET = 128, 32769, 4096
+
+
 # Nemotron-3-Nano-30B-A3B at the sizes of the cell
 # nemotron-3-nano-30b-a3b.reason-decode: published widths, 13 layers
 # MEMEM*EMEMEM* (6 mixers, 5 expert layers of 64 held, 2 attention layers),
@@ -153,6 +160,21 @@ def pool_writes(hlo: str, n_layers: int, num_blocks: int,
         m = _INSTR.match(line)
         if m and m.group(3) in _IN_PLACE and shape in m.group(2):
             found.append([m.group(3), m.group(1)])
+    return found
+
+
+def kernel_operand_shapes(hlo: str, kernel: str) -> list:
+    """The operand shapes (``operand_layout_constraints``) of the calls of
+    the Pallas kernel ``kernel`` in the optimized module, each distinct list
+    once."""
+    found = []
+    for line in hlo.splitlines():
+        if (re.match(rf"\s*(?:ROOT )?%{kernel}[.\d]* = ", line)
+                and "tpu_custom_call" in line):
+            shapes = re.findall(r"(\w+\[[\d,]*\])\{", re.search(
+                r"operand_layout_constraints=\{(.*?)\}, \w+=", line).group(1))
+            if shapes not in found:
+                found.append(shapes)
     return found
 
 
@@ -461,6 +483,8 @@ def compile_all() -> dict:
     "flash_movers": {name: flash_layout_movers() of a program that runs them},
     "latent_vmem": {name: scoped VMEM of each latent kernel call},
     "pool_writes": {serve program: pool_writes() of it},
+    "window_operands": {serve program: kernel_operand_shapes() of its window
+    layers' decode kernel},
     "pool_aliases": {serve program: kernel_aliases() of its decode kernel}}."""
     import jax
     import jax.numpy as jnp
@@ -495,6 +519,7 @@ def compile_all() -> dict:
     state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
     latent_calls, latent_vmem, flash_products, flash_movers = {}, {}, {}, {}
     pair_rows, pool_writers, pool_aliases = {}, {}, {}
+    window_operands = {}
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
@@ -526,6 +551,8 @@ def compile_all() -> dict:
                 int(n) for line in text.splitlines()
                 if "tpu_custom_call" in line and re.match(r"\s*%mla_", line)
                 for n in _SCOPED.findall(line)]
+            window_operands[name] = kernel_operand_shapes(
+                text, "window_decode_attn")
             if pool is not None:
                 pool_movers[name] = pool_shaped_data_movers(text, *pool)
                 pool_writers[name] = pool_writes(text, *pool)
@@ -823,6 +850,44 @@ def compile_all() -> dict:
             pairs=(TRINITY_BUCKET, tcfg.num_experts_per_tok, expert_widths(
                 tcfg.hidden_size, tcfg.moe_intermediate_size)))
 
+    # MiMo-V2-Flash's serve programs whole, at the cell's own sizes: K rows
+    # of 192 a head and V rows of 128 in a pool of 4 KV heads and rings of
+    # 8, a sink a query head in the window layers' kernel, and the bytes the
+    # chip must hold (2.22B bf16 parameters, a 2.68 GB pool, 0.63 GB of
+    # rings).
+    from ray_tpu.models import mimo_v2
+
+    mcfg = mimo_v2.flash_share()
+    mparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: mimo_v2.init_params(mcfg, key),
+                       jax.random.key(0)))
+    mgen = PagedGenerator(mparams, mcfg, slots=MIMO_SLOTS,
+                          num_blocks=MIMO_POOL, block_tokens=bt,
+                          attention_kernel="pallas")
+    mpool, mslot = (tuple(arr(x.shape, x.dtype) for x in jax.eval_shape(f))
+                    for f in (lambda: mimo_v2.init_pool(mcfg, MIMO_POOL, bt),
+                              lambda: mimo_v2.init_slot_state(mcfg, MIMO_SLOTS)))
+    mstate = (mparams, mpool, mslot,
+              arr((MIMO_SLOTS, mgen.logits_dim), jnp.float32),
+              arr((MIMO_SLOTS, 2), jnp.uint32))
+    m_slot = lambda dtype: arr((MIMO_SLOTS,), dtype)  # noqa: E731
+    m_geometry = (mcfg.n_layers, MIMO_POOL, bt)
+    m_rings = (mcfg.window_layers, MIMO_SLOTS, mcfg.ring_blocks)
+    attempt("mimo_decode",
+            lambda: mgen.decode_fn(8).trace(
+                *mstate, arr((MIMO_SLOTS, mgen.blocks_per_seq), jnp.int32),
+                m_slot(jnp.int32), m_slot(jnp.bool_), m_slot(jnp.bool_),
+                m_slot(jnp.float32)), pool=m_geometry, state=m_rings,
+            weights=shapes_of(mparams))
+    attempt(f"mimo_prefill_{MIMO_BUCKET}",
+            lambda: mgen.prefill_fn(MIMO_BUCKET).trace(
+                *mstate, arr((mgen.blocks_per_seq,), jnp.int32),
+                arr((1, MIMO_BUCKET), jnp.int32), i32, i32, i32, i32),
+            pool=m_geometry, state=m_rings,
+            pairs=(MIMO_BUCKET, mcfg.num_experts_per_tok, expert_widths(
+                mcfg.hidden_size, mcfg.moe_intermediate_size)))
+
     # Nemotron-H's serve programs whole, at the cell's own sizes: a layer is
     # ONE thing, so the state kernel is called by the six mixer layers alone
     # on a float32 state of its own depth, the attention kernel by the two
@@ -894,7 +959,7 @@ def compile_all() -> dict:
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
             "flash_products": flash_products, "flash_movers": flash_movers,
             "pair_rows": pair_rows, "pool_writes": pool_writers,
-            "pool_aliases": pool_aliases}
+            "pool_aliases": pool_aliases, "window_operands": window_operands}
 
 
 @pytest.fixture(scope="module")
@@ -1351,6 +1416,57 @@ def test_trinity_serve_programs_fit_the_chip(verdict, program, kernels, need):
 
 
 @pytest.mark.parametrize("program,kernels,need", [
+    ("mimo_decode", {"window_decode_attn": "bf16[128,64,1,128]",
+                     "paged_decode_attn": "bf16[128,64,1,128]"},
+     (7.7e9, 8.1e9)),
+    ("mimo_prefill_4096", {"window_prefill_attn": "bf16[1,64,4096,128]",
+                           "paged_prefill_attn": "bf16[1,64,4096,128]"},
+     (8.6e9, 9.1e9))])
+def test_mimo_serve_programs_fit_the_chip(verdict, program, kernels, need):
+    """MiMo-V2-Flash's ``paged_decode`` and its largest ``paged_prefill`` at
+    the sizes of ``mimo-v2-flash.swa-decode`` (128 slots, a pool of 32,769
+    blocks; at ISSUE 49's first sizes, 256 slots and 65,537 blocks, the two
+    need 11.26 and 12.14 GB): they compile for a v5e (64 query heads of 192 in chunks of two:
+    a prefill tile of 32 queries keeps the q block inside the scoped VMEM)
+    and arguments plus temporaries stay under ISSUE 49's 14.5 GB. Both
+    kernels' outputs are the V side's 128 wide for all 64 query heads; the
+    window layers' decode kernel takes q rows of 8 x 192 lanes, rings of 8 x
+    192 and 8 x 128 lanes viewed as blocks, and the sinks, one a query head,
+    float32. No instruction copies or slices data the size of the pool or of
+    the rings. Of the weights the decode program moves two, both layer 0's
+    and both the COMPILER'S: it parks the first layer's ``w_q`` and ``w_k``
+    (the first products' operands) in its alternate memory before the loop,
+    turned to the layout it wants for a product whose output is cut into
+    heads of 192 (1.5 lane tiles), and brings them back a step: 106 MB a
+    step beside the ~8 GB a step reads (PERF.md 7, item 31). No other layer's
+    matrix, no expert's, nor the head is copied."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    # weights 4.44 GB + pool 2.68 GB + rings 0.63 GB
+    assert need[0] < verdict["need_bytes"][program] < need[1], verdict["need_bytes"]
+    assert verdict["need_bytes"][program] < 14.5e9
+    found = {n: s for n, s in verdict["kernels"][program]
+             if not n.startswith("ragged-dot")}
+    assert found == kernels, verdict["kernels"][program]
+    assert verdict["pool_movers"][program] == []
+    assert verdict["state_movers"][program] == []
+    vmem = verdict["scoped_vmem"][program]
+    assert max(vmem) > 1 << 20 and all(0 <= v < V5E_SCOPED_VMEM for v in vmem)
+    if program == "mimo_decode":
+        moved = sorted((shape.split("{")[0], where == "ENTRY") for _op, _name,
+                       shape, where in verdict["weight_movers"][program])
+        assert moved == [("bf16[4096,12288]", False), ("bf16[4096,12288]", True),
+                         ("bf16[4096,768]", False), ("bf16[4096,768]", True)]
+        blocks = MIMO_SLOTS * 3                  # a ring: 128 + 64 rows
+        assert verdict["window_operands"][program] == [[
+            f"s32[{MIMO_SLOTS},3]", f"s32[{MIMO_SLOTS}]", "s32[1]",
+            f"bf16[{MIMO_SLOTS},1,64,1536]", f"bf16[5,{blocks},64,1536]",
+            f"bf16[5,{blocks},64,1024]", "f32[64,1]"]]
+    # one window call a window layer, one paged call a full layer
+    assert sorted(verdict["grids"][program]) == sorted(
+        [[MIMO_SLOTS, 1]] * 7 if program == "mimo_decode" else [[1, 128]] * 7)
+
+
+@pytest.mark.parametrize("program,kernels,need", [
     ("nemotron_decode", {"ssd_decode": "f32[6,128,128,4096]",
                          "paged_decode_attn": "bf16[128,32,1,128]"},
      (10.2e9, 10.5e9)),
@@ -1449,7 +1565,7 @@ def test_kimi_serve_programs_fit_the_chip(verdict, program, kernel, shape,
 
 @pytest.mark.parametrize("program", [
     "kimi_prefill_2048", "kimi_prefill_3072", "longcat_prefill_1024",
-    "trinity_prefill_8192"])
+    "trinity_prefill_8192", "mimo_prefill_4096"])
 def test_a_prefills_expert_layer_holds_no_array_of_all_the_pairs(verdict,
                                                                  program):
     """``held_experts_ffn`` under its row bound: the compiled bucket writes
