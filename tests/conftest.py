@@ -19,11 +19,36 @@ if "--xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", "
     os.environ["XLA_FLAGS"] = (
         os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
     ).strip()
+# The suite compiles thousands of CPU programs and runs each for milliseconds:
+# LLVM's optimisation passes were a third of a family file's time (PR 50) and
+# buy nothing a case reads. What a case compares is XLA's program against a
+# reference, at the same tolerances; the HLO passes run as ever.
+if "--xla_backend_optimization_level" not in os.environ["XLA_FLAGS"]:
+    os.environ["XLA_FLAGS"] += " --xla_backend_optimization_level=0"
 os.environ["JAX_PLATFORMS"] = "cpu"
-# The persistent compile cache stays OFF for the whole test tree (drivers
-# and the cluster processes they spawn): tests/test_devtools_jax.py counts
-# backend compiles, and a warm cache changes those counts.
-os.environ["JAX_ENABLE_COMPILATION_CACHE"] = "0"
+
+# One compile cache a RUN: a program is compiled once, not once a case. Most
+# cases build a fresh ``PagedGenerator`` or a fresh ``jax.jit(lambda ...)``,
+# which misses JAX's in-memory cache however equal its program is; JAX's own
+# persistent cache (it holds CPU executables too) finds it again. The process
+# that starts the run makes an EMPTY directory and exports it before xdist
+# starts its workers, which inherit it, as do the cluster processes a test
+# forks; ``pytest_sessionfinish`` removes it. Nothing outlives a run and
+# nothing is read from an earlier one, so a run is as cold and as repeatable
+# as without it. A module that measures compiling turns the cache off for
+# itself (the ``no_compile_cache`` fixture below); the benchmark's rehearsal
+# children set JAX_ENABLE_COMPILATION_CACHE=0 in their own environment and
+# stay the cold program a chip would see.
+import shutil  # noqa: E402
+import tempfile  # noqa: E402
+
+_RUN_CACHE = None
+if "PYTEST_XDIST_WORKER" not in os.environ:
+    _RUN_CACHE = tempfile.mkdtemp(prefix="ray_tpu_tests_jax_cache_")
+    os.environ["JAX_COMPILATION_CACHE_DIR"] = _RUN_CACHE
+os.environ.pop("JAX_ENABLE_COMPILATION_CACHE", None)
+os.environ["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+os.environ["JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES"] = "-1"
 
 import pytest  # noqa: E402
 
@@ -39,6 +64,7 @@ jax.config.update("jax_platforms", "cpu")
 jax.config.update("jax_default_device", jax.devices("cpu")[0])
 jax.config.update("jax_default_matmul_precision", "highest")
 
+import faulthandler  # noqa: E402
 import signal  # noqa: E402
 import threading  # noqa: E402
 
@@ -73,6 +99,21 @@ from ray_tpu.devtools import jitcheck as _jitcheck  # noqa: E402
 _JITCHECK_ON = _jitcheck.maybe_install()
 
 TEST_TIMEOUT_S = 180  # matches the reference's pytest.ini per-test timeout
+WATCHDOG_S = TEST_TIMEOUT_S + 30  # then the watchdog that needs no interpreter
+
+
+_REAL_STDERR = pytest.StashKey[int]()
+
+
+def pytest_configure(config):
+    """While pytest configures, its output capture is suspended and fd 2 is
+    the run's real stderr: a copy is kept for the watchdog below, whose dump
+    would else land in the capture's file and go with the process."""
+    config.stash[_REAL_STDERR] = os.dup(2)
+
+
+def pytest_unconfigure(config):
+    os.close(config.stash[_REAL_STDERR])
 
 
 def pytest_sessionstart(session):
@@ -89,6 +130,42 @@ def pytest_sessionstart(session):
             "raylint found NEW findings (RAY_TPU_LINT_IN_CI=1) — fix them "
             "or accept deliberately with "
             "`python -m ray_tpu.devtools.lint --update-baseline`")
+
+
+# xdist hands out FILES (``--dist loadfile``) in collection order, so a long
+# file late in the alphabet is the run's tail, with five workers idle. These
+# go first: the compile-only children hold their worker for minutes, and the
+# kernel's main file is the longest of all. A file joins the list when the
+# per-file junit table (``.claude/skills/verify/SKILL.md``) shows it ending
+# the run.
+_LONG_FILES_FIRST = ("test_v5e_compile.py", "test_paged_attention_kernel.py")
+
+
+def pytest_collection_modifyitems(items):
+    items.sort(key=lambda item: item.path.name not in _LONG_FILES_FIRST)
+
+
+def pytest_sessionfinish(session):
+    """The run's compile cache goes with the run (the process that made it
+    removes it; an xdist worker made none)."""
+    if _RUN_CACHE is not None:
+        shutil.rmtree(_RUN_CACHE, ignore_errors=True)
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """For a module that MEASURES compiling (counts backend compiles, times
+    a warm-up against a second call): the run's compile cache is off while
+    its cases run, so a program another file compiled first is compiled
+    here again. JAX decides once whether the cache is in use; the reset
+    makes it decide again."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
 
 
 @pytest.fixture(autouse=True)
@@ -142,10 +219,15 @@ def _steady_state_guard(request):
 
 
 @pytest.fixture(autouse=True)
-def _per_test_timeout():
+def _per_test_timeout(pytestconfig):
     """Hang protection for a condition-variable-heavy runtime: SIGALRM raises
     in the main thread if a test exceeds the budget (pytest-timeout is not in
-    the image)."""
+    the image). A Python handler runs only when the main thread is back in
+    the interpreter, so a wait in native code (PR 45: every worker in a
+    futex wait until the run's own clock) outlasts it: at ``WATCHDOG_S``
+    faulthandler's C thread prints every thread's stack and ends the
+    process. Under xdist that costs the one running case: the worker is
+    reported down, its case failed, another worker started."""
     if threading.current_thread() is not threading.main_thread():
         yield
         return
@@ -155,9 +237,12 @@ def _per_test_timeout():
 
     old = signal.signal(signal.SIGALRM, _on_alarm)
     signal.alarm(TEST_TIMEOUT_S)
+    faulthandler.dump_traceback_later(WATCHDOG_S, exit=True,
+                                      file=pytestconfig.stash[_REAL_STDERR])
     try:
         yield
     finally:
+        faulthandler.cancel_dump_traceback_later()
         signal.alarm(0)
         signal.signal(signal.SIGALRM, old)
 

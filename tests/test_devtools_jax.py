@@ -16,6 +16,10 @@ import pytest
 
 from ray_tpu.devtools import jaxlint, jitcheck, lint
 
+# jitcheck counts backend compiles and their seconds: here a compile is a
+# compile, not a program another file left in the run's compile cache.
+pytestmark = pytest.mark.usefixtures("no_compile_cache")
+
 
 def _write(tmp_path, rel, src):
     p = tmp_path / rel

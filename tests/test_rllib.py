@@ -211,6 +211,7 @@ class TestLearner:
 
 
 class TestPPOE2E:
+    @pytest.mark.slow  # a learning curve (PR 50): `-m slow` runs it
     def test_cartpole_learns(self):
         """The learning-regression gate (reference:
         ``rllib/tuned_examples/ppo/cartpole-ppo.yaml`` — reach return R)."""
@@ -685,6 +686,7 @@ class TestSAC:
         q = m.q_value(params["q1"], obs, act)
         assert q.shape == (32,)
 
+    @pytest.mark.slow  # a learning curve (PR 50): `-m slow` runs it
     def test_sac_learns_pendulum(self, ray_start_regular):
         """Continuous-control learning gate (reference:
         rllib/tuned_examples/sac/pendulum-sac.yaml — improve return)."""
@@ -782,6 +784,7 @@ class TestOffline:
         env.close()
         return episodes
 
+    @pytest.mark.slow  # a learning curve (PR 50): `-m slow` runs it
     def test_bc_clones_expert(self, ray_start_regular):
         import gymnasium as gym
 
@@ -799,6 +802,7 @@ class TestOffline:
         ev = algo.evaluate(lambda: gym.make("CartPole-v1"), num_episodes=5)
         assert ev["episode_return_mean"] >= 100.0, ev
 
+    @pytest.mark.slow  # a learning curve (PR 50): `-m slow` runs it
     def test_marwil_beats_bc_on_mixed_data(self, ray_start_regular):
         """Mixed-quality corpus: MARWIL's advantage weighting should favor
         the good trajectories; with beta=0 (BC) the clone averages the
@@ -1092,6 +1096,7 @@ class TestCQL:
         env.close()
         return {k: np.stack(v) for k, v in cols.items()}
 
+    @pytest.mark.slow  # a learning curve (PR 50): `-m slow` runs it
     def test_cql_penalty_pushes_down_ood_q(self, ray_start_regular):
         """The conservative term must leave Q(s, a_random) BELOW
         Q(s, a_data) after training — the defining CQL property."""

@@ -8,13 +8,19 @@ happily and which has no VMEM, so a kernel that overflows the chip's 16 MB of
 scoped VMEM, or a Mosaic call left for GSPMD to partition, passes every
 interpret-mode test and fails on the first chip.
 
-The compiles run in ONE child process (``python tests/test_v5e_compile.py``
-prints a verdict per program — a builder can run it by hand): the test
-process has JAX pinned to its CPU configuration, and two processes loading
-libtpu at once collide on its lock file.
+The compiles run in child processes (``python tests/test_v5e_compile.py``
+prints a verdict per program — a builder can run it by hand, all programs in
+one process): the test process has JAX pinned to its CPU configuration. The
+``verdict`` fixture starts ``COMPILE_CHILDREN`` of them side by side, each
+compiling every n-th program (``python tests/test_v5e_compile.py I N``), with
+``ALLOW_MULTIPLE_LIBTPU_LOAD=1``: without it two processes loading libtpu at
+once collide on its lock file. (Threads of one process were tried first, PR
+50: one run in five died of a stack overflow inside the compiler.)
 """
 
+import concurrent.futures
 import hashlib
+import itertools
 import json
 import math
 import os
@@ -76,6 +82,10 @@ MIMO_SLOTS, MIMO_POOL, MIMO_BUCKET = 128, 32769, 4096
 # 128 slots, pool 16513 x 16 for the TWO attention layers, the 2176 bucket
 # (max_seq_len: the largest program the warm-up compiles).
 NEMOTRON_SLOTS, NEMOTRON_POOL, NEMOTRON_BUCKET = 128, 16513, 2176
+
+# The fixture's children: one compiled the 52 programs in 371 s alone and 538
+# beside five busy workers, of a limit of 700.
+COMPILE_CHILDREN = 4
 
 
 # instruction_multiset() of three families' compiled decode programs on PR
@@ -459,8 +469,10 @@ def jit_calls(jaxpr, name: str) -> list:
     return found
 
 
-def compile_all() -> dict:
-    """Child side: {"skip": reason} or {"programs": {name: "ok" | error},
+def compile_all(share: int = 0, of: int = 1) -> dict:
+    """Child side, for the programs whose place in the list below is
+    ``share`` modulo ``of``: {"skip": reason} or
+    {"programs": {name: "ok" | error},
     "kernels": {name: [[instruction name, first output shape], ...]},
     "grids": {name: pallas_grids() of the traced program},
     "scoped_vmem": {name: bytes of scoped VMEM each Pallas call takes},
@@ -528,10 +540,13 @@ def compile_all() -> dict:
                            "expert_capacity_ffn_roofline.json")) as f:
         capacity_pattern = json.load(f)["pattern"]
     capacity_ops, multisets = {}, {}
+    place = itertools.count()
 
     def attempt(name, trace, pool=None, state=None, weights=None,
                 shared=False, state_kernel="gdn_decode", pairs=None,
                 capacity=False):
+        if next(place) % of != share:
+            return
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
@@ -964,15 +979,32 @@ def compile_all() -> dict:
 
 @pytest.fixture(scope="module")
 def verdict():
-    """One child process compiles everything; both tests read its verdict."""
-    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu")
-    child = subprocess.run([sys.executable, os.path.abspath(__file__)],
-                           env=env, capture_output=True, text=True,
-                           timeout=700)
-    assert child.returncode == 0, child.stderr[-3000:]
-    out = json.loads(child.stdout.strip().splitlines()[-1])
-    if "skip" in out:
-        pytest.skip(out["skip"])
+    """The children compile everything between them; every test reads the
+    one verdict their shares add up to."""
+    # Each program is compiled once: the run's compile cache would only be
+    # written, never read. And the compiler's flags are its defaults, not
+    # the test tree's (conftest's are for CPU programs).
+    env = dict(os.environ, PYTHONPATH=REPO, JAX_PLATFORMS="cpu",
+               JAX_ENABLE_COMPILATION_CACHE="0",
+               ALLOW_MULTIPLE_LIBTPU_LOAD="1")
+    env.pop("XLA_FLAGS", None)
+
+    def compile_share(share):
+        return subprocess.run(
+            [sys.executable, os.path.abspath(__file__), str(share),
+             str(COMPILE_CHILDREN)], env=env, capture_output=True, text=True,
+            timeout=700)
+
+    with concurrent.futures.ThreadPoolExecutor(COMPILE_CHILDREN) as waiters:
+        children = list(waiters.map(compile_share, range(COMPILE_CHILDREN)))
+    out = {}
+    for child in children:
+        assert child.returncode == 0, child.stderr[-3000:]
+        part = json.loads(child.stdout.strip().splitlines()[-1])
+        if "skip" in part:
+            pytest.skip(part["skip"])
+        for key, by_program in part.items():
+            out.setdefault(key, {}).update(by_program)
     return out
 
 
@@ -1775,4 +1807,4 @@ ENTRY %main (w: f32[2,1024,4096], e: f32[50304,1024], h: bf16[4096,1024]) -> bf1
 
 
 if __name__ == "__main__":
-    print(json.dumps(compile_all()))
+    print(json.dumps(compile_all(*(int(arg) for arg in sys.argv[1:3]))))
