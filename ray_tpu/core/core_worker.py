@@ -42,6 +42,7 @@ from ray_tpu.core.rpc import (RpcClient, RpcClientPool, RpcConnectionError,
                               RpcRemoteError)
 from ray_tpu.core.task_spec import (SpecCacheMiss, SpecEncoder, TaskSpec,
                                     TaskType, spec_var_fields)
+from ray_tpu.util import tracing
 from ray_tpu.utils.logging import get_logger, log_swallowed
 
 logger = get_logger("core_worker")
@@ -644,11 +645,15 @@ class _GenState:
     reported (notes may arrive out of order across pool threads), a done
     flag + total, and the consumer's progress for producer backpressure."""
 
-    __slots__ = ("items", "total", "cv", "consumed", "lock", "error_at",
-                 "released", "released_at")
+    __slots__ = ("items", "published_ns", "total", "cv", "consumed", "lock",
+                 "error_at", "released", "released_at")
 
     def __init__(self):
         self.items: Dict[int, ObjectID] = {}
+        # Beside each reported item, ``tracing.now_ns()`` of the instant its
+        # report made it visible here, on THIS process's clock (no stamp
+        # crosses a process); cleared with ``items``.
+        self.published_ns: Dict[int, int] = {}
         self.total: Optional[int] = None  # set when the task completes
         self.lock = threading.Lock()
         self.cv = threading.Condition(self.lock)
@@ -740,6 +745,7 @@ class _OwnerService:
                 # are collected by release_generator).
                 core.reference_counter.set_owned(oid)
             state.items.setdefault(index, oid)
+            state.published_ns.setdefault(index, tracing.now_ns())
             state.cv.notify_all()
 
     def generator_progress(self, task_id_bytes: bytes) -> int:
@@ -3246,6 +3252,15 @@ class CoreWorker:
                 return got
             await asyncio.sleep(0.005)
 
+    def generator_item_published_ns(self, task_id: TaskID,
+                                    index: int) -> Optional[int]:
+        """``tracing.now_ns()`` of the instant the producer's report made
+        item ``index`` visible to ``next_generator_item``; None for an item
+        that came with the task's completion record alone."""
+        with self._cache_lock:
+            state = self._generators.get(task_id)
+        return None if state is None else state.published_ns.get(index)
+
     def release_generator(self, task_id: TaskID) -> None:
         """Consumer dropped its ObjectRefGenerator: reclaim the stream
         state and free owned items the consumer never took a ref to
@@ -3267,6 +3282,7 @@ class CoreWorker:
             orphans = [oid for idx, oid in state.items.items()
                        if idx >= state.consumed]
             state.items.clear()
+            state.published_ns.clear()
         for oid in orphans:
             self.reference_counter.drop_owned_if_unreferenced(oid)
         with self._cache_lock:

@@ -113,6 +113,10 @@ class ObjectRefGenerator:
         self._task_id = task_id
         self._runtime = runtime
         self._next_index = 0
+        # ``tracing.now_ns()`` of the instant the ref ``__next__`` returned
+        # last became visible to this process's runtime, on this process's
+        # clock; None where the runtime kept none for it.
+        self.last_published_ns: int | None = None
 
     def __iter__(self):
         return self
@@ -121,6 +125,8 @@ class ObjectRefGenerator:
         ref = self._runtime.next_generator_item(self._task_id, self._next_index)
         if ref is None:
             raise StopIteration
+        self.last_published_ns = self._runtime.generator_item_published_ns(
+            self._task_id, self._next_index)
         self._next_index += 1
         return ref
 
@@ -133,6 +139,8 @@ class ObjectRefGenerator:
         )
         if ref is None:
             raise StopAsyncIteration
+        self.last_published_ns = self._runtime.generator_item_published_ns(
+            self._task_id, self._next_index)
         self._next_index += 1
         return ref
 

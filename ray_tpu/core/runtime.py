@@ -61,7 +61,7 @@ from ray_tpu.core.task_spec import (
     TaskSpec,
     TaskType,
 )
-from ray_tpu.util import flightrec
+from ray_tpu.util import flightrec, tracing
 from ray_tpu.utils.logging import get_logger, log_swallowed
 
 logger = get_logger("runtime")
@@ -98,6 +98,7 @@ class TaskState:
         "resources",
         "bundle_held",
         "generator_items",
+        "generator_published_ns",
         "generator_done",
         "generator_cv",
     )
@@ -115,8 +116,21 @@ class TaskState:
         self.resources: Optional[ResourceSet] = None
         self.bundle_held = None  # (strategy, ResourceSet) while running in a PG bundle
         self.generator_items: List[ObjectID] = []
+        # Beside each item's id, ``tracing.now_ns()`` of the instant it became
+        # visible to the consumer (``serve.request``'s ``take_lag_ns`` and
+        # ``client_hold_ns`` count from it); lives and goes with the ids.
+        self.generator_published_ns: List[int] = []
         self.generator_done = False
         self.generator_cv = threading.Condition(self.lock)
+
+    def publish_generator_item(self, oid: ObjectID, done: bool = False) -> None:
+        """One more item of the stream is the consumer's to take."""
+        with self.generator_cv:
+            self.generator_items.append(oid)
+            self.generator_published_ns.append(tracing.now_ns())
+            if done:
+                self.generator_done = True
+            self.generator_cv.notify_all()
 
 
 class LocalNode:
@@ -868,9 +882,7 @@ class Runtime:
             for item in result:
                 oid = ObjectID.for_task_return(spec.task_id, index)
                 self.store.put(oid, item)
-                with state.generator_cv:
-                    state.generator_items.append(oid)
-                    state.generator_cv.notify_all()
+                state.publish_generator_item(oid)
                 index += 1
             with state.generator_cv:
                 state.generator_done = True
@@ -902,10 +914,7 @@ class Runtime:
         if num_returns in ("dynamic", "streaming"):
             oid = ObjectID.for_task_return(spec.task_id, len(state.generator_items))
             self.store.put(oid, error)
-            with state.generator_cv:
-                state.generator_items.append(oid)
-                state.generator_done = True
-                state.generator_cv.notify_all()
+            state.publish_generator_item(oid, done=True)
             return
         for oid in spec.return_object_ids(max(1, num_returns if isinstance(num_returns, int) else 1)):
             self._put_result(oid, error)
@@ -1005,6 +1014,15 @@ class Runtime:
 
         loop = asyncio.get_event_loop()
         return await loop.run_in_executor(None, self.next_generator_item, task_id, index)
+
+    def generator_item_published_ns(self, task_id: TaskID,
+                                    index: int) -> Optional[int]:
+        """``tracing.now_ns()`` of the instant item ``index`` became visible
+        to ``next_generator_item`` (an item it has already returned)."""
+        state = self.tasks.get(task_id)
+        if state is None or index >= len(state.generator_published_ns):
+            return None
+        return state.generator_published_ns[index]
 
     def release_generator(self, task_id: TaskID) -> None:
         """In-process runtime keeps generator items in the task record, which

@@ -9,8 +9,11 @@ an operator's tool for a run the profiler did not trace: ``stats()`` says how
 much of the window was host CPU, host waiting, ``device_get`` and hand-off;
 this says WHERE in the step (wall and ``cpu_ns`` of each ``llm.step.<phase>``,
 the prefill's jitted call by quantile) and WHEN in the run (by 5 s bucket
-before the last span: the hand-off's ``handoff_ns``, the rows a decode
-carried, and how late a request's last item reached its client).
+before the last span: the hand-off's ``handoff_ns`` and how often the driver
+changed, the rows a decode carried, how late a request's last item reached its
+client and, beside it, the four hand-overs of an item's way back: the pick-up
+in ``drive``, the replica's publish, the handle's take, the caller's hold,
+each as mean and max, and the CPU the streams' two sides took).
 """
 from __future__ import annotations
 
@@ -64,12 +67,17 @@ def summarise(spans: Iterable) -> Dict:
     n = max(1, len(decode))
     calls = by.get("llm.prefill.dispatch", [])
     steps_by: Dict[int, list] = {}
-    for s in steps:
-        b = steps_by.setdefault(bucket(s.start_ns), [0, 0.0, 0, 0])
+    driver = None
+    for s in sorted(steps, key=lambda s: s.start_ns):
+        b = steps_by.setdefault(bucket(s.start_ns), [0, 0.0, 0, 0, 0])
         b[0] += 1
         b[1] += _attr(s, "handoff_ns") / 1e9
         b[2] += _attr(s, "batch")
         b[3] += _attr(s, "admit_stopped") == "queue_empty"
+        # A step run by another thread than the step before: the driver was
+        # held in its own yield and another consumer elected itself.
+        b[4] += driver not in (None, _attr(s, "driver"))
+        driver = _attr(s, "driver")
 
     # A request's way around the engine, by the first span of each name in
     # its trace: client -> engine's queue, engine's finish -> client's end.
@@ -80,13 +88,18 @@ def summarise(spans: Iterable) -> Dict:
         return out
 
     outer, waits = first("serve.request"), first("llm.admission_wait")
+    streams = first("serve.replica_stream")
     requests_by: Dict[int, list] = {}
+    hops_by: Dict[int, list] = {}
     submit, tail = [], []
     for tid, inner in first("llm.request").items():
         if tid in outer and tid in waits:
             submit.append((waits[tid].start_ns - outer[tid].start_ns) / 1e6)
             tail.append((outer[tid].end_ns - inner.end_ns) / 1e6)
             requests_by.setdefault(bucket(inner.end_ns), []).append(tail[-1])
+            if tid in streams:
+                hops_by.setdefault(bucket(inner.end_ns), []).append(
+                    (inner, streams[tid], outer[tid]))
     return {
         "spans": sum(len(ss) for ss in by.values()),
         "decode_steps": len(decode),
@@ -108,12 +121,59 @@ def summarise(spans: Iterable) -> Dict:
                     "max": _quantile(tail, 1.0)},
         "steps_by_bucket_s": {
             str(b): {"steps": v[0], "handoff_s": v[1],
-                     "mean_batch": v[2] / v[0], "queue_empty": v[3]}
+                     "mean_batch": v[2] / v[0], "queue_empty": v[3],
+                     "driver_switches": v[4]}
             for b, v in sorted(steps_by.items())},
         "requests_by_bucket_s_of_engine_finish": {
             str(b): {"n": len(v), "tail_p50_ms": _quantile(v, 0.5),
-                     "tail_p90_ms": _quantile(v, 0.9)}
+                     "tail_p90_ms": _quantile(v, 0.9),
+                     **_hops(hops_by.get(b, []))}
             for b, v in sorted(requests_by.items())},
+    }
+
+
+def _hops(requests: List[tuple]) -> Dict:
+    """The four hand-overs of an item's way back over the requests of one
+    bucket, ``(llm.request, serve.replica_stream, serve.request)`` each:
+    a hop's mean is its summed attr over the takes or items it was summed
+    over, its max the largest single one; the CPU shares are the streams'
+    two sides' ``cpu_ns`` over the bucket's length (a request's CPU is
+    counted where it ENDED)."""
+    if not requests:
+        return {}
+
+    def hop(i: int, total: str, per: str, largest: str, unit: float) -> Dict:
+        n = sum(_attr(r[i], per) for r in requests)
+        return {"mean": sum(_attr(r[i], total) for r in requests) / unit / n
+                if n else None,
+                "max": max(_attr(r[i], largest) for r in requests) / unit}
+
+    def cpu_share(i: int) -> float:
+        return 100.0 * sum(_attr(r[i], "cpu_ns") for r in requests) / (
+            BUCKET_S * 1e9)
+
+    return {
+        "pickup_lag_ms": hop(0, "pickup_lag_ns", "pickups",
+                             "pickup_lag_max_ns", 1e6),
+        "publish_us_per_item": hop(1, "publish_ns", "items",
+                                   "publish_max_ns", 1e3),
+        "take_lag_ms": hop(2, "take_lag_ns", "items", "take_lag_max_ns", 1e6),
+        "client_hold_ms": hop(2, "client_hold_ns", "items",
+                              "client_hold_max_ns", 1e6),
+        # Of the take, the part inside ray_tpu.get once the ref was in
+        # hand; the rest is the iterator's wake-up.
+        "get_ms": sum(_attr(r[2], "get_ns") for r in requests) / 1e6
+        / max(1, sum(_attr(r[2], "items") for r in requests)),
+        "end_wait_ms": {
+            "mean": _mean([_attr(r[2], "end_wait_ns") / 1e6
+                           for r in requests]),
+            "max": max(_attr(r[2], "end_wait_ns") for r in requests) / 1e6},
+        # Of the stream's span, the part its thread spent driving steps for
+        # every slot: a consumer that is late because it was the driver.
+        "drove_share": 100.0 * sum(_attr(r[1], "drove_ns") for r in requests)
+        / max(1, sum(r[1].end_ns - r[1].start_ns for r in requests)),
+        "producer_cpu_share": cpu_share(1),
+        "consumer_cpu_share": cpu_share(2),
     }
 
 

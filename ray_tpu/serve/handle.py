@@ -89,7 +89,7 @@ class DeploymentResponse:
         return self._ref
 
 
-def _emit_request_span(trace, replica_key: str) -> None:
+def _emit_request_span(trace, replica_key: str, **attrs) -> None:
     """Close the serve.request root span (submission → response finished)."""
     if trace is None:
         return
@@ -104,18 +104,21 @@ def _emit_request_span(trace, replica_key: str) -> None:
         "serve.request",
         (req_ctx[0], parent_ctx[1] if parent_ctx else None, req_ctx[2]),
         span_id=req_ctx[1], start=submit_ns, end=tracing.now_ns(),
-        attrs={"replica": replica_key})
+        attrs={"replica": replica_key, **attrs})
 
 
 class DeploymentResponseGenerator:
     def __init__(self, gen, router: "Router", replica_key: str, trace=None,
-                 release=None):
+                 release=None, submit_cpu=None):
         self._gen = gen
         self._router = router
         self._replica_key = replica_key
         self._done = False
         self._trace = trace
         self._release = release
+        # (thread ident, its ``time.thread_time_ns()``) at submit, of a
+        # sampled request: the stream's end reads the thread's clock again.
+        self._submit_cpu = submit_cpu
 
     @property
     def trace_id(self) -> Optional[str]:
@@ -125,19 +128,50 @@ class DeploymentResponseGenerator:
         return None
 
     def __iter__(self):
+        from ray_tpu.util import tracing
+
+        now_ns = tracing.now_ns
         first = self.trace_id is not None
+        gen = self._gen
+        # The last hand-over of an item's way back, summed over the stream
+        # for ``serve.request``'s attrs: the item was published (the
+        # runtime's stamp), this iterator asked for it, and had its VALUE.
+        # ``take_lag`` counts from when both the item and the asker were
+        # there (the wake-up and the get: the transport's), ``client_hold``
+        # is how long a published item lay while the caller, the item before
+        # it in hand, had not asked. The two never cover an instant twice.
+        items = take_lag = take_lag_max = get_total = 0
+        client_hold = client_hold_max = 0
+        t_asked = t_in_hand = 0
+        stamped = True
         try:
-            for ref in self._gen:
+            while True:
+                t_asked = now_ns()
+                try:
+                    ref = next(gen)
+                except StopIteration:
+                    break
+                t_ref = now_ns()
                 item = ray_tpu.get(ref)
+                t_held, t_in_hand = t_in_hand, now_ns()
+                items += 1
+                get_total += t_in_hand - t_ref
+                published = gen.last_published_ns
+                if published is None:
+                    stamped = False
+                else:
+                    lag = t_in_hand - max(published, t_asked)
+                    take_lag += lag
+                    take_lag_max = max(take_lag_max, lag)
+                    hold = max(0, t_asked - max(published, t_held))
+                    client_hold += hold
+                    client_hold_max = max(client_hold_max, hold)
                 if first:
                     # An instant: the first stream item leaves the handle
                     # for its caller — where the client's TTFT clock stops.
                     first = False
-                    from ray_tpu.util import tracing
-
-                    now = tracing.now_ns()
                     tracing.emit("serve.first_item", self._trace[1],
-                                 start=now, end=now)
+                                 start=t_in_hand, end=t_in_hand)
                 yield item
         finally:
             if not self._done:
@@ -145,7 +179,21 @@ class DeploymentResponseGenerator:
                 self._router._dec(self._replica_key)
                 if self._release is not None:
                     self._release()
-                _emit_request_span(self._trace, self._replica_key)
+                # ``end_wait_ns``: the last item in hand, how long the
+                # iterator then waited to be told the stream was over (0 for
+                # a stream its caller abandoned).
+                attrs = {"items": items, "get_ns": get_total,
+                         "end_wait_ns": (now_ns() - t_asked
+                                         if t_asked > t_in_hand else 0)}
+                if stamped:     # never guessed for a runtime that kept none
+                    attrs.update(take_lag_ns=take_lag,
+                                 take_lag_max_ns=take_lag_max,
+                                 client_hold_ns=client_hold,
+                                 client_hold_max_ns=client_hold_max)
+                cpu = self._submit_cpu
+                if cpu is not None and cpu[0] == threading.get_ident():
+                    attrs["cpu_ns"] = time.thread_time_ns() - cpu[1]
+                _emit_request_span(self._trace, self._replica_key, **attrs)
 
 
 class Router:
@@ -509,6 +557,10 @@ class DeploymentHandle:
         parent_ctx, req_ctx = self._trace_root()
         sampled = req_ctx is not None and req_ctx[2]
         submit_ns = tracing.now_ns()
+        # The caller's thread's CPU clock beside it: ``serve.request``'s
+        # ``cpu_ns`` is what this thread burnt from here to the stream's end.
+        submit_cpu = ((threading.get_ident(), time.thread_time_ns())
+                      if sampled and self._stream else None)
         prefix_hash = self._affinity_hash(args)
         # Tenant quota gate sits in FRONT of the router: an over-quota
         # tenant sheds here without consuming any replica queue slot.
@@ -541,7 +593,7 @@ class DeploymentHandle:
                 return DeploymentResponseGenerator(
                     gen, self._router, key,
                     trace=(parent_ctx, req_ctx, submit_ns),
-                    release=release)
+                    release=release, submit_cpu=submit_cpu)
             ref = replica.handle_request.remote(self._method, *args, **kwargs)
 
             def resubmit(method=self._method, a=args, kw=kwargs,

@@ -58,6 +58,7 @@ from ray_tpu.devtools import jitcheck
 from ray_tpu.models.generate import (KVBlockManager, NoFreeBlocks,
                                      PagedGenerator)
 from ray_tpu.serve.errors import Saturated
+from ray_tpu.serve.replica import note_driven
 from ray_tpu.util import tracing
 from ray_tpu.utils.logging import get_logger
 
@@ -119,6 +120,8 @@ class _Request:
         "out_ids", "hit_tokens",
         "submitted_ns", "prefill_end_ns", "prefill_span",
         "scheduled", "retiring", "blocks",
+        "delivered_ns", "pickups", "pickup_lag_ns", "pickup_lag_max_ns",
+        "finished_ns",
     )
 
     def __init__(self, prompt, padded, real_len, bucket, max_new,
@@ -142,6 +145,18 @@ class _Request:
         # last tokens are delivered.
         self.retiring: Optional[str] = None
         self.blocks: List[int] = []
+        # The first hand-over of a token's way back: ``_deliver`` stamps
+        # ``delivered_ns`` when it extends an EMPTY ``tokens`` (the oldest
+        # untaken token's instant), ``drive`` counts each take of a
+        # non-empty ``tokens`` and how long after that stamp it came.
+        self.delivered_ns = 0
+        self.pickups = 0
+        self.pickup_lag_ns = 0
+        self.pickup_lag_max_ns = 0
+        # When a request finished by count: its ``llm.request`` span ends
+        # there and is recorded by the consumer's last take, which it has
+        # to count.
+        self.finished_ns: Optional[int] = None
         self.done = False
         self.cancelled = False
         self.error: Optional[BaseException] = None
@@ -169,9 +184,12 @@ class _Request:
         # Captured at submit time on the request's own thread; engine spans
         # must use THIS explicit context (the step loop runs on whichever
         # thread won the driver election — its ambient context belongs to a
-        # different request). None unless the trace sampled in.
+        # different request). None unless the trace sampled in, and with
+        # tracing off: a task that was handed no context runs under one of
+        # its own that says "sampled" (``Runtime._adopt_trace``).
         self.trace_ctx = (tracing.current_context()
-                          if tracing.is_sampled() else None)
+                          if tracing.trace_enabled() and tracing.is_sampled()
+                          else None)
 
     def decode_tps(self) -> float:
         if self.decode_seconds == 0:
@@ -321,10 +339,13 @@ class _StepTrace:
         self.prefill_ns = self.prefill_cpu_ns = 0
         self._open: List = []           # the step's annotation, the phase's
         if on:
-            self._push("llm.step")
+            # One instant on both clocks, once a step: a profiler session
+            # records when this annotation opened on ITS clock and, as the
+            # event's argument, the span clock's reading of that instant.
+            self._push("llm.step", span_ns=tracing.now_ns())
 
-    def _push(self, name: str) -> None:
-        ann = tracing.annotation(name)
+    def _push(self, name: str, **args) -> None:
+        ann = tracing.annotation(name, **args)
         ann.__enter__()
         self._open.append(ann)
 
@@ -415,8 +436,9 @@ class LLMEngine:
     **When a retired request's blocks return to the pool.** Retirement by
     count frees the SLOT at schedule time, one step before the request's
     last tokens are on the host; its BLOCKS stay pinned until those tokens
-    are delivered (the chain is registered with them), then ``finish_reason``,
-    ``done`` and the ``llm.request`` span follow. Cancellation frees slot
+    are delivered (the chain is registered with them), then ``finish_reason``
+    and ``done`` follow and the ``llm.request`` span ends (its consumer's
+    next take records it, with that take counted). Cancellation frees slot
     and blocks at once; tokens of a cancelled request still in flight are
     dropped at delivery.
     """
@@ -530,7 +552,8 @@ class LLMEngine:
             "slot_steps_total", "slot_steps_offered_total",
             "admit_stopped_budget_total", "admit_stopped_queue_empty_total",
             "admit_stopped_no_slot_total", "admit_stopped_no_blocks_total",
-            "admit_starved_total", "admit_blocked_pool_s"), 0)
+            "admit_starved_total", "admit_blocked_pool_s",
+            "stream_pickups_total", "stream_pickup_lag_s"), 0)
         # The end of the last step if the engine still held a request then
         # (_holds_request_locked), else None: the next step charges the
         # stretch up to its start to step_handoff_s. Written under
@@ -704,6 +727,10 @@ class LLMEngine:
                     out = list(req.tokens)
                     req.tokens.clear()
                     done, err = req.done, req.error
+                    if out:
+                        self._count_pickup_locked(req)
+                    if done:
+                        self._emit_finished_locked(req)
                 for tok in out:
                     yield tok
                 if err is not None:
@@ -712,9 +739,13 @@ class LLMEngine:
                     return
                 if self._step_lock.acquire(False):
                     try:
-                        self._step()
+                        st = self._step()
                     finally:
                         self._step_lock.release()
+                    # The step was every slot's, run on this request's
+                    # thread: its time is not this stream's.
+                    note_driven(st.end_ns - st.marks[0][1],
+                                st.end_cpu_ns - st.marks[0][2])
                 else:
                     with self._state_lock:
                         if not req.tokens and not req.done:
@@ -732,6 +763,18 @@ class LLMEngine:
             # instead of waiting out a poll slice.
             self._wake_inflight()
 
+    def _count_pickup_locked(self, req: _Request) -> None:
+        """The consumer took a non-empty ``tokens``: how long after
+        ``_deliver`` made the oldest of them the request's. Under
+        _state_lock (the take's own hold)."""
+        lag = tracing.now_ns() - req.delivered_ns
+        req.pickups += 1
+        req.pickup_lag_ns += lag
+        req.pickup_lag_max_ns = max(req.pickup_lag_max_ns, lag)
+        with self._agg_lock:
+            self._counts["stream_pickups_total"] += 1
+            self._counts["stream_pickup_lag_s"] += lag
+
     def _wake_inflight(self) -> None:
         with self._state_lock:
             for r in self._slot_req:
@@ -745,6 +788,9 @@ class LLMEngine:
         free its slot for the next admission."""
         with self._state_lock:
             if req.done:
+                # Finished by count, its last tokens never taken: the span
+                # still has to be recorded.
+                self._emit_finished_locked(req)
                 return
             req.cancelled = True
             try:
@@ -760,7 +806,7 @@ class LLMEngine:
             req.done = True
             if req.finish_reason is None:
                 req.finish_reason = "cancelled"
-            self._emit_request_span(req, slot)
+            self._emit_request_span(req)
             req.cond.notify_all()
             if not self._holds_request_locked():
                 # The last request held went without a step: what follows
@@ -776,17 +822,30 @@ class LLMEngine:
                 or (due is not None
                     and any(not r.done for _, r, _ in due.rows)))
 
-    def _emit_request_span(self, req: _Request, slot: Optional[int]) -> None:
-        """``llm.request``: submit to finish, whatever the finish was. A ring
-        append: safe under _state_lock."""
+    def _emit_request_span(self, req: _Request,
+                           end_ns: Optional[int] = None) -> None:
+        """``llm.request``: submit to finish, whatever the finish was, with
+        the consumer's takes so far (its prompt, hit and slot are on its
+        ``llm.prefill``). A ring append: safe under _state_lock."""
         if req.trace_ctx is None:
             return
         tracing.emit("llm.request", req.trace_ctx,
-                     start=req.submitted_ns, end=tracing.now_ns(),
-                     attrs={"prompt_len": req.real_len,
-                            "hit_tokens": req.hit_tokens,
-                            "tokens": req.emitted, "slot": slot,
-                            "finish_reason": req.finish_reason})
+                     start=req.submitted_ns,
+                     end=tracing.now_ns() if end_ns is None else end_ns,
+                     attrs={"tokens": req.emitted,
+                            "finish_reason": req.finish_reason,
+                            "pickups": req.pickups,
+                            "pickup_lag_ns": req.pickup_lag_ns,
+                            "pickup_lag_max_ns": req.pickup_lag_max_ns})
+
+    def _emit_finished_locked(self, req: _Request) -> None:
+        """Record the ``llm.request`` span of a request that finished by
+        count (``_finish_locked`` left its end on it): by the consumer's
+        take that found it done, or by ``_cancel`` where the consumer went
+        without one. Under _state_lock; idempotent."""
+        if req.finished_ns is not None:
+            end_ns, req.finished_ns = req.finished_ns, None
+            self._emit_request_span(req, end_ns)
 
     def _free_slot_locked(self, slot: int) -> None:
         """Unpin the slot's blocks and clear its bookkeeping. Under
@@ -840,16 +899,18 @@ class LLMEngine:
         req.blocks, self._slot_blocks[slot] = self._slot_blocks[slot], []
         self._free_slot_locked(slot)
         if req.emitted == req.scheduled:    # nothing of it is in flight
-            self._finish_locked(req, slot)
+            self._finish_locked(req)
 
-    def _finish_locked(self, req: _Request, slot: int) -> None:
+    def _finish_locked(self, req: _Request) -> None:
         """A retired request's last tokens are delivered: register its
         chain, return its blocks to the pool, tell its consumer."""
         self._on_retire_locked(req)
         self._release_blocks_locked(req)
         req.finish_reason = req.retiring
         req.done = True
-        self._emit_request_span(req, slot)
+        # ``llm.request`` ends HERE; the take that finds the request done
+        # records it, with the pick-up of these last tokens counted.
+        req.finished_ns = tracing.now_ns()
         req.cond.notify_all()
 
     def _fail_inflight(self, err: BaseException) -> None:
@@ -874,12 +935,12 @@ class LLMEngine:
                 r.done = True
                 if r.finish_reason is None:
                     r.finish_reason = "error"
-                self._emit_request_span(r, None)
+                self._emit_request_span(r)
                 r.cond.notify_all()
         self._reset_device_state()
 
     # -- the iteration-level scheduler ----------------------------------------
-    def _step(self) -> None:
+    def _step(self) -> _StepTrace:
         # Called holding _step_lock (the elected driver). Post-warmup the
         # step runs under the steady-state contract: any new XLA compile or
         # implicit device->host read is a violation (recorded when jitcheck
@@ -910,6 +971,7 @@ class LLMEngine:
         finally:
             st.close()      # whatever happened, no annotation stays open
         self._record_step(st)
+        return st
 
     def _record_step(self, st: _StepTrace) -> None:
         """Fold one finished step into the counters and, traced, record it
@@ -1141,6 +1203,9 @@ class LLMEngine:
         batch_size = len(due.rows)
         firsts: List[tuple] = []  # sampled requests' (req, slot, ntok)
         with self._state_lock:
+            # One reading a chunk, under the lock no consumer can take
+            # before: the instant its tokens became their requests'.
+            delivered_ns = tracing.now_ns()
             for slot, req, upto in due.rows:
                 if req.done:
                     # Cancelled since the dispatch (slot and blocks went
@@ -1152,6 +1217,8 @@ class LLMEngine:
                     if req.trace_ctx is not None:
                         firsts.append((req, slot, upto))
                 new_toks = host_toks[slot, :upto].tolist()
+                if new_toks and not req.tokens:
+                    req.delivered_ns = delivered_ns
                 req.tokens.extend(new_toks)
                 req.out_ids.extend(new_toks)
                 req.emitted += upto
@@ -1162,7 +1229,7 @@ class LLMEngine:
                 # in phase 1 of this step (``scheduled`` reached ``max_new``
                 # when the chunk was dispatched, a step ago).
                 if req.retiring is not None and req.emitted == req.scheduled:
-                    self._finish_locked(req, slot)
+                    self._finish_locked(req)
                 else:
                     req.cond.notify_all()
             # What the engine still holds once this step's tokens are out:
@@ -1397,6 +1464,12 @@ class LLMEngine:
         # ``admit_stopped_{queue_empty,no_slot,budget,no_blocks}_total``;
         # ``admit_starved_total`` counts the steps that dispatched a decode
         # with a slot empty and the queue empty: the clients were elsewhere.
+        # The first hand-over of a token's way back to its client:
+        # ``stream_pickups_total`` counts the consumers' takes of a
+        # non-empty ``req.tokens`` in ``drive``, ``stream_pickup_lag_s`` sums
+        # how long after ``_deliver`` made the oldest of them the request's
+        # each take came (the replica's ``get_metrics`` continues the
+        # account: ``stream_items_total`` and on).
         with self._agg_lock:
             out.update({k: v / 1e9 if k.endswith("_s") else float(v)
                         for k, v in self._counts.items()})

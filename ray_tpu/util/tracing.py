@@ -405,16 +405,17 @@ def emit(name: str, ctx: Optional[Tuple[str, str, bool]], *, start: int,
 _annotation_cls = None
 
 
-def annotation(name: str):
+def annotation(name: str, **args):
     """A ``jax.profiler.TraceAnnotation`` for ``name``: the same interval,
     written into whatever profiler session is open, above the device lines.
-    With no session open it costs one flag check inside the profiler."""
+    With no session open it costs one flag check inside the profiler, and
+    ``args`` (the event's arguments in the trace) are not encoded."""
     global _annotation_cls
     if _annotation_cls is None:
         from jax.profiler import TraceAnnotation
 
         _annotation_cls = TraceAnnotation
-    return _annotation_cls(name)
+    return _annotation_cls(name, **args)
 
 
 @contextlib.contextmanager

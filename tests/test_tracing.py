@@ -782,12 +782,20 @@ class TestStepAccounting:
         """The operator's reader of ``cpu_ns`` and ``handoff_ns``
         (``python -m ray_tpu.devtools.stepspans``) agrees with stats()."""
         from ray_tpu.devtools import stepspans
+        from ray_tpu.serve.replica import ReplicaActor
         eng = _engine(tiny_model, 65, "ringsum")
+        # Through a replica's streaming method, so that the ring also holds
+        # the way back: ``serve.replica_stream`` beside ``llm.request``.
+        replica = ReplicaActor(
+            "ringsum", lambda i: eng.stream([7, 3, 11 + i], max_new_tokens=8),
+            (), {})
         t0 = tracing.now_ns()
         before = eng.stats()
         for i in range(2):
             with tracing.span("serve.request"):
-                assert len(eng.generate([7, 3, 11 + i], max_new_tokens=8)) == 8
+                assert len(list(replica.handle_request_streaming(
+                    "__call__", i, _trace_submit_ts=tracing.wall_of(
+                        tracing.now_ns())))) == 8
         d = _delta(eng.stats(), before)
         out = stepspans.summarise(tracing.recorded(t0))
         assert out["decode_steps"] == d["steps_total"] > 0
@@ -802,8 +810,28 @@ class TestStepAccounting:
         assert out["submit_ms"]["p50"] >= 0 and out["tail_ms"]["max"] >= 0
         assert sum(b["handoff_s"] for b in out["steps_by_bucket_s"].values()
                    ) == pytest.approx(d["step_handoff_s"], rel=1e-6)
-        assert sum(b["n"] for b in
-                   out["requests_by_bucket_s_of_engine_finish"].values()) == 2
+        by_finish = out["requests_by_bucket_s_of_engine_finish"].values()
+        assert sum(b["n"] for b in by_finish) == 2
+        # One consumer drove every step: no driver ever changed, and its
+        # stream's span is mostly steps it ran for the engine.
+        assert sum(b["driver_switches"]
+                   for b in out["steps_by_bucket_s"].values()) == \
+            d["step_driver_switches_total"] == 0
+        for b in by_finish:
+            # The hops beside the tail, in the same buckets: the pick-ups
+            # and the publishes as stats() counted them; this caller went
+            # through no handle, so its take and hold have no items.
+            assert 0 <= b["pickup_lag_ms"]["mean"] <= b["pickup_lag_ms"]["max"]
+            assert 0 < b["publish_us_per_item"]["mean"] \
+                <= b["publish_us_per_item"]["max"]
+            assert b["take_lag_ms"]["mean"] is None
+            assert b["client_hold_ms"]["mean"] is None
+            assert 0 < b["drove_share"] <= 100
+            assert 0 < b["producer_cpu_share"] <= 100
+            assert b["consumer_cpu_share"] == 0
+        assert sum(b["pickup_lag_ms"]["mean"] is not None
+                   for b in by_finish) >= 1
+        assert d["stream_pickups_total"] >= 2 and d["stream_pickup_lag_s"] >= 0
         empty = stepspans.summarise([])
         assert empty["spans"] == empty["decode_steps"] == empty["requests"] == 0
 
