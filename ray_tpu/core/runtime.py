@@ -258,6 +258,13 @@ class ActorRunner:
 
         Ordering is enforced upstream by the Runtime's sequence tracker, which
         survives actor restarts; the runner is a plain FIFO executor.
+
+        One task wakes ONE parked runner (a replica parks ~300 on this
+        condition). K submits hand their K notifications to K distinct
+        waiters; a waiter re-checks the mailbox under the lock whatever
+        ended its wait, and a runner that ends a task looks at the mailbox
+        before it parks, so a task that found every runner busy needs no
+        notification at all. Only ``kill`` wakes all: each must see ``dead``.
         """
         with self.lock:
             if self.dead:
@@ -268,7 +275,7 @@ class ActorRunner:
                 )
             self.num_pending += 1
             self.mailbox.append(state)
-            self.cv.notify_all()
+            self.cv.notify()
         if self.is_async and self._loop is not None:
             import asyncio
 
@@ -348,7 +355,8 @@ class ActorRunner:
 
     def kill(self, error: BaseException) -> List[TaskState]:
         """Mark dead; return drained mailbox + reorder buffer for error
-        propagation."""
+        propagation. Wakes EVERY parked runner (``submit`` wakes one): each
+        has to see ``dead`` and leave."""
         with self.lock:
             self.dead = True
             self.death_error = error
