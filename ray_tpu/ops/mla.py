@@ -17,8 +17,9 @@ squared) and how the two absorbed projections are laid out in memory.
 
 from __future__ import annotations
 
+import contextlib
 from dataclasses import dataclass
-from typing import Any, Mapping, Optional
+from typing import Any, Callable, Mapping, Optional
 
 import jax
 import jax.numpy as jnp
@@ -53,15 +54,19 @@ class LatentSpec:
 
 
 def latent_attention(ap, x, pool, sub, blk, off, tables, lengths, positions,
-                     spec: LatentSpec, kernel: str):
+                     spec: LatentSpec, kernel: str,
+                     select: Optional[Callable] = None):
     """One latent-attention sublayer over the paged rows, ``W_kvb`` absorbed.
 
     ``x`` [S, T, D] (normed); the T new rows are written to blocks ``blk``
     [S, T] of sublayer ``sub`` at offsets ``off`` first, then attended with
     the rest through ``tables``. ``ap`` holds ``w_qa``, ``q_norm``, ``w_qb``
     [r, H, nope + rope], ``w_kva`` [D, rank + rope], ``kv_norm``, ``w_kb``,
-    ``w_vb`` (see ``LatentSpec.heads_major``) and ``w_o`` [H, v, D]. Returns
-    (out [S, T, D], pool)."""
+    ``w_vb`` (see ``LatentSpec.heads_major``) and ``w_o`` [H, v, D].
+    ``select(c_q)`` (the query latent after its norm, [S, T, r]) gives keep
+    bits [S, T, NB * bt]: a query attends a row only if its bit is set
+    (``ops/sparse_select.py``; a family whose indexer reads the same latent).
+    Returns (out [S, T, D], pool)."""
     p, dt = spec, spec.dtype
     R = p.rank
     S, T, _ = x.shape
@@ -90,13 +95,17 @@ def latent_attention(ap, x, pool, sub, blk, off, tables, lengths, positions,
     q_abs = jnp.concatenate(
         [mm(kb, q_nope, ap["w_kb"], dt), q_rope,
          jnp.zeros((S, T, H, pad), dt)], axis=-1)
-    if kernel in ("pallas", "interpret"):
-        o_lat = latent_paged_attention(
-            q_abs, pool, tables, lengths, sub, value_lanes=R,
-            scale=p.softmax_scale, interpret=kernel == "interpret")
-    else:
-        o_lat = latent_paged_attention_reference(
-            q_abs, pool, tables, lengths, sub, value_lanes=R,
-            scale=p.softmax_scale)
+    keep = None if select is None else select(cq)
+    with (contextlib.nullcontext() if keep is None
+          else jax.named_scope("attn_sparse")):
+        if kernel in ("pallas", "interpret"):
+            o_lat = latent_paged_attention(
+                q_abs, pool, tables, lengths, sub, value_lanes=R,
+                scale=p.softmax_scale, interpret=kernel == "interpret",
+                keep=keep)
+        else:
+            o_lat = latent_paged_attention_reference(
+                q_abs, pool, tables, lengths, sub, value_lanes=R,
+                scale=p.softmax_scale, keep=keep)
     o = mm(vb, o_lat, ap["w_vb"], dt)
     return mm("sthv,hvd->std", o, ap["w_o"], dt), pool

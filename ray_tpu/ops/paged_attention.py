@@ -1077,6 +1077,7 @@ def _latent_kernel(
     scale: float,
     num_heads: int,
     value_lanes: int,
+    keep_ref=None,             # [n_groups, T, G*bt] block: see _keep_rows
     **walk,                    # _walk_live_groups' static arguments
 ):
     H, T = num_heads, walk["q_tile"]
@@ -1091,7 +1092,10 @@ def _latent_kernel(
             jnp.int32, (rows, tokens), 1)
         row = jax.lax.broadcasted_iota(jnp.int32, (rows, tokens), 0)
         q_pos = ctx + (0 if T == 1 else jax.lax.div(row, H))
-        scores = jnp.where(kv_pos <= q_pos, scores, _NEG_INF)
+        visible = kv_pos <= q_pos
+        if keep_ref is not None:
+            visible = jnp.logical_and(visible, _keep_rows(keep_ref[g], H))
+        scores = jnp.where(visible, scores, _NEG_INF)
         m_prev = m_scr[:]
         m_new = jnp.maximum(m_prev, jnp.max(scores, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -1115,6 +1119,34 @@ def _latent_kernel(
         half_ref, (m_scr, l_scr, acc_scr), attend, finalize, o_ref, **walk)
 
 
+def _keep_rows(keep, num_heads: int):
+    """A group's keep bits ``[T, tokens]`` (1.0 or 0.0, one row a QUERY) as
+    the ``[T * num_heads, tokens]`` booleans of the latent kernel's score
+    rows (query-major: row ``t * H + h``). A decode step's one row
+    broadcasts; a tile's rows are repeated by a product with the one-hot
+    ``[T * H, T]``, which the matrix unit does beside the scores' own
+    (``T / W`` of their work) and Mosaic lowers whatever ``T`` and ``H``."""
+    T, tokens = keep.shape
+    if T == 1:        # (a v5e compares no bfloat16)
+        return jnp.broadcast_to(keep.astype(jnp.float32),
+                                (num_heads, tokens)) > 0
+    row = jax.lax.broadcasted_iota(jnp.int32, (T * num_heads, T), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (T * num_heads, T), 1)
+    own = (jax.lax.div(row, num_heads) == col).astype(keep.dtype)
+    return jax.lax.dot_general(
+        own, keep, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32) > 0
+
+
+def _latent_keep_kernel(tables_ref, lengths_ref, layer_ref, q_ref, keep_ref,
+                        pool_hbm, o_ref, *scratch, **kw):
+    """``_latent_kernel`` with one more operand, a keep bit a (query, kv
+    position): a query attends a position only if its bit is set (and the
+    position is visible). The body without the operand is untouched."""
+    _latent_kernel(tables_ref, lengths_ref, layer_ref, q_ref, pool_hbm, o_ref,
+                   *scratch, keep_ref=keep_ref, **kw)
+
+
 def latent_paged_attention(
     q: jax.Array,                # [S, T, H, W] — absorbed queries, W = row width
     pool: jax.Array,             # [A, num_blocks, bt, W] latent rows, A sublayers
@@ -1125,28 +1157,35 @@ def latent_paged_attention(
     value_lanes: int,
     scale: float,
     interpret: bool = False,
+    keep: Optional[jax.Array] = None,    # [S, T, NB * bt] bool
 ) -> jax.Array:
     """Softmax over the latent rows, returns ``sum_j p_j row_j[:value_lanes]``
     as [S, T, H, value_lanes]: the caller up-projects it per head. Query t of
     slot s sits at ``lengths[s] + t`` and attends positions ``<=`` it; the T
     new rows must already be in the pool. A slot whose table begins with the
     trash block 0 is parked: not walked, its rows zeros (as
-    :func:`paged_attention`)."""
+    :func:`paged_attention`). ``keep``: query t of slot s attends position j
+    only if ``keep[s, t, j]`` as well (a learned selection,
+    ``ops/sparse_select.py``); the walk still fetches every live block."""
     W = q.shape[3]
     if pool.ndim != 4 or pool.shape[3] != W or W % 128:
         raise ValueError(
             f"pool {pool.shape} is not [A, num_blocks, bt, {W}] of whole "
             f"128-lane tiles")
-    return _latent_attention(
-        q, pool, tables.astype(jnp.int32), lengths.astype(jnp.int32),
-        jnp.asarray(layer, jnp.int32).reshape(1), value_lanes=value_lanes,
-        scale=float(scale), interpret=interpret)
+    operands = (q, pool, tables.astype(jnp.int32), lengths.astype(jnp.int32),
+                jnp.asarray(layer, jnp.int32).reshape(1))
+    if keep is not None and keep.shape != (
+            *q.shape[:2], tables.shape[1] * pool.shape[2]):
+        raise ValueError(f"keep {keep.shape} is not [slots, queries, table "
+                         f"entries x block tokens]")
+    return _latent_attention(*operands, keep, value_lanes=value_lanes,
+                             scale=float(scale), interpret=interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("value_lanes", "scale", "interpret"))
-def _latent_attention(q, pool, tables, lengths, layer, *, value_lanes, scale,
-                      interpret):
+def _latent_attention(q, pool, tables, lengths, layer, keep=None, *,
+                      value_lanes, scale, interpret):
     """:func:`latent_paged_attention` on checked operands: a jit of its own,
     as :func:`_paged_attention` is and for its reason (a serve program calls
     it once a sublayer, and there are ten of them a family)."""
@@ -1165,10 +1204,25 @@ def _latent_attention(q, pool, tables, lengths, layer, *, value_lanes, scale,
     def q_index(s, i, *_):
         return (s, i, 0, 0)
 
+    kernel, keep_ops, keep_specs = _latent_kernel, (), []
+    if keep is not None:
+        # The bits as the walk takes them: a block a (slot, query tile) of
+        # ``[groups, tq, G*bt]``, so that a group's are one index of a leading
+        # dimension; 1.0 / 0.0 in q's type (a tile of 16 queries is one
+        # bfloat16 sublane tile). Pad queries and pad positions keep nothing.
+        tokens = G * bt
+        n_groups = pl.cdiv(keep.shape[2], tokens)
+        keep = jnp.pad(keep, ((0, 0), (0, q_tiles * tq - T),
+                              (0, n_groups * tokens - keep.shape[2])))
+        keep = keep.reshape(S, q_tiles, tq, n_groups, tokens).swapaxes(2, 3)
+        kernel, keep_ops = _latent_keep_kernel, (keep.astype(q.dtype),)
+        keep_specs = [pl.BlockSpec((None, None, n_groups, tq, tokens),
+                                   lambda s, i, *_: (s, i, 0, 0, 0))]
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(S, q_tiles),
-        in_specs=[pl.BlockSpec((None, 1, tq * H, W), q_index),
+        in_specs=[pl.BlockSpec((None, 1, tq * H, W), q_index), *keep_specs,
                   pl.BlockSpec(memory_space=pl.ANY)],
         out_specs=pl.BlockSpec((None, 1, tq * H, value_lanes), q_index),
         scratch_shapes=[
@@ -1182,7 +1236,7 @@ def _latent_attention(q, pool, tables, lengths, layer, *, value_lanes, scale,
     )
     out = pl.pallas_call(
         functools.partial(
-            _latent_kernel, scale=scale, num_heads=H, value_lanes=value_lanes,
+            kernel, scale=scale, num_heads=H, value_lanes=value_lanes,
             block_tokens=bt, q_tile=tq, total=T, nb_seq=tables.shape[1],
             group_blocks=G, unroll_full=True),
         grid_spec=grid_spec,
@@ -1190,15 +1244,17 @@ def _latent_attention(q, pool, tables, lengths, layer, *, value_lanes, scale,
                                        q.dtype),
         interpret=interpret,
         name="mla_decode_attn" if T == 1 else "mla_prefill_attn",
-    )(tables, lengths, layer, qr, pool)
+    )(tables, lengths, layer, qr, *keep_ops, pool)
     return out.reshape(S, q_tiles * tq, H, value_lanes)[:, :T]
 
 
 def latent_paged_attention_reference(q, pool, tables, lengths, layer, *,
-                                     value_lanes: int, scale: float) -> jax.Array:
+                                     value_lanes: int, scale: float,
+                                     keep=None) -> jax.Array:
     """Gather-path oracle of :func:`latent_paged_attention` (and the CPU
     path of the serve programs): the table's rows gathered into
-    [S, NB*bt, W], masked dense attention over them."""
+    [S, NB*bt, W], masked dense attention over them (and over ``keep``
+    [S, T, NB*bt] where given)."""
     S, T, H, W = q.shape
     bt = pool.shape[2]
     n = tables.shape[1] * bt
@@ -1207,7 +1263,10 @@ def latent_paged_attention_reference(q, pool, tables, lengths, layer, *,
                         preferred_element_type=jnp.float32) * scale
     kv_pos = jnp.arange(n)[None, None, None, :]
     q_pos = lengths.reshape(-1, 1, 1, 1) + jnp.arange(T)[None, None, :, None]
-    probs = jax.nn.softmax(jnp.where(kv_pos <= q_pos, scores, _NEG_INF), axis=-1)
+    visible = kv_pos <= q_pos
+    if keep is not None:
+        visible = jnp.logical_and(visible, keep[:, None] != 0)
+    probs = jax.nn.softmax(jnp.where(visible, scores, _NEG_INF), axis=-1)
     out = jnp.einsum("shtn,snv->sthv", probs,
                      rows[..., :value_lanes].astype(jnp.float32))
     return out.astype(q.dtype)

@@ -120,3 +120,77 @@ class TestLatentWalk:
             latent_paged_attention(q, clean[..., :-128], *rest,
                                    value_lanes=128, scale=0.25,
                                    interpret=True)
+
+
+class TestKeepBits:
+    """The latent kernel with one more operand, a keep bit a (query, kv
+    position) (a learned selection's, ``ops/sparse_select.py``), against the
+    oracle given the same bits. Every query keeps its own position, so no
+    row of the softmax is empty; about a third of the rest."""
+
+    @classmethod
+    def _check_kept(cls, lengths, t_tokens, seed):
+        q, clean, poisoned, rest = TestLatentWalk._ops(lengths, t_tokens,
+                                                      seed=seed)
+        rng = np.random.default_rng(seed + 100)
+        S, N = len(lengths), TestLatentWalk.LNB * TestLatentWalk.LBT
+        keep = rng.random((S, t_tokens, N)) < 0.3
+        pos = (np.asarray([ln or 0 for ln in lengths])[:, None]
+               + np.arange(t_tokens)[None])
+        keep[np.arange(S)[:, None], np.arange(t_tokens)[None],
+             np.minimum(pos, N - 1)] = True
+        keep = jnp.asarray(keep)
+        ref = latent_paged_attention_reference(
+            q, clean, *rest, value_lanes=128, scale=0.25, keep=keep)
+        out = latent_paged_attention(
+            q, poisoned, *rest, value_lanes=128, scale=0.25, interpret=True,
+            keep=keep)
+        _assert_live_close(out, ref, lengths)
+        dense = latent_paged_attention_reference(
+            q, clean, *rest, value_lanes=128, scale=0.25)
+        live = [s for s, ln in enumerate(lengths) if ln is not None]
+        assert np.abs(np.asarray(ref - dense))[live].max() > 1e-3   # the bits bind
+
+    @pytest.mark.parametrize("length", [1, 17, 511, 512, 513, 1100])
+    def test_decode_steps_attend_the_kept_rows_alone(self, length):
+        self._check_kept([None, length, None, 40], 1, seed=length)
+
+    @pytest.mark.parametrize("start,t_tokens", [(37, 16), (37, 40), (500, 40),
+                                                (0, 64)])
+    def test_query_tiles_attend_the_kept_rows_alone(self, start, t_tokens):
+        """One whole tile, two tiles and a ragged third (its pad queries keep
+        nothing), across the second group's edge, and a prefill from 0."""
+        self._check_kept([start], t_tokens, seed=t_tokens + start)
+
+    def test_keep_bits_of_another_shape_are_refused(self):
+        q, clean, _poisoned, rest = TestLatentWalk._ops([5], 1)
+        with pytest.raises(ValueError, match="keep"):
+            latent_paged_attention(q, clean, *rest, value_lanes=128,
+                                   scale=0.25, interpret=True,
+                                   keep=jnp.ones((1, 1, 100), bool))
+
+
+# sha256 (first 16 hex digits) of the traced ``latent_paged_attention`` WITHOUT
+# keep bits, a decode step and a ragged prefill, addresses and this file's
+# line numbers taken out: PR 52's tree gives the same two (the body a caller
+# that passes no bits runs is the parent's: the operand is a second kernel
+# function around the first, ``_latent_keep_kernel``).
+BODY_DIGESTS = {1: "49a3958b37790b14", 40: "441d77e21ed69373"}
+
+
+@pytest.mark.parametrize("t_tokens", sorted(BODY_DIGESTS))
+def test_the_body_that_takes_no_keep_bits_is_the_one_it_was(t_tokens):
+    import hashlib
+    import re
+
+    import jax
+
+    S, H, W, NB, bt = 3, 16, 256, 40, 16
+    jaxpr = jax.make_jaxpr(lambda q, pool, tables, lengths: latent_paged_attention(
+        q, pool, tables, lengths, 1, value_lanes=128, scale=0.25))(
+        jnp.zeros((S, t_tokens, H, W), jnp.bfloat16),
+        jnp.zeros((2, 50, bt, W), jnp.bfloat16),
+        jnp.zeros((S, NB), jnp.int32), jnp.zeros((S,), jnp.int32))
+    text = re.sub(r" at 0x[0-9a-f]+", "", str(jaxpr))
+    text = re.sub(r"/[^ ]*paged_attention.py:\d+", "", text)
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == BODY_DIGESTS[t_tokens]

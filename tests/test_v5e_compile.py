@@ -76,6 +76,21 @@ TRINITY_SLOTS, TRINITY_POOL, TRINITY_BUCKET = 64, 30785, 8192
 MIMO_SLOTS, MIMO_POOL, MIMO_BUCKET = 128, 32769, 4096
 
 
+# GLM-5 at the sizes of the cell glm-5.sparse-decode: published widths, one
+# dense and four expert layers, 8 experts held, 48 slots, pool 21937 x 16 in
+# BOTH pool arrays (latent rows and index keys), the 8192 bucket (the
+# largest).
+GLM_SLOTS, GLM_POOL, GLM_BUCKET = 48, 21937, 8192
+# The per-layer metrics that find an operation of the selection by its name
+# (a Pallas kernel) or, where XLA runs it, by its kind and shape: the decode
+# step's, then the prefill's.
+DSA_METRICS = ("dsa_index_ms_per_step.batch", "dsa_select_ms_per_step.batch",
+               "dsa_gather_ms_per_step.batch", "sparse_attn_roofline")
+DSA_PREFILL_METRICS = ("dsa_index_ms_per_prefill.batch",
+                       "dsa_select_ms_per_prefill.batch",
+                       "mla_attn_ms_per_prefill.batch")
+
+
 # Nemotron-3-Nano-30B-A3B at the sizes of the cell
 # nemotron-3-nano-30b-a3b.reason-decode: published widths, 13 layers
 # MEMEM*EMEMEM* (6 mixers, 5 expert layers of 64 held, 2 attention layers),
@@ -488,6 +503,8 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
     ``shared_expert_ms_per_step.batch``},
     "capacity_ops": {Nemotron decode: named_ops() of the pattern of
     ``expert_capacity_ffn_roofline``},
+    "dsa_ops": {GLM-5 serve program: {metric of DSA_METRICS: named_ops() of
+    its pattern}},
     "multisets": {name: instruction_multiset() of the compiled program},
     "latent_calls": {name: [calls of the latent kernel's jit, distinct
     traced bodies among them]},
@@ -540,11 +557,16 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
                            "expert_capacity_ffn_roofline.json")) as f:
         capacity_pattern = json.load(f)["pattern"]
     capacity_ops, multisets = {}, {}
+    dsa_patterns, dsa_ops = {}, {}
+    for metric in DSA_METRICS + DSA_PREFILL_METRICS:
+        with open(os.path.join(REPO, "benchmark", "metrics",
+                               f"{metric}.json")) as f:
+            dsa_patterns[metric] = json.load(f)["pattern"]
     place = itertools.count()
 
     def attempt(name, trace, pool=None, state=None, weights=None,
                 shared=False, state_kernel="gdn_decode", pairs=None,
-                capacity=False):
+                capacity=False, dsa=False):
         if next(place) % of != share:
             return
         try:
@@ -582,6 +604,9 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
                 shared_expert_ops[name] = named_ops(text, shared_pattern)
             if capacity:
                 capacity_ops[name] = named_ops(text, capacity_pattern)
+            if dsa:
+                dsa_ops[name] = {metric: named_ops(text, pattern)
+                                 for metric, pattern in dsa_patterns.items()}
             if pairs is not None:
                 pair_rows[name] = pair_row_arrays(text, *pairs)
             multisets[name] = instruction_multiset(text)
@@ -903,6 +928,38 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
             pairs=(MIMO_BUCKET, mcfg.num_experts_per_tok, expert_widths(
                 mcfg.hidden_size, mcfg.moe_intermediate_size)))
 
+    # GLM-5's serve programs whole, at the cell's own sizes: the selection's
+    # kernel under its two names beside the latent kernel's (which takes the
+    # keep bits), what the selection's metrics would count, and the bytes the
+    # chip must hold (2.70B bf16 parameters and 3.78 GB in the two pools).
+    from ray_tpu.models import glm_dsa
+
+    gcfg = glm_dsa.glm_5_share()
+    gparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: glm_dsa.init_params(gcfg, key),
+                       jax.random.key(0)))
+    ggen = PagedGenerator(gparams, gcfg, slots=GLM_SLOTS, num_blocks=GLM_POOL,
+                          block_tokens=bt, attention_kernel="pallas")
+    gpool = tuple(arr(x.shape, x.dtype) for x in jax.eval_shape(
+        lambda: glm_dsa.init_pool(gcfg, GLM_POOL, bt)))
+    gstate = (gparams, gpool, (),
+              arr((GLM_SLOTS, ggen.logits_dim), jnp.float32),
+              arr((GLM_SLOTS, 2), jnp.uint32))
+    g_slot = lambda dtype: arr((GLM_SLOTS,), dtype)  # noqa: E731
+    g_geometry = (gcfg.attn_sublayers, GLM_POOL, bt)
+    attempt("glm_decode",
+            lambda: ggen.decode_fn(8).trace(
+                *gstate, arr((GLM_SLOTS, ggen.blocks_per_seq), jnp.int32),
+                g_slot(jnp.int32), g_slot(jnp.bool_), g_slot(jnp.bool_),
+                g_slot(jnp.float32)), pool=g_geometry,
+            weights=shapes_of(gparams), dsa=True)
+    attempt(f"glm_prefill_{GLM_BUCKET}",
+            lambda: ggen.prefill_fn(GLM_BUCKET).trace(
+                *gstate, arr((ggen.blocks_per_seq,), jnp.int32),
+                arr((1, GLM_BUCKET), jnp.int32), i32, i32, i32, i32),
+            pool=g_geometry, dsa=True)
+
     # Nemotron-H's serve programs whole, at the cell's own sizes: a layer is
     # ONE thing, so the state kernel is called by the six mixer layers alone
     # on a float32 state of its own depth, the attention kernel by the two
@@ -970,7 +1027,8 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
             "state_movers": state_movers, "weight_movers": weight_movers,
             "state_roundings": state_roundings,
             "shared_expert_ops": shared_expert_ops,
-            "capacity_ops": capacity_ops, "multisets": multisets,
+            "capacity_ops": capacity_ops, "dsa_ops": dsa_ops,
+            "multisets": multisets,
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
             "flash_products": flash_products, "flash_movers": flash_movers,
             "pair_rows": pair_rows, "pool_writes": pool_writers,
@@ -1808,3 +1866,89 @@ ENTRY %main (w: f32[2,1024,4096], e: f32[50304,1024], h: bf16[4096,1024]) -> bf1
 
 if __name__ == "__main__":
     print(json.dumps(compile_all(*(int(arg) for arg in sys.argv[1:3]))))
+
+
+@pytest.mark.parametrize("program,kernels,need", [
+    # the selection's kernel takes tiles of 32 rows: 48 slots are two, padded
+    ("glm_decode", {"dsa_select": "bf16[64,8192]",
+                    "mla_decode_attn": "bf16[48,1,64,512]"}, (8.3e9, 8.7e9)),
+    ("glm_prefill_8192", {"dsa_select_prefill": "bf16[256,8192]",
+                          "mla_prefill_attn": "bf16[1,512,1024,512]"},
+     (10.3e9, 10.8e9))])
+def test_glm_serve_programs_fit_the_chip(verdict, program, kernels, need):
+    """GLM-5's ``paged_decode`` and its largest ``paged_prefill`` at the
+    sizes of ``glm-5.sparse-decode`` (48 slots, 21,937 blocks in both pool
+    arrays): they compile for a v5e, the latent kernel WITH its keep-bit
+    operand and the selection's kernel among them (a v5e compares no
+    bfloat16: the bits are widened first), and arguments plus temporaries
+    stay under ISSUE 53's 14.5 GB: weights 5.41 GB + the two pools 2.70 GB,
+    + 0.36 GB of temporaries in decode and 2.44 GB in the 8,192 bucket, whose
+    index scores and keep bits are made 256 queries at a time (whole, the 32
+    heads' products are 8.6 GB). No instruction copies or slices data the
+    size of a pool. Of the weights the decode program moves ONE matrix a
+    layer, ``W_qb`` [2048, 64, 256], and that is the COMPILER'S: it wants the
+    query product's operand heads-major, copies each layer's once a CALL
+    before the loop of 8 steps (335 MB beside the ~45 GB a call reads) and
+    parks layer 0's in its alternate memory (as MiMo-V2's two, PERF.md 7 item
+    31). The indexer's ``w_q`` is stored a plain ``[r, heads x head_dim]``
+    matrix: stored ``[r, heads, head_dim]`` it was re-laid on every STEP."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    assert need[0] < verdict["need_bytes"][program] < need[1], verdict["need_bytes"]
+    assert verdict["need_bytes"][program] < 14.5e9
+    found = {n: s for n, s in verdict["kernels"][program]
+             if not n.startswith("ragged-dot")}
+    assert found == kernels, verdict["kernels"][program]
+    assert verdict["pool_movers"][program] == []
+    if program == "glm_decode":
+        moved = sorted((shape.split("{")[0], where == "ENTRY") for _op, _name,
+                       shape, where in verdict["weight_movers"][program])
+        assert moved == [("bf16[2048,64,256]", False)] + [
+            ("bf16[2048,64,256]", True)] * 5, moved
+    vmem = verdict["scoped_vmem"][program]
+    assert max(vmem) > 1 << 20 and all(0 <= v < V5E_SCOPED_VMEM for v in vmem)
+    # one call of each a layer: 5 latent, 5 selections
+    assert len(verdict["grids"][program]) == 10, verdict["grids"][program]
+
+
+def test_the_selections_patterns_match_its_operations_alone(verdict):
+    """The selection's metrics find what XLA runs by kind and shape: in the
+    compiled decode program ``dsa_index_ms_per_step.batch`` matches ONE
+    fusion a layer (the scores' product with the ReLU, the head weights and
+    the sum over heads) under the ``dsa_index_scores`` scope,
+    ``dsa_gather_ms_per_step.batch`` one (the index keys through the table)
+    under ``dsa_gather``, ``dsa_select_ms_per_step.batch`` the kernel; and
+    ``sparse_attn_roofline`` those three and the latent kernel, nothing
+    else. In the prefill program none of them matches anything: its kernels
+    carry other names and its fusions other shapes. The prefill's three
+    likewise, the other way about: in the 8,192 bucket the scores of a block
+    of 256 queries are one fusion a layer under the same scope, the selection
+    and the latent kernel one call a layer under a prompt's names, and in the
+    decode program they match nothing."""
+    ops = verdict["dsa_ops"]["glm_decode"]
+    by = {m: sorted({name for name, _scope in found}) for m, found in ops.items()}
+    assert by["dsa_index_ms_per_step.batch"] == ["fusion:fusion:f32[48,8192]"]
+    assert by["dsa_gather_ms_per_step.batch"] == [
+        "fusion:fusion:bf16[24576,16,128]"]
+    assert by["dsa_select_ms_per_step.batch"] == [
+        "dsa_select:custom-call:bf16[64,8192]"]
+    for metric, scope in (("dsa_index_ms_per_step.batch", "/dsa_index_scores/"),
+                          ("dsa_gather_ms_per_step.batch", "/dsa_gather/"),
+                          ("dsa_select_ms_per_step.batch", "/dsa_select/")):
+        assert len(ops[metric]) == 5, ops[metric]
+        assert all(scope in s for _name, s in ops[metric]), ops[metric]
+    assert by["sparse_attn_roofline"] == sorted(
+        by["dsa_index_ms_per_step.batch"] + by["dsa_gather_ms_per_step.batch"]
+        + by["dsa_select_ms_per_step.batch"]
+        + ["mla_decode_attn:custom-call:bf16[48,1,64,512]"])
+    assert len(ops["sparse_attn_roofline"]) == 20
+    prefill = verdict["dsa_ops"]["glm_prefill_8192"]
+    assert all(prefill[m] == [] for m in DSA_METRICS)
+    assert all(ops[m] == [] for m in DSA_PREFILL_METRICS)
+    for metric, name, scope in (
+            ("dsa_index_ms_per_prefill.batch", "fusion:fusion:f32[256,8192]",
+             "/dsa_index_scores/"),
+            ("dsa_select_ms_per_prefill.batch", "dsa_select_prefill:", "/dsa_select/"),
+            ("mla_attn_ms_per_prefill.batch", "mla_prefill_attn:", "/attn_sparse/")):
+        assert len(prefill[metric]) == 5, prefill[metric]
+        assert all(n.startswith(name) and scope in s
+                   for n, s in prefill[metric]), prefill[metric]
