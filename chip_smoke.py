@@ -8,7 +8,8 @@ a seed:
 
 - *kernels*: ``flash_attention`` forward and gradients against its dense
   reference (at the model's shape under 512-blocks and at the shape
-  gpt2-medium's train step hands it, one q block a head), ``paged_attention`` (decode, verify, prefill at every bucket)
+  gpt2-medium's train step hands it, one q block a head), ``paged_attention`` (decode, verify, prefill at every bucket,
+  and one bucket five eighths full with the walk handed the count of real rows)
   against its gather reference, on random inputs at the model's shapes, and
   ``latent_paged_attention`` against its own at the two latent cells' shapes
   (64 heads on 640-lane rows; 128 slots x 64 table entries, 96 x 192);
@@ -109,17 +110,31 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     # Every slot owns a shuffled chain over its whole table (block 0 = trash).
     tables = jnp.asarray(rng.permutation(np.arange(1, pool_blocks))
                          [:SLOTS * nb_seq].reshape(SLOTS, nb_seq), jnp.int32)
-    kernel = jax.jit(lambda *a: paged_attention(*a, interpret=interpret))
+    kernel = jax.jit(lambda *a, **kw: paged_attention(
+        *a, interpret=interpret, **kw))
     oracle = jax.jit(paged_attention_reference)
 
-    def paged_case(name, t_tokens, lengths):
+    def real_rows_err(out, want, real):
+        """``_rel_err`` over a prefill's first ``real`` query rows, the walk
+        handed that count (``queries``); 1.0 unless every row behind them is
+        zeros, which is what the walk leaves of a pad row."""
+        if bool(np.asarray(out[:, real:], np.float32).any()):
+            return 1.0
+        return _rel_err(out[:, :real], want[:, :real])
+
+    def paged_case(name, t_tokens, lengths, real=None):
         n = len(lengths)
         qq = jnp.asarray(rng.standard_normal((n, t_tokens, H, D), np.float32), dt)
         ops = (qq, k_pool, v_pool, tables[:n],
                jnp.asarray(lengths, jnp.int32), 0)
         with jax.default_matmul_precision("highest"):  # read at trace time
             want = oracle(*ops)
-        errs[name] = _rel_err(kernel(*ops), want)
+        if real is None:
+            errs[name] = _rel_err(kernel(*ops), want)
+        else:
+            errs[name] = real_rows_err(
+                kernel(*ops, queries=jnp.full((n,), real, jnp.int32)), want,
+                real)
 
     appending = jax.jit(lambda *a: paged_attention_append(
         *a, interpret=interpret))
@@ -162,6 +177,9 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     for b in _default_buckets(ctx):
         paged_case(f"paged_prefill_{b}", b, [0])
     paged_case("paged_prefill_prefix_hit", ctx // 4, [3 * bt])
+    # a prompt that fills its bucket to five eighths: the walk is handed the
+    # count of real rows and skips the tiles of pad rows alone
+    paged_case("paged_prefill_half_filled", ctx, [0], real=ctx * 5 // 8 - 3)
 
     # The latent kernel at the shapes of longcat-flash-omni.moe-decode and
     # kimi-k2.5.agent-decode: slots, table entries, a prefill bucket; the
@@ -172,8 +190,8 @@ def phase_kernels(cfg, interpret: bool) -> dict:
     if interpret:
         cells = {"longcat": (4, 8, 32), "kimi": (3, 12, 40)}
     latent = dict(value_lanes=values, scale=width ** -0.5)
-    latent_kernel = jax.jit(lambda *a: latent_paged_attention(
-        *a, 1, interpret=interpret, **latent))
+    latent_kernel = jax.jit(lambda *a, **kw: latent_paged_attention(
+        *a, 1, interpret=interpret, **latent, **kw))
     latent_oracle = jax.jit(lambda *a: latent_paged_attention_reference(
         *a, 1, **latent))
     for cell, (slots, nb, bucket) in cells.items():
@@ -193,6 +211,12 @@ def phase_kernels(cfg, interpret: bool) -> dict:
             with jax.default_matmul_precision("highest"):
                 want = latent_oracle(*ops)
             errs[name] = _rel_err(latent_kernel(*ops), want)
+            if t_tokens > 1:
+                real = t_tokens * 5 // 8 - 3
+                errs[name + "_half_filled"] = real_rows_err(
+                    latent_kernel(*ops, queries=jnp.full((n,), real,
+                                                         jnp.int32)),
+                    want, real)
 
     worst = max(errs, key=errs.get)
     return {"ok": all(e <= REL_TOL for e in errs.values()),

@@ -350,8 +350,9 @@ def _window_attend(q, k, v, rings, wl: int, ctx, c: AfmoeConfig, kernel: str):
     kw = dict(scale=c.head_dim ** -0.5, window=c.sliding_window)
     with jax.named_scope("attn_window"):
         if kernel in ("pallas", "interpret"):
+            # a prefill's walk follows its real rows alone
             o = paged_attention(q, *operands, interpret=kernel == "interpret",
-                                **kw)
+                                queries=ctx.get("suffix_len"), **kw)
         else:       # the gather path: [S, T, window] rows, a CPU's sizes
             o = paged_attention_reference(q, *operands, **kw)
     return o, rings
@@ -392,7 +393,8 @@ def _attention(lw, a, pool, rings, layer: int, ctx, c: AfmoeConfig, kernel: str)
                 v.reshape(S, T, -1))
         with jax.named_scope("attn_full"):
             o = _paged_attend(q, k_pool, v_pool, ctx["tables"], ctx["lengths"],
-                              idx, scale=c.head_dim ** -0.5, kernel=kernel)
+                              idx, scale=c.head_dim ** -0.5, kernel=kernel,
+                              queries=ctx.get("suffix_len"))
         pool = (k_pool, v_pool)
     with jax.named_scope("attn_gate"):
         o = (o.reshape(S, T, -1) * jax.nn.sigmoid(gate)).astype(dt)

@@ -386,7 +386,8 @@ def _attention(lw, u, pool, layer, ctx, c: FalconH1Config, kernel: str):
         v_pool = v_pool.at[layer, ctx["blk"], ctx["off"]].set(
             v.reshape(S, T, -1))
     o = _paged_attend(q, k_pool, v_pool, ctx["tables"], ctx["lengths"], layer,
-                      scale=c.head_dim ** -0.5, kernel=kernel)
+                      scale=c.head_dim ** -0.5, kernel=kernel,
+                      queries=ctx.get("suffix_len"))
     return _mm("ste,ed->std", o.reshape(S, T, -1), lw["w_o"], dt), (k_pool, v_pool)
 
 

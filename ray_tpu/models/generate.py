@@ -154,16 +154,19 @@ def init_block_pool(config: TransformerConfig, num_blocks: int,
 
 
 def _paged_attend(q, k_pool, v_pool, tables, lengths, layer, *, scale,
-                  kernel):
+                  kernel, queries=None):
     """Attention over ``layer`` of the whole paged pool, switched by
     ``kernel``: the Pallas kernel streams only live blocks, in place
     (compiled on TPU, interpret on CPU); ``gather`` is the legacy
     table-gather + dense-mask path. A pool row narrower than ``q``'s heads
     holds grouped KV heads (``init_block_pool``): both paths give query head
-    ``h`` KV head ``h // (H // KV)``."""
+    ``h`` KV head ``h // (H // KV)``. ``queries``: a prefill's count of real
+    query rows; the kernel walks for those alone and zeroes the rest, the
+    gather path attends every row as ever (nobody reads a pad row)."""
     if kernel in ("pallas", "interpret"):
         return paged_attention(q, k_pool, v_pool, tables, lengths, layer,
-                               scale=scale, interpret=kernel == "interpret")
+                               scale=scale, interpret=kernel == "interpret",
+                               queries=queries)
     S, T, H, D = q.shape
     max_len = tables.shape[1] * k_pool.shape[2]
     KV = k_pool.shape[3] // D
@@ -184,8 +187,10 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
     were written by earlier sequences sharing the same blocks, so attention
     gathers them back through the table without recomputing. Only the first
     ``suffix_len`` positions are real — pad writes redirect to trash block
-    0 and pad queries are causally ahead of every real row, so their
-    garbage never reaches a real position's softmax.
+    0, and the kernel is handed the count: it walks the table for the real
+    queries alone and a pad query's row of its output is zeros (on the
+    gather path a pad query attends, causally ahead of every real row, and
+    nobody reads what it gives).
 
     ``params`` is the working tree of :func:`gpt2_working_params`: every
     matrix an array of its own in the compute type, read where it lies."""
@@ -217,7 +222,7 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
             k_pool = k_pool.at[layer, blk, off].set(k.reshape(P, -1))
             v_pool = v_pool.at[layer, blk, off].set(v.reshape(P, -1))
         o = _paged_attend(q, k_pool, v_pool, table[None], lengths1, layer,
-                          scale=scale, kernel=kernel)
+                          scale=scale, kernel=kernel, queries=suffix_len)
         o = jnp.einsum("bthk,hkd->btd", o, bp["wo"], preferred_element_type=jnp.float32).astype(c.dtype) + bp["bo"]
         h = h + o
         x = layer_norm(h, bp["ln2_g"], bp["ln2_b"])

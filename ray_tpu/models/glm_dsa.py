@@ -334,10 +334,12 @@ def select(cq, *, ip, a, index, sub, tables, positions, c: GlmDsaConfig,
 
 
 def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
-             valid, c: GlmDsaConfig, kernel: str, last_row=None):
+             valid, c: GlmDsaConfig, kernel: str, last_row=None,
+             queries=None):
     """tokens [S, T] at absolute ``positions`` [S, T]; rows go to cells
     (``blk``, ``off``) of both pools; ``valid`` [S, T] marks the tokens whose
     output is read. ``last_row``: hand the head that one position alone.
+    ``queries``: a prefill's count of real rows, the attention kernel's.
     Returns (logits float32, pool, pick counts, kept): the expert layers'
     counts summed over layers (``moe.PICK_COUNT_NAMES``' order), and the keep
     bits set for each slot [S], a sublayer's mean."""
@@ -361,7 +363,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
                 index_keys(lp["indexer"], a, positions, c))
         o, latent = latent_attention(
             lp["attn"], a, latent, l, blk, off, tables, lengths, positions,
-            spec, kernel,
+            spec, kernel, queries=queries,
             select=functools.partial(
                 counted, ip=lp["indexer"], a=a, index=index, sub=l,
                 tables=tables, positions=positions, c=c, kernel=kernel))
@@ -415,7 +417,7 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     logits, pool, counts, _ = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
         blk[None], (positions % bt)[None], valid[None], c, kernel,
-        last_row=suffix_len - 1)
+        last_row=suffix_len - 1, queries=suffix_len)
     return logits, pool, state, _aux(counts, jnp.zeros((1,), bool), lengths1,
                                      jnp.zeros((1,), jnp.int32), c)
 

@@ -280,10 +280,12 @@ def expert_layer(lp, x, valid, c: KimiK2Config):
 
 
 def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
-             valid, c: KimiK2Config, kernel: str, last_row=None):
+             valid, c: KimiK2Config, kernel: str, last_row=None,
+             queries=None):
     """tokens [S, T] at absolute ``positions`` [S, T]; rows go to pool cells
     (``blk``, ``off``); ``valid`` [S, T] marks the tokens whose output is
-    read. ``last_row``: hand the head that one position alone. Returns
+    read. ``last_row``: hand the head that one position alone. ``queries``:
+    a prefill's count of real rows, the attention kernel's. Returns
     (logits float32, pool, counts): the expert layers' pick counts summed
     over layers, then a 1 for this token step (``longcat.AUX_COUNTS``' order)."""
     dt = c.dtype
@@ -294,7 +296,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     for l, lp in enumerate(params["layers"]):
         o, pool = latent_attention(
             lp["attn"], rms_norm(x, lp["norm_attn"], eps), pool, l, blk, off,
-            tables, lengths, positions, spec, kernel)
+            tables, lengths, positions, spec, kernel, queries=queries)
         h = x + o
         u = rms_norm(h, lp["norm_ffn"], eps)
         if "ffn" in lp:                  # l < first_k_dense_replace
@@ -333,7 +335,7 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     logits, pool, counts = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
         blk[None], (positions % bt)[None], valid[None], config, kernel,
-        last_row=suffix_len - 1)
+        last_row=suffix_len - 1, queries=suffix_len)
     return logits, (pool,), state, counts
 
 

@@ -222,10 +222,11 @@ def init_latent_pool(config: LongCatConfig, num_blocks: int,
 
 
 def _mla(ap, x, pool, sub: int, blk, off, tables, lengths, positions,
-         c: LongCatConfig, kernel: str):
+         c: LongCatConfig, kernel: str, queries=None):
     """The shared latent sublayer (``ops/mla.py``) with this family's spec."""
     return latent_attention(ap, x, pool, sub, blk, off, tables, lengths,
-                            positions, c.latent_spec(), kernel)
+                            positions, c.latent_spec(), kernel,
+                            queries=queries)
 
 
 def _moe(lp, x, valid, c: LongCatConfig):
@@ -240,10 +241,11 @@ def _moe(lp, x, valid, c: LongCatConfig):
 
 
 def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
-             valid, c: LongCatConfig, kernel: str):
+             valid, c: LongCatConfig, kernel: str, queries=None):
     """tokens [S, T] at absolute ``positions`` [S, T]; rows go to pool cells
     (``blk``, ``off``); ``valid`` [S, T] marks the tokens whose output is
-    read. Returns (logits [S, T, V] float32, pool, counts): the expert
+    read; ``queries``: a prefill's count of real rows, the attention
+    kernel's. Returns (logits [S, T, V] float32, pool, counts): the expert
     layers' pick counts summed over layers, then a 1 for this token step
     (``AUX_COUNTS`` names them in this order)."""
     dt = c.dtype
@@ -253,7 +255,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     for l, lp in enumerate(params["layers"]):
         o, pool = _mla(lp["attn"][0], rms_norm(x, lp["norm_attn"][0], eps),
                        pool, 2 * l, blk, off, tables, lengths, positions, c,
-                       kernel)
+                       kernel, queries)
         a = x + o
         hn = rms_norm(a, lp["norm_ffn"][0], eps)
         m, cnt = _moe(lp, hn, valid, c)
@@ -261,7 +263,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
         b = a + _ffn(lp["ffn"][0], hn, dt)
         o, pool = _mla(lp["attn"][1], rms_norm(b, lp["norm_attn"][1], eps),
                        pool, 2 * l + 1, blk, off, tables, lengths, positions,
-                       c, kernel)
+                       c, kernel, queries)
         cc = b + o
         d = cc + _ffn(lp["ffn"][1], rms_norm(cc, lp["norm_ffn"][1], eps), dt)
         x = d + m
@@ -289,7 +291,8 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     lengths1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
     logits, pool, counts = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
-        blk[None], (positions % bt)[None], valid[None], config, kernel)
+        blk[None], (positions % bt)[None], valid[None], config, kernel,
+        queries=suffix_len)
     return logits, (pool,), state, counts
 
 

@@ -350,10 +350,11 @@ def init_slot_state(config: MimoV2Config,
             jnp.zeros(shape + (KV * c.v_head_dim,), c.dtype))
 
 
-def _attend(q, operands, kernel: str, **kw):
+def _attend(q, operands, kernel: str, ctx, **kw):
     if kernel in ("pallas", "interpret"):
+        # a prefill's walk follows its real rows alone
         return paged_attention(q, *operands, interpret=kernel == "interpret",
-                               **kw)
+                               queries=ctx.get("suffix_len"), **kw)
     # the gather path: a CPU's sizes
     return paged_attention_reference(q, *operands, **kw)
 
@@ -388,7 +389,7 @@ def _window_attend(q, k, v, sink, rings, wl: int, ctx, c: MimoV2Config,
         operands = (_as_blocks(rings[0]), _as_blocks(rings[1]), tables,
                     ctx["lengths"], wl)
     with jax.named_scope("attn_window"):
-        o = _attend(q, operands, kernel, scale=c.head_dim ** -0.5,
+        o = _attend(q, operands, kernel, ctx, scale=c.head_dim ** -0.5,
                     window=c.sliding_window, sinks=sink)
     return o, rings
 
@@ -438,7 +439,7 @@ def _attention(lw, a, pool, rings, layer: int, ctx, c: MimoV2Config,
             v_pool = v_pool.at[idx, ctx["blk"], ctx["off"]].set(v)
         with jax.named_scope("attn_full"):
             o = _attend(q, (k_pool, v_pool, ctx["tables"], ctx["lengths"], idx),
-                        kernel, scale=c.head_dim ** -0.5, sinks=sink)
+                        kernel, ctx, scale=c.head_dim ** -0.5, sinks=sink)
         pool = (k_pool, v_pool)
     return _mm("ste,ed->std", o.reshape(S, T, -1), lw["w_o"], dt), pool, rings
 

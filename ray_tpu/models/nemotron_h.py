@@ -464,7 +464,8 @@ def _attention(lw, u, pool, al, ctx, c: NemotronHConfig, kernel: str):
             kv[:, :, KV:].reshape(S, T, -1))
     with jax.named_scope("attn_full"):
         o = _paged_attend(q, k_pool, v_pool, ctx["tables"], ctx["lengths"],
-                          al, scale=c.head_dim ** -0.5, kernel=kernel)
+                          al, scale=c.head_dim ** -0.5, kernel=kernel,
+                          queries=ctx.get("suffix_len"))
     return _mm("ste,ed->std", o.reshape(S, T, -1), lw["w_o"], dt), (k_pool, v_pool)
 
 

@@ -344,9 +344,10 @@ def _linear_decode(lw, x, state, layer, active, c: OlmoHybridConfig,
 
 
 def _full_mixer(lw, x, pool, layer, blk, off, tables, lengths,
-                c: OlmoHybridConfig, kernel: str):
+                c: OlmoHybridConfig, kernel: str, queries=None):
     """Multi-head attention over the paged rows, no rotation: ``x`` [S, T,
-    D]; the T new rows go to pool cells (``blk``, ``off``) first."""
+    D]; the T new rows go to pool cells (``blk``, ``off``) first.
+    ``queries``: a prefill's count of real rows (``_paged_attend``)."""
     dt = c.dtype
     S, T, D = x.shape
     k_pool, v_pool = pool
@@ -360,7 +361,7 @@ def _full_mixer(lw, x, pool, layer, blk, off, tables, lengths,
         v_pool = v_pool.at[layer, blk, off].set(v)
     o = _paged_attend(q.reshape(S, T, c.n_heads, c.head_dim), k_pool, v_pool,
                       tables, lengths, layer, scale=c.head_dim ** -0.5,
-                      kernel=kernel)
+                      kernel=kernel, queries=queries)
     return _mm("ste,ed->std", o.reshape(S, T, D), lw["w_o"], dt), (k_pool, v_pool)
 
 
@@ -389,7 +390,8 @@ def _period_fn(c: OlmoHybridConfig, prefill: bool, kernel: str):
             else:
                 o, pool = _full_mixer(
                     lw, x, pool, i * n_full + fi, ctx["blk"], ctx["off"],
-                    ctx["tables"], ctx["lengths"], c, kernel)
+                    ctx["tables"], ctx["lengths"], c, kernel,
+                    ctx.get("suffix_len"))
                 fi += 1
             h = x + rms_norm(o, lw["norm_mixer"], eps)
             x = h + rms_norm(_ffn(lw["ffn"], h, dt), lw["norm_ffn"], eps)

@@ -55,7 +55,7 @@ class LatentSpec:
 
 def latent_attention(ap, x, pool, sub, blk, off, tables, lengths, positions,
                      spec: LatentSpec, kernel: str,
-                     select: Optional[Callable] = None):
+                     select: Optional[Callable] = None, queries=None):
     """One latent-attention sublayer over the paged rows, ``W_kvb`` absorbed.
 
     ``x`` [S, T, D] (normed); the T new rows are written to blocks ``blk``
@@ -66,6 +66,9 @@ def latent_attention(ap, x, pool, sub, blk, off, tables, lengths, positions,
     ``select(c_q)`` (the query latent after its norm, [S, T, r]) gives keep
     bits [S, T, NB * bt]: a query attends a row only if its bit is set
     (``ops/sparse_select.py``; a family whose indexer reads the same latent).
+    ``queries`` [S]: a prefill's count of real rows of ``x``; the kernel
+    walks for those alone and its pad rows read zeros, the gather path
+    attends every row as ever (nobody reads a pad row).
     Returns (out [S, T, D], pool)."""
     p, dt = spec, spec.dtype
     R = p.rank
@@ -102,7 +105,7 @@ def latent_attention(ap, x, pool, sub, blk, off, tables, lengths, positions,
             o_lat = latent_paged_attention(
                 q_abs, pool, tables, lengths, sub, value_lanes=R,
                 scale=p.softmax_scale, interpret=kernel == "interpret",
-                keep=keep)
+                keep=keep, queries=queries)
         else:
             o_lat = latent_paged_attention_reference(
                 q_abs, pool, tables, lengths, sub, value_lanes=R,

@@ -46,7 +46,16 @@ into the pool at those positions (the caller writes K/V first, then attends:
 the gather path's order; a prefill, the windowed walk and every family but
 GPT-2 call it so). Queries are tiled ``_Q_TILE`` at a time so that VMEM is
 bounded by the tile, not by T; decode and verify are a single tile, and each
-tile walks only the blocks at or below its own last real query.
+tile walks only the blocks at or below its own last real query. Which rows
+are real a PREFILL says at run time (``queries`` [S], a fourth
+scalar-prefetch operand: of slot ``s``'s T rows the first ``queries[s]``; a
+prompt fills its bucket to a half or three quarters, and the pad rows, all
+causally AHEAD of the real ones, were three fifths of an 8,192 bucket's
+walk). The tile that straddles the count walks to its last real query; a
+tile behind it is a parked step of its own (no copy, nothing attended, zeros
+stored, the next step's first group handed on), under a window too; every
+pad row of the result is zeros. A caller that passes no count (a decode step,
+a verify: every row real) traces the body it always did.
 
 ``paged_attention_append`` reads AND writes: a decode step (T = 1) hands it
 each slot's new K and V row as operands beside pools that do not hold them
@@ -167,15 +176,35 @@ def _last_block(first_pos, q_tile: int, block_tokens: int):
     return jax.lax.div(first_pos + q_tile - 1, block_tokens)
 
 
+def _tile_real_queries(queries_ref, s, i, q_tile: int, total: int):
+    """How many of tile ``i``'s ``q_tile`` queries are REAL where slot ``s``
+    brings ``queries_ref[s]`` real ones of ``total`` (a prefill's prompt in
+    its bucket): ``q_tile`` before the count, the rest in the tile that
+    straddles it, 0 or less in a tile of pad queries. None without a count:
+    the caller's static ``total`` says it all."""
+    if queries_ref is None:
+        return None
+    return jnp.minimum(q_tile,
+                       jnp.minimum(queries_ref[s], total) - i * q_tile)
+
+
 def _tile_last_block(lengths_ref, s, i, q_tile: int, total: int,
-                     block_tokens: int, nb_seq: int, ring: bool = False):
+                     block_tokens: int, nb_seq: int, ring: bool = False,
+                     queries_ref=None):
     """The last table entry that query tile ``i`` of slot ``s`` attends, by
     its last REAL query: of ``total`` queries the last tile may hold fewer
     than ``q_tile``, and its pad queries, like a slot at capacity, would pass
     the slot's live blocks or the table's end. ``ring``: the LOGICAL block,
-    which a table read modulo its width has no end for."""
-    real = jnp.minimum(q_tile, total - i * q_tile)
+    which a table read modulo its width has no end for. ``queries_ref`` [S]:
+    the slot's count of real queries, read at run time where ``total`` is the
+    caller's static one; a tile with none of them attends nothing, -1."""
+    real = _tile_real_queries(queries_ref, s, i, q_tile, total)
+    counted = real is not None
+    if not counted:
+        real = jnp.minimum(q_tile, total - i * q_tile)
     last = _last_block(lengths_ref[s] + i * q_tile, real, block_tokens)
+    if counted:
+        last = jnp.where(real > 0, last, -1)
     return last if ring else jnp.minimum(last, nb_seq - 1)
 
 
@@ -198,11 +227,15 @@ def _clamped_block_index(q_tile: int, block_tokens: int, step_blocks: int,
     ``tables[s, b]`` of layer ``layer[0]``, ``b`` clamped onto the tile's
     last live block (by its real queries, ``total`` over all tiles), so that
     a dead entry is never dereferenced and a step that maps where the last
-    one did costs no DMA."""
-    def kv_index(s, i, j, tbl, ln, lyr):
+    one did costs no DMA. ``queries``: the slots' counts of real queries
+    where they are a fourth prefetched operand; a tile of pad queries has
+    no live block and maps onto the slot's first entry."""
+    def kv_index(s, i, j, tbl, ln, lyr, queries=None):
         last_blk = _tile_last_block(ln, s, i, q_tile, total, block_tokens,
-                                    nb_seq)
+                                    nb_seq, queries_ref=queries)
         blk = jnp.minimum(j * step_blocks + offset, last_blk)
+        if queries is not None:
+            blk = jnp.maximum(blk, 0)
         return (lyr[0], tbl[s, blk], 0, 0)
 
     return kv_index
@@ -321,11 +354,25 @@ def _init_accumulators(m_scr, l_scr, acc_scr, sink_ref=None):
     acc_scr[:] = jnp.zeros_like(acc_scr)
 
 
-def _finalize(o_ref, l_scr, acc_scr, kv_heads: int):
+def _real_rows(out, real, query_of_row):
+    """``out`` [rows, lanes] with the rows of a tile's pad queries zeroed:
+    ``query_of_row(row)`` is a row's query in the tile, real iff below
+    ``real``. A select, not a product: a pad query's scores pass unfetched
+    rows of the buffers, and whatever it summed must not flow on."""
+    row = jax.lax.broadcasted_iota(jnp.int32, (out.shape[0], 1), 0)
+    return jnp.where(query_of_row(row) < real, out, 0)
+
+
+def _finalize(o_ref, l_scr, acc_scr, kv_heads: int, real=None):
+    """The accumulators into the output block, head by head. ``real``: how
+    many of the tile's queries are real (a counted call's straddling tile);
+    the others' rows are written as zeros."""
     _, H, T, D = o_ref.shape
     C = acc_scr.shape[-1] // D
     R = H // kv_heads                                 # query heads a KV head
     out = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+    if real is not None:                              # rows are (head, query)
+        out = _real_rows(out, real, lambda row: jax.lax.rem(row, T))
     for h in range(H):                                # static unroll
         e0 = (h // R % C) * D                # its KV head's lanes in its chunk
         o_ref[0, h] = out[h * T:(h + 1) * T, e0:e0 + D]
@@ -339,7 +386,7 @@ def _walk_live_groups(
     half_ref,                  # SMEM [1]: the half this step's first group is in
     accumulators,              # VMEM scratch (m, l, acc), reset here
     attend,                    # attend(g, ctx, half, fetched): group g is in
-    finalize,                  # finalize(): the accumulators into ``o_ref``
+    finalize,                  # finalize(real): accumulators into ``o_ref``
     o_ref,                     # the step's output block
     *,
     block_tokens: int,
@@ -350,6 +397,7 @@ def _walk_live_groups(
     unroll_full: bool = False,
     window: Optional[int] = None,
     sink_ref=None,             # VMEM [H, 1]: where the softmax starts, or None
+    queries_ref=None,          # scalar prefetch [S]: real queries a slot
 ):
     """The walk both kernels share: grid step ``(s, i)`` of a LIVE slot
     resets its accumulators, loops over the groups of ``G`` table entries its
@@ -382,7 +430,19 @@ def _walk_live_groups(
     the table modulo ``nb_seq`` (a ring); group indices stay LOGICAL, so
     ``attend`` masks by position as ever. A ring's pool has NO trash block
     (slot 0's ring starts at block 0), so under a window every slot is
-    walked."""
+    walked.
+
+    ``queries_ref``: of slot ``s``'s ``total`` queries the first
+    ``queries_ref[s]`` are real (a prefill's prompt in its bucket; without the
+    operand all are, and the traced body is the one the digests hold). The
+    tile that straddles the count is bounded by its last real query, as the
+    ragged last tile is, and ``finalize`` gets the count of its real queries
+    (None without the operand) to zero the others' rows by. A tile of pad
+    queries alone is a parked step, under a window too: its last block reads
+    -1, so the step before it starts nothing for it, and it copies, attends
+    and finalizes nothing, writes zeros and hands on the next step's first
+    group (the next slot's: behind a pad tile the slot has only more of
+    them)."""
     s = pl.program_id(0)
     i = pl.program_id(1)
     n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
@@ -394,7 +454,7 @@ def _walk_live_groups(
 
     def last_block(s_, i_):
         last = _tile_last_block(lengths_ref, s_, i_, T, total, bt, nb_seq,
-                                ring)
+                                ring, queries_ref)
         return last if ring else jnp.where(tables_ref[s_, 0] == 0, -1, last)
 
     def live_entries(last, g):
@@ -505,9 +565,9 @@ def _walk_live_groups(
         # The half the prefetched first group of the next step went into.
         half_ref[0] = jax.lax.rem(
             first_half + (n_groups - g0 if ring else n_groups), 2)
-        finalize()
+        finalize(_tile_real_queries(queries_ref, s, i, T, total))
 
-    if ring:
+    if ring and queries_ref is None:
         live_step()
         return
     pl.when(last_blk >= 0)(live_step)
@@ -521,8 +581,10 @@ def _walk_live_groups(
         # is none), in the half ``half_ref`` still names.
         @pl.when(has_next)
         def _hand_on():
-            live_copies(next_s, 0, live_entries(next_last, 0), first_half,
-                        lambda c: c.start())
+            first_g = next_g0 if ring else 0
+            live_copies(next_s, first_g, live_entries(next_last, first_g),
+                        first_half, lambda c: c.start(),
+                        *([dead_entries(next_first, first_g)] if ring else []))
 
 
 def _attend_buffers(q_ref, o_ref, k_buf, v_buf, accumulators, g, ctx, half,
@@ -555,6 +617,7 @@ def _paged_kernel(
     scale: float,
     kv_heads: int,
     sink_ref=None,             # [H, 1] float32 (``_paged_sink_kernel``)
+    queries_ref=None,          # scalar prefetch [S] (``_counted``)
     **walk,                    # _walk_live_groups' static arguments
 ):
     """Two pools, K and V, KV heads folded into the lanes: the walk
@@ -569,8 +632,8 @@ def _paged_kernel(
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
         sems, half_ref, (m_scr, l_scr, acc_scr), attend,
-        lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref,
-        sink_ref=sink_ref, **walk)
+        lambda real: _finalize(o_ref, l_scr, acc_scr, kv_heads, real), o_ref,
+        sink_ref=sink_ref, queries_ref=queries_ref, **walk)
 
 
 def _paged_sink_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
@@ -578,6 +641,17 @@ def _paged_sink_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
     """``_paged_kernel`` with one operand more, the sinks ``[H, 1]``."""
     _paged_kernel(tables_ref, lengths_ref, layer_ref, q_ref, k_hbm, v_hbm,
                   *rest, sink_ref=sink_ref, **kw)
+
+
+def _counted(kernel):
+    """``kernel`` with one scalar-prefetch operand more, the slots' counts of
+    real queries ``[S]`` behind ``layer``: what a caller that passes
+    ``queries`` lowers. The kernel without it is untouched."""
+    def counted(tables_ref, lengths_ref, layer_ref, queries_ref, *rest, **kw):
+        kernel(tables_ref, lengths_ref, layer_ref, *rest,
+               queries_ref=queries_ref, **kw)
+
+    return counted
 
 
 def _paged_append_kernel(
@@ -647,7 +721,8 @@ def _paged_append_kernel(
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (k_hbm, v_hbm), (k_buf, v_buf),
         sems, half_ref, (m_scr, l_scr, acc_scr), attend,
-        lambda: _finalize(o_ref, l_scr, acc_scr, kv_heads), o_ref, **walk)
+        lambda real: _finalize(o_ref, l_scr, acc_scr, kv_heads, real), o_ref,
+        **walk)
     # The half the last group lay in: the one before the half the walk left
     # for the next step's first group.
     # raylint: ignore[untimed-wait] — a DMA semaphore inside the kernel
@@ -666,20 +741,24 @@ def _paged_kernel_unaligned(
     total: int,                # queries over all tiles (the last may be ragged)
     nb_seq: int,
     group_blocks: int,
+    queries_ref=None,          # scalar prefetch [S] (``_counted``)
 ):
     """The same groups for a folded width off the 128-lane grid, where
     Mosaic refuses to slice a block out of the pool for a DMA (the ref's
     last dimension is padded to lanes, and a slice must be a multiple of
     128 of them): the groups are a grid axis, a group's ``G`` blocks come in
     through ``G`` BlockSpecs (``_clamped_block_index``), and a step past the
-    slot's last group skips its body and re-fetches nothing."""
+    slot's last group skips its body and re-fetches nothing. A tile of pad
+    queries (``queries_ref``) has no live block: every step of it skips the
+    body, and its accumulators finalize to zeros."""
     G, bt, T = group_blocks, block_tokens, q_tile
     k_refs, v_refs = rest[:G], rest[G:2 * G]
     o_ref = rest[2 * G]
     m_scr, l_scr, acc_scr = rest[2 * G + 1:]
     s, i, j = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     ctx = lengths_ref[s] + i * T
-    last_blk = _tile_last_block(lengths_ref, s, i, T, total, bt, nb_seq)
+    last_blk = _tile_last_block(lengths_ref, s, i, T, total, bt, nb_seq,
+                                queries_ref=queries_ref)
 
     @pl.when(j == 0)
     def _init():
@@ -697,7 +776,8 @@ def _paged_kernel_unaligned(
 
     @pl.when(j == pl.num_programs(2) - 1)
     def _done():
-        _finalize(o_ref, l_scr, acc_scr, kv_heads)
+        _finalize(o_ref, l_scr, acc_scr, kv_heads,
+                  _tile_real_queries(queries_ref, s, i, T, total))
 
 
 def paged_attention(
@@ -712,6 +792,7 @@ def paged_attention(
     interpret: bool = False,
     window: Optional[int] = None,
     sinks: Optional[jax.Array] = None,
+    queries: Optional[jax.Array] = None,     # [S] int32: real queries a slot
 ) -> jax.Array:
     """Fused paged-attention over the block pool; returns [S, T, H, Dv].
 
@@ -744,7 +825,15 @@ def paged_attention(
     head that enters the softmax's denominator and carries no value, ``p_ij
     = exp(s_ij) / (exp(sinks[h]) + sum_j' exp(s_ij'))`` (not scaled by
     ``scale``). Both take the walk over whole 128-lane rows, of both pools;
-    a call that passes neither traces the body it always did."""
+    a call that passes neither traces the body it always did.
+
+    ``queries`` [S]: of slot ``s``'s ``T`` query rows the first
+    ``queries[s]`` are real (a prefill hands in its prompt's length in the
+    bucket). The walk then follows the real rows alone: a query tile with
+    none of them starts no copy and attends nothing, under a window too, and
+    every pad row of the result is zeros; the real rows are what the call
+    without the operand gives, bit for bit. A call without it (a decode step,
+    a verify: every row real) traces the body it always did."""
     S, T, H, D = q.shape
     if window is not None and (window < 1 or k_pool.shape[3] % 128):
         raise ValueError(
@@ -772,9 +861,21 @@ def paged_attention(
     return _paged_attention(
         q, k_pool, v_pool, tables.astype(jnp.int32),
         lengths.astype(jnp.int32), jnp.asarray(layer, jnp.int32).reshape(1),
-        sinks=sinks,
+        sinks=sinks, queries=_real_query_counts(queries, S),
         scale=float(scale) if scale is not None else 1.0 / D**0.5,
         interpret=interpret, window=None if window is None else int(window))
+
+
+def _real_query_counts(queries, slots: int):
+    """``queries`` as the kernels' fourth prefetched operand, int32 ``[S]``
+    (a prefill's one slot may hand in a scalar), or None."""
+    if queries is None:
+        return None
+    queries = jnp.asarray(queries, jnp.int32).reshape(-1)
+    if queries.shape != (slots,):
+        raise ValueError(f"queries {queries.shape}: want one count a slot, "
+                         f"[{slots}]")
+    return queries
 
 
 # The appending call holds every slot's new K and V row in VMEM for the whole
@@ -851,14 +952,16 @@ _Q_BLOCK_VMEM_BYTES = 4 << 20
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret", "window"))
 def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None,
-                     sinks=None, *, scale, interpret, window=None):
+                     sinks=None, queries=None, *, scale, interpret,
+                     window=None):
     """:func:`paged_attention` on checked operands, ``layer`` an int32[1]
     VALUE: a jit of its own, so that a program that calls it once a layer
     (24 unrolled layers, eight serve programs) traces and lowers the kernel
     once and calls it 24 times; XLA inlines the calls. ``rows``: the new
     ``(k_row, v_row)`` of :func:`paged_attention_append`, whose kernel and
     results (the pools beside the output) these are then. ``sinks``
-    [H, 1] float32 or None."""
+    [H, 1] float32 or None. ``queries`` int32 [S] or None: a fourth
+    prefetched operand and the kernel that reads it (``_counted``)."""
     S, T, H, D = q.shape
     bt = k_pool.shape[2]
     W = k_pool.shape[3]                               # a row: KV heads x D
@@ -878,8 +981,9 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None,
         tq //= 2
     q_tiles = pl.cdiv(T, tq)
     if T % tq:
-        # Ragged last tile: pad queries are causally AHEAD of every real one
-        # and their rows are sliced off below.
+        # Ragged last tile: its pad queries are causally AHEAD of every real
+        # one, the walk is bounded by the real ones (``total``, and the
+        # caller's ``queries`` below it) and their rows are sliced off below.
         qt = jnp.pad(qt, ((0, 0), (0, 0), (0, q_tiles * tq - T), (0, 0)))
 
     C = _heads_per_chunk(KV, R * tq, D, Dv)
@@ -957,8 +1061,11 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None,
         # one block, the same at every grid step: fetched once
         kernel = _paged_sink_kernel
         extra = [pl.BlockSpec((H, 1), lambda s, i, *_: (0, 0))]
+    counts = [] if queries is None else [queries]
+    if counts:
+        kernel = _counted(kernel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(counts),
         grid=grid,
         in_specs=[q_spec] + kv_specs + extra,
         out_specs=out_spec,
@@ -970,7 +1077,8 @@ def _paged_attention(q, k_pool, v_pool, tables, lengths, layer, rows=None,
         out_shape=out_shape,
         interpret=interpret,
         name=name,
-    )(tables, lengths, layer, qw, *pools, *([] if sinks is None else [sinks]))
+    )(tables, lengths, layer, *counts, qw, *pools,
+      *([] if sinks is None else [sinks]))
     return out[:, :, :T].transpose(0, 2, 1, 3)        # [S, T, H, Dv]
 
 
@@ -1078,6 +1186,7 @@ def _latent_kernel(
     num_heads: int,
     value_lanes: int,
     keep_ref=None,             # [n_groups, T, G*bt] block: see _keep_rows
+    queries_ref=None,          # scalar prefetch [S] (``_counted``)
     **walk,                    # _walk_live_groups' static arguments
 ):
     H, T = num_heads, walk["q_tile"]
@@ -1110,13 +1219,16 @@ def _latent_kernel(
         acc_scr[:] = acc_scr[:] * alpha + pv
         m_scr[:] = m_new
 
-    def finalize():
-        o_ref[0] = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(
-            o_ref.dtype)
+    def finalize(real):
+        out = (acc_scr[:] / jnp.maximum(l_scr[:], 1e-30)).astype(o_ref.dtype)
+        if real is not None:                          # rows are (query, head)
+            out = _real_rows(out, real, lambda row: jax.lax.div(row, H))
+        o_ref[0] = out
 
     _walk_live_groups(
         tables_ref, lengths_ref, layer_ref, (pool_hbm,), (kv_buf,), sems,
-        half_ref, (m_scr, l_scr, acc_scr), attend, finalize, o_ref, **walk)
+        half_ref, (m_scr, l_scr, acc_scr), attend, finalize, o_ref,
+        queries_ref=queries_ref, **walk)
 
 
 def _keep_rows(keep, num_heads: int):
@@ -1158,6 +1270,7 @@ def latent_paged_attention(
     scale: float,
     interpret: bool = False,
     keep: Optional[jax.Array] = None,    # [S, T, NB * bt] bool
+    queries: Optional[jax.Array] = None,     # [S] int32: real queries a slot
 ) -> jax.Array:
     """Softmax over the latent rows, returns ``sum_j p_j row_j[:value_lanes]``
     as [S, T, H, value_lanes]: the caller up-projects it per head. Query t of
@@ -1166,7 +1279,9 @@ def latent_paged_attention(
     trash block 0 is parked: not walked, its rows zeros (as
     :func:`paged_attention`). ``keep``: query t of slot s attends position j
     only if ``keep[s, t, j]`` as well (a learned selection,
-    ``ops/sparse_select.py``); the walk still fetches every live block."""
+    ``ops/sparse_select.py``); the walk still fetches every live block.
+    ``queries``: as :func:`paged_attention`'s, the walk follows the slot's
+    first ``queries[s]`` query rows alone and the others read zeros."""
     W = q.shape[3]
     if pool.ndim != 4 or pool.shape[3] != W or W % 128:
         raise ValueError(
@@ -1178,14 +1293,16 @@ def latent_paged_attention(
             *q.shape[:2], tables.shape[1] * pool.shape[2]):
         raise ValueError(f"keep {keep.shape} is not [slots, queries, table "
                          f"entries x block tokens]")
-    return _latent_attention(*operands, keep, value_lanes=value_lanes,
-                             scale=float(scale), interpret=interpret)
+    return _latent_attention(*operands, keep,
+                             _real_query_counts(queries, q.shape[0]),
+                             value_lanes=value_lanes, scale=float(scale),
+                             interpret=interpret)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("value_lanes", "scale", "interpret"))
-def _latent_attention(q, pool, tables, lengths, layer, keep=None, *,
-                      value_lanes, scale, interpret):
+def _latent_attention(q, pool, tables, lengths, layer, keep=None,
+                      queries=None, *, value_lanes, scale, interpret):
     """:func:`latent_paged_attention` on checked operands: a jit of its own,
     as :func:`_paged_attention` is and for its reason (a serve program calls
     it once a sublayer, and there are ten of them a family)."""
@@ -1194,8 +1311,8 @@ def _latent_attention(q, pool, tables, lengths, layer, keep=None, *,
     tq = min(T, _LATENT_Q_TILE)
     q_tiles = pl.cdiv(T, tq)
     if T % tq:
-        # Ragged last tile: pad queries are causally AHEAD of every real one
-        # and their rows are sliced off below.
+        # Ragged last tile: as ``_paged_attention``'s, bounded by its real
+        # queries and sliced off below.
         q = jnp.pad(q, ((0, 0), (0, q_tiles * tq - T), (0, 0), (0, 0)))
     qr = q.reshape(S, q_tiles, tq * H, W)
     G = _blocks_per_group(bt, W, pool.dtype.itemsize,
@@ -1219,8 +1336,11 @@ def _latent_attention(q, pool, tables, lengths, layer, keep=None, *,
         keep_specs = [pl.BlockSpec((None, None, n_groups, tq, tokens),
                                    lambda s, i, *_: (s, i, 0, 0, 0))]
 
+    counts = [] if queries is None else [queries]
+    if counts:
+        kernel = _counted(kernel)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=3 + len(counts),
         grid=(S, q_tiles),
         in_specs=[pl.BlockSpec((None, 1, tq * H, W), q_index), *keep_specs,
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -1244,7 +1364,7 @@ def _latent_attention(q, pool, tables, lengths, layer, keep=None, *,
                                        q.dtype),
         interpret=interpret,
         name="mla_decode_attn" if T == 1 else "mla_prefill_attn",
-    )(tables, lengths, layer, qr, *keep_ops, pool)
+    )(tables, lengths, layer, *counts, qr, *keep_ops, pool)
     return out.reshape(S, q_tiles * tq, H, value_lanes)[:, :T]
 
 

@@ -553,7 +553,8 @@ class LLMEngine:
             "admit_stopped_budget_total", "admit_stopped_queue_empty_total",
             "admit_stopped_no_slot_total", "admit_stopped_no_blocks_total",
             "admit_starved_total", "admit_blocked_pool_s",
-            "stream_pickups_total", "stream_pickup_lag_s"), 0)
+            "stream_pickups_total", "stream_pickup_lag_s",
+            "prefill_rows_total", "prefill_pad_rows_total"), 0)
         # The end of the last step if the engine still held a request then
         # (_holds_request_locked), else None: the next step charges the
         # stretch up to its start to step_handoff_s. Written under
@@ -1334,6 +1335,8 @@ class LLMEngine:
         # Counted per admission, together: a stats() from another thread
         # never reads one without the other.
         with self._agg_lock:
+            self._counts["prefill_rows_total"] += req.bucket
+            self._counts["prefill_pad_rows_total"] += req.bucket - suffix_len
             if self._slot_state:    # the prefill wrote the slot's from zero
                 self._state_counts["state_resets_total"] += 1
             if not self._prefix_cache:
@@ -1464,6 +1467,10 @@ class LLMEngine:
         # ``admit_stopped_{queue_empty,no_slot,budget,no_blocks}_total``;
         # ``admit_starved_total`` counts the steps that dispatched a decode
         # with a slot empty and the queue empty: the clients were elsewhere.
+        # ``prefill_rows_total`` counts the rows the prefill programs were
+        # handed (an admission's suffix BUCKET), ``prefill_pad_rows_total``
+        # those of them behind the suffix's last token: rows the products
+        # still multiply and the attention kernels no longer walk for.
         # The first hand-over of a token's way back to its client:
         # ``stream_pickups_total`` counts the consumers' takes of a
         # non-empty ``req.tokens`` in ``drive``, ``stream_pickup_lag_s`` sums

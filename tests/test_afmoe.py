@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 import engine_contract
+import half_filled_bucket
 from benchmark.manifest import load_file
 from ray_tpu.models import afmoe
 from ray_tpu.models.generate import PagedGenerator
@@ -140,6 +141,17 @@ def test_paged_prefill_and_decode_match_the_reference(model, kernel):
     for before, after in zip(parked, state):
         np.testing.assert_array_equal(np.asarray(after[:, 0]), before)
     assert np.asarray(state[0][:, 1]).any()
+
+
+def test_a_half_filled_bucket_walks_for_its_real_rows(model):
+    """100 tokens in the 256 bucket, two query tiles of the attention kernel:
+    the first straddles the prompt's end, the second is pad rows alone and is
+    skipped; the table behind the prompt's blocks is the trash block. The last
+    real row's logits are the reference's."""
+    cfg, params = model
+    seq = [int(t) for t in np.random.default_rng(7).integers(1, 200, 100)]
+    np.testing.assert_allclose(half_filled_bucket.last_row(params, cfg, seq, 256),
+                               ref_logits(model, seq)[99], atol=TOL)
 
 
 def test_a_short_prompt_under_the_window(model):

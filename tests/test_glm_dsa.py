@@ -30,6 +30,7 @@ import numpy as np
 import pytest
 
 import engine_contract
+import half_filled_bucket
 from benchmark.manifest import load_file
 from ray_tpu.models import glm_dsa, kimi_k2
 from ray_tpu.models.generate import PagedGenerator
@@ -144,6 +145,17 @@ def test_paged_prefill_and_decode_match_the_reference(model, kernel):
         assert was.any()
         np.testing.assert_array_equal(np.asarray(p[:, 7]), was)
         np.testing.assert_array_equal(np.asarray(p[:, 3]), was)
+
+
+def test_a_half_filled_bucket_walks_for_its_real_rows(model):
+    """27 tokens in the 64 bucket, four query tiles of the latent kernel: one
+    whole, one that straddles the prompt's end, two of pad rows alone that
+    are skipped; the table behind the prompt's blocks is the trash block. The
+    last real row's logits are the reference's."""
+    cfg, params = model
+    seq = [int(t) for t in np.random.default_rng(7).integers(1, 200, 27)]
+    np.testing.assert_allclose(half_filled_bucket.last_row(params, cfg, seq, 64),
+                               ref_logits(model, seq)[26], atol=TOL)
 
 
 def test_a_selection_that_keeps_everything_is_the_dense_layer(model):

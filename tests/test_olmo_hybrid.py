@@ -26,6 +26,7 @@ import numpy as np
 import pytest
 
 import engine_contract
+import half_filled_bucket
 from benchmark.manifest import load_file
 from ray_tpu.models import longcat, olmo_hybrid, transformer
 from ray_tpu.models.generate import PagedGenerator
@@ -115,6 +116,17 @@ def test_paged_prefill_and_decode_match_the_reference(model, kernel):
     # the parked slot's state never moved from zero
     assert not np.asarray(state[0][:, 1]).any()
     assert not np.asarray(state[1][:, :, 1]).any()
+
+
+def test_a_half_filled_bucket_walks_for_its_real_rows(model):
+    """100 tokens in the 256 bucket, two query tiles of the attention kernel:
+    the first straddles the prompt's end, the second is pad rows alone and is
+    skipped; the table behind the prompt's blocks is the trash block. The last
+    real row's logits are the reference's."""
+    cfg, params = model
+    seq = [int(t) for t in np.random.default_rng(7).integers(1, 200, 100)]
+    np.testing.assert_allclose(half_filled_bucket.last_row(params, cfg, seq, 256),
+                               ref_logits(model, seq)[99], atol=TOL)
 
 
 def test_the_decay_init_gives_the_state_a_memory(model):

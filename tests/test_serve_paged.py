@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 import engine_contract
+import half_filled_bucket
 import ray_tpu
 from ray_tpu.models import generate, transformer
 from ray_tpu.serve.handle import DeploymentHandle, Router
@@ -113,6 +114,30 @@ class TestPagedOracleEquivalence:
         # Chain 28 tokens: full-block hit 24, capped tail walk adds ≤ bt-1;
         # at minimum both full blocks of the prompt hit.
         assert h1 >= 2 * BT
+
+    @pytest.mark.parametrize("length,bucket", [(3, 16), (16, 16), (25, 32)])
+    def test_prefill_rows_and_pad_rows_are_counted(self, paged, length,
+                                                   bucket):
+        """An admission adds its suffix BUCKET to ``prefill_rows_total`` and
+        the bucket less the suffix to ``prefill_pad_rows_total``; a repeat
+        of the prompt hits its own chain and adds its SUFFIX's bucket."""
+        p = [230 + length] * length
+        before = paged.stats()
+        _out, hit = _hit_delta(paged, p, 2)
+        cold = paged.stats()
+        assert hit == 0
+        assert cold["prefill_rows_total"] - before["prefill_rows_total"] == bucket
+        assert (cold["prefill_pad_rows_total"]
+                - before["prefill_pad_rows_total"]) == bucket - length
+        _out, hit = _hit_delta(paged, p, 2)
+        warm = paged.stats()
+        if length >= BT:
+            assert 0 < hit < length
+        suffix = length - hit
+        rows = warm["prefill_rows_total"] - cold["prefill_rows_total"]
+        assert rows == paged._suffix_bucket(suffix)
+        assert (warm["prefill_pad_rows_total"]
+                - cold["prefill_pad_rows_total"]) == rows - suffix
 
     def test_sampled_matches_oracle(self, paged, oracle):
         p = PROMPTS[1]
@@ -485,6 +510,22 @@ def test_engine_contract_with_the_appending_kernel(lane_model, check):
     kw = dict(engine_contract.ENGINE_KW, attention_kernel="interpret")
     for prompt, toks in check(params, cfg, kw):
         assert toks == lane_oracle(prompt, len(toks))
+
+
+@pytest.mark.parametrize("d_model", [128, 64])
+def test_a_half_filled_bucket_walks_for_its_real_rows(d_model):
+    """100 tokens in the 256 bucket, two query tiles of the attention kernel
+    (over whole lane tiles and, 64 lanes wide, with the groups on the grid):
+    the first straddles the prompt's end, the second is pad rows alone; the
+    table behind the prompt's blocks is the trash block. The last real row's
+    logits are the gather path's, which attends every row."""
+    cfg = transformer.tiny(d_model=d_model, max_seq_len=256)
+    params = transformer.init_params(cfg, jax.random.key(0))
+    seq = [int(t) for t in np.random.default_rng(7).integers(1, 200, 100)]
+    got, want = (half_filled_bucket.last_row(params, cfg, seq, 256, kernel=k)
+                 for k in ("interpret", "gather"))
+    np.testing.assert_allclose(got, want, atol=2e-4)
+    assert np.abs(want).max() > 0.1
 
 
 # -- the look-ahead: one decode chunk queued behind the one that runs ---------

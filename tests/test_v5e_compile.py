@@ -445,6 +445,20 @@ def pallas_grids(jaxpr) -> list:
     return found
 
 
+def pallas_prefetched(jaxpr) -> dict:
+    """{kernel name: the counts of scalar-prefetch operands its
+    ``pallas_call``s take, sorted and distinct}, nested calls included."""
+    found = {}
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            found.setdefault(eqn.params["name"], set()).add(
+                eqn.params["grid_mapping"].num_index_operands)
+        for inner in _inner_jaxprs(eqn):
+            for name, counts in pallas_prefetched(inner).items():
+                found.setdefault(name, set()).update(counts)
+    return {name: sorted(counts) for name, counts in found.items()}
+
+
 def pallas_products(jaxpr, prefix: str) -> dict:
     """{kernel name: [[lhs dtype, rhs dtype, output dtype], ...]}: every
     ``dot_general`` inside the ``pallas_call``s whose name starts with
@@ -490,6 +504,7 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
     {"programs": {name: "ok" | error},
     "kernels": {name: [[instruction name, first output shape], ...]},
     "grids": {name: pallas_grids() of the traced program},
+    "prefetched": {name: pallas_prefetched() of the traced program},
     "scoped_vmem": {name: bytes of scoped VMEM each Pallas call takes},
     "pool_movers": {serve program: pool_shaped_data_movers() of it},
     "temp_bytes": {serve program: temporaries the compiler reports},
@@ -548,7 +563,7 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
     state_roundings, weight_movers, shared_expert_ops = {}, {}, {}
     latent_calls, latent_vmem, flash_products, flash_movers = {}, {}, {}, {}
     pair_rows, pool_writers, pool_aliases = {}, {}, {}
-    window_operands = {}
+    window_operands, prefetched = {}, {}
     with open(os.path.join(REPO, "benchmark", "metrics",
                            "shared_expert_ms_per_step.batch.json")) as f:
         shared_pattern = json.load(f)["pattern"]
@@ -572,6 +587,7 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
         try:
             traced = trace()
             grids[name] = pallas_grids(traced.jaxpr.jaxpr)
+            prefetched[name] = pallas_prefetched(traced.jaxpr.jaxpr)
             flash_products[name] = pallas_products(traced.jaxpr.jaxpr,
                                                    "flash_")
             bodies = jit_calls(traced.jaxpr.jaxpr, "_latent_attention")
@@ -1022,6 +1038,7 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
             placed(p, b.param_shardings), placed(o, b.opt_shardings),
             {"tokens": arr((16, ctx), jnp.int32, b.batch_sharding)}))
     return {"programs": programs, "kernels": kernels, "grids": grids,
+            "prefetched": prefetched,
             "scoped_vmem": scoped_vmem, "pool_movers": pool_movers,
             "temp_bytes": temp_bytes, "need_bytes": need_bytes,
             "state_movers": state_movers, "weight_movers": weight_movers,
@@ -1258,6 +1275,68 @@ def test_the_other_families_decode_programs_are_what_they_were(
     assert calls and not any(calls), calls
     if multiset is not None:
         assert verdict["multisets"][program] == multiset
+
+
+# instruction_multiset() of the other compiled decode programs on PR 53's
+# tree (b3bda2d), taken the same way: PR 54 gave every family's PREFILL one
+# operand more for its attention kernels (the count of real rows) and meant to
+# leave every decode program, which passes none, the program it was.
+DECODE_PROGRAMS = {
+    "serve_decode_medium_1281": [1644, "966b7115aef33b1a"],
+    "serve_decode_medium_1537": [1644, "6a767aef989299b6"],
+    "serve_decode_medium_2049": [1644, "5051d29f15557ee9"],
+    "serve_decode_xl_1281": [1816, "dfdbbaf5a8386844"],
+    "serve_decode_xl_1537": [1816, "958acc2688ad5774"],
+    "serve_decode_xl_2049": [1816, "18d2878595530d39"],
+    "longcat_decode": [7335, "409f8a2cd98666fd"],
+    "kimi_decode": [7594, "de36f56732c0b40e"],
+    "mimo_decode": [6626, "1d24984d144679e6"],
+    "glm_decode": [7250, "065c359f4e3468bd"],
+    "paged_decode": [30, "72079d8e70b19d47"],
+    "paged_verify": [29, "64f8a62669735346"],
+}
+
+
+@pytest.mark.parametrize("program", sorted(DECODE_PROGRAMS))
+def test_a_decode_program_is_the_one_it_was_before_prefills_were_counted(
+        verdict, program):
+    """A decode step and a verify pass no count of real rows (every row is
+    real): their attention kernels take the three prefetched operands they
+    took, and the compiled program's instructions are PR 53's. A PR that
+    changes one of these programs on purpose takes a new pair."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    assert verdict["multisets"][program] == DECODE_PROGRAMS[program]
+    counts = {kernel: n for kernel, n in verdict["prefetched"][program].items()
+              if kernel.endswith("_attn")}
+    assert counts and all(n == [3] for n in counts.values()), counts
+
+
+@pytest.mark.parametrize("program,kernels", [
+    ("serve_prefill_medium_1281", ["paged_prefill_attn"]),
+    ("serve_prefill_xl_1281", ["paged_prefill_attn"]),      # groups on the grid
+    ("longcat_prefill_1024", ["mla_prefill_attn"]),
+    (f"kimi_prefill_{KIMI_BUCKETS[-1]}", ["mla_prefill_attn"]),
+    (f"glm_prefill_{GLM_BUCKET}", ["mla_prefill_attn"]),    # with keep bits
+    (f"olmo_prefill_{OLMO_BUCKET}", ["paged_prefill_attn"]),
+    (f"falcon_prefill_{FALCON_BUCKET}", ["paged_prefill_attn"]),
+    (f"nemotron_prefill_{NEMOTRON_BUCKET}", ["paged_prefill_attn"]),
+    (f"trinity_prefill_{TRINITY_BUCKET}",
+     ["paged_prefill_attn", "window_prefill_attn"]),        # the ring's walk too
+    (f"mimo_prefill_{MIMO_BUCKET}",
+     ["paged_prefill_attn", "window_prefill_attn"]),        # sinks, a narrow V
+])
+def test_a_prefill_hands_its_attention_kernels_the_count_of_real_rows(
+        verdict, program, kernels):
+    """Every family's prefill program compiles for a v5e with the count as a
+    FOURTH scalar-prefetch operand of each of its attention kernels
+    (``paged_attention(queries=)``), under the names they had."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    counts = {kernel: n for kernel, n in verdict["prefetched"][program].items()
+              if kernel.endswith("_attn")}
+    assert counts == {kernel: [4] for kernel in kernels}, counts
+    # the kernel alone, as a caller without a count traces it: three
+    assert verdict["prefetched"]["paged_prefill_1024"] == {
+        "paged_prefill_attn": [3]}
 
 
 def test_the_pool_write_scan_sees_a_scatter_and_an_aliased_kernel():
