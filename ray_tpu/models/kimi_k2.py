@@ -262,20 +262,26 @@ def describe(config: KimiK2Config) -> Dict[str, int]:
 # Forward over the paged latent pool
 # ---------------------------------------------------------------------------
 
-def expert_layer(lp, x, valid, c: KimiK2Config):
+def expert_layer(lp, x, valid, c):
     """``sum_{i in P, held} w_i E_i(u) + E_shared(u)`` on ``x`` [S, T, D]:
     (out, pick counts). Tokens not ``valid`` route to no expert; the shared
-    expert's products run over every row (a dead row's result is dead)."""
+    expert's products run over every row (a dead row's result is dead).
+    ``c`` is the config of any family whose experts are gated (``lfm2``
+    calls this too): it names ``num_experts_per_tok``,
+    ``routed_scaling_factor``, ``n_routed_experts``, ``held`` and ``dtype``;
+    a layer without ``"shared"`` has no shared expert."""
     S, T, D = x.shape
     flat = x.reshape(S * T, D)
-    idx, w = moe.route_topk(
-        flat, lp["router"], lp["router_bias"], topk=c.num_experts_per_tok,
-        scale=c.routed_scaling_factor, score="sigmoid", renormalise=True)
+    with jax.named_scope("moe_router"):
+        idx, w = moe.route_topk(
+            flat, lp["router"], lp["router_bias"], topk=c.num_experts_per_tok,
+            scale=c.routed_scaling_factor, score="sigmoid", renormalise=True)
     out, counts = moe.held_experts_ffn(
         flat, idx, w, lp["experts"]["w_gate_up"], lp["experts"]["w_down"],
         held=c.held, n_routed=c.n_routed_experts, valid=valid.reshape(S * T))
-    with jax.named_scope("moe_shared"):
-        out = out + gated_ffn(lp["shared"], flat, c.dtype)
+    if "shared" in lp:
+        with jax.named_scope("moe_shared"):
+            out = out + gated_ffn(lp["shared"], flat, c.dtype)
     return out.reshape(S, T, D), counts
 
 

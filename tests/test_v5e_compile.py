@@ -98,6 +98,15 @@ DSA_PREFILL_METRICS = ("dsa_index_ms_per_prefill.batch",
 # (max_seq_len: the largest program the warm-up compiles).
 NEMOTRON_SLOTS, NEMOTRON_POOL, NEMOTRON_BUCKET = 128, 16513, 2176
 
+# LFM2-8B-A1B at the sizes of the cell lfm2-8b-a1b.conv-decode: published
+# widths, the first 10 layers (8 convolution, 2 attention; 2 dense, 8 expert
+# layers of all 32 experts), 128 slots, pool 16513 x 16 for the TWO
+# attention layers, the 2176 bucket (max_seq_len: the largest program the
+# warm-up compiles).
+LFM2_SLOTS, LFM2_POOL, LFM2_BUCKET = 128, 16513, 2176
+# The per-layer metrics that find the family's fusions by their output shapes.
+LFM2_METRICS = ("lfm2_expert_ffn_roofline", "short_conv_roofline")
+
 # The fixture's children: one compiled the 52 programs in 371 s alone and 538
 # beside five busy workers, of a limit of 700.
 COMPILE_CHILDREN = 4
@@ -520,6 +529,8 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
     ``expert_capacity_ffn_roofline``},
     "dsa_ops": {GLM-5 serve program: {metric of DSA_METRICS: named_ops() of
     its pattern}},
+    "lfm2_ops": {LFM2 decode: {metric of LFM2_METRICS: named_ops() of its
+    pattern}},
     "multisets": {name: instruction_multiset() of the compiled program},
     "latent_calls": {name: [calls of the latent kernel's jit, distinct
     traced bodies among them]},
@@ -577,11 +588,16 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
         with open(os.path.join(REPO, "benchmark", "metrics",
                                f"{metric}.json")) as f:
             dsa_patterns[metric] = json.load(f)["pattern"]
+    lfm2_patterns, lfm2_ops = {}, {}
+    for metric in LFM2_METRICS:
+        with open(os.path.join(REPO, "benchmark", "metrics",
+                               f"{metric}.json")) as f:
+            lfm2_patterns[metric] = json.load(f)["pattern"]
     place = itertools.count()
 
     def attempt(name, trace, pool=None, state=None, weights=None,
                 shared=False, state_kernel="gdn_decode", pairs=None,
-                capacity=False, dsa=False):
+                capacity=False, dsa=False, lfm2_fusions=False):
         if next(place) % of != share:
             return
         try:
@@ -623,6 +639,9 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
             if dsa:
                 dsa_ops[name] = {metric: named_ops(text, pattern)
                                  for metric, pattern in dsa_patterns.items()}
+            if lfm2_fusions:
+                lfm2_ops[name] = {metric: named_ops(text, pattern)
+                                  for metric, pattern in lfm2_patterns.items()}
             if pairs is not None:
                 pair_rows[name] = pair_row_arrays(text, *pairs)
             multisets[name] = instruction_multiset(text)
@@ -1017,6 +1036,43 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
             pool=n_geometry, state=n_state, weights=shapes_of(nparams),
             state_kernel="ssd_decode")
 
+    # LFM2's serve programs whole, at the cell's own sizes: the attention
+    # kernel called by the two attention layers alone on a pool of its own
+    # depth (32 query heads of 64 over a row of 8 KV heads), the convolution
+    # layers' tails the only slot state and written where they lie, the
+    # experts' capacity form WALKED in the decode program too (512 pairs > 8
+    # x 32 experts); no weight re-laid on a call, and the bytes the chip must
+    # hold (3.20B bf16 parameters, a 1.08 GB pool, 8.4 MB of tails).
+    from ray_tpu.models import lfm2
+
+    fcfg = lfm2.lfm2_8b_a1b_stage()
+    fparams = jax.tree.map(
+        lambda x: arr(x.shape, x.dtype),
+        jax.eval_shape(lambda key: lfm2.init_params(fcfg, key),
+                       jax.random.key(0)))
+    fgen = PagedGenerator(fparams, fcfg, slots=LFM2_SLOTS,
+                          num_blocks=LFM2_POOL, block_tokens=bt,
+                          attention_kernel="pallas")
+    fkv = arr((fcfg.n_layers, LFM2_POOL, bt, fcfg.n_kv_heads * fcfg.head_dim))
+    fslot = tuple(arr(x.shape, x.dtype) for x in jax.eval_shape(
+        lambda: lfm2.init_slot_state(fcfg, LFM2_SLOTS)))
+    fstate = (fparams, (fkv, fkv), fslot,
+              arr((LFM2_SLOTS, fgen.logits_dim), jnp.float32),
+              arr((LFM2_SLOTS, 2), jnp.uint32))
+    f_slot = lambda dtype: arr((LFM2_SLOTS,), dtype)  # noqa: E731
+    f_geometry = (fcfg.n_layers, LFM2_POOL, bt)
+    attempt("lfm2_decode",
+            lambda: fgen.decode_fn(8).trace(
+                *fstate, arr((LFM2_SLOTS, fgen.blocks_per_seq), jnp.int32),
+                f_slot(jnp.int32), f_slot(jnp.bool_), f_slot(jnp.bool_),
+                f_slot(jnp.float32)), pool=f_geometry,
+            weights=shapes_of(fparams), lfm2_fusions=True)
+    attempt(f"lfm2_prefill_{LFM2_BUCKET}",
+            lambda: fgen.prefill_fn(LFM2_BUCKET).trace(
+                *fstate, arr((fgen.blocks_per_seq,), jnp.int32),
+                arr((1, LFM2_BUCKET), jnp.int32), i32, i32, i32, i32),
+            pool=f_geometry, weights=shapes_of(fparams))
+
     rules = ShardingRules()
     optimizer = optax.adamw(3e-4, weight_decay=0.1)
     for name, spec in [("train_step_data4", MeshSpec(data=4)),
@@ -1045,7 +1101,7 @@ def compile_all(share: int = 0, of: int = 1) -> dict:
             "state_roundings": state_roundings,
             "shared_expert_ops": shared_expert_ops,
             "capacity_ops": capacity_ops, "dsa_ops": dsa_ops,
-            "multisets": multisets,
+            "lfm2_ops": lfm2_ops, "multisets": multisets,
             "latent_calls": latent_calls, "latent_vmem": latent_vmem,
             "flash_products": flash_products, "flash_movers": flash_movers,
             "pair_rows": pair_rows, "pool_writes": pool_writers,
@@ -1696,6 +1752,76 @@ def test_nemotron_h_serve_programs_fit_the_chip(verdict, program, kernels,
         assert calls == 6 and roundings >= 6 * calls, (calls, roundings)
         grids = verdict["grids"][program]
         assert grids.count([128, 8]) == 6 and grids.count([128, 1]) == 2, grids
+
+
+@pytest.mark.parametrize("program,kernels,need", [
+    ("lfm2_decode", {"paged_decode_attn": "bf16[128,32,1,64]"},
+     (7.4e9, 7.65e9)),
+    ("lfm2_prefill_2176", {"paged_prefill_attn": "bf16[1,32,2176,64]"},
+     (7.5e9, 7.9e9))])
+def test_lfm2_serve_programs_fit_the_chip(verdict, program, kernels, need):
+    """LFM2's ``paged_decode`` and its largest ``paged_prefill`` at the sizes
+    of ``lfm2-8b-a1b.conv-decode``: they compile for a v5e and arguments plus
+    temporaries leave room for the check's float32 pass (0.54 GB of logits)
+    beside them. The attention kernel is the ONLY Pallas call, twice on
+    a pool of TWO layers with all 32 query heads of 64 in its output over a
+    row of the eight KV heads; there is no state kernel and no grouped
+    product: the experts' capacity form is walked in passes in BOTH programs
+    (64 rows an expert in the decode step, 256 in the bucket). No
+    instruction copies or slices data the size of the pool or of a weight
+    (``W_q`` is stored a head first for that; the tails are 11.5 MB and a
+    layer reads its own megabyte of them: not scanned); of the programs'
+    Pallas calls the benchmark's ``paged_attn_roofline`` pattern matches the
+    decode kernel ALONE."""
+    assert verdict["programs"][program] == "ok", verdict["programs"][program]
+    # weights 6.39 GB + pool 1.08 GB + tails 0.01 GB + last 0.03 GB;
+    # temporaries 0.03 GB (decode), 0.2 GB (the bucket)
+    assert need[0] < verdict["need_bytes"][program] < need[1], verdict["need_bytes"]
+    assert dict(verdict["kernels"][program]) == kernels, verdict["kernels"][program]
+    assert verdict["pool_movers"][program] == []
+    assert verdict["weight_movers"][program] == []
+    assert all(0 <= v < V5E_SCOPED_VMEM
+               for v in verdict["scoped_vmem"][program])
+    names = [f"{name}:custom-call:{shape}"
+             for name, shape in verdict["kernels"][program]]
+    decode = program == "lfm2_decode"
+    assert [n.split(":")[0] for n in names
+            if re.search(PAGED_ATTN_PATTERN, n)] == (
+        ["paged_decode_attn"] if decode else [])
+    assert verdict["temp_bytes"][program] < 0.5e9, verdict["temp_bytes"]
+    if decode:
+        assert verdict["grids"][program].count([128, 1]) == 2, verdict["grids"]
+        assert verdict["prefetched"][program] == {"paged_decode_attn": [3]}
+    else:
+        assert verdict["prefetched"][program] == {"paged_prefill_attn": [4]}
+
+
+def test_lfm2s_patterns_match_their_fusions_alone(verdict):
+    """``lfm2_expert_ffn_*`` and ``short_conv_*`` find the family's fusions
+    by their output shapes: in the compiled decode program the experts'
+    pattern matches THREE fusions an expert layer (the gate-and-up product,
+    the activation, the down product: 24), all under the ``moe_experts``
+    scope inside the walk's loop, and the mixer's FIVE fusions a convolution
+    layer (the in-projection, the taps with the gate, the new row, the
+    tail's slice and its write: 40), all under ``short_conv``; neither
+    matches anything else, and the out-projection (the residual's shape) is
+    in neither."""
+    ops = verdict["lfm2_ops"]["lfm2_decode"]
+    experts = ops["lfm2_expert_ffn_roofline"]
+    assert len(experts) == 3 * 8, experts
+    assert {name for name, _scope in experts} == {
+        "fusion:fusion:f32[32,64,3584]", "fusion:fusion:bf16[2048,1792]",
+        "fusion:fusion:f32[32,64,2048]"}
+    assert all("/moe_experts/" in scope and "/while/" in scope
+               for _name, scope in experts), experts
+    mixer = ops["short_conv_roofline"]
+    assert len(mixer) == 5 * 8, mixer
+    assert {name for name, _scope in mixer} == {
+        "fusion:fusion:f32[128,6144]", "fusion:fusion:bf16[1,128,2048]",
+        "fusion:fusion:bf16[8,2,128,2048]",
+        "multiply_reduce_fusion:fusion:f32[128,2048]",
+        "slice_bitcast_fusion:fusion:bf16[2,128,2048]"}
+    assert all("/short_conv/" in scope for _name, scope in mixer), mixer
 
 
 @pytest.mark.parametrize("program,kernel,shape,need", [
