@@ -58,10 +58,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import longcat
-from ray_tpu.models.generate import (AuxCount, PagedFamily, _paged_attend,
-                                     init_block_pool)
-from ray_tpu.ops import moe
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, AuxCount,
+                                     PagedFamily, _paged_attend,
+                                     decode_cells, expert_aux,
+                                     init_block_pool, prefill_cells)
+from ray_tpu.ops import moe, window_ring
 from ray_tpu.ops.layers import (gated_ffn, mm as _mm, rms_norm, rope,
                                 rope_frequencies)
 from ray_tpu.ops.paged_attention import (paged_attention,
@@ -288,38 +289,13 @@ def init_params(config: AfmoeConfig, key: jax.Array) -> Dict:
 # ---------------------------------------------------------------------------
 
 def init_slot_state(config: AfmoeConfig, slots: int) -> Tuple[jax.Array, jax.Array]:
-    """``(K rings, V rings)``, each ``[window layers, slots, ring blocks,
-    window_block_tokens, KV heads * head_dim]``: position ``p`` of a slot
-    lies in block ``(p // block) mod ring blocks``, row ``p mod block``. The
-    decode kernel walks it as ``[window layers, slots * ring blocks, ...]``
-    (the two major dimensions merged: the same bytes) through a table that
-    is each slot's own blocks."""
+    """``(K rings, V rings)`` as ``ops/window_ring.py`` lays them, each
+    ``[window layers, slots, ring blocks, window_block_tokens, KV heads *
+    head_dim]``."""
     c = config
     shape = (c.window_layers, slots, c.ring_blocks, c.window_block_tokens,
              c.num_key_value_heads * c.head_dim)
     return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
-
-
-def _ring_write(rings, wl: int, slot, positions, keep, k, v, c: AfmoeConfig):
-    """Rows ``k`` / ``v`` [N, KV*hd] at ``positions`` [N] of slots ``slot``
-    [N] into window layer ``wl``'s rings; rows not ``keep`` are dropped (a
-    parked slot, a pad position, a position a later one of the same call
-    overwrites)."""
-    k_ring, v_ring = rings
-    rb = c.window_block_tokens
-    # An index past the slots is out of bounds: ``mode="drop"`` skips it.
-    where = (wl, jnp.where(keep, slot, k_ring.shape[1]),
-             (positions // rb) % c.ring_blocks, positions % rb)
-    with jax.named_scope("window_ring_write"):
-        return (k_ring.at[where].set(k, mode="drop"),
-                v_ring.at[where].set(v, mode="drop"))
-
-
-def _as_blocks(ring):
-    """[window layers, slots, blocks, rows, lanes] -> the kernel's pool
-    ``[window layers, slots * blocks, rows, lanes]``."""
-    n, s, r = ring.shape[:3]
-    return ring.reshape((n, s * r) + ring.shape[3:])
 
 
 def _window_attend(q, k, v, rings, wl: int, ctx, c: AfmoeConfig, kernel: str):
@@ -334,19 +310,17 @@ def _window_attend(q, k, v, rings, wl: int, ctx, c: AfmoeConfig, kernel: str):
     if ctx["prefill"]:
         # Of two positions a ring apart the later one's row stays.
         keep = ctx["valid"].reshape(-1) & (pos >= ctx["suffix_len"] - c.ring_rows)
-        rings = _ring_write(rings, wl, ctx["slot"], pos, keep, rows(k), rows(v), c)
+        rings = window_ring.write(rings, wl, ctx["slot"], pos, keep, rows(k),
+                                  rows(v))
         pb = math.gcd(T, 128)
         view = lambda a: a.reshape(1, T // pb, pb, -1)  # noqa: E731
         operands = (view(k), view(v), jnp.arange(T // pb)[None],
                     jnp.zeros((1,), jnp.int32), 0)
     else:
-        slot = jnp.arange(S)
-        rings = _ring_write(rings, wl, slot, pos, ctx["active"], rows(k),
-                            rows(v), c)
-        tables = (slot[:, None] * c.ring_blocks
-                  + jnp.arange(c.ring_blocks)[None, :]).astype(jnp.int32)
-        operands = (_as_blocks(rings[0]), _as_blocks(rings[1]), tables,
-                    ctx["lengths"], wl)
+        rings = window_ring.write(rings, wl, jnp.arange(S), pos, ctx["active"],
+                                  rows(k), rows(v))
+        operands = (*map(window_ring.as_blocks, rings),
+                    window_ring.slot_tables(rings[0]), ctx["lengths"], wl)
     kw = dict(scale=c.head_dim ** -0.5, window=c.sliding_window)
     with jax.named_scope("attn_window"):
         if kernel in ("pallas", "interpret"):
@@ -402,20 +376,13 @@ def _attention(lw, a, pool, rings, layer: int, ctx, c: AfmoeConfig, kernel: str)
 
 
 def expert_layer(lp, x, valid, c: AfmoeConfig):
-    """``sum_{i in P, held} w_i E_i(u) + E_shared(u)`` on ``x`` [S, T, D]:
-    (out, pick counts). Tokens not ``valid`` route to no expert; the shared
-    expert's products run over every row (a dead row's result is dead)."""
-    S, T, D = x.shape
-    flat = x.reshape(S * T, D)
-    idx, w = moe.route_topk(
-        flat, lp["router"], lp["router_bias"], topk=c.num_experts_per_tok,
-        scale=c.route_scale, score=c.score_func, renormalise=c.route_norm)
-    out, counts = moe.held_experts_ffn(
-        flat, idx, w, lp["experts"]["w_gate_up"], lp["experts"]["w_down"],
-        held=c.held, n_routed=c.num_experts, valid=valid.reshape(S * T))
-    with jax.named_scope("moe_shared"):
-        out = out + gated_ffn(lp["shared"], flat, c.dtype)
-    return out.reshape(S, T, D), counts
+    """``moe.expert_layer`` under this family's names, with a gated shared
+    expert."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.num_experts_per_tok, scale=c.route_scale,
+        score=c.score_func, renormalise=c.route_norm, held=c.held,
+        n_routed=c.num_experts,
+        shared=lambda fp, rows: gated_ffn(fp, rows, c.dtype))
 
 
 def _forward(params, tokens, pool, rings, ctx, c: AfmoeConfig, kernel: str,
@@ -448,13 +415,6 @@ def _forward(params, tokens, pool, rings, ctx, c: AfmoeConfig, kernel: str,
     return logits, pool, rings, counts
 
 
-def _aux(counts, capped):
-    """``AUX_COUNTS``' order: the pick counts, a 1 for this token step, the
-    active slots whose context was past the window."""
-    return jnp.concatenate([counts, jnp.ones((1,), jnp.int32),
-                            jnp.reshape(capped, (1,)).astype(jnp.int32)])
-
-
 def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
                           suffix_len, slot, config: AfmoeConfig,
                           block_tokens: int, kernel: str = "gather"):
@@ -465,22 +425,17 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     of every window layer into slot ``slot``'s rings; pad tokens route to no
     expert. The head sees ONE row, the last real position: logits
     ``[1, 1, V]``."""
-    c = config
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     ctx = {"prefill": True, "slot": jnp.asarray(slot, jnp.int32),
            "suffix_len": jnp.asarray(suffix_len, jnp.int32),
            "positions": positions[None], "valid": valid[None],
-           "blk": blk[None], "off": (positions % bt)[None],
-           "tables": table[None],
+           "blk": blk[None], "off": off[None], "tables": table[None],
            "lengths": jnp.reshape(start_pos, (1,)).astype(jnp.int32)}
     logits, pool, rings, counts = _forward(
-        params, tokens, tuple(pool), tuple(state), ctx, c, kernel,
+        params, tokens, tuple(pool), tuple(state), ctx, config, kernel,
         last_row=suffix_len - 1)
-    return logits, pool, rings, _aux(counts, 0)
+    return logits, pool, rings, expert_aux(counts, 0)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -496,21 +451,16 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
     if T != 1:
         raise ValueError("a ring takes one row a step: got "
                          f"{T} (speculative verify is not supported)")
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None]
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(positions < max_len,
-                    tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     if active is None:
         active = jnp.ones((S,), bool)
     ctx = {"prefill": False, "active": active, "positions": positions,
-           "valid": active[:, None], "blk": blk, "off": pos_c % bt,
+           "valid": active[:, None], "blk": blk, "off": off,
            "tables": tables, "lengths": lengths}
     logits, pool, rings, counts = _forward(
         params, tokens, tuple(pool), tuple(state), ctx, c, kernel)
     capped = jnp.sum(active & (lengths >= c.sliding_window))
-    return logits, pool, rings, _aux(counts, capped)
+    return logits, pool, rings, expert_aux(counts, capped)
 
 
 def describe(config: AfmoeConfig) -> Dict[str, int]:
@@ -524,10 +474,9 @@ def describe(config: AfmoeConfig) -> Dict[str, int]:
             "kv_heads": c.num_key_value_heads}
 
 
-# LongCat's names for the expert layer's counts, so that the same readers
-# read this family; then the active slot-steps whose context was past the
-# window (beside the engine's ``state_slot_steps_total``).
-AUX_COUNTS = longcat.AUX_COUNTS + (
+# The expert layers' counts, then the active slot-steps whose context was past
+# the window (beside the engine's ``state_slot_steps_total``).
+AUX_COUNTS = EXPERT_AUX_COUNTS + (
     AuxCount("window_capped_slot_steps_total"),)
 
 PAGED_FAMILY = PagedFamily(

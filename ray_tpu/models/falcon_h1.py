@@ -71,7 +71,8 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.generate import (PagedFamily, _paged_attend,
-                                     init_block_pool)
+                                     decode_cells, init_block_pool,
+                                     prefill_cells)
 from ray_tpu.ops import causal_conv, ssd
 from ray_tpu.ops.layers import mm as _mm, rms_norm, rope, rope_frequencies
 
@@ -453,20 +454,15 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     ``table`` (pad rows to trash block 0) and slot ``slot``'s state from
     zero. The head sees ONE row, the last real position: logits come back
     ``[1, 1, V]``."""
-    c = config
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, _, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     ctx = {"slot": jnp.asarray(slot, jnp.int32),
            "suffix_len": jnp.asarray(suffix_len, jnp.int32),
-           "positions": positions[None],
-           "blk": blk[None], "off": (positions % bt)[None],
+           "positions": positions[None], "blk": blk[None], "off": off[None],
            "tables": table[None],
            "lengths": jnp.reshape(start_pos, (1,)).astype(jnp.int32)}
     logits, pool, state = _forward(
-        params, tokens, pool, state, c, True, kernel, ctx,
+        params, tokens, pool, state, config, True, kernel, ctx,
         head_rows=lambda x: lax.dynamic_slice_in_dim(
             x, suffix_len - 1, 1, axis=1))
     return logits, pool, state, None
@@ -479,23 +475,17 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
     """The family's ``decode``: ``tokens`` [S, 1], slot s's token at position
     ``lengths[s]``. Active slots' states advance by the token; a parked
     slot's stay bit for bit, its K/V write lands in trash block 0."""
-    c = config
     S, T = tokens.shape
     if T != 1:
         raise ValueError("a recurrent state advances one token a step: "
                          f"got {T} (speculative verify is not supported)")
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None]
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(positions < max_len,
-                    tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     if active is None:
         active = jnp.ones((S,), bool)
-    ctx = {"active": active, "positions": positions, "blk": blk,
-           "off": pos_c % bt, "tables": tables, "lengths": lengths}
+    ctx = {"active": active, "positions": positions, "blk": blk, "off": off,
+           "tables": tables, "lengths": lengths}
     logits, pool, state = _forward(
-        params, tokens, pool, state, c, False, kernel, ctx)
+        params, tokens, pool, state, config, False, kernel, ctx)
     return logits, pool, state, None
 
 

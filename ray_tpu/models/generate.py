@@ -25,6 +25,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.transformer import TransformerConfig
+from ray_tpu.ops import moe
 from ray_tpu.ops.layers import gelu, layer_norm, linear, rope
 from ray_tpu.ops.paged_attention import (append_rows_fit, paged_attention,
                                          paged_attention_append)
@@ -155,6 +156,37 @@ def init_block_pool(config: TransformerConfig, num_blocks: int,
     return jnp.zeros(shape, c.dtype), jnp.zeros(shape, c.dtype)
 
 
+def prefill_cells(table, start_pos, suffix_len, P: int, block_tokens: int):
+    """The pool cells of a prefill's ``P`` rows (a suffix bucket at positions
+    ``[start_pos, start_pos + P)``, the first ``suffix_len`` real) through
+    ``table`` [NB]: position ``p`` lives in block ``table[p // block_tokens]``
+    at row ``p % block_tokens``, and a pad row goes to trash block 0. Returns
+    (positions [P], valid [P], blk [P], off [P])."""
+    positions = start_pos + jnp.arange(P)
+    valid = jnp.arange(P) < suffix_len
+    blk = jnp.where(valid, table[jnp.clip(positions // block_tokens, 0,
+                                          table.shape[0] - 1)], 0)
+    return positions, valid, blk, positions % block_tokens
+
+
+def decode_cells(tables, lengths, T: int, block_tokens: int):
+    """The pool cells of a decode step's ``T`` rows a slot through ``tables``
+    [S, NB]: slot ``s``'s row ``t`` sits at position ``lengths[s] + t``. A
+    position at or past the table's capacity writes to trash block 0 rather
+    than clamping onto the last cell: a slot at capacity is finished as
+    ``length_cap`` by the engine BEFORE dispatch, so in-range rows never see
+    a silently overwritten chain, and the redirect only shields a parked
+    slot's overhang writes (its table is all trash). Returns (positions
+    [S, T], blk [S, T], off [S, T])."""
+    S, NB = tables.shape
+    max_len = NB * block_tokens
+    positions = lengths[:, None] + jnp.arange(T)[None, :]
+    pos_c = jnp.minimum(positions, max_len - 1)
+    blk = jnp.where(positions < max_len,
+                    tables[jnp.arange(S)[:, None], pos_c // block_tokens], 0)
+    return positions, blk, pos_c % block_tokens
+
+
 def _paged_attend(q, k_pool, v_pool, tables, lengths, layer, *, scale,
                   kernel, queries=None):
     """Attention over ``layer`` of the whole paged pool, switched by
@@ -198,19 +230,14 @@ def _forward_prefill_paged(params, tokens, k_pool, v_pool, table, start_pos,
     matrix an array of its own in the compute type, read where it lies."""
     c = config
     B, P = tokens.shape  # B == 1
-    NB = table.shape[0]
-    bt = block_tokens
     h = jnp.take(params["tok_embed"], tokens, axis=0)[..., :c.d_model]
-    positions = start_pos + jnp.arange(P)
+    positions, _, blk, off = prefill_cells(table, start_pos, suffix_len, P,
+                                           block_tokens)
     if c.pos == "learned":
         h = h + params["pos_embed"][jnp.minimum(
             positions, c.max_seq_len - 1)][None, :, :c.d_model]
     scale = 1.0 / c.head_dim**0.5
     lengths1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
-    write_ok = jnp.arange(P) < suffix_len
-    blk = jnp.where(write_ok,
-                    table[jnp.clip(positions // bt, 0, NB - 1)], 0)
-    off = positions % bt
 
     for layer, bp in enumerate(params["layers"]):
         x = layer_norm(h, bp["ln1_g"], bp["ln1_b"])
@@ -247,22 +274,13 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
     engine's decode step) hands the kernel the new rows and gets the pools
     back with them in (``paged_attention_append``: no pass over HBM to
     write a row that the next operation fetches); otherwise they are
-    scattered first. Inactive slots carry all-trash tables: the kernel
-    writes nothing for them, the scatter writes block 0, and their outputs
-    are dead.
-
-    Positions at or past table capacity write nothing (the scatter:
-    redirect to trash block 0) rather than clamping onto the last cell — a
-    slot at capacity must be finished as ``length_cap`` by the engine BEFORE
-    dispatch, so in-range rows never see a silently overwritten chain; the
-    redirect only shields a parked slot's overhang writes.
+    scattered first (:func:`decode_cells`). Inactive slots carry all-trash
+    tables: the kernel writes nothing for them, the scatter writes block 0,
+    and their outputs are dead.
 
     ``params`` is the working tree of :func:`gpt2_working_params`."""
     c = config
     S, T = tokens.shape
-    NB = tables.shape[1]
-    bt = block_tokens
-    max_len = NB * bt
     h = jnp.take(params["tok_embed"], tokens, axis=0)[..., :c.d_model]
     positions = lengths[:, None] + jnp.arange(T)[None, :]  # [S, T]
     if c.pos == "learned":
@@ -273,15 +291,14 @@ def _forward_decode_paged(params, tokens, k_pool, v_pool, tables, lengths,
     # the new rows as operands and writes them itself. Otherwise (the gather
     # path, several tokens a slot, a pool row off the 128-lane grid, more
     # rows than the kernel holds in VMEM) the rows are scattered into the
-    # pool first and attended there.
+    # pool first and attended there: the cells are taken in that arm alone
+    # (the order in which the step first reads its operands is part of the
+    # compiled program, and the appending program reads ``tables`` in its
+    # kernel's call first).
     appends = (kernel in ("pallas", "interpret") and T == 1
                and append_rows_fit(S, k_pool.shape[3]))
     if not appends:
-        write_ok = positions < max_len
-        pos_c = jnp.minimum(positions, max_len - 1)
-        blk = jnp.where(write_ok, tables[jnp.arange(S)[:, None], pos_c // bt],
-                        0)
-        off = pos_c % bt
+        _, blk, off = decode_cells(tables, lengths, T, block_tokens)
 
     for layer, bp in enumerate(params["layers"]):
         x = layer_norm(h, bp["ln1_g"], bp["ln1_b"])
@@ -320,6 +337,32 @@ class AuxCount(NamedTuple):
     decode: str
     prefill: Optional[str] = None
     step_attr: Optional[str] = None
+
+
+# ``stats()`` names of an expert family's counts (:func:`expert_aux`), the same
+# for every family so that the same readers read them all (a family without
+# zero-compute experts leaves ``moe_picks_zero_total`` at 0). A prefill's picks
+# are kept apart (the per-step means stay the decode step's), and how often its
+# expert layers took the bounded row buffer and walked past its first window;
+# its busiest expert, experts hit and step are not kept. The decode chunk's
+# held pairs ride ``llm.step``.
+_PREFILL_KEPT = ("picks", "picks_zero", "picks_held", "bounded_calls",
+                 "extra_windows")
+EXPERT_AUX_COUNTS = tuple(
+    AuxCount(f"moe_{n}_total",
+             f"moe_prefill_{n}_total" if n in _PREFILL_KEPT else None,
+             "moe_held_pairs" if n == "picks_held" else None)
+    for n in moe.PICK_COUNT_NAMES) + (AuxCount("moe_steps_total"),)
+
+
+def expert_aux(counts, *more):
+    """A forward pass's ``aux`` in ``EXPERT_AUX_COUNTS``' order: the expert
+    layers' pick counts summed over layers (``moe.expert_layer``'s), a 1 for
+    this token step, then ``more``: the values of the entries a family
+    appends to the tuple, scalars or vectors."""
+    return jnp.concatenate(
+        [counts, jnp.ones((1,), jnp.int32)]
+        + [jnp.ravel(m).astype(jnp.int32) for m in more])
 
 
 class PagedFamily(NamedTuple):

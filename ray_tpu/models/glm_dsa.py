@@ -29,7 +29,8 @@ block manager and the engine know block ids alone.
 Attention is the shared latent sublayer (``ops/mla.py``) with the selection
 handed in as keep bits (``ops/sparse_select.py``): no YaRN (``rope_type``
 default), softmax scale ``(qk_nope + qk_rope)^-0.5``. The expert layer is
-Kimi-K2's own function (``models/kimi_k2.py:expert_layer``).
+Kimi-K2's in form (sigmoid scores renormalised over the picks, a gated shared
+expert), bound to ``ops/moe.py:expert_layer`` under the same key names.
 
 The family refuses the prefix cache: a hit at position p is a prefill that
 starts at p, whose queries score CACHED index keys of blocks other slots
@@ -50,12 +51,13 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import longcat
-from ray_tpu.models.generate import AuxCount, PagedFamily
-from ray_tpu.models.kimi_k2 import expert_layer
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, AuxCount,
+                                     PagedFamily, decode_cells, expert_aux,
+                                     prefill_cells)
 from ray_tpu.ops import moe
 from ray_tpu.ops.layers import gated_ffn, layer_norm, mm, rms_norm, rope
-from ray_tpu.ops.mla import LatentSpec, latent_attention
+from ray_tpu.ops.mla import LatentSpec, init_latent_pool, latent_attention
+from ray_tpu.ops.paged_attention import latent_group_blocks
 from ray_tpu.ops.sparse_select import keep_bits
 
 # What seeded weights must bring that a trained model has: attention logits
@@ -288,8 +290,8 @@ def init_pool(config: GlmDsaConfig, num_blocks: int,
     keys [layers, num_blocks, block_tokens, index_head_dim])``: block 0 of
     both is the trash block, blocks are dimension 1 of both."""
     c = config
-    return (jnp.zeros((c.attn_sublayers, num_blocks, block_tokens,
-                       c.pool_width), c.dtype),
+    return (init_latent_pool(c.latent_spec(), c.attn_sublayers, num_blocks,
+                             block_tokens),
             jnp.zeros((c.attn_sublayers, num_blocks, block_tokens,
                        c.index_head_dim), c.dtype))
 
@@ -331,6 +333,16 @@ def select(cq, *, ip, a, index, sub, tables, positions, c: GlmDsaConfig,
         keys = index[sub, tables].reshape(S, -1, c.index_head_dim)
     return keep_bits(q, w, keys, positions, k=c.index_topk, kernel=kernel,
                      dtype=c.dtype)
+
+
+def expert_layer(lp, x, valid, c: GlmDsaConfig):
+    """``moe.expert_layer`` under this family's names: sigmoid scores
+    renormalised over the picks, a gated shared expert."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.num_experts_per_tok,
+        scale=c.routed_scaling_factor, score="sigmoid", renormalise=True,
+        held=c.held, n_routed=c.n_routed_experts,
+        shared=lambda fp, rows: gated_ffn(fp, rows, c.dtype))
 
 
 def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
@@ -385,17 +397,16 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
 
 
 def _aux(counts, live, contexts, kept, c: GlmDsaConfig):
-    """``AUX_COUNTS``' order: the pick counts, a 1 for this token step, then
-    the selection's over the ``live`` slots at ``contexts`` (rows visible to
-    this step's query, its own among them): rows chosen (``kept`` [S], the
-    keep bits that were SET, so that the count witnesses the selection and
-    not the traffic), rows visible, slot-steps whose context was past
+    """``AUX_COUNTS``' order: the expert layers' counts, then the selection's
+    over the ``live`` slots at ``contexts`` (rows visible to this step's
+    query, its own among them): rows chosen (``kept`` [S], the keep bits
+    that were SET, so that the count witnesses the selection and not the
+    traffic), rows visible, slot-steps whose context was past
     ``index_topk``, slot-steps."""
     ctx = jnp.where(live, contexts, 0)
-    dsa = [jnp.sum(jnp.where(live, kept, 0)), jnp.sum(ctx),
-           jnp.sum(ctx > c.index_topk), jnp.sum(live)]
-    return jnp.concatenate([counts, jnp.ones((1,), jnp.int32),
-                            jnp.stack(dsa).astype(jnp.int32)])
+    return expert_aux(counts, jnp.stack(
+        [jnp.sum(jnp.where(live, kept, 0)), jnp.sum(ctx),
+         jnp.sum(ctx > c.index_topk), jnp.sum(live)]))
 
 
 def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
@@ -407,19 +418,15 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     row, the last real position (logits [1, 1, V]). The family keeps no slot
     state (``state`` is the empty tuple, handed back). A prefill's selection
     is not counted (``AUX_COUNTS``: the decode steps' alone)."""
-    c = config
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     lengths1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
     logits, pool, counts, _ = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
-        blk[None], (positions % bt)[None], valid[None], c, kernel,
+        blk[None], off[None], valid[None], config, kernel,
         last_row=suffix_len - 1, queries=suffix_len)
     return logits, pool, state, _aux(counts, jnp.zeros((1,), bool), lengths1,
-                                     jnp.zeros((1,), jnp.int32), c)
+                                     jnp.zeros((1,), jnp.int32), config)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -427,28 +434,21 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
                          kernel: str = "gather",
                          active: Optional[jax.Array] = None):
     """The family's ``decode``: ``tokens`` [S, T], slot s's token t at
-    position ``lengths[s] + t``. Writes at or past table capacity go to trash
-    block 0 of both pools; slots not ``active`` route to no expert and count
-    no selection."""
-    c = config
+    position ``lengths[s] + t`` (``generate.decode_cells``, the same cells
+    of both pools); slots not ``active`` route to no expert and count no
+    selection."""
     S, T = tokens.shape
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None] + jnp.arange(T)[None, :]
-    write_ok = positions < max_len
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(write_ok, tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     live = jnp.ones((S,), bool) if active is None else active
     logits, pool, counts, kept = _forward(
-        params, tokens, pool, tables, lengths, positions, blk, pos_c % bt,
-        jnp.broadcast_to(live[:, None], (S, T)), c, kernel)
-    return logits, pool, state, _aux(counts, live, lengths + T, kept, c)
+        params, tokens, pool, tables, lengths, positions, blk, off,
+        jnp.broadcast_to(live[:, None], (S, T)), config, kernel)
+    return logits, pool, state, _aux(counts, live, lengths + T, kept, config)
 
 
-# LongCat's names for the expert layer's counts (so that the same readers read
-# every expert family), then the selection's four; ``dsa_rows`` rides the
+# The expert layers' counts, then the selection's four; ``dsa_rows`` rides the
 # ``llm.step`` span.
-AUX_COUNTS = longcat.AUX_COUNTS + (
+AUX_COUNTS = EXPERT_AUX_COUNTS + (
     AuxCount("dsa_selected_rows_total", None, "dsa_rows"),
     AuxCount("dsa_context_rows_total"),
     AuxCount("dsa_capped_slot_steps_total"),
@@ -462,5 +462,6 @@ PAGED_FAMILY = PagedFamily(
     unsupported=("prefix_cache",),
     aux_counts=AUX_COUNTS,
     describe=describe,
-    walk_group_blocks=longcat.latent_walk_group_blocks,
+    walk_group_blocks=lambda c, pool: latent_group_blocks(
+        pool[0], c.num_attention_heads),
 )

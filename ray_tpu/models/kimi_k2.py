@@ -25,8 +25,8 @@ of a deployment are summed it counts once.
 Attention is the shared latent sublayer (``ops/mla.py``): no
 ``mla_scale_*`` factors, YaRN's blended rotary frequencies, a softmax scale
 times ``mscale ** 2``, and the two absorbed projections stored heads-major,
-as their products read them. The pool is LongCat's: one ``[c_kv | k_rope]``
-row a token a layer, padded to 640 lanes.
+as their products read them. The pool is ``ops/mla.py``'s: one ``[c_kv |
+k_rope]`` row a token a layer, padded to 640 lanes.
 
 Weights are created and stored in ``param_dtype`` (bfloat16), one array a
 matrix and no stacking over layers; the serve programs read them as stored
@@ -41,11 +41,12 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import longcat
-from ray_tpu.models.generate import PagedFamily
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, PagedFamily,
+                                     decode_cells, expert_aux, prefill_cells)
 from ray_tpu.ops import moe
 from ray_tpu.ops.layers import gated_ffn, rms_norm, yarn_mscale
-from ray_tpu.ops.mla import LatentSpec, latent_attention
+from ray_tpu.ops.mla import LatentSpec, init_latent_pool, latent_attention
+from ray_tpu.ops.paged_attention import latent_group_blocks
 
 # Kimi-K2.5's published ``rope_scaling`` (type yarn), as sorted pairs so that
 # the config stays hashable; ``KimiK2Config`` takes a mapping too.
@@ -262,27 +263,14 @@ def describe(config: KimiK2Config) -> Dict[str, int]:
 # Forward over the paged latent pool
 # ---------------------------------------------------------------------------
 
-def expert_layer(lp, x, valid, c):
-    """``sum_{i in P, held} w_i E_i(u) + E_shared(u)`` on ``x`` [S, T, D]:
-    (out, pick counts). Tokens not ``valid`` route to no expert; the shared
-    expert's products run over every row (a dead row's result is dead).
-    ``c`` is the config of any family whose experts are gated (``lfm2``
-    calls this too): it names ``num_experts_per_tok``,
-    ``routed_scaling_factor``, ``n_routed_experts``, ``held`` and ``dtype``;
-    a layer without ``"shared"`` has no shared expert."""
-    S, T, D = x.shape
-    flat = x.reshape(S * T, D)
-    with jax.named_scope("moe_router"):
-        idx, w = moe.route_topk(
-            flat, lp["router"], lp["router_bias"], topk=c.num_experts_per_tok,
-            scale=c.routed_scaling_factor, score="sigmoid", renormalise=True)
-    out, counts = moe.held_experts_ffn(
-        flat, idx, w, lp["experts"]["w_gate_up"], lp["experts"]["w_down"],
-        held=c.held, n_routed=c.n_routed_experts, valid=valid.reshape(S * T))
-    if "shared" in lp:
-        with jax.named_scope("moe_shared"):
-            out = out + gated_ffn(lp["shared"], flat, c.dtype)
-    return out.reshape(S, T, D), counts
+def expert_layer(lp, x, valid, c: KimiK2Config):
+    """``moe.expert_layer`` under this family's names: sigmoid scores
+    renormalised over the picks, a gated shared expert."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.num_experts_per_tok,
+        scale=c.routed_scaling_factor, score="sigmoid", renormalise=True,
+        held=c.held, n_routed=c.n_routed_experts,
+        shared=lambda fp, rows: gated_ffn(fp, rows, c.dtype))
 
 
 def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
@@ -292,8 +280,8 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     (``blk``, ``off``); ``valid`` [S, T] marks the tokens whose output is
     read. ``last_row``: hand the head that one position alone. ``queries``:
     a prefill's count of real rows, the attention kernel's. Returns
-    (logits float32, pool, counts): the expert layers' pick counts summed
-    over layers, then a 1 for this token step (``longcat.AUX_COUNTS``' order)."""
+    (logits float32, pool, the expert layers' pick counts summed over
+    layers)."""
     dt = c.dtype
     eps = c.rms_norm_eps
     spec = c.latent_spec()
@@ -317,7 +305,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     x = rms_norm(x, params["norm_f"], eps)
     logits = jnp.einsum("std,dv->stv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
-    return logits, pool, jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
+    return logits, pool, counts
 
 
 def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
@@ -332,17 +320,14 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     one reads. The family keeps no slot state (``state`` is the empty tuple,
     handed back)."""
     (pool,) = pool
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     lengths1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
     logits, pool, counts = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
-        blk[None], (positions % bt)[None], valid[None], config, kernel,
+        blk[None], off[None], valid[None], config, kernel,
         last_row=suffix_len - 1, queries=suffix_len)
-    return logits, (pool,), state, counts
+    return logits, (pool,), state, expert_aux(counts)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -350,35 +335,28 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
                          kernel: str = "gather",
                          active: Optional[jax.Array] = None):
     """The family's ``decode``: ``tokens`` [S, T], slot s's token t at
-    position ``lengths[s] + t``. Writes at or past table capacity go to trash
-    block 0; slots not ``active`` route to no expert, so an idle slot's
-    garbage reads no routed expert's weights and counts no pick."""
+    position ``lengths[s] + t`` (``generate.decode_cells``); slots not
+    ``active`` route to no expert, so an idle slot's garbage reads no routed
+    expert's weights and counts no pick."""
     (pool,) = pool
     S, T = tokens.shape
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None] + jnp.arange(T)[None, :]
-    write_ok = positions < max_len
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(write_ok, tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     valid = jnp.ones((S, T), bool) if active is None else jnp.broadcast_to(
         active[:, None], (S, T))
     logits, pool, counts = _forward(
-        params, tokens, pool, tables, lengths, positions, blk, pos_c % bt,
-        valid, config, kernel)
-    return logits, (pool,), state, counts
+        params, tokens, pool, tables, lengths, positions, blk, off, valid,
+        config, kernel)
+    return logits, (pool,), state, expert_aux(counts)
 
 
 PAGED_FAMILY = PagedFamily(
-    # LongCat's pool (one ``[c_kv | k_rope]`` row a token an attention
-    # sublayer, block 0 the trash block) and its names for the counts, so
-    # that the same readers read both families: this family has no
-    # zero-compute expert, and ``moe_picks_zero_total`` stays 0.
-    init_pool=longcat.init_latent_pool,
+    init_pool=lambda c, num_blocks, block_tokens: (init_latent_pool(
+        c.latent_spec(), c.attn_sublayers, num_blocks, block_tokens),),
     prefill=forward_prefill_paged,
     decode=forward_decode_paged,
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
-    aux_counts=longcat.AUX_COUNTS,
+    aux_counts=EXPERT_AUX_COUNTS,
     describe=describe,
-    walk_group_blocks=longcat.latent_walk_group_blocks,
+    walk_group_blocks=lambda c, pool: latent_group_blocks(
+        pool[0], c.num_attention_heads),
 )

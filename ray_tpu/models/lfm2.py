@@ -37,8 +37,8 @@ f)`` in float32 over ``num_experts`` outputs, the ``num_experts_per_tok``
 largest of ``s + expert_bias`` picked (the bias selects, it never weighs), a
 pick's weight ``routed_scaling_factor x s_i / sum of the picked s``
 (``norm_topk_prob``), experts of the same gated form at
-``moe_intermediate_size``, no shared expert (``kimi_k2.expert_layer``: the
-one expert layer of the families whose experts are ``silu_gate``). ``held =
+``moe_intermediate_size``, no shared expert (``ops/moe.py:expert_layer``).
+``held =
 (first, count)`` says which experts' weights live here; the published model
 on one chip of a pipeline holds them all, ``(0, num_experts)``.
 
@@ -67,10 +67,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import longcat
-from ray_tpu.models.generate import (PagedFamily, _paged_attend,
-                                     init_block_pool)
-from ray_tpu.models.kimi_k2 import expert_layer
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, PagedFamily,
+                                     _paged_attend, decode_cells, expert_aux,
+                                     init_block_pool, prefill_cells)
 from ray_tpu.ops import causal_conv, moe
 from ray_tpu.ops.layers import gated_ffn, mm as _mm, rms_norm, rope
 
@@ -176,8 +175,7 @@ class Lfm2Config:
 
     @property
     def n_routed_experts(self) -> int:
-        """The router's outputs, under the name ``kimi_k2.expert_layer``
-        reads."""
+        """The router's outputs, under the other expert families' name."""
         return self.num_experts
 
     @property
@@ -421,6 +419,15 @@ def _attention(mw, a, pool, al, ctx, c: Lfm2Config, kernel: str):
     return _mm("ste,ed->std", o.reshape(S, T, -1), mw["w_o"], dt), (k_pool, v_pool)
 
 
+def expert_layer(lp, x, valid, c: Lfm2Config):
+    """``moe.expert_layer`` under this family's names: sigmoid scores
+    renormalised over the picks, no shared expert."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.num_experts_per_tok,
+        scale=c.routed_scaling_factor, score="sigmoid", renormalise=True,
+        held=c.held, n_routed=c.num_experts)
+
+
 @functools.lru_cache(maxsize=None)
 def _layer_fn(c: Lfm2Config, kind: str, ffn: str, prefill: bool, kernel: str):
     """One KIND of layer (its mixer x its feed-forward) as a jit of its own,
@@ -474,12 +481,6 @@ def _forward(params, tokens, pool, state, c: Lfm2Config, prefill: bool,
     return logits, mem[ATTENTION], mem[CONV], counts
 
 
-def _aux(counts):
-    """``longcat.AUX_COUNTS``' order: the pick counts, a 1 for this token
-    step."""
-    return jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
-
-
 def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
                           suffix_len, slot, config: Lfm2Config,
                           block_tokens: int, kernel: str = "gather"):
@@ -489,22 +490,17 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     layers' rows through ``table`` (pad rows to trash block 0) and slot
     ``slot``'s tails from zero; pad tokens route to no expert. The head sees
     ONE row, the last real position: logits ``[1, 1, V]``."""
-    c = config
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     ctx = {"slot": jnp.asarray(slot, jnp.int32),
            "suffix_len": jnp.asarray(suffix_len, jnp.int32),
            "valid": valid[None], "positions": positions[None],
-           "blk": blk[None], "off": (positions % bt)[None],
-           "tables": table[None],
+           "blk": blk[None], "off": off[None], "tables": table[None],
            "lengths": jnp.reshape(start_pos, (1,)).astype(jnp.int32)}
     logits, pool, state, counts = _forward(
-        params, tokens, pool, state, c, True, kernel, ctx,
+        params, tokens, pool, state, config, True, kernel, ctx,
         last_row=suffix_len - 1)
-    return logits, pool, state, _aux(counts)
+    return logits, pool, state, expert_aux(counts)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -515,24 +511,18 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
     ``lengths[s]``. Active slots' tails take the token's row; a parked
     slot's stay bit for bit, its K/V write lands in trash block 0 and it
     routes to no expert."""
-    c = config
     S, T = tokens.shape
     if T != 1:
         raise ValueError("the convolution's tail advances one token a step: "
                          f"got {T} (speculative verify is not supported)")
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None]
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(positions < max_len,
-                    tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     if active is None:
         active = jnp.ones((S,), bool)
     ctx = {"active": active, "valid": active[:, None], "positions": positions,
-           "blk": blk, "off": pos_c % bt, "tables": tables, "lengths": lengths}
+           "blk": blk, "off": off, "tables": tables, "lengths": lengths}
     logits, pool, state, counts = _forward(
-        params, tokens, pool, state, c, False, kernel, ctx)
-    return logits, pool, state, _aux(counts)
+        params, tokens, pool, state, config, False, kernel, ctx)
+    return logits, pool, state, expert_aux(counts)
 
 
 def describe(config: Lfm2Config) -> Dict[str, int]:
@@ -558,8 +548,6 @@ PAGED_FAMILY = PagedFamily(
     # As the other families with a state a slot: a hit at position p would
     # need every convolution layer's tail at p (ROADMAP R4).
     unsupported=("prefix_cache",),
-    # LongCat's names for the expert layers' counts, so that the same
-    # readers read this family.
-    aux_counts=longcat.AUX_COUNTS,
+    aux_counts=EXPERT_AUX_COUNTS,
     describe=describe,
 )

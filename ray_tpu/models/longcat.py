@@ -40,10 +40,11 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.generate import AuxCount, PagedFamily
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, PagedFamily,
+                                     decode_cells, expert_aux, prefill_cells)
 from ray_tpu.ops import moe
 from ray_tpu.ops.layers import gated_ffn as _ffn, rms_norm
-from ray_tpu.ops.mla import LatentSpec, latent_attention
+from ray_tpu.ops.mla import LatentSpec, init_latent_pool, latent_attention
 from ray_tpu.ops.paged_attention import latent_group_blocks
 
 
@@ -211,23 +212,6 @@ def init_params(config: LongCatConfig, key: jax.Array) -> Dict:
 # Forward over the paged latent pool
 # ---------------------------------------------------------------------------
 
-def init_latent_pool(config: LongCatConfig, num_blocks: int,
-                     block_tokens: int) -> Tuple[jax.Array]:
-    """``([attention sublayers, num_blocks, block_tokens, pool_width],)``:
-    one array where GPT-2 has a K and a V pool. Block 0 is the trash block,
-    blocks are dimension 1, so the generator's block copy, extract and insert
-    index it as they index GPT-2's."""
-    c = config
-    return (jnp.zeros((c.attn_sublayers, num_blocks, block_tokens,
-                       c.pool_width), c.dtype),)
-
-
-def latent_walk_group_blocks(config, pool) -> int:
-    """``PagedFamily.walk_group_blocks`` of a family whose pool's first
-    array is the latent rows: a decode tile's rows are its heads."""
-    return latent_group_blocks(pool[0], config.num_attention_heads)
-
-
 def _mla(ap, x, pool, sub: int, blk, off, tables, lengths, positions,
          c: LongCatConfig, kernel: str, queries=None):
     """The shared latent sublayer (``ops/mla.py``) with this family's spec."""
@@ -236,15 +220,14 @@ def _mla(ap, x, pool, sub: int, blk, off, tables, lengths, positions,
                             queries=queries)
 
 
-def _moe(lp, x, valid, c: LongCatConfig):
-    S, T, D = x.shape
-    flat = x.reshape(S * T, D)
-    idx, w = moe.route_topk(flat, lp["router"], lp["router_bias"],
-                            topk=c.moe_topk, scale=c.routed_scaling_factor)
-    out, counts = moe.held_experts_ffn(
-        flat, idx, w, lp["experts"]["w_gate_up"], lp["experts"]["w_down"],
-        held=c.held, n_routed=c.n_routed_experts, valid=valid.reshape(S * T))
-    return out.reshape(S, T, D), counts
+def expert_layer(lp, x, valid, c: LongCatConfig):
+    """``moe.expert_layer`` under this family's names: a softmax over the
+    routed AND the zero-compute outputs, no renormalising, no shared
+    expert."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.moe_topk, scale=c.routed_scaling_factor,
+        score="softmax", renormalise=False, held=c.held,
+        n_routed=c.n_routed_experts)
 
 
 def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
@@ -252,9 +235,8 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     """tokens [S, T] at absolute ``positions`` [S, T]; rows go to pool cells
     (``blk``, ``off``); ``valid`` [S, T] marks the tokens whose output is
     read; ``queries``: a prefill's count of real rows, the attention
-    kernel's. Returns (logits [S, T, V] float32, pool, counts): the expert
-    layers' pick counts summed over layers, then a 1 for this token step
-    (``AUX_COUNTS`` names them in this order)."""
+    kernel's. Returns (logits [S, T, V] float32, pool, the expert layers'
+    pick counts summed over layers)."""
     dt = c.dtype
     eps = c.rms_norm_eps
     x = jnp.take(params["tok_embed"], tokens, axis=0).astype(dt)
@@ -265,7 +247,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
                        kernel, queries)
         a = x + o
         hn = rms_norm(a, lp["norm_ffn"][0], eps)
-        m, cnt = _moe(lp, hn, valid, c)
+        m, cnt = expert_layer(lp, hn, valid, c)
         counts = counts + cnt
         b = a + _ffn(lp["ffn"][0], hn, dt)
         o, pool = _mla(lp["attn"][1], rms_norm(b, lp["norm_attn"][1], eps),
@@ -277,7 +259,7 @@ def _forward(params, tokens, pool, tables, lengths, positions, blk, off,
     x = rms_norm(x, params["norm_f"], eps)
     logits = jnp.einsum("std,dv->stv", x, params["lm_head"],
                         preferred_element_type=jnp.float32)
-    return logits, pool, jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
+    return logits, pool, counts
 
 
 def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
@@ -290,17 +272,14 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     ``generate._forward_prefill_paged``, plus the pick counts; the family
     keeps no slot state (``state`` is the empty tuple, handed back)."""
     (pool,) = pool
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     lengths1 = jnp.reshape(start_pos, (1,)).astype(jnp.int32)
     logits, pool, counts = _forward(
         params, tokens, pool, table[None], lengths1, positions[None],
-        blk[None], (positions % bt)[None], valid[None], config, kernel,
+        blk[None], off[None], valid[None], config, kernel,
         queries=suffix_len)
-    return logits, (pool,), state, counts
+    return logits, (pool,), state, expert_aux(counts)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -308,44 +287,27 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
                          kernel: str = "gather",
                          active: Optional[jax.Array] = None):
     """The family's ``decode``: ``tokens`` [S, T], slot s's token t at
-    position ``lengths[s] + t``. Writes at or past table capacity go to trash
-    block 0 (as ``generate._forward_decode_paged``); slots not ``active``
-    route to no expert, so an idle slot's garbage reads no expert's weights
-    and counts no pick."""
+    position ``lengths[s] + t`` (``generate.decode_cells``); slots not
+    ``active`` route to no expert, so an idle slot's garbage reads no
+    expert's weights and counts no pick."""
     (pool,) = pool
     S, T = tokens.shape
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None] + jnp.arange(T)[None, :]
-    write_ok = positions < max_len
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(write_ok, tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     valid = jnp.ones((S, T), bool) if active is None else jnp.broadcast_to(
         active[:, None], (S, T))
     logits, pool, counts = _forward(
-        params, tokens, pool, tables, lengths, positions, blk, pos_c % bt,
-        valid, config, kernel)
-    return logits, (pool,), state, counts
+        params, tokens, pool, tables, lengths, positions, blk, off, valid,
+        config, kernel)
+    return logits, (pool,), state, expert_aux(counts)
 
-
-# ``stats()`` names of ``_forward``'s counts. A prefill's picks are kept apart
-# (the per-step means stay the decode step's), and how often its expert
-# layers took the bounded row buffer and walked past its first window; its
-# busiest expert, experts hit and step are not kept. The decode chunk's held
-# pairs ride ``llm.step``.
-_PREFILL_KEPT = ("picks", "picks_zero", "picks_held", "bounded_calls",
-                 "extra_windows")
-AUX_COUNTS = tuple(
-    AuxCount(f"moe_{n}_total",
-             f"moe_prefill_{n}_total" if n in _PREFILL_KEPT else None,
-             "moe_held_pairs" if n == "picks_held" else None)
-    for n in moe.PICK_COUNT_NAMES) + (AuxCount("moe_steps_total"),)
 
 PAGED_FAMILY = PagedFamily(
-    init_pool=init_latent_pool,
+    init_pool=lambda c, num_blocks, block_tokens: (init_latent_pool(
+        c.latent_spec(), c.attn_sublayers, num_blocks, block_tokens),),
     prefill=forward_prefill_paged,
     decode=forward_decode_paged,
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
-    aux_counts=AUX_COUNTS,
-    walk_group_blocks=latent_walk_group_blocks,
+    aux_counts=EXPERT_AUX_COUNTS,
+    walk_group_blocks=lambda c, pool: latent_group_blocks(
+        pool[0], c.num_attention_heads),
 )

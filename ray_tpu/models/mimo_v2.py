@@ -63,9 +63,10 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.afmoe import AUX_COUNTS, _as_blocks, _aux, _ring_write
-from ray_tpu.models.generate import PagedFamily
-from ray_tpu.ops import moe
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, AuxCount,
+                                     PagedFamily, decode_cells, expert_aux,
+                                     prefill_cells)
+from ray_tpu.ops import moe, window_ring
 from ray_tpu.ops.layers import (gated_ffn, mm as _mm, rms_norm, rope,
                                 rope_frequencies)
 from ray_tpu.ops.paged_attention import (paged_attention,
@@ -319,8 +320,6 @@ def init_params(config: MimoV2Config, key: jax.Array) -> Dict:
 
 # ---------------------------------------------------------------------------
 # The two kinds of memory: the full layers' pool, the window layers' rings
-# (a ring's write and its view as blocks are Trinity's, ``models/afmoe.py``:
-# they read ``window_block_tokens`` and ``ring_blocks`` off whatever config)
 # ---------------------------------------------------------------------------
 
 def init_pool(config: MimoV2Config, num_blocks: int,
@@ -337,12 +336,9 @@ def init_pool(config: MimoV2Config, num_blocks: int,
 
 def init_slot_state(config: MimoV2Config,
                     slots: int) -> Tuple[jax.Array, jax.Array]:
-    """``(K rings, V rings)``: ``[window layers, slots, ring blocks,
-    window_block_tokens, swa KV heads * 192]`` and ``[.., swa KV heads *
-    128]``: position ``p`` of a slot lies in block ``(p // block) mod ring
-    blocks``, row ``p mod block``. The decode kernel walks them as ``[window
-    layers, slots * ring blocks, ...]`` through a table that is each slot's
-    own blocks."""
+    """``(K rings, V rings)`` as ``ops/window_ring.py`` lays them:
+    ``[window layers, slots, ring blocks, window_block_tokens, swa KV heads
+    * 192]`` and ``[.., swa KV heads * 128]``."""
     c = config
     shape = (c.window_layers, slots, c.ring_blocks, c.window_block_tokens)
     KV = c.swa_num_key_value_heads
@@ -375,19 +371,17 @@ def _window_attend(q, k, v, sink, rings, wl: int, ctx, c: MimoV2Config,
     if ctx["prefill"]:
         # Of two positions a ring apart the later one's row stays.
         keep = ctx["valid"].reshape(-1) & (pos >= ctx["suffix_len"] - c.ring_rows)
-        rings = _ring_write(rings, wl, ctx["slot"], pos, keep, rows(k), rows(v), c)
+        rings = window_ring.write(rings, wl, ctx["slot"], pos, keep, rows(k),
+                                  rows(v))
         pb = math.gcd(T, 128)
         view = lambda a: a.reshape(1, T // pb, pb, -1)  # noqa: E731
         operands = (view(k), view(v), jnp.arange(T // pb)[None],
                     jnp.zeros((1,), jnp.int32), 0)
     else:
-        slot = jnp.arange(S)
-        rings = _ring_write(rings, wl, slot, pos, ctx["active"], rows(k),
-                            rows(v), c)
-        tables = (slot[:, None] * c.ring_blocks
-                  + jnp.arange(c.ring_blocks)[None, :]).astype(jnp.int32)
-        operands = (_as_blocks(rings[0]), _as_blocks(rings[1]), tables,
-                    ctx["lengths"], wl)
+        rings = window_ring.write(rings, wl, jnp.arange(S), pos, ctx["active"],
+                                  rows(k), rows(v))
+        operands = (*map(window_ring.as_blocks, rings),
+                    window_ring.slot_tables(rings[0]), ctx["lengths"], wl)
     with jax.named_scope("attn_window"):
         o = _attend(q, operands, kernel, ctx, scale=c.head_dim ** -0.5,
                     window=c.sliding_window, sinks=sink)
@@ -445,18 +439,11 @@ def _attention(lw, a, pool, rings, layer: int, ctx, c: MimoV2Config,
 
 
 def expert_layer(lp, x, valid, c: MimoV2Config):
-    """``sum_{i in P, held} w_i E_i(u)`` on ``x`` [S, T, D]: (out, pick
-    counts). Tokens not ``valid`` route to no expert. No shared expert."""
-    S, T, D = x.shape
-    flat = x.reshape(S * T, D)
-    idx, w = moe.route_topk(
-        flat, lp["router"], lp["router_bias"], topk=c.num_experts_per_tok,
-        scale=c.route_scale, score=c.scoring_func,
-        renormalise=c.norm_topk_prob)
-    out, counts = moe.held_experts_ffn(
-        flat, idx, w, lp["experts"]["w_gate_up"], lp["experts"]["w_down"],
-        held=c.held, n_routed=c.n_routed_experts, valid=valid.reshape(S * T))
-    return out.reshape(S, T, D), counts
+    """``moe.expert_layer`` under this family's names; no shared expert."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.num_experts_per_tok, scale=c.route_scale,
+        score=c.scoring_func, renormalise=c.norm_topk_prob, held=c.held,
+        n_routed=c.n_routed_experts)
 
 
 def _forward(params, tokens, pool, rings, ctx, c: MimoV2Config, kernel: str,
@@ -498,22 +485,17 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     of every window layer into slot ``slot``'s rings; pad tokens route to no
     expert. The head sees ONE row, the last real position: logits
     ``[1, 1, V]``."""
-    c = config
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    positions, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     ctx = {"prefill": True, "slot": jnp.asarray(slot, jnp.int32),
            "suffix_len": jnp.asarray(suffix_len, jnp.int32),
            "positions": positions[None], "valid": valid[None],
-           "blk": blk[None], "off": (positions % bt)[None],
-           "tables": table[None],
+           "blk": blk[None], "off": off[None], "tables": table[None],
            "lengths": jnp.reshape(start_pos, (1,)).astype(jnp.int32)}
     logits, pool, rings, counts = _forward(
-        params, tokens, tuple(pool), tuple(state), ctx, c, kernel,
+        params, tokens, tuple(pool), tuple(state), ctx, config, kernel,
         last_row=suffix_len - 1)
-    return logits, pool, rings, _aux(counts, 0)
+    return logits, pool, rings, expert_aux(counts, 0)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -529,21 +511,16 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
     if T != 1:
         raise ValueError("a ring takes one row a step: got "
                          f"{T} (speculative verify is not supported)")
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None]
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(positions < max_len,
-                    tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    positions, blk, off = decode_cells(tables, lengths, T, block_tokens)
     if active is None:
         active = jnp.ones((S,), bool)
     ctx = {"prefill": False, "active": active, "positions": positions,
-           "valid": active[:, None], "blk": blk, "off": pos_c % bt,
+           "valid": active[:, None], "blk": blk, "off": off,
            "tables": tables, "lengths": lengths}
     logits, pool, rings, counts = _forward(
         params, tokens, tuple(pool), tuple(state), ctx, c, kernel)
     capped = jnp.sum(active & (lengths >= c.sliding_window))
-    return logits, pool, rings, _aux(counts, capped)
+    return logits, pool, rings, expert_aux(counts, capped)
 
 
 def describe(config: MimoV2Config) -> Dict[str, int]:
@@ -569,8 +546,9 @@ PAGED_FAMILY = PagedFamily(
     logits_dim=lambda params, config: params["lm_head"].shape[-1],
     init_slot_state=init_slot_state,
     unsupported=("prefix_cache",),
-    # Trinity's: the expert layer's counts, then the active slot-steps whose
-    # context was past the window.
-    aux_counts=AUX_COUNTS,
+    # The expert layers' counts, then the active slot-steps whose context
+    # was past the window (Trinity's names: the same readers read both).
+    aux_counts=EXPERT_AUX_COUNTS + (
+        AuxCount("window_capped_slot_steps_total"),),
     describe=describe,
 )

@@ -73,9 +73,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import longcat
-from ray_tpu.models.generate import (PagedFamily, _paged_attend,
-                                     init_block_pool)
+from ray_tpu.models.generate import (EXPERT_AUX_COUNTS, PagedFamily,
+                                     _paged_attend, decode_cells, expert_aux,
+                                     init_block_pool, prefill_cells)
 from ray_tpu.ops import causal_conv, moe, ssd
 from ray_tpu.ops.layers import mm as _mm, rms_norm
 
@@ -479,23 +479,14 @@ def relu2_ffn(fp, x, dtype):
 
 
 def expert_layer(lp, x, valid, c: NemotronHConfig):
-    """``sum_{i in P, held} w_i E_i(u) + E_shared(u)`` on ``x`` [S, T, D]:
-    (out, pick counts). Tokens not ``valid`` route to no expert; the shared
-    expert's products run over every row (a dead row's result is dead)."""
-    S, T, D = x.shape
-    flat = x.reshape(S * T, D)
-    with jax.named_scope("moe_router"):
-        idx, w = moe.route_topk(
-            flat, lp["router"], lp["router_bias"], topk=c.num_experts_per_tok,
-            scale=c.routed_scaling_factor, score="sigmoid",
-            renormalise=c.norm_topk_prob)
-    out, counts = moe.held_experts_ffn(
-        flat, idx, w, lp["experts"]["w_up"], lp["experts"]["w_down"],
-        held=c.held, n_routed=c.n_routed_experts, valid=valid.reshape(S * T),
-        form="relu2")
-    with jax.named_scope("moe_shared"):
-        out = out + relu2_ffn(lp["shared"], flat, c.dtype)
-    return out.reshape(S, T, D), counts
+    """``moe.expert_layer`` under this family's names: two-matrix ``relu2``
+    experts, the shared one too."""
+    return moe.expert_layer(
+        lp, x, valid, topk=c.num_experts_per_tok,
+        scale=c.routed_scaling_factor, score="sigmoid",
+        renormalise=c.norm_topk_prob, held=c.held,
+        n_routed=c.n_routed_experts, form="relu2", w_in="w_up",
+        shared=lambda fp, rows: relu2_ffn(fp, rows, c.dtype))
 
 
 @functools.lru_cache(maxsize=None)
@@ -547,12 +538,6 @@ def _forward(params, tokens, pool, state, c: NemotronHConfig, prefill: bool,
     return logits, mem[ATTENTION], mem[MIXER], counts
 
 
-def _aux(counts):
-    """``longcat.AUX_COUNTS``' order: the pick counts, a 1 for this token
-    step."""
-    return jnp.concatenate([counts, jnp.ones((1,), jnp.int32)])
-
-
 def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
                           suffix_len, slot, config: NemotronHConfig,
                           block_tokens: int, kernel: str = "gather"):
@@ -562,21 +547,17 @@ def forward_prefill_paged(params, tokens, pool, state, table, start_pos,
     layers' rows through ``table`` (pad rows to trash block 0) and slot
     ``slot``'s mixer states from zero; pad tokens route to no expert. The
     head sees ONE row, the last real position: logits ``[1, 1, V]``."""
-    c = config
-    P = tokens.shape[1]
-    NB, bt = table.shape[0], block_tokens
-    positions = start_pos + jnp.arange(P)
-    valid = jnp.arange(P) < suffix_len
-    blk = jnp.where(valid, table[jnp.clip(positions // bt, 0, NB - 1)], 0)
+    _, valid, blk, off = prefill_cells(
+        table, start_pos, suffix_len, tokens.shape[1], block_tokens)
     ctx = {"slot": jnp.asarray(slot, jnp.int32),
            "suffix_len": jnp.asarray(suffix_len, jnp.int32),
-           "valid": valid[None], "blk": blk[None],
-           "off": (positions % bt)[None], "tables": table[None],
+           "valid": valid[None], "blk": blk[None], "off": off[None],
+           "tables": table[None],
            "lengths": jnp.reshape(start_pos, (1,)).astype(jnp.int32)}
     logits, pool, state, counts = _forward(
-        params, tokens, pool, state, c, True, kernel, ctx,
+        params, tokens, pool, state, config, True, kernel, ctx,
         last_row=suffix_len - 1)
-    return logits, pool, state, _aux(counts)
+    return logits, pool, state, expert_aux(counts)
 
 
 def forward_decode_paged(params, tokens, pool, state, tables, lengths,
@@ -587,24 +568,18 @@ def forward_decode_paged(params, tokens, pool, state, tables, lengths,
     ``lengths[s]``. Active slots' states advance by the token; a parked
     slot's stay bit for bit, its K/V write lands in trash block 0 and it
     routes to no expert."""
-    c = config
     S, T = tokens.shape
     if T != 1:
         raise ValueError("a recurrent state advances one token a step: "
                          f"got {T} (speculative verify is not supported)")
-    NB, bt = tables.shape[1], block_tokens
-    max_len = NB * bt
-    positions = lengths[:, None]
-    pos_c = jnp.minimum(positions, max_len - 1)
-    blk = jnp.where(positions < max_len,
-                    tables[jnp.arange(S)[:, None], pos_c // bt], 0)
+    _, blk, off = decode_cells(tables, lengths, T, block_tokens)
     if active is None:
         active = jnp.ones((S,), bool)
     ctx = {"active": active, "valid": active[:, None], "blk": blk,
-           "off": pos_c % bt, "tables": tables, "lengths": lengths}
+           "off": off, "tables": tables, "lengths": lengths}
     logits, pool, state, counts = _forward(
-        params, tokens, pool, state, c, False, kernel, ctx)
-    return logits, pool, state, _aux(counts)
+        params, tokens, pool, state, config, False, kernel, ctx)
+    return logits, pool, state, expert_aux(counts)
 
 
 def describe(config: NemotronHConfig) -> Dict[str, int]:
@@ -628,8 +603,6 @@ PAGED_FAMILY = PagedFamily(
     # As the other families with a state a slot: a hit at position p would
     # need every mixer layer's state at p (ROADMAP R4).
     unsupported=("prefix_cache",),
-    # LongCat's names for the expert layers' counts, so that the same
-    # readers read this family.
-    aux_counts=longcat.AUX_COUNTS,
+    aux_counts=EXPERT_AUX_COUNTS,
     describe=describe,
 )

@@ -53,6 +53,16 @@ class LatentSpec:
     heads_major: bool = False
 
 
+def init_latent_pool(spec: LatentSpec, sublayers: int, num_blocks: int,
+                     block_tokens: int):
+    """The latent rows of ``sublayers`` attention sublayers, ``[sublayers,
+    num_blocks, block_tokens, spec.pool_width]``: one array where GPT-2 has
+    a K and a V pool. Block 0 is the trash block and blocks are dimension 1,
+    so the generator's block copy indexes it as it indexes GPT-2's."""
+    return jnp.zeros((sublayers, num_blocks, block_tokens, spec.pool_width),
+                     spec.dtype)
+
+
 def latent_attention(ap, x, pool, sub, blk, off, tables, lengths, positions,
                      spec: LatentSpec, kernel: str,
                      select: Optional[Callable] = None, queries=None):

@@ -17,7 +17,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -492,3 +492,45 @@ def held_experts_ffn(h: jax.Array, idx: jax.Array, weights: jax.Array,
               "experts_hit": jnp.sum(sizes > 0), **bounded}
     return out.astype(h.dtype), jnp.stack(
         [counts[n] for n in PICK_COUNT_NAMES]).astype(jnp.int32)
+
+
+def expert_layer(lp: Dict, x: jax.Array, valid: jax.Array, *, topk: int,
+                 scale: float, score: str, renormalise: bool,
+                 held: Tuple[int, int], n_routed: int,
+                 form: str = "silu_gate", w_in: str = "w_gate_up",
+                 shared: Optional[Callable] = None,
+                 ) -> Tuple[jax.Array, jax.Array]:
+    """One chip's part of a routed expert layer on ``x`` [S, T, D], the one
+    every expert family binds its config's names to: ``sum_{i in picks,
+    held} w_i E_i(x)`` (:func:`route_topk` over all the router's outputs,
+    :func:`held_experts_ffn` over the experts ``held`` of ``n_routed``, of
+    the ``form``), plus ``E_shared(x)`` where the family has a shared expert.
+    Returns (out [S, T, D], counts int32 [PICK_COUNTS]).
+
+    ``lp`` holds ``router`` [D, outputs], ``router_bias`` [outputs],
+    ``experts`` (its first matrix under the name ``w_in``, and ``w_down``)
+    and, with ``shared``, the shared expert's weights ``lp["shared"]``:
+    ``shared(lp["shared"], rows)`` is the family's own feed-forward on the
+    FLAT rows [S * T, D]; it is whole on every chip and runs over every row
+    (a dead row's result is dead). Tokens not ``valid`` [S, T] (pad
+    positions, parked slots) route to no expert, read no expert's weights
+    and count no pick.
+
+    The layer's scopes, the names a profile's operations carry:
+    ``moe_router`` holds the router's product, the scores and the top-k;
+    ``moe_experts`` the expert products alone (inside
+    :func:`held_experts_ffn`: the sort, the gathers and the weighted sum
+    around them carry no scope of the three); ``moe_shared`` the shared
+    expert."""
+    S, T, D = x.shape
+    flat = x.reshape(S * T, D)
+    with jax.named_scope("moe_router"):
+        idx, w = route_topk(flat, lp["router"], lp["router_bias"], topk=topk,
+                            scale=scale, score=score, renormalise=renormalise)
+    out, counts = held_experts_ffn(
+        flat, idx, w, lp["experts"][w_in], lp["experts"]["w_down"], held=held,
+        n_routed=n_routed, valid=valid.reshape(S * T), form=form)
+    if shared is not None:
+        with jax.named_scope("moe_shared"):
+            out = out + shared(lp["shared"], flat)
+    return out.reshape(S, T, D), counts

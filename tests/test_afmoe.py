@@ -438,7 +438,8 @@ def test_the_programs_carry_the_named_scopes_and_kernel_names(model):
         np.zeros(2, np.int32), np.ones(2, bool), np.ones(2, bool),
         np.zeros(2, np.float32)).as_text(debug_info=True)
     for scope in ("attn_window", "attn_full", "attn_gate", "window_ring_write",
-                  "kv_pool_write", "moe_shared", "moe_experts", "dense_ffn"):
+                  "kv_pool_write", "moe_router", "moe_shared", "moe_experts",
+                  "dense_ffn"):
         assert scope in text, scope
     jaxpr = str(jax.make_jaxpr(gen.decode_fn(1))(
         params, pool, state, last, keys, np.zeros((2, 4), np.int32),

@@ -162,7 +162,7 @@ def test_absorbed_latent_attention_matches_unabsorbed(model, kernel):
     T = 21
     x = jax.random.normal(jax.random.key(3), (1, T, cfg.hidden_size))
     want = np.asarray(ref._mla(ap, x, ref_config(cfg)))[0]
-    (pool,) = longcat.init_latent_pool(cfg, 6, BT)
+    (pool,) = cfg.paged_family().init_pool(cfg, 6, BT)
     table = jnp.asarray([[2, 4]], jnp.int32)
     pos = jnp.arange(T - 1)[None]
     out, pool = longcat._mla(
@@ -195,7 +195,7 @@ def test_shares_sum_to_the_uncut_layer():
         part = cfg.replace(held=(first, 4))
         lp_part = dict(lp, experts=jax.tree.map(
             lambda w: w[first:first + 4], lp["experts"]))
-        out, _counts = longcat._moe(lp_part, h, jnp.ones((1, 24), bool), part)
+        out, _counts = longcat.expert_layer(lp_part, h, jnp.ones((1, 24), bool), part)
         shares_prog.append(np.asarray(out))
         lw_part = dict(lw, w_gate_up=lw["w_gate_up"][first:first + 4],
                        w_down=lw["w_down"][first:first + 4])
@@ -206,7 +206,7 @@ def test_shares_sum_to_the_uncut_layer():
                                atol=TOL)
     np.testing.assert_allclose(sum(shares_ref) + zero_only, uncut, atol=TOL)
     # and the uncut program layer is the uncut reference layer
-    out, counts = longcat._moe(lp, h, jnp.ones((1, 24), bool), cfg)
+    out, counts = longcat.expert_layer(lp, h, jnp.ones((1, 24), bool), cfg)
     np.testing.assert_allclose(np.asarray(out), uncut, atol=TOL)
     assert int(counts[0]) == int(counts[1]) + int(counts[2]) == 24 * 3
 

@@ -395,8 +395,9 @@ def test_the_programs_carry_the_named_scopes_and_kernel_names(model):
             np.zeros(2, np.float32))
     text = gen.decode_fn(2).lower(*args).as_text(debug_info=True)
     for scope in ("attn_window", "attn_full", "window_ring_write",
-                  "kv_pool_write", "moe_experts", "dense_ffn"):
+                  "kv_pool_write", "moe_router", "moe_experts", "dense_ffn"):
         assert scope in text, scope
+    assert "moe_shared" not in text                 # there is no shared expert
     jaxpr = str(jax.make_jaxpr(gen.decode_fn(1))(*args))
     # the profiler tells window from full by the kernel's own name
     assert "window_decode_attn" in jaxpr and "paged_decode_attn" in jaxpr
